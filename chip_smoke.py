@@ -1,0 +1,681 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the trainer still starts on the chip.
+
+    python chip_smoke.py
+
+drives the training path once through the entry points a user calls, at
+the full width and depth of `TransformerConfig.bert_base()`, on every chip
+JAX finds: `make_mesh` -> `synchronous_sgd` -> `make_train_step`, the
+tensor-parallel `make_sharded_train_step`, `kfrun` workers joined by
+`initialize_device_plane()`, two worlds bridged by `make_hier_train_step`,
+and the Pallas flash-attention kernels compiled by Mosaic. It exits 0 only
+if every phase passed, and then ends its output with two lines: the report
+(`CHIP_SMOKE_REPORT ` and one JSON object: versions, and per phase its wall
+seconds, seconds to the first step and the checks' values) and, last, the
+result, `{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`
+with the device as JAX reports it and no other key. On the first failure it
+names the phase and exits non-zero with neither line. There is no flag:
+without a TPU the first phase fails.
+
+This process never imports jax. A chip belongs to one process at a time,
+so every phase runs in a child (or a `kfrun` tree) that is gone before the
+next one starts. The phase bodies below are plain functions of the model
+configuration and step count; `tests/test_chip_smoke.py` drives them at
+`tiny()` size on the CPU mesh. What they time is printed as smoke timings
+and is no benchmark figure.
+"""
+
+from __future__ import annotations
+
+import collections
+import hashlib
+import json
+import os
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+RESULT_TAG = "CHIP_SMOKE_RESULT "  # a child's line, read by the parent
+REPORT_TAG = "CHIP_SMOKE_REPORT "  # the parent's account of every phase
+
+# -- what the chip run uses (the bodies take these as arguments) ----------
+PER_CHIP_BATCH = 16  # largest power of two that leaves headroom in 16 GB
+TRAIN_STEPS = 8  # after the compiling one
+WORKER_STEPS = 3  # launcher and hierarchical workers
+LEARNING_RATE = 3e-4
+# train-sharded against train, first three losses. Both compute in bf16
+# (8-bit mantissa, 2^-8 = 3.9e-3 per rounding) and reduce in different
+# orders: tp splits the contractions of wo/w_out and the vocabulary sum
+# of the loss, dp=2 averages two half-batches where dp=4 averages four
+# quarters. One part in a hundred is about two and a half roundings.
+LOSS_RTOL = 1e-2
+# kernels against the float32 dense reference, as a share of the largest
+# reference value: outputs and gradients are rounded to bf16 once more
+# than the reference's, and the backward's delta term is built from the
+# bf16-rounded output.
+KERNEL_TOL = 2e-2
+KERNEL_SEQ = 2048
+KERNEL_HEAD_DIMS = (64, 128)
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+class SmokeFailure(RuntimeError):
+    """A check of the smoke did not hold."""
+
+
+def _check(ok: bool, what: str) -> None:
+    if not ok:
+        raise SmokeFailure(what)
+
+
+# -- phase bodies (run in children; import jax lazily) ---------------------
+
+_events = None
+
+
+def _jax_events() -> collections.Counter:
+    """Count jax.monitoring events by name, from the first call on. Every
+    compile request — XLA compile or persistent-cache load — raises one
+    COMPILE_EVENT, so a difference of two readings is a count of
+    compilations, not an inference from step times."""
+    global _events
+    if _events is None:
+        from jax import monitoring
+
+        _events = collections.Counter()
+        monitoring.register_event_listener(
+            lambda event, **kw: _events.update([event])
+        )
+        monitoring.register_event_duration_secs_listener(
+            lambda event, duration, **kw: _events.update([event])
+        )
+    return _events
+
+
+def _device_report() -> dict:
+    import jax
+
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def _memory(devices) -> dict:
+    """bytes_in_use / peak_bytes_in_use per device (None where the
+    backend keeps no statistics, as the CPU's does not)."""
+    stats = [d.memory_stats() or {} for d in devices]
+    return {
+        "bytes_in_use": [s.get("bytes_in_use") for s in stats],
+        "peak_bytes_in_use": [s.get("peak_bytes_in_use") for s in stats],
+    }
+
+
+def _seeded_batch(cfg, global_batch: int):
+    """A fixed batch of token ids, (B, S+1): the loss shifts it by one."""
+    import numpy as np
+
+    rng = np.random.RandomState(0)
+    return rng.randint(
+        0, cfg.vocab_size, (global_batch, cfg.max_seq + 1)
+    ).astype(np.int32)
+
+
+def _init_params(cfg):
+    import jax
+
+    from kungfu_tpu.models.transformer import init_transformer
+
+    return jax.jit(lambda key: init_transformer(key, cfg))(jax.random.PRNGKey(0))
+
+
+def _loss_fn(cfg):
+    from kungfu_tpu.models.transformer import transformer_loss
+
+    return lambda params, batch: transformer_loss(params, batch, cfg)
+
+
+def _run_steps(step, params, opt_state, batch, n_steps: int,
+               devices) -> dict:
+    """One compiling step and n_steps more; every step closed by
+    block_until_ready, compilations counted per step, `devices`' memory
+    read while the parameters and optimizer state are still alive."""
+    import jax
+
+    events = _jax_events()
+    losses, seconds, compiles = [], [], []
+    for _ in range(n_steps + 1):
+        c0 = events[COMPILE_EVENT]
+        t0 = time.perf_counter()
+        params, opt_state, loss = step(params, opt_state, batch)
+        jax.block_until_ready((params, opt_state, loss))
+        seconds.append(time.perf_counter() - t0)
+        compiles.append(events[COMPILE_EVENT] - c0)
+        losses.append(float(loss))
+    _check(all(l == l and abs(l) != float("inf") for l in losses),
+           f"non-finite loss: {losses}")
+    _check(losses[-1] < losses[0],
+           f"loss did not fall on the fixed batch: {losses}")
+    _check(sum(compiles[1:]) == 0,
+           f"compilations after the first step: {compiles}")
+    return {
+        "params": params,
+        "losses": [round(l, 6) for l in losses],
+        "first_step_s": round(seconds[0], 3),
+        "step_s": [round(s, 4) for s in seconds[1:]],
+        "compiles_first_step": compiles[0],
+        "compiles_after_first_step": sum(compiles[1:]),
+        "cache_hits": events[CACHE_HIT_EVENT],
+        "cache_misses": events[CACHE_MISS_EVENT],
+        **_memory(devices),
+    }
+
+
+def devices_phase() -> dict:
+    """Every device is a TPU of a kind the repo's one table names."""
+    import importlib.metadata as md
+
+    import jax
+    import jaxlib
+
+    from kungfu_tpu.parallel.chip import require_tpu
+
+    require_tpu()
+    return {
+        "device": _device_report(),
+        "versions": {
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            "libtpu": md.version("libtpu"),
+        },
+    }
+
+
+def train_phase(cfg, steps: int, per_chip_batch: int) -> dict:
+    """The README quick-start on all chips of one process: data-parallel
+    S-SGD over AdamW through make_train_step."""
+    import jax
+    import optax
+
+    from kungfu_tpu.optimizers import synchronous_sgd
+    from kungfu_tpu.parallel import make_mesh, make_train_step
+    from kungfu_tpu.parallel.chip import enable_compile_cache
+    from kungfu_tpu.parallel.dp import replicate, shard_batch
+
+    t_start = time.perf_counter()
+    enable_compile_cache()
+    n = jax.device_count()
+    mesh = make_mesh({"dp": n})
+    opt = synchronous_sgd(optax.adamw(LEARNING_RATE), "dp")
+    params = replicate(_init_params(cfg), mesh)
+    opt_state = replicate(opt.init(params), mesh)
+    batch = shard_batch(_seeded_batch(cfg, per_chip_batch * n), mesh)
+    step = make_train_step(_loss_fn(cfg), opt, mesh)
+    setup_s = time.perf_counter() - t_start
+
+    out = _run_steps(step, params, opt_state, batch, steps, jax.devices())
+    params = out.pop("params")
+    leaves = jax.tree.leaves(params)
+    _check(all(len(l.sharding.device_set) == n for l in leaves),
+           f"a parameter leaf does not span all {n} devices")
+    batch_devices = {s.device for s in batch.addressable_shards}
+    _check(len(batch_devices) == n,
+           f"batch shards sit on {len(batch_devices)} devices, not {n}")
+    return {
+        "device": _device_report(),
+        "mesh": {"dp": n},
+        "global_batch": per_chip_batch * n,
+        "param_count": sum(l.size for l in leaves),
+        "setup_s": round(setup_s, 3),
+        **out,
+    }
+
+
+def train_sharded_phase(cfg, steps: int, global_batch: int,
+                        reference_losses) -> dict:
+    """The same model and batch over dp=2 x tp: param_pspecs ->
+    shard_params -> make_sharded_train_step; its first losses must agree
+    with the data-parallel run's."""
+    import jax
+    import optax
+
+    from kungfu_tpu.models.transformer import param_pspecs
+    from kungfu_tpu.parallel import make_mesh
+    from kungfu_tpu.parallel.chip import enable_compile_cache
+    from kungfu_tpu.parallel.dp import shard_batch
+    from kungfu_tpu.parallel.sharded import (
+        init_opt_state,
+        make_sharded_train_step,
+        shard_params,
+    )
+
+    t_start = time.perf_counter()
+    enable_compile_cache()
+    n = jax.device_count()
+    tp = n // 2
+    mesh = make_mesh({"dp": 2, "tp": tp})
+    specs = param_pspecs(cfg, "tp")
+    opt = optax.adamw(LEARNING_RATE)
+    params = shard_params(_init_params(cfg), mesh, specs)
+    opt_state = init_opt_state(opt, params, mesh)
+    batch = shard_batch(_seeded_batch(cfg, global_batch), mesh)
+    step = make_sharded_train_step(_loss_fn(cfg), opt, mesh, specs)
+    setup_s = time.perf_counter() - t_start
+
+    out = _run_steps(step, params, opt_state, batch, steps, jax.devices())
+    params = out.pop("params")
+    for name in ("wqkv", "w_in"):
+        leaf = params["layers"][name]
+        widths = {s.data.shape[-1] for s in leaf.addressable_shards}
+        _check(widths == {leaf.shape[-1] // tp},
+               f"{name} is not split {tp} ways over tp: shard widths {widths}")
+        _check(len(leaf.sharding.device_set) == n,
+               f"{name} does not span all {n} devices")
+    k = len(reference_losses)
+    drift = [abs(a - b) / abs(b)
+             for a, b in zip(out["losses"][:k], reference_losses)]
+    _check(max(drift) <= LOSS_RTOL,
+           f"losses {out['losses'][:k]} differ from the data-parallel run's "
+           f"{list(reference_losses)} by more than {LOSS_RTOL}: {drift}")
+    return {
+        "device": _device_report(),
+        "mesh": {"dp": 2, "tp": tp},
+        "mesh_devices": [[d.id for d in row] for row in mesh.devices],
+        "mesh_coords": [[getattr(d, "coords", None) for d in row]
+                        for row in mesh.devices],
+        "global_batch": global_batch,
+        "setup_s": round(setup_s, 3),
+        **out,
+        "loss_drift": [round(d, 6) for d in drift],
+    }
+
+
+def kernels_phase(seq: int, head_dims, interpret: bool,
+                  blk: int = 512) -> dict:
+    """flash_attention forward and backward against the float32 dense
+    reference and its jax.grad, bf16, causal."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kungfu_tpu.ops.flash_attention import (
+        _dense_reference,
+        flash_attention,
+    )
+    from kungfu_tpu.parallel.chip import enable_compile_cache
+
+    enable_compile_cache()
+    out = {"device": _device_report(), "seq": seq, "errors": {}}
+    t_start = time.perf_counter()
+
+    def value_and_grads(f):
+        return jax.jit(
+            jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+        )
+
+    with jax.default_matmul_precision("highest"):
+        for hd in head_dims:
+            # drawn on the host: no program besides the two under test
+            rng = np.random.RandomState(hd)
+            q, k, v, w = (
+                jnp.asarray(rng.standard_normal((1, 4, seq, hd)), jnp.bfloat16)
+                for _ in range(4)
+            )
+            scale = 1.0 / hd ** 0.5
+
+            def flash(q, k, v):
+                o = flash_attention(q, k, v, True, None, blk, blk, interpret)
+                return jnp.sum(o.astype(jnp.float32) * w), o
+
+            def dense(q, k, v):
+                o = _dense_reference(q, k, v, True, scale)
+                return jnp.sum(o.astype(jnp.float32) * w), o
+
+            (_, o_f), g_f = value_and_grads(flash)(q, k, v)
+            (_, o_d), g_d = value_and_grads(dense)(q, k, v)
+            for name, a, b in zip(
+                ("out", "dq", "dk", "dv"), (o_f, *g_f), (o_d, *g_d)
+            ):
+                a = a.astype(jnp.float32)
+                b = b.astype(jnp.float32)
+                _check(bool(jnp.all(jnp.isfinite(a))),
+                       f"hd={hd} {name}: non-finite values")
+                err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                out["errors"][f"hd{hd}_{name}"] = round(err, 5)
+                _check(err <= KERNEL_TOL,
+                       f"hd={hd} {name}: error {err:.4f} of the reference's "
+                       f"scale exceeds {KERNEL_TOL}")
+    out["check_s"] = round(time.perf_counter() - t_start, 3)
+    return out
+
+
+def _params_digest(params) -> bytes:
+    import jax
+    import numpy as np
+
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(params):
+        h.update(np.asarray(leaf).tobytes())
+    return h.digest()
+
+
+def _native_loaded() -> bool:
+    from kungfu_tpu.base import ops
+
+    return bool(ops._load_native())
+
+
+def launcher_worker(cfg, steps: int, per_chip_batch: int,
+                    chips_per_worker: int = 1) -> dict:
+    """One kfrun worker of the launcher phase: join the one device world,
+    take a few S-SGD steps over it, agree on the parameters."""
+    from kungfu_tpu import api
+    from kungfu_tpu.parallel import initialize_device_plane
+
+    rank, size = api.current_rank(), api.cluster_size()
+    initialize_device_plane()
+
+    import jax
+    import numpy as np
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from kungfu_tpu.initializer import broadcast_variables
+    from kungfu_tpu.optimizers import synchronous_sgd
+    from kungfu_tpu.parallel import make_mesh, make_train_step
+    from kungfu_tpu.parallel.dp import replicate
+
+    t_start = time.perf_counter()
+    n = jax.device_count()
+    _check(jax.local_device_count() == chips_per_worker,
+           f"worker {rank} opened {jax.local_device_count()} devices, "
+           f"not {chips_per_worker}")
+    _check(jax.process_count() == size,
+           f"jax.process_count() {jax.process_count()} != {size} workers")
+    _check(n == size * chips_per_worker,
+           f"the world has {n} devices, not {size * chips_per_worker}")
+
+    # rank 0 is the source of truth, wherever libtpu put its chip
+    source = int(broadcast_variables({"rank": np.asarray(rank, np.int32)})["rank"])
+    _check(source == 0,
+           f"broadcast_variables delivered rank {source}'s values, not rank 0's")
+
+    mesh = make_mesh({"dp": n})
+    opt = synchronous_sgd(optax.adamw(LEARNING_RATE), "dp")
+    params = broadcast_variables(_init_params(cfg), mesh)
+    opt_state = replicate(opt.init(params), mesh)
+    tokens = _seeded_batch(cfg, per_chip_batch * n)
+    batch = jax.make_array_from_callback(
+        tokens.shape, NamedSharding(mesh, P("dp")), lambda idx: tokens[idx]
+    )
+    step = make_train_step(_loss_fn(cfg), opt, mesh)
+    setup_s = time.perf_counter() - t_start
+
+    out = _run_steps(step, params, opt_state, batch, steps,
+                     jax.local_devices())
+    agreed = api.consensus(_params_digest(out.pop("params")), "chip-smoke")
+    _check(agreed, "workers disagree on the parameters after the last step")
+    api.run_barrier()
+    return {
+        "rank": rank,
+        "workers": size,
+        "device": _device_report(),
+        "local_devices": [d.id for d in jax.local_devices()],
+        "process_index": jax.process_index(),
+        "global_batch": per_chip_batch * n,
+        "setup_s": round(setup_s, 3),
+        **out,
+        "params_agree": agreed,
+        "native_kernels": _native_loaded(),
+    }
+
+
+def hier_worker(cfg, steps: int, per_chip_batch: int) -> dict:
+    """One kfrun worker of the two-world launch: its chips are a world of
+    their own; gradients cross worlds over the host plane from inside the
+    jitted step (make_hier_train_step's io_callback)."""
+    from kungfu_tpu import api
+
+    rank, size = api.current_rank(), api.cluster_size()
+
+    import jax
+    import optax
+
+    from kungfu_tpu.ops.hierarchical import make_hier_train_step
+    from kungfu_tpu.parallel import make_mesh
+    from kungfu_tpu.parallel.chip import enable_compile_cache
+    from kungfu_tpu.parallel.dp import replicate, shard_batch
+
+    t_start = time.perf_counter()
+    enable_compile_cache()
+    n = jax.device_count()
+    _check(jax.process_count() == 1, "a hierarchical worker is its own world")
+    mesh = make_mesh({"dp": n})
+    opt = optax.adamw(LEARNING_RATE)
+    params = replicate(_init_params(cfg), mesh)  # same seed in every world
+    opt_state = replicate(opt.init(params), mesh)
+    rows = per_chip_batch * n
+    tokens = _seeded_batch(cfg, rows * size)[rank * rows:(rank + 1) * rows]
+    batch = shard_batch(tokens, mesh)
+    step = make_hier_train_step(_loss_fn(cfg), opt, mesh)
+    setup_s = time.perf_counter() - t_start
+
+    out = _run_steps(step, params, opt_state, batch, steps,
+                     jax.local_devices())
+    agreed = api.consensus(_params_digest(out.pop("params")), "chip-smoke-hier")
+    _check(agreed, "the worlds hold different parameters after the last step")
+    api.run_barrier()
+    return {
+        "rank": rank,
+        "worlds": size,
+        "device": _device_report(),
+        "local_devices": [d.id for d in jax.local_devices()],
+        "global_batch": rows * size,
+        "setup_s": round(setup_s, 3),
+        **out,
+        "params_agree": agreed,
+        "native_kernels": _native_loaded(),
+    }
+
+
+# -- the children, on the chip ---------------------------------------------
+
+
+def _require_memory_in_use(result: dict, at_least: int) -> None:
+    used = result["bytes_in_use"]
+    _check(all(b is not None and b >= at_least for b in used),
+           f"a device reports less than {at_least} bytes in use: {used}")
+
+
+def _child(phase: str, arg: str) -> dict:
+    """Run one phase in this process at the chip's sizes. Every branch
+    establishes that the devices are TPUs before it compiles anything."""
+    if phase == "devices":
+        return devices_phase()
+
+    from kungfu_tpu.models.transformer import TransformerConfig
+    from kungfu_tpu.parallel.chip import require_tpu
+
+    cfg = TransformerConfig.bert_base()
+    if phase == "launcher-worker":
+        from kungfu_tpu.parallel import initialize_device_plane
+
+        initialize_device_plane()  # before the backend starts
+        require_tpu()
+        return launcher_worker(cfg, WORKER_STEPS, PER_CHIP_BATCH)
+    require_tpu()
+    if phase == "train":
+        result = train_phase(cfg, TRAIN_STEPS, PER_CHIP_BATCH)
+        # replicated f32 parameters and both AdamW moments, at the least
+        _require_memory_in_use(result, 3 * 4 * result["param_count"])
+        return result
+    if phase == "train-sharded":
+        spec = json.loads(arg)
+        result = train_sharded_phase(
+            cfg, TRAIN_STEPS, spec["global_batch"], spec["losses"]
+        )
+        # make_mesh reshapes jax.devices() in enumeration order: the
+        # chips of one tp group must be ICI neighbours on the 2x2 host
+        for row in result["mesh_coords"]:
+            for a, b in zip(row, row[1:]):
+                hops = sum(abs(x - y) for x, y in zip(a, b))
+                _check(hops == 1, f"tp neighbours {a} and {b} are {hops} "
+                                  "hops apart")
+        return result
+    if phase == "hier-worker":
+        return hier_worker(cfg, WORKER_STEPS, PER_CHIP_BATCH)
+    if phase == "kernels":
+        return kernels_phase(KERNEL_SEQ, KERNEL_HEAD_DIMS, interpret=False)
+    raise SystemExit(f"chip_smoke: unknown phase {phase!r}")
+
+
+# -- the parent: no jax here -----------------------------------------------
+
+
+def _run(name: str, argv, timeout: float):
+    """Run one child (or kfrun tree) in a session of its own, echo its
+    output, collect its tagged result lines, and leave nothing of it
+    behind. Returns (exit code, results, wall seconds)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    t0 = time.monotonic()
+    proc = subprocess.Popen(
+        argv, cwd=REPO, env=env, stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT, text=True, start_new_session=True,
+    )
+
+    def kill_tree():
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+
+    timer = threading.Timer(timeout, kill_tree)
+    timer.start()
+    results = []
+    try:
+        for line in proc.stdout:
+            sys.stdout.write(f"[{name}] {line}")
+            sys.stdout.flush()
+            if RESULT_TAG in line:
+                results.append(json.loads(line.split(RESULT_TAG, 1)[1]))
+        code = proc.wait()
+    finally:
+        timed_out = not timer.is_alive()
+        timer.cancel()
+        kill_tree()  # stragglers of the session, if any
+    if timed_out:
+        print(f"[{name}] killed after {timeout:.0f} s", flush=True)
+        code = code or 124
+    return code, results, round(time.monotonic() - t0, 1)
+
+
+def _kfrun(np_: int, host_chips: int, worker_phase: str):
+    return [
+        sys.executable, "-m", "kungfu_tpu.runner.cli",
+        "-np", str(np_), "-H", f"127.0.0.1:{np_}",
+        "-devices-per-host", str(host_chips),
+        "--", sys.executable, os.path.join(REPO, "chip_smoke.py"),
+        worker_phase,
+    ]
+
+
+def result_line(device: dict) -> str:
+    """The last line of a passing run: these keys and no others."""
+    return json.dumps({
+        "ok": True,
+        "device": {
+            "platform": str(device["platform"]),
+            "kind": str(device["kind"]),
+            "count": int(device["count"]),
+        },
+    })
+
+
+def main() -> int:
+    deadline = time.monotonic() + 1150  # the whole run, compilation included
+    phases = {}
+
+    def fail(name: str, why: str, code: int = 1):
+        print(f"chip_smoke: phase '{name}' FAILED ({why})", file=sys.stderr)
+        sys.exit(code or 1)
+
+    def phase(name: str, argv, want: int = 1, cap: float = 420):
+        """Run a phase; exit on its failure, naming it."""
+        budget = min(cap, deadline - time.monotonic())
+        code, results, wall = (
+            _run(name, argv, budget) if budget > 0 else (124, [], 0.0)
+        )
+        if code != 0 or len(results) != want:
+            fail(name, f"exit code {code}, {len(results)} of {want} results",
+                 code)
+        phases[name] = {"ok": True, "wall_s": wall}
+        return results
+
+    me = [sys.executable, os.path.join(REPO, "chip_smoke.py")]
+    (found,) = phase("devices", me + ["devices"], cap=180)
+    n = found["device"]["count"]
+
+    (train,) = phase("train", me + ["train"])
+    phases["train"].update(train)
+
+    if n >= 4:
+        spec = {"global_batch": train["global_batch"],
+                "losses": train["losses"][:3]}
+        (sharded,) = phase(
+            "train-sharded", me + ["train-sharded", json.dumps(spec)]
+        )
+        phases["train-sharded"].update(sharded)
+
+    # the host plane's native kernels are not tracked: build them here,
+    # on the CPU that will run them, from the tracked sources
+    build = subprocess.run(
+        ["sh", os.path.join(REPO, "native", "build.sh")],
+        capture_output=True, text=True,
+    )
+    if build.returncode != 0:
+        print(build.stdout + build.stderr, flush=True)
+        fail("launcher", f"native/build.sh exit code {build.returncode}")
+
+    workers = phase("launcher", _kfrun(n, n, "launcher-worker"), want=n)
+    workers.sort(key=lambda w: w["rank"])
+    phases["launcher"].update(workers[0], workers=n, local_devices=[
+        w["local_devices"] for w in workers
+    ])
+    native = all(w["native_kernels"] for w in workers)
+
+    if n >= 4:
+        worlds = phase("launcher-hier", _kfrun(2, n, "hier-worker"), want=2)
+        worlds.sort(key=lambda w: w["rank"])
+        phases["launcher-hier"].update(worlds[0], local_devices=[
+            w["local_devices"] for w in worlds
+        ])
+        native = native and all(w["native_kernels"] for w in worlds)
+
+    (kernels,) = phase("kernels", me + ["kernels"])
+    phases["kernels"].update(kernels)
+
+    for p in phases.values():
+        p.pop("device", None)
+    print(REPORT_TAG + json.dumps({
+        "versions": found["versions"],
+        "model": "TransformerConfig.bert_base(), uncut",
+        "per_chip_batch": PER_CHIP_BATCH,
+        "native_kernels": native,
+        "phases": phases,
+    }))
+    print(result_line(found["device"]), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    if len(sys.argv) == 1:
+        sys.exit(main())
+    # a child of the run above: one phase, one tagged result line
+    outcome = _child(sys.argv[1], sys.argv[2] if len(sys.argv) > 2 else "")
+    print(RESULT_TAG + json.dumps(outcome), flush=True)

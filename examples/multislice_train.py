@@ -6,11 +6,13 @@ cross-slice gradient average rides the DCN host plane from INSIDE the
 compiled step (parity: the reference's hierarchical NCCL+CPU allreduce,
 gpu/collective.cpp:108-162). Run it:
 
-  kfrun -np 2 -H 127.0.0.1:2 python3 examples/multislice_train.py
+  kfrun -np 2 -devices-per-host 4 python3 examples/multislice_train.py
 
-On real hardware each worker would see its own slice's chips; here each
-worker self-provisions a 4-device virtual CPU world so the full dp-within
-x dp-across composition runs anywhere.
+Each worker trains on the chips the launcher gave it. Without chips,
+`--devices 4` makes every worker a 4-device virtual CPU world, so the
+dp-within x dp-across composition runs anywhere:
+
+  kfrun -np 2 python3 examples/multislice_train.py --devices 4
 """
 
 import argparse
@@ -19,8 +21,8 @@ import argparse
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=10)
-    p.add_argument("--devices", type=int, default=4,
-                   help="virtual devices per worker (0 = real backend)")
+    p.add_argument("--devices", type=int, default=0,
+                   help="virtual CPU devices per worker (0 = real backend)")
     args = p.parse_args()
 
     import jax

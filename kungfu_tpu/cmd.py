@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import os
 import socket
+import sys
 from typing import Callable, List
 
 from kungfu_tpu.runner.monitored import send_heartbeat
@@ -39,12 +40,35 @@ def _run_worker(f: Callable[[int], None], rank: int, env: dict) -> None:
     finalize_default_peer()
 
 
+def _holds_chips() -> bool:
+    """Has this process started a JAX backend that owns accelerators?"""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized() and jax.default_backend() != "cpu"
+
+
 def launch_multiprocess(f: Callable[[int], None], np_: int) -> None:
     """Run ``f(rank)`` in ``np_`` local worker processes wired into one
     host-plane cluster (parity: launch_multiprocess). Inside ``f`` the
     normal API works: ``kungfu_tpu.api.current_rank()``, collectives,
-    optimizers. Raises RuntimeError if any worker exits nonzero."""
+    optimizers. Raises RuntimeError if any worker exits nonzero.
+
+    The workers get no device slots (kfrun's ``-devices-per-host`` does
+    that), so this refuses to start them from a process that already
+    holds the chips: a chip belongs to one process, and the children
+    would hang or fail opening it."""
     import multiprocessing as mp
+
+    if _holds_chips():
+        raise RuntimeError(
+            "launch_multiprocess: this process has started a JAX backend "
+            "that holds the accelerators, so its children could not open "
+            "them; launch before touching jax, or use kfrun "
+            "-devices-per-host"
+        )
 
     from kungfu_tpu.plan.peer import PeerID, PeerList
     from kungfu_tpu.runner import env as kfenv

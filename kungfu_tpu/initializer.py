@@ -9,7 +9,7 @@ TPU-native mapping:
   the broadcast — there is exactly one logical value.
 - Across processes (multi-host pod, or workers rejoining after an elastic
   resize), host-level values can diverge; `broadcast_variables` forces
-  process-0's values everywhere (XLA AllReduce under the hood via
+  rank 0's values everywhere (XLA AllReduce under the hood via
   multihost_utils), mirroring BroadcastGlobalVariablesOp.
 """
 
@@ -20,14 +20,26 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def broadcast_variables(tree, mesh: Mesh = None):
-    """Force every process to process-0's values, then replicate on-mesh.
+    """Force every process to rank 0's values, then replicate on-mesh.
 
     Single-process: pure replication (no communication).
+
+    The source is the worker whose kfrun rank is 0. `jax.process_index()`
+    is not the rank: on a TPU host libtpu numbers the processes by where
+    their chips sit (chip runs, PR 21: ranks 0..3 got process indices
+    1, 3, 2, 0 on one machine and 0, 2, 3, 1 on the next), so JAX's
+    default source would be whichever worker holds that chip — after a
+    resize, possibly a joiner.
     """
     if jax.process_count() > 1:
         from jax.experimental import multihost_utils
 
-        tree = multihost_utils.broadcast_one_to_all(tree)
+        from kungfu_tpu.peer import get_default_peer
+
+        peer = get_default_peer()
+        # a JAX world kfrun did not form has no ranks: JAX's default then
+        is_source = peer.rank == 0 if peer.size == jax.process_count() else None
+        tree = multihost_utils.broadcast_one_to_all(tree, is_source=is_source)
     if mesh is not None:
         tree = jax.device_put(tree, NamedSharding(mesh, P()))
     return tree

@@ -161,8 +161,19 @@ _knob("KF_ALLREDUCE_STRATEGY", "BINARY_TREE_STAR", _stripped,
       section=_SEC_CONTRACT, kind="str")
 _knob("KF_DEVICE_SLOTS", "", _csv,
       "Comma-separated accelerator chip ids this worker may open "
-      "(empty = unrestricted). Mirrored into `TPU_VISIBLE_DEVICES`.",
+      "(empty = unrestricted). Mirrored into libtpu's per-process "
+      "variables, which make the worker a device world of its own: "
+      "`TPU_VISIBLE_CHIPS`, `TPU_CHIPS_PER_PROCESS_BOUNDS`, "
+      "`TPU_PROCESS_BOUNDS=1,1,1`, `ALLOW_MULTIPLE_LIBTPU_LOAD=1`.",
       section=_SEC_CONTRACT, kind="csv")
+_knob("KF_DEVICE_WORLD", "", _str,
+      "JSON object of the libtpu variables that join this worker into "
+      "the one device world spanning all workers (`TPU_PROCESS_BOUNDS`, "
+      "`TPU_PROCESS_ADDRESSES`, `TPU_PROCESS_PORT`, `CLOUD_TPU_TASK_ID`); "
+      "`initialize_device_plane()` applies it before the backend starts. "
+      "Set beside `KF_DEVICE_SLOTS` when the workers hold the chips of "
+      "one host in rank order.",
+      section=_SEC_CONTRACT, kind="json")
 _knob("KF_SPAWN_TS", "", _str,
       "Unix timestamp the runner spawned this worker at; start() reports "
       "spawn→ready latency from it.",

@@ -235,8 +235,6 @@ def make_ring_transformer_loss(cfg: TransformerConfig, mesh,
     """Sequence-parallel causal-LM loss: batch = (tokens, targets), both
     (B, S) with B divisible by dp and S by sp. Returns loss_fn(params,
     batch) -> replicated scalar, jit/grad-compatible (shard_map inside)."""
-    from kungfu_tpu.parallel._compat import shard_map
-
     sp_size = mesh.shape[sp_axis]
 
     def shard_loss(params, batch):
@@ -245,7 +243,7 @@ def make_ring_transformer_loss(cfg: TransformerConfig, mesh,
         loss = lm_head_loss(params, x, targets, cfg)
         return jax.lax.pmean(jax.lax.pmean(loss, sp_axis), dp_axis)
 
-    return shard_map(
+    return jax.shard_map(
         shard_loss,
         mesh=mesh,
         in_specs=(P(), (P(dp_axis, sp_axis), P(dp_axis, sp_axis))),

@@ -11,12 +11,12 @@ Differentiable via custom_vjp: the forward kernel also emits the per-row
 log-sum-exp, and the backward runs two fused Pallas kernels (dq over
 k-blocks; dk/dv over q-blocks) that recompute exact block probabilities
 from it — the standard two-pass flash backward. Neither direction ever
-materializes an (S, S) tensor. Shapes the grid can't tile fall back to
-a q-chunk-rematerialized formulation (`_chunked_reference`) under
-jax.vjp — identical math, same memory bound.
+materializes an (S, S) tensor. A sequence length the blocks do not
+divide is an error, not a dense fallback.
 
-Off-TPU the kernel runs in interpret mode so the same code path is
-testable on the CPU meshes used by this repo's test suite.
+The kernels compile with Mosaic unless the caller passes
+`interpret=True` (the tests, on the CPU mesh); the backend is never
+consulted to choose.
 """
 
 from __future__ import annotations
@@ -39,71 +39,6 @@ def _dense_reference(q, k, v, causal: bool, sm_scale: float):
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
-
-
-def _chunked_reference(q, k, v, causal: bool, sm_scale: float,
-                       blk_q: int = 512, blk_k: int = 512):
-    """Differentiable online-softmax attention with bounded memory:
-    `lax.map` over Q-CHUNKS, each chunk wrapped in `jax.checkpoint`.
-
-    Per chunk, an inner k-block scan runs the flash recurrence; the
-    checkpoint boundary means the outer map's saved residuals are just
-    the chunk inputs (O(S x hd) total), and the inner scan's per-step
-    carries exist only transiently during that chunk's backward
-    (O(S/blk_k x blk_q x hd)). Scanning k-blocks at FULL q (the naive
-    layout) would be wrong: scan's VJP saves the (S, hd) acc carry per
-    k-step — Theta(S^2 hd / blk_k), a quadratic bill hidden in
-    residuals. The flash backward runs through jax.vjp of this."""
-    B, H, S, hd = q.shape
-    blk_q = min(blk_q, S)
-    blk_k = min(blk_k, S)
-    if S % blk_q or S % blk_k:
-        return _dense_reference(q, k, v, causal, sm_scale)
-    n_qb, n_kb = S // blk_q, S // blk_k
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    kb_ = kf.reshape(B, H, n_kb, blk_k, hd).transpose(2, 0, 1, 3, 4)
-    vb_ = vf.reshape(B, H, n_kb, blk_k, hd).transpose(2, 0, 1, 3, 4)
-
-    @jax.checkpoint
-    def one_chunk(args):
-        qc, q_off = args  # (B, H, blk_q, hd), scalar block offset
-        qcf = qc.astype(jnp.float32)
-        qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-
-        def body(carry, inp):
-            m, l, acc = carry
-            kb, vb, kb_idx = inp
-            s = jnp.einsum("bhqd,bhkd->bhqk", qcf, kb) * sm_scale
-            if causal:
-                kpos = kb_idx * blk_k + lax.broadcasted_iota(
-                    jnp.int32, (blk_q, blk_k), 1
-                )
-                mask = kpos <= qpos
-                s = jnp.where(mask, s, NEG_INF)
-                maskf = mask.astype(jnp.float32)
-            else:
-                maskf = 1.0
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new) * maskf
-            corr = jnp.exp(m - m_new)
-            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            acc = acc * corr + jnp.einsum("bhqk,bhkd->bhqd", p, vb)
-            return (m_new, l, acc), None
-
-        m0 = jnp.full((B, H, blk_q, 1), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, H, blk_q, 1), jnp.float32)
-        acc0 = jnp.zeros((B, H, blk_q, hd), jnp.float32)
-        (_, l, acc), _ = lax.scan(
-            body, (m0, l0, acc0), (kb_, vb_, jnp.arange(n_kb))
-        )
-        return acc / l
-
-    q_chunks = q.reshape(B, H, n_qb, blk_q, hd).transpose(2, 0, 1, 3, 4)
-    offsets = jnp.arange(n_qb) * blk_q
-    out = lax.map(one_chunk, (q_chunks, offsets))  # (n_qb, B, H, blk_q, hd)
-    out = out.transpose(1, 2, 0, 3, 4).reshape(B, H, S, hd)
-    return out.astype(q.dtype)
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
@@ -167,6 +102,18 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         )
 
 
+def _blocks(S: int, blk_q: int, blk_k: int):
+    """Clamp the block sizes to S; raise when they do not tile it."""
+    blk_q = min(blk_q, S)
+    blk_k = min(blk_k, S)
+    if S % blk_q or S % blk_k:
+        raise ValueError(
+            f"flash_attention: sequence length {S} is not a multiple of "
+            f"the blocks ({blk_q}, {blk_k})"
+        )
+    return blk_q, blk_k
+
+
 def _kv_index(blk_q, blk_k, causal, b, i, j):
     if not causal:
         return (b, j, 0)
@@ -180,14 +127,7 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, hd = q.shape
-    blk_q = min(blk_q, S)
-    blk_k = min(blk_k, S)
-    if S % blk_q or S % blk_k:
-        # degenerate shapes: correctness beats fusion
-        out = _dense_reference(q, k, v, causal, sm_scale)
-        return (out, None) if with_lse else out
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    blk_q, blk_k = _blocks(S, blk_q, blk_k)
     qf = q.reshape(B * H, S, hd)
     kf = k.reshape(B * H, S, hd)
     vf = v.reshape(B * H, S, hd)
@@ -343,8 +283,6 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, hd = q.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     # delta = rowsum(dO * O): one fused elementwise+reduce pass, XLA's
     # job; 8-lane-replicated to match the LSE layout (see _finalize)
     delta = jnp.sum(
@@ -406,7 +344,8 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
-                    blk_q: int = 512, blk_k: int = 512, interpret=None):
+                    blk_q: int = 512, blk_k: int = 512,
+                    interpret: bool = False):
     """Fused causal attention for (B, H, S, hd) q/k/v; drop-in for the
     transformer's pluggable attention core:
 
@@ -414,11 +353,10 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
 
     Forward AND backward are Pallas kernels (two-pass flash backward:
     dq streams k-blocks, dk/dv stream q-blocks, block probs recomputed
-    from the forward's saved row log-sum-exp). Measured fwd+bwd on a
-    v5e chip (bf16, B=2 H=8 hd=64, defaults — BENCH_FLASH_r05.json):
-    1.25x XLA dense at S=1024, ~parity at 2048, 1.3x at 4096, 2.1x at
-    8192; at 16384 dense OOMs on the (S, S) score tensor while this
-    kernel's working set stays O(BLK x S).
+    from the forward's saved row log-sum-exp). S must be a multiple of
+    both block sizes (each clamped to S). Speed against XLA dense: round
+    5, earlier stack, record removed in PR 21; not measured on the
+    current one.
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -428,11 +366,6 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
 def _fwd(q, k, v, causal, sm_scale, blk_q, blk_k, interpret):
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    S = q.shape[2]
-    if S % min(blk_q, S) or S % min(blk_k, S):
-        # degenerate shapes: dense forward, remat-chunked vjp backward
-        out = _forward(q, k, v, causal, sm_scale, blk_q, blk_k, interpret)
-        return out, (q, k, v, None, None)
     out, lse = _forward(
         q, k, v, causal, sm_scale, blk_q, blk_k, interpret, with_lse=True
     )
@@ -443,19 +376,10 @@ def _bwd(causal, sm_scale, blk_q, blk_k, interpret, res, g):
     q, k, v, o, lse = res
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    if lse is None:
-        # fallback (shapes the kernel grid can't tile): vjp through the
-        # remat-chunked formulation — identical math, no (S, S) tensor
-        _, vjp = jax.vjp(
-            lambda q, k, v: _chunked_reference(q, k, v, causal, sm_scale, blk_k),
-            q, k, v,
-        )
-        return vjp(g)
     # fused two-pass flash backward kernels (dq, then dk/dv)
-    S = q.shape[2]
+    blk_q, blk_k = _blocks(q.shape[2], blk_q, blk_k)
     return _backward_kernels(
-        q, k, v, o, lse, g, causal, sm_scale,
-        min(blk_q, S), min(blk_k, S), interpret,
+        q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k, interpret
     )
 
 

@@ -160,8 +160,6 @@ def make_hier_train_step(
     mesh (ICI), then averaged across worlds on the host plane, then the
     optax update applies identically in every world.
     """
-    from kungfu_tpu.parallel._compat import shard_map
-
     reducer = CrossSliceReducer(peer=peer, name=name, compress=compress)
     bspec = batch_spec if batch_spec is not None else P(axis_name)
 
@@ -170,7 +168,7 @@ def make_hier_train_step(
         grads = jax.tree.map(lambda g: lax.pmean(g, axis_name), grads)
         return lax.pmean(loss, axis_name), grads
 
-    sharded_grads = shard_map(
+    sharded_grads = jax.shard_map(
         local_grads,
         mesh=mesh,
         in_specs=(P(), bspec),

@@ -22,6 +22,7 @@ Elastic semantics:
 
 from __future__ import annotations
 
+import os
 import socket
 import threading
 from typing import Optional
@@ -52,9 +53,15 @@ def initialize_device_plane(platform: Optional[str] = None) -> None:
     Must run before any other JAX API touches the backend (jax.devices()
     etc.) — the same constraint the reference's NCCL init has. Single
     process (no kfrun): no-op, local devices only.
+
+    Workers that kfrun pinned to chips of one host (`-devices-per-host`)
+    are each a device world of their own until they call this: it applies
+    the libtpu variables the runner derived for the joined world
+    (`KF_DEVICE_WORLD`, runner/env.py) before the backend starts.
     """
     import jax
 
+    from kungfu_tpu.parallel.chip import enable_compile_cache
     from kungfu_tpu.peer import get_default_peer
 
     with _lock:
@@ -63,12 +70,22 @@ def initialize_device_plane(platform: Optional[str] = None) -> None:
         peer = get_default_peer()
         if platform:
             jax.config.update("jax_platforms", platform)
+        enable_compile_cache()
         sess = peer.current_session()
         if peer.config.single_process or sess.size == 1:
             _state["local_only"] = True
             _state["initialized"] = True
             log.debug("device plane: single-process, local devices only")
             return
+        if peer.config.device_slots:
+            if not peer.config.device_world:
+                raise RuntimeError(
+                    "device plane: this worker is pinned to chips "
+                    f"{peer.config.device_slots} but kfrun described no joined "
+                    "world for the layout (workers must be on one host and "
+                    "hold its chips in rank order; see runner/env.py)"
+                )
+            os.environ.update(peer.config.device_world)
         if sess.rank == 0:
             host = peer.self_id.host
             addr = f"{host}:{_free_port(host)}".encode()
@@ -102,8 +119,8 @@ def shutdown_device_plane() -> None:
             jax.distributed.shutdown()
         # Drop live backends + compiled programs so the next JAX call (after
         # re-initialize) builds a client for the NEW process set. JAX has no
-        # public backend-reset API; feature-detect the internal one and fail
-        # with guidance (use reload mode) if a future JAX moves it.
+        # public backend-reset API, so this leans on a private one and
+        # fails loudly, pointing at reload mode, where it is missing.
         try:
             from jax._src import xla_bridge
 

@@ -19,7 +19,6 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import optax
-from kungfu_tpu.parallel._compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -47,7 +46,7 @@ def make_train_step(
         loss = jax.lax.pmean(loss, axis_name)
         return params, opt_state, loss
 
-    spmd = shard_map(
+    spmd = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(), P(), batch_spec),

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from kungfu_tpu.parallel._compat import shard_map
 
 
 def make_mesh(shape: Optional[Dict[str, int]] = None, *, devices=None) -> Mesh:
@@ -94,8 +93,8 @@ class DeviceSession:
     def spmd(self, fn, in_specs, out_specs, check_vma: bool = False):
         """shard_map+jit over this mesh (one compiled SPMD program)."""
         return jax.jit(
-            shard_map(fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
+            jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=check_vma)
         )
 
     @functools.cached_property

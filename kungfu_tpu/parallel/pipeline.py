@@ -105,8 +105,6 @@ def make_pp_transformer_loss(cfg, mesh, n_micro: int, pp_axis: str = "pp",
             loss = lax.pmean(loss, dp_axis)
         return loss
 
-    from kungfu_tpu.parallel._compat import shard_map
-
     batch_spec = P(dp_axis) if dp_axis is not None else P()
     param_specs = {
         "embed": P(),
@@ -118,7 +116,7 @@ def make_pp_transformer_loss(cfg, mesh, n_micro: int, pp_axis: str = "pp",
             "w_in": 0, "w_out": 0,
         }),
     }
-    return shard_map(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(param_specs, (batch_spec, batch_spec)),

@@ -36,8 +36,9 @@ def make_sharded_train_step(
 ):
     """Build a jitted SPMD train step with sharded params.
 
-    loss_fn(params, batch) -> scalar. Optimizer state inherits the param
-    shardings leaf-wise where shapes match (optax state mirrors params).
+    loss_fn(params, batch) -> scalar. Optimizer state keeps the shardings
+    it arrives with; build it with `init_opt_state` so that what the step
+    returns is placed like what it took.
     Returns step(params, opt_state, batch) -> (params, opt_state, loss).
     """
 
@@ -58,3 +59,20 @@ def make_sharded_train_step(
 
 def shard_params(params, mesh: Mesh, param_specs):
     return jax.device_put(params, named(mesh, param_specs))
+
+
+def init_opt_state(optimizer: optax.GradientTransformation, params, mesh: Mesh):
+    """optimizer.init(params), placed for make_sharded_train_step.
+
+    Parameter-shaped leaves (optax's moments) take their parameter's
+    sharding from `zeros_like`. Leaves that depend on no parameter — the
+    step count — come out on one device; the step would return them
+    replicated over the mesh, and the changed input sharding would
+    compile the whole step a second time. They are replicated here.
+    """
+    replicated = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda x: x if isinstance(x.sharding, NamedSharding)
+        else jax.device_put(x, replicated),
+        optimizer.init(params),
+    )

@@ -8,6 +8,13 @@ runner/flags.go:30-145:
   kfrun -w -config-server URL ...           # elastic watch mode
   kfrun -np 4 -auto-recover 10s ...         # failure auto-recovery
   kfrun -builtin-config-port 9100 ...       # embedded config server
+  kfrun -np 4 -devices-per-host 4 ...       # one chip per worker (runner/env.py
+                                            # derives each worker's libtpu env)
+
+The runner package imports no jax, here or in any module it loads: a
+chip belongs to one process at a time, and that process must be a
+worker. A runner that touched the backend would hold the chips its
+workers were started to open.
 """
 
 from __future__ import annotations
@@ -69,7 +76,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "CPU slice (parity: KUNGFU_USE_AFFINITY)")
     p.add_argument("-devices-per-host", type=int, default=0,
                    help="partition this many chip ids among local workers "
-                        "(TPU_VISIBLE_DEVICES pinning; 0 = no pinning)")
+                        "(each opens only its own through libtpu's "
+                        "per-process variables; 0 = no pinning)")
     p.add_argument("-debug-port", type=int, default=-1,
                    help="HTTP endpoint: Stage dumps + /cluster/{metrics,"
                         "trace,health,links} telemetry (0 = ephemeral)")
@@ -145,6 +153,11 @@ def main(argv: Optional[List[str]] = None) -> int:
         args.host_capacity = next(
             (h.slots for h in hosts if h.host == self_host), 1
         )
+        if args.devices_per_host not in (0,) + kfenv.HOST_CHIPS:
+            raise ValueError(
+                f"-devices-per-host {args.devices_per_host}: the libtpu "
+                f"topology is known only for hosts of {kfenv.HOST_CHIPS} chips"
+            )
         if 0 < args.devices_per_host < args.host_capacity:
             # at full capacity every local worker needs >= 1 chip, or a
             # later elastic grow would exhaust the watcher's slot pool
@@ -220,6 +233,8 @@ def make_one_worker_proc(
         elastic_mode=args.elastic_mode,
         init_progress=progress,
         device_slots=device_slots,
+        host_devices=args.devices_per_host,
+        port_range=parse_port_range(args.port_range),
     )
     env["KF_LOG_PREFIX"] = f"{rank}/{len(cluster.workers)}"
     env["KF_SPAWN_TS"] = str(time.time())

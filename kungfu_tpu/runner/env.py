@@ -8,8 +8,9 @@ started without them becomes a single-process cluster of itself.
 from __future__ import annotations
 
 import dataclasses
+import json
 import os
-from typing import Optional
+from typing import Optional, Sequence, Tuple
 
 from kungfu_tpu.base.strategy import DEFAULT_STRATEGY, Strategy
 from kungfu_tpu.plan.peer import PeerID, PeerList
@@ -24,6 +25,7 @@ CONFIG_SERVER = "KF_CONFIG_SERVER"
 ELASTIC_MODE = "KF_ELASTIC_MODE"
 INIT_PROGRESS = "KF_INIT_PROGRESS"
 DEVICE_SLOTS = "KF_DEVICE_SLOTS"
+DEVICE_WORLD = "KF_DEVICE_WORLD"
 # tuning (parity: config/config.go:24-67)
 ENABLE_MONITORING = "KF_CONFIG_ENABLE_MONITORING"
 ENABLE_STALL_DETECTION = "KF_CONFIG_ENABLE_STALL_DETECTION"
@@ -32,8 +34,22 @@ LOG_LEVEL = "KF_CONFIG_LOG_LEVEL"
 ALL_ENV_NAMES = [
     SELF_SPEC, INIT_PEERS, INIT_RUNNERS, PARENT_ID, INIT_CLUSTER_VERSION,
     ALLREDUCE_STRATEGY, CONFIG_SERVER, ELASTIC_MODE, INIT_PROGRESS,
-    DEVICE_SLOTS, ENABLE_MONITORING, ENABLE_STALL_DETECTION, LOG_LEVEL,
+    DEVICE_SLOTS, DEVICE_WORLD, ENABLE_MONITORING, ENABLE_STALL_DETECTION,
+    LOG_LEVEL,
 ]
+
+# libtpu's per-process topology on one host, as the chip accepted it in
+# PR 21 (libtpu 0.0.34, TPU v5 lite; the values jax's own multi-process
+# TPU test harness uses). A worker holding c chips sees them as the grid
+# _CHIP_BOUNDS[c]; k such workers, worker i holding chips [i*c, (i+1)*c)
+# of a 4-chip host, join into the process grid _PROCESS_BOUNDS[c, k]
+# (chips 0,1 and chips 2,3 are the two columns of the 2x2). libtpu places
+# each process by where its chips sit, not by its task id, so
+# jax.process_index() is not the rank. Host sizes and layouts not listed
+# here have not met the chip and are refused rather than guessed.
+HOST_CHIPS = (1, 4)
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+_PROCESS_BOUNDS = {(1, 4): "2,2,1", (2, 2): "2,1,1", (1, 2): "1,2,1"}
 
 
 @dataclasses.dataclass
@@ -51,6 +67,10 @@ class WorkerConfig:
     # chip ids this worker may open (empty = unrestricted); parity:
     # job/gpu_resource.go slot assignment via CUDA_VISIBLE_DEVICES
     device_slots: tuple = ()
+    # libtpu variables that place this worker in the ONE device world
+    # spanning all workers (empty = the runner described none);
+    # initialize_device_plane() applies them before the backend starts
+    device_world: dict = dataclasses.field(default_factory=dict)
 
 
 def parse_config_from_env(environ=None) -> WorkerConfig:
@@ -83,7 +103,72 @@ def parse_config_from_env(environ=None) -> WorkerConfig:
         elastic_mode=env.get(ELASTIC_MODE, ""),
         init_progress=int(env.get(INIT_PROGRESS, "0") or 0),
         device_slots=tuple(int(s) for s in slots_raw.split(",") if s.strip()),
+        device_world=json.loads(env.get(DEVICE_WORLD) or "{}"),
     )
+
+
+def tpu_process_env(
+    self_id: PeerID,
+    peers: PeerList,
+    device_slots: Sequence[int],
+    host_devices: int,
+    port_range: Optional[Tuple[int, int]] = None,
+) -> dict:
+    """The libtpu variables for one worker holding `device_slots` of a
+    host with `host_devices` chips.
+
+    By default every worker is its own device world: it opens only its
+    chips (`TPU_VISIBLE_CHIPS`), sees them as a grid of its own
+    (`TPU_CHIPS_PER_PROCESS_BOUNDS`) and waits for nobody
+    (`TPU_PROCESS_BOUNDS=1,1,1`). When the workers are all on this host
+    and hold its chips in rank order, `KF_DEVICE_WORLD` also carries the
+    variables that join them into one world — the process grid, every
+    worker's libtpu port (the worker's own port mirrored to the top of
+    `port_range`) and this worker's position — for
+    `initialize_device_plane()` to apply; the choice between the two
+    worlds is the worker's, made before its backend starts.
+    """
+    n = len(device_slots)
+    if host_devices not in HOST_CHIPS or n not in _CHIP_BOUNDS:
+        raise ValueError(
+            f"no libtpu topology known for {n} chips of a {host_devices}-chip "
+            f"host (hosts: {HOST_CHIPS}, chips per worker: "
+            f"{tuple(_CHIP_BOUNDS)})"
+        )
+    env = {
+        "TPU_VISIBLE_CHIPS": ",".join(str(i) for i in device_slots),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[n],
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        # several processes of one host each load libtpu
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+    local = [p for p in peers if p.host == self_id.host]
+    i = local.index(self_id)
+    joinable = (
+        port_range is not None
+        and len(local) == len(peers)
+        and (n, len(local)) in _PROCESS_BOUNDS
+        and list(device_slots) == list(range(i * n, (i + 1) * n))
+    )
+    if joinable:
+        lo, hi = port_range
+        ports = [hi - (p.port - lo) for p in local]
+        if not all(lo <= q <= hi for q in ports) or (
+            set(ports) & {p.port for p in local}
+        ):
+            raise ValueError(
+                f"port range {lo}-{hi} leaves no room for the libtpu ports "
+                f"of {len(local)} workers"
+            )
+        env[DEVICE_WORLD] = json.dumps({
+            "TPU_PROCESS_BOUNDS": _PROCESS_BOUNDS[n, len(local)],
+            "TPU_PROCESS_ADDRESSES": ",".join(
+                f"{p.host}:{port}" for p, port in zip(local, ports)
+            ),
+            "TPU_PROCESS_PORT": str(ports[i]),
+            "CLOUD_TPU_TASK_ID": str(i),
+        })
+    return env
 
 
 def worker_env(
@@ -97,6 +182,8 @@ def worker_env(
     elastic_mode: str = "",
     init_progress: int = 0,
     device_slots=None,
+    host_devices: int = 0,
+    port_range: Optional[Tuple[int, int]] = None,
 ) -> dict:
     """Env block a runner sets for a spawned worker (parity: job.go:35-80)."""
     env = {
@@ -113,9 +200,9 @@ def worker_env(
     if elastic_mode:
         env[ELASTIC_MODE] = elastic_mode
     if device_slots:
-        ids = ",".join(str(i) for i in device_slots)
-        env[DEVICE_SLOTS] = ids
-        # the TPU analog of CUDA_VISIBLE_DEVICES (job.go:35-80): libtpu
-        # initializes only these chips in each worker process
-        env["TPU_VISIBLE_DEVICES"] = ids
+        env[DEVICE_SLOTS] = ",".join(str(i) for i in device_slots)
+        # the TPU analog of CUDA_VISIBLE_DEVICES (job.go:35-80)
+        env.update(tpu_process_env(
+            self_id, peers, device_slots, host_devices, port_range
+        ))
     return env

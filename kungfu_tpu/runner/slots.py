@@ -8,8 +8,9 @@ TPU mapping: the runner partitions the host's chip ids among its local
 workers and exports per-process visibility env:
 - ``KF_DEVICE_SLOTS``  — the framework's own contract (comma-separated ids),
   readable via WorkerConfig.device_slots;
-- ``TPU_VISIBLE_DEVICES`` — consumed by libtpu so each process initializes
-  only its chips (the TPU analog of CUDA_VISIBLE_DEVICES).
+- libtpu's per-process variables (``TPU_VISIBLE_CHIPS`` and the topology
+  that goes with it), derived from the slots in ``runner/env.py`` so each
+  process opens only its chips (the TPU analog of CUDA_VISIBLE_DEVICES).
 The elastic watcher draws/returns slots from one pool across resizes, so a
 joiner never doubles up on a surviving worker's chips.
 """

@@ -311,15 +311,10 @@ class Watcher:
         _t_spawn0 = time.monotonic()
         slots = None
         if self.slot_pool is not None:
-            try:
-                slots = self.slot_pool.get(self.chips_per_worker)
-                self._worker_slots[w] = slots
-            except RuntimeError as e:
-                # a growing host exceeding its chip budget must not crash
-                # the runner mid-resize: spawn unpinned and say so (the
-                # upfront cli check makes this unreachable for valid plans)
-                log.warn("kfrun: %s; spawning %s unpinned", e, w)
-                slots = None
+            # a short pool raises and takes the run down with it: an
+            # unpinned worker would try to open chips other workers hold
+            slots = self.slot_pool.get(self.chips_per_worker)
+            self._worker_slots[w] = slots
         p = make_one_worker_proc(
             self.args, self.cmd, stage.cluster, w, self.self_host, self.strategy,
             self.config_server_url, version=stage.version, progress=stage.progress,

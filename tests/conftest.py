@@ -1,4 +1,4 @@
-"""Test config: force an 8-device virtual CPU mesh before the backend starts.
+"""Test config: an 8-device virtual CPU mesh, set before the backend starts.
 
 Mirrors the reference's multi-process-on-localhost test strategy
 (SURVEY.md §4): we get multi-chip semantics on one machine via XLA's
@@ -11,15 +11,14 @@ are too late; jax.config.update works until the backend is initialized.
 
 import os
 
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-).strip()
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # older jax: XLA_FLAGS above already forces the 8-device host platform
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
+
+# Tests compile for the CPU and must leave no compile cache in the
+# checkout: enable_compile_cache() places the directory, this switches the
+# cache itself off — here, and through the environment in every agent the
+# tests spawn.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+jax.config.update("jax_enable_compilation_cache", False)

@@ -39,7 +39,7 @@ def main() -> int:
     mesh = make_mesh({"dp": n_dev})
 
     # cross-process psum sanity: every device contributes its global index+1
-    from kungfu_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     f = jax.jit(
         shard_map(

@@ -30,7 +30,7 @@ def device_psum_check() -> None:
     n_dev = jax.device_count()
     assert jax.process_count() == size, (jax.process_count(), size)
     mesh = make_mesh({"dp": n_dev})
-    from kungfu_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     f = jax.jit(
         shard_map(lambda x: jax.lax.psum(x, "dp"), mesh=mesh,

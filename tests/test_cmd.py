@@ -65,3 +65,17 @@ def test_monitor_signal_helpers_no_monitor():
     cmd.monitor_batch_end(0)
     cmd.monitor_epoch_end(0)
     cmd.monitor_train_end(0)
+
+
+def test_launch_multiprocess_refuses_when_parent_holds_chips(monkeypatch):
+    """The children get no device slots: started from a process whose JAX
+    backend already owns the accelerators they would hang opening them."""
+    import pytest
+
+    from kungfu_tpu import cmd
+
+    monkeypatch.setattr(cmd, "_holds_chips", lambda: True)
+    with pytest.raises(RuntimeError, match="holds the accelerators"):
+        cmd.launch_multiprocess(lambda rank: None, 2)
+    monkeypatch.undo()
+    assert cmd._holds_chips() is False  # the CPU backend holds nothing

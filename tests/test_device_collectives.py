@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from kungfu_tpu.parallel._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from kungfu_tpu.base.ops import ReduceOp

@@ -5,6 +5,7 @@ Parity: srcs/go/kungfu/job/gpu_resource.go + job.go CUDA_VISIBLE_DEVICES —
 N workers on one host must each see a disjoint device set.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -41,19 +42,145 @@ class TestSlotPool:
         assert partition(4, 4) == [[0], [1], [2], [3]]
 
 
+def _host_envs(n_workers, host_devices=4, port_range=(38000, 38999)):
+    """worker_env of every worker of one fully used host, in rank order."""
+    from kungfu_tpu.plan.peer import PeerID, PeerList
+    from kungfu_tpu.runner import env as kfenv
+
+    peers = PeerList(
+        [PeerID("127.0.0.1", port_range[0] + i) for i in range(n_workers)]
+    )
+    return [
+        kfenv.worker_env(
+            self_id=p, peers=peers, runners=PeerList(),
+            parent=PeerID("127.0.0.1", 38080), device_slots=slots,
+            host_devices=host_devices, port_range=port_range,
+        )
+        for p, slots in zip(peers, partition(host_devices, n_workers))
+    ]
+
+
 def test_worker_env_carries_slots():
+    from kungfu_tpu.runner import env as kfenv
+
+    env = _host_envs(2)[1]
+    assert env[kfenv.DEVICE_SLOTS] == "2,3"
+    assert env["TPU_VISIBLE_CHIPS"] == "2,3"
+    cfg = kfenv.parse_config_from_env(env)
+    assert cfg.device_slots == (2, 3)
+    assert cfg.device_world == json.loads(env[kfenv.DEVICE_WORLD])
+
+
+@pytest.mark.parametrize("n_workers,chip_bounds,world_bounds", [
+    (4, "1,1,1", "2,2,1"),
+    (2, "1,2,1", "2,1,1"),
+    (1, "2,2,1", None),  # one worker holds the host: nothing to join
+])
+def test_worker_env_tpu_topology_of_a_four_chip_host(
+        n_workers, chip_bounds, world_bounds):
+    """Each worker is by default a device world of its own chips, and
+    carries beside it the variables that join all workers into one."""
+    from kungfu_tpu.runner import env as kfenv
+
+    envs = _host_envs(n_workers)
+    # own world: disjoint chips that cover the host, same shape everywhere
+    chips = [e["TPU_VISIBLE_CHIPS"].split(",") for e in envs]
+    assert sorted(c for cs in chips for c in cs) == ["0", "1", "2", "3"]
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == chip_bounds
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["ALLOW_MULTIPLE_LIBTPU_LOAD"] == "1"
+        assert e[kfenv.DEVICE_SLOTS] == e["TPU_VISIBLE_CHIPS"]
+        # nothing of the joined world leaks into libtpu's own names
+        assert not {"TPU_PROCESS_ADDRESSES", "TPU_PROCESS_PORT",
+                    "CLOUD_TPU_TASK_ID"} & set(e)
+    if world_bounds is None:
+        assert all(kfenv.DEVICE_WORLD not in e for e in envs)
+        return
+    worlds = [json.loads(e[kfenv.DEVICE_WORLD]) for e in envs]
+    # joined world: one grid and one address list for all, a port and a
+    # position of its own for each
+    assert {w["TPU_PROCESS_BOUNDS"] for w in worlds} == {world_bounds}
+    (addresses,) = {w["TPU_PROCESS_ADDRESSES"] for w in worlds}
+    addresses = addresses.split(",")
+    assert len(set(addresses)) == n_workers
+    for i, w in enumerate(worlds):
+        assert w["CLOUD_TPU_TASK_ID"] == str(i)
+        assert addresses[i] == f"127.0.0.1:{w['TPU_PROCESS_PORT']}"
+    # libtpu's ports come from the top of the range, the workers' own
+    # from the bottom
+    ports = {int(w["TPU_PROCESS_PORT"]) for w in worlds}
+    assert ports == {38999 - i for i in range(n_workers)}
+
+
+def test_worker_env_refuses_layouts_the_chip_has_not_met():
     from kungfu_tpu.plan.peer import PeerID, PeerList
     from kungfu_tpu.runner import env as kfenv
 
     me = PeerID("127.0.0.1", 38000)
-    env = kfenv.worker_env(
-        self_id=me, peers=PeerList([me]), runners=PeerList(),
-        parent=PeerID("127.0.0.1", 38080), device_slots=[2, 3],
+    kw = dict(self_id=me, peers=PeerList([me]), runners=PeerList(),
+              parent=None)
+    with pytest.raises(ValueError, match="no libtpu topology"):
+        kfenv.worker_env(device_slots=[0, 1], host_devices=8, **kw)
+    with pytest.raises(ValueError, match="no libtpu topology"):
+        kfenv.worker_env(device_slots=[0, 1, 2], host_devices=4, **kw)
+    # a port range the mirrored libtpu ports would collide in
+    with pytest.raises(ValueError, match="no room"):
+        _host_envs(4, port_range=(38000, 38005))
+
+
+def test_partly_used_host_joins_its_first_column():
+    """Two one-chip workers on chips 0,1 of a four-chip host (the state
+    after a shrink) join as a 1x2 grid; three workers are no rectangle
+    and get no joined world, so initialize_device_plane() would refuse."""
+    from kungfu_tpu.plan.peer import PeerID, PeerList
+    from kungfu_tpu.runner import env as kfenv
+
+    def envs(k):
+        peers = PeerList([PeerID("127.0.0.1", 38000 + i) for i in range(k)])
+        return [
+            kfenv.worker_env(
+                self_id=p, peers=peers, runners=PeerList(), parent=None,
+                device_slots=[i], host_devices=4, port_range=(38000, 38999),
+            )
+            for i, p in enumerate(peers)
+        ]
+
+    for i, env in enumerate(envs(2)):
+        assert env["TPU_VISIBLE_CHIPS"] == str(i)
+        world = json.loads(env[kfenv.DEVICE_WORLD])
+        assert world["TPU_PROCESS_BOUNDS"] == "1,2,1"
+        assert world["CLOUD_TPU_TASK_ID"] == str(i)
+    assert all(kfenv.DEVICE_WORLD not in env for env in envs(3))
+
+
+def test_standby_activation_carries_the_tpu_env(tmp_path):
+    """A warm standby imports jax BEFORE it learns its identity; the
+    activation spec must deliver the per-process TPU variables into its
+    environment before the worker command runs."""
+    from kungfu_tpu.runner import env as kfenv
+    from kungfu_tpu.runner.standby import run_activated
+
+    env = _host_envs(4)[2]
+    out = tmp_path / "env.json"
+    probe = (
+        "import json, os; json.dump({k: v for k, v in os.environ.items() "
+        f"if k.startswith(('TPU_', 'KF_DEVICE', 'ALLOW_'))}}, open({str(out)!r}, 'w'))"
     )
-    assert env[kfenv.DEVICE_SLOTS] == "2,3"
-    assert env["TPU_VISIBLE_DEVICES"] == "2,3"
-    cfg = kfenv.parse_config_from_env(env)
-    assert cfg.device_slots == (2, 3)
+    code = (
+        "import sys; sys.path.insert(0, sys.argv[1]); import json; "
+        "from kungfu_tpu.runner.standby import run_activated; "
+        "run_activated(json.loads(sys.argv[2]))"
+    )
+    spec = {"env": env, "argv": [sys.executable, "-c", probe]}
+    clean = {k: v for k, v in os.environ.items()
+             if not k.startswith(("TPU_", "KF_"))}
+    subprocess.run([sys.executable, "-c", code, REPO, json.dumps(spec)],
+                   env=clean, check=True, timeout=60)
+    seen = json.loads(out.read_text())
+    want = {k: v for k, v in env.items()
+            if k.startswith(("TPU_", "KF_DEVICE", "ALLOW_"))}
+    assert seen == want and kfenv.DEVICE_WORLD in seen
 
 
 def test_kfrun_pins_disjoint_devices():
@@ -65,7 +192,7 @@ def test_kfrun_pins_disjoint_devices():
         "from kungfu_tpu.peer import get_default_peer\n"
         "slots = get_default_peer().config.device_slots\n"
         "assert len(slots) == 2, slots\n"
-        "assert os.environ['TPU_VISIBLE_DEVICES'] == ','.join(map(str, slots))\n"
+        "assert os.environ['TPU_VISIBLE_CHIPS'] == ','.join(map(str, slots))\n"
         "import numpy as np\n"
         "from kungfu_tpu.base.ops import ReduceOp\n"
         "from kungfu_tpu.base.workspace import Workspace\n"
@@ -94,7 +221,7 @@ class TestWatcherReallocation:
     """apply_delta must draw joiner slots from the pool and return leavers'
     slots, never overlapping live workers (parity: watcher + GPU pool)."""
 
-    def _watcher(self, n_dev=8, cap=4):
+    def _watcher(self, n_dev=4, cap=4):
         import argparse
 
         from kungfu_tpu.runner.watch import Stage, Watcher
@@ -106,6 +233,7 @@ class TestWatcherReallocation:
         args = argparse.Namespace(
             runner_port=38080, elastic_mode="", logdir="", quiet=True,
             devices_per_host=n_dev, host_capacity=cap, debug_port=-1,
+            port_range="38000-38999",
         )
         w = Watcher(args, [sys.executable, "-c", "import time; time.sleep(30)"],
                     "127.0.0.1", Strategy.STAR, "")
@@ -121,23 +249,21 @@ class TestWatcherReallocation:
         return w, stage
 
     def test_grow_and_shrink_keep_slots_disjoint(self):
-        w, stage = self._watcher(n_dev=8, cap=4)
+        w, stage = self._watcher(n_dev=4, cap=2)
         try:
-            w.apply_delta(stage(0, 2))
+            w.apply_delta(stage(0, 1))
             slots_v0 = dict(w._worker_slots)
-            assert all(len(s) == 2 for s in slots_v0.values())
-            flat = sorted(i for s in slots_v0.values() for i in s)
-            assert flat == [0, 1, 2, 3]
+            assert list(slots_v0.values()) == [[0, 1]]
 
-            w.apply_delta(stage(1, 4))  # grow: joiners draw fresh ids
+            w.apply_delta(stage(1, 2))  # grow: the joiner draws fresh ids
             all_slots = [i for s in w._worker_slots.values() for i in s]
-            assert sorted(all_slots) == list(range(8))  # disjoint, full
-            # survivors kept their original stripes
+            assert sorted(all_slots) == list(range(4))  # disjoint, full
+            # the survivor kept its original stripe
             for worker, s in slots_v0.items():
                 assert w._worker_slots[worker] == s
 
-            w.apply_delta(stage(2, 1))  # shrink: leavers' ids return
-            assert w.slot_pool.available == 6
+            w.apply_delta(stage(2, 1))  # shrink: the leaver's ids return
+            assert w.slot_pool.available == 2
             (only,) = w._worker_slots.values()
             assert len(only) == 2
         finally:
@@ -152,6 +278,20 @@ class TestWatcherReallocation:
             w.apply_delta(stage(0, 2))
             envs = [p.env["KF_DEVICE_SLOTS"] for p in w.current.values()]
             assert sorted(envs) == ["0,1", "2,3"]
+            assert sorted(p.env["TPU_VISIBLE_CHIPS"]
+                          for p in w.current.values()) == ["0,1", "2,3"]
+        finally:
+            for p in w.current.values():
+                p.kill()
+
+    def test_short_pool_fails_the_resize(self):
+        """A worker that cannot get its chips is never spawned unpinned:
+        the pool's error takes the resize down."""
+        w, stage = self._watcher(n_dev=4, cap=2)
+        try:
+            with pytest.raises(RuntimeError, match="slot pool exhausted"):
+                w.apply_delta(stage(0, 3))
+            assert len(w.current) == 2  # the third was not started
         finally:
             for p in w.current.values():
                 p.kill()

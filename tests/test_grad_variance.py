@@ -17,7 +17,7 @@ from kungfu_tpu.monitor.grad_variance import (
 from kungfu_tpu.parallel import make_mesh
 from kungfu_tpu.utils.state import Counter, ExponentialMovingAverage
 from jax.sharding import PartitionSpec as P
-from kungfu_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 
 def _run_monitored(per_worker_grads, interval=1, steps=1):

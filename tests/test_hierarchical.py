@@ -6,7 +6,6 @@ import os
 import subprocess
 import sys
 
-import jax
 import numpy as np
 import pytest
 
@@ -59,13 +58,6 @@ def test_cross_slice_reducer_single_world_identity():
         peer.stop()
 
 
-@pytest.mark.skipif(
-    not hasattr(jax.config, "jax_num_cpu_devices"),
-    reason="jax-env: this jax (<=0.4.x) lacks the jax_num_cpu_devices "
-    "option the spawned agents use to self-provision 4-device CPU "
-    "worlds (they must clear XLA_FLAGS to control their own device "
-    "count); upgrading jax re-enables this automatically",
-)
 def test_hier_two_worlds_bit_identical_to_single_world():
     """2 kfrun workers x 4 virtual devices each train S-SGD to params
     bit-identical to one 8-device world (VERDICT r3 done-criterion)."""

@@ -36,23 +36,6 @@ def run_device_agent(np_, timeout=240):
     )
 
 
-# capability gate, not a version pin: multiprocess CPU collectives
-# arrived with the jax_cpu_collectives_implementation option (gloo);
-# without it every cross-process device computation dies with
-# "Multiprocess computations aren't implemented on the CPU backend"
-_CPU_MULTIPROCESS = hasattr(
-    __import__("jax").config, "jax_cpu_collectives_implementation"
-)
-
-pytestmark = pytest.mark.skipif(
-    not _CPU_MULTIPROCESS,
-    reason="jax-env: this jaxlib's CPU backend has no multiprocess "
-    "collectives (XlaRuntimeError: \"Multiprocess computations aren't "
-    "implemented on the CPU backend\"); needs a gloo-enabled jax "
-    "(jax_cpu_collectives_implementation) or a real accelerator",
-)
-
-
 @pytest.mark.parametrize("np_", [2, 3])
 def test_kfrun_forms_one_jax_world(np_):
     r = run_device_agent(np_)

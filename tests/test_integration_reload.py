@@ -11,21 +11,10 @@ import re
 import subprocess
 import sys
 
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "reload_agent.py")
 
 
-@pytest.mark.skipif(
-    not hasattr(
-        __import__("jax").config, "jax_cpu_collectives_implementation"
-    ),
-    reason="jax-env: the reload agent's device_psum_check needs "
-    "multiprocess CPU collectives, which this jaxlib lacks "
-    "(XlaRuntimeError: \"Multiprocess computations aren't implemented "
-    "on the CPU backend\"); a gloo-enabled jax re-enables this",
-)
 def test_reload_mode_restarts_with_progress_and_fresh_mesh():
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")

@@ -125,7 +125,7 @@ def test_adaptive_sgd_resyncs_at_switch(mesh):
     """The switch step's broadcast erases divergence accumulated during SMA:
     seeding divergent per-shard params must end with identical replicas."""
     import jax.numpy as jnp
-    from kungfu_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     opt = adaptive_sgd(optax.sgd(0.0), change_step=3, axis_name="dp", alpha=0.0)

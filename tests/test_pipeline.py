@@ -34,20 +34,6 @@ def _pp_mesh(pp):
     return make_mesh({"pp": pp}, devices=jax.devices()[:pp])
 
 
-# jax-env triage (seed-identical failures): differentiating the
-# psum-carrying pipeline body under this jax's (0.4.x)
-# jax.experimental.shard_map raises _SpecError from its out-spec
-# checker (NoFail placeholders leak into the spec comparison); the
-# forward-only pp tests pass. Non-strict: an upgraded jax counts these
-# as ordinary passes again with no edit here.
-_SHARD_MAP_GRAD_XFAIL = pytest.mark.xfail(
-    strict=False,
-    reason="jax-env: 0.4.x shard_map _SpecError when differentiating "
-    "psum-carrying pipeline bodies (forward-only pp tests pass); "
-    "fixed in newer jax",
-)
-
-
 @pytest.mark.parametrize("pp,n_micro", [(2, 4), (4, 4), (4, 8), (8, 8)])
 def test_pp_loss_matches_dense(pp, n_micro):
     cfg = _cfg(n_layers=8)
@@ -59,7 +45,6 @@ def test_pp_loss_matches_dense(pp, n_micro):
     assert abs(dense - pipe) < 1e-5, (dense, pipe)
 
 
-@_SHARD_MAP_GRAD_XFAIL
 def test_pp_gradients_match_dense():
     cfg = _cfg(n_layers=4)
     params = init_transformer(jax.random.PRNGKey(0), cfg)
@@ -72,7 +57,6 @@ def test_pp_gradients_match_dense():
                                    rtol=2e-4, atol=2e-5)
 
 
-@_SHARD_MAP_GRAD_XFAIL
 def test_pp_composes_with_dp():
     cfg = _cfg(n_layers=4)
     params = init_transformer(jax.random.PRNGKey(0), cfg)
@@ -92,7 +76,6 @@ def test_pp_composes_with_dp():
                                    rtol=2e-4, atol=2e-5)
 
 
-@_SHARD_MAP_GRAD_XFAIL
 def test_pp_trains():
     cfg = _cfg(n_layers=4)
     params = init_transformer(jax.random.PRNGKey(0), cfg)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from kungfu_tpu.parallel._compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kungfu_tpu.parallel import make_mesh

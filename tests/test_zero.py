@@ -688,7 +688,7 @@ def test_optax_zero_sharded_matches_ssgd():
 
     from kungfu_tpu.optimizers import synchronous_sgd, zero_sharded
     from kungfu_tpu.parallel import make_mesh
-    from kungfu_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     if jax.device_count() < 8:
         pytest.skip("needs the 8-device CPU mesh")
