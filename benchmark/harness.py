@@ -1,0 +1,377 @@
+"""One cell, measured: the process that holds the chip runs `measure`.
+
+It imports jax, so the parent (`run.py`) never imports this module. The
+pieces are plain functions of the cell's files, so the tests drive them at
+tiny sizes on the CPU mesh; only `child.py` decides that the devices are a
+chip, and `end_to_end.result_line` refuses a record that is not from one.
+"""
+
+from __future__ import annotations
+
+import collections
+import hashlib
+import json
+import math
+import os
+import time
+
+import numpy as np
+
+from benchmark import manifest
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+WARMUP_STEPS = 3  # after the first step, before the probe
+PROBE_STEPS = 6  # five intervals, whose median sets the window's step count
+TRACE_STEPS = 20  # what the traced run profiles, after its window
+SAMPLE_INDEX = 1 << 20  # the reference sample's place in the seeded stream
+
+
+class EventCounter:
+    """Counts jax.monitoring events by name from its construction on. Every
+    compile request, XLA compile or persistent-cache load, raises one
+    COMPILE_EVENT, so a difference of two readings counts compilations
+    (copied from chip_smoke._jax_events)."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.counts = collections.Counter()
+        monitoring.register_event_listener(
+            lambda event, **kw: self.counts.update([event]))
+        monitoring.register_event_duration_secs_listener(
+            lambda event, duration, **kw: self.counts.update([event]))
+
+    def __getitem__(self, event: str) -> int:
+        return self.counts[event]
+
+
+def family_of(config: dict):
+    return manifest.plugin("families", config["family"])
+
+
+def load_peaks(kind: str) -> dict:
+    """The published peaks of a `device_kind`; an unknown kind is an error."""
+    with open(os.path.join(os.path.dirname(__file__), "peaks.json")) as f:
+        chips = json.load(f)["chips"]
+    if kind not in chips:
+        raise RuntimeError(f"unknown device kind {kind!r}; peaks.json knows "
+                           f"{sorted(chips)}")
+    return chips[kind]
+
+
+def require_chips(devices, chips: int) -> dict:
+    """The peaks of the devices JAX found; raises unless all are TPUs of a
+    known kind and there are at least as many as the cell asks for."""
+    for d in devices:
+        if d.platform != "tpu":
+            raise RuntimeError(
+                f"no TPU: JAX found {d.platform} device {d.device_kind!r}; "
+                "the benchmark runs on the chip only")
+    if len(devices) < chips:
+        raise RuntimeError(f"the cell asks for {chips} chips, JAX found "
+                           f"{len(devices)}")
+    return load_peaks(devices[0].device_kind)
+
+
+def params_digest(tree) -> bytes:
+    import jax
+
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(tree):
+        h.update(np.asarray(leaf).tobytes())
+    return h.digest()
+
+
+def run_steps(step, state, opt_state, pool, place, n: int, start: int = 0,
+              clock=time.perf_counter):
+    """n optimizer steps with one step of look-ahead: dispatch step i + 1,
+    then block on the loss of step i, as a logging loop does. The device
+    queue is never drained, and the completion times of successive losses
+    give the step intervals. Batch `start + i` of the cycled pool feeds
+    step i. The three host phases are recorded as spans [name, start, end]
+    on `clock`."""
+    rec = {"t_start": clock(), "t_done": [], "spans": []}
+    spans = rec["spans"]
+    losses = []
+
+    def wait_for(loss):
+        t = clock()
+        loss.block_until_ready()
+        done = clock()
+        spans.append(["bench.wait", t, done])
+        rec["t_done"].append(done)
+
+    for i in range(n):
+        t0 = clock()
+        batch = place(pool[(start + i) % len(pool)])
+        t1 = clock()
+        state, opt_state, loss = step(state, opt_state, batch)
+        t2 = clock()
+        spans.append(["bench.input", t0, t1])
+        spans.append(["bench.dispatch", t1, t2])
+        if losses:
+            wait_for(losses[-1])
+        losses.append(loss)
+    if losses:
+        wait_for(losses[-1])
+    rec["losses"] = [float(l) for l in losses]
+    return state, opt_state, rec
+
+
+def intervals(rec: dict) -> list:
+    """Seconds between successive step completions."""
+    done = rec["t_done"]
+    return [b - a for a, b in zip(done, done[1:])]
+
+
+def steps_for(seconds: float, probe: dict) -> int:
+    """The window as a number of steps, fixed before it starts."""
+    median = float(np.median(intervals(probe)))
+    return max(2, int(math.floor(seconds / median)))
+
+
+def program_memory(compiled) -> dict:
+    """What the step program holds on one device while it runs."""
+    m = compiled.memory_analysis()
+    out = {
+        "argument_bytes": int(m.argument_size_in_bytes),
+        "output_bytes": int(m.output_size_in_bytes),
+        "temp_bytes": int(m.temp_size_in_bytes),
+        "alias_bytes": int(m.alias_size_in_bytes),
+    }
+    out["total_bytes"] = (out["argument_bytes"] + out["output_bytes"]
+                          + out["temp_bytes"] - out["alias_bytes"])
+    return out
+
+
+def relative_error(got, want) -> float:
+    """|got - want| / |want| over all leaves as one float32 vector."""
+    import jax
+    import jax.numpy as jnp
+
+    num = den = 0.0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        num += float(jnp.sum(jnp.square(g - w)))
+        den += float(jnp.sum(jnp.square(w)))
+    return math.sqrt(num / den)
+
+
+def eqns_of(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (scan, remat,
+    pjit, cond, custom derivatives)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from eqns_of(inner)
+
+
+def precision_faults(config: dict, head_width: int, jaxpr, state,
+                     opt_state) -> list:
+    """Where the program keeps or computes less than the configuration
+    states, as sentences; empty when it holds to it. The numbers of the
+    reference check cannot see these: bfloat16 parameters round to what
+    the bfloat16 matmuls see anyway, and at the initial parameters the
+    logits are too small for a bfloat16 head to move loss or gradients by
+    more than the blocks' own rounding. So they are read off the program:
+    every floating leaf of the state and the optimizer's state is of
+    `param_dtype`; the loss, and every matmul and reduction with a
+    dimension of the head's width (the logits, the softmax's sums, and
+    their transposes in the backward pass), are of `head_dtype`. `jaxpr` is
+    that of the family's `program_loss_and_grads`."""
+    import jax
+    import jax.numpy as jnp
+
+    faults = []
+    want = jnp.dtype(config["param_dtype"])
+    for what, tree in (("state", state), ("optimizer state", opt_state)):
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+            if jnp.issubdtype(leaf.dtype, jnp.floating) and leaf.dtype != want:
+                faults.append(f"{what}{jax.tree_util.keystr(path)} is "
+                              f"{leaf.dtype}, not {want}")
+    head = jnp.dtype(config["head_dtype"])
+    if jaxpr.out_avals[0].dtype != head:
+        faults.append(f"the loss is {jaxpr.out_avals[0].dtype}, not {head}")
+    for eqn in eqns_of(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if not (name == "dot_general" or name.startswith(("reduce_", "arg"))):
+            continue
+        avals = [v.aval for v in (*eqn.invars, *eqn.outvars)
+                 if hasattr(v.aval, "shape")]
+        if not any(head_width in a.shape for a in avals):
+            continue
+        low = {str(a.dtype) for a in avals
+               if jnp.issubdtype(a.dtype, jnp.floating) and a.dtype != head}
+        if low:
+            shapes = [tuple(a.shape) for a in avals]
+            faults.append(f"{name} over the head's width {shapes} is in "
+                          f"{sorted(low)}, not {head}")
+    return faults
+
+
+def reference_check(family, config: dict, seed: int, final_state,
+                    opt_state) -> dict:
+    """The program's loss and gradients against the plain float32
+    reference, on a seeded sample and the run's initial parameters (made
+    again from the seed: the run's own were donated to its first step),
+    to the family's tolerances; and the program's declared precision,
+    read from the same traced program and the run's final state."""
+    state = family.init(config, seed)
+    sample = family.host_batch(config, seed, SAMPLE_INDEX,
+                               family.REFERENCE_SAMPLES)
+    traced = family.program_loss_and_grads(config).trace(state, sample)
+    faults = precision_faults(config, family.head_width(config), traced.jaxpr,
+                              final_state, opt_state)
+    loss, grads = traced.lower().compile()(state, sample)
+    ref_loss, ref_grads = family.reference_loss_and_grads(config, state, sample)
+    loss, ref_loss = float(loss), float(ref_loss)
+    return {
+        "loss": loss,
+        "reference_loss": ref_loss,
+        "loss_error": abs(loss - ref_loss) / abs(ref_loss),
+        "grad_error": relative_error(grads, ref_grads),
+        "loss_rtol": family.LOSS_RTOL,
+        "grad_rtol": family.GRAD_RTOL,
+        "precision_faults": faults,
+    }
+
+
+def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
+            trace_dir, events: EventCounter, t_command: float) -> dict:
+    """Set up, warm up, run the window, check. Returns the run's record;
+    every rank of a world computes it, the reporting rank writes it.
+    `trace_dir`, where given, makes this the traced run: after the window,
+    TRACE_STEPS steps more under `jax.profiler`."""
+    import jax
+
+    t_world = time.time()
+    config, traffic = cell["config"], cell["traffic"]
+    family = family_of(config)
+    factory = manifest.plugin("steps", traffic["step"])
+    chips = mesh.devices.size
+    samples_per_step = traffic["per_chip_batch"] * chips
+    step_fn, init_opt_state = factory.build(family, config, traffic, mesh)
+
+    state = world.place_state(family.init(config, seed), mesh)
+    state, opt_state = factory.place(state, init_opt_state(state), mesh)
+    pool = [family.host_batch(config, seed, i, samples_per_step)
+            for i in range(traffic["pool"])]
+    place = manifest.plugin("placements", traffic["placement"]).make(
+        mesh, factory.BATCH_AXIS)
+
+    # the first step: lower, compile (or load from the cache), run
+    t0 = time.perf_counter()
+    batch = place(pool[0])
+    step = step_fn.lower(state, opt_state, batch).compile()
+    state, opt_state, loss = step(state, opt_state, batch)
+    first_loss = float(loss)
+    first_step_s = time.perf_counter() - t0
+    memory = program_memory(step)
+
+    # warm up the one shape, then time a few steps to size the window
+    at = 1
+    state, opt_state, warm = run_steps(
+        step, state, opt_state, pool, place, WARMUP_STEPS, at)
+    at += WARMUP_STEPS
+    state, opt_state, probe = run_steps(
+        step, state, opt_state, pool, place, PROBE_STEPS, at)
+    at += PROBE_STEPS
+    # The traced run measures the same window first, untraced, and then
+    # profiles TRACE_STEPS steps more. Rank 0 alone knows that it traces:
+    # the other ranks take its whole count as one window.
+    n_traced = TRACE_STEPS if trace_dir else 0
+    n = world.agree_steps(steps_for(seconds, probe) + n_traced) - n_traced
+
+    compiles_before = events[COMPILE_EVENT]
+    # nothing is switched off for the window: what stops a user's loop (the
+    # host's pauses, Python's collector) stops this one
+    t_window = time.time()
+    state, opt_state, window = run_steps(
+        step, state, opt_state, pool, place, n, at)
+    at += n
+    window["compiles"] = events[COMPILE_EVENT] - compiles_before
+    traced_window = None
+    if trace_dir:
+        # The device alone. With the runtime's host events on, the profiler
+        # records every one of the 400,000 small `Transpose` calls by which
+        # PJRT linearizes a 38.5 MB image batch on the host, and each ResNet
+        # step then waits 0.77 s for its input (chip runs, PR 23); the
+        # benchmark's own spans are put on the trace's clock afterwards
+        # (trace_reduce.place_spans).
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        compiles_before = events[COMPILE_EVENT]
+        try:
+            state, opt_state, traced_window = run_steps(
+                step, state, opt_state, pool, place, n_traced, at)
+        finally:
+            jax.profiler.stop_trace()
+        window["compiles"] += events[COMPILE_EVENT] - compiles_before
+
+    # checks, outside the window
+    before = [first_loss] + warm["losses"] + probe["losses"]
+    k = min(len(pool), len(before), n)
+    losses = window["losses"] + (traced_window["losses"] if traced_window else [])
+    failed = sum(1 for l in losses if not math.isfinite(l))
+    leaves = jax.tree.leaves(state)
+    checks = {
+        "no_compile_in_window": window["compiles"] == 0,
+        "no_step_failed": failed == 0,
+        # one pass over the pool at the start against one at the end
+        "loss_fell": float(np.mean(losses[-k:])) < float(np.mean(before[:k])),
+        "state_spans_mesh": all(
+            len(l.sharding.device_set) == chips for l in leaves),
+        "one_process_a_worker": jax.process_count() == world.size,
+        "workers_agree_on_state": world.agree_digest(state),
+    }
+    # The reporting rank alone compares with the reference, on its own
+    # chip: in a joined world only rank 0 writes the compile cache, so the
+    # other workers would compile both programs again in every run (68 s
+    # each for the reference, on three workers; chip run, PR 23).
+    reference = None
+    if world.rank == 0:
+        reference = reference_check(family, config, seed, state, opt_state)
+        checks["reference_loss"] = reference["loss_error"] <= reference["loss_rtol"]
+        checks["reference_grads"] = reference["grad_error"] <= reference["grad_rtol"]
+        checks["declared_precision"] = not reference["precision_faults"]
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    device = jax.devices()[0]
+    return {
+        "workload": cell["name"],
+        "seed": seed,
+        "traced": bool(trace_dir),
+        "rank": world.rank,
+        "device": {"platform": device.platform, "kind": device.device_kind,
+                   "count": len(jax.devices())},
+        "chips": chips,
+        "samples_per_step": samples_per_step,
+        "flops_per_sample": family.flops_per_sample(config),
+        "peak_flops": peaks["bf16_flops"],
+        "t_command": t_command,
+        "t_world": t_world,
+        "t_window": t_window,
+        "first_step_s": first_step_s,
+        "program_memory": memory,
+        "memory_stats_peak_bytes": max(
+            (s.get("peak_bytes_in_use") or 0 for s in stats), default=0),
+        "window": window,
+        "traced_window": traced_window,
+        "losses_before": before,
+        "probe_intervals_s": intervals(probe),
+        "attempted": n + n_traced,
+        "failed": failed,
+        "checks": checks,
+        "correct": all(checks.values()),
+        "reference": reference,
+        "cache": {"hits": events[CACHE_HIT_EVENT],
+                  "misses": events[CACHE_MISS_EVENT]},
+        "versions": {"jax": jax.__version__},
+    }
