@@ -374,11 +374,15 @@ def launcher_worker(cfg, steps: int, per_chip_batch: int,
                     chips_per_worker: int = 1) -> dict:
     """One kfrun worker of the launcher phase: join the one device world,
     take a few S-SGD steps over it, agree on the parameters."""
-    from kungfu_tpu import api
+    from kungfu_tpu import api, knobs
     from kungfu_tpu.parallel import initialize_device_plane
+    from kungfu_tpu.telemetry import tracing
 
     rank, size = api.current_rank(), api.cluster_size()
     initialize_device_plane()
+    # kfrun's clock for this worker starts where the runner spawned it
+    spawn_ts = float(knobs.raw("KF_SPAWN_TS") or time.time())
+    since_spawn = {"world": time.time() - spawn_ts}
 
     import jax
     import numpy as np
@@ -407,17 +411,41 @@ def launcher_worker(cfg, steps: int, per_chip_batch: int,
 
     mesh = make_mesh({"dp": n})
     opt = synchronous_sgd(optax.adamw(LEARNING_RATE), "dp")
-    params = broadcast_variables(_init_params(cfg), mesh)
-    opt_state = replicate(opt.init(params), mesh)
-    tokens = _seeded_batch(cfg, per_chip_batch * n)
-    batch = jax.make_array_from_callback(
-        tokens.shape, NamedSharding(mesh, P("dp")), lambda idx: tokens[idx]
-    )
+    # the smoke's own phases between the program's spans, so that the
+    # timeline below has no unnamed interval
+    with tracing.span("smoke.init_params"):
+        params = jax.block_until_ready(_init_params(cfg))
+    params = broadcast_variables(params, mesh)
+    with tracing.span("smoke.opt_state"):
+        opt_state = jax.block_until_ready(replicate(opt.init(params), mesh))
+    with tracing.span("smoke.batch"):
+        tokens = _seeded_batch(cfg, per_chip_batch * n)
+        batch = jax.make_array_from_callback(
+            tokens.shape, NamedSharding(mesh, P("dp")), lambda idx: tokens[idx]
+        )
     step = make_train_step(_loss_fn(cfg), opt, mesh)
     setup_s = time.perf_counter() - t_start
+    since_spawn["state_placed"] = time.time() - spawn_ts
 
     out = _run_steps(step, params, opt_state, batch, steps,
                      jax.local_devices())
+    since_spawn["first_step"] = since_spawn["state_placed"] + out["first_step_s"]
+    # the launcher and placement phases by span, once a process (PERF.md)
+    spans_ms, timeline = {}, []
+    to_wall = time.time() - time.perf_counter()  # the ring's clock -> wall
+    for prefix in ("worker.", "device_plane.", "broadcast.", "smoke."):
+        spans_ms.update(tracing.summary_ms(prefix))
+        # each span where it fell: a sum hides that a name ran twice, and
+        # what waited between two spans
+        timeline += [[e.name, round(e.start + to_wall - spawn_ts, 3),
+                      round(e.duration, 3), e.args]
+                     for e in tracing.full_events(prefix)]
+    timeline.sort(key=lambda e: e[1])
+    if rank == 0:
+        print("launch and placement spans (ms): " + json.dumps(spans_ms),
+              flush=True)
+        print("[name, seconds since spawn, seconds, args]: "
+              + json.dumps(timeline), flush=True)
     agreed = api.consensus(_params_digest(out.pop("params")), "chip-smoke")
     _check(agreed, "workers disagree on the parameters after the last step")
     api.run_barrier()
@@ -429,6 +457,10 @@ def launcher_worker(cfg, steps: int, per_chip_batch: int,
         "process_index": jax.process_index(),
         "global_batch": per_chip_batch * n,
         "setup_s": round(setup_s, 3),
+        "spawn_ts": spawn_ts,
+        "since_spawn_s": {k: round(v, 3) for k, v in since_spawn.items()},
+        "spans_ms": spans_ms,
+        "span_timeline": timeline,
         **out,
         "params_agree": agreed,
         "native_kernels": _native_loaded(),
@@ -642,8 +674,12 @@ def main() -> int:
         print(build.stdout + build.stderr, flush=True)
         fail("launcher", f"native/build.sh exit code {build.returncode}")
 
+    t_kfrun = time.time()
     workers = phase("launcher", _kfrun(n, n, "launcher-worker"), want=n)
     workers.sort(key=lambda w: w["rank"])
+    # command start -> the runner spawned rank 0: the runner's own start
+    workers[0]["command_to_spawn_s"] = round(
+        workers[0].pop("spawn_ts") - t_kfrun, 3)
     phases["launcher"].update(workers[0], workers=n, local_devices=[
         w["local_devices"] for w in workers
     ])

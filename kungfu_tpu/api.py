@@ -350,8 +350,8 @@ def trace_summary(prefix: str = "") -> dict:
     """Total ms per hot-path span recorded in this process (transport
     send/recv, collective walks, fuse pack/unpack, elastic state sync) —
     parity: the reference compiles TRACE_SCOPE into its GPU hot paths
-    (srcs/cpp/include/kungfu/utils/trace.hpp, gpu_collective.cpp)."""
-    from kungfu_tpu.utils import trace
+    (trace.hpp under srcs/cpp/include/kungfu/utils, gpu_collective.cpp)."""
+    from kungfu_tpu.telemetry import tracing as trace
 
     return trace.summary_ms(prefix)
 

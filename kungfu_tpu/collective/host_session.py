@@ -71,7 +71,7 @@ from kungfu_tpu.telemetry import metrics as tmetrics
 from kungfu_tpu.transport.client import Client
 from kungfu_tpu.transport.handlers import CollectiveEndpoint
 from kungfu_tpu.transport.message import ConnType
-from kungfu_tpu.utils import trace
+from kungfu_tpu.telemetry import tracing as trace
 from kungfu_tpu.utils.handoff import parallel_run as _par
 from kungfu_tpu.utils.stall import stall_detect
 

@@ -22,7 +22,7 @@ import numpy as np
 from kungfu_tpu import knobs
 from kungfu_tpu.base.workspace import Workspace
 from kungfu_tpu.collective.strategies import effective_cpu_count
-from kungfu_tpu.utils import trace
+from kungfu_tpu.telemetry import tracing as trace
 from kungfu_tpu.utils.handoff import HandoffQueue, parallel_run as _par
 from kungfu_tpu.utils.pool import get_buffer_pool
 from kungfu_tpu.utils.stall import stall_detect

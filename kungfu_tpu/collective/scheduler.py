@@ -85,7 +85,7 @@ from kungfu_tpu.base.workspace import Workspace
 from kungfu_tpu.telemetry import config as tconfig
 from kungfu_tpu.telemetry import metrics as tmetrics
 from kungfu_tpu.telemetry import steptrace
-from kungfu_tpu.utils import trace
+from kungfu_tpu.telemetry import tracing as trace
 from kungfu_tpu.utils.handoff import HandoffQueue
 from kungfu_tpu.utils.stall import stall_detect
 

@@ -47,7 +47,7 @@ from kungfu_tpu.plan import topology as topo
 from kungfu_tpu.plan.graph import Graph
 from kungfu_tpu.plan.peer import PeerID
 from kungfu_tpu.transport.message import ConnType, Flags
-from kungfu_tpu.utils import trace
+from kungfu_tpu.telemetry import tracing as trace
 from kungfu_tpu.utils.handoff import parallel_run as _par
 from kungfu_tpu.utils.pool import get_buffer_pool, get_pool
 

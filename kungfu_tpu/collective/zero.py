@@ -73,7 +73,7 @@ from kungfu_tpu.base.workspace import Workspace
 from kungfu_tpu.plan import topology as topo
 from kungfu_tpu.telemetry import config as tconfig
 from kungfu_tpu.telemetry import metrics as tmetrics
-from kungfu_tpu.utils import trace
+from kungfu_tpu.telemetry import tracing as trace
 
 
 def bucket_layout(sizes: Sequence[int], cap_bytes: int,

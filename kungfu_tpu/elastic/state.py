@@ -64,7 +64,7 @@ class ElasticState:
     def _sync_state(self) -> None:
         if self._get_state is None:
             return
-        from kungfu_tpu.utils import trace
+        from kungfu_tpu.telemetry import tracing as trace
 
         with trace.span("elastic.sync_state"):
             self._sync_state_traced()

@@ -18,6 +18,8 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from kungfu_tpu.telemetry import tracing
+
 
 def broadcast_variables(tree, mesh: Mesh = None):
     """Force every process to rank 0's values, then replicate on-mesh.
@@ -39,7 +41,14 @@ def broadcast_variables(tree, mesh: Mesh = None):
         peer = get_default_peer()
         # a JAX world kfrun did not form has no ranks: JAX's default then
         is_source = peer.rank == 0 if peer.size == jax.process_count() else None
-        tree = multihost_utils.broadcast_one_to_all(tree, is_source=is_source)
+        leaves = jax.tree.leaves(tree)
+        # returns host arrays, so the span ends when the values are here
+        with tracing.span("broadcast.one_to_all", leaves=len(leaves),
+                          bytes=sum(getattr(l, "nbytes", 0) for l in leaves)):
+            tree = multihost_utils.broadcast_one_to_all(tree, is_source=is_source)
     if mesh is not None:
-        tree = jax.device_put(tree, NamedSharding(mesh, P()))
+        # set-up code: the span waits for the copies, so that it holds them
+        with tracing.span("broadcast.replicate"):
+            tree = jax.block_until_ready(
+                jax.device_put(tree, NamedSharding(mesh, P())))
     return tree

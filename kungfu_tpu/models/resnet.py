@@ -155,8 +155,9 @@ def resnet_loss(model: ResNet, params, batch_stats, batch):
         train=True,
         mutable=["batch_stats"],
     )
-    logp = jax.nn.log_softmax(logits)
-    loss = -jnp.mean(
-        jnp.sum(jax.nn.one_hot(labels, logits.shape[-1]) * logp, axis=-1)
-    )
+    with jax.named_scope("head_loss"):
+        logp = jax.nn.log_softmax(logits)
+        loss = -jnp.mean(
+            jnp.sum(jax.nn.one_hot(labels, logits.shape[-1]) * logp, axis=-1)
+        )
     return loss, updates["batch_stats"]

@@ -131,19 +131,22 @@ def _attention(x, wqkv, wo, cfg: TransformerConfig, core=_full_attention_core):
     q = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
     k = k.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
     v = v.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
-    ctx = core(q, k, v)
+    with jax.named_scope("attn_core"):
+        ctx = core(q, k, v)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, D)
     return ctx @ wo
 
 
 def _block(x, layer, cfg: TransformerConfig, core=_full_attention_core):
     dt = cfg.dtype
-    x = x + _attention(_rmsnorm(x, layer["ln1_scale"]),
-                       layer["wqkv"].astype(dt), layer["wo"].astype(dt), cfg,
-                       core=core)
-    h = _rmsnorm(x, layer["ln2_scale"])
-    h = jax.nn.gelu(h @ layer["w_in"].astype(dt))
-    return x + h @ layer["w_out"].astype(dt)
+    with jax.named_scope("attn"):
+        x = x + _attention(_rmsnorm(x, layer["ln1_scale"]),
+                           layer["wqkv"].astype(dt), layer["wo"].astype(dt),
+                           cfg, core=core)
+    with jax.named_scope("ffn"):
+        h = _rmsnorm(x, layer["ln2_scale"])
+        h = jax.nn.gelu(h @ layer["w_in"].astype(dt))
+        return x + h @ layer["w_out"].astype(dt)
 
 
 def lm_head_loss(params, x, targets, cfg: TransformerConfig):
@@ -151,18 +154,20 @@ def lm_head_loss(params, x, targets, cfg: TransformerConfig):
     hidden states `x` (..., S, D). The ONE implementation shared by the
     dense, ring (sequence-parallel) and pipeline paths — a loss change
     (label smoothing, z-loss, dtype policy) lands everywhere at once."""
-    h = _rmsnorm(x, params["ln_f_scale"])
-    logits = h.astype(jnp.float32) @ params["embed"].astype(jnp.float32).T
-    logp = jax.nn.log_softmax(logits)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)
-    return -jnp.mean(ll)
+    with jax.named_scope("head_loss"):
+        h = _rmsnorm(x, params["ln_f_scale"])
+        logits = h.astype(jnp.float32) @ params["embed"].astype(jnp.float32).T
+        logp = jax.nn.log_softmax(logits)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)
+        return -jnp.mean(ll)
 
 
 def transformer_hidden(params, tokens, cfg: TransformerConfig):
     """tokens (B, S) int32 -> final hidden states (B, S, D) pre-norm."""
     B, S = tokens.shape
     dt = cfg.dtype
-    x = params["embed"].astype(dt)[tokens] + params["pos_embed"].astype(dt)[:S]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens] + params["pos_embed"].astype(dt)[:S]
 
     def body(x, layer):
         return _block(x, layer, cfg), None
@@ -213,11 +218,12 @@ def ring_transformer_apply_shard(params, tokens, cfg: TransformerConfig,
             f"global sequence {sp_size * Sl} exceeds max_seq {cfg.max_seq}"
         )
     dt = cfg.dtype
-    idx = jax.lax.axis_index(sp_axis)
-    pos = jax.lax.dynamic_slice(
-        params["pos_embed"], (idx * Sl, 0), (Sl, cfg.d_model)
-    )
-    x = params["embed"].astype(dt)[tokens] + pos.astype(dt)
+    with jax.named_scope("embed"):
+        idx = jax.lax.axis_index(sp_axis)
+        pos = jax.lax.dynamic_slice(
+            params["pos_embed"], (idx * Sl, 0), (Sl, cfg.d_model)
+        )
+        x = params["embed"].astype(dt)[tokens] + pos.astype(dt)
 
     def ring_core(q, k, v):
         return ring_self_attention(q, k, v, sp_axis, sp_size, causal=True)

@@ -32,8 +32,10 @@ def synchronous_sgd(base: optax.GradientTransformation, axis_name: str = "dp") -
         return base.init(params)
 
     def update(grads, state, params=None, **extra):
-        grads = jax.tree.map(lambda g: lax.pmean(g, axis_name), grads)
-        return base.update(grads, state, params, **extra)
+        with jax.named_scope("grad_allreduce"):
+            grads = jax.tree.map(lambda g: lax.pmean(g, axis_name), grads)
+        with jax.named_scope("optimizer_update"):
+            return base.update(grads, state, params, **extra)
 
     return optax.GradientTransformation(init, update)
 
@@ -96,12 +98,14 @@ def zero_sharded(
                 padded, axis_name, scatter_dimension=0, tiled=True
             ) / k
 
-        grad_shards = jax.tree.map(g_shard, grads)
+        with jax.named_scope("grad_allreduce"):
+            grad_shards = jax.tree.map(g_shard, grads)
         param_shards = jax.tree.map(_my_shard, params)
-        shard_updates, base_state = base.update(
-            grad_shards, state.base, param_shards, **extra
-        )
-        new_shards = optax.apply_updates(param_shards, shard_updates)
+        with jax.named_scope("optimizer_update"):
+            shard_updates, base_state = base.update(
+                grad_shards, state.base, param_shards, **extra
+            )
+            new_shards = optax.apply_updates(param_shards, shard_updates)
 
         # all-gather the updated shards and express the result as an
         # optax update (new - old), unpadded and reshaped per leaf
@@ -135,8 +139,13 @@ def synchronous_averaging(
     def update(grads, state, params, **extra):
         if params is None:
             raise ValueError("synchronous_averaging requires params")
-        avg = jax.tree.map(lambda p: lax.pmean(p, axis_name), params)
-        base_updates, base_state = base.update(grads, state.base, params, **extra)
+        # the scope is named for what it is in S-SGD; here the reduction
+        # averages the parameters, not the gradients
+        with jax.named_scope("grad_allreduce"):
+            avg = jax.tree.map(lambda p: lax.pmean(p, axis_name), params)
+        with jax.named_scope("optimizer_update"):
+            base_updates, base_state = base.update(
+                grads, state.base, params, **extra)
         # total update = alpha * (avg - p) + base_update(local grads)
         updates = jax.tree.map(
             lambda a, p, u: alpha * (a - p) + u, avg, params, base_updates

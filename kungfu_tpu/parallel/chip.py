@@ -66,6 +66,10 @@ def enable_compile_cache() -> str:
     lets the restarted workers load their programs instead of compiling
     them again.
     """
+    # by default the key leaves metadata out, so a program cached before a
+    # `jax.named_scope` changed is loaded with its old `op_name`s and a
+    # profile shows those (chip run, PR 24)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -27,6 +27,7 @@ import socket
 import threading
 from typing import Optional
 
+from kungfu_tpu.telemetry import tracing
 from kungfu_tpu.utils import log
 from kungfu_tpu.utils.stall import stall_detect
 
@@ -70,7 +71,8 @@ def initialize_device_plane(platform: Optional[str] = None) -> None:
         peer = get_default_peer()
         if platform:
             jax.config.update("jax_platforms", platform)
-        enable_compile_cache()
+        with tracing.span("device_plane.compile_cache"):
+            enable_compile_cache()
         sess = peer.current_session()
         if peer.config.single_process or sess.size == 1:
             _state["local_only"] = True
@@ -92,17 +94,24 @@ def initialize_device_plane(platform: Optional[str] = None) -> None:
         else:
             addr = b""
         with stall_detect("device_plane_bootstrap"):
-            addr = sess.broadcast_bytes(addr, f"kungfu::devplane:v{peer.cluster_version}")
+            with tracing.span("device_plane.bootstrap"):
+                addr = sess.broadcast_bytes(
+                    addr, f"kungfu::devplane:v{peer.cluster_version}")
             coordinator = addr.decode()
             log.info(
                 "device plane: initializing process %d/%d, coordinator %s",
                 sess.rank, sess.size, coordinator,
             )
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=sess.size,
-                process_id=sess.rank,
-            )
+            with tracing.span("device_plane.distributed_initialize"):
+                jax.distributed.initialize(
+                    coordinator_address=coordinator,
+                    num_processes=sess.size,
+                    process_id=sess.rank,
+                )
+            # the backend starts at the first call that needs it; taken here
+            # so that its seconds are timed where they are spent
+            with tracing.span("device_plane.backend_start"):
+                jax.devices()
         _state["initialized"] = True
         _state["local_only"] = False
         _state["version"] = peer.cluster_version

@@ -41,8 +41,11 @@ def make_train_step(
 
     def local_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        # forward and backward need no scope: under value_and_grad JAX names
+        # their ops jvp(<scope>) and transpose(jvp(<scope>)) (docs/telemetry.md)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         loss = jax.lax.pmean(loss, axis_name)
         return params, opt_state, loss
 

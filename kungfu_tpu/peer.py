@@ -35,7 +35,8 @@ from kungfu_tpu.transport.handlers import (
 )
 from kungfu_tpu.transport.message import ConnType, Flags, Message
 from kungfu_tpu.transport.server import Server
-from kungfu_tpu.utils import log, trace
+from kungfu_tpu.telemetry import tracing as trace
+from kungfu_tpu.utils import log
 from kungfu_tpu.utils.stall import stall_detect
 
 _default_peer: Optional["Peer"] = None

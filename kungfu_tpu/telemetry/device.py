@@ -1,0 +1,205 @@
+"""The device plane of the one tracing system: a profile an operator takes.
+
+`telemetry/tracing.py` times the host; the scopes in the program
+(`jax.named_scope`, vocabulary in docs/telemetry.md) name what the chip
+runs. This module joins them on one clock:
+
+    from kungfu_tpu.telemetry import device, tracing
+    compiled = step.lower(params, opt_state, batch).compile()
+    with device.profile("/tmp/prof"):
+        with tracing.span("train.step"):
+            params, opt_state, loss = compiled(params, opt_state, batch)
+            loss.block_until_ready()
+    print(device.phase_ms(device.find_xplane("/tmp/prof"),
+                          device.scope_table(compiled)))
+
+While `profile()` is open every `tracing.span` also enters a
+`jax.profiler.TraceAnnotation` of its name, so the ring's spans sit in the
+`.xplane.pb` beside the device's ops, on the profiler's clock. The Python
+tracer is off and the host tracer at its lowest level that keeps user
+annotations (`host_tracer_level = 1`). That level still records PJRT's
+per-batch `Transpose` calls: free for a step that moves kilobytes, 9 % of
+a ResNet step that moves 38.5 MB a batch (chip run, PERF.md, PR 24).
+
+Imported by nothing at start-up: the runner and `tracing` itself import no
+jax.
+"""
+
+from __future__ import annotations
+
+import bisect
+import collections
+import contextlib
+import glob
+import os
+import re
+import statistics
+from typing import Dict, Iterator, List, Tuple
+
+from kungfu_tpu.telemetry import tracing
+
+PHASES = ("forward", "backward", "optimizer", "all_reduce", "unattributed")
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+# jit(f), shard_map, while/body/closed_call and the primitive at the path's
+# end are how JAX got there, not where in the program the op belongs
+_PLUMBING = re.compile(r"^(jit\(.*\)|pjit|shard_map|while|body|cond|branch_\d+|"
+                       r"closed_call|checkpoint|remat|custom_jvp_call|"
+                       r"custom_vjp_call.*)$")
+
+
+@contextlib.contextmanager
+def profile(log_dir: str) -> Iterator[str]:
+    """Take a `jax.profiler` trace into `log_dir` with the Python tracer
+    off and the host tracer at the level of user annotations, and mirror every
+    `tracing.span` into it while it is open."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    tracing._mirror = jax.profiler.TraceAnnotation
+    try:
+        yield log_dir
+    finally:
+        tracing._mirror = None
+        jax.profiler.stop_trace()
+
+
+def find_xplane(log_dir: str) -> str:
+    """The newest `.xplane.pb` a profile left under `log_dir`."""
+    found = sorted(glob.glob(
+        os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
+    return found[-1]
+
+
+def scope_table(compiled) -> Dict[str, str]:
+    """{HLO instruction name: op_name} of a compiled program, from
+    `compiled.as_text()`: the scope path JAX wrote on each instruction,
+    `jit(local_step)/shard_map/transpose(jvp())/while/body/closed_call/attn/attn_core/dot_general`.
+    An instruction the compiler made itself (a copy, a bitcast) has none
+    and is left out."""
+    table = {}
+    for line in compiled.as_text().splitlines():
+        name = _INSTRUCTION.match(line)
+        scope = _OP_NAME.search(line)
+        if name and scope:
+            table[name.group(1)] = scope.group(1)
+    return table
+
+
+def scope_parts(op_name: str) -> List[str]:
+    """The components of an `op_name` that say where in the program an
+    instruction belongs: plumbing and the primitive's own name taken out."""
+    parts = op_name.split("/")[:-1] if "/" in op_name else []
+    return [p for p in parts if not _PLUMBING.match(p)]
+
+
+def phase_of(op_name: str) -> str:
+    """Forward, backward, optimizer, all-reduce or unattributed, by path
+    component: `grad_allreduce` first, then `optimizer` /
+    `optimizer_update`, then any `transpose(`, then any `jvp(` or other
+    scope of the model."""
+    parts = scope_parts(op_name or "")
+    if "grad_allreduce" in parts:
+        return "all_reduce"
+    if "optimizer" in parts or "optimizer_update" in parts:
+        return "optimizer"
+    if any(p.startswith("transpose(") for p in parts):
+        return "backward"
+    if parts:
+        return "forward"
+    return "unattributed"
+
+
+def _instruction(event_name: str) -> str:
+    """The TPU's trace names an op by its whole instruction text,
+    `%fusion.13 = ...`; the CPU's by the instruction's name."""
+    return event_name.split(" = ", 1)[0].lstrip("%")
+
+
+def _self_ns(events: List[Tuple[str, int, int]]) -> List[Tuple[str, int, int]]:
+    """(name, start, own nanoseconds) of each event of one line: its
+    duration less that of the events it encloses (a `while` encloses the
+    ops of its body)."""
+    out, stack = [], []  # stack of [name, start, end, enclosed_ns]
+    for name, a, b in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][2] <= a:
+            n, s, e, inner = stack.pop()
+            out.append((n, s, e - s - inner))
+        if stack and b <= stack[-1][2]:
+            stack[-1][3] += b - a
+        stack.append([name, a, b, 0])
+    for n, s, e, inner in stack:
+        out.append((n, s, e - s - inner))
+    return out
+
+
+def phase_ms(xplane_path: str, table: Dict[str, str]) -> Dict[str, float]:
+    """{phase: milliseconds a step} from a profile of the program whose
+    `scope_table` is `table`: every op's own time, classed by `phase_of`,
+    summed within each run of the program on the first device, median over
+    the runs. The program is the one that took most of the profile's
+    device time. On a TPU the ops are the device plane's "XLA Ops" line and
+    a run is an event of "XLA Modules"; the CPU backend puts its ops on the
+    host plane's thread lines, stamped with `hlo_module`, `run_id` and
+    `device_ordinal`."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(xplane_path)
+    planes = sorted(data.planes, key=lambda p: p.name)
+    for plane in planes:
+        lines = {line.name: line for line in plane.lines}
+        if not (plane.name.startswith("/device:") and "XLA Ops" in lines
+                and "XLA Modules" in lines):
+            continue
+        programs = collections.defaultdict(list)
+        for e in lines["XLA Modules"].events:
+            programs[e.name].append(
+                (int(e.start_ns), int(e.start_ns + e.duration_ns)))
+        if not programs:
+            continue
+        runs = sorted(max(programs.values(),
+                          key=lambda rs: sum(b - a for a, b in rs)))
+        starts = [a for a, _ in runs]
+        steps = [collections.Counter() for _ in runs]
+        ops = [(_instruction(e.name), int(e.start_ns),
+                int(e.start_ns + e.duration_ns)) for e in lines["XLA Ops"].events]
+        for name, start, own in _self_ns(ops):
+            i = bisect.bisect_right(starts, start) - 1
+            if i >= 0 and start < runs[i][1]:
+                steps[i][phase_of(table.get(name, ""))] += own
+        return _median_ms(steps)
+    # the CPU backend: no device plane
+    programs = collections.defaultdict(lambda: collections.defaultdict(collections.Counter))
+    for plane in planes:
+        for line in plane.lines:
+            found = []
+            for e in line.events:
+                stats = dict(e.stats)
+                # an op's event is named by its instruction; the thunk
+                # executor's own events carry the stats too
+                if "hlo_module" in stats and stats.get("hlo_op") == e.name:
+                    key = (stats["hlo_module"], stats.get("device_ordinal", 0),
+                           stats.get("run_id"), e.name)
+                    found.append((key, int(e.start_ns),
+                                  int(e.start_ns + e.duration_ns)))
+            for (module, device, run, name), _, own in _self_ns(found):
+                programs[module][(device, run)][phase_of(table.get(name, ""))] += own
+    if not programs:
+        return {}
+    steps = max(programs.values(),
+                key=lambda runs: sum(sum(c.values()) for c in runs.values()))
+    first = min(device for device, _ in steps)
+    return _median_ms([c for (device, _), c in steps.items() if device == first])
+
+
+def _median_ms(steps: List[collections.Counter]) -> Dict[str, float]:
+    if not steps:
+        return {}
+    return {phase: statistics.median(s[phase] for s in steps) / 1e6
+            for phase in PHASES}

@@ -1,8 +1,8 @@
 """Span-based tracing with Chrome-trace/Perfetto JSON export.
 
-The successor of the old ``kungfu_tpu.utils.trace`` scoped tracer (which
-now re-exports this module): named spans carried in a bounded ring
-buffer — recording is always-on because a span is two perf_counter
+The one tracing module (call sites import it as
+``from kungfu_tpu.telemetry import tracing as trace``): named spans carried
+in a bounded ring buffer — recording is always-on because a span is two perf_counter
 calls, a small tuple and a deque append — plus:
 
 - nesting: each thread keeps a span stack, so events know their depth
@@ -14,7 +14,7 @@ calls, a small tuple and a deque append — plus:
   ``i`` instants) loadable by chrome://tracing and ui.perfetto.dev.
 
 Capability parity: the reference compiles TRACE_SCOPE into its hot paths
-(srcs/cpp/include/kungfu/utils/trace.hpp); the ring-buffer + JSON export
+(trace.hpp under srcs/cpp/include/kungfu/utils); the ring-buffer + JSON export
 follows the standard Chrome trace-event format.
 """
 
@@ -140,27 +140,40 @@ def _step_args(args: Optional[dict]) -> Optional[dict]:
     return d
 
 
+# None, or `jax.profiler.TraceAnnotation` while telemetry.device.profile()
+# is open: every span then also enters an annotation of its name, so the
+# ring's spans sit in the device profile on the profiler's clock. This
+# module imports no jax; device.profile() places the class here.
+_mirror = None
+
+
 class _Span:
     """Class-based context manager (NOT @contextmanager: spans sit on
     every collective/transport call and generator CMs cost ~3x more to
     enter). Records a complete event on exit; nesting depth comes from a
     per-thread stack."""
 
-    __slots__ = ("name", "args", "t0", "depth")
+    __slots__ = ("name", "args", "t0", "depth", "mirror")
 
     def __init__(self, name: str, args: Optional[dict]):
         self.name = name
         self.args = args
+        self.mirror = None
 
     def __enter__(self):
         st = _stack()
         self.depth = len(st)
         st.append(self.name)
+        if _mirror is not None:
+            self.mirror = _mirror(self.name)
+            self.mirror.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self.t0
+        if self.mirror is not None:
+            self.mirror.__exit__(*exc)
         _stack().pop()
         _append(
             TraceEvent(
@@ -203,7 +216,7 @@ def instant(name: str, **args) -> None:
 
 
 def events(prefix: str = "") -> List[Tuple[str, float, float]]:
-    """(name, start, duration) tuples — the legacy utils.trace shape."""
+    """(name, start, duration) tuples — the old scoped tracer's shape."""
     return [
         (e.name, e.start, e.duration) for e in full_events(prefix)
     ]

@@ -20,7 +20,7 @@ from kungfu_tpu.plan.peer import PeerID
 # it and must never be the outer of the two
 _KF_LOCK_ORDER = ("lock", "_pool_lock")
 from kungfu_tpu.transport import shm
-from kungfu_tpu.utils import trace
+from kungfu_tpu.telemetry import tracing as trace
 from kungfu_tpu.transport.message import (
     ConnType,
     Flags,

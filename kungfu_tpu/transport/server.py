@@ -21,7 +21,7 @@ from typing import Callable, Dict, Optional
 
 from kungfu_tpu.plan.peer import PeerID
 from kungfu_tpu.transport import shm
-from kungfu_tpu.utils import trace
+from kungfu_tpu.telemetry import tracing as trace
 from kungfu_tpu.transport.message import (
     ConnType,
     Flags,

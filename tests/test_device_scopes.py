@@ -1,0 +1,343 @@
+"""The scopes in the program (`jax.named_scope`, vocabulary in
+docs/telemetry.md): every name reaches the compiled HLO's `op_name`s in its
+forward and backward form, `grad_allreduce` holds the gradients'
+all-reduces, few instructions carry no scope, and the scopes are metadata
+only: the StableHLO text a step lowers to is the same without them.
+Single process, on the CPU mesh; nothing here opens a port."""
+
+import contextlib
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from kungfu_tpu.models.resnet import init_resnet, resnet18_thin, resnet_loss
+from kungfu_tpu.models.transformer import (TransformerConfig, init_transformer,
+                                           transformer_loss)
+from kungfu_tpu.optimizers import (adaptive_sgd, synchronous_averaging,
+                                   synchronous_sgd, zero_sharded)
+from kungfu_tpu.parallel import make_mesh, make_train_step
+from kungfu_tpu.telemetry import device
+
+DP = 4
+MODEL_SCOPES = {
+    "transformer": ("embed", "attn", "attn_core", "ffn", "head_loss"),
+    "resnet": ("ResNet", "conv_init", "bn_init", "BottleneckBlock_0",
+               "BottleneckBlock_1", "Conv_0", "BatchNorm_0", "Dense_0",
+               "head_loss"),
+}
+# XLA's own instructions (copies, bitcasts, parameters, tuples) carry no
+# op_name, and the updates the step factory applies outside `optimizer` (the
+# ResNet body's) and the loss's pmean carry none of the vocabulary. Read here:
+# 12 % (transformer) and 21 % (ResNet) of the entry's and the loops'
+# instructions that have an op_name at all.
+UNSCOPED_LIMIT = {"transformer": 0.20, "resnet": 0.30}
+
+
+def _mesh():
+    return make_mesh({"dp": DP}, devices=jax.devices()[:DP])
+
+
+def _transformer_step(wrap):
+    cfg = TransformerConfig.tiny()
+    opt = wrap(optax.adamw(1e-3))
+    step = make_train_step(functools.partial(transformer_loss, cfg=cfg), opt,
+                           _mesh())
+    params = init_transformer(jax.random.PRNGKey(0), cfg)
+    batch = jnp.zeros((2 * DP, 17), jnp.int32)
+    return step, (params, opt.init(params), batch)
+
+
+def _resnet_step(wrap):
+    """The per-chip body a loss with auxiliary state needs (it has no place
+    in `make_train_step`), as the benchmark's ResNet family has it, with the
+    step factory's `optimizer` scope."""
+    net = resnet18_thin()
+    opt = wrap(optax.sgd(0.1, momentum=0.9))
+    params, stats = init_resnet(jax.random.PRNGKey(0), net, image_size=32)
+
+    def local_step(state, opt_state, batch):
+        def loss_of(p):
+            return resnet_loss(net, p, state["batch_stats"], batch)
+
+        (loss, new_stats), grads = jax.value_and_grad(loss_of, has_aux=True)(
+            state["params"])
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, state["params"])
+            new_params = optax.apply_updates(state["params"], updates)
+        new_stats = jax.tree.map(lambda x: lax.pmean(x, "dp"), new_stats)
+        return ({"params": new_params, "batch_stats": new_stats}, opt_state,
+                lax.pmean(loss, "dp"))
+
+    step = jax.jit(jax.shard_map(
+        local_step, mesh=_mesh(), in_specs=(P(), P(), P("dp")),
+        out_specs=(P(), P(), P()), check_vma=False))
+    batch = (jnp.zeros((2 * DP, 32, 32, 3), jnp.float32),
+             jnp.zeros((2 * DP,), jnp.int32))
+    return step, ({"params": params, "batch_stats": stats}, opt.init(params),
+                  batch)
+
+
+STEPS = {"transformer": _transformer_step, "resnet": _resnet_step}
+
+
+def _zero_step(wrap):
+    """`make_train_step`'s body with the optimizer's state sharded over the
+    axis, as `zero_sharded` needs it: each replica makes and keeps the
+    state of its shard."""
+    cfg = TransformerConfig.tiny()
+    # momentum's state is arrays only: a scalar step count has no shard
+    opt = wrap(optax.sgd(0.1, momentum=0.9))
+    loss_fn = functools.partial(transformer_loss, cfg=cfg)
+
+    def local_step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, lax.pmean(loss, "dp")
+
+    step = jax.jit(jax.shard_map(
+        local_step, mesh=_mesh(), in_specs=(P(), P("dp"), P("dp")),
+        out_specs=(P(), P("dp"), P()), check_vma=False))
+    init = jax.jit(jax.shard_map(opt.init, mesh=_mesh(), in_specs=P(),
+                                 out_specs=P("dp"), check_vma=False))
+    params = init_transformer(jax.random.PRNGKey(0), cfg)
+    return step, (params, init(params), jnp.zeros((2 * DP, 17), jnp.int32))
+
+
+WRAPPERS = {
+    "synchronous_sgd": lambda base: synchronous_sgd(base, "dp"),
+    "zero_sharded": lambda base: zero_sharded(base, DP, "dp"),
+    "synchronous_averaging": lambda base: synchronous_averaging(base, "dp"),
+    "adaptive_sgd": lambda base: adaptive_sgd(base, 3, "dp"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _table(model: str, wrapper: str = "synchronous_sgd") -> dict:
+    build = _zero_step if wrapper == "zero_sharded" else STEPS[model]
+    step, args = build(WRAPPERS[wrapper])
+    compiled = step.lower(*args).compile()
+    return {"table": device.scope_table(compiled), "text": compiled.as_text()}
+
+
+_parts = device.scope_parts
+
+
+def _has(table, scope, form):
+    """Some instruction lies under `scope` in the forward (`jvp(`, no
+    `transpose(`) or backward (`transpose(`) form. A scope at the top of
+    the differentiated function is written `jvp(scope)`; one further down
+    follows as a component of its own."""
+    for op_name in table.values():
+        parts = _parts(op_name)
+        under = any(p == scope or p in (f"jvp({scope})",
+                                        f"transpose(jvp({scope}))")
+                    for p in parts)
+        backward = any(p.startswith("transpose(") for p in parts)
+        if under and backward == (form == "backward"):
+            return True
+    return False
+
+
+@pytest.mark.parametrize("form", ["forward", "backward"])
+@pytest.mark.parametrize("model,scope", [
+    (m, s) for m, scopes in MODEL_SCOPES.items() for s in scopes])
+def test_model_scope_reaches_the_compiled_program(model, scope, form):
+    assert _has(_table(model)["table"], scope, form), (
+        f"no {form} instruction of the {model} step under {scope!r}")
+
+
+@pytest.mark.parametrize("model", sorted(STEPS))
+@pytest.mark.parametrize("scope", ["optimizer", "grad_allreduce",
+                                   "optimizer_update"])
+def test_optimizer_scopes_reach_the_compiled_program(model, scope):
+    table = _table(model)["table"]
+    assert any(scope in _parts(v) for v in table.values())
+
+
+@pytest.mark.parametrize("model", sorted(STEPS))
+def test_optimizer_scopes_nest_under_the_step_factorys(model):
+    for op_name in _table(model)["table"].values():
+        parts = _parts(op_name)
+        if "grad_allreduce" in parts or "optimizer_update" in parts:
+            assert "optimizer" in parts, op_name
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("model", sorted(STEPS))
+def test_grad_allreduce_holds_every_all_reduce_of_the_gradients(model):
+    """Read off the traced program, before XLA combines all-reduces (on the
+    CPU it makes one of the gradients', the batch statistics' and the
+    loss's, under the first's name): one `psum` a parameter leaf lies under
+    `grad_allreduce`, and the others (the loss's, ResNet's statistics')
+    do not."""
+    step, args = STEPS[model](WRAPPERS["synchronous_sgd"])
+    params = args[0]["params"] if model == "resnet" else args[0]
+    psums = [e for e in _eqns(step.trace(*args).jaxpr.jaxpr)
+             if e.primitive.name == "psum"]
+    under = [e for e in psums
+             if "grad_allreduce" in str(e.source_info.name_stack).split("/")]
+    assert sum(len(e.invars) for e in under) == len(jax.tree.leaves(params))
+    shapes = sorted(v.aval.shape for e in under for v in e.invars)
+    assert shapes == sorted(l.shape for l in jax.tree.leaves(params))
+    assert len(psums) > len(under)  # the loss's, at least, is outside
+
+
+def _all_reduces(text):
+    """The op_name of every all-reduce instruction of a compiled program."""
+    found = []
+    for line in text.splitlines():
+        if re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+ = .*? all-reduce(?:-start)?\(", line):
+            scope = re.search(r'op_name="([^"]*)"', line)
+            found.append(scope.group(1) if scope else "")
+    return found
+
+
+def test_the_compiled_all_reduce_keeps_the_scope():
+    """In the transformer step the gradients' all-reduce is the program's
+    first, so the combined instruction carries `grad_allreduce`: what the
+    benchmark's reader finds in the trace."""
+    reduces = _all_reduces(_table("transformer")["text"])
+    assert reduces and all("grad_allreduce" in _parts(r) for r in reduces)
+
+
+@pytest.mark.parametrize("model", sorted(STEPS))
+def test_few_instructions_carry_no_scope(model):
+    table = _table(model)["table"]
+    none = [v for v in table.values() if device.phase_of(v) == "unattributed"]
+    # parameters are named by their argument (`params['embed']`), not a scope
+    none = [v for v in none if "/" in v]
+    assert len(none) / len(table) < UNSCOPED_LIMIT[model], (
+        len(none), len(table), sorted(set(none))[:20])
+
+
+@pytest.mark.parametrize("model", sorted(STEPS))
+@pytest.mark.parametrize("phase", ["forward", "backward", "optimizer",
+                                   "all_reduce"])
+def test_every_phase_has_instructions(model, phase):
+    table = _table(model)["table"]
+    assert any(device.phase_of(v) == phase for v in table.values())
+
+
+@pytest.mark.parametrize("wrapper", ["zero_sharded", "synchronous_averaging",
+                                     "adaptive_sgd"])
+@pytest.mark.parametrize("scope", ["grad_allreduce", "optimizer_update"])
+def test_the_other_wrappers_use_the_same_two_names(wrapper, scope):
+    table = _table("transformer", wrapper)["table"]
+    assert any(scope in _parts(v) for v in table.values())
+    collectives = [v for v in table.values()
+                   if v.rsplit("/", 1)[-1] in ("psum", "psum_scatter",
+                                               "reduce_scatter")]
+    assert any("grad_allreduce" in _parts(v) for v in collectives)
+
+
+def _no_scopes(monkeypatch):
+    """`jax.named_scope` does nothing for the rest of the test."""
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+
+
+@pytest.mark.parametrize("model", sorted(STEPS))
+def test_scopes_are_metadata_only(model, monkeypatch):
+    """The StableHLO text without debug info, which PERF.md hashes, is byte
+    for byte the same with `jax.named_scope` patched to do nothing: no
+    metric can move."""
+    step, args = STEPS[model](WRAPPERS["synchronous_sgd"])
+    with_scopes = step.lower(*args).as_text()
+    _no_scopes(monkeypatch)
+    step, args = STEPS[model](WRAPPERS["synchronous_sgd"])
+    assert with_scopes == step.lower(*args).as_text()
+
+
+def test_the_patched_scope_is_really_off(monkeypatch):
+    """The comparison above compares two different traces: with the patch
+    the compiled program carries none of the transformer's names."""
+    _no_scopes(monkeypatch)
+    step, args = _transformer_step(WRAPPERS["synchronous_sgd"])
+    table = device.scope_table(step.lower(*args).compile())
+    assert not any("attn_core" in v or "grad_allreduce" in v
+                   for v in table.values())
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(local_step)/shard_map/jvp()/while/body/closed_call/attn/attn_core/dot_general",
+     ["jvp()", "attn", "attn_core"]),
+    ("jit(local_step)/shard_map/transpose(jvp(head_loss))/jit(log_softmax)/mul",
+     ["transpose(jvp(head_loss))"]),
+    ("jit(local_step)/shard_map/optimizer/grad_allreduce/psum",
+     ["optimizer", "grad_allreduce"]),
+    ("jit(local_step)/shard_map/psum", []),
+    ("params['embed']", []),
+    ("", []),
+])
+def test_scope_parts(op_name, want):
+    assert device.scope_parts(op_name) == want
+
+
+BLOCK = "jit(local_step)/shard_map/jvp()/while/body/closed_call"
+BACK = "jit(local_step)/shard_map/transpose(jvp())/while/body/closed_call"
+
+
+@pytest.mark.parametrize("op_name,phase", [
+    (f"{BACK}/attn/attn_core/bhqk,bhkd->bhqd/dot_general", "backward"),
+    (f"{BLOCK}/attn/attn_core/reduce_max", "forward"),
+    (f"{BLOCK}/ffn/dot_general", "forward"),
+    ("jit(local_step)/shard_map/optimizer/grad_allreduce/psum", "all_reduce"),
+    ("jit(local_step)/shard_map/optimizer/grad_allreduce/div", "all_reduce"),
+    ("jit(local_step)/shard_map/optimizer/optimizer_update/mul", "optimizer"),
+    ("jit(local_step)/optimizer/add", "optimizer"),
+    ("jit(step)/optimizer_update/sqrt", "optimizer"),
+    ("jit(local_step)/shard_map/jvp()/while", "forward"),
+    ("jit(local_step)/shard_map/transpose(jvp())/while", "backward"),
+    ("jit(local_step)/while", "unattributed"),  # a bare while
+    ("jit(local_step)/shard_map/psum", "unattributed"),  # the loss's
+    ("jit(local_step)/add", "unattributed"),
+    ("params['embed']", "unattributed"),
+    ("", "unattributed"),
+    (None, "unattributed"),
+    ("jit(local_step)/jvp(head_loss)/jit(log_softmax)/reduce_max", "forward"),
+    ("jit(local_step)/transpose(jvp(head_loss))/jit(take_along_axis)/scatter-add",
+     "backward"),
+    ("jit(local_step)/jvp(ResNet)/BottleneckBlock_12/BatchNorm_1/mul", "forward"),
+    ("jit(local_step)/transpose(jvp(ResNet))/bn_init/reduce_sum", "backward"),
+    # whole components only: a scope that merely contains a name of the rule
+    ("jit(local_step)/my_optimizer_update/mul", "forward"),
+])
+def test_the_phase_rule_case_by_case(op_name, phase):
+    assert device.phase_of(op_name) == phase
+
+
+def test_the_compile_cache_is_keyed_by_the_scopes():
+    """JAX's cache key leaves metadata out by default (it hashes the text
+    the test above shows to be the same), so an executable cached before a
+    scope changed would serve its old names; `enable_compile_cache` asks
+    for the key that holds them."""
+    from kungfu_tpu.parallel import chip
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = {k: getattr(jax.config, k)
+              for k in (flag, "jax_compilation_cache_dir")}
+    try:
+        jax.config.update(flag, False)
+        chip.enable_compile_cache()
+        assert getattr(jax.config, flag) is True
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)

@@ -14,9 +14,12 @@ import urllib.request
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "dying_elastic_agent.py")
-DEBUG_PORT = 38497
+PORTS = kfrun_ports()  # this xdist worker's block, not kfrun's defaults
+DEBUG_PORT = PORTS.spare(0)
 
 
 def _poll_postmortem(base_url, proc, timeout_s=240.0):
@@ -46,6 +49,7 @@ def test_sigkilled_worker_leaves_a_black_box(tmp_path):
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *PORTS.args,
             "-np", "3", "-H", "127.0.0.1:4",
             "-w", "-auto-recover", "30s",
             "-warm-spares", "0",
@@ -67,7 +71,7 @@ def test_sigkilled_worker_leaves_a_black_box(tmp_path):
             pytest.fail(
                 f"no postmortem appeared: {err}\nstdout:\n{out}\nstderr:\n{errout}"
             )
-        dead_peer = "127.0.0.1:38002"  # rank 2 of 3 on the 38000+ range
+        dead_peer = PORTS.worker(2)  # rank 2 of 3, in rank order from the base
         assert dead_peer in doc["peers"], doc
         pm = doc["peers"][dead_peer][-1]
         assert pm["death"] == "signal SIGKILL (-9)"
@@ -92,7 +96,7 @@ def test_sigkilled_worker_leaves_a_black_box(tmp_path):
     # the run itself still recovers and completes (size 2, progress carried)
     assert proc.returncode == 0, f"stdout:\n{out}\nstderr:\n{errout}"
     # the worker_postmortem audit event was recorded on the runner
-    assert "worker_postmortem recorded for 127.0.0.1:38002" in errout, errout
+    assert f"worker_postmortem recorded for {PORTS.worker(2)}" in errout, errout
 
     # -- durable surface: the run dir outlives the runner --
     pm_file = tmp_path / "postmortems.jsonl"

@@ -10,6 +10,8 @@ import sys
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "host_agent.py")
 
@@ -22,6 +24,7 @@ def run_kfrun(np_, strategy, extra_env=None, timeout=120):
     return subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", str(np_),
             "-H", f"127.0.0.1:{np_}",
             "-strategy", strategy,

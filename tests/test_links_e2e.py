@@ -14,9 +14,12 @@ import urllib.request
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "links_agent.py")
-DEBUG_PORT = 38498
+PORTS = kfrun_ports()  # this xdist worker's block, not kfrun's defaults
+DEBUG_PORT = PORTS.spare(0)
 
 
 def _poll_links(base_url, proc, np_, timeout_s=120.0):
@@ -55,6 +58,7 @@ def test_np4_link_matrix_end_to_end(tmp_path):
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *PORTS.args,
             "-np", str(np_), "-H", f"127.0.0.1:{np_}",
             "-w", "-debug-port", str(DEBUG_PORT), "-q",
             sys.executable, AGENT,

@@ -17,11 +17,14 @@ import urllib.request
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MEM_AGENT = os.path.join(REPO, "tests", "integration", "memory_agent.py")
 OOM_AGENT = os.path.join(REPO, "tests", "integration", "oom_agent.py")
-DEBUG_PORT = 38499
-OOM_DEBUG_PORT = 38496
+PORTS = kfrun_ports()  # this xdist worker's block, not kfrun's defaults
+DEBUG_PORT = PORTS.spare(0)
+OOM_DEBUG_PORT = PORTS.spare(1)
 
 
 def _fetch(base_url, path):
@@ -64,6 +67,7 @@ def test_np4_memory_plane_and_leak_watchdog(tmp_path):
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *PORTS.args,
             "-np", str(np_), "-H", f"127.0.0.1:{np_}",
             "-w", "-debug-port", str(DEBUG_PORT), "-q",
             sys.executable, MEM_AGENT,
@@ -72,7 +76,7 @@ def test_np4_memory_plane_and_leak_watchdog(tmp_path):
         text=True, cwd=REPO,
     )
     base_url = f"http://127.0.0.1:{DEBUG_PORT}"
-    leaker = f"127.0.0.1:{38000 + np_ - 1}"
+    leaker = PORTS.worker(np_ - 1)
     try:
         # -- every peer's decomposition, untracked honest and < 50% --
         def full_matrix():
@@ -171,6 +175,7 @@ def test_oom_near_fake_limit_harvests_suspected_postmortem(tmp_path):
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *PORTS.args,
             "-np", "3", "-H", "127.0.0.1:4",
             "-w", "-auto-recover", "30s",
             "-warm-spares", "0",
@@ -182,7 +187,7 @@ def test_oom_near_fake_limit_harvests_suspected_postmortem(tmp_path):
         text=True, cwd=REPO,
     )
     base_url = f"http://127.0.0.1:{OOM_DEBUG_PORT}"
-    dead_peer = "127.0.0.1:38002"
+    dead_peer = PORTS.worker(2)
     try:
         def harvested():
             doc = _fetch(base_url, "/cluster/postmortem")

@@ -13,6 +13,8 @@ import sys
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "protowatch_agent.py")
 
@@ -27,6 +29,7 @@ def _run(np_, extra_env=None, timeout=150):
     return subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,
             "-np", str(np_), "-H", f"127.0.0.1:{np_}",
             sys.executable, AGENT,
         ],
@@ -69,7 +72,7 @@ def test_np4_live_bench_clean_under_sentinel():
     Runs SHAPED with a lockstep re-plan round (ISSUE 14): the shaped
     harness + vote/exchange/adopt collectives must stay silent too."""
     r = _run(4, extra_env={
-        "KF_SHAPE_LINKS": "127.0.0.1:38001>127.0.0.1:38002=lat:5",
+        "KF_SHAPE_LINKS": f"{kfrun_ports().worker(1)}>{kfrun_ports().worker(2)}=lat:5",
         "KF_CONFIG_REPLAN": "auto",
     })
     out = r.stdout + r.stderr

@@ -264,7 +264,7 @@ def test_fused_group_all_reduce_two_peers():
             np.testing.assert_allclose(got_b, w, rtol=1e-6)
         # hot-path tracing is live: any collective leaves spans behind
         # (VERDICT r4 5.1 — a tracer nothing traces with is shelf-ware)
-        from kungfu_tpu.utils import trace
+        from kungfu_tpu.telemetry import tracing as trace
 
         names = {n for n, _, _ in trace.events()}
         assert "transport.send" in names

@@ -96,7 +96,7 @@ def test_run_activated_python_script(tmp_path, capfd):
 
 
 def test_tracer_spans():
-    from kungfu_tpu.utils import trace
+    from kungfu_tpu.telemetry import tracing as trace
 
     trace.clear()
     with trace.span("t.a"):

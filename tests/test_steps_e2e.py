@@ -18,15 +18,18 @@ import urllib.request
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "steps_agent.py")
-DEBUG_PORT = 38499
+PORTS = kfrun_ports()  # this xdist worker's block, not kfrun's defaults
+DEBUG_PORT = PORTS.spare(0)
 
-# kfrun's default slot assignment: first-fit over the 38000+ port range,
-# so np=4 on one host is 38000..38003 in rank order. The injected edge
-# is rank 1 -> rank 2 — a real ring edge of the segmented walk.
-SLOW_SRC = "127.0.0.1:38001"
-SLOW_DST = "127.0.0.1:38002"
+# kfrun's slot assignment: first-fit over the port range, so np=4 on one
+# host is base..base+3 in rank order. The injected edge is rank 1 ->
+# rank 2 — a real ring edge of the segmented walk.
+SLOW_SRC = PORTS.worker(1)
+SLOW_DST = PORTS.worker(2)
 
 
 def _poll_steps(base_url, proc, timeout_s=120.0):
@@ -73,6 +76,7 @@ def test_np4_steps_end_to_end(tmp_path):
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *PORTS.args,
             "-np", str(np_), "-H", f"127.0.0.1:{np_}",
             "-w", "-debug-port", str(DEBUG_PORT), "-q",
             sys.executable, AGENT,

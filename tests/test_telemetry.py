@@ -211,8 +211,9 @@ class TestTracing:
         assert any(e["name"] == "t_io" for e in doc["traceEvents"])
 
     def test_legacy_shim_api(self):
-        """utils.trace call sites keep working and feed the same buffer."""
-        from kungfu_tpu.utils import trace as shim
+        """The call shapes of the old scoped tracer (record, events,
+        summary_ms), under the one name its call sites import now."""
+        from kungfu_tpu.telemetry import tracing as shim
 
         shim.clear()
         shim.record("t_legacy", 0.25)
