@@ -18,6 +18,7 @@ the framework's flagship workload for the BERT-config benchmark
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -104,13 +105,34 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp") -> Dict:
     }
 
 
+# What the backward pass keeps (PERF.md, PR 25). The layer scan stacks every
+# residual of its body once a layer, so each piece below whose residuals are
+# cheap functions of something smaller that is saved anyway says so itself
+# with `jax.checkpoint`: it keeps its inputs and recomputes the rest where
+# the backward pass wants it. One HBM byte costs the v5e 240 operations, so
+# an S x S probability array (12 bytes an element, written and read) is
+# worth 2,900 operations against the 128 of a second QK^T, at every length.
+# `prevent_cse=False`: inside a scan body the barrier is unnecessary and
+# costs fusions.
+_recompute = functools.partial(jax.checkpoint, prevent_cse=False)
+
+
+@_recompute
 def _rmsnorm(x, scale, eps=1e-6):
+    """Keeps x and scale; the f32 upcast, the variance and the normalised
+    output are recomputed."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
 
 
+@_recompute
 def _full_attention_core(q, k, v):
-    """(B, H, S, hd) q/k/v -> causal attention context, same shape."""
+    """(B, H, S, hd) q/k/v -> causal attention context, same shape.
+
+    Keeps q, k, v; scores, mask, the f32 softmax and its cast are
+    recomputed. The checkpoint is this core's own, not `_attention`'s or
+    `_block`'s: a core plugged from outside (the ring, flash attention's
+    `custom_vjp`) keeps its own residuals and is never run twice."""
     hd = q.shape[-1]
     S = q.shape[2]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(hd).astype(q.dtype)
@@ -118,6 +140,15 @@ def _full_attention_core(q, k, v):
     scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+@_recompute
+def _gelu_out(pre, w_out):
+    """gelu(pre) @ w_out. Keeps the pre-activation and w_out; the
+    tanh-gelu, its four temporaries and with them the matmul's operand are
+    recomputed. Saving the gelu's output for that matmul instead was 0.15
+    ms a step slower at bert_base's size and 0.6 GB larger (PERF.md, PR 25)."""
+    return jax.nn.gelu(pre) @ w_out
 
 
 def _attention(x, wqkv, wo, cfg: TransformerConfig, core=_full_attention_core):
@@ -144,9 +175,8 @@ def _block(x, layer, cfg: TransformerConfig, core=_full_attention_core):
                            layer["wqkv"].astype(dt), layer["wo"].astype(dt),
                            cfg, core=core)
     with jax.named_scope("ffn"):
-        h = _rmsnorm(x, layer["ln2_scale"])
-        h = jax.nn.gelu(h @ layer["w_in"].astype(dt))
-        return x + h @ layer["w_out"].astype(dt)
+        pre = _rmsnorm(x, layer["ln2_scale"]) @ layer["w_in"].astype(dt)
+        return x + _gelu_out(pre, layer["w_out"].astype(dt))
 
 
 def lm_head_loss(params, x, targets, cfg: TransformerConfig):

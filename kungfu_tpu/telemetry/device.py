@@ -42,11 +42,12 @@ PHASES = ("forward", "backward", "optimizer", "all_reduce", "unattributed")
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
-# jit(f), shard_map, while/body/closed_call and the primitive at the path's
-# end are how JAX got there, not where in the program the op belongs
+# jit(f), shard_map, while/body/closed_call, checkpoint/rematted_computation
+# and the primitive at the path's end are how JAX got there, not where in
+# the program the op belongs
 _PLUMBING = re.compile(r"^(jit\(.*\)|pjit|shard_map|while|body|cond|branch_\d+|"
-                       r"closed_call|checkpoint|remat|custom_jvp_call|"
-                       r"custom_vjp_call.*)$")
+                       r"closed_call|checkpoint|remat|rematted_computation|"
+                       r"custom_jvp_call|custom_vjp_call.*)$")
 
 
 @contextlib.contextmanager
@@ -90,6 +91,45 @@ def scope_table(compiled) -> Dict[str, str]:
         if name and scope:
             table[name.group(1)] = scope.group(1)
     return table
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for sub in value if isinstance(value, (tuple, list)) else (value,):
+            inner = getattr(sub, "jaxpr", sub)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def saved_bytes(fn, *abstract_args) -> List[Tuple[Tuple[int, ...], str, int]]:
+    """(shape, dtype, bytes) of every array that a forward `lax.scan` of
+    `value_and_grad(fn)` stacks for a reversed one: what the layer scan of
+    a model saves for its backward pass, largest first. Read off
+    `jax.make_jaxpr` over `jax.ShapeDtypeStruct`s, so nothing runs and
+    nothing is compiled: a budget a test can hold on the CPU (PERF.md,
+    PR 25)."""
+    import jax
+
+    saved = []
+
+    def walk(jaxpr):
+        read_reversed = set()
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan" and eqn.params["reverse"]:
+                skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+                read_reversed.update(map(id, eqn.invars[skip:]))
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan" and not eqn.params["reverse"]:
+                for v in eqn.outvars[eqn.params["num_carry"]:]:
+                    if id(v) in read_reversed:
+                        a = v.aval
+                        saved.append((tuple(a.shape), a.dtype.name,
+                                      a.size * a.dtype.itemsize))
+            for inner in _sub_jaxprs(eqn):
+                walk(inner)
+
+    walk(jax.make_jaxpr(jax.value_and_grad(fn))(*abstract_args).jaxpr)
+    return sorted(saved, key=lambda s: -s[2])
 
 
 def scope_parts(op_name: str) -> List[str]:

@@ -286,6 +286,19 @@ def test_the_patched_scope_is_really_off(monkeypatch):
     ("jit(local_step)/shard_map/psum", []),
     ("params['embed']", []),
     ("", []),
+    # what a `jax.checkpoint` inside a scope adds (PR 25): the wrapper and,
+    # for what the backward pass recomputes, `rematted_computation`, with
+    # the scope path written a second time in front of them
+    ("jit(local_step)/transpose(jvp())/while/body/closed_call/checkpoint/attn/attn_core/bhqd,bhkd->bhqk/dot_general",
+     ["transpose(jvp())", "attn", "attn_core", "bhqd,bhkd->bhqk"]),
+    ("jit(local_step)/shard_map/transpose(jvp())/while/body/closed_call/attn/attn_core/attn/attn_core/checkpoint/rematted_computation/reduce_max",
+     ["transpose(jvp())", "attn", "attn_core", "attn", "attn_core"]),
+    ("jit(local_step)/shard_map/transpose(jvp())/while/body/closed_call/ffn/ffn/checkpoint/rematted_computation/tanh",
+     ["transpose(jvp())", "ffn", "ffn"]),
+    ("jit(local_step)/shard_map/transpose(jvp(head_loss))/jvp(head_loss)/checkpoint/rematted_computation/rsqrt",
+     ["transpose(jvp(head_loss))", "jvp(head_loss)"]),
+    ("jit(local_step)/shard_map/jvp()/while/body/closed_call/attn/attn/checkpoint/rsqrt",
+     ["jvp()", "attn", "attn"]),
 ])
 def test_scope_parts(op_name, want):
     assert device.scope_parts(op_name) == want
@@ -319,9 +332,47 @@ BACK = "jit(local_step)/shard_map/transpose(jvp())/while/body/closed_call"
     ("jit(local_step)/transpose(jvp(ResNet))/bn_init/reduce_sum", "backward"),
     # whole components only: a scope that merely contains a name of the rule
     ("jit(local_step)/my_optimizer_update/mul", "forward"),
+    # a recomputed op runs in the backward pass and counts there (PR 25)
+    ("jit(local_step)/transpose(jvp())/while/body/closed_call/checkpoint/attn/attn_core/bhqd,bhkd->bhqk/dot_general",
+     "backward"),
+    (f"{BACK}/attn/attn_core/attn/attn_core/checkpoint/rematted_computation/bhqd,bhkd->bhqk/dot_general",
+     "backward"),
+    (f"{BACK}/attn/attn_core/attn/attn_core/checkpoint/rematted_computation/exp", "backward"),
+    (f"{BACK}/ffn/ffn/checkpoint/rematted_computation/tanh", "backward"),
+    (f"{BACK}/attn/attn/checkpoint/rematted_computation/rsqrt", "backward"),
+    ("jit(local_step)/shard_map/transpose(jvp(head_loss))/jvp(head_loss)/checkpoint/rematted_computation/rsqrt",
+     "backward"),
+    # the same pieces where they first run
+    (f"{BLOCK}/attn/attn_core/exp", "forward"),
+    (f"{BLOCK}/ffn/ffn/checkpoint/tanh", "forward"),
 ])
 def test_the_phase_rule_case_by_case(op_name, phase):
     assert device.phase_of(op_name) == phase
+
+
+@pytest.mark.parametrize("scope", ["attn_core", "attn", "ffn"])
+def test_what_the_backward_pass_recomputes_keeps_its_scope(scope):
+    """The dense core, the norms (`attn`, `ffn`) and the gelu (`ffn`) are
+    recomputed in the backward pass (PR 25): the compiled step holds their
+    `rematted_computation`, every instruction of it sorts under backward,
+    and its scope is still among its parts."""
+    # `jit(`: a reducer (the `add` of a reduce_sum) is a computation of its
+    # own and carries the path from the scope on, without the loop's
+    again = [v for v in _table("transformer")["table"].values()
+             if v.startswith("jit(") and "rematted_computation" in v.split("/")
+             and scope == _parts(v)[-1]]
+    assert again
+    assert {device.phase_of(v) for v in again} == {"backward"}
+
+
+def test_the_dense_core_has_a_forward_and_a_recomputed_matmul():
+    """QK^T is in the program three times: forward, recomputed, and
+    transposed for the gradients of q and k."""
+    table = _table("transformer")["table"]
+    qk = [v for v in table.values() if v.endswith("bhqd,bhkd->bhqk/dot_general")]
+    assert any(device.phase_of(v) == "forward" for v in qk)
+    assert any("rematted_computation" in v and device.phase_of(v) == "backward"
+               for v in qk)
 
 
 def test_the_compile_cache_is_keyed_by_the_scopes():
