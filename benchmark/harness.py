@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from benchmark import manifest
+from benchmark import manifest, trace_reduce
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
@@ -221,7 +221,10 @@ def reference_check(family, config: dict, seed: int, final_state,
     reference, on a seeded sample and the run's initial parameters (made
     again from the seed: the run's own were donated to its first step),
     to the family's tolerances; and the program's declared precision,
-    read from the same traced program and the run's final state."""
+    read from the same traced program and the run's final state, of which
+    shapes and types are enough (`jax.ShapeDtypeStruct` trees). Three
+    copies of the parameters are alive at the most here: the initial
+    state, the program's gradients and the reference's."""
     state = family.init(config, seed)
     sample = family.host_batch(config, seed, SAMPLE_INDEX,
                                family.REFERENCE_SAMPLES)
@@ -230,6 +233,7 @@ def reference_check(family, config: dict, seed: int, final_state,
                               final_state, opt_state)
     loss, grads = traced.lower().compile()(state, sample)
     ref_loss, ref_grads = family.reference_loss_and_grads(config, state, sample)
+    del state
     loss, ref_loss = float(loss), float(ref_loss)
     return {
         "loss": loss,
@@ -315,23 +319,31 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
         finally:
             jax.profiler.stop_trace()
         window["compiles"] += events[COMPILE_EVENT] - compiles_before
+    # which scope of the program each device op belongs to, for the
+    # per-layer metrics that split the traced steps by it
+    scopes = trace_reduce.scope_table(step.as_text()) if trace_dir else None
 
     # checks, outside the window
     before = [first_loss] + warm["losses"] + probe["losses"]
     k = min(len(pool), len(before), n)
     losses = window["losses"] + (traced_window["losses"] if traced_window else [])
     failed = sum(1 for l in losses if not math.isfinite(l))
-    leaves = jax.tree.leaves(state)
     checks = {
         "no_compile_in_window": window["compiles"] == 0,
         "no_step_failed": failed == 0,
         # one pass over the pool at the start against one at the end
         "loss_fell": float(np.mean(losses[-k:])) < float(np.mean(before[:k])),
-        "state_spans_mesh": all(
-            len(l.sharding.device_set) == chips for l in leaves),
+        "state_spans_mesh": all(len(l.sharding.device_set) == chips
+                                for l in jax.tree.leaves(state)),
         "one_process_a_worker": jax.process_count() == world.size,
         "workers_agree_on_state": world.agree_digest(state),
     }
+    # The run's arrays end here. The reference check reads only the shapes
+    # and types of the final state and the optimizer's, and holds three
+    # copies of the parameters of its own; with these four beside them a
+    # configuration that fills the chip in its window could not be checked.
+    state, opt_state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (state, opt_state))
     # The reporting rank alone compares with the reference, on its own
     # chip: in a joined world only rank 0 writes the compile cache, so the
     # other workers would compile both programs again in every run (68 s
@@ -371,6 +383,7 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
         "checks": checks,
         "correct": all(checks.values()),
         "reference": reference,
+        "scopes": scopes,
         "cache": {"hits": events[CACHE_HIT_EVENT],
                   "misses": events[CACHE_MISS_EVENT]},
         "versions": {"jax": jax.__version__},

@@ -15,7 +15,10 @@ object, the *reduced trace*:
      "lines": {plane: {line name: events}}}              what the trace held
 
 Everything else here is arithmetic on that object, with no jax, so the
-parent of a run and the tests use it as it is.
+parent of a run and the tests use it as it is. The traced run's record also
+holds the step program's scope table (`scope_table`, read off
+`compiled.as_text()` in `harness.measure`): `scope_ms` splits each step's
+device time by it.
 
 How the TPU's trace is laid out (read by hand, chip run of PR 23, jax 0.9.0,
 libtpu 0.0.34): each chip is a plane "/device:TPU:<n>" with the lines
@@ -276,6 +279,99 @@ def all_reduce_segments(c: dict) -> tuple:
     mine.extend([a, b] for name, a, b in c.get("async", [])
                 if is_all_reduce(name, kinds))
     return union(mine), others
+
+
+# -- scopes: which part of the program an op belongs to ----------------------
+# The benchmark's own copy of what `kungfu_tpu.telemetry.device` does for an
+# operator: later PRs change the program, not the yardstick.
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+# jit(f), shard_map, while/body/closed_call and checkpoint/rematted_computation
+# are how JAX got to an op, not where in the program it belongs
+_PLUMBING = re.compile(r"(jit\(.*\)|pjit|shard_map|while|body|cond|branch_\d+|"
+                       r"closed_call|checkpoint|remat|rematted_computation|"
+                       r"custom_jvp_call|custom_vjp_call.*)\Z")
+
+
+def scope_table(hlo_text: str) -> dict:
+    """{instruction name: op_name} of a compiled program's text
+    (`compiled.as_text()`): the scope path JAX wrote on each instruction,
+    `jit(local_step)/shard_map/transpose(jvp())/while/body/closed_call/attn/attn_core/dot_general`.
+    An instruction the compiler made itself (a copy, a bitcast) has none
+    and is left out."""
+    table = {}
+    for line in hlo_text.splitlines():
+        name = _INSTRUCTION.match(line)
+        scope = _OP_NAME.search(line)
+        if name and scope:
+            table[name.group(1)] = scope.group(1)
+    return table
+
+
+def scope_parts(op_name: str) -> list:
+    """The components of an `op_name` that say where in the program an
+    instruction belongs: the plumbing and, at the path's end, the
+    primitive's own name taken out."""
+    return [p for p in op_name.split("/")[:-1] if not _PLUMBING.match(p)]
+
+
+def scope_names(parts: list) -> set:
+    """Every word of a scope path, the transforms' wrappers opened:
+    `transpose(jvp(head_loss))` holds `head_loss` as `attn/attn_core`
+    holds `attn_core`. JAX writes some scopes into the wrapper
+    (`jvp(head_loss)`, `jvp(embed)`, `jvp(ResNet)`) and others as
+    components of their own (`jvp()/while/body/closed_call/attn`,
+    `jvp(ResNet)/BottleneckBlock_0`): read off the three cells' programs."""
+    return {word for p in parts for word in re.split(r"[()]", p) if word}
+
+
+def phase_of(parts: list) -> str:
+    """The phase of an op that is no all-reduce itself, by the components
+    of its scope path (the rule of PR 24, written once): `optimizer`,
+    `optimizer_update` or `grad_allreduce` -> optimizer (what `pmean`
+    leaves beside its collective, a division, is the optimizer wrapper's
+    arithmetic); else any `transpose(` -> backward; else any other scope,
+    `jvp(` or the model's own -> forward; else unattributed."""
+    if any(p in ("optimizer", "optimizer_update", "grad_allreduce")
+           for p in parts):
+        return "optimizer"
+    if any(p.startswith("transpose(") for p in parts):
+        return "backward"
+    return "forward" if parts else "unattributed"
+
+
+def scope_ms(record: dict, trace, wanted):
+    """Milliseconds a step, the median over the traced steps, of the own
+    time (`self_segments`: a `while` less its body) of the traced chip's
+    ops for which `wanted(phase, names)` holds (`phase_of` and
+    `scope_names` of the op's scope path); `record["scopes"]` is the
+    step program's `scope_table`. An all-reduce, found by its HLO
+    operation whatever its scope, is `allreduce_ms`' and is in no scope's
+    time, so forward, backward, optimizer, unattributed and the
+    all-reduces together are every op's own time once. An op the table
+    does not name is unattributed. None without a table, a traced chip or
+    one such op."""
+    scopes = record.get("scopes")
+    if not scopes or not trace or not trace["chips"]:
+        return None
+    c = chip(trace)
+    kinds = c.get("kinds", {})
+    verdict = {}  # by name: a step's few hundred ops run in every step
+
+    def is_mine(name):
+        if is_all_reduce(name, kinds):
+            return False
+        parts = scope_parts(scopes.get(name, ""))
+        return wanted(phase_of(parts), scope_names(parts))
+
+    mine = []
+    for name, segments in self_segments(c["ops"]):
+        if name not in verdict:
+            verdict[name] = is_mine(name)
+        if verdict[name]:
+            mine.extend(segments)
+    return median(per_step(c, mine)) / 1e6 if mine else None
 
 
 def percentile(values, q: float):

@@ -281,17 +281,22 @@ def events():
     return harness.EventCounter()
 
 
+def _tiny_cell(workload="bert_base.ssgd_1chip", mesh=None):
+    from kungfu_tpu.parallel import make_mesh
+
+    mesh = mesh or {"dp": 4}
+    cell = mf.cell(mf.load(), workload)
+    cell["config"].update(TINY[cell["config_name"]])
+    cell["traffic"].update(per_chip_batch=2, mesh=mesh)
+    return cell, make_mesh(mesh, devices=jax.devices()[:4])
+
+
 @pytest.mark.parametrize("workload", ["bert_base.ssgd_1chip", "resnet50.ssgd_1chip"])
 def test_measure_at_tiny_size_on_four_cpu_devices(workload, events):
     """The whole of `measure` — state, pool, first step, warm-up, probe,
     window, checks — on a dp = 4 mesh of virtual CPU devices."""
-    from kungfu_tpu.parallel import make_mesh
-
     m = mf.load()
-    cell = mf.cell(m, workload)
-    cell["config"].update(TINY[cell["config_name"]])
-    cell["traffic"].update(per_chip_batch=2, mesh={"dp": 4})
-    mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
+    cell, mesh = _tiny_cell(workload)
     record = harness.measure(cell, mesh, OneProcess(),
                              {"bf16_flops": 197e12}, seed=3, seconds=0.3,
                              trace_dir=None, events=events,
@@ -318,18 +323,70 @@ def test_measure_at_tiny_size_on_four_cpu_devices(workload, events):
         end_to_end.result_line(record, None, m)
 
 
+class KeepsTheState(OneProcess):
+    """A world that holds on to the run's final state, as `measure` itself
+    did until PR 26."""
+
+    def agree_digest(self, state):
+        self.kept = state
+        return True
+
+
+@pytest.mark.parametrize("world,copies", [(OneProcess, 3), (KeepsTheState, 4)])
+def test_the_reference_check_holds_three_copies_of_the_parameters(
+        world, copies, events, monkeypatch):
+    """What is alive on the devices when the reference has computed its
+    gradients: the initial state made again from the seed, the program's
+    gradients and the reference's, X bytes each, and beside them only the
+    sample, the two losses and the first placed batch. The run's final
+    state (X) and AdamW's (2X) are gone by then: with them it was 6X, which
+    no configuration that fills a chip in its window can hold beside a
+    float32 reference. The second case shows that the count sees it: one
+    kept copy of the state makes four."""
+    from benchmark.families import transformer
+
+    seen = {}
+    reference = transformer.reference_loss_and_grads
+
+    def counting(config, state, sample):
+        out = reference(config, state, sample)
+        jax.block_until_ready(out)
+        seen["x"] = sum(a.nbytes for a in jax.tree.leaves(state))
+        seen["alive"] = sum(a.nbytes for a in jax.live_arrays())
+        return out
+
+    monkeypatch.setattr(transformer, "reference_loss_and_grads", counting)
+    cell, mesh = _tiny_cell()
+    record = harness.measure(cell, mesh, world(), {"bf16_flops": 197e12},
+                             seed=3, seconds=0.1, trace_dir=None, events=events,
+                             t_command=time.time())
+    assert record["correct"], record["checks"]
+    x = seen["x"]
+    assert x > 300_000  # the tiny model's parameters, float32
+    small = x // 10  # sample, losses, one placed batch: some kilobytes
+    assert copies * x <= seen["alive"] <= copies * x + small, seen["alive"] / x
+
+
+def test_measure_takes_a_mesh_of_two_axes(events):
+    """{"dp": 2, "tp": 2}: the batch is split over `BATCH_AXIS` and the state
+    spans all four devices; nothing in `measure` names the mesh's axes."""
+    cell, mesh = _tiny_cell(mesh={"dp": 2, "tp": 2})
+    record = harness.measure(cell, mesh, OneProcess(), {"bf16_flops": 197e12},
+                             seed=3, seconds=0.1, trace_dir=None, events=events,
+                             t_command=time.time())
+    assert record["correct"], (record["checks"], record["reference"])
+    assert record["chips"] == 4 and record["samples_per_step"] == 8
+    assert record["scopes"] is None  # the scope table is the traced run's
+
+
 def test_the_traced_run_measures_the_window_and_then_profiles(events, tmp_path):
     """`--trace 1`: the same untraced window first, for the stalls' share
     and the input's wait, then TRACE_STEPS steps under the profiler, which
     the trace's reduction is given."""
     from benchmark import trace_reduce
-    from kungfu_tpu.parallel import make_mesh
 
     m = mf.load()
-    cell = mf.cell(m, "bert_base.ssgd_1chip")
-    cell["config"].update(TINY["bert_base"])
-    cell["traffic"].update(per_chip_batch=2, mesh={"dp": 4})
-    mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
+    cell, mesh = _tiny_cell()
 
     class Asked(OneProcess):
         def agree_steps(self, n):
@@ -356,3 +413,14 @@ def test_the_traced_run_measures_the_window_and_then_profiles(events, tmp_path):
     found = end_to_end.layer_values(record, None, names)
     assert found["stall_share_pct"] >= 0.0 and found["input_wait_ms_p50"] > 0.0
     json.dumps(record)
+    # the traced record names each instruction's scope, for the per-layer
+    # metrics that split the step by it: every scope of the program's
+    # vocabulary is on some instruction, and each phase is there
+    names = [trace_reduce.scope_names(trace_reduce.scope_parts(op))
+             for op in record["scopes"].values()]
+    for scope in ("embed", "attn", "attn_core", "ffn", "head_loss", "optimizer",
+                  "grad_allreduce", "optimizer_update"):
+        assert any(scope in n for n in names), scope
+    phases = {trace_reduce.phase_of(trace_reduce.scope_parts(op))
+              for op in record["scopes"].values()}
+    assert phases == {"forward", "backward", "optimizer", "unattributed"}

@@ -3,8 +3,11 @@ rule of the contract that can be checked without a chip refuses its fault —
 first of all the one PR 22 was refused for."""
 
 import copy
+import importlib.util
 import json
+import math
 import os
+import shutil
 import subprocess
 import sys
 
@@ -125,9 +128,86 @@ def test_check_refuses(sound, fault):
     assert any(says in f for f in faults), (fault, faults)
 
 
-def test_one_four_chip_cell_is_always_allowed(sound):
-    assert sum(w["chips"] == 4 for w in sound["workloads"]) == 1
-    assert len(sound["workloads"]) < 8  # a quarter, rounded down, is 0
+THIRD_FAMILY = '''"""A third family, as a `model_config` PR would add one: its own keys in
+its configuration file, and the entry points the harness calls."""
+
+REFERENCE_SAMPLES = 1
+LOSS_RTOL = 1e-4
+GRAD_RTOL = 1e-2
+
+
+def init(cfg, seed): ...
+def loss_fn(cfg): ...
+def trainable(state): ...
+def head_width(cfg): ...
+def program_loss_and_grads(cfg): ...
+def reference_loss_and_grads(cfg, state, batch): ...
+def host_batch(cfg, seed, i, n): ...
+def flops_per_sample(cfg): ...
+'''
+
+
+@pytest.fixture(scope="module")
+def scratch(tmp_path_factory):
+    """A copy of the benchmark in a scratch repo, with what the next PRs
+    bring as files alone: a third family with a configuration of other keys
+    than the transformer's seven, a traffic mix over a mesh of two axes
+    under a third launcher, a per-layer metric of the new cells' own, and
+    24 traffic files more, so that a manifest of any size up to the
+    contract's 24 cells can be built on it. -> (repo, bench_dir)."""
+    repo = tmp_path_factory.mktemp("scratch_repo")
+    bench = repo / "benchmark"
+    shutil.copytree(mf.BENCH_DIR, bench, ignore=shutil.ignore_patterns(
+        "out", "__pycache__", "testdata"))
+    (repo / "tests" / "benchmark").mkdir(parents=True)
+    (bench / "families" / "sparse_experts.py").write_text(THIRD_FAMILY)
+    (bench / "configs" / "third.json").write_text(json.dumps({
+        "family": "sparse_experts", "source": "https://example.org/third/config.json",
+        "hidden_size": 2048, "num_experts": 64, "num_experts_per_tok": 8,
+        "rope_theta": 10000.0, "param_dtype": "float32",
+        "compute_dtype": "bfloat16", "head_dtype": "float32", "reduced": []}))
+    body = mf._read_json("traffic", "ssgd_1chip.json")
+    shutil.copy(bench / "launchers" / "none.py", bench / "launchers" / "one_host.py")
+    (bench / "traffic" / "dp2tp2_4chip.json").write_text(json.dumps(
+        dict(body, mesh={"dp": 2, "tp": 2}, launcher="one_host")))
+    shutil.copy(bench / "layer_metrics" / "fwd_ms.py",
+                bench / "layer_metrics" / "fwd_ms.third.py")
+    for i in range(24):
+        (bench / "traffic" / f"mix_{i:02d}.json").write_text(json.dumps(body))
+    return str(repo), str(bench)
+
+
+def _manifest_of(sound, cells, four):
+    """The tree's manifest with `cells` cells, the first `four` of them on
+    four chips, over the scratch tree's 24 traffic files; every
+    configuration is used."""
+    m = copy.deepcopy(sound)
+    configs = [c["name"] for c in m["configs"]]
+    m["workloads"] = [
+        {"name": f"{configs[i % len(configs)]}.mix_{i:02d}",
+         "config": configs[i % len(configs)], "traffic": f"mix_{i:02d}",
+         "chips": 4 if i < four else 1, "why": "a cell of a manifest built in a test"}
+        for i in range(cells)]
+    for metric in m["end_to_end"] + m["per_layer"]:
+        metric.pop("workloads", None)
+    return m
+
+
+@pytest.mark.parametrize("cells,four,sound_", [
+    (3, 1, True), (3, 2, False), (4, 1, True), (7, 1, True), (7, 2, False),
+    (8, 2, True), (8, 3, False), (24, 6, True), (24, 7, False)])
+def test_one_four_chip_cell_is_always_allowed(sound, scratch, cells, four, sound_):
+    """A quarter of the cells, rounded down, may ask for four chips, and
+    one always may: asked of `manifest.check` about manifests built here,
+    whatever the tree's own manifest holds today."""
+    repo, bench = scratch
+    faults = mf.check(_manifest_of(sound, cells, four), bench, repo)
+    if sound_:
+        assert faults == []
+    else:
+        allowed = max(1, math.floor(cells / 4))
+        assert faults == [f"{four} of {cells} cells ask for four chips; at "
+                          f"most {allowed} may"]
 
 
 def _line(traced):
@@ -274,24 +354,121 @@ def test_plugin_names_are_names():
         mf.plugin("launchers", "../run")
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in mf.load()["workloads"]])
-def test_every_cell_finds_its_files(sound, cell):
-    c = mf.cell(sound, cell)
-    assert c["config"]["family"] in ("transformer", "resnet")
-    assert c["traffic"]["launcher"] in ("none", "kfrun")
-    assert c["traffic"]["mesh"] == {"dp": c["chips"]}
+def _holds_what_the_harness_needs(c: dict, find) -> None:
+    """What `child.py` and `harness.measure` need of a cell's files, and
+    nothing about which families, launchers or meshes exist today.
+    `find(kind, name)` is the module `<kind>/<name>.py`."""
     json.dumps(c)  # plain data all the way down
-    # and everything the traffic file names is there, with its entry points
-    found = {kind: mf.plugin(kind, named(c["traffic"]))
+    mesh = c["traffic"]["mesh"]  # -> parallel.make_mesh: {axis: size}
+    assert mesh and all(isinstance(k, str) and isinstance(v, int) and v >= 1
+                        for k, v in mesh.items()), mesh
+    assert math.prod(mesh.values()) == c["chips"]
+    assert c["traffic"]["per_chip_batch"] >= 1 and c["traffic"]["pool"] >= 1
+    # everything the traffic file names is there, with its entry points
+    found = {kind: find(kind, named(c["traffic"]))
              for kind, named in mf.TRAFFIC_PLUGINS.items()}
     assert callable(found["launchers"].argv) and callable(found["launchers"].join)
     assert callable(found["steps"].build) and callable(found["steps"].place)
-    assert found["steps"].BATCH_AXIS == "dp"
+    assert found["steps"].BATCH_AXIS in mesh
     assert callable(found["optimizers"].make) and callable(found["placements"].make)
-    family = mf.plugin("families", c["config"]["family"])
+    family = find("families", c["config"]["family"])
     for name in ("init", "trainable", "host_batch", "flops_per_sample",
                  "head_width", "program_loss_and_grads",
                  "reference_loss_and_grads"):
         assert callable(getattr(family, name)), name
     assert hasattr(family, "loss_fn") != hasattr(family, "local_step")
+    assert family.REFERENCE_SAMPLES >= 1
     assert 0 < family.LOSS_RTOL < family.GRAD_RTOL < 0.1
+    # what `precision_faults` holds the program to: types jax knows
+    import jax.numpy as jnp
+
+    for key in ("param_dtype", "head_dtype"):
+        assert jnp.issubdtype(jnp.dtype(c["config"][key]), jnp.floating), key
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in mf.load()["workloads"]])
+def test_every_cell_finds_its_files(sound, cell):
+    _holds_what_the_harness_needs(mf.cell(sound, cell), mf.plugin)
+
+
+def _module_at(bench: str):
+    def find(kind, name):
+        spec = importlib.util.spec_from_file_location(
+            f"scratch_{kind}_{name}", os.path.join(bench, kind, name + ".py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        return module
+    return find
+
+
+def _eight_cells(sound):
+    """The tree's cells and what R1 and R7 would add as files alone: a
+    third family on one chip and on a dp x tp mesh of four, eight cells,
+    two of them on four chips; and a per-layer metric for the new cells
+    only."""
+    m = copy.deepcopy(sound)
+    m["configs"].append({
+        "name": "third", "source": "https://example.org/third/config.json",
+        "file": "benchmark/configs/third.json", "reduced": [],
+        "why": "a third family: routed experts, rotary positions"})
+    for config, traffic, chips in [
+            ("third", "ssgd_1chip", 1), ("third", "dp2tp2_4chip", 4),
+            ("third", "ssgd_1chip_b128", 1), ("bert_base", "ssgd_1chip_b128", 1),
+            ("resnet50", "mix_00", 1)]:
+        m["workloads"].append({
+            "name": f"{config}.{traffic}", "config": config, "traffic": traffic,
+            "chips": chips, "why": "a cell that a later PR adds as files alone"})
+    m["per_layer"].append(dict(m["per_layer"][-1], name="fwd_ms.third",
+                               workloads=["third.ssgd_1chip", "third.dp2tp2_4chip"]))
+    return m
+
+
+def test_the_next_family_mesh_and_eighth_cell_come_as_files(sound, scratch, monkeypatch):
+    """A scratch copy of the benchmark takes a third family file, a traffic
+    file over {"dp": 2, "tp": 2}, a per-layer metric reported in the new
+    cells alone and an eighth cell, the second on four chips, with no edit
+    to a file that is there: `manifest.check` finds it sound, and every
+    cell holds to what the harness needs. A closed list of families,
+    launchers, meshes or cell counts in either fails here, not in the
+    `model_config` PR that adds the files."""
+    repo, bench = scratch
+    m = _eight_cells(sound)
+    assert mf.check(m, bench, repo) == []
+    assert len(m["workloads"]) == 8
+    assert sum(w["chips"] == 4 for w in m["workloads"]) == 2
+    monkeypatch.setattr(mf, "REPO", repo)
+    monkeypatch.setattr(mf, "BENCH_DIR", bench)
+    for w in m["workloads"]:
+        _holds_what_the_harness_needs(mf.cell(m, w["name"]), _module_at(bench))
+    new = mf.cell(m, "third.dp2tp2_4chip")
+    assert new["config"]["family"] == "sparse_experts"
+    assert new["traffic"]["mesh"] == {"dp": 2, "tp": 2}
+    # the new metric is in the new cells' traced lines and in no other
+    assert "fwd_ms.third" in {x["name"] for x in mf.metrics_of(
+        m, "per_layer", "third.dp2tp2_4chip")}
+    assert "fwd_ms.third" not in {x["name"] for x in mf.metrics_of(
+        m, "per_layer", "bert_base.ssgd_1chip")}
+
+
+@pytest.mark.parametrize("closed_list,refuses", [
+    ("family", ["third.ssgd_1chip", "third.dp2tp2_4chip", "third.ssgd_1chip_b128"]),
+    ("launcher", ["third.dp2tp2_4chip"]),
+    ("mesh", ["third.dp2tp2_4chip"]),
+])
+def test_the_parents_closed_lists_refuse_the_scratch_tree(sound, scratch, monkeypatch,
+                                                           closed_list, refuses):
+    """The three whitelists this file held every cell to until PR 26
+    (family in (transformer, resnet), launcher in (none, kfrun), mesh ==
+    {dp: chips}): put back, each refuses the scratch tree's new cells,
+    which `_holds_what_the_harness_needs` takes."""
+    repo, bench = scratch
+    monkeypatch.setattr(mf, "REPO", repo)
+    monkeypatch.setattr(mf, "BENCH_DIR", bench)
+    m = _eight_cells(sound)
+    parents = {
+        "family": lambda c: c["config"]["family"] in ("transformer", "resnet"),
+        "launcher": lambda c: c["traffic"]["launcher"] in ("none", "kfrun"),
+        "mesh": lambda c: c["traffic"]["mesh"] == {"dp": c["chips"]},
+    }
+    assert [w["name"] for w in m["workloads"]
+            if not parents[closed_list](mf.cell(m, w["name"]))] == refuses

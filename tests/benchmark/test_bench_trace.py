@@ -3,6 +3,7 @@ arithmetic by hand on a drawn trace, then the same code on a trace recorded
 on the chip (cut from this PR's own run) against answers worked out
 independently of it."""
 
+import copy
 import gzip
 import json
 import os
@@ -11,7 +12,10 @@ import pytest
 
 from benchmark import end_to_end, manifest as mf, trace_reduce as tr
 from benchmark.layer_metrics import (allreduce_exposed_ms, allreduce_ms,
-                                     device_idle_pct, device_step_ms)
+                                     attention_core_ms, bwd_ms,
+                                     device_idle_pct, device_step_ms, fwd_ms,
+                                     head_loss_ms, optimizer_ms,
+                                     unattributed_ms)
 
 MS = 1_000_000  # nanoseconds
 
@@ -41,6 +45,22 @@ DRAWN = {
              ["bench.dispatch", int(12.5 * MS), 13 * MS]],
     "lines": {},
 }
+
+
+# The drawn step program's scope table, as `trace_reduce.scope_table` reads
+# one off a compiled program: the scan (`while.1`) and the attention core's
+# matmul in its body forward, the head's backward, the optimizer's update,
+# and the all-reduce under `grad_allreduce`.
+BLOCK = "jit(step)/shard_map/jvp()/while/body/closed_call"
+DRAWN_SCOPES = {
+    "while.1": "jit(step)/shard_map/jvp()/while",
+    "fusion.1": f"{BLOCK}/attn/attn_core/bhqd,bhkd->bhqk/dot_general",
+    "fusion.2": "jit(step)/shard_map/transpose(jvp(head_loss))/jit(log_softmax)/mul",
+    "all-reduce.1": "jit(step)/shard_map/optimizer/grad_allreduce/psum",
+    "fusion.3": "jit(step)/shard_map/optimizer/optimizer_update/add",
+}
+SCOPE_READERS = (fwd_ms, bwd_ms, optimizer_ms, unattributed_ms,
+                 attention_core_ms, head_loss_ms)
 
 
 @pytest.mark.parametrize("intervals,want", [
@@ -172,29 +192,128 @@ def test_place_spans_puts_the_host_clock_on_the_trace_clock():
 
 
 def test_readers_return_nothing_without_a_trace():
+    scoped = {"scopes": DRAWN_SCOPES}
     for reader in (device_step_ms, allreduce_ms, allreduce_exposed_ms,
-                   device_idle_pct):
-        assert reader.read({}, None) is None
-        assert reader.read({}, {"chips": [], "host": [], "lines": {}}) is None
+                   device_idle_pct, *SCOPE_READERS):
+        assert reader.read(scoped, None) is None
+        assert reader.read(scoped, {"chips": [], "host": [], "lines": {}}) is None
+
+
+@pytest.mark.parametrize("record", [{}, {"scopes": None}, {"scopes": {}}])
+def test_scope_readers_return_nothing_without_a_scope_table(record):
+    """An untraced run's record has `scopes: None`, and one written before
+    PR 26 has no such key."""
+    for reader in SCOPE_READERS:
+        assert reader.read(record, DRAWN) is None
+    assert device_step_ms.read(record, DRAWN) == pytest.approx(9.25)
+
+
+def test_drawn_scopes_a_while_keeps_only_its_own_time():
+    """Forward: step 0 the scan's own millisecond [2, 3) and fusion.1's two,
+    not the six the scan spans; step 1 fusion.1's three."""
+    record = {"scopes": DRAWN_SCOPES}
+    assert fwd_ms.read(record, DRAWN) == pytest.approx(3.0)
+    assert attention_core_ms.read(record, DRAWN) == pytest.approx(2.5)
+    # the head's backward runs in step 0 alone: 3 ms and 0
+    assert bwd_ms.read(record, DRAWN) == pytest.approx(1.5)
+    assert head_loss_ms.read(record, DRAWN) == pytest.approx(1.5)
+    # fusion.3: 1.5 and 2 ms; the all-reduce beside it under
+    # `optimizer/grad_allreduce` is `allreduce_ms`' and in no scope's time
+    assert optimizer_ms.read(record, DRAWN) == pytest.approx(1.75)
+    assert allreduce_ms.read(record, DRAWN) == pytest.approx(3.5)
+    # every op is in the table: nothing is unattributed, and none is read
+    assert unattributed_ms.read(record, DRAWN) is None
+
+
+def test_drawn_scopes_an_op_the_table_does_not_name_is_unattributed():
+    table = {k: v for k, v in DRAWN_SCOPES.items() if k != "while.1"}
+    table["fusion.3"] = "jit(step)/shard_map"  # plumbing alone names no scope
+    record = {"scopes": table}
+    assert fwd_ms.read(record, DRAWN) == pytest.approx(2.5)
+    # the scan's own millisecond of step 0 and fusion.3: 2.5 and 2 ms
+    assert unattributed_ms.read(record, DRAWN) == pytest.approx(2.25)
+    assert optimizer_ms.read(record, DRAWN) is None
+    # an all-reduce is one by its HLO operation, whatever its scope says
+    table["all-reduce.1"] = f"{BLOCK}/ffn/psum"
+    assert fwd_ms.read({"scopes": table}, DRAWN) == pytest.approx(2.5)
+    kinds = json.loads(json.dumps(DRAWN))
+    kinds["chips"][0]["kinds"] = {"fusion.1": "all-reduce"}
+    assert fwd_ms.read({"scopes": table}, kinds) is None
+
+
+def _parts_and_step(record, trace):
+    parts = [reader.read(record, trace) for reader in (
+        fwd_ms, bwd_ms, optimizer_ms, unattributed_ms)]
+    return sum(p or 0.0 for p in parts), parts
+
+
+def test_drawn_parts_and_the_exposed_all_reduce_make_up_the_step():
+    """Forward, backward, optimizer, unattributed and the all-reduce are
+    every op's own time once. Where an op overlaps an all-reduce (drawn:
+    fusion.3 over its last millisecond in step 0) the step holds that time
+    once, so it is the exposed part that completes the sum; on the chip the
+    all-reduces were synchronous and the two are one number."""
+    record = {"scopes": {k: v for k, v in DRAWN_SCOPES.items() if k != "while.1"}}
+    total, parts = _parts_and_step(record, DRAWN)
+    assert None not in parts
+    assert total + allreduce_exposed_ms.read(record, DRAWN) == pytest.approx(
+        device_step_ms.read(record, DRAWN))
+    assert total + allreduce_ms.read(record, DRAWN) == pytest.approx(
+        device_step_ms.read(record, DRAWN) + 0.5)
+
+
+def _record(workload="bert_base.ssgd_1chip"):
+    return {"workload": workload, "traced": True,
+            "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+            "t_command": 0.0, "t_world": 1.0, "first_step_s": 1.0,
+            "chips": 1, "samples_per_step": 16,
+            "window": {"compiles": 0, "t_done": [1.0, 1.1, 1.2, 1.3],
+                       "spans": [["bench.input", 1.0, 1.001]]},
+            "program_memory": {"total_bytes": 1}, "memory_stats_peak_bytes": 1,
+            # the scan's own time is left to no scope, so that every
+            # scope metric has something to read
+            "scopes": {k: v for k, v in DRAWN_SCOPES.items() if k != "while.1"},
+            "correct": True, "attempted": 20, "failed": 0}
 
 
 def test_a_traced_line_without_device_ops_is_refused():
-    record = {"workload": "bert_base.ssgd_1chip", "traced": True,
-              "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
-              "t_command": 0.0, "t_world": 1.0, "first_step_s": 1.0,
-              "chips": 1, "samples_per_step": 16,
-              "window": {"compiles": 0, "t_done": [1.0, 1.1, 1.2, 1.3],
-                         "spans": [["bench.input", 1.0, 1.001]]},
-              "program_memory": {"total_bytes": 1}, "memory_stats_peak_bytes": 1,
-              "correct": True, "attempted": 20, "failed": 0}
     with pytest.raises(RuntimeError, match="no device operation"):
-        end_to_end.result_line(record, {"chips": [], "host": [], "lines": {}},
+        end_to_end.result_line(_record(), {"chips": [], "host": [], "lines": {}},
                                mf.load())
-    line = end_to_end.result_line(record, DRAWN, mf.load())
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in mf.load()["workloads"]])
+def test_a_traced_line_holds_exactly_its_cells_per_layer_metrics(workload):
+    """Each cell's traced line holds the per-layer metrics the manifest
+    lists for that cell and no other: a metric reported in some cells only
+    (`"workloads": [...]`) is absent from the others' lines."""
+    manifest = mf.load()
+    line = end_to_end.result_line(_record(workload), DRAWN, manifest)
     assert tuple(line) == mf.TRACED_RESULT_KEYS
     assert line["device"]["busy_s"] == pytest.approx(18.5e-3)
     assert line["device"]["window_s"] == pytest.approx(22e-3)
-    assert set(line["metrics"]) == {m["name"] for m in mf.load()["per_layer"]}
+    mine = {m["name"] for m in mf.metrics_of(manifest, "per_layer", workload)}
+    assert set(line["metrics"]) == mine
+    everywhere = {m["name"] for m in manifest["per_layer"] if "workloads" not in m}
+    assert {"fwd_ms", "bwd_ms", "unattributed_ms"} <= everywhere <= mine
+    assert mf.check_result_line(line, manifest, workload, True) == []
+
+
+def test_a_metric_listed_for_another_cell_alone_is_absent_from_this_line():
+    manifest = copy.deepcopy(mf.load())
+    (entry,) = [m for m in manifest["per_layer"] if m["name"] == "head_loss_ms"]
+    entry["workloads"] = ["resnet50.ssgd_1chip"]
+    assert mf.check(manifest) == []
+    line = end_to_end.result_line(_record(), DRAWN, manifest)
+    assert "head_loss_ms" not in line["metrics"]
+    assert "attention_core_ms" in line["metrics"]
+    assert mf.check_result_line(line, manifest, "bert_base.ssgd_1chip", True) == []
+    other = end_to_end.result_line(_record("resnet50.ssgd_1chip"), DRAWN, manifest)
+    assert other["metrics"]["head_loss_ms"] == {"value": pytest.approx(1.5), "unit": "ms"}
+    # and a line that carries it all the same is refused
+    line["metrics"]["head_loss_ms"] = {"value": 1.5, "unit": "ms"}
+    assert any("head_loss_ms is not a per_layer metric of bert_base.ssgd_1chip" in f
+               for f in mf.check_result_line(line, manifest, "bert_base.ssgd_1chip", True))
 
 
 def test_asynchronous_all_reduce_counts_from_start_to_done():
@@ -274,6 +393,86 @@ def test_recorded_breakdown(recorded):
     assert b["idle_gaps"][0] == ["bench.wait", pytest.approx(5_027e-9)]
 
 
+def _partial_table(trace):
+    """A scope table that names only some of a recorded trace's ops, by
+    their names: the traces are PR 23's, recorded before the program had
+    scopes. Every `fusion` forward under `ffn`, every
+    `bitcast_dynamic-update-slice_fusion` backward under the attention
+    core, every `copy` the optimizer's; some 300 other ops are left out."""
+    table = {}
+    for name in {o[0] for o in tr.chip(trace)["ops"]}:
+        if name.startswith("fusion"):
+            table[name] = f"{BLOCK}/ffn/dot_general"
+        elif name.startswith("bitcast"):
+            table[name] = ("jit(step)/shard_map/transpose(jvp())/while/body/"
+                           "closed_call/attn/attn_core/dot_general")
+        elif name.startswith("copy"):
+            table[name] = "jit(step)/shard_map/optimizer/optimizer_update/add"
+    return table
+
+
+def test_recorded_parts_sum_to_the_device_step(recorded):
+    """Own times painted apart from trace_reduce (one cell a nanosecond, an
+    enclosed op painted over its `while`), counted by the same name rule."""
+    record = {"scopes": _partial_table(recorded)}
+    total, parts = _parts_and_step(record, recorded)
+    assert parts == pytest.approx([47.354286, 21.580496, 1.8021235, 7.832697],
+                                  rel=1e-9)
+    assert attention_core_ms.read(record, recorded) == pytest.approx(21.580496)
+    assert head_loss_ms.read(record, recorded) is None
+    assert total == pytest.approx(device_step_ms.read(record, recorded), abs=1e-6)
+
+
+def test_recorded_four_chip_parts_and_all_reduces_sum_to_the_step(recorded_four):
+    """`psum.73` is an all-reduce by its HLO operation and by nothing in
+    its name; given the optimizer's scope it is still no part of
+    `optimizer_ms`, and the five numbers make up the step."""
+    table = _partial_table(recorded_four)
+    table["psum.73"] = "jit(step)/shard_map/optimizer/grad_allreduce/psum"
+    table["psum.74"] = "jit(step)/shard_map/optimizer/optimizer_update/add"
+    record = {"scopes": table}
+    total, parts = _parts_and_step(record, recorded_four)
+    assert parts == pytest.approx([43.544945, 21.595075, 2.054824, 11.492242],
+                                  rel=1e-9)
+    assert total + allreduce_ms.read(record, recorded_four) == pytest.approx(
+        device_step_ms.read(record, recorded_four), abs=1e-6)
+
+
+@pytest.fixture(scope="module")
+def recorded_scopes():
+    """`bert_base.ssgd_1chip` after PR 25, steps 7 and 8 of a traced run of
+    PR 26 whose step program came from the compile cache, with the rows of
+    its record's scope table that name an op of those steps."""
+    path = os.path.join(mf.BENCH_DIR, "testdata",
+                        "bert_base_1chip_scopes_2steps.json.gz")
+    with gzip.open(path, "rt") as f:
+        return json.load(f)
+
+
+def test_recorded_scope_metrics(recorded_scopes):
+    """Against a painting made apart from trace_reduce: every op's interval
+    onto an array of one cell a nanosecond, an enclosed op over its `while`,
+    each cell labelled by the scope path of the op that owns it."""
+    c = tr.chip(recorded_scopes)
+    assert len(c["steps"]) == 2 and len(c["ops"]) == 3804
+    names = {o[0] for o in c["ops"]}
+    # 268 of the 373 ops have no row: the compiler's copies, converts, slices
+    assert len(names) == 373 and len(names & set(recorded_scopes["scopes"])) == 105
+    record = {"scopes": recorded_scopes["scopes"]}
+    want = {fwd_ms: 21.29225, bwd_ms: 31.9941575, optimizer_ms: 3.7527755,
+            unattributed_ms: 0.8148525, attention_core_ms: 10.6110965,
+            head_loss_ms: 11.706252}
+    for reader, ms in want.items():
+        assert reader.read(record, recorded_scopes) == pytest.approx(ms, rel=1e-12)
+    total, _ = _parts_and_step(record, recorded_scopes)
+    assert tr.per_step(c, tr.busy(c)) == [57_852_706, 57_855_365]
+    assert total == pytest.approx(device_step_ms.read(record, recorded_scopes), abs=1e-6)
+    assert allreduce_ms.read(record, recorded_scopes) == 0.0
+    # under 2 % of the step is no scope's
+    assert (unattributed_ms.read(record, recorded_scopes)
+            < 0.02 * device_step_ms.read(record, recorded_scopes))
+
+
 @pytest.fixture(scope="module")
 def recorded_four():
     path = os.path.join(mf.BENCH_DIR, "testdata",
@@ -334,3 +533,68 @@ def test_op_names_and_labels():
     assert tr.op_label(text).startswith("(f32[256], bf16[128,56,56,256]) fusion(")
     assert len(tr.op_label(text)) <= 56
     assert tr.op_name("bench.wait") == "bench.wait"
+
+
+HLO_TEXT = '''HloModule jit_local_step, is_scheduled=true
+
+%fused_computation.13 (param_0: f32[256]) -> f32[256] {
+  %param_0 = f32[256]{0} parameter(0)
+  ROOT %multiply.5 = f32[256]{0} multiply(%param_0, %param_0), metadata={op_name="jit(local_step)/shard_map/jvp()/while/body/closed_call/ffn/mul" source_file="transformer.py" source_line=178}
+}
+
+ENTRY %main.1 (p: f32[256]) -> f32[256] {
+  %p = f32[256]{0} parameter(0), metadata={op_name="state[\\'embed\\']"}
+  %copy.3 = f32[256]{0} copy(%p)
+  %fusion.13 = f32[256]{0} fusion(%copy.3), kind=kLoop, calls=%fused_computation.13, metadata={op_name="jit(local_step)/shard_map/jvp()/while/body/closed_call/ffn/mul" source_file="transformer.py" source_line=178}
+  %psum.73 = f32[256]{0} all-reduce(%fusion.13), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(local_step)/shard_map/optimizer/grad_allreduce/psum"}
+  ROOT %fusion.205 = f32[256]{0} fusion(%psum.73), kind=kOutput, calls=%fused_computation.13, metadata={op_name="jit(local_step)/shard_map/optimizer/optimizer_update/add"}
+}
+'''
+
+
+def test_scope_table_reads_the_compiled_programs_text():
+    table = tr.scope_table(HLO_TEXT)
+    assert table["fusion.13"] == "jit(local_step)/shard_map/jvp()/while/body/closed_call/ffn/mul"
+    assert table["psum.73"] == "jit(local_step)/shard_map/optimizer/grad_allreduce/psum"
+    assert table["fusion.205"].endswith("optimizer/optimizer_update/add")  # a ROOT
+    assert table["multiply.5"].endswith("ffn/mul")  # inside a fusion: never an event
+    assert "copy.3" not in table  # the compiler's own: no op_name
+    assert tr.scope_table("") == {}
+    # the names are the trace's: what `op_name` cuts from an event's text
+    event = "%fusion.13 = f32[256]{0:T(256)} fusion(f32[256]{0:T(256)} %copy.3)"
+    assert tr.op_name(event) in table
+
+
+BACK = "jit(local_step)/shard_map/transpose(jvp())/while/body/closed_call"
+
+
+@pytest.mark.parametrize("op_name,parts,phase", [
+    (f"{BLOCK}/attn/attn_core/dot_general", ["jvp()", "attn", "attn_core"], "forward"),
+    (f"{BACK}/attn/attn_core/attn/attn_core/checkpoint/rematted_computation/exp",
+     ["transpose(jvp())", "attn", "attn_core", "attn", "attn_core"], "backward"),
+    ("jit(local_step)/shard_map/transpose(jvp(head_loss))/jit(log_softmax)/mul",
+     ["transpose(jvp(head_loss))"], "backward"),
+    ("jit(local_step)/shard_map/jvp(ResNet)/BottleneckBlock_0/Conv_0/conv_general_dilated",
+     ["jvp(ResNet)", "BottleneckBlock_0", "Conv_0"], "forward"),
+    # a transposed array is not a transposed computation
+    (f"{BLOCK}/attn/transpose", ["jvp()", "attn"], "forward"),
+    ("jit(local_step)/shard_map/optimizer/optimizer_update/sub",
+     ["optimizer", "optimizer_update"], "optimizer"),
+    ("jit(local_step)/shard_map/grad_allreduce/div", ["grad_allreduce"], "optimizer"),
+    ("jit(local_step)/shard_map/optimizer/transpose(jvp(x))/mul",
+     ["optimizer", "transpose(jvp(x))"], "optimizer"),
+    ("jit(local_step)/shard_map", [], "unattributed"),
+    ("copy.3", [], "unattributed"),
+    ("", [], "unattributed"),
+])
+def test_scope_parts_and_phase(op_name, parts, phase):
+    assert tr.scope_parts(op_name) == parts
+    assert tr.phase_of(parts) == phase
+
+
+def test_scope_names_open_the_transforms_wrappers():
+    assert tr.scope_names(["transpose(jvp(head_loss))", "jvp(head_loss)"]) == {
+        "transpose", "jvp", "head_loss"}
+    assert "attn_core" in tr.scope_names(["jvp()", "attn", "attn_core"])
+    assert "head_loss" not in tr.scope_names(["jvp()", "head_loss_scale"])
+    assert tr.scope_names([]) == set()
