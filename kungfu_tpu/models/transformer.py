@@ -9,6 +9,13 @@ TPU-first design notes:
   these annotations — nothing is hand-scheduled.
 - Compute in bfloat16 (MXU native), params and optimizer state in f32.
 - Static shapes everywhere; layers are stacked and scanned-friendly.
+- The layer is data (ROADMAP D1): `TransformerConfig` says where positions
+  come from (a learned table or rotary), whether q and k are normed, what
+  the feed-forward is (gelu, gated silu, or routed experts through
+  `ops.moe.moe_ffn`), whether the head is tied, and which attention core
+  runs (XLA's dense one or `ops.flash_attention`). The defaults are the
+  block the repo has always had, so `bert_base()` and `tiny()` mean what
+  they meant; `olmoe_1b_7b()` is the first published architecture.
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -19,7 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +43,31 @@ class TransformerConfig:
     d_ff: int = 2048
     max_seq: int = 512
     dtype: Any = jnp.bfloat16
+    # what the layer is; every default is the repo's own block
+    positions: str = "learned"  # or "rope": rotate-half over the whole head
+    rope_theta: float = 10000.0
+    qk_norm: bool = False  # RMSNorm over all of q and of k, before the heads split
+    norm_eps: float = 1e-6
+    ffn: str = "gelu"  # "gelu" (w_in, w_out) | "swiglu" (gated silu) | "moe"
+    n_experts: int = 0  # ffn == "moe": experts of width d_ff, gated silu
+    top_k: int = 0  # experts a token; raw softmax probabilities gate them
+    router_aux_coef: float = 0.0  # x load-balancing loss, added to the loss
+    router_z_coef: float = 0.0  # x router z-loss
+    tied_head: bool = True  # False: `lm_head` (V, D) of its own
+    attn_core: str = "dense"  # or "flash": ops.flash_attention
+    flash_blocks: Tuple[int, int] = (512, 512)
+    flash_interpret: bool = False  # the tests' CPU mesh; never chosen by backend
+
+    def __post_init__(self):
+        for field, value, known in (
+                ("positions", self.positions, ("learned", "rope")),
+                ("ffn", self.ffn, ("gelu", "swiglu", "moe")),
+                ("attn_core", self.attn_core, ("dense", "flash"))):
+            if value not in known:
+                raise ValueError(f"{field} {value!r} is not one of {known}")
+        if self.ffn == "moe" and not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(f"ffn 'moe' needs 1 <= top_k <= n_experts, got "
+                             f"{self.top_k} of {self.n_experts}")
 
     @property
     def head_dim(self) -> int:
@@ -52,6 +84,28 @@ class TransformerConfig:
         return cls(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
                    d_ff=128, max_seq=64)
 
+    @classmethod
+    def olmoe_1b_7b(cls, n_layers: int = 16, **changes) -> "TransformerConfig":
+        """allenai/OLMoE-1B-7B-0125-Instruct's config.json: rotary, q/k
+        norm, 64 gated-silu experts of width 1024, 8 a token, untied head.
+        The auxiliary losses' coefficients are the OLMoE paper's
+        (arXiv:2409.02060)."""
+        return dataclasses.replace(cls(
+            vocab_size=50304, d_model=2048, n_heads=16, n_layers=n_layers,
+            d_ff=1024, max_seq=4096, positions="rope", rope_theta=10000.0,
+            qk_norm=True, norm_eps=1e-5, ffn="moe", n_experts=64, top_k=8,
+            router_aux_coef=0.01, router_z_coef=0.001, tied_head=False,
+            attn_core="flash"), **changes)
+
+    @classmethod
+    def tiny_moe(cls, **changes) -> "TransformerConfig":
+        """Every mechanism of `olmoe_1b_7b` on, at the tests' size; the
+        flash core in interpret mode."""
+        return dataclasses.replace(cls.olmoe_1b_7b(
+            n_layers=2, vocab_size=256, d_model=64, n_heads=4, d_ff=32,
+            max_seq=64, n_experts=8, top_k=3, flash_blocks=(32, 32),
+            flash_interpret=True), **changes)
+
 
 def init_transformer(key, cfg: TransformerConfig) -> Dict:
     """Params in f32; cast to cfg.dtype at apply time."""
@@ -61,48 +115,82 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
     def dense(k, shape):
         return jax.random.normal(k, shape, jnp.float32) * scale
 
+    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
     layers = []
     for i in range(cfg.n_layers):
-        lk = jax.random.split(keys[2 + i], 4)
-        layers.append({
-            "ln1_scale": jnp.ones((cfg.d_model,), jnp.float32),
-            "ln2_scale": jnp.ones((cfg.d_model,), jnp.float32),
-            "wqkv": dense(lk[0], (cfg.d_model, 3 * cfg.d_model)),
-            "wo": dense(lk[1], (cfg.d_model, cfg.d_model)),
-            "w_in": dense(lk[2], (cfg.d_model, cfg.d_ff)),
-            "w_out": dense(lk[3], (cfg.d_ff, cfg.d_model)),
-        })
+        # the gelu block draws what it always drew from four keys; the
+        # other feed-forwards take further keys of a split of their own
+        lk = jax.random.split(keys[2 + i], 4 if cfg.ffn == "gelu" else 6)
+        layer = {
+            "ln1_scale": jnp.ones((D,), jnp.float32),
+            "ln2_scale": jnp.ones((D,), jnp.float32),
+            "wqkv": dense(lk[0], (D, 3 * D)),
+            "wo": dense(lk[1], (D, D)),
+        }
+        if cfg.ffn == "gelu":
+            layer["w_in"] = dense(lk[2], (D, F))
+            layer["w_out"] = dense(lk[3], (F, D))
+        else:
+            stack = (E,) if cfg.ffn == "moe" else ()
+            layer["w_gate"] = dense(lk[2], stack + (D, F))
+            layer["w_up"] = dense(lk[3], stack + (D, F))
+            layer["w_down"] = dense(lk[4], stack + (F, D))
+        if cfg.ffn == "moe":
+            layer["router"] = dense(lk[5], (D, E))
+        if cfg.qk_norm:
+            layer["q_norm_scale"] = jnp.ones((D,), jnp.float32)
+            layer["k_norm_scale"] = jnp.ones((D,), jnp.float32)
+        layers.append(layer)
     # stack layers: leading axis = layer, enables lax.scan over layers
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
-    return {
-        "embed": dense(keys[0], (cfg.vocab_size, cfg.d_model)),
-        "pos_embed": dense(keys[1], (cfg.max_seq, cfg.d_model)),
-        "ln_f_scale": jnp.ones((cfg.d_model,), jnp.float32),
+    params = {
+        "embed": dense(keys[0], (cfg.vocab_size, D)),
+        "ln_f_scale": jnp.ones((D,), jnp.float32),
         "layers": stacked,
     }
+    if cfg.positions == "learned":
+        params["pos_embed"] = dense(keys[1], (cfg.max_seq, D))
+    if not cfg.tied_head:
+        params["lm_head"] = dense(jax.random.fold_in(keys[1], 1),
+                                  (cfg.vocab_size, D))
+    return params
 
 
-def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp") -> Dict:
-    """PartitionSpec tree matching init_transformer's param tree.
+def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
+                 ep_axis: str = "ep") -> Dict:
+    """PartitionSpec tree matching init_transformer's param tree, whatever
+    the layer is.
 
-    Column-parallel wqkv/w_in (shard output features over tp), row-parallel
-    wo/w_out (shard input features over tp); embedding sharded over vocab.
-    Layer-stacked leaves have a leading layer axis (unsharded).
+    Column-parallel wqkv/w_in/w_gate/w_up (shard output features over tp),
+    row-parallel wo/w_out/w_down (shard input features over tp); embedding
+    and an untied head sharded over vocab; an expert stack over `ep_axis`
+    on its expert dimension, the router whole. Layer-stacked leaves have a
+    leading layer axis (unsharded). The q/k norms' scales span all of q's
+    features, which tp splits: sharded like them.
     """
-    t = tp_axis
-    return {
-        "embed": P(t, None),
-        "pos_embed": P(),
-        "ln_f_scale": P(),
-        "layers": {
-            "ln1_scale": P(None),
-            "ln2_scale": P(None),
-            "wqkv": P(None, None, t),
-            "wo": P(None, t, None),
-            "w_in": P(None, None, t),
-            "w_out": P(None, t, None),
-        },
+    t, e = tp_axis, ep_axis
+    layers = {
+        "ln1_scale": P(None),
+        "ln2_scale": P(None),
+        "wqkv": P(None, None, t),
+        "wo": P(None, t, None),
     }
+    if cfg.ffn == "gelu":
+        layers.update(w_in=P(None, None, t), w_out=P(None, t, None))
+    elif cfg.ffn == "swiglu":
+        layers.update(w_gate=P(None, None, t), w_up=P(None, None, t),
+                      w_down=P(None, t, None))
+    else:
+        layers.update(w_gate=P(None, e, None, t), w_up=P(None, e, None, t),
+                      w_down=P(None, e, t, None), router=P(None, None, None))
+    if cfg.qk_norm:
+        layers.update(q_norm_scale=P(None, t), k_norm_scale=P(None, t))
+    specs = {"embed": P(t, None), "ln_f_scale": P(), "layers": layers}
+    if cfg.positions == "learned":
+        specs["pos_embed"] = P()
+    if not cfg.tied_head:
+        specs["lm_head"] = P(t, None)
+    return specs
 
 
 # What the backward pass keeps (PERF.md, PR 25). The layer scan stacks every
@@ -117,10 +205,15 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp") -> Dict:
 _recompute = functools.partial(jax.checkpoint, prevent_cse=False)
 
 
-@_recompute
 def _rmsnorm(x, scale, eps=1e-6):
     """Keeps x and scale; the f32 upcast, the variance and the normalised
-    output are recomputed."""
+    output are recomputed. `eps` is data of the configuration, not of the
+    program: a Python number."""
+    return _rmsnorm_at(x, scale, eps)
+
+
+@functools.partial(_recompute, static_argnums=(2,))
+def _rmsnorm_at(x, scale, eps):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
 
@@ -151,77 +244,216 @@ def _gelu_out(pre, w_out):
     return jax.nn.gelu(pre) @ w_out
 
 
-def _attention(x, wqkv, wo, cfg: TransformerConfig, core=_full_attention_core):
+@functools.partial(_recompute, static_argnums=(2,))
+def _rope(q, k, theta: float):
+    """Rotary positions on (B, H, S, hd) q and k, positions 0..S-1: the
+    rotate-half form over the whole head dimension, angles and the rotation
+    in float32. Keeps q and k; the angles, their cos and sin and the
+    rotation are recomputed."""
+    S, hd = q.shape[2], q.shape[3]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    angles = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)  # (S, hd)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+
+    def rotate(t):
+        t32 = t.astype(jnp.float32)
+        half = jnp.concatenate([-t32[..., hd // 2:], t32[..., :hd // 2]], axis=-1)
+        return (t32 * cos + half * sin).astype(t.dtype)
+
+    return rotate(q), rotate(k)
+
+
+@_recompute
+def _silu_gate_out(gate, up, w_down):
+    """(silu(gate) * up) @ w_down. Keeps gate, up and w_down; the silu and
+    the product are recomputed, as `_gelu_out` recomputes its gelu."""
+    return (jax.nn.silu(gate) * up) @ w_down
+
+
+def attention_core_of(cfg: TransformerConfig):
+    """The (q, k, v) -> ctx core the configuration names."""
+    if cfg.attn_core == "dense":
+        return _full_attention_core
+    from kungfu_tpu.ops.flash_attention import flash_attention
+
+    blk_q, blk_k = cfg.flash_blocks
+    return lambda q, k, v: flash_attention(q, k, v, True, None, blk_q, blk_k,
+                                           cfg.flash_interpret)
+
+
+def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None):
     """QKV projection + head reshape around a pluggable (q,k,v)->ctx core
-    (full attention by default, the ring core for sequence parallelism —
-    ONE copy of the projection plumbing for both paths)."""
+    (the configuration's by default, the ring core for sequence parallelism
+    — ONE copy of the projection plumbing for every path). `qk_scales` =
+    (q_norm_scale, k_norm_scale) where the configuration norms q and k."""
     B, S, D = x.shape
     H, hd = cfg.n_heads, cfg.head_dim
     qkv = x @ wqkv  # (B, S, 3D)
     q, k, v = jnp.split(qkv, 3, axis=-1)
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = _rmsnorm(q, qk_scales[0], cfg.norm_eps)
+            k = _rmsnorm(k, qk_scales[1], cfg.norm_eps)
     q = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
     k = k.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
     v = v.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+    if cfg.positions == "rope":
+        with jax.named_scope("rope"):
+            q, k = _rope(q, k, cfg.rope_theta)
     with jax.named_scope("attn_core"):
-        ctx = core(q, k, v)
+        ctx = (core or attention_core_of(cfg))(q, k, v)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, D)
     return ctx @ wo
 
 
-def _block(x, layer, cfg: TransformerConfig, core=_full_attention_core):
-    dt = cfg.dtype
+def _layer(x, layer, cfg: TransformerConfig, core=None):
+    """One layer -> (x, aux): aux is the expert layer's `ops.moe.MoeAux`
+    (router losses and token-choices per expert), None of any other."""
+    dt, eps = cfg.dtype, cfg.norm_eps
     with jax.named_scope("attn"):
-        x = x + _attention(_rmsnorm(x, layer["ln1_scale"]),
+        scales = ((layer["q_norm_scale"], layer["k_norm_scale"])
+                  if cfg.qk_norm else None)
+        x = x + _attention(_rmsnorm(x, layer["ln1_scale"], eps),
                            layer["wqkv"].astype(dt), layer["wo"].astype(dt),
-                           cfg, core=core)
+                           cfg, core=core, qk_scales=scales)
+    if cfg.ffn == "moe":
+        from kungfu_tpu.ops.moe import moe_ffn, raw_gates, swiglu_experts
+
+        with jax.named_scope("moe"):
+            B, S, D = x.shape
+            h = _rmsnorm(x, layer["ln2_scale"], eps).reshape(B * S, D)
+            y, aux = moe_ffn(
+                h, layer["router"],
+                (layer["w_gate"], layer["w_up"], layer["w_down"]),
+                top_k=cfg.top_k, gates=raw_gates, expert_fn=swiglu_experts)
+            return x + y.reshape(B, S, D), aux
     with jax.named_scope("ffn"):
-        pre = _rmsnorm(x, layer["ln2_scale"]) @ layer["w_in"].astype(dt)
-        return x + _gelu_out(pre, layer["w_out"].astype(dt))
+        h = _rmsnorm(x, layer["ln2_scale"], eps)
+        if cfg.ffn == "swiglu":
+            return x + _silu_gate_out(h @ layer["w_gate"].astype(dt),
+                                      h @ layer["w_up"].astype(dt),
+                                      layer["w_down"].astype(dt)), None
+        pre = h @ layer["w_in"].astype(dt)
+        return x + _gelu_out(pre, layer["w_out"].astype(dt)), None
+
+
+def _block(x, layer, cfg: TransformerConfig, core=None):
+    """One layer's hidden states alone, for the paths that have no place
+    for an expert layer's auxiliary losses (pipeline, ring, a plugged
+    core)."""
+    return _layer(x, layer, cfg, core=core)[0]
+
+
+def _head_logits(params, x, cfg: TransformerConfig):
+    """Final norm and the LM head, tied to the embedding or `lm_head` of its
+    own, in float32."""
+    h = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
+    head = params["embed"] if cfg.tied_head else params["lm_head"]
+    return h.astype(jnp.float32) @ head.astype(jnp.float32).T
 
 
 def lm_head_loss(params, x, targets, cfg: TransformerConfig):
-    """Final norm + tied-embedding LM head + next-token cross-entropy on
-    hidden states `x` (..., S, D). The ONE implementation shared by the
-    dense, ring (sequence-parallel) and pipeline paths — a loss change
-    (label smoothing, z-loss, dtype policy) lands everywhere at once."""
+    """Final norm + LM head + next-token cross-entropy on hidden states `x`
+    (..., S, D). The ONE implementation shared by the dense, ring
+    (sequence-parallel) and pipeline paths — a loss change (label
+    smoothing, z-loss, dtype policy) lands everywhere at once."""
     with jax.named_scope("head_loss"):
-        h = _rmsnorm(x, params["ln_f_scale"])
-        logits = h.astype(jnp.float32) @ params["embed"].astype(jnp.float32).T
-        logp = jax.nn.log_softmax(logits)
+        logp = jax.nn.log_softmax(_head_logits(params, x, cfg))
         ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)
         return -jnp.mean(ll)
 
 
-def transformer_hidden(params, tokens, cfg: TransformerConfig):
-    """tokens (B, S) int32 -> final hidden states (B, S, D) pre-norm."""
-    B, S = tokens.shape
+def _embed(params, tokens, cfg: TransformerConfig):
+    S = tokens.shape[1]
+    if S > cfg.max_seq and cfg.positions == "rope":
+        raise ValueError(f"sequence {S} exceeds max_seq {cfg.max_seq}")
     dt = cfg.dtype
     with jax.named_scope("embed"):
-        x = params["embed"].astype(dt)[tokens] + params["pos_embed"].astype(dt)[:S]
+        x = params["embed"].astype(dt)[tokens]
+        if cfg.positions == "learned":
+            x = x + params["pos_embed"].astype(dt)[:S]
+        return x
+
+
+def _hidden(params, tokens, cfg: TransformerConfig):
+    """-> (final hidden states, the layers' stacked aux or None)."""
+    x = _embed(params, tokens, cfg)
 
     def body(x, layer):
-        return _block(x, layer, cfg), None
+        return _layer(x, layer, cfg)
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    return x
+    return jax.lax.scan(body, x, params["layers"])
+
+
+def transformer_hidden(params, tokens, cfg: TransformerConfig):
+    """tokens (B, S) int32 -> final hidden states (B, S, D) pre-norm."""
+    return _hidden(params, tokens, cfg)[0]
 
 
 def transformer_apply(params, tokens, cfg: TransformerConfig):
     """tokens (B, S) int32 -> logits (B, S, V) in f32."""
-    x = transformer_hidden(params, tokens, cfg)
-    x = _rmsnorm(x, params["ln_f_scale"])
-    return x.astype(jnp.float32) @ params["embed"].astype(jnp.float32).T
+    return _head_logits(params, transformer_hidden(params, tokens, cfg), cfg)
 
 
 def transformer_loss(params, batch, cfg: TransformerConfig):
-    """Next-token cross-entropy. batch = tokens (B, S+1) or (tokens, targets)."""
+    """Next-token cross-entropy, plus the expert layers' load-balancing and
+    router z-losses (each a mean over the layers) at the configuration's
+    coefficients. batch = tokens (B, S+1) or (tokens, targets)."""
     if isinstance(batch, (tuple, list)):
         tokens, targets = batch
     else:
         tokens, targets = batch[:, :-1], batch[:, 1:]
-    x = transformer_hidden(params, tokens, cfg)
-    return lm_head_loss(params, x, targets, cfg)
+    x, aux = _hidden(params, tokens, cfg)
+    loss = lm_head_loss(params, x, targets, cfg)
+    if aux is not None:
+        with jax.named_scope("moe"), jax.named_scope("moe_router"):
+            loss = (loss + cfg.router_aux_coef * jnp.mean(aux.load_balance)
+                    + cfg.router_z_coef * jnp.mean(aux.z_loss))
+    return loss
 
+
+def routing_stats(params, tokens, cfg: TransformerConfig):
+    """What the router did with tokens (B, S), layer by layer: jit this
+    beside the step (the step returns a loss and nothing else). `counts`
+    (L, E) token-choices computed per expert, `dropped` (L,) of the B * S *
+    top_k that were not (0: the expert layer has no capacity), and
+    `max_over_mean` (L,) the busiest expert's load over the mean load;
+    `chosen` (L, B * S, top_k) the experts each token took."""
+    _, aux = _hidden(params, tokens, cfg)
+    if aux is None:
+        raise ValueError("routing_stats: the configuration has no expert layer")
+    choices = tokens.size * cfg.top_k
+    counts = aux.counts
+    return {
+        "counts": counts,
+        "dropped": choices - jnp.sum(counts, axis=-1),
+        "max_over_mean": jnp.max(counts, axis=-1) * (cfg.n_experts / choices),
+        "chosen": aux.chosen,
+    }
+
+
+def record_routing(stats, registry=None) -> None:
+    """`routing_stats`' numbers as gauges of `telemetry.metrics`, a series
+    a layer: `kungfu_moe_dropped_token_choices`, `kungfu_moe_max_over_mean_load`
+    and, per expert, `kungfu_moe_expert_token_choices`."""
+    from kungfu_tpu.telemetry import metrics
+
+    reg = registry or metrics.REGISTRY
+    dropped = reg.gauge("kungfu_moe_dropped_token_choices",
+                        "token-choices the expert layer did not compute",
+                        ("layer",))
+    skew = reg.gauge("kungfu_moe_max_over_mean_load",
+                     "the busiest expert's token-choices over the mean",
+                     ("layer",))
+    load = reg.gauge("kungfu_moe_expert_token_choices",
+                     "token-choices computed by one expert",
+                     ("layer", "expert"))
+    for layer, row in enumerate(np.asarray(stats["counts"])):
+        dropped.labels(layer).set(float(stats["dropped"][layer]))
+        skew.labels(layer).set(float(stats["max_over_mean"][layer]))
+        for expert, n in enumerate(row):
+            load.labels(layer, expert).set(float(n))
 
 # ---------------------------------------------------------------------------
 # sequence-parallel (ring attention) path: the long-context mode. The whole
@@ -240,6 +472,11 @@ def ring_transformer_apply_shard(params, tokens, cfg: TransformerConfig,
     (B, S_local, D) — feed them to lm_head_loss."""
     from kungfu_tpu.ops.ring_attention import ring_self_attention
 
+    if cfg.positions != "learned" or cfg.ffn == "moe":
+        raise NotImplementedError(
+            "the ring path slices the learned position table a shard and "
+            "has no place for an expert layer's losses; rotary positions "
+            "need per-shard offsets (ROADMAP R6)")
     B, Sl = tokens.shape
     if sp_size * Sl > cfg.max_seq:
         # loud, like the dense path: dynamic_slice would otherwise CLAMP

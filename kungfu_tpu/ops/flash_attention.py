@@ -16,7 +16,8 @@ divide is an error, not a dense fallback.
 
 The kernels compile with Mosaic unless the caller passes
 `interpret=True` (the tests, on the CPU mesh); the backend is never
-consulted to choose.
+consulted to choose. `models/transformer.py` runs them as the attention
+core of a configuration with `attn_core="flash"`.
 """
 
 from __future__ import annotations
@@ -39,6 +40,14 @@ def _dense_reference(q, k, v, causal: bool, sm_scale: float):
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def _nt(a, b):
+    """a @ b.T with float32 accumulation, the operands in the type they
+    came in: bfloat16 q/k/v go to the MXU as bfloat16 (an upcast to float32
+    first costs the multi-pass float32 matmul), float32 ones stay float32."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
@@ -67,10 +76,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = _nt(q, k) * sm_scale
         if causal:
             qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
             kpos = k_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
@@ -85,7 +92,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         corr = jnp.exp(m - m_new)
         l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
         m_scr[:, :1] = m_new
 
@@ -191,21 +198,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         lse = lse_ref[0][:, :1]
         delta = dl_ref[0][:, :1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+        s = _nt(q, k) * sm_scale
         p = jnp.exp(s - lse)
         if causal:
             qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
             kpos = k_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
             p = jnp.where(kpos <= qpos, p, 0.0)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_scr[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32) * sm_scale
+        ds = p * (_nt(do, v) - delta)
+        dq_scr[...] += jnp.dot(ds.astype(k.dtype), k,
+                               preferred_element_type=jnp.float32) * sm_scale
 
     @pl.when(kb == n_kb - 1)
     def _finalize():
@@ -238,22 +242,21 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         lse = lse_ref[0][:, :1]
         delta = dl_ref[0][:, :1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+        s = _nt(q, k) * sm_scale
         p = jnp.exp(s - lse)
         if causal:
             qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
             kpos = k_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
             p = jnp.where(kpos <= qpos, p, 0.0)
-        dv_scr[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_scr[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32) * sm_scale
+        # transposed in float32, then cast: Mosaic transposes 32-bit tiles
+        dv_scr[...] += jnp.dot(p.T.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        ds = p * (_nt(do, v) - delta)
+        dk_scr[...] += jnp.dot(ds.T.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32) * sm_scale
 
     @pl.when(qi == n_qb - 1)
     def _finalize():
@@ -354,9 +357,13 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
     Forward AND backward are Pallas kernels (two-pass flash backward:
     dq streams k-blocks, dk/dv stream q-blocks, block probs recomputed
     from the forward's saved row log-sum-exp). S must be a multiple of
-    both block sizes (each clamped to S). Speed against XLA dense: round
-    5, earlier stack, record removed in PR 21; not measured on the
-    current one.
+    both block sizes (each clamped to S). q, k, v go to the MXU in the type
+    they come in (bfloat16 in the model), accumulated in float32. On the
+    v5e at (2, 16, 4096, 128) bfloat16 and 512 x 512 blocks, inside the
+    layer scan under `value_and_grad`: 2.39 ms forward, 4.21 ms backward,
+    31.7 % of the bf16 peak for the causal core's required operations
+    (`flash_roofline_pct`, cell `olmoe_1b_7b.ssgd_seq4096_1chip`; PERF.md,
+    PR 27). A dense core's float32 scores are 1.07 GB a sequence there.
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)

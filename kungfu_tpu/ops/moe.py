@@ -1,113 +1,253 @@
-"""Expert parallelism: top-k routed MoE FFN with all_to_all dispatch.
+"""Top-k routed mixture-of-experts feed-forward, on one shard or across an
+expert axis.
 
 Beyond-reference capability (the reference is data-parallel only,
-SURVEY §2.4); on TPU the expert dimension is a mesh axis and token
-dispatch is `lax.all_to_all` over ICI — the canonical TPU MoE layout
-(per-device expert groups, capacity-bounded buckets).
+SURVEY §2.4). `moe_ffn` is one function for both layouts:
 
-`moe_ffn` is the general form: E = axis_size * experts_per_device global
-experts, top_k ∈ {1, 2} routing with renormalized gates, capacity
-dropping per (source shard, choice). Each shard packs its tokens into
-per-expert capacity buckets (choices side by side on the bucket axis so
-ONE all_to_all carries both), exchanges buckets with every peer, applies
-its local expert stack as one batched einsum, and sends results back the
-way they came. Dropped tokens (over capacity) pass through on the
-residual path (combine weight 0), the standard switch behavior; a top-2
-token keeps whichever of its choices fit.
+- **One shard** (`axis_size == 1`, the model's path in
+  `models/transformer.py`): nothing crosses a wire, so nothing needs a
+  capacity. The T x top_k token-choices are ordered by expert, the group
+  sizes counted, the experts run over groups of the sizes that came
+  (`jax.lax.ragged_dot`, which the TPU compiler lowers to a grouped-matmul
+  kernel), and the results go back by the inverse order, weighted by their
+  gates. Every token-choice is computed whatever the load: no drops, no
+  dense pass over all experts.
+- **Across an expert axis** (`axis_size > 1`, inside a `shard_map`): E =
+  axis_size * experts_per_device global experts. Each shard packs its
+  tokens into per-expert capacity buckets (choices side by side on the
+  bucket axis so ONE all_to_all carries them all), exchanges buckets with
+  every peer over ICI, runs its local experts over the equal groups that
+  arrived, and sends results back the way they came. A token-choice over
+  capacity is dropped (combine weight 0, the residual path carries the
+  token): the capacity is what bounds the wire.
 
-`switch_moe` (top-1, one expert per device) is the round-4 surface,
-preserved as a thin special case.
-
-Runs INSIDE a shard_map over the expert axis.
+The gate rule and the expert function are the caller's: `switch_gates`
+(top-1 raw, top-k renormalised) with `gelu_experts` by default, `raw_gates`
+with `swiglu_experts` for OLMoE. `switch_moe` (top-1, one expert per device)
+is the round-4 surface, a thin special case.
 """
 
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 
-def moe_ffn(x, router_w, w_in, w_out, axis_name: str, axis_size: int,
-            top_k: int = 1, capacity_factor: float = 1.25):
-    """x (T, D) tokens on this shard; router_w (D, E).
+class MoeAux(NamedTuple):
+    """What the router says beside the output. `load_balance` = E * sum_e
+    f_e P_e, f_e the share of the token-choices that went to expert e and
+    P_e its mean router probability (1 when both are uniform); `z_loss` =
+    mean over tokens of logsumexp(router logits)^2; `counts` (E,) int32 =
+    token-choices computed per expert, so T * top_k - counts.sum() is what
+    was dropped (0 on one shard by construction); `chosen` (T, top_k) int32
+    = the experts the router took for each token."""
+    load_balance: jax.Array
+    z_loss: jax.Array
+    counts: jax.Array
+    chosen: jax.Array
 
-    w_in (epd, D, F), w_out (epd, F, D) are THIS device's expert stack
-    (leading dim = experts per device); E = axis_size * epd. Returns
-    (out (T, D), aux_loss) — out is zero for dropped tokens (caller adds
-    the residual), aux_loss is the switch load-balancing loss on the
-    primary choice."""
-    if top_k not in (1, 2):
-        raise ValueError(f"top_k must be 1 or 2, got {top_k}")
+
+def switch_gates(top_probs):
+    """Top-1 keeps the RAW router probability (switch semantics); top-k
+    renormalises over the chosen experts (GShard/Mixtral combine)."""
+    if top_probs.shape[-1] == 1:
+        return top_probs
+    return top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
+
+
+def raw_gates(top_probs):
+    """The chosen experts' softmax probabilities as they are (OLMoE,
+    `norm_topk_prob` false)."""
+    return top_probs
+
+
+def gelu_experts(rows, experts, group_sizes):
+    """Two-matrix gelu experts: experts = (w_in (e, D, F), w_out (e, F, D));
+    rows (N, D) ordered by expert in groups of `group_sizes` (e,)."""
+    w_in, w_out = experts
+    h = jax.nn.gelu(lax.ragged_dot(rows, w_in.astype(rows.dtype), group_sizes))
+    return lax.ragged_dot(h, w_out.astype(rows.dtype), group_sizes)
+
+
+@functools.partial(jax.checkpoint, prevent_cse=False)
+def _silu_gate_down(gate, up, w_down, group_sizes):
+    """(silu(gate) * up) @ w_down over the groups. Keeps gate, up and
+    w_down; the silu, the product and with them the matmul's operand are
+    recomputed (PERF.md, PR 25's rule)."""
+    return lax.ragged_dot(jax.nn.silu(gate) * up, w_down, group_sizes)
+
+
+def swiglu_experts(rows, experts, group_sizes):
+    """Three-matrix gated-silu experts: experts = (w_gate (e, D, F), w_up
+    (e, D, F), w_down (e, F, D)); y = w_down (silu(w_gate x) * w_up x)."""
+    w_gate, w_up, w_down = (w.astype(rows.dtype) for w in experts)
+    gate = lax.ragged_dot(rows, w_gate, group_sizes)
+    up = lax.ragged_dot(rows, w_up, group_sizes)
+    return _silu_gate_down(gate, up, w_down, group_sizes)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """x[perm] for a permutation `perm` of x's rows with `inverse` its
+    inverse. The backward pass is the gather g[inverse]: autodiff writes a
+    scatter-add for a gather whose indices it cannot know to be distinct
+    (7.3 ms a step against 2.2 for the gather at 65,536 x 2,048 bf16 on the
+    v5e; PERF.md, PR 27)."""
+    return x[perm]
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inverse: (x[perm], (perm, inverse)),
+    lambda res, g: (g[res[1]], None, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x, order, inverse, top_k: int):
+    """Token-choice c = t * top_k + j reads token t: x[order // top_k], the
+    T * top_k rows in the order `order` of the token-choices (`inverse` its
+    inverse). Backward: the rows' cotangents back in token order by a
+    gather, summed over each token's top_k in float32; not the scatter-add
+    of 65,536 rows into 8,192 that autodiff writes."""
+    return x[order // top_k]
+
+
+def _dispatch_rows_bwd(top_k, res, g):
+    inverse, = res
+    g = g[inverse].reshape(-1, top_k, g.shape[-1])
+    return jnp.sum(g.astype(jnp.float32), axis=1).astype(g.dtype), None, None
+
+
+_dispatch_rows.defvjp(
+    lambda x, order, inverse, top_k: (x[order // top_k], (inverse,)),
+    _dispatch_rows_bwd)
+
+
+def route(x, router_w, top_k: int):
+    """(logits, probs, top_probs, top_idx) of tokens x (T, D): the softmax
+    over all E experts in float32 (the router's matmul at the highest
+    precision: it is T x D x E, nothing beside the experts', and a rounded
+    router weight moves the k-th choice of a token whose k-th and (k+1)-th
+    probabilities are close), the top_k largest taken."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_probs, top_idx = lax.top_k(probs, top_k)
+    return logits, probs, top_probs, top_idx
+
+
+def _aux(logits, probs, counts, chosen) -> MoeAux:
+    """The router's losses from its own choices (`counts` of them an
+    expert, kept or not)."""
+    E = probs.shape[-1]
+    share = counts.astype(jnp.float32) / chosen.size
+    z = jax.nn.logsumexp(logits, axis=-1)
+    return MoeAux(E * jnp.sum(share * jnp.mean(probs, axis=0)),
+                  jnp.mean(jnp.square(z)), counts, chosen)
+
+
+def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
+            top_k: int = 1, capacity_factor: float = 1.25,
+            gates=switch_gates, expert_fn=gelu_experts):
+    """x (T, D) tokens on this shard; router_w (D, E); `experts` a tuple of
+    THIS device's expert weight stacks (leading dim = experts per device,
+    epd; E = axis_size * epd), handed to `expert_fn(rows, experts,
+    group_sizes)`. Returns (out (T, D), MoeAux) — out holds nothing of a
+    dropped token-choice (the caller adds the residual). `capacity_factor`
+    matters only where `axis_size > 1`. With an axis, runs INSIDE a
+    shard_map over it, and `load_balance` / `z_loss` are its means."""
+    if top_k < 1:
+        raise ValueError(f"top_k must be at least 1, got {top_k}")
     T, D = x.shape
-    epd = w_in.shape[0]
+    epd = experts[0].shape[0]
     E = axis_size * epd
     if router_w.shape[-1] != E:
         raise ValueError(
             f"router width {router_w.shape[-1]} != axis_size*epd = {E}"
         )
+    if top_k > E:
+        raise ValueError(f"top_k {top_k} exceeds the {E} experts")
+
+    with jax.named_scope("moe_router"):
+        logits, probs, top_probs, top_idx = route(x, router_w, top_k)
+        gate = gates(top_probs)  # (T, top_k), float32
+
+    if axis_size == 1:
+        with jax.named_scope("moe_dispatch"):
+            # token-choice c = t * top_k + j; `order` lists them by expert
+            flat = top_idx.reshape(T * top_k)
+            order = jnp.argsort(flat, stable=True)
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(T * top_k, dtype=order.dtype))  # order's inverse
+            counts = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+            rows = _dispatch_rows(x, order, back, top_k)  # (T * top_k, D)
+        with jax.named_scope("moe_experts"):
+            y = expert_fn(rows, experts, counts)
+        with jax.named_scope("moe_combine"):
+            y = _permute_rows(y, back, order).reshape(T, top_k, D)
+            out = jnp.sum(y.astype(jnp.float32) * gate[:, :, None],
+                          axis=1).astype(x.dtype)
+        with jax.named_scope("moe_router"):
+            aux = _aux(logits, probs, counts, top_idx)
+        return out, aux
+
     C = max(1, int(capacity_factor * T / E))  # per (shard, choice) capacity
     K = top_k * C  # bucket slots per expert on the wire
-
-    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
-    top_probs, top_idx = lax.top_k(probs, top_k)  # (T, top_k)
-    # top-1 keeps the RAW router prob as its gate (switch semantics);
-    # top-2 renormalizes over the chosen pair (GShard/Mixtral combine)
-    gates = (
-        top_probs
-        if top_k == 1
-        else top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
-    )
-
-    send = jnp.zeros((E, K, D), x.dtype)
-    scat = []
-    for j in range(top_k):
-        expert_j = top_idx[:, j]  # (T,)
-        onehot = jax.nn.one_hot(expert_j, E, dtype=jnp.int32)
-        pos = jnp.cumsum(onehot, axis=0) * onehot
-        slot = jnp.sum(pos, axis=-1) - 1  # 0-based within (expert, choice)
-        kept = slot < C
-        se = jnp.where(kept, expert_j, 0)
-        sc = jnp.where(kept, j * C + slot, 0)
-        send = send.at[se, sc].add(jnp.where(kept[:, None], x, 0),
-                                   mode="drop")
-        scat.append((se, sc, kept))
-
-    # exchange: group bucket rows by destination DEVICE (expert e lives on
-    # device e // epd at local index e % epd)
-    send = send.reshape(axis_size, epd, K, D)
-    recv = lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False)  # (axis_size, epd, K, D)
-    # local expert stack as one batched einsum over the epd dim
-    h = jax.nn.gelu(
-        jnp.einsum("sjkd,jdf->sjkf", recv, w_in.astype(recv.dtype))
-    )
-    y = jnp.einsum("sjkf,jfd->sjkd", h, w_out.astype(recv.dtype))
-    back = lax.all_to_all(y, axis_name, split_axis=0, concat_axis=0,
-                          tiled=False)
-    back = back.reshape(E, K, D)  # my tokens' results, per (expert, slot)
-
-    out = jnp.zeros((T, D), x.dtype)
-    for j, (se, sc, kept) in enumerate(scat):
-        got = back[se, sc]  # (T, D)
-        got = jnp.where(kept[:, None], got, 0)
-        out = out + got.astype(x.dtype) * gates[:, j, None].astype(x.dtype)
-
-    # switch aux loss on the primary choice: E * sum_e frac_e * mean_prob_e
-    onehot1 = jax.nn.one_hot(top_idx[:, 0], E, dtype=jnp.float32)
-    frac = jnp.mean(onehot1, axis=0)
-    mean_prob = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac * mean_prob)
-    aux = lax.pmean(aux, axis_name)
+    with jax.named_scope("moe_dispatch"):
+        send = jnp.zeros((E, K, D), x.dtype)
+        counts = jnp.zeros((E,), jnp.int32)
+        scat = []
+        for j in range(top_k):
+            expert_j = top_idx[:, j]  # (T,)
+            onehot = jax.nn.one_hot(expert_j, E, dtype=jnp.int32)
+            pos = jnp.cumsum(onehot, axis=0) * onehot
+            slot = jnp.sum(pos, axis=-1) - 1  # 0-based within (expert, choice)
+            kept = slot < C
+            se = jnp.where(kept, expert_j, 0)
+            sc = jnp.where(kept, j * C + slot, 0)
+            send = send.at[se, sc].add(jnp.where(kept[:, None], x, 0),
+                                       mode="drop")
+            counts = counts.at[expert_j].add(kept.astype(jnp.int32))
+            scat.append((se, sc, kept))
+        # exchange: group bucket rows by destination DEVICE (expert e lives
+        # on device e // epd at local index e % epd)
+        send = send.reshape(axis_size, epd, K, D)
+        recv = lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
+                              tiled=False)  # (axis_size, epd, K, D)
+    with jax.named_scope("moe_experts"):
+        # the local experts over equal groups: every peer's K slots each
+        rows = recv.transpose(1, 0, 2, 3).reshape(epd * axis_size * K, D)
+        y = expert_fn(rows, experts,
+                      jnp.full((epd,), axis_size * K, jnp.int32))
+        y = y.reshape(epd, axis_size, K, D).transpose(1, 0, 2, 3)
+    with jax.named_scope("moe_combine"):
+        back = lax.all_to_all(y, axis_name, split_axis=0, concat_axis=0,
+                              tiled=False)
+        back = back.reshape(E, K, D)  # my tokens' results, per (expert, slot)
+        out = jnp.zeros((T, D), x.dtype)
+        for j, (se, sc, kept) in enumerate(scat):
+            got = jnp.where(kept[:, None], back[se, sc], 0)
+            out = out + got.astype(x.dtype) * gate[:, j, None].astype(x.dtype)
+    with jax.named_scope("moe_router"):
+        # the losses see the router's choices, kept or not
+        asked = jnp.zeros((E,), jnp.int32).at[top_idx.reshape(-1)].add(1)
+        aux = _aux(logits, probs, asked, top_idx)
+        aux = aux._replace(load_balance=lax.pmean(aux.load_balance, axis_name),
+                           z_loss=lax.pmean(aux.z_loss, axis_name),
+                           counts=counts)
     return out, aux
 
 
 def switch_moe(x, router_w, w_in, w_out, axis_name: str, axis_size: int,
                capacity_factor: float = 1.25):
     """Top-1 switch MoE with one expert per device (the round-4 surface):
-    w_in (D, F), w_out (F, D). See `moe_ffn` for the general form."""
-    return moe_ffn(
-        x, router_w, w_in[None], w_out[None], axis_name, axis_size,
+    w_in (D, F), w_out (F, D). Returns (out, load-balancing loss). See
+    `moe_ffn` for the general form."""
+    out, aux = moe_ffn(
+        x, router_w, (w_in[None], w_out[None]), axis_name, axis_size,
         top_k=1, capacity_factor=capacity_factor,
     )
+    return out, aux.load_balance

@@ -22,3 +22,30 @@ jax.config.update("jax_num_cpu_devices", 8)
 # tests spawn.
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 jax.config.update("jax_enable_compilation_cache", False)
+
+
+def _build_native_once() -> None:
+    """`kungfu_tpu/base/libkfnative.so` is built where it runs
+    (`-march=native`) and git ignores it, so a fresh clone has none and
+    `tests/test_wire_codec.py` and `tests/test_wire_q.py` fail at import.
+    Build it with `native/build.sh` when it is absent: once, under a file
+    lock, because the driver runs six pytest-xdist workers and each imports
+    this file; the others wait for the lock and find the library there. A
+    machine without a compiler keeps the numpy paths and those two files'
+    import errors, as before."""
+    import fcntl
+    import subprocess
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    lib = os.path.join(repo, "kungfu_tpu", "base", "libkfnative.so")
+    build_dir = os.path.join(repo, "native", "build")  # git-ignored
+    os.makedirs(build_dir, exist_ok=True)
+    with open(os.path.join(build_dir, ".conftest.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not os.path.exists(lib):
+            subprocess.run(["sh", os.path.join(repo, "native", "build.sh")],
+                           stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
+                           check=False)
+
+
+_build_native_once()

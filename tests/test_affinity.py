@@ -6,6 +6,8 @@ import sys
 
 import pytest
 
+from ports import kfrun_ports
+
 from kungfu_tpu.runner.affinity import (
     apply_affinity,
     numa_nodes,
@@ -103,6 +105,7 @@ def test_kfrun_use_affinity_masks():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2", "-H", "127.0.0.1:2", "-use-affinity",
             sys.executable, "-c", script,
         ],

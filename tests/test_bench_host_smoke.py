@@ -9,6 +9,8 @@ import sys
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "bench_host_agent.py")
 
@@ -35,6 +37,7 @@ def test_bench_host_ab_smoke(algo, wire):
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2", "-H", "127.0.0.1:2",
             sys.executable, AGENT,
         ],
@@ -74,6 +77,7 @@ def _run_bench(np_, env_extra, timeout=240):
     return subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", str(np_), "-H", f"127.0.0.1:{np_}",
             sys.executable, AGENT,
         ],

@@ -6,6 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ports import kfrun_ports
+
 from kungfu_tpu.elastic.checkpoint import (
     Checkpointer,
     dump_final_variables,
@@ -90,6 +92,7 @@ def test_checkpoint_resume_under_auto_recover(tmp_path):
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2", "-H", "127.0.0.1:2",
             "-auto-recover", "30s",
             sys.executable, agent, str(tmp_path / "ck"),

@@ -9,6 +9,8 @@ import sys
 
 import pytest
 
+from ports import kfrun_ports
+
 import chip_smoke
 from kungfu_tpu.models.transformer import TransformerConfig
 
@@ -89,6 +91,7 @@ def test_worker_bodies_under_kfrun(body, local_devices):
     env["JAX_NUM_CPU_DEVICES"] = str(local_devices)
     r = subprocess.run(
         [sys.executable, "-m", "kungfu_tpu.runner.cli", "-np", "2",
+         *kfrun_ports().args,  # this xdist worker's block
          "-H", "127.0.0.1:2", "--", sys.executable, "-c", _WORKER, body],
         env=env, capture_output=True, text=True, timeout=240, cwd=REPO,
     )

@@ -12,6 +12,8 @@ import sys
 
 import pytest
 
+from ports import kfrun_ports
+
 from kungfu_tpu.runner.slots import SlotPool, partition
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -208,6 +210,7 @@ def test_kfrun_pins_disjoint_devices():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2", "-devices-per-host", "4",
             "--", sys.executable, "-c", agent,
         ],

@@ -12,6 +12,8 @@ import sys
 import numpy as np
 import pytest
 
+from ports import kfrun_ports
+
 from kungfu_tpu.elastic.dataset import ElasticDataset
 from kungfu_tpu.elastic.schedule import parse_schedule, schedule_target
 
@@ -90,6 +92,7 @@ def test_schedule_driven_elastic_training_converges():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2",
             "-H", "127.0.0.1:4",
             "-w",

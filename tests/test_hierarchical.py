@@ -9,6 +9,8 @@ import sys
 import numpy as np
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "hier_agent.py")
 
@@ -71,6 +73,7 @@ def test_hier_two_worlds_bit_identical_to_single_world():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2", "-H", "127.0.0.1:2",
             sys.executable, AGENT,
         ],

@@ -11,6 +11,8 @@ import sys
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "adaptive_agent.py")
 
@@ -21,6 +23,7 @@ def test_slow_link_flips_strategy_cluster_wide():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "3",
             "-H", "127.0.0.1:3",
             "-strategy", "BINARY_TREE_STAR",

@@ -11,6 +11,8 @@ import sys
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "device_agent.py")
 
@@ -24,6 +26,7 @@ def run_device_agent(np_, timeout=240):
     return subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", str(np_),
             "-H", f"127.0.0.1:{np_}",
             "--", sys.executable, AGENT,

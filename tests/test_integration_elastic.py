@@ -11,6 +11,8 @@ import sys
 
 import pytest
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "elastic_agent.py")
 JOINER_FIRST_AGENT = os.path.join(
@@ -24,6 +26,7 @@ def test_elastic_resize_schedule():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2",
             "-H", "127.0.0.1:4",
             "-w",
@@ -48,6 +51,7 @@ def test_joiner_listed_first_cannot_reset_survivor_state():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2",
             "-H", "127.0.0.1:4",
             "-w",

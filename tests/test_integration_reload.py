@@ -11,6 +11,8 @@ import re
 import subprocess
 import sys
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "reload_agent.py")
 
@@ -22,6 +24,7 @@ def test_reload_mode_restarts_with_progress_and_fresh_mesh():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2",
             "-H", "127.0.0.1:4",
             "-w",

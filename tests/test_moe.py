@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -105,9 +106,10 @@ def test_switch_moe_differentiable():
     assert float(jnp.abs(g[0]).sum()) > 0  # router learns via the gate
 
 
-def _dense_topk_reference(x_all, router_w, w_in_all, w_out_all, top_k):
-    """Every token through its top-k experts, renormalized gates, no
-    drops (numpy reference for moe_ffn)."""
+def _dense_topk_reference(x_all, router_w, w_in_all, w_out_all, top_k,
+                          renormalize=True):
+    """Every token through its top-k experts, renormalized gates (or the
+    raw probabilities), no drops (numpy reference for moe_ffn)."""
     logits = x_all.astype(np.float32) @ np.asarray(router_w, np.float32)
     probs = np.exp(logits - logits.max(-1, keepdims=True))
     probs /= probs.sum(-1, keepdims=True)
@@ -116,7 +118,7 @@ def _dense_topk_reference(x_all, router_w, w_in_all, w_out_all, top_k):
     for i in range(len(x_all)):
         chosen = order[i]
         g = probs[i, chosen]
-        if top_k > 1:
+        if top_k > 1 and renormalize:
             g = g / g.sum()
         for ei, gi in zip(chosen, g):
             h = jax.nn.gelu(
@@ -135,70 +137,116 @@ def _run_moe_general(x, router_w, w_in_all, w_out_all, ep, top_k,
     def shard_fn(x_sh, router_w, w_in_sh, w_out_sh):
         # w_*_sh arrive with a leading (1,) shard axis over the (epd, ...)
         # expert stack
-        return moe_ffn(
-            x_sh, router_w, w_in_sh[0], w_out_sh[0], "ep", ep,
+        out, aux = moe_ffn(
+            x_sh, router_w, (w_in_sh[0], w_out_sh[0]), "ep", ep,
             top_k=top_k, capacity_factor=capacity_factor,
         )
+        return out, aux.load_balance, aux.counts
 
     fn = jax.jit(
         shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P("ep"), P(), P("ep"), P("ep")),
-            out_specs=(P("ep"), P()),
+            out_specs=(P("ep"), P(), P("ep")),
             check_vma=False,
         )
     )
     return fn(x, router_w, w_in_all, w_out_all)
 
 
-def test_moe_top2_matches_dense_when_no_drops():
-    ep, T, D, F = 4, 32, 8, 16
-    x = jax.random.normal(jax.random.PRNGKey(0), (T, D), jnp.float32)
-    router_w = jax.random.normal(jax.random.PRNGKey(1), (D, ep), jnp.float32)
-    w_in = jax.random.normal(jax.random.PRNGKey(2), (ep, D, F), jnp.float32) * 0.3
-    w_out = jax.random.normal(jax.random.PRNGKey(3), (ep, F, D), jnp.float32) * 0.3
-    out, aux = _run_moe_general(
-        x, router_w, w_in.reshape(ep, 1, D, F), w_out.reshape(ep, 1, F, D),
-        ep, top_k=2, capacity_factor=float(ep),
-    )
-    ref = _dense_topk_reference(np.asarray(x), router_w, w_in, w_out, top_k=2)
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
-    assert np.isfinite(float(aux)) and float(aux) > 0
-
-
-def test_moe_multiple_experts_per_device():
-    ep, epd, T, D, F = 4, 2, 32, 8, 16
-    E = ep * epd
-    x = jax.random.normal(jax.random.PRNGKey(0), (T, D), jnp.float32)
+def _weights(E, D, F):
     router_w = jax.random.normal(jax.random.PRNGKey(1), (D, E), jnp.float32)
     w_in = jax.random.normal(jax.random.PRNGKey(2), (E, D, F), jnp.float32) * 0.3
     w_out = jax.random.normal(jax.random.PRNGKey(3), (E, F, D), jnp.float32) * 0.3
-    out, aux = _run_moe_general(
-        x, router_w,
-        w_in.reshape(ep, epd, D, F), w_out.reshape(ep, epd, F, D),
-        ep, top_k=1, capacity_factor=float(E),
+    return router_w, w_in, w_out
+
+
+# (top_k, shards, experts per shard): today's top-1 and top-2 cases, and
+# OLMoE's eight
+WIRE_CASES = [(2, 4, 1), (1, 4, 2), (8, 4, 4)]
+
+
+@pytest.mark.parametrize("top_k,ep,epd", WIRE_CASES)
+def test_moe_matches_dense_when_no_drops(top_k, ep, epd):
+    T, D, F = 32, 8, 16
+    E = ep * epd
+    x = jax.random.normal(jax.random.PRNGKey(0), (T, D), jnp.float32)
+    router_w, w_in, w_out = _weights(E, D, F)
+    out, aux, counts = _run_moe_general(
+        x, router_w, w_in.reshape(ep, epd, D, F), w_out.reshape(ep, epd, F, D),
+        ep, top_k=top_k, capacity_factor=float(E),
     )
-    ref = _dense_reference(np.asarray(x), router_w, w_in, w_out)
+    ref = _dense_topk_reference(np.asarray(x), router_w, w_in, w_out, top_k)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
+    assert np.isfinite(float(aux)) and float(aux) > 0
+    # every shard's counts: all of its T / ep * top_k token-choices kept
+    assert np.asarray(counts).reshape(ep, E).sum(1).tolist() == [T // ep * top_k] * ep
 
 
-def test_moe_top2_differentiable():
-    ep, T, D, F = 2, 16, 8, 8
+@pytest.mark.parametrize("top_k", [1, 2, 8])
+def test_moe_one_shard_is_dropless_and_matches_dense(top_k):
+    """`axis_size` 1: no capacity, no wire; every token-choice is computed
+    whatever the load, here with every token sent to the same experts."""
+    from kungfu_tpu.ops.moe import moe_ffn, raw_gates, switch_gates
+
+    T, D, F, E = 48, 8, 16, 16
+    x = jax.random.normal(jax.random.PRNGKey(0), (T, D), jnp.float32)
+    router_w, w_in, w_out = _weights(E, D, F)
+    for gates, renormalize in ((switch_gates, True), (raw_gates, False)):
+        out, aux = jax.jit(lambda x, r: moe_ffn(
+            x, r, (w_in, w_out), top_k=top_k, gates=gates))(x, router_w)
+        ref = _dense_topk_reference(np.asarray(x), router_w, w_in, w_out,
+                                    top_k, renormalize)
+        np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
+        assert int(aux.counts.sum()) == T * top_k
+        assert aux.chosen.shape == (T, top_k)
+    # an adversarial router: the first top_k experts take every token
+    # (a constant feature, so the logits do not depend on the token)
+    x1 = x.at[:, 0].set(1.0)
+    skewed = jnp.zeros((D, E)).at[0, :top_k].set(
+        20.0 + jnp.arange(top_k, 0, -1.0))
+    out, aux = jax.jit(lambda x, r: moe_ffn(
+        x, r, (w_in, w_out), top_k=top_k))(x1, skewed)
+    assert aux.counts.tolist() == [T] * top_k + [0] * (E - top_k)
+    ref = _dense_topk_reference(np.asarray(x1), skewed, w_in, w_out, top_k)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
+    # uniform load balances to 1, everything on top_k of E experts to E / top_k
+    assert float(aux.load_balance) == pytest.approx(E / top_k, rel=1e-3)
+
+
+def test_moe_refuses_what_it_cannot_route():
+    from kungfu_tpu.ops.moe import moe_ffn
+
+    x = jnp.zeros((4, 8))
+    router_w, w_in, w_out = _weights(4, 8, 16)
+    with pytest.raises(ValueError, match="at least 1"):
+        moe_ffn(x, router_w, (w_in, w_out), top_k=0)
+    with pytest.raises(ValueError, match="exceeds the 4 experts"):
+        moe_ffn(x, router_w, (w_in, w_out), top_k=5)
+    with pytest.raises(ValueError, match="router width"):
+        moe_ffn(x, router_w[:, :3], (w_in, w_out))
+
+
+@pytest.mark.parametrize("top_k,ep", [(2, 2), (8, 1)])
+def test_moe_differentiable(top_k, ep):
+    T, D, F = 16, 8, 8
+    E = max(ep, top_k)
+    epd = E // ep
     from kungfu_tpu.ops.moe import moe_ffn
 
     mesh = _ep_mesh(ep)
     x = jax.random.normal(jax.random.PRNGKey(0), (T, D), jnp.float32)
-    router_w = jax.random.normal(jax.random.PRNGKey(1), (D, ep), jnp.float32)
-    w_in = jax.random.normal(jax.random.PRNGKey(2), (ep, 1, D, F), jnp.float32) * 0.3
-    w_out = jax.random.normal(jax.random.PRNGKey(3), (ep, 1, F, D), jnp.float32) * 0.3
+    router_w, w_in, w_out = _weights(E, D, F)
+    w_in, w_out = w_in.reshape(ep, epd, D, F), w_out.reshape(ep, epd, F, D)
 
     def loss(params):
         w_in, w_out, router_w = params
 
         def shard_fn(x_sh, router_w, w_in_sh, w_out_sh):
-            out, aux = moe_ffn(x_sh, router_w, w_in_sh[0], w_out_sh[0],
-                               "ep", ep, top_k=2, capacity_factor=2.0)
-            return jnp.sum(out ** 2) + 0.01 * aux
+            out, aux = moe_ffn(x_sh, router_w, (w_in_sh[0], w_out_sh[0]),
+                               "ep", ep, top_k=top_k, capacity_factor=2.0)
+            return (jax.lax.pmean(jnp.sum(out ** 2), "ep")
+                    + 0.01 * aux.load_balance + 0.001 * aux.z_loss)
 
         fn = shard_map(
             shard_fn, mesh=mesh,
@@ -212,3 +260,4 @@ def test_moe_top2_differentiable():
     for t in g:
         assert np.all(np.isfinite(np.asarray(t)))
     assert float(np.abs(np.asarray(g[0])).sum()) > 0
+    assert float(np.abs(np.asarray(g[2])).sum()) > 0

@@ -25,6 +25,8 @@ import threading
 import numpy as np
 import pytest
 
+from ports import kfrun_ports
+
 from kungfu_tpu.base.ops import ReduceOp
 from kungfu_tpu.base.strategy import Strategy
 from kungfu_tpu.base.workspace import Workspace
@@ -508,6 +510,7 @@ def test_scheduler_bench_smoke_np4_lockwatch():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "4", "-H", "127.0.0.1:4",
             sys.executable, AGENT,
         ],

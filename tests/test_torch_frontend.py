@@ -8,6 +8,8 @@ import sys
 import numpy as np
 import pytest
 
+from ports import kfrun_ports
+
 torch = pytest.importorskip("torch")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -57,6 +59,7 @@ def test_torch_e2e_two_workers(async_mode):
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "2", "-H", "127.0.0.1:2",
             sys.executable, AGENT,
         ],

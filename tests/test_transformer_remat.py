@@ -94,6 +94,7 @@ def both(plain):
             with pytest.MonkeyPatch.context() as m:
                 if patched:
                     m.setattr(transformer, "_block", plain._block)
+                    m.setattr(transformer, "_layer", plain._layer)
                     m.setattr(transformer, "_rmsnorm", plain._rmsnorm)
                 fn = jax.value_and_grad(make())
                 names = set(_primitives(jax.make_jaxpr(fn)(params, batch).jaxpr))

@@ -7,6 +7,8 @@ import re
 import subprocess
 import sys
 
+from ports import kfrun_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "dying_elastic_agent.py")
 
@@ -17,6 +19,7 @@ def test_watch_autorecover_sigkilled_worker():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "3", "-H", "127.0.0.1:4",
             "-w", "-auto-recover", "30s",
             "-warm-spares", "0",

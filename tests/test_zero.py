@@ -29,6 +29,8 @@ import time
 import numpy as np
 import pytest
 
+from ports import kfrun_ports
+
 from kungfu_tpu.base.ops import (
     ReduceOp,
     copy_segment,
@@ -809,6 +811,7 @@ def test_zero_api_e2e_np3_kfrun():
     r = subprocess.run(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
+            *kfrun_ports().args,  # this xdist worker's block
             "-np", "3", "-H", "127.0.0.1:3",
             sys.executable, agent,
         ],
