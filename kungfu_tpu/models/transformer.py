@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from kungfu_tpu.optimizers import core as grad_sync
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -377,13 +379,17 @@ def _embed(params, tokens, cfg: TransformerConfig):
 
 
 def _hidden(params, tokens, cfg: TransformerConfig):
-    """-> (final hidden states, the layers' stacked aux or None)."""
+    """-> (final hidden states, the layers' stacked aux or None). Under
+    plain S-SGD on several chips a layer's gradients are averaged in the
+    iteration of the backward scan that produces them
+    (`optimizers.core.reduce_in_backward`, the identity otherwise)."""
     x = _embed(params, tokens, cfg)
+    stacked = params["layers"]
 
     def body(x, layer):
-        return _layer(x, layer, cfg)
+        return _layer(x, grad_sync.reduce_in_backward(layer, of=stacked), cfg)
 
-    return jax.lax.scan(body, x, params["layers"])
+    return jax.lax.scan(body, x, stacked)
 
 
 def transformer_hidden(params, tokens, cfg: TransformerConfig):
@@ -404,6 +410,12 @@ def transformer_loss(params, batch, cfg: TransformerConfig):
         tokens, targets = batch
     else:
         tokens, targets = batch[:, :-1], batch[:, 1:]
+    # the leaves outside the layer scan (the stack's go through `_hidden`'s):
+    # under plain S-SGD on several chips their gradients are averaged where
+    # the backward pass completes them
+    params = {**grad_sync.reduce_in_backward(
+        {k: v for k, v in params.items() if k != "layers"}),
+        "layers": params["layers"]}
     x, aux = _hidden(params, tokens, cfg)
     loss = lm_head_loss(params, x, targets, cfg)
     if aux is not None:

@@ -5,9 +5,23 @@ Capability parity with the reference optimizer framework
 ada_sgd.py): each wrapper takes a base optax optimizer and injects
 cross-replica communication into the update. TPU-first: the communication
 is `lax.pmean`/`psum` traced into the SAME compiled program as the model
-step, so grad-allreduce overlaps backprop under XLA's scheduler — there is
-no op-ordering problem (the NCCL scheduler's job, scheduler.cpp:37-129, is
-subsumed by XLA's static schedule).
+step, so there is no op-ordering problem (the NCCL scheduler's job,
+scheduler.cpp:37-129, is subsumed by XLA's static schedule).
+
+Where the reduction runs is the program's to say, and on the TPU it is
+never hidden: XLA there emits every all-reduce as a synchronous operation
+of the step program, beside independent matmuls as after a scan, so no
+all-reduce overlaps backprop (read from the compiled programs and from the
+chip, PERF.md, PR 29; the reference's claim for its NCCL scheduler does not
+carry over). A wrapper's `update` gets the gradients when the whole
+backward pass has produced them; for a model whose layers are a `lax.scan`
+that is after the scan, four all-reduces of 435 MB for `bert_base`. Plain
+S-SGD through `parallel.make_train_step` over an axis of more than one
+member instead reduces a layer's gradients in the iteration of the backward
+scan that produces them (`reduce_in_backward`, below): one all-reduce of
+the layer's 28 MB an iteration, as synchronous as before, 0.6 ms a step
+less on four v5e chips. Every other wrapper, and `synchronous_sgd.update`
+called by hand, reduces after the backward pass as it always did.
 
 All wrappers must run inside a `shard_map` over the mesh axis they reduce
 on (see kungfu_tpu.parallel.make_train_step).
@@ -15,6 +29,9 @@ on (see kungfu_tpu.parallel.make_train_step).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -23,21 +40,115 @@ import optax
 from jax import lax
 
 
-def synchronous_sgd(base: optax.GradientTransformation, axis_name: str = "dp") -> optax.GradientTransformation:
+class SynchronousSGD(optax.GradientTransformation):
+    """What `synchronous_sgd` returns: an `optax.GradientTransformation`
+    that also says it is plain S-SGD, over which axis and of which base, so
+    that `make_train_step` may go past its `pmean` to the base where the
+    loss has averaged every gradient already. A transformation built from
+    it (`optax.chain`, `adaptive_sgd`) is not one."""
+
+    def __new__(cls, base: optax.GradientTransformation, axis_name: str):
+        def update_reduced(grads, state, params=None, **extra):
+            with jax.named_scope("optimizer_update"):
+                return base.update(grads, state, params, **extra)
+
+        def update(grads, state, params=None, **extra):
+            with jax.named_scope("grad_allreduce"):
+                grads = jax.tree.map(lambda g: lax.pmean(g, axis_name), grads)
+            return update_reduced(grads, state, params, **extra)
+
+        self = super().__new__(cls, base.init, update)
+        self.axis_name = axis_name
+        # the base's update, for gradients that are the axis' mean already
+        self.update_reduced = update_reduced
+        return self
+
+
+def synchronous_sgd(base: optax.GradientTransformation, axis_name: str = "dp") -> SynchronousSGD:
     """S-SGD (parity: SynchronousSGDOptimizer, sync_sgd.py:15-109): average
-    gradients over the axis before the base update. One fused XLA AllReduce
-    per step (XLA combines the per-leaf psums)."""
+    gradients over the axis before the base update. `update` reduces every
+    leaf after the backward pass (XLA combines the per-leaf psums into a few
+    all-reduces, each a synchronous operation on the TPU: none overlaps
+    anything); through `make_train_step`, with a loss that calls
+    `reduce_in_backward`, each leaf is reduced inside the backward pass
+    instead. Once either way, to the same values."""
+    return SynchronousSGD(base, axis_name)
 
-    def init(params):
-        return base.init(params)
 
-    def update(grads, state, params=None, **extra):
-        with jax.named_scope("grad_allreduce"):
-            grads = jax.tree.map(lambda g: lax.pmean(g, axis_name), grads)
-        with jax.named_scope("optimizer_update"):
-            return base.update(grads, state, params, **extra)
+class _GradSync:
+    """One traced step's record: the axis `make_train_step` declared, the
+    parameter leaves the loss was called with, and those of them whose
+    gradients the loss has averaged over that axis itself."""
 
-    return optax.GradientTransformation(init, update)
+    def __init__(self, axis_name: str):
+        self.axis_name = axis_name
+        self.seen, self.reduced = [], []
+
+    def watching(self, loss_fn):
+        """`loss_fn`, noting the parameters it is differentiated at."""
+        def watched(params, *args):
+            self.seen.extend(jax.tree.leaves(params))
+            return loss_fn(params, *args)
+
+        return watched
+
+    def covers_all(self) -> bool:
+        return bool(self.seen) and all(
+            any(leaf is r for r in self.reduced) for leaf in self.seen)
+
+
+_grad_sync = contextvars.ContextVar("grad_sync", default=None)
+
+
+@contextlib.contextmanager
+def reducing_in_backward(axis_name: str):
+    """While this is open (`make_train_step` opens it around the trace of
+    `value_and_grad(loss_fn)`), `reduce_in_backward` averages over
+    `axis_name`. Yields the trace's `_GradSync`."""
+    sync = _GradSync(axis_name)
+    token = _grad_sync.set(sync)
+    try:
+        yield sync
+    finally:
+        _grad_sync.reset(token)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _mean_cotangent(axis_name, tree):
+    return tree
+
+
+def _mean_cotangent_bwd(axis_name, _, cotangent):
+    with jax.named_scope("grad_allreduce"):
+        return (jax.tree.map(lambda g: lax.pmean(g, axis_name), cotangent),)
+
+
+_mean_cotangent.defvjp(lambda axis_name, tree: (tree, None), _mean_cotangent_bwd)
+
+
+def reduce_in_backward(params, of=None):
+    """For a loss to pass its parameters through, where it first uses them:
+    the identity, whose cotangent is `lax.pmean`ed over the data axis in the
+    place of the backward pass that produces it. Inside a scan over stacked
+    parameters, call it in the body on the iteration's slice and give the
+    stacked tree as `of`: each layer's gradient is then reduced in the
+    iteration of the backward scan that produces it.
+
+    With no axis declared (any caller but `make_train_step` under plain
+    S-SGD over more than one member) it returns `params` itself and nothing
+    is traced."""
+    sync = _grad_sync.get()
+    if sync is None:
+        return params
+    whole = jax.tree.leaves(params if of is None else of)
+    if of is not None:
+        for part, leaf in zip(jax.tree.leaves(params), whole, strict=True):
+            if part.shape != leaf.shape[1:]:
+                raise ValueError(
+                    f"reduce_in_backward: {part.shape} is no slice along the "
+                    f"first axis of {leaf.shape}")
+    sync.reduced.extend(whole)
+    return _mean_cotangent(sync.axis_name, params)
 
 
 class _ZeroState(NamedTuple):

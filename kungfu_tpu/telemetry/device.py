@@ -145,7 +145,9 @@ def phase_of(op_name: str) -> str:
     `optimizer_update`, then any `transpose(`, then any `jvp(` or other
     scope of the model."""
     parts = scope_parts(op_name or "")
-    if "grad_allreduce" in parts:
+    # also as JAX writes it at the top of a transposed function,
+    # `transpose(jvp(grad_allreduce))`: the leaves a loss reduces itself
+    if any("grad_allreduce" in p for p in parts):
         return "all_reduce"
     if "optimizer" in parts or "optimizer_update" in parts:
         return "optimizer"
