@@ -3,11 +3,11 @@ group allreduce (ISSUE 10 tentpole).
 
 The synchronous step loop launches `group_all_reduce` at step end, so
 the engine idles through the whole backprop and then burns a serial
-walk (BENCH_HOST_r06/r07: the bert walk's 43s→27s came entirely from
-engine work, none from overlap). The reference's L4 NCCL scheduler
-(PAPER.md §1) orders collectives by gradient readiness and overlaps
-them with backprop; arXiv:1810.11112 measures that overlap as the
-dominant scale lever. This is the host-plane equivalent:
+walk (PR 4-5, CPU loopback: the bert walk's 43s→27s came entirely from
+engine work, none from overlap; PERF.md, history). The reference's L4
+NCCL scheduler (PAPER.md §1) orders collectives by gradient readiness
+and overlaps them with backprop; arXiv:1810.11112 measures that overlap
+as the dominant scale lever. This is the host-plane equivalent:
 
 - callers :meth:`~CollectiveScheduler.submit` one workspace per tensor
   as its gradient becomes ready and :meth:`~CollectiveScheduler.flush`

@@ -5,7 +5,8 @@ Lazy re-exports (PEP 562): `noise_scale`/`grad_variance` drag in
 jax.numpy machinery (~330 ms even with jax itself already imported),
 and the TRANSPORT imports this package for `monitor.net` on every Peer
 construction — an eager import here put a third of a second inside
-every elastic joiner's critical path (measured round 5, bench_resize).
+every elastic joiner's critical path (measured round 5, on the resize
+latency's phase breakdown).
 """
 
 import importlib

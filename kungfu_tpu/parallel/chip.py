@@ -2,7 +2,7 @@
 compiled programs are kept.
 
 One table names every `device_kind` the repo has run on, with the
-published peaks a benchmark divides by. `chip_smoke.py` and `bench.py`
+published peaks a benchmark divides by. `chip_smoke.py` and `benchmark/`
 both read it; a device that is not in it is an error, never a default.
 """
 

@@ -278,7 +278,7 @@ class StepRecorder:
     def overlap_frac(self) -> Optional[float]:
         """Comm hidden under compute / total comm for this step: the
         engine-busy time not surfaced as flush wait (the scheduler-side
-        measure the BENCH_HOST_r08/r09 OVERLAP lines report)."""
+        measure the host bench's OVERLAP line reports)."""
         if self.busy_us <= 0:
             return None
         return max(0.0, self.busy_us - self.flush_wait_us) / self.busy_us

@@ -325,7 +325,7 @@ class ZeroSGDOptimizer:
     def state_bytes(self) -> int:
         """Optimizer-held bytes on this peer: ~1/k of the replicated
         path in sharded mode (the `kungfu_sharded_update_state_bytes`
-        story the bench reports)."""
+        gauge)."""
         if self._mode is None:
             self._build()
         if self._zs is not None:

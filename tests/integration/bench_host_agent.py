@@ -22,24 +22,10 @@ def main() -> None:
     wire = os.environ.get("KF_BENCH_WIRE", "")
     if wire:
         argv += ["--wire", wire]
-    if os.environ.get("KF_BENCH_WIRE_AB", ""):
-        argv += ["--wire-ab"]
     if os.environ.get("KF_BENCH_ASYNC", ""):
         argv += ["--async"]
     if os.environ.get("KF_BENCH_PASSES", ""):
         argv += ["--passes", os.environ["KF_BENCH_PASSES"]]
-    if os.environ.get("KF_BENCH_ZERO", ""):
-        argv += ["--zero"]
-    if os.environ.get("KF_BENCH_REPLAN", ""):
-        argv += ["--replan"]
-    if os.environ.get("KF_BENCH_DECISIONS", ""):
-        argv += ["--decisions"]
-    if os.environ.get("KF_BENCH_STEPS", ""):
-        argv += ["--steps"]
-    if os.environ.get("KF_BENCH_RESOURCES", ""):
-        argv += ["--resources"]
-    if os.environ.get("KF_BENCH_MEMORY", ""):
-        argv += ["--memory"]
     sys.argv = argv
     from kungfu_tpu.benchmarks.__main__ import main as bench_main
 
