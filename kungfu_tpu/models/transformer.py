@@ -359,11 +359,44 @@ def lm_head_loss(params, x, targets, cfg: TransformerConfig):
     """Final norm + LM head + next-token cross-entropy on hidden states `x`
     (..., S, D). The ONE implementation shared by the dense, ring
     (sequence-parallel) and pipeline paths — a loss change (label
-    smoothing, z-loss, dtype policy) lands everywhere at once."""
+    smoothing, z-loss, dtype policy) lands everywhere at once. A new term
+    of the loss goes into `_xent_fwd` and its derivative into `_xent_bwd`,
+    both as functions of the logits and their log-sum-exp: a `log_softmax`
+    beside them writes the second (rows, vocabulary) array back."""
     with jax.named_scope("head_loss"):
-        logp = jax.nn.log_softmax(_head_logits(params, x, cfg))
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)
-        return -jnp.mean(ll)
+        return _xent(_head_logits(params, x, cfg), targets)
+
+
+@jax.custom_vjp
+def _xent(logits, targets):
+    """mean(logsumexp(logits) - logits[target]) over (..., V) logits and
+    (...) targets. A `custom_vjp` to say which one array of the logits'
+    size the backward pass reads: the logits themselves, which the head's
+    matmul writes anyway, with the per-row log-sum-exp beside them.
+    Autodiff of `log_softmax` keeps the log-probabilities and that of
+    `logsumexp` keeps exp(x - max), each a second such array written and
+    read every step (3.05 ms of bert_base's, PERF.md, PR 31)."""
+    return _xent_fwd(logits, targets)[0]
+
+
+def _xent_fwd(logits, targets):
+    lse = jax.nn.logsumexp(logits, axis=-1)  # shifted by the row maximum
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return jnp.mean(lse - picked), (logits, lse, targets)
+
+
+def _xent_bwd(res, g):
+    """(softmax - onehot) * g / rows, elementwise in the residuals, the
+    one-hot as a comparison with an iota and not a scatter: XLA fuses it
+    into the operands of the two backward matmuls."""
+    logits, lse, targets = res
+    hot = jax.lax.broadcasted_iota(
+        jnp.int32, logits.shape, logits.ndim - 1) == targets[..., None]
+    dlogits = (jnp.exp(logits - lse[..., None]) - hot) * (g / lse.size)
+    return dlogits.astype(logits.dtype), None
+
+
+_xent.defvjp(_xent_fwd, _xent_bwd)
 
 
 def _embed(params, tokens, cfg: TransformerConfig):
