@@ -16,6 +16,15 @@ TPU-first design notes:
   runs (XLA's dense one or `ops.flash_attention`). The defaults are the
   block the repo has always had, so `bert_base()` and `tiny()` mean what
   they meant; `olmoe_1b_7b()` is the first published architecture.
+- Layers may differ in kind (PR 33): `layer_kinds` gives each layer the
+  fields that replace the configuration's own for it (heads, window, rotary
+  rule, feed-forward), successive layers of one kind are one stacked tree
+  and one `lax.scan`, and `params["layers"]` is then the tuple of those
+  stacks in the model's layer order. Grouped heads with a head size of
+  their own, a band mask, a per-head output gate, rotary over part of the
+  head with YaRN's frequencies, renormalised and scaled expert gates, a
+  shared expert, and an expert layer that holds a share of the experts its
+  router sees are each a field.
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -24,8 +33,10 @@ the framework's flagship workload for the BERT-config benchmark
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Tuple
 
 import jax
@@ -59,22 +70,89 @@ class TransformerConfig:
     attn_core: str = "dense"  # or "flash": ops.flash_attention
     flash_blocks: Tuple[int, int] = (512, 512)
     flash_interpret: bool = False  # the tests' CPU mesh; never chosen by backend
+    # a head size of its own, or fewer key/value heads than query heads
+    # (query head h reads key/value head h // (n_heads // n_kv_heads)): either
+    # gives the layer wq, wk, wv and wo of their own widths in wqkv's place
+    head_size: int = 0  # 0: d_model // n_heads
+    n_kv_heads: int = 0  # 0: n_heads
+    window: int = 0  # w: query i sees key j iff 0 <= i - j < w; 0: every earlier key
+    rotary_share: float = 1.0  # the leading share of each head that rope rotates
+    # () or YaRN's (factor, original positions, beta_fast, beta_slow,
+    # attention_factor): blended frequencies, cos and sin times the factor
+    yarn: Tuple = ()
+    head_gate: bool = False  # sigmoid(h @ w_head_gate), one a query head, on the core's output
+    gates: str = "raw"  # or "renorm": the chosen experts' probabilities over their sum
+    routed_scale: float = 1.0  # times the routed experts' gates
+    # (first, count): the router sees n_experts, this chip holds `count` of
+    # them from `first` and computes their part of the layer; (): all
+    experts_held: Tuple = ()
+    shared_ff: int = 0  # a gated-silu expert of this width that every token takes
+    # the layer scan keeps a layer's input alone and runs the layer again in
+    # the backward pass, where what the pieces keep of it would not fit
+    layer_remat: bool = False
+    # one tuple of (field, value) pairs a layer: what replaces the fields
+    # above for that layer; (): every layer is the configuration's own
+    layer_kinds: Tuple = ()
 
     def __post_init__(self):
         for field, value, known in (
                 ("positions", self.positions, ("learned", "rope")),
                 ("ffn", self.ffn, ("gelu", "swiglu", "moe")),
-                ("attn_core", self.attn_core, ("dense", "flash"))):
+                ("attn_core", self.attn_core, ("dense", "flash")),
+                ("gates", self.gates, ("raw", "renorm"))):
             if value not in known:
                 raise ValueError(f"{field} {value!r} is not one of {known}")
         if self.ffn == "moe" and not 1 <= self.top_k <= self.n_experts:
             raise ValueError(f"ffn 'moe' needs 1 <= top_k <= n_experts, got "
                              f"{self.top_k} of {self.n_experts}")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(f"{self.n_heads} query heads are no multiple of "
+                             f"{self.kv_heads} key/value heads")
+        if self.split_qkv and self.qk_norm:
+            raise ValueError("qk_norm spans the features of the fused wqkv; "
+                             "with a head size or key/value heads of their "
+                             "own the layer has wq, wk, wv and no such norm")
+        if (self.window or self.kv_heads != self.n_heads) and self.attn_core != "flash":
+            raise ValueError("a window and grouped heads are the flash core's "
+                             "(attn_core 'flash'); the dense core has neither")
+        if self.layer_kinds and len(self.layer_kinds) != self.n_layers:
+            raise ValueError(f"{len(self.layer_kinds)} layer kinds for "
+                             f"{self.n_layers} layers")
+        for kind in self.layer_kinds:
+            dataclasses.replace(self, layer_kinds=(), n_layers=1, **dict(kind))
 
     @property
     def head_dim(self) -> int:
+        if self.head_size:
+            return self.head_size
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def split_qkv(self) -> bool:
+        """wq, wk, wv, wo of their own widths in the place of wqkv."""
+        return bool(self.head_size or self.n_kv_heads)
+
+    @property
+    def stacks(self) -> Tuple:
+        """((configuration of one layer kind, its successive layers), ...)
+        in the model's layer order; one stack of all layers where the
+        layers do not differ."""
+        if not self.layer_kinds:
+            return ((self, self.n_layers),)
+        runs = []
+        for kind in self.layer_kinds:
+            if runs and runs[-1][0] == kind:
+                runs[-1][1] += 1
+            else:
+                runs.append([kind, 1])
+        return tuple((dataclasses.replace(self, layer_kinds=(), n_layers=n,
+                                          **dict(kind)), n)
+                     for kind, n in runs)
 
     @classmethod
     def bert_base(cls) -> "TransformerConfig":
@@ -117,38 +195,63 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
     def dense(k, shape):
         return jax.random.normal(k, shape, jnp.float32) * scale
 
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
-    layers = []
-    for i in range(cfg.n_layers):
+    D = cfg.d_model
+
+    def init_layer(key, cfg):
         # the gelu block draws what it always drew from four keys; the
-        # other feed-forwards take further keys of a split of their own
-        lk = jax.random.split(keys[2 + i], 4 if cfg.ffn == "gelu" else 6)
+        # other feed-forwards take further keys of a split of their own,
+        # and what PR 33 brought those of a second split
+        F, E = cfg.d_ff, cfg.n_experts
+        lk = jax.random.split(key, 4 if cfg.ffn == "gelu" else 6)
+        if cfg.split_qkv or cfg.head_gate or cfg.shared_ff:
+            xk = jax.random.split(jax.random.fold_in(key, 1), 6)
         layer = {
             "ln1_scale": jnp.ones((D,), jnp.float32),
             "ln2_scale": jnp.ones((D,), jnp.float32),
-            "wqkv": dense(lk[0], (D, 3 * D)),
-            "wo": dense(lk[1], (D, D)),
         }
+        if cfg.split_qkv:
+            q_width, kv_width = (h * cfg.head_dim
+                                 for h in (cfg.n_heads, cfg.kv_heads))
+            layer["wq"] = dense(lk[0], (D, q_width))
+            layer["wk"] = dense(xk[0], (D, kv_width))
+            layer["wv"] = dense(xk[1], (D, kv_width))
+            layer["wo"] = dense(lk[1], (q_width, D))
+        else:
+            layer["wqkv"] = dense(lk[0], (D, 3 * D))
+            layer["wo"] = dense(lk[1], (D, D))
+        if cfg.head_gate:
+            layer["w_head_gate"] = dense(xk[2], (D, cfg.n_heads))
         if cfg.ffn == "gelu":
             layer["w_in"] = dense(lk[2], (D, F))
             layer["w_out"] = dense(lk[3], (F, D))
         else:
-            stack = (E,) if cfg.ffn == "moe" else ()
+            held = cfg.experts_held[1] if cfg.experts_held else E
+            stack = (held,) if cfg.ffn == "moe" else ()
             layer["w_gate"] = dense(lk[2], stack + (D, F))
             layer["w_up"] = dense(lk[3], stack + (D, F))
             layer["w_down"] = dense(lk[4], stack + (F, D))
         if cfg.ffn == "moe":
             layer["router"] = dense(lk[5], (D, E))
+            if cfg.shared_ff:
+                layer["shared_gate"] = dense(xk[3], (D, cfg.shared_ff))
+                layer["shared_up"] = dense(xk[4], (D, cfg.shared_ff))
+                layer["shared_down"] = dense(xk[5], (cfg.shared_ff, D))
         if cfg.qk_norm:
             layer["q_norm_scale"] = jnp.ones((D,), jnp.float32)
             layer["k_norm_scale"] = jnp.ones((D,), jnp.float32)
-        layers.append(layer)
-    # stack layers: leading axis = layer, enables lax.scan over layers
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+        return layer
+
+    # stack layers: leading axis = layer, enables lax.scan over layers; a
+    # stack for each run of layers of one kind
+    stacks, at = [], 2
+    for kind, n in cfg.stacks:
+        layers = [init_layer(keys[at + i], kind) for i in range(n)]
+        stacks.append(jax.tree.map(lambda *xs: jnp.stack(xs), *layers))
+        at += n
     params = {
         "embed": dense(keys[0], (cfg.vocab_size, D)),
         "ln_f_scale": jnp.ones((D,), jnp.float32),
-        "layers": stacked,
+        "layers": tuple(stacks) if cfg.layer_kinds else stacks[0],
     }
     if cfg.positions == "learned":
         params["pos_embed"] = dense(keys[1], (cfg.max_seq, D))
@@ -163,31 +266,51 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     """PartitionSpec tree matching init_transformer's param tree, whatever
     the layer is.
 
-    Column-parallel wqkv/w_in/w_gate/w_up (shard output features over tp),
-    row-parallel wo/w_out/w_down (shard input features over tp); embedding
-    and an untied head sharded over vocab; an expert stack over `ep_axis`
-    on its expert dimension, the router whole. Layer-stacked leaves have a
-    leading layer axis (unsharded). The q/k norms' scales span all of q's
-    features, which tp splits: sharded like them.
+    Column-parallel wqkv (or wq, wk, wv and the head gate)/w_in/w_gate/w_up
+    (shard output features over tp), row-parallel wo/w_out/w_down (shard
+    input features over tp), a shared expert like a gated-silu
+    feed-forward; embedding and an untied head sharded over vocab; an
+    expert stack over `ep_axis` on its expert dimension, the router whole.
+    Layer-stacked leaves have a leading layer axis (unsharded); a
+    configuration with `layer_kinds` has a tuple of such stacks. The q/k
+    norms' scales span all of q's features, which tp splits: sharded like
+    them.
     """
     t, e = tp_axis, ep_axis
-    layers = {
-        "ln1_scale": P(None),
-        "ln2_scale": P(None),
-        "wqkv": P(None, None, t),
-        "wo": P(None, t, None),
-    }
-    if cfg.ffn == "gelu":
-        layers.update(w_in=P(None, None, t), w_out=P(None, t, None))
-    elif cfg.ffn == "swiglu":
-        layers.update(w_gate=P(None, None, t), w_up=P(None, None, t),
-                      w_down=P(None, t, None))
-    else:
-        layers.update(w_gate=P(None, e, None, t), w_up=P(None, e, None, t),
-                      w_down=P(None, e, t, None), router=P(None, None, None))
-    if cfg.qk_norm:
-        layers.update(q_norm_scale=P(None, t), k_norm_scale=P(None, t))
-    specs = {"embed": P(t, None), "ln_f_scale": P(), "layers": layers}
+
+    def stack_specs(cfg):
+        layers = {
+            "ln1_scale": P(None),
+            "ln2_scale": P(None),
+            "wo": P(None, t, None),
+        }
+        if cfg.split_qkv:  # heads over tp, as wqkv's columns are
+            layers.update(wq=P(None, None, t), wk=P(None, None, t),
+                          wv=P(None, None, t))
+        else:
+            layers.update(wqkv=P(None, None, t))
+        if cfg.head_gate:
+            layers.update(w_head_gate=P(None, None, t))
+        if cfg.ffn == "gelu":
+            layers.update(w_in=P(None, None, t), w_out=P(None, t, None))
+        elif cfg.ffn == "swiglu":
+            layers.update(w_gate=P(None, None, t), w_up=P(None, None, t),
+                          w_down=P(None, t, None))
+        else:
+            layers.update(w_gate=P(None, e, None, t), w_up=P(None, e, None, t),
+                          w_down=P(None, e, t, None),
+                          router=P(None, None, None))
+            if cfg.shared_ff:
+                layers.update(shared_gate=P(None, None, t),
+                              shared_up=P(None, None, t),
+                              shared_down=P(None, t, None))
+        if cfg.qk_norm:
+            layers.update(q_norm_scale=P(None, t), k_norm_scale=P(None, t))
+        return layers
+
+    stacks = tuple(stack_specs(kind) for kind, _ in cfg.stacks)
+    specs = {"embed": P(t, None), "ln_f_scale": P(),
+             "layers": stacks if cfg.layer_kinds else stacks[0]}
     if cfg.positions == "learned":
         specs["pos_embed"] = P()
     if not cfg.tied_head:
@@ -246,22 +369,52 @@ def _gelu_out(pre, w_out):
     return jax.nn.gelu(pre) @ w_out
 
 
-@functools.partial(_recompute, static_argnums=(2,))
-def _rope(q, k, theta: float):
-    """Rotary positions on (B, H, S, hd) q and k, positions 0..S-1: the
-    rotate-half form over the whole head dimension, angles and the rotation
-    in float32. Keeps q and k; the angles, their cos and sin and the
-    rotation are recomputed."""
+def _yarn_ramp(rd: int, theta: float, yarn: Tuple):
+    """YaRN's blend (arXiv:2309.00071, as the transformers library's
+    `_compute_yarn_parameters` computes it): 0 for the rd // 2 frequencies
+    that turn more than beta_fast times over the original positions and
+    keep their own frequency, 1 for those that turn fewer than beta_slow
+    times and take theirs over `factor`, linear between."""
+    _, original, beta_fast, beta_slow, _ = yarn
+
+    def dim_of(turns):
+        return (rd * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), rd - 1)
+    span = (high - low) or 0.001
+    return jnp.clip((jnp.arange(rd // 2, dtype=jnp.float32) - low) / span, 0, 1)
+
+
+@functools.partial(_recompute, static_argnums=(2, 3, 4))
+def _rope(q, k, theta: float, share: float, yarn: Tuple):
+    """Rotary positions on (B, H, S, hd) q and k (each its own H),
+    positions 0..S-1: the rotate-half form over the leading `share` of the
+    head dimension (the rest passes through), angles and the rotation in
+    float32, under `yarn` its frequencies and attention factor. Keeps q and
+    k; the angles, their cos and sin and the rotation are recomputed."""
     S, hd = q.shape[2], q.shape[3]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    rd = int(hd * share)  # the rotated features
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
+    if yarn:
+        ramp = _yarn_ramp(rd, theta, yarn)
+        inv_freq = inv_freq / yarn[0] * ramp + inv_freq * (1 - ramp)
     angles = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)  # (S, hd)
+    angles = jnp.concatenate([angles, angles], axis=-1)  # (S, rd)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn:
+        cos, sin = cos * yarn[4], sin * yarn[4]
 
     def rotate(t):
         t32 = t.astype(jnp.float32)
-        half = jnp.concatenate([-t32[..., hd // 2:], t32[..., :hd // 2]], axis=-1)
-        return (t32 * cos + half * sin).astype(t.dtype)
+        if rd < hd:
+            t32, rest = t32[..., :rd], t32[..., rd:]
+        half = jnp.concatenate([-t32[..., rd // 2:], t32[..., :rd // 2]], axis=-1)
+        turned = t32 * cos + half * sin
+        if rd < hd:
+            turned = jnp.concatenate([turned, rest], axis=-1)
+        return turned.astype(t.dtype)
 
     return rotate(q), rotate(k)
 
@@ -281,32 +434,96 @@ def attention_core_of(cfg: TransformerConfig):
 
     blk_q, blk_k = cfg.flash_blocks
     return lambda q, k, v: flash_attention(q, k, v, True, None, blk_q, blk_k,
-                                           cfg.flash_interpret)
+                                           cfg.flash_interpret,
+                                           cfg.window or None)
 
 
-def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None):
-    """QKV projection + head reshape around a pluggable (q,k,v)->ctx core
-    (the configuration's by default, the ring core for sequence parallelism
-    — ONE copy of the projection plumbing for every path). `qk_scales` =
-    (q_norm_scale, k_norm_scale) where the configuration norms q and k."""
-    B, S, D = x.shape
-    H, hd = cfg.n_heads, cfg.head_dim
-    qkv = x @ wqkv  # (B, S, 3D)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    if cfg.qk_norm:
-        with jax.named_scope("qk_norm"):
-            q = _rmsnorm(q, qk_scales[0], cfg.norm_eps)
-            k = _rmsnorm(k, qk_scales[1], cfg.norm_eps)
-    q = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+def _gated_out(ctx, pre, wo):
+    """(ctx (B, H, S, hd) times sigmoid(pre (B, S, H)), a scalar a head and
+    position, the sigmoid in float32) as (B, S, H * hd) @ wo. Under its
+    checkpoint (`_kept`) it keeps ctx, which the core keeps anyway, pre and
+    wo; the gated copy of ctx, the matmul's operand, is made again, as
+    `_gelu_out` makes its gelu again."""
+    B, H, S, hd = ctx.shape
+    gate = jax.nn.sigmoid(pre.astype(jnp.float32)).astype(ctx.dtype)
+    ctx = ctx * gate.transpose(0, 2, 1)[..., None]
+    return ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd) @ wo
+
+
+def _split_heads(x, wqkv, cfg):
+    """x @ (wq, wk, wv) as (B, heads, S, hd) q, k, v with rotary positions.
+    Under its checkpoint (`_kept`) it keeps x and the matrices: q before
+    its rotation (0.15 GB a layer of 72 heads at 8,192 positions) is made
+    again with the rotation, where `_rope` alone keeps it beside the
+    rotated q that the core keeps."""
+    B, S, _ = x.shape
+    q, k, v = (
+        (x @ w).reshape(B, S, -1, cfg.head_dim).transpose(0, 2, 1, 3)
+        for w in wqkv)
     if cfg.positions == "rope":
         with jax.named_scope("rope"):
-            q, k = _rope(q, k, cfg.rope_theta)
-    with jax.named_scope("attn_core"):
+            q, k = _rope(q, k, cfg.rope_theta, cfg.rotary_share, cfg.yarn)
+    return q, k, v
+
+
+_KEPT = {
+    _gated_out: _recompute(_gated_out),
+    _split_heads: functools.partial(_recompute, static_argnums=(2,))(_split_heads),
+}
+
+
+def _kept(piece, cfg: TransformerConfig):
+    """`piece` under a checkpoint of its own, which says what it keeps for
+    the backward pass; `piece` as it is in a layer that is run again whole
+    (`layer_remat`), where a checkpoint inside would run it a third time."""
+    return piece if cfg.layer_remat else _KEPT[piece]
+
+
+def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
+               w_head_gate=None):
+    """QKV projection + head reshape around a pluggable (q,k,v)->ctx core
+    (the configuration's by default, the ring core for sequence parallelism
+    — ONE copy of the projection plumbing for every path). `wqkv` is the
+    fused (D, 3D) matrix, or (wq, wk, wv) where q's width and k's, v's are
+    the configuration's own (`split_qkv`). `qk_scales` = (q_norm_scale,
+    k_norm_scale) where the configuration norms q and k; `w_head_gate` (D,
+    H) where it gates each head's output."""
+    B, S, _ = x.shape
+    H, hd = cfg.n_heads, cfg.head_dim
+    if cfg.split_qkv:
+        q, k, v = _kept(_split_heads, cfg)(x, wqkv, cfg)
+    else:
+        qkv = x @ wqkv  # (B, S, 3D)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = _rmsnorm(q, qk_scales[0], cfg.norm_eps)
+                k = _rmsnorm(k, qk_scales[1], cfg.norm_eps)
+        q = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+        k = k.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+        if cfg.positions == "rope":
+            with jax.named_scope("rope"):
+                q, k = _rope(q, k, cfg.rope_theta, cfg.rotary_share, cfg.yarn)
+    with _core_kind_scope(cfg), jax.named_scope("attn_core"):
         ctx = (core or attention_core_of(cfg))(q, k, v)
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, D)
+    if cfg.head_gate:
+        with jax.named_scope("attn_gate"):
+            return _kept(_gated_out, cfg)(ctx, x @ w_head_gate, wo)
+    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     return ctx @ wo
+
+
+def _core_kind_scope(cfg: TransformerConfig):
+    """`attn_window` or `attn_full` around the core where a model has both
+    kinds of layer to tell apart (a window anywhere in it, or split
+    projections); nothing more around the one core every other
+    configuration runs."""
+    if cfg.window:
+        return jax.named_scope("attn_window")
+    if cfg.split_qkv:
+        return jax.named_scope("attn_full")
+    return contextlib.nullcontext()
 
 
 def _layer(x, layer, cfg: TransformerConfig, core=None):
@@ -316,19 +533,31 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
     with jax.named_scope("attn"):
         scales = ((layer["q_norm_scale"], layer["k_norm_scale"])
                   if cfg.qk_norm else None)
-        x = x + _attention(_rmsnorm(x, layer["ln1_scale"], eps),
-                           layer["wqkv"].astype(dt), layer["wo"].astype(dt),
-                           cfg, core=core, qk_scales=scales)
+        h = _rmsnorm(x, layer["ln1_scale"], eps)
+        wqkv = (tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv"))
+                if cfg.split_qkv else layer["wqkv"].astype(dt))
+        x = x + _attention(h, wqkv, layer["wo"].astype(dt),
+                           cfg, core=core, qk_scales=scales,
+                           w_head_gate=(layer["w_head_gate"].astype(dt)
+                                        if cfg.head_gate else None))
     if cfg.ffn == "moe":
-        from kungfu_tpu.ops.moe import moe_ffn, raw_gates, swiglu_experts
+        from kungfu_tpu.ops.moe import (moe_ffn, raw_gates, renormalised_gates,
+                                        scaled, swiglu_experts)
 
         with jax.named_scope("moe"):
             B, S, D = x.shape
             h = _rmsnorm(x, layer["ln2_scale"], eps).reshape(B * S, D)
+            gates = raw_gates if cfg.gates == "raw" else renormalised_gates
             y, aux = moe_ffn(
                 h, layer["router"],
                 (layer["w_gate"], layer["w_up"], layer["w_down"]),
-                top_k=cfg.top_k, gates=raw_gates, expert_fn=swiglu_experts)
+                top_k=cfg.top_k, gates=scaled(gates, cfg.routed_scale),
+                expert_fn=swiglu_experts, held=cfg.experts_held or None)
+            if cfg.shared_ff:
+                with jax.named_scope("moe_shared"):
+                    y = y + _silu_gate_out(h @ layer["shared_gate"].astype(dt),
+                                           h @ layer["shared_up"].astype(dt),
+                                           layer["shared_down"].astype(dt))
             return x + y.reshape(B, S, D), aux
     with jax.named_scope("ffn"):
         h = _rmsnorm(x, layer["ln2_scale"], eps)
@@ -338,6 +567,15 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
                                       layer["w_down"].astype(dt)), None
         pre = h @ layer["w_in"].astype(dt)
         return x + _gelu_out(pre, layer["w_out"].astype(dt)), None
+
+
+# `layer_remat`: the scan keeps the layer's input and, of what the layer
+# computes, the flash core's output and row sums alone (0.15 GB a layer of
+# 72 heads at 8,192 positions): the projections, the rotation and the
+# feed-forward are run again in the backward pass, the forward kernel is not
+_layer_again = jax.checkpoint(
+    _layer, static_argnums=(2,), prevent_cse=False,
+    policy=jax.checkpoint_policies.save_only_these_names("flash_out", "flash_lse"))
 
 
 def _block(x, layer, cfg: TransformerConfig, core=None):
@@ -412,17 +650,28 @@ def _embed(params, tokens, cfg: TransformerConfig):
 
 
 def _hidden(params, tokens, cfg: TransformerConfig):
-    """-> (final hidden states, the layers' stacked aux or None). Under
-    plain S-SGD on several chips a layer's gradients are averaged in the
-    iteration of the backward scan that produces them
-    (`optimizers.core.reduce_in_backward`, the identity otherwise)."""
+    """-> (final hidden states, the expert layers' stacked aux or None),
+    one scan for each stack of layers of one kind. Under plain S-SGD on
+    several chips a layer's gradients are averaged in the iteration of the
+    backward scan that produces them (`optimizers.core.reduce_in_backward`,
+    the identity otherwise)."""
     x = _embed(params, tokens, cfg)
-    stacked = params["layers"]
+    stacks = params["layers"] if cfg.layer_kinds else (params["layers"],)
+    auxes = []
+    for (kind, _), stacked in zip(cfg.stacks, stacks, strict=True):
 
-    def body(x, layer):
-        return _layer(x, grad_sync.reduce_in_backward(layer, of=stacked), cfg)
+        run = _layer_again if kind.layer_remat else _layer
 
-    return jax.lax.scan(body, x, stacked)
+        def body(x, layer, kind=kind, stacked=stacked, run=run):
+            return run(
+                x, grad_sync.reduce_in_backward(layer, of=stacked), kind)
+
+        x, aux = jax.lax.scan(body, x, stacked)
+        if aux is not None:
+            auxes.append(aux)
+    if len(auxes) > 1:  # the expert layers' aux, stack after stack
+        return x, jax.tree.map(lambda *a: jnp.concatenate(a), *auxes)
+    return x, auxes[0] if auxes else None
 
 
 def transformer_hidden(params, tokens, cfg: TransformerConfig):
@@ -451,7 +700,7 @@ def transformer_loss(params, batch, cfg: TransformerConfig):
         "layers": params["layers"]}
     x, aux = _hidden(params, tokens, cfg)
     loss = lm_head_loss(params, x, targets, cfg)
-    if aux is not None:
+    if aux is not None and (cfg.router_aux_coef or cfg.router_z_coef):
         with jax.named_scope("moe"), jax.named_scope("moe_router"):
             loss = (loss + cfg.router_aux_coef * jnp.mean(aux.load_balance)
                     + cfg.router_z_coef * jnp.mean(aux.z_loss))
@@ -459,29 +708,42 @@ def transformer_loss(params, batch, cfg: TransformerConfig):
 
 
 def routing_stats(params, tokens, cfg: TransformerConfig):
-    """What the router did with tokens (B, S), layer by layer: jit this
-    beside the step (the step returns a loss and nothing else). `counts`
-    (L, E) token-choices computed per expert, `dropped` (L,) of the B * S *
-    top_k that were not (0: the expert layer has no capacity), and
-    `max_over_mean` (L,) the busiest expert's load over the mean load;
-    `chosen` (L, B * S, top_k) the experts each token took."""
+    """What the router did with tokens (B, S), expert layer by expert
+    layer: jit this beside the step (the step returns a loss and nothing
+    else). `counts` (L, experts held) token-choices computed per expert
+    here, `held_rows` (L,) their sum, `dropped` (L,) of the token-choices
+    that fell on an expert held here those that were not computed (0: the
+    expert layer has no capacity), and `max_over_mean` (L,) the busiest
+    held expert's load over the mean load of all the router's experts;
+    `chosen` (L, B * S, top_k) the experts each token took; `layer` (L,)
+    which of the model's layers each row is."""
     _, aux = _hidden(params, tokens, cfg)
     if aux is None:
         raise ValueError("routing_stats: the configuration has no expert layer")
-    choices = tokens.size * cfg.top_k
+    kinds = [kind for kind, n in cfg.stacks for _ in range(n)]
+    moe = [kind for kind in kinds if kind.ffn == "moe"][0]
+    choices = tokens.size * moe.top_k
+    first, held = moe.experts_held or (0, moe.n_experts)
     counts = aux.counts
+    asked = jnp.sum((aux.chosen >= first) & (aux.chosen < first + held),
+                    axis=(1, 2))
     return {
         "counts": counts,
-        "dropped": choices - jnp.sum(counts, axis=-1),
-        "max_over_mean": jnp.max(counts, axis=-1) * (cfg.n_experts / choices),
+        "held_rows": jnp.sum(counts, axis=-1),
+        "dropped": asked - jnp.sum(counts, axis=-1),
+        "max_over_mean": jnp.max(counts, axis=-1) * (moe.n_experts / choices),
         "chosen": aux.chosen,
+        "layer": jnp.asarray([i for i, kind in enumerate(kinds)
+                              if kind.ffn == "moe"], jnp.int32),
     }
 
 
 def record_routing(stats, registry=None) -> None:
     """`routing_stats`' numbers as gauges of `telemetry.metrics`, a series
-    a layer: `kungfu_moe_dropped_token_choices`, `kungfu_moe_max_over_mean_load`
-    and, per expert, `kungfu_moe_expert_token_choices`."""
+    a layer: `kungfu_moe_dropped_token_choices`, `kungfu_moe_max_over_mean_load`,
+    `kungfu_moe_held_rows` and `kungfu_moe_held_share` (the token-choices
+    computed here, and their share of all the layer's), and, per expert
+    held, `kungfu_moe_expert_token_choices`."""
     from kungfu_tpu.telemetry import metrics
 
     reg = registry or metrics.REGISTRY
@@ -494,9 +756,19 @@ def record_routing(stats, registry=None) -> None:
     load = reg.gauge("kungfu_moe_expert_token_choices",
                      "token-choices computed by one expert",
                      ("layer", "expert"))
-    for layer, row in enumerate(np.asarray(stats["counts"])):
-        dropped.labels(layer).set(float(stats["dropped"][layer]))
-        skew.labels(layer).set(float(stats["max_over_mean"][layer]))
+    rows = reg.gauge("kungfu_moe_held_rows",
+                     "token-choices computed by the experts held here",
+                     ("layer",))
+    share = reg.gauge("kungfu_moe_held_share",
+                      "held rows over all the layer's token-choices",
+                      ("layer",))
+    choices = stats["chosen"][0].size
+    for i, row in enumerate(np.asarray(stats["counts"])):
+        layer = int(stats["layer"][i])
+        dropped.labels(layer).set(float(stats["dropped"][i]))
+        skew.labels(layer).set(float(stats["max_over_mean"][i]))
+        rows.labels(layer).set(float(stats["held_rows"][i]))
+        share.labels(layer).set(float(stats["held_rows"][i]) / choices)
         for expert, n in enumerate(row):
             load.labels(layer, expert).set(float(n))
 

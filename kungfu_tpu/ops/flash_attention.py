@@ -14,6 +14,17 @@ from it — the standard two-pass flash backward. Neither direction ever
 materializes an (S, S) tensor. A sequence length the blocks do not
 divide is an error, not a dense fallback.
 
+Two things a layer may ask beside the causal mask (ROADMAP D4, PR 33).
+**Grouped heads**: k and v with fewer heads than q, H = g x Hkv; query head
+h reads key/value head h // g through the index maps (no copy of k, v is
+made), and the dK/dV kernel's grid runs over the key/value heads with the
+g query heads of a group inside its sequential sweep, so dk, dv are summed
+in VMEM and written once. **A window**: key j is seen by query i iff
+0 <= i - j < window; every kernel's sweep then covers the blocks that
+touch the band and no others (`_kv_steps`, `_q_steps`: 2 of 16 at blocks
+of 512, window 512 and 8,192 positions). A call with neither is the
+program it was before both.
+
 The kernels compile with Mosaic unless the caller passes
 `interpret=True` (the tests, on the CPU mesh); the backend is never
 consulted to choose. `models/transformer.py` runs them as the attention
@@ -27,16 +38,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
 
 
-def _dense_reference(q, k, v, causal: bool, sm_scale: float):
+def _dense_reference(q, k, v, causal: bool, sm_scale: float, window=None):
     S = q.shape[2]
+    g = q.shape[1] // k.shape[1]
+    if g > 1:  # query head h reads key/value head h // g
+        k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sm_scale
     if causal:
-        mask = jnp.tril(jnp.ones((S, S), bool))
+        behind = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+        mask = behind >= 0 if window is None else (behind >= 0) & (behind < window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
@@ -50,22 +66,65 @@ def _nt(a, b):
                            preferred_element_type=jnp.float32)
 
 
+def _first_kv_block(blk_q: int, blk_k: int, window, i):
+    """The first k-block that q-block i's rows see under a window: the
+    block of key i * blk_q - (window - 1), row 0's oldest."""
+    return jnp.maximum(i * blk_q - (window - 1), 0) // blk_k
+
+
+def _last_q_block(blk_q: int, blk_k: int, window, S: int, j):
+    """The last q-block that sees k-block j: the block of query
+    j * blk_k + blk_k - 1 + window - 1, the newest key's last reader."""
+    return jnp.minimum(j * blk_k + blk_k + window - 2, S - 1) // blk_q
+
+
+def _kv_steps(S: int, blk_q: int, blk_k: int, window) -> int:
+    """The length of a q-block's sweep over k-blocks: all of them without a
+    window, and with one the most that any q-block's band touches, first
+    live block to diagonal."""
+    if window is None:
+        return S // blk_k
+    return max((r + blk_q - 1) // blk_k - max(r - (window - 1), 0) // blk_k + 1
+               for r in range(0, S, blk_q))
+
+
+def _q_steps(S: int, blk_q: int, blk_k: int, window) -> int:
+    """The length of a k-block's sweep over q-blocks, as `_kv_steps`:
+    diagonal to last reader."""
+    if window is None:
+        return S // blk_q
+    return max(min(c + blk_k + window - 2, S - 1) // blk_q - c // blk_q + 1
+               for c in range(0, S, blk_k))
+
+
+def _mask(q_off, k_off, blk_q: int, blk_k: int, window):
+    """(blk_q, blk_k) bool: key seen by query, causal and inside the window."""
+    qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
+    kpos = k_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
+    if window is None:
+        return kpos <= qpos
+    return (kpos <= qpos) & (qpos - kpos < window)
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-            blk_q: int, blk_k: int, causal: bool, sm_scale: float):
+            blk_q: int, blk_k: int, causal: bool, sm_scale: float,
+            window=None):
     """One (bh, q-block, k-block) grid program. The TPU grid runs the
     LAST dimension sequentially on one core, so the (m, l, acc) flash
     accumulators live in VMEM scratch across the k-block sweep; K/V
     arrive one block at a time via BlockSpec streaming — VMEM holds
-    O(blk) state regardless of S."""
+    O(blk) state regardless of S. Under a window the sweep starts at the
+    q-block's first live k-block."""
     from jax.experimental import pallas as pl
 
-    kb = pl.program_id(2)
+    step = pl.program_id(2)
     qi = pl.program_id(1)
     n_kb = pl.num_programs(2)
+    kb = step if window is None else step + _first_kv_block(blk_q, blk_k, window, qi)
     q_off = qi * blk_q
     k_off = kb * blk_k
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr[...], NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr[...])
@@ -79,9 +138,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         q, k, v = q_ref[0], k_ref[0], v_ref[0]
         s = _nt(q, k) * sm_scale
         if causal:
-            qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            kpos = k_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            mask = kpos <= qpos
+            mask = _mask(q_off, k_off, blk_q, blk_k, window)
             s = jnp.where(mask, s, NEG_INF)
             maskf = mask.astype(jnp.float32)
         else:
@@ -96,7 +153,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         )
         m_scr[:, :1] = m_new
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(step == n_kb - 1)
     def _finalize():
         o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
         # log-sum-exp per row: the backward recomputes exact block probs
@@ -121,39 +178,62 @@ def _blocks(S: int, blk_q: int, blk_k: int):
     return blk_q, blk_k
 
 
-def _kv_index(blk_q, blk_k, causal, b, i, j):
+def _group(q, k, v, causal: bool, window) -> int:
+    """Query heads to a key/value head; raises for shapes and masks the
+    kernels do not run."""
+    H, Hkv = q.shape[1], k.shape[1]
+    if k.shape != v.shape or H % Hkv or (
+            k.shape[:1] + k.shape[2:] != q.shape[:1] + q.shape[2:]):
+        raise ValueError(
+            f"flash_attention: q {q.shape} against k {k.shape}, v {v.shape}: "
+            "k and v share a shape, and q's heads are a multiple of theirs")
+    if window is not None and not (causal and window >= 1):
+        raise ValueError("flash_attention: a window is causal (0 <= i - j < "
+                         f"window) and at least 1, got {window}")
+    return H // Hkv
+
+
+def _kv_head(g: int, b):
+    """Row b of q's (B * H) leading axis reads row b // g of k's and v's
+    (B * Hkv): H = g * Hkv, so the batch index carries over."""
+    return b if g == 1 else lax.div(b, g)
+
+
+def _kv_index(blk_q, blk_k, causal, window, g, b, i, j):
+    """Step j of q-block i's sweep: its k-block, clamped at the diagonal so
+    that a dead step repeats the last live index and Pallas skips the
+    fetch (`pl.when` already skips the compute)."""
     if not causal:
-        return (b, j, 0)
+        return (_kv_head(g, b), j, 0)
     diag = (i * blk_q + blk_q - 1) // blk_k  # last live k-block for q-block i
-    return (b, jnp.minimum(j, diag), 0)
+    j = j if window is None else j + _first_kv_block(blk_q, blk_k, window, i)
+    return (_kv_head(g, b), jnp.minimum(j, diag), 0)
 
 
 def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
-             blk_k: int, interpret, with_lse: bool = False):
+             blk_k: int, interpret, with_lse: bool = False, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, hd = q.shape
+    g = _group(q, k, v, causal, window)
     blk_q, blk_k = _blocks(S, blk_q, blk_k)
     qf = q.reshape(B * H, S, hd)
-    kf = k.reshape(B * H, S, hd)
-    vf = v.reshape(B * H, S, hd)
+    kf = k.reshape(B * H // g, S, hd)
+    vf = v.reshape(B * H // g, S, hd)
+    kv_index = functools.partial(_kv_index, blk_q, blk_k, causal, window, g)
     out, lse = pl.pallas_call(
         functools.partial(_kernel, blk_q=blk_q, blk_k=blk_k, causal=causal,
-                          sm_scale=sm_scale),
+                          sm_scale=sm_scale, window=window),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
             jax.ShapeDtypeStruct((B * H, S, 8), jnp.float32),
         ],
-        grid=(B * H, S // blk_q, S // blk_k),
+        grid=(B * H, S // blk_q, _kv_steps(S, blk_q, blk_k, window)),
         in_specs=[
             pl.BlockSpec((1, blk_q, hd), lambda b, i, j: (b, i, 0)),
-            # causal: clamp the K/V block index at the q-block's diagonal
-            # so dead above-diagonal blocks repeat the previous index and
-            # Pallas skips their HBM fetch entirely (pl.when already
-            # skips their compute)
-            pl.BlockSpec((1, blk_k, hd), functools.partial(_kv_index, blk_q, blk_k, causal)),
-            pl.BlockSpec((1, blk_k, hd), functools.partial(_kv_index, blk_q, blk_k, causal)),
+            pl.BlockSpec((1, blk_k, hd), kv_index),
+            pl.BlockSpec((1, blk_k, hd), kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, blk_q, hd), lambda b, i, j: (b, i, 0)),
@@ -174,7 +254,7 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                dq_scr, *, blk_q: int, blk_k: int, causal: bool,
-               sm_scale: float):
+               sm_scale: float, window=None):
     """dQ: per (bh, q-block) program, k-blocks stream sequentially.
     Block probs are recomputed exactly from the saved row LSE (standard
     two-pass flash backward), so no (S, S) tensor exists anywhere:
@@ -184,13 +264,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     """
     from jax.experimental import pallas as pl
 
-    kb = pl.program_id(2)
+    step = pl.program_id(2)
     qi = pl.program_id(1)
     n_kb = pl.num_programs(2)
+    kb = step if window is None else step + _first_kv_block(blk_q, blk_k, window, qi)
     q_off = qi * blk_q
     k_off = kb * blk_k
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
 
@@ -204,22 +285,24 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         s = _nt(q, k) * sm_scale
         p = jnp.exp(s - lse)
         if causal:
-            qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            kpos = k_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            p = jnp.where(kpos <= qpos, p, 0.0)
+            p = jnp.where(_mask(q_off, k_off, blk_q, blk_k, window), p, 0.0)
         ds = p * (_nt(do, v) - delta)
         dq_scr[...] += jnp.dot(ds.astype(k.dtype), k,
                                preferred_element_type=jnp.float32) * sm_scale
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(step == n_kb - 1)
     def _finalize():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
                 dv_ref, dk_scr, dv_scr, *, blk_q: int, blk_k: int,
-                causal: bool, sm_scale: float):
-    """dK/dV: per (bh, k-block) program, q-blocks stream sequentially:
+                causal: bool, sm_scale: float, window=None, n_qb=None,
+                seq=None):
+    """dK/dV: per (key/value head, k-block) program, the q-blocks of each
+    of the group's query heads stream sequentially (`n_qb` steps a head,
+    all of the sweep where the heads are not grouped), so a group's dk
+    and dv are summed in the scratch and written once:
         p   = exp(q k^T * scale - lse)
         dv += p^T @ dO
         ds  = p * (dO v^T - delta)
@@ -227,18 +310,24 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
     """
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
     kj = pl.program_id(1)
-    n_qb = pl.num_programs(2)
+    n_steps = pl.num_programs(2)
+    qi = step if n_qb is None else lax.rem(step, n_qb)
+    if window is not None:
+        qi = qi + (kj * blk_k) // blk_q  # the k-block's first live q-block
     q_off = qi * blk_q
     k_off = kj * blk_k
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
 
-    live = (q_off + blk_q - 1 >= k_off) if causal else (qi >= 0)
+    if window is not None:  # from the diagonal, as far as the last reader
+        live = qi <= _last_q_block(blk_q, blk_k, window, seq, kj)
+    else:
+        live = (q_off + blk_q - 1 >= k_off) if causal else (qi >= 0)
 
     @pl.when(live)
     def _compute():
@@ -248,9 +337,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
         s = _nt(q, k) * sm_scale
         p = jnp.exp(s - lse)
         if causal:
-            qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            kpos = k_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            p = jnp.where(kpos <= qpos, p, 0.0)
+            p = jnp.where(_mask(q_off, k_off, blk_q, blk_k, window), p, 0.0)
         # transposed in float32, then cast: Mosaic transposes 32-bit tiles
         dv_scr[...] += jnp.dot(p.T.astype(do.dtype), do,
                                preferred_element_type=jnp.float32)
@@ -258,42 +345,43 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
         dk_scr[...] += jnp.dot(ds.T.astype(q.dtype), q,
                                preferred_element_type=jnp.float32) * sm_scale
 
-    @pl.when(qi == n_qb - 1)
+    @pl.when(step == n_steps - 1)
     def _finalize():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _q_index(blk_q, blk_k, causal, b, j, i):
-    """dK/dV grid: clamp dead above-diagonal q-block fetches at the
-    k-block's first live q-block (mirror of _kv_index)."""
+def _q_index(blk_q, blk_k, causal, window, g, n_qb, S, b, j, i):
+    """dK/dV grid, step i of k-block j's sweep: which query head of the
+    group (step // n_qb) and which of its q-blocks. Dead fetches are
+    clamped: above the diagonal at the k-block's first live q-block
+    (mirror of _kv_index), past the window at its last."""
+    if g > 1:
+        b, i = b * g + lax.div(i, n_qb), lax.rem(i, n_qb)
     if not causal:
         return (b, i, 0)
     lo = (j * blk_k) // blk_q
-    return (b, jnp.maximum(i, lo), 0)
-
-
-def _q_index2(blk_q, blk_k, causal, b, j, i):
-    if not causal:
-        return (b, i, 0)
-    lo = (j * blk_k) // blk_q
-    return (b, jnp.maximum(i, lo), 0)
+    if window is None:
+        return (b, jnp.maximum(i, lo), 0)
+    return (b, jnp.minimum(i + lo, _last_q_block(blk_q, blk_k, window, S, j)), 0)
 
 
 def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
-                      interpret):
+                      interpret, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, hd = q.shape
+    group = _group(q, k, v, causal, window)
+    Hkv = H // group
     # delta = rowsum(dO * O): one fused elementwise+reduce pass, XLA's
     # job; 8-lane-replicated to match the LSE layout (see _finalize)
     delta = jnp.sum(
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     )  # (B, H, S)
     qf = q.reshape(B * H, S, hd)
-    kf = k.reshape(B * H, S, hd)
-    vf = v.reshape(B * H, S, hd)
+    kf = k.reshape(B * Hkv, S, hd)
+    vf = v.reshape(B * Hkv, S, hd)
     gf = g.reshape(B * H, S, hd)
     lsef = lse  # (B*H, S, 8) straight from the forward kernel
     deltaf = jnp.broadcast_to(
@@ -302,36 +390,38 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
 
     q_spec = pl.BlockSpec((1, blk_q, hd), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec(
-        (1, blk_k, hd), functools.partial(_kv_index, blk_q, blk_k, causal)
+        (1, blk_k, hd),
+        functools.partial(_kv_index, blk_q, blk_k, causal, window, group)
     )
     row_spec = pl.BlockSpec((1, blk_q, 8), lambda b, i, j: (b, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, blk_q=blk_q, blk_k=blk_k,
-                          causal=causal, sm_scale=sm_scale),
+                          causal=causal, sm_scale=sm_scale, window=window),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-        grid=(B * H, S // blk_q, S // blk_k),
+        grid=(B * H, S // blk_q, _kv_steps(S, blk_q, blk_k, window)),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((blk_q, hd), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, gf, lsef, deltaf)
 
-    qi_spec = pl.BlockSpec(
-        (1, blk_q, hd), functools.partial(_q_index, blk_q, blk_k, causal)
-    )
-    row_i_spec = pl.BlockSpec(
-        (1, blk_q, 8), functools.partial(_q_index2, blk_q, blk_k, causal)
-    )
+    # one sweep over a k-block's q-blocks for each query head of the group
+    n_qb = _q_steps(S, blk_q, blk_k, window)
+    q_index = functools.partial(_q_index, blk_q, blk_k, causal, window,
+                                group, n_qb, S)
+    qi_spec = pl.BlockSpec((1, blk_q, hd), q_index)
+    row_i_spec = pl.BlockSpec((1, blk_q, 8), q_index)
     kj_spec = pl.BlockSpec((1, blk_k, hd), lambda b, j, i: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, blk_q=blk_q, blk_k=blk_k,
-                          causal=causal, sm_scale=sm_scale),
+                          causal=causal, sm_scale=sm_scale, window=window,
+                          n_qb=n_qb if group > 1 else None, seq=S),
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, hd), k.dtype),
-            jax.ShapeDtypeStruct((B * H, S, hd), v.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, S, hd), k.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, S, hd), v.dtype),
         ],
-        grid=(B * H, S // blk_k, S // blk_q),
+        grid=(B * Hkv, S // blk_k, group * n_qb),
         in_specs=[qi_spec, kj_spec, kj_spec, qi_spec, row_i_spec, row_i_spec],
         out_specs=[kj_spec, kj_spec],
         scratch_shapes=[
@@ -341,18 +431,21 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
         interpret=interpret,
     )(qf, kf, vf, gf, lsef, deltaf)
 
-    shape = (B, H, S, hd)
-    return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
                     blk_q: int = 512, blk_k: int = 512,
-                    interpret: bool = False):
-    """Fused causal attention for (B, H, S, hd) q/k/v; drop-in for the
+                    interpret: bool = False, window: int = None):
+    """Fused causal attention for (B, H, S, hd) q and (B, Hkv, S, hd) k, v,
+    H a multiple of Hkv (equal: plain multi-head); drop-in for the
     transformer's pluggable attention core:
 
         _block(x, layer, cfg, core=lambda q, k, v: flash_attention(q, k, v))
+
+    `window`: key j is seen by query i iff 0 <= i - j < window (None: every
+    earlier key), and only the blocks of that band are visited.
 
     Forward AND backward are Pallas kernels (two-pass flash backward:
     dq streams k-blocks, dk/dv stream q-blocks, block probs recomputed
@@ -364,29 +457,50 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
     31.7 % of the bf16 peak for the causal core's required operations
     (`flash_roofline_pct`, cell `olmoe_1b_7b.ssgd_seq4096_1chip`; PERF.md,
     PR 27). A dense core's float32 scores are 1.07 GB a sequence there.
+    At (1, 48, 8192, 128) on 8 key/value heads, a layer: 12.7 ms forward,
+    12.5 dK/dV, 10.4 dQ, 33.9 % of the peak; at 72 heads and window 512:
+    4.4, 4.0, 3.3 ms, 19 % of the peak for the band's operations, two
+    blocks computed a row where the band's area is one (cell
+    `laguna_s_2_1.ssgd_1seq_1chip`; PERF.md, PR 33).
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    return _forward(q, k, v, causal, sm_scale, blk_q, blk_k, interpret)
+    return _forward(q, k, v, causal, sm_scale, blk_q, blk_k, interpret,
+                    window=window)
 
 
-def _fwd(q, k, v, causal, sm_scale, blk_q, blk_k, interpret):
+def _fwd(q, k, v, causal, sm_scale, blk_q, blk_k, interpret, window):
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     out, lse = _forward(
-        q, k, v, causal, sm_scale, blk_q, blk_k, interpret, with_lse=True
+        q, k, v, causal, sm_scale, blk_q, blk_k, interpret, with_lse=True,
+        window=window
     )
+    if window is not None or q.shape[1] != k.shape[1]:
+        # kept between the passes as one number a row: the 8 replicated
+        # lanes are padded to 128 in HBM, 302 MB a layer of 72 heads at
+        # 8,192 positions for 2.4 MB of numbers. The plain call keeps what
+        # it kept, text for text.
+        lse = lse[:, :, 0]
+        # names for a checkpoint around the caller that runs the layer
+        # again and keeps these two, so that the forward kernel runs once
+        # (`models/transformer._layer_again`)
+        out = checkpoint_name(out, "flash_out")
+        lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, sm_scale, blk_q, blk_k, interpret, res, g):
+def _bwd(causal, sm_scale, blk_q, blk_k, interpret, window, res, g):
     q, k, v, o, lse = res
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
+    if lse.ndim == 2:
+        lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (8,))
     # fused two-pass flash backward kernels (dq, then dk/dv)
     blk_q, blk_k = _blocks(q.shape[2], blk_q, blk_k)
     return _backward_kernels(
-        q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k, interpret
+        q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k, interpret,
+        window=window
     )
 
 

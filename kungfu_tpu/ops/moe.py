@@ -11,7 +11,10 @@ SURVEY §2.4). `moe_ffn` is one function for both layouts:
   (`jax.lax.ragged_dot`, which the TPU compiler lowers to a grouped-matmul
   kernel), and the results go back by the inverse order, weighted by their
   gates. Every token-choice is computed whatever the load: no drops, no
-  dense pass over all experts.
+  dense pass over all experts. Told which experts it holds (`held`, one
+  chip's share of a layer whose experts lie on several chips), the shard
+  routes over all of the router's experts and computes the part of the
+  result that its own give, as dropless; it exchanges nothing.
 - **Across an expert axis** (`axis_size > 1`, inside a `shard_map`): E =
   axis_size * experts_per_device global experts. Each shard packs its
   tokens into per-expert capacity buckets (choices side by side on the
@@ -42,8 +45,9 @@ class MoeAux(NamedTuple):
     f_e P_e, f_e the share of the token-choices that went to expert e and
     P_e its mean router probability (1 when both are uniform); `z_loss` =
     mean over tokens of logsumexp(router logits)^2; `counts` (E,) int32 =
-    token-choices computed per expert, so T * top_k - counts.sum() is what
-    was dropped (0 on one shard by construction); `chosen` (T, top_k) int32
+    token-choices computed per expert (held here, under `held`), so T * top_k
+    - counts.sum() is what was dropped (0 on one shard by construction, of
+    the token-choices that fell on its experts); `chosen` (T, top_k) int32
     = the experts the router took for each token."""
     load_balance: jax.Array
     z_loss: jax.Array
@@ -56,13 +60,27 @@ def switch_gates(top_probs):
     renormalises over the chosen experts (GShard/Mixtral combine)."""
     if top_probs.shape[-1] == 1:
         return top_probs
-    return top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
+    return renormalised_gates(top_probs)
 
 
 def raw_gates(top_probs):
     """The chosen experts' softmax probabilities as they are (OLMoE,
     `norm_topk_prob` false)."""
     return top_probs
+
+
+def renormalised_gates(top_probs):
+    """The chosen experts' probabilities over their sum, whatever top_k
+    (`norm_topk_prob` true)."""
+    return top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
+
+
+def scaled(gates, scale: float):
+    """`gates` times a constant (a routed scaling factor); `gates` itself
+    at 1."""
+    if scale == 1.0:
+        return gates
+    return lambda top_probs: scale * gates(top_probs)
 
 
 def gelu_experts(rows, experts, group_sizes):
@@ -149,21 +167,131 @@ def _aux(logits, probs, counts, chosen) -> MoeAux:
                   jnp.mean(jnp.square(z)), counts, chosen)
 
 
+def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
+                sizes, i):
+    """What rows i * chunk to (i + 1) * chunk of a share's row order add to
+    the layer: (T, D) float32. Rows past the last group are token-choices
+    that fell elsewhere: they go in as zeros and weigh nothing, in both
+    passes (the grouped matmul leaves whatever it finds in a row of no
+    group). A row is weighed by its gate where it lies."""
+    T, D = x.shape
+    ends = jnp.cumsum(sizes)
+    lo = i * chunk
+    mine = lax.dynamic_slice(order, (lo,), (chunk,))
+    token = mine // top_k  # token-choice c = t * top_k + j reads token t
+    live = (lo + jnp.arange(chunk) < ends[-1])[:, None]
+    # the part of each expert's group that lies in this chunk
+    here = (jnp.clip(ends, lo, lo + chunk)
+            - jnp.clip(ends - sizes, lo, lo + chunk))
+    with jax.named_scope("moe_dispatch"):
+        rows = jnp.where(live, x[token], 0)
+    with jax.named_scope("moe_experts"):
+        y = expert_fn(rows, experts, here)
+    with jax.named_scope("moe_combine"):
+        weight = jnp.where(live, gate.reshape(T * top_k, 1)[mine], 0)
+        y = jnp.where(live, y.astype(jnp.float32) * weight, 0)
+        return jnp.zeros((T, D), jnp.float32).at[token].add(y)
+
+
+def _live_chunks(chunk: int, sizes):
+    return (jnp.sum(sizes) + chunk - 1) // chunk
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
+               sizes):
+    """The held experts' part of the layer, -> out (T, D). `order` lists the
+    token-choices with those for the held experts first, group by group
+    (`sizes` (held,) of them an expert). Dropless whatever the load: the
+    rows are taken `chunk` at a time, as many chunks as the groups fill,
+    up to all T * min(top_k, held) rows that can fall here. So the work is
+    that of the rows that came, and the memory that of one chunk: the
+    TPU's grouped matmul costs what its buffer holds, not what its groups
+    hold (8.8 ms at 65,536 rows of which 2,560 are live; PERF.md, PR 33).
+    The loop's length is data, so the backward pass is written out: the
+    same loop, each chunk run again and transposed (nothing is kept but the
+    arguments), its cotangents added up in float32."""
+    out = lax.fori_loop(
+        0, _live_chunks(chunk, sizes),
+        lambda i, out: out + _chunk_part(expert_fn, top_k, chunk, x, gate,
+                                         experts, order, sizes, i),
+        jnp.zeros(x.shape, jnp.float32))
+    return out.astype(x.dtype)
+
+
+def _held_part_fwd(expert_fn, top_k, chunk, x, gate, experts, order, sizes):
+    return (_held_part(expert_fn, top_k, chunk, x, gate, experts, order, sizes),
+            (x, gate, experts, order, sizes))
+
+
+def _held_part_bwd(expert_fn, top_k, chunk, res, g):
+    x, gate, experts, order, sizes = res
+    g = g.astype(jnp.float32)
+
+    def one(i, sums):
+        _, transpose = jax.vjp(
+            lambda *a: _chunk_part(expert_fn, top_k, chunk, *a, order, sizes, i),
+            x, gate, experts)
+        return jax.tree.map(lambda s, d: s + d.astype(jnp.float32), sums,
+                            transpose(g))
+
+    sums = lax.fori_loop(
+        0, _live_chunks(chunk, sizes), one,
+        jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
+                     (x, gate, experts)))
+    dx, dgate, dexperts = jax.tree.map(lambda s, a: s.astype(a.dtype), sums,
+                                       (x, gate, experts))
+    return dx, dgate, dexperts, None, None
+
+
+_held_part.defvjp(_held_part_fwd, _held_part_bwd)
+
+
+def _share_chunk(T: int, top_k: int, held: int, n_experts: int) -> int:
+    """Rows to a chunk of a share's row order: four times what a balanced
+    router sends to `held` of `n_experts` experts, so that one chunk is
+    the usual case and a step's work does not move with the routing (a
+    router with no balancing loss, trained 80 steps on the Laguna cell's
+    pool, sends its held experts up to 2.6 times the balanced load, from
+    1.0 at the start: PERF.md, PR 33); never more than can fall here."""
+    usual = -(-4 * T * top_k * held // n_experts)
+    return min(T * min(top_k, held), -(-usual // 8) * 8)
+
+
 def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
             top_k: int = 1, capacity_factor: float = 1.25,
-            gates=switch_gates, expert_fn=gelu_experts):
+            gates=switch_gates, expert_fn=gelu_experts, held=None):
     """x (T, D) tokens on this shard; router_w (D, E); `experts` a tuple of
     THIS device's expert weight stacks (leading dim = experts per device,
     epd; E = axis_size * epd), handed to `expert_fn(rows, experts,
     group_sizes)`. Returns (out (T, D), MoeAux) — out holds nothing of a
     dropped token-choice (the caller adds the residual). `capacity_factor`
     matters only where `axis_size > 1`. With an axis, runs INSIDE a
-    shard_map over it, and `load_balance` / `z_loss` are its means."""
+    shard_map over it, and `load_balance` / `z_loss` are its means.
+
+    `held` = (first, count), on one shard: the router is of any width E and
+    this shard's epd = count experts are numbers first to first + count - 1
+    of them, one chip's share of a layer whose experts lie on several. The
+    router, its top_k and `gates` are over all E; `out` is the part of the
+    layer that the held experts give, nothing of the token-choices that
+    fell elsewhere (no exchange is made and nothing stands in for one).
+    Dropless: up to T * min(top_k, count) rows, the most that can fall
+    here, are computed, a chunk at a time (`_held_part`), and `counts` is
+    of the held experts."""
     if top_k < 1:
         raise ValueError(f"top_k must be at least 1, got {top_k}")
     T, D = x.shape
     epd = experts[0].shape[0]
     E = axis_size * epd
+    if held is not None:
+        first, count = held
+        E = router_w.shape[-1]
+        if axis_size != 1 or count != epd or not 0 <= first <= E - count:
+            raise ValueError(
+                f"held {held}: on one shard, {epd} experts held of the "
+                f"router's {E}; across an expert axis the exchange decides")
+        if count == E:  # every expert is here: the layer as it always was
+            held = None
     if router_w.shape[-1] != E:
         raise ValueError(
             f"router width {router_w.shape[-1]} != axis_size*epd = {E}"
@@ -179,10 +307,29 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
         with jax.named_scope("moe_dispatch"):
             # token-choice c = t * top_k + j; `order` lists them by expert
             flat = top_idx.reshape(T * top_k)
-            order = jnp.argsort(flat, stable=True)
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(T * top_k, dtype=order.dtype))  # order's inverse
+            group = flat
+            if held is not None:
+                # the held experts' groups first, in their order, then
+                # every token-choice that fell elsewhere
+                group = jnp.where((flat >= first) & (flat < first + count),
+                                  flat - first, count)
+            order = jnp.argsort(group, stable=True)
+            if held is None:
+                back = jnp.zeros_like(order).at[order].set(
+                    jnp.arange(T * top_k, dtype=order.dtype))  # order's inverse
             counts = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        if held is not None:
+            sizes = counts[first:first + count]
+            chunk = _share_chunk(T, top_k, count, E)
+            # every chunk of the rows that can fall here lies inside `order`
+            n = -(-T * min(top_k, count) // chunk) * chunk
+            order = jnp.pad(order, (0, max(0, n - T * top_k)))
+            out = _held_part(expert_fn, top_k, chunk, x, gate, experts, order,
+                             sizes)
+            with jax.named_scope("moe_router"):
+                aux = _aux(logits, probs, counts, top_idx)._replace(counts=sizes)
+            return out, aux
+        with jax.named_scope("moe_dispatch"):
             rows = _dispatch_rows(x, order, back, top_k)  # (T * top_k, D)
         with jax.named_scope("moe_experts"):
             y = expert_fn(rows, experts, counts)

@@ -261,3 +261,129 @@ def test_moe_differentiable(top_k, ep):
         assert np.all(np.isfinite(np.asarray(t)))
     assert float(np.abs(np.asarray(g[0])).sum()) > 0
     assert float(np.abs(np.asarray(g[2])).sum()) > 0
+
+
+# --- a share of the experts on one shard (PR 33) ------------------------------
+
+def _share_setup(T=48, D=16, F=8, E=16, seed=0):
+    from kungfu_tpu.ops import moe
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (T, D), jnp.float32)
+    router = jax.random.normal(ks[1], (D, E), jnp.float32)
+    experts = tuple(jax.random.normal(k, shape, jnp.float32) * 0.3
+                    for k, shape in zip(ks[2:], ((E, D, F), (E, D, F), (E, F, D))))
+    gates = moe.scaled(moe.renormalised_gates, 2.5)
+    return moe, x, router, experts, gates
+
+
+def _share(moe, x, router, experts, gates, top_k, first, count):
+    mine = tuple(w[first:first + count] for w in experts)
+    return moe.moe_ffn(x, router, mine, top_k=top_k, gates=gates,
+                       expert_fn=moe.swiglu_experts, held=(first, count))
+
+
+@pytest.mark.parametrize("top_k,count,E", [(3, 4, 16), (5, 2, 16), (1, 8, 16),
+                                           (3, 16, 16), (3, 4, 64)])
+def test_the_shares_parts_add_up_to_the_whole_layer(top_k, count, E):
+    """Every share routes over all E experts and computes its own experts'
+    part: the parts of all the shares sum to the layer with every expert
+    held, values and gradients, and the shares' counts are the whole
+    layer's, side by side."""
+    moe, x, router, experts, gates = _share_setup(E=E)
+
+    def whole(x, router, experts):
+        return moe.moe_ffn(x, router, experts, top_k=top_k, gates=gates,
+                           expert_fn=moe.swiglu_experts)
+
+    def parts(x, router, experts):
+        outs = [_share(moe, x, router, experts, gates, top_k, first, count)
+                for first in range(0, E, count)]
+        return (sum(out for out, _ in outs),
+                jnp.concatenate([aux.counts for _, aux in outs]))
+
+    want, want_aux = whole(x, router, experts)
+    got, counts = parts(x, router, experts)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert counts.tolist() == want_aux.counts.tolist()
+    assert int(counts.sum()) == 48 * top_k
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a)[0] ** 2)
+
+    for g, w in zip(jax.tree.leaves(jax.grad(loss(parts), (0, 1, 2))(x, router, experts)),
+                    jax.tree.leaves(jax.grad(loss(whole), (0, 1, 2))(x, router, experts))):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("E,chunks", [(16, 1), (64, 4)])
+def test_a_share_is_dropless_under_the_worst_load(E, chunks):
+    """Every token's three choices on the four experts held: all T x
+    min(top_k, held) rows that can fall here are taken, in as many chunks
+    as they fill (of 64 experts a chunk is a quarter of them), none is
+    dropped, values and gradients are the whole layer's; with the choices
+    all elsewhere the part is zero."""
+    moe, x, router, experts, gates = _share_setup(E=E)
+    assert -(-48 * 3 // moe._share_chunk(48, 3, 4, E)) == chunks
+    # logits that put experts 4, 5, 6 first for every token, whatever x
+    router = jnp.zeros_like(router).at[0, 4:7].set(jnp.array([3.0, 2.0, 1.0]))
+    x = x.at[:, 0].set(jnp.abs(x[:, 0]) + 1.0)
+    out, aux = _share(moe, x, router, experts, gates, 3, 4, 4)
+    assert aux.counts.tolist() == [48, 48, 48, 0]
+    assert int(aux.counts.sum()) == 48 * 3  # every held choice computed
+    def whole(x, router, experts):
+        return moe.moe_ffn(x, router, experts, top_k=3, gates=gates,
+                           expert_fn=moe.swiglu_experts)[0]
+
+    def part(x, router, experts):
+        return _share(moe, x, router, experts, gates, 3, 4, 4)[0]
+
+    np.testing.assert_allclose(np.asarray(out), np.asarray(whole(x, router, experts)),
+                               rtol=1e-5, atol=1e-5)
+    for g, w in zip(
+            jax.tree.leaves(jax.grad(lambda *a: jnp.sum(part(*a) ** 2), (0, 1, 2))(
+                x, router, experts)),
+            jax.tree.leaves(jax.grad(lambda *a: jnp.sum(whole(*a) ** 2), (0, 1, 2))(
+                x, router, experts))):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
+    nothing, aux = _share(moe, x, router, experts, gates, 3, 8, 4)
+    assert aux.counts.tolist() == [0, 0, 0, 0]
+    assert float(jnp.abs(nothing).max()) == 0.0
+
+
+def test_a_share_of_every_expert_is_the_layer_itself():
+    """`held` = (0, E) lowers to the program without it, text for text."""
+    moe, x, router, experts, gates = _share_setup()
+
+    def run(held):
+        return jax.jit(lambda x, r, e: moe.moe_ffn(
+            x, r, e, top_k=3, gates=moe.raw_gates,
+            expert_fn=moe.swiglu_experts, held=held)[0]).lower(
+                x, router, experts).as_text()
+
+    assert run((0, 16)) == run(None)
+
+
+def test_a_share_that_does_not_fit_raises():
+    moe, x, router, experts, gates = _share_setup()
+    mine = tuple(w[:4] for w in experts)
+    for held in ((0, 3), (13, 4), (-1, 4)):
+        with pytest.raises(ValueError, match="held"):
+            moe.moe_ffn(x, router, mine, top_k=2, gates=gates,
+                        expert_fn=moe.swiglu_experts, held=held)
+    with pytest.raises(ValueError, match="held"):
+        moe.moe_ffn(x, router, mine, "ep", 2, top_k=2, gates=gates,
+                    expert_fn=moe.swiglu_experts, held=(0, 4))
+
+
+def test_gate_rules():
+    from kungfu_tpu.ops import moe
+
+    p = jnp.array([[0.5, 0.25, 0.05], [0.2, 0.2, 0.1]])
+    np.testing.assert_allclose(moe.renormalised_gates(p).sum(-1), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(moe.renormalised_gates(p[:, :1]), 1.0)  # top-1 too
+    assert moe.scaled(moe.raw_gates, 1.0) is moe.raw_gates
+    np.testing.assert_allclose(moe.scaled(moe.raw_gates, 2.5)(p), 2.5 * p)

@@ -111,7 +111,7 @@ def _capacity(factor):
     return experts
 
 
-def _no_rope(q, k, theta):
+def _no_rope(q, k, *rule):
     return q, k
 
 
