@@ -387,36 +387,78 @@ def _yarn_ramp(rd: int, theta: float, yarn: Tuple):
     return jnp.clip((jnp.arange(rd // 2, dtype=jnp.float32) - low) / span, 0, 1)
 
 
-@functools.partial(_recompute, static_argnums=(2, 3, 4))
-def _rope(q, k, theta: float, share: float, yarn: Tuple):
-    """Rotary positions on (B, H, S, hd) q and k (each its own H),
-    positions 0..S-1: the rotate-half form over the leading `share` of the
-    head dimension (the rest passes through), angles and the rotation in
-    float32, under `yarn` its frequencies and attention factor. Keeps q and
-    k; the angles, their cos and sin and the rotation are recomputed."""
-    S, hd = q.shape[2], q.shape[3]
+def _rotary_tables(S: int, hd: int, theta: float, share: float, yarn: Tuple):
+    """(S, hd) float32 cos and sin of positions 0..S-1 for the rotate-half
+    form over the leading `share` of the head: the rd // 2 frequencies on
+    both halves of the rotated features, under `yarn` its blended
+    frequencies and its attention factor on both tables, and cos 1, sin 0
+    on the features that pass through. Traced `jnp` of static shapes: made
+    again inside the program wherever a pass wants them."""
     rd = int(hd * share)  # the rotated features
     inv_freq = 1.0 / (theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
     if yarn:
         ramp = _yarn_ramp(rd, theta, yarn)
         inv_freq = inv_freq / yarn[0] * ramp + inv_freq * (1 - ramp)
     angles = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)  # (S, rd)
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)  # (S, rd // 2)
     if yarn:
         cos, sin = cos * yarn[4], sin * yarn[4]
+    through = jnp.ones((S, hd - rd), jnp.float32)
+    return (jnp.concatenate([cos, cos, through], axis=-1),
+            jnp.concatenate([sin, sin, jnp.zeros_like(through)], axis=-1))
 
-    def rotate(t):
-        t32 = t.astype(jnp.float32)
-        if rd < hd:
-            t32, rest = t32[..., :rd], t32[..., rd:]
-        half = jnp.concatenate([-t32[..., rd // 2:], t32[..., :rd // 2]], axis=-1)
-        turned = t32 * cos + half * sin
-        if rd < hd:
-            turned = jnp.concatenate([turned, rest], axis=-1)
-        return turned.astype(t.dtype)
 
-    return rotate(q), rotate(k)
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _turned(t, rule: Tuple, back: bool):
+    """One pass over (B, H, S, hd) t (`ops.rotary.rotate`): cos * t + sin *
+    P t, P the signed swap of the two halves of the rotated features, or,
+    `back`, its transpose cos * t - sin * P t on a cotangent; the sign of P
+    rides in the sine table. The kernel reads t as (B, S, H * hd) and writes
+    (B, H, S, hd), and the other way about on the way back: the transposition
+    below undoes the caller's own, so a projection's output goes to the
+    attention core through this one pass. Mosaic where the program is
+    lowered for the TPU, the same kernel interpreted anywhere else. Under a
+    `jax.jit` of its own, so that a step's calls of one shape trace and
+    lower one body: the Laguna cell's first step is 2 s shorter warm and 9 s
+    cold for it on the chip's host (PERF.md, PR 35)."""
+    from kungfu_tpu.ops.rotary import rotate
+
+    theta, share, yarn = rule
+    B, H, S, hd = t.shape
+    half = int(hd * share) // 2
+    cos, sin = _rotary_tables(S, hd, theta, share, yarn)
+    sin = jnp.where((jnp.arange(hd) < half) != back, -sin, sin)
+    if not back:
+        t = t.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
+    kernel = functools.partial(rotate, half=half, into_heads=not back)
+    out = jax.lax.platform_dependent(
+        t, cos, sin, tpu=kernel,
+        default=functools.partial(kernel, interpret=True))
+    return out.reshape(B, S, H, hd).transpose(0, 2, 1, 3) if back else out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rotated(t, rule: Tuple):
+    """The rotation is linear in t and its transpose is the same pass with
+    the sine's sign turned, so the backward pass needs nothing of t: no
+    residual, and no transposed slices (pads) and concatenations (slices
+    and adds) of q's size in float32, which is what autodiff writes for the
+    rotate-half form (33.7 ms of the Laguna cell's step, PERF.md, PR 35)."""
+    return _turned(t, rule, False)
+
+
+_rotated.defvjp(lambda t, rule: (_turned(t, rule, False), None),
+                lambda rule, _, dy: (_turned(dy, rule, True),))
+
+
+def _rope(q, k, theta: float, share: float, yarn: Tuple):
+    """Rotary positions on (B, H, S, hd) q and k (each its own H),
+    positions 0..S-1: the rotate-half form over the leading `share` of the
+    head dimension (the rest passes through), angles and the rotation in
+    float32, under `yarn` its frequencies and attention factor. Keeps
+    nothing for the backward pass."""
+    rule = (theta, share, yarn)
+    return _rotated(q, rule), _rotated(k, rule)
 
 
 @_recompute
@@ -441,9 +483,9 @@ def attention_core_of(cfg: TransformerConfig):
 def _gated_out(ctx, pre, wo):
     """(ctx (B, H, S, hd) times sigmoid(pre (B, S, H)), a scalar a head and
     position, the sigmoid in float32) as (B, S, H * hd) @ wo. Under its
-    checkpoint (`_kept`) it keeps ctx, which the core keeps anyway, pre and
-    wo; the gated copy of ctx, the matmul's operand, is made again, as
-    `_gelu_out` makes its gelu again."""
+    checkpoint (`_gated_out_kept`) it keeps ctx, which the core keeps
+    anyway, pre and wo; the gated copy of ctx, the matmul's operand, is made
+    again, as `_gelu_out` makes its gelu again."""
     B, H, S, hd = ctx.shape
     gate = jax.nn.sigmoid(pre.astype(jnp.float32)).astype(ctx.dtype)
     ctx = ctx * gate.transpose(0, 2, 1)[..., None]
@@ -452,10 +494,8 @@ def _gated_out(ctx, pre, wo):
 
 def _split_heads(x, wqkv, cfg):
     """x @ (wq, wk, wv) as (B, heads, S, hd) q, k, v with rotary positions.
-    Under its checkpoint (`_kept`) it keeps x and the matrices: q before
-    its rotation (0.15 GB a layer of 72 heads at 8,192 positions) is made
-    again with the rotation, where `_rope` alone keeps it beside the
-    rotated q that the core keeps."""
+    The backward pass wants x and the matrices and nothing else: the
+    rotation keeps nothing, so there is no checkpoint to say so."""
     B, S, _ = x.shape
     q, k, v = (
         (x @ w).reshape(B, S, -1, cfg.head_dim).transpose(0, 2, 1, 3)
@@ -466,17 +506,9 @@ def _split_heads(x, wqkv, cfg):
     return q, k, v
 
 
-_KEPT = {
-    _gated_out: _recompute(_gated_out),
-    _split_heads: functools.partial(_recompute, static_argnums=(2,))(_split_heads),
-}
-
-
-def _kept(piece, cfg: TransformerConfig):
-    """`piece` under a checkpoint of its own, which says what it keeps for
-    the backward pass; `piece` as it is in a layer that is run again whole
-    (`layer_remat`), where a checkpoint inside would run it a third time."""
-    return piece if cfg.layer_remat else _KEPT[piece]
+# in a layer that is run again whole (`layer_remat`) the piece as it is: a
+# checkpoint inside would run it a third time
+_gated_out_kept = _recompute(_gated_out)
 
 
 def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
@@ -491,7 +523,7 @@ def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
     B, S, _ = x.shape
     H, hd = cfg.n_heads, cfg.head_dim
     if cfg.split_qkv:
-        q, k, v = _kept(_split_heads, cfg)(x, wqkv, cfg)
+        q, k, v = _split_heads(x, wqkv, cfg)
     else:
         qkv = x @ wqkv  # (B, S, 3D)
         q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -509,7 +541,8 @@ def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
         ctx = (core or attention_core_of(cfg))(q, k, v)
     if cfg.head_gate:
         with jax.named_scope("attn_gate"):
-            return _kept(_gated_out, cfg)(ctx, x @ w_head_gate, wo)
+            gated_out = _gated_out if cfg.layer_remat else _gated_out_kept
+            return gated_out(ctx, x @ w_head_gate, wo)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     return ctx @ wo
 

@@ -293,8 +293,9 @@ def test_a_layer_run_again_runs_its_forward_kernel_once():
 
     def kernels(mc):
         loss = lambda p, b: transformer.transformer_loss(p, b, mc)
-        return str(jax.make_jaxpr(jax.grad(loss))(state, _sample())).count(
-            "pallas_call")
+        text = str(jax.make_jaxpr(jax.grad(loss))(state, _sample()))
+        # the flash kernels: the rotary passes (PR 35) are kernels too
+        return text.count("pallas_call") - text.count("name=rotary")
 
     assert kernels(family.model_config(CONFIG)) == 3 * 3
     plain = jax.checkpoint(transformer._layer, static_argnums=(2,))
