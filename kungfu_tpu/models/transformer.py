@@ -25,6 +25,13 @@ TPU-first design notes:
   head with YaRN's frequencies, renormalised and scaled expert gates, a
   shared expert, and an expert layer that holds a share of the experts its
   router sees are each a field.
+- A layer's token mixer is a kind too (PR 36): `mixer` is softmax attention
+  or the gated delta rule (`ops.gated_delta`: a fused q, k, v, z projection,
+  a causal depthwise convolution, a linear recurrence with a matrix state a
+  head, a gated norm), and layers of both mixers stand in one stack of
+  `layer_kinds`. Norms with the scale 1 + w, q/k norms a head, a gate a
+  feature from a q projection of twice the width and a sigmoid gate on the
+  shared expert are each a field.
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -90,6 +97,20 @@ class TransformerConfig:
     # the layer scan keeps a layer's input alone and runs the layer again in
     # the backward pass, where what the pieces keep of it would not fit
     layer_remat: bool = False
+    # the layer's token mixer: softmax "attention" over the fields above, or
+    # "gated_delta", the gated delta rule (`ops.gated_delta`) over
+    # `delta_heads` = (key heads, value heads, head size) behind a causal
+    # depthwise convolution of `conv_taps` taps
+    mixer: str = "attention"
+    delta_heads: Tuple = ()
+    conv_taps: int = 4
+    norm_offset: bool = False  # every RMSNorm's scale is 1 + w, w from 0
+    # with wq, wk, wv of their own (`split_qkv`), `qk_norm` is an RMSNorm a
+    # head over the head size, q's and k's scales (head size,) each.
+    # q_gate: wq is twice as wide, a head's q and then its gate, and
+    # sigmoid(gate), one a feature, is on the core's output
+    q_gate: bool = False
+    shared_gate: bool = False  # sigmoid(h @ w_shared_gate (D, 1)) on the shared expert
     # one tuple of (field, value) pairs a layer: what replaces the fields
     # above for that layer; (): every layer is the configuration's own
     layer_kinds: Tuple = ()
@@ -99,7 +120,8 @@ class TransformerConfig:
                 ("positions", self.positions, ("learned", "rope")),
                 ("ffn", self.ffn, ("gelu", "swiglu", "moe")),
                 ("attn_core", self.attn_core, ("dense", "flash")),
-                ("gates", self.gates, ("raw", "renorm"))):
+                ("gates", self.gates, ("raw", "renorm")),
+                ("mixer", self.mixer, ("attention", "gated_delta"))):
             if value not in known:
                 raise ValueError(f"{field} {value!r} is not one of {known}")
         if self.ffn == "moe" and not 1 <= self.top_k <= self.n_experts:
@@ -108,10 +130,17 @@ class TransformerConfig:
         if self.n_heads % self.kv_heads:
             raise ValueError(f"{self.n_heads} query heads are no multiple of "
                              f"{self.kv_heads} key/value heads")
-        if self.split_qkv and self.qk_norm:
-            raise ValueError("qk_norm spans the features of the fused wqkv; "
-                             "with a head size or key/value heads of their "
-                             "own the layer has wq, wk, wv and no such norm")
+        if self.q_gate and not self.split_qkv:
+            raise ValueError("q_gate doubles wq, which a layer has with a head "
+                             "size or key/value heads of its own (`split_qkv`)")
+        if self.mixer == "gated_delta" and not (
+                len(self.delta_heads) == 3 and self.delta_heads[0] >= 1
+                and self.delta_heads[1] % self.delta_heads[0] == 0):
+            raise ValueError("mixer 'gated_delta' needs delta_heads = (key "
+                             "heads, value heads a multiple of them, head "
+                             f"size), got {self.delta_heads}")
+        if self.shared_gate and not self.shared_ff:
+            raise ValueError("shared_gate gates the shared expert (shared_ff)")
         if (self.window or self.kv_heads != self.n_heads) and self.attn_core != "flash":
             raise ValueError("a window and grouped heads are the flash core's "
                              "(attn_core 'flash'); the dense core has neither")
@@ -197,29 +226,59 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
 
     D = cfg.d_model
 
+    def unit(cfg, shape):
+        """A norm's weight at the start: its scale is the weight, from 1, or
+        1 + the weight, from 0."""
+        return (jnp.zeros if cfg.norm_offset else jnp.ones)(shape, jnp.float32)
+
     def init_layer(key, cfg):
         # the gelu block draws what it always drew from four keys; the
         # other feed-forwards take further keys of a split of their own,
-        # and what PR 33 brought those of a second split
+        # what PR 33 brought those of a second split, and the gated delta
+        # mixer and the shared expert's gate (PR 36) those of a third
         F, E = cfg.d_ff, cfg.n_experts
         lk = jax.random.split(key, 4 if cfg.ffn == "gelu" else 6)
         if cfg.split_qkv or cfg.head_gate or cfg.shared_ff:
             xk = jax.random.split(jax.random.fold_in(key, 1), 6)
+        if cfg.mixer == "gated_delta" or cfg.shared_gate:
+            gk = jax.random.split(jax.random.fold_in(key, 2), 6)
         layer = {
-            "ln1_scale": jnp.ones((D,), jnp.float32),
-            "ln2_scale": jnp.ones((D,), jnp.float32),
+            "ln1_scale": unit(cfg, (D,)),
+            "ln2_scale": unit(cfg, (D,)),
         }
-        if cfg.split_qkv:
+        if cfg.mixer == "gated_delta":
+            Hk, Hv, d = cfg.delta_heads
+            K = cfg.conv_taps
+            # the decay's parameters as the Gated DeltaNet reference
+            # implementation draws them (Mamba2's): A uniform in (0, 16),
+            # dt log-uniform in (0.001, 0.1) and dt_bias its inverse
+            # softplus, so g = -A softplus(a + dt_bias) is about -A dt at
+            # the start, from a memory of a thousand positions to one of
+            # less than one, head by head; the taps as a depthwise Conv1d's
+            # default, uniform within 1 / sqrt(K)
+            dt = jnp.exp(jax.random.uniform(gk[4], (Hv,), jnp.float32,
+                                            math.log(0.001), math.log(0.1)))
+            layer.update(
+                w_qkvz=dense(gk[0], (D, 2 * (Hk + Hv) * d)),
+                w_ba=dense(gk[1], (D, 2 * Hv)),
+                conv_w=jax.random.uniform(gk[2], (K, (2 * Hk + Hv) * d),
+                                          jnp.float32, -K ** -0.5, K ** -0.5),
+                A_log=jnp.log(jax.random.uniform(gk[3], (Hv,), jnp.float32,
+                                                 1e-3, 16.0)),
+                dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+                gdn_norm_scale=jnp.ones((d,), jnp.float32),
+                wo=dense(lk[1], (Hv * d, D)))
+        elif cfg.split_qkv:
             q_width, kv_width = (h * cfg.head_dim
                                  for h in (cfg.n_heads, cfg.kv_heads))
-            layer["wq"] = dense(lk[0], (D, q_width))
+            layer["wq"] = dense(lk[0], (D, q_width * (2 if cfg.q_gate else 1)))
             layer["wk"] = dense(xk[0], (D, kv_width))
             layer["wv"] = dense(xk[1], (D, kv_width))
             layer["wo"] = dense(lk[1], (q_width, D))
         else:
             layer["wqkv"] = dense(lk[0], (D, 3 * D))
             layer["wo"] = dense(lk[1], (D, D))
-        if cfg.head_gate:
+        if cfg.head_gate and cfg.mixer == "attention":
             layer["w_head_gate"] = dense(xk[2], (D, cfg.n_heads))
         if cfg.ffn == "gelu":
             layer["w_in"] = dense(lk[2], (D, F))
@@ -236,9 +295,12 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
                 layer["shared_gate"] = dense(xk[3], (D, cfg.shared_ff))
                 layer["shared_up"] = dense(xk[4], (D, cfg.shared_ff))
                 layer["shared_down"] = dense(xk[5], (cfg.shared_ff, D))
-        if cfg.qk_norm:
-            layer["q_norm_scale"] = jnp.ones((D,), jnp.float32)
-            layer["k_norm_scale"] = jnp.ones((D,), jnp.float32)
+            if cfg.shared_gate:
+                layer["w_shared_gate"] = dense(gk[5], (D, 1))
+        if cfg.qk_norm and cfg.mixer == "attention":
+            width = cfg.head_dim if cfg.split_qkv else D
+            layer["q_norm_scale"] = unit(cfg, (width,))
+            layer["k_norm_scale"] = unit(cfg, (width,))
         return layer
 
     # stack layers: leading axis = layer, enables lax.scan over layers; a
@@ -250,7 +312,7 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
         at += n
     params = {
         "embed": dense(keys[0], (cfg.vocab_size, D)),
-        "ln_f_scale": jnp.ones((D,), jnp.float32),
+        "ln_f_scale": unit(cfg, (D,)),
         "layers": tuple(stacks) if cfg.layer_kinds else stacks[0],
     }
     if cfg.positions == "learned":
@@ -274,7 +336,8 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     Layer-stacked leaves have a leading layer axis (unsharded); a
     configuration with `layer_kinds` has a tuple of such stacks. The q/k
     norms' scales span all of q's features, which tp splits: sharded like
-    them.
+    them; a head's own (split projections) are whole. A gated delta mixer's
+    fused projection and taps are column-parallel.
     """
     t, e = tp_axis, ep_axis
 
@@ -284,12 +347,19 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
             "ln2_scale": P(None),
             "wo": P(None, t, None),
         }
-        if cfg.split_qkv:  # heads over tp, as wqkv's columns are
+        if cfg.mixer == "gated_delta":
+            # the fused projection's and the convolution's channels over tp
+            # like any column-parallel matrix; a number a head and the
+            # norm's scale whole
+            layers.update(w_qkvz=P(None, None, t), w_ba=P(None, None, None),
+                          conv_w=P(None, None, t), A_log=P(None, None),
+                          dt_bias=P(None, None), gdn_norm_scale=P(None, None))
+        elif cfg.split_qkv:  # heads over tp, as wqkv's columns are
             layers.update(wq=P(None, None, t), wk=P(None, None, t),
                           wv=P(None, None, t))
         else:
             layers.update(wqkv=P(None, None, t))
-        if cfg.head_gate:
+        if cfg.head_gate and cfg.mixer == "attention":
             layers.update(w_head_gate=P(None, None, t))
         if cfg.ffn == "gelu":
             layers.update(w_in=P(None, None, t), w_out=P(None, t, None))
@@ -304,8 +374,12 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
                 layers.update(shared_gate=P(None, None, t),
                               shared_up=P(None, None, t),
                               shared_down=P(None, t, None))
-        if cfg.qk_norm:
-            layers.update(q_norm_scale=P(None, t), k_norm_scale=P(None, t))
+            if cfg.shared_gate:
+                layers.update(w_shared_gate=P(None, None, None))
+        if cfg.qk_norm and cfg.mixer == "attention":
+            # over all of q's features, which tp splits, or over one head's
+            spec = P(None, None) if cfg.split_qkv else P(None, t)
+            layers.update(q_norm_scale=spec, k_norm_scale=spec)
         return layers
 
     stacks = tuple(stack_specs(kind) for kind, _ in cfg.stacks)
@@ -492,23 +566,55 @@ def _gated_out(ctx, pre, wo):
     return ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd) @ wo
 
 
-def _split_heads(x, wqkv, cfg):
-    """x @ (wq, wk, wv) as (B, heads, S, hd) q, k, v with rotary positions.
-    The backward pass wants x and the matrices and nothing else: the
-    rotation keeps nothing, so there is no checkpoint to say so."""
+def _split_heads(x, wqkv, cfg, qk_scales=None):
+    """x @ (wq, wk, wv) as (B, heads, S, hd) q, k, v with rotary positions,
+    and the (B, S, H, hd) gate that a doubled wq carries behind each head's
+    q (`q_gate`; None without). q and k are normed a head where the
+    configuration says so (`qk_scales`). Without a norm the backward pass
+    wants x and the matrices and nothing else: the rotation keeps nothing,
+    so there is no checkpoint to say so."""
     B, S, _ = x.shape
-    q, k, v = (
-        (x @ w).reshape(B, S, -1, cfg.head_dim).transpose(0, 2, 1, 3)
-        for w in wqkv)
+    hd = cfg.head_dim
+
+    def heads(t):
+        return t.transpose(0, 2, 1, 3)
+
+    gate = None
+    if cfg.q_gate:
+        q = (x @ wqkv[0]).reshape(B, S, -1, 2 * hd)
+        q, gate = q[..., :hd], q[..., hd:]
+    else:
+        q = (x @ wqkv[0]).reshape(B, S, -1, hd)
+    if not cfg.qk_norm:
+        q = heads(q)
+    k = (x @ wqkv[1]).reshape(B, S, -1, hd)
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = heads(_rmsnorm(q, qk_scales[0], cfg.norm_eps))
+            k = _rmsnorm(k, qk_scales[1], cfg.norm_eps)
+    k = heads(k)
+    v = heads((x @ wqkv[2]).reshape(B, S, -1, hd))
     if cfg.positions == "rope":
         with jax.named_scope("rope"):
             q, k = _rope(q, k, cfg.rope_theta, cfg.rotary_share, cfg.yarn)
-    return q, k, v
+    return q, k, v, gate
+
+
+def _feature_gated_out(ctx, gate, wo):
+    """(ctx (B, H, S, hd) times sigmoid(gate (B, S, H, hd)), one a feature,
+    the sigmoid in float32) as (B, S, H * hd) @ wo. Under its checkpoint
+    (`_feature_gated_out_kept`) it keeps ctx, gate and wo and makes the
+    gated copy again, as `_gated_out` does."""
+    B, H, S, hd = ctx.shape
+    ctx = ctx.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
+        gate.astype(jnp.float32)).astype(ctx.dtype)
+    return ctx.reshape(B, S, H * hd) @ wo
 
 
 # in a layer that is run again whole (`layer_remat`) the piece as it is: a
 # checkpoint inside would run it a third time
 _gated_out_kept = _recompute(_gated_out)
+_feature_gated_out_kept = _recompute(_feature_gated_out)
 
 
 def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
@@ -517,13 +623,16 @@ def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
     (the configuration's by default, the ring core for sequence parallelism
     — ONE copy of the projection plumbing for every path). `wqkv` is the
     fused (D, 3D) matrix, or (wq, wk, wv) where q's width and k's, v's are
-    the configuration's own (`split_qkv`). `qk_scales` = (q_norm_scale,
-    k_norm_scale) where the configuration norms q and k; `w_head_gate` (D,
-    H) where it gates each head's output."""
+    the configuration's own (`split_qkv`), wq twice as wide where it carries
+    a gate a feature (`q_gate`). `qk_scales` = (q_norm_scale, k_norm_scale)
+    where the configuration norms q and k, over all of their features or,
+    with split projections, a head; `w_head_gate` (D, H) where it gates each
+    head's output."""
     B, S, _ = x.shape
     H, hd = cfg.n_heads, cfg.head_dim
+    gate = None
     if cfg.split_qkv:
-        q, k, v = _split_heads(x, wqkv, cfg)
+        q, k, v, gate = _split_heads(x, wqkv, cfg, qk_scales)
     else:
         qkv = x @ wqkv  # (B, S, 3D)
         q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -543,6 +652,11 @@ def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
         with jax.named_scope("attn_gate"):
             gated_out = _gated_out if cfg.layer_remat else _gated_out_kept
             return gated_out(ctx, x @ w_head_gate, wo)
+    if gate is not None:
+        with jax.named_scope("attn_gate"):
+            gated_out = (_feature_gated_out if cfg.layer_remat
+                         else _feature_gated_out_kept)
+            return gated_out(ctx, gate, wo)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     return ctx @ wo
 
@@ -559,41 +673,168 @@ def _core_kind_scope(cfg: TransformerConfig):
     return contextlib.nullcontext()
 
 
+def _l2_normed(t, scale: float, dtype):
+    """t / sqrt(|t|^2 + 1e-6) * scale over the last axis, in float32."""
+    t = t.astype(jnp.float32)
+    norm = jax.lax.rsqrt(jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+    return (t * (norm * scale)).astype(dtype)
+
+
+def _gated_norm(o, scale, z, eps):
+    """rms(o) * scale * silu(z) over the last axis (a head), in float32, the
+    result in o's type. No checkpoint of its own, nor `_l2_normed`: the
+    block of heads they stand in is run again whole (`_delta_heads`)."""
+    o32 = o.astype(jnp.float32)
+    var = jnp.mean(jnp.square(o32), axis=-1, keepdims=True)
+    y = o32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+    return (y * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype)
+
+
+# value heads a block of the gated delta mixer (`_gated_delta_mixer`)
+DELTA_HEAD_BLOCK = 8
+
+
+@functools.partial(_recompute, static_argnums=(3,))
+def _delta_heads(h, part, norm_scale, cfg: TransformerConfig):
+    """A block of the Gated DeltaNet mixer's key heads with their value
+    heads, from normed hidden states h (B, S, D) to the block's part of the
+    mixer's output (B, S, D); `part` = the block's columns of W_qkvz, W_ba
+    and the taps, its A_log and dt_bias, its rows of W_o. Keeps its
+    arguments and runs again in the backward pass."""
+    from kungfu_tpu.ops.gated_delta import causal_conv, gated_delta_rule
+
+    w_qkvz, w_ba, conv_w, A_log, dt_bias, wo = part
+    dt, f32 = cfg.dtype, jnp.float32
+    B, S, _ = h.shape
+    d = cfg.delta_heads[2]
+    r = cfg.delta_heads[1] // cfg.delta_heads[0]
+    kb = A_log.shape[0] // r  # key heads in this block
+    with jax.named_scope("gdn_proj"):
+        qkvz = (h @ w_qkvz.astype(dt)).reshape(B, S, kb, (2 + 2 * r) * d)
+        ba = jnp.dot(h.astype(f32), w_ba.astype(f32),
+                     precision=jax.lax.Precision.HIGHEST).reshape(B, S, kb, 2 * r)
+        b, a = (t.reshape(B, S, kb * r).transpose(0, 2, 1)
+                for t in (ba[..., :r], ba[..., r:]))
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(A_log.astype(f32))[:, None] * jax.nn.softplus(
+            a + dt_bias.astype(f32)[:, None])
+    with jax.named_scope("gdn_conv"):
+        qkv = qkvz[..., :(2 + r) * d].reshape(B, S, kb * (2 + r) * d)
+        qkv = jax.nn.silu(causal_conv(qkv, conv_w)).reshape(B, S, kb, (2 + r) * d)
+        q = _l2_normed(qkv[..., :d], d ** -0.5, dt).transpose(0, 2, 1, 3)
+        k = _l2_normed(qkv[..., d:2 * d], 1.0, dt).transpose(0, 2, 1, 3)
+        v = qkv[..., 2 * d:].reshape(B, S, kb * r, d).transpose(0, 2, 1, 3)
+        if r > 1:  # value head j reads key head j // r
+            q, k = jnp.repeat(q, r, axis=1), jnp.repeat(k, r, axis=1)
+    with jax.named_scope("gdn_core"):
+        o = gated_delta_rule(q, k, v, g, beta)
+    with jax.named_scope("gdn_norm"):
+        z = qkvz[..., (2 + r) * d:].reshape(B, S, kb * r, d)
+        y = _gated_norm(o.transpose(0, 2, 1, 3), norm_scale, z, cfg.norm_eps)
+    with jax.named_scope("gdn_proj"):
+        return y.reshape(B, S, kb * r * d) @ wo.astype(dt)
+
+
+def _gated_delta_mixer(h, layer, cfg: TransformerConfig):
+    """The Gated DeltaNet mixer on normed hidden states h (B, S, D), Hk key
+    heads and Hv = r Hk value heads of one size d. W_qkvz's columns lie a
+    key head at a time, as the published layout has them: its q, its k, its
+    r value heads' v and their z; W_ba's likewise, its r b and r a; the
+    taps' its q, k and v channels. [q | k | v] go through the causal
+    convolution and a silu; q and k are normalised a head (q over sqrt(d)
+    besides); beta = sigmoid(b) and the log decay g = -exp(A_log)
+    softplus(a + dt_bias), a number a value head and position, are float32
+    from a float32 projection as the router's is; the gated delta rule
+    (`ops.gated_delta`); an RMSNorm a head times silu(z); W_o. The heads are
+    taken a block of `DELTA_HEAD_BLOCK` value heads at a time, one after
+    another, each block run again in the backward pass (`_delta_heads`):
+    what a block keeps and makes for all positions at once is 0.15 GB a
+    value head at 16,384 positions, and 32 heads beside a model's state are
+    more than a chip has. Scopes `gdn_proj`, `gdn_conv`, `gdn_core`,
+    `gdn_norm`."""
+    Hk, Hv, _ = cfg.delta_heads
+    r = Hv // Hk
+    kb = max(b for b in range(1, Hk + 1)
+             if Hk % b == 0 and b * r <= max(DELTA_HEAD_BLOCK, r))
+
+    def blocks(w, axis):
+        """`axis`, a key head at a time, as (blocks, ..., a block's, ...)."""
+        shape = w.shape[:axis] + (Hk // kb, -1) + w.shape[axis + 1:]
+        return jnp.moveaxis(w.reshape(shape), axis, 0)
+
+    parts = (blocks(layer["w_qkvz"], 1), blocks(layer["w_ba"], 1),
+             blocks(layer["conv_w"], 1), blocks(layer["A_log"], 0),
+             blocks(layer["dt_bias"], 0), blocks(layer["wo"], 0))
+
+    def one(out, part):
+        return out + _delta_heads(h, part, layer["gdn_norm_scale"], cfg
+                                  ).astype(jnp.float32), None
+
+    out, _ = jax.lax.scan(one, jnp.zeros(h.shape, jnp.float32), parts)
+    return out.astype(h.dtype)
+
+
+def _scale(w, cfg: TransformerConfig):
+    """A norm's scale from its weight: the weight, or 1 + it."""
+    return 1.0 + w if cfg.norm_offset else w
+
+
+def _expert_layer(h, layer, cfg: TransformerConfig):
+    """The expert layer on normed tokens h (T, D) -> (y (T, D), aux): the
+    routed experts held here through `ops.moe.moe_ffn`, and the shared
+    expert where the configuration has one, behind its sigmoid gate where it
+    has that."""
+    from kungfu_tpu.ops.moe import (moe_ffn, raw_gates, renormalised_gates,
+                                    scaled, swiglu_experts)
+
+    dt = cfg.dtype
+    gates = raw_gates if cfg.gates == "raw" else renormalised_gates
+    y, aux = moe_ffn(
+        h, layer["router"],
+        (layer["w_gate"], layer["w_up"], layer["w_down"]),
+        top_k=cfg.top_k, gates=scaled(gates, cfg.routed_scale),
+        expert_fn=swiglu_experts, held=cfg.experts_held or None)
+    if cfg.shared_ff:
+        with jax.named_scope("moe_shared"):
+            shared = _silu_gate_out(h @ layer["shared_gate"].astype(dt),
+                                    h @ layer["shared_up"].astype(dt),
+                                    layer["shared_down"].astype(dt))
+            if cfg.shared_gate:
+                shared = shared * jax.nn.sigmoid(
+                    (h @ layer["w_shared_gate"].astype(dt)
+                     ).astype(jnp.float32)).astype(dt)
+            y = y + shared
+    return y, aux
+
+
 def _layer(x, layer, cfg: TransformerConfig, core=None):
     """One layer -> (x, aux): aux is the expert layer's `ops.moe.MoeAux`
     (router losses and token-choices per expert), None of any other."""
     dt, eps = cfg.dtype, cfg.norm_eps
-    with jax.named_scope("attn"):
-        scales = ((layer["q_norm_scale"], layer["k_norm_scale"])
-                  if cfg.qk_norm else None)
-        h = _rmsnorm(x, layer["ln1_scale"], eps)
-        wqkv = (tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv"))
-                if cfg.split_qkv else layer["wqkv"].astype(dt))
-        x = x + _attention(h, wqkv, layer["wo"].astype(dt),
-                           cfg, core=core, qk_scales=scales,
-                           w_head_gate=(layer["w_head_gate"].astype(dt)
-                                        if cfg.head_gate else None))
+    if cfg.mixer == "gated_delta":
+        with jax.named_scope("gdn"):
+            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
+            x = x + _gated_delta_mixer(h, layer, cfg)
+    else:
+        with jax.named_scope("attn"):
+            scales = ((_scale(layer["q_norm_scale"], cfg),
+                       _scale(layer["k_norm_scale"], cfg))
+                      if cfg.qk_norm else None)
+            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
+            wqkv = (tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv"))
+                    if cfg.split_qkv else layer["wqkv"].astype(dt))
+            x = x + _attention(h, wqkv, layer["wo"].astype(dt),
+                               cfg, core=core, qk_scales=scales,
+                               w_head_gate=(layer["w_head_gate"].astype(dt)
+                                            if cfg.head_gate else None))
     if cfg.ffn == "moe":
-        from kungfu_tpu.ops.moe import (moe_ffn, raw_gates, renormalised_gates,
-                                        scaled, swiglu_experts)
-
         with jax.named_scope("moe"):
             B, S, D = x.shape
-            h = _rmsnorm(x, layer["ln2_scale"], eps).reshape(B * S, D)
-            gates = raw_gates if cfg.gates == "raw" else renormalised_gates
-            y, aux = moe_ffn(
-                h, layer["router"],
-                (layer["w_gate"], layer["w_up"], layer["w_down"]),
-                top_k=cfg.top_k, gates=scaled(gates, cfg.routed_scale),
-                expert_fn=swiglu_experts, held=cfg.experts_held or None)
-            if cfg.shared_ff:
-                with jax.named_scope("moe_shared"):
-                    y = y + _silu_gate_out(h @ layer["shared_gate"].astype(dt),
-                                           h @ layer["shared_up"].astype(dt),
-                                           layer["shared_down"].astype(dt))
+            h = _rmsnorm(x, _scale(layer["ln2_scale"], cfg), eps).reshape(B * S, D)
+            y, aux = _expert_layer(h, layer, cfg)
             return x + y.reshape(B, S, D), aux
     with jax.named_scope("ffn"):
-        h = _rmsnorm(x, layer["ln2_scale"], eps)
+        h = _rmsnorm(x, _scale(layer["ln2_scale"], cfg), eps)
         if cfg.ffn == "swiglu":
             return x + _silu_gate_out(h @ layer["w_gate"].astype(dt),
                                       h @ layer["w_up"].astype(dt),
@@ -621,7 +862,7 @@ def _block(x, layer, cfg: TransformerConfig, core=None):
 def _head_logits(params, x, cfg: TransformerConfig):
     """Final norm and the LM head, tied to the embedding or `lm_head` of its
     own, in float32."""
-    h = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
+    h = _rmsnorm(x, _scale(params["ln_f_scale"], cfg), cfg.norm_eps)
     head = params["embed"] if cfg.tied_head else params["lm_head"]
     return h.astype(jnp.float32) @ head.astype(jnp.float32).T
 
