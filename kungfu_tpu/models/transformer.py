@@ -748,10 +748,10 @@ def _gated_delta_mixer(h, layer, cfg: TransformerConfig):
     (`ops.gated_delta`); an RMSNorm a head times silu(z); W_o. The heads are
     taken a block of `DELTA_HEAD_BLOCK` value heads at a time, one after
     another, each block run again in the backward pass (`_delta_heads`):
-    what a block keeps and makes for all positions at once is 0.15 GB a
-    value head at 16,384 positions, and 32 heads beside a model's state are
-    more than a chip has. Scopes `gdn_proj`, `gdn_conv`, `gdn_core`,
-    `gdn_norm`."""
+    the rule's kernels hold a chunk in VMEM (PR 37; XLA's temporaries were
+    0.15 GB a head), but a block still keeps q, k, v, z and the chunks'
+    states, and dropping the blocks needs its own account of memory (ROADMAP
+    S17). Scopes `gdn_proj`, `gdn_conv`, `gdn_core`, `gdn_norm`."""
     Hk, Hv, _ = cfg.delta_heads
     r = Hv // Hk
     kb = max(b for b in range(1, Hk + 1)

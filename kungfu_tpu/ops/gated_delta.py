@@ -1,5 +1,5 @@
-"""The gated delta rule as a chunked scan, and the causal depthwise
-convolution that stands before it in a Gated DeltaNet layer.
+"""The gated delta rule in Pallas kernels, forward and backward, and the
+causal depthwise convolution that stands before it in a Gated DeltaNet layer.
 
 The definition is a recurrence over the sequence with a (dk, dv) state a
 head (arXiv:2412.06464, Gated Delta Networks), S_0 = 0:
@@ -18,34 +18,50 @@ chunk's start and d_ij = exp(G_i - G_j):
     O  = (exp(G) Q) S + P U,              P_ij = d_ij q_i.k_j         (j <= i)
     S+ = exp(G_C) S + (exp(G_C - G) K)^T U
 
-so all of a chunk is matrix products, and what runs in sequence is two
-thin products a chunk on the state. Three phases:
+so all of a chunk is matrix products, and what runs in sequence is the
+state from chunk to chunk. Three kernels, Mosaic where the program is
+lowered for the TPU and the same kernels interpreted anywhere else:
 
-1. `_local`, every chunk at once: T = (I + A)^-1 (`_unit_lower_inverse`,
-   exact block substitution in six doublings, float32), W = T (beta exp(G)
-   K), U0 = T (beta V), exp(G) Q, exp(G_C - G) K, P. Every decay is an
-   exponential of a difference G_i - G_j <= 0 taken in float32: nothing is
-   divided by a decay, so a strong one underflows to 0 and nothing
-   overflows.
-2. `_states`, a `lax.scan` over the chunks: S+ = a S + Kd^T (U0 - W S),
-   the state carried in float32; it gives the state at every chunk's start.
-3. `_combine`, every chunk at once again: U = U0 - W S, O = Qg S + P U.
+1. The solve's pass (`_solve`), every chunk at once: XLA makes A (one
+   batched product and the decays) and `_unit_lower_inverse` inverts I + A
+   by forward substitution in `_substitution_kernel`, float32, a system a
+   lane: a step of the substitution is a multiply and a subtract over 512
+   systems at once. T = (I + A)^-1 leaves it in q's type, 33 MB a call of
+   eight heads at 16,384 positions. (Six doublings of two float32 products
+   at the highest precision, the block substitution this replaced, took 3.2
+   ms a call as XLA fusions and would take more on the MXU a chunk at a
+   time; the substitution takes 0.3. PERF.md, PR 37.)
+2. `_forward_kernel`, grid (batch, blocks of heads, blocks of chunks), the
+   last axis in sequence with each head's (dk, dv) float32 state in VMEM
+   scratch: a grid step reads 512 positions of q, k, v, g, beta and T
+   straight from the (B, H, S, d) arrays, loops over its eight chunks
+   (`_chunk`: W = T (beta exp(G) K), U = T (beta V) - W S, exp(G) Q, exp(G_C
+   - G) K, P, all in VMEM), and writes o and the state each chunk starts
+   from. Four heads a grid step run side by side in the loop's body: their
+   chains do not depend on each other, and one chain alone waits for the
+   MXU (1.22 ms a call with one head a step, 0.64 with four).
+3. `_backward_kernel`, the same grid last block to first with dS in
+   scratch: each chunk's local quantities are made again from q, k, v, g,
+   beta, T and the kept state, and dq, dk, dv, dg, dbeta and T's cotangent
+   leave it; what k, g and beta receive through T is the solve's own
+   cotangent (d A = -T^T dT T^T and A's derivative, XLA's) and is added
+   outside.
 
 The matrix products take their operands in the type q, k, v come in
 (bfloat16 in the model, so float32 inputs give a float32 computation) and
-accumulate in float32; g and beta are float32 and every decay is applied in
-float32 (a bfloat16 decay of 0.99 is 0.988, and a state that is read 100
-chunks later is then off by 16 %).
+accumulate in float32; g and beta are float32, every decay is an
+exponential of a difference G_i - G_j <= 0 taken in float32 (nothing is
+divided by a decay, so a strong one underflows to 0 and nothing overflows),
+and the state is float32 (a bfloat16 decay of 0.99 is 0.988, and a state
+that is read 100 chunks later is then off by 16 %). g and beta go through
+the kernels as rows of a chunk, (1, C); a column is made of a row, and a row
+of a column, through the diagonal's mask and a sum, so nothing is
+transposed.
 
-The backward pass is written out (`jax.custom_vjp`): it keeps the five
-inputs and the chunk-boundary states (B H S/C dk dv float32: 0.5 GB a layer
-of 32 heads at 16,384 positions), walks the chunks in reverse with the
-transposed recurrence dS = C_n + a dS+ - W^T (Kd dS+), again two thin
-products a chunk, and then takes the cotangents of phases 1 and 3 for all
-chunks at once. No state a position exists in either pass. Phases 1 and 3
-hold about 0.1 GB a head for all chunks at once at 16,384 positions: a
-caller with many heads and little room hands them over a block at a time,
-as the model's mixer does.
+The backward pass keeps the five inputs and the chunk-boundary states (B H
+S/C dk dv float32: 0.5 GB a layer of 32 heads at 16,384 positions). No
+state a position exists in either pass, and no array of all chunks but T
+and its cotangent (0.1 GB a call of eight heads).
 
 `models/transformer.py` runs it as the core of a layer whose `mixer` is
 `"gated_delta"`, under the scope `gdn_core`.
@@ -58,8 +74,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 64
+BLOCK_CHUNKS = 8  # chunks a grid step of the two passes' kernels: 512 positions
+BLOCK_HEADS = 4  # heads a grid step: their chains are independent
+SOLVE_LANES = 512  # systems a grid step of the solve's kernel
+VMEM_LIMIT = 64 << 20  # of the chip's 128 MiB; the default scope is 16
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -104,29 +126,73 @@ causal_conv.defvjp(lambda x, taps: (causal_conv(x, taps), (x, taps)),
                    _causal_conv_bwd)
 
 
+def _on_platform(kernel, *args, **static):
+    """Mosaic where the program is lowered for the TPU, the same kernel
+    interpreted anywhere else."""
+    return lax.platform_dependent(
+        *args, tpu=functools.partial(kernel, interpret=False, **static),
+        default=functools.partial(kernel, interpret=True, **static))
+
+
+def _substitution_kernel(a_ref, t_ref):
+    """Forward substitution, a system a lane: a_ref[i, j] and t_ref[i, j]
+    are (lanes,) rows that hold entry (i, j) of every system. Column by
+    column, row j of T is final once the columns before it are taken out of
+    the rows below: T_i -= A_ij T_j for i > j, a multiply and a subtract on
+    float32, nothing divided and nothing reordered. T_j ends at its
+    diagonal, so the rows' tiles of eight entries beyond j's are left out."""
+    C = a_ref.shape[0]
+    tile = 8 if C % 8 == 0 else C
+    at = lax.broadcasted_iota(jnp.int32, t_ref.shape[1:], 0)
+
+    def identity(i, carry):
+        t_ref[i] = (at == i).astype(t_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, C, identity, None)
+    for first in range(0, C, tile):
+        upto = slice(0, first + tile)
+
+        def column(j, carry, upto=upto):
+            row_j = t_ref[j, upto, :]
+
+            def below(i, carry):
+                t_ref[i, upto, :] = (t_ref[i, upto, :]
+                                     - a_ref[i, pl.ds(j, 1), :] * row_j)
+                return carry
+
+            return lax.fori_loop(j + 1, C, below, carry)
+
+        lax.fori_loop(first, min(first + tile, C - 1), column, None)
+
+
+def _substitution(a, *, interpret: bool):
+    """(C, C, n) -> (C, C, n): the inverse of I + a[:, :, s] for each s."""
+    C, _, n = a.shape
+    lanes = SOLVE_LANES if n % SOLVE_LANES == 0 else n
+    block = pl.BlockSpec((C, C, lanes), lambda s: (0, 0, s))
+    return pl.pallas_call(
+        _substitution_kernel, grid=(n // lanes,), in_specs=[block],
+        out_specs=block, out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret, name="gated_delta_solve")(a)
+
+
 @jax.custom_vjp
 def _unit_lower_inverse(a):
-    """(I + a)^-1 for strictly lower triangular a (..., C, C), C a power of
-    two, float32. Block substitution by doubling: with T the inverse of the
-    block diagonal of I + a at block size b and E the blocks of a that join
-    two such blocks into one of size 2b, the inverse at 2b is T - T E T
-    (exactly: (T E)^2 = 0). Six doublings of two products each at C = 64,
-    each as stable as forward substitution, where the Neumann product (I -
-    a)(I + a^2)(I + a^4)... cancels binomially large terms when successive
-    keys are alike. The derivative is that of an inverse, two products: d a
-    = -T^T dT T^T."""
+    """(I + a)^-1 for strictly lower triangular a (..., C, C), float32, by
+    forward substitution in a kernel of its own (`_substitution_kernel`):
+    the systems go to the lanes (a transposition each way, XLA's), so a
+    step of the substitution is one multiply and subtract over every system
+    at once. As stable as forward substitution because it is that: the
+    Neumann product (I - a)(I + a^2)(I + a^4)... cancels binomially large
+    terms when successive keys are alike. The derivative is that of an
+    inverse, two products: d a = -T^T dT T^T."""
     C = a.shape[-1]
-    at = jnp.arange(C)
-    T = jnp.broadcast_to(jnp.eye(C, dtype=a.dtype), a.shape)
-    b = 1
-    while b < C:
-        joined = ((at[:, None] // (2 * b) == at[None, :] // (2 * b))
-                  & (at[:, None] // b != at[None, :] // b))
-        E = jnp.where(joined, a, 0)
-        T = T - jnp.matmul(jnp.matmul(T, E, precision=_HIGHEST), T,
-                           precision=_HIGHEST)
-        b *= 2
-    return T
+    systems = jnp.moveaxis(a.reshape(-1, C, C), 0, -1)
+    T = _on_platform(_substitution, systems)
+    return jnp.moveaxis(T, -1, 0).reshape(a.shape)
 
 
 def _unit_lower_inverse_bwd(T, dT):
@@ -139,75 +205,238 @@ _unit_lower_inverse.defvjp(lambda a: (_unit_lower_inverse(a),) * 2,
                            _unit_lower_inverse_bwd)
 
 
-def _mm(a, b, spec: str):
-    """einsum with float32 accumulation, the operands as they come."""
-    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+def _solve(k, g, beta, chunk: int):
+    """The pass before the kernels, every chunk at once: T = (I + A)^-1, (B,
+    H, S / chunk, chunk, chunk) in k's type, A_ij = beta_i exp(G_i - G_j)
+    k_i.k_j below the diagonal. The inverse in float32, rounded after it."""
+    B, H, S, dk = k.shape
+    kc = k.reshape(B, H, S // chunk, chunk, dk)
+    G = jnp.cumsum(g.reshape(B, H, S // chunk, chunk), axis=-1)
+    at = jnp.arange(chunk)
+    below = at[:, None] > at[None, :]
+    decay = jnp.exp(jnp.where(below, G[..., :, None] - G[..., None, :], -jnp.inf))
+    kk = jnp.einsum("...id,...jd->...ij", kc, kc,
+                    preferred_element_type=jnp.float32)
+    A = beta.reshape(G.shape)[..., :, None] * decay * kk  # 0 where decay is
+    return _unit_lower_inverse(A).astype(k.dtype)
 
 
-def _local(q, k, v, g, beta):
-    """Phase 1 on (N, B, H, C, d) q, k, v and (N, B, H, C) float32 g, beta,
-    N chunks of C positions: -> (W, U0, Qg, Kd, P, a). W, Qg, Kd (.., C,
-    dk) and P (.., C, C) in q's type, operands of the products to come; U0
-    (.., C, dv), from which W S is subtracted, and a = exp(G_C) (..) in
-    float32."""
+def _dot(a, b, dims=((1,), (0,))):
+    """a @ b with float32 accumulation, the operands as they come; `dims`
+    the contracted axis of each."""
+    return lax.dot_general(a, b, (dims, ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))  # a @ b^T
+_TN = ((0,), (0,))  # a^T @ b
+
+
+def _to_column(row, eye):
+    """(1, C) -> (C, 1) through the diagonal's mask: no transposition."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _to_row(column, eye):
+    return jnp.sum(jnp.where(eye, column, 0.0), axis=0, keepdims=True)
+
+
+def _chunk(q, k, v, g, beta, T, S):
+    """One chunk in VMEM: q, k (C, dk), v (C, dv) and T (C, C) in their
+    type, g and beta (1, C) float32 as rows, S (dk, dv) float32 the state at
+    its start -> what both passes need of it. Products take their operands
+    in q's type and accumulate in float32; every decay is an exponential of
+    a difference of running sums, in float32."""
     dt, f32 = q.dtype, jnp.float32
-    C = q.shape[-2]
-    G = jnp.cumsum(g, axis=-1)
-    at = jnp.arange(C)
-    lower = at[:, None] >= at[None, :]
-    decay = jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :], -jnp.inf))
-    A = jnp.where(at[:, None] > at[None, :],
-                  beta[..., :, None] * decay * _mm(k, k, "...id,...jd->...ij"), 0)
-    T = _unit_lower_inverse(A).astype(dt)
-    k32 = k.astype(f32)
-    eG = jnp.exp(G)[..., None]
-    W = _mm(T, (beta[..., None] * eG * k32).astype(dt), "...ij,...jd->...id")
-    U0 = _mm(T, (beta[..., None] * v.astype(f32)).astype(dt), "...ij,...jd->...id")
-    Qg = (q.astype(f32) * eG).astype(dt)
-    Kd = (k32 * jnp.exp(G[..., -1:] - G)[..., None]).astype(dt)
-    P = (decay * _mm(q, k, "...id,...jd->...ij")).astype(dt)
-    return W.astype(dt), U0, Qg, Kd, P, jnp.exp(G[..., -1])
+    C = q.shape[0]
+    at_row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    at_col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    lower, eye = at_col <= at_row, at_col == at_row
+    G = jnp.sum(jnp.where(lower, g, 0.0), axis=1, keepdims=True)  # (C, 1)
+    G_end = jnp.sum(g, axis=1, keepdims=True)  # (1, 1)
+    decay = jnp.exp(jnp.where(lower, G - _to_row(G, eye), -jnp.inf))
+    b = _to_column(beta, eye)
+    eG, to_end, a = jnp.exp(G), jnp.exp(G_end - G), jnp.exp(G_end)
+    q32, k32, v32 = q.astype(f32), k.astype(f32), v.astype(f32)
+    bk, bv = (b * eG * k32).astype(dt), (b * v32).astype(dt)
+    W = _dot(T, bk).astype(dt)
+    Qg, Kd = (q32 * eG).astype(dt), (k32 * to_end).astype(dt)
+    qk = _dot(q, k, _NT)
+    P = (decay * qk).astype(dt)
+    Sd = S.astype(dt)
+    U = (_dot(T, bv) - _dot(W, Sd)).astype(dt)
+    return dict(lower=lower, eye=eye, decay=decay, b=b, eG=eG, to_end=to_end,
+                a=a, q32=q32, k32=k32, v32=v32, bk=bk, bv=bv, W=W, Qg=Qg,
+                Kd=Kd, qk=qk, P=P, S=S, Sd=Sd, U=U)
 
 
-def _next_state(S, W, U0, Kd, a):
-    """S+ = a S + Kd^T (U0 - W S) and U, for one chunk or for all."""
-    dt = W.dtype
-    U = U0 - _mm(W, S.astype(dt), "...cd,...dv->...cv")
-    return (a[..., None, None] * S
-            + _mm(Kd, U.astype(dt), "...cd,...cv->...dv")), U
+def _rows(c, chunk: int):
+    return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
 
 
-def _states(W, U0, Kd, a):
-    """Phase 2: the state at the start of each chunk, (N, B, H, dk, dv)
-    float32, S_0 = 0."""
-    def step(S, chunk):
-        return _next_state(S, *chunk)[0], S
+def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, T_ref, o_ref,
+                    states_ref, S_scr, *, chunk: int):
+    """A block of chunks of some heads, first to last; each head's state in
+    `S_scr` from one grid step to the next along the sequence, and in
+    `states_ref` as each chunk starts from it, for the backward pass."""
 
-    S0 = jnp.zeros(a.shape[1:] + (W.shape[-1], U0.shape[-1]), jnp.float32)
-    return lax.scan(step, S0, (W, U0, Kd, a))[1]
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        S_scr[...] = jnp.zeros_like(S_scr)
 
+    def one(c, carry):
+        rows, row = _rows(c, chunk), pl.ds(c, 1)
+        for h in range(S_scr.shape[0]):  # independent chains, side by side
+            S = S_scr[h]
+            states_ref[0, h, c] = S
+            x = _chunk(q_ref[0, h, rows, :], k_ref[0, h, rows, :],
+                       v_ref[0, h, rows, :], g_ref[0, h, row, :],
+                       beta_ref[0, h, row, :], T_ref[0, h, c], S)
+            o_ref[0, h, rows, :] = (_dot(x["Qg"], x["Sd"])
+                                    + _dot(x["P"], x["U"])).astype(o_ref.dtype)
+            S_scr[h] = x["a"] * x["S"] + _dot(x["Kd"], x["U"], _TN)
+        return carry
 
-def _combine(local, S):
-    """Phase 3 with the states S at the chunks' starts: -> (O in float32,
-    the states at the chunks' ends)."""
-    W, U0, Qg, Kd, P, a = local
-    dt = W.dtype
-    S_next, U = _next_state(S, W, U0, Kd, a)
-    O = (_mm(Qg, S.astype(dt), "...cd,...dv->...cv")
-         + _mm(P, U.astype(dt), "...ij,...jv->...iv"))
-    return O, S_next
-
-
-def _chunked(x, chunk: int):
-    """(B, H, S, ...) -> (N, B, H, chunk, ...)."""
-    B, H, S = x.shape[:3]
-    x = x.reshape((B, H, S // chunk, chunk) + x.shape[3:])
-    return jnp.moveaxis(x, 2, 0)
+    lax.fori_loop(0, g_ref.shape[2], one, None)
 
 
-def _unchunked(x):
-    x = jnp.moveaxis(x, 0, 2)
-    return x.reshape(x.shape[:2] + (-1,) + x.shape[4:])
+def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, T_ref, states_ref,
+                     do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dT_ref,
+                     dS_scr, *, chunk: int):
+    """The same block last chunk to first, the grid's blocks last to first
+    (the index maps), the state's cotangent in `dS_scr`. Each chunk's local
+    quantities are made again from the inputs, T and the kept state; what T
+    receives goes out in float32 for the solve's own cotangent."""
+    blocks = g_ref.shape[2]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dS_scr[...] = jnp.zeros_like(dS_scr)
+
+    def lanes(t):
+        return jnp.sum(t, axis=1, keepdims=True)
+
+    def one(i, carry):
+        c = blocks - 1 - i
+        rows, row = _rows(c, chunk), pl.ds(c, 1)
+        for h in range(dS_scr.shape[0]):  # independent chains, side by side
+            q, k, T = q_ref[0, h, rows, :], k_ref[0, h, rows, :], T_ref[0, h, c]
+            x = _chunk(q, k, v_ref[0, h, rows, :], g_ref[0, h, row, :],
+                       beta_ref[0, h, row, :], T, states_ref[0, h, c])
+            dt = q.dtype
+            dO, dS = do_ref[0, h, rows, :].astype(dt), dS_scr[h]
+            dSd, Sd, U, eye = dS.astype(dt), x["Sd"], x["U"], x["eye"]
+            b, eG, to_end, a = x["b"], x["eG"], x["to_end"], x["a"]
+            q32, k32, v32 = x["q32"], x["k32"], x["v32"]
+            # the state's chain: U's cotangent, then the state's own
+            dU = (_dot(x["P"], dO, _TN) + _dot(x["Kd"], dSd)).astype(dt)
+            dS_scr[h] = (a * dS + _dot(x["Qg"], dO, _TN)
+                         - _dot(x["W"], dU, _TN))
+            da = jnp.sum(lanes(x["S"] * dS), axis=0, keepdims=True)
+            # the products' other operands
+            dKd, dQg = _dot(U, dSd, _NT), _dot(dO, Sd, _NT)
+            dP = _dot(dO, U, _NT)
+            dW = (-_dot(dU, Sd, _NT)).astype(dt)
+            dT_ref[0, h, c] = _dot(dW, x["bk"], _NT) + _dot(dU, x["bv"], _NT)
+            dbk, dbv = _dot(T, dW, _TN), _dot(T, dU, _TN)
+            dqk = (dP * x["decay"]).astype(dt)
+            dq_ref[0, h, rows, :] = (eG * dQg + _dot(dqk, k)).astype(dq_ref.dtype)
+            dk_ref[0, h, rows, :] = (b * eG * dbk + to_end * dKd
+                                     + _dot(dqk, q, _TN)).astype(dk_ref.dtype)
+            dv_ref[0, h, rows, :] = (b * dbv).astype(dv_ref.dtype)
+            dbk_k = lanes(dbk * k32)
+            dbeta_ref[0, h, row, :] = _to_row(eG * dbk_k + lanes(dbv * v32), eye)
+            # the decays: every one an exponential of running sums of g
+            d_to_end = lanes(dKd * k32) * to_end
+            d_log = dP * x["qk"] * x["decay"]  # of exp(G_i - G_j), j <= i
+            dG = (lanes(d_log) - _to_column(jnp.sum(d_log, axis=0, keepdims=True), eye)
+                  + (b * dbk_k + lanes(dQg * q32)) * eG - d_to_end)
+            dG_end = da * a + jnp.sum(d_to_end, axis=0, keepdims=True)
+            dg_ref[0, h, row, :] = dG_end + jnp.sum(
+                jnp.where(x["lower"], dG, 0.0), axis=0, keepdims=True)
+        return carry
+
+    lax.fori_loop(0, blocks, one, None)
+
+
+def _block_chunks(N: int) -> int:
+    """Chunks a grid step: the most up to `BLOCK_CHUNKS` that divide N in
+    whole sublane tiles of g's rows, or all of them."""
+    for n in range(min(N, BLOCK_CHUNKS), 7, -1):
+        if N % n == 0 and n % 8 == 0:
+            return n
+    return N
+
+
+def _specs(B, H, S, dk, dv, chunk, back: bool):
+    """Block specs by name for a grid (B, H, blocks of chunks), the blocks
+    in reverse for the backward pass."""
+    N = S // chunk
+    n = _block_chunks(N)
+    last = N // n - 1
+    heads = max(h for h in range(1, BLOCK_HEADS + 1) if H % h == 0)
+
+    def at(*tail):
+        if back:
+            return lambda b, h, s: (b, h, last - s) + tail
+        return lambda b, h, s: (b, h, s) + tail
+
+    return (B, H // heads, N // n), dict(
+        qk=pl.BlockSpec((1, heads, n * chunk, dk), at(0)),
+        v=pl.BlockSpec((1, heads, n * chunk, dv), at(0)),
+        row=pl.BlockSpec((1, heads, n, chunk), at(0)),
+        T=pl.BlockSpec((1, heads, n, chunk, chunk), at(0, 0)),
+        state=pl.BlockSpec((1, heads, n, dk, dv), at(0, 0)),
+        scratch=pltpu.VMEM((heads, dk, dv), jnp.float32))
+
+
+_PARAMS = dict(compiler_params=pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=VMEM_LIMIT))
+
+
+def _forward(q, k, v, g, beta, T, *, chunk: int, interpret: bool):
+    """-> (o, the state at each chunk's start (B, H, S / chunk, dk, dv)
+    float32)."""
+    B, H, S, dk = q.shape
+    dv, N = v.shape[-1], S // chunk
+    grid, spec = _specs(B, H, S, dk, dv, chunk, back=False)
+    rows = (B, H, N, chunk)
+    return pl.pallas_call(
+        functools.partial(_forward_kernel, chunk=chunk),
+        grid=grid,
+        in_specs=[spec["qk"], spec["qk"], spec["v"], spec["row"], spec["row"],
+                  spec["T"]],
+        out_specs=[spec["v"], spec["state"]],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((B, H, N, dk, dv), jnp.float32)],
+        scratch_shapes=[spec["scratch"]],
+        interpret=interpret, name="gated_delta_forward", **_PARAMS,
+    )(q, k, v, g.reshape(rows), beta.reshape(rows), T)
+
+
+def _backward(q, k, v, g, beta, T, states, do, *, chunk: int, interpret: bool):
+    """-> (dq, dk, dv, dg, dbeta, dT): dk, dg and dbeta without what they
+    receive through T, and T's cotangent in float32."""
+    B, H, S, dk = q.shape
+    dv, N = v.shape[-1], S // chunk
+    grid, spec = _specs(B, H, S, dk, dv, chunk, back=True)
+    rows = (B, H, N, chunk)
+    shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)]
+    shapes += [jax.ShapeDtypeStruct(rows, jnp.float32)] * 2
+    shapes += [jax.ShapeDtypeStruct(T.shape, jnp.float32)]
+    *d, dg, dbeta, dT = pl.pallas_call(
+        functools.partial(_backward_kernel, chunk=chunk),
+        grid=grid,
+        in_specs=[spec["qk"], spec["qk"], spec["v"], spec["row"], spec["row"],
+                  spec["T"], spec["state"], spec["v"]],
+        out_specs=[spec["qk"], spec["qk"], spec["v"], spec["row"], spec["row"],
+                   spec["T"]],
+        out_shape=shapes,
+        scratch_shapes=[spec["scratch"]],
+        interpret=interpret, name="gated_delta_backward", **_PARAMS,
+    )(q, k, v, g.reshape(rows), beta.reshape(rows), T, states, do)
+    return (*d, dg.reshape(g.shape), dbeta.reshape(beta.shape), dT)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -227,37 +456,19 @@ def _fwd(q, k, v, g, beta, chunk):
     if chunk & (chunk - 1) or S % chunk:
         raise ValueError(f"gated_delta_rule: the sequence length {S} is no "
                          f"multiple of the chunk {chunk}, a power of two")
-    local = _local(*(_chunked(x, chunk) for x in (q, k, v, g, beta)))
-    W, U0, _, Kd, _, a = local
-    states = _states(W, U0, Kd, a)
-    O, _ = _combine(local, states)
-    return _unchunked(O).astype(v.dtype), (q, k, v, g, beta, states)
+    T = _solve(k, g, beta, chunk)
+    o, states = _on_platform(_forward, q, k, v, g, beta, T, chunk=chunk)
+    return o, (q, k, v, g, beta, states)
 
 
 def _bwd(chunk, res, do):
     q, k, v, g, beta, states = res
-    inputs = tuple(_chunked(x, chunk) for x in (q, k, v, g, beta))
-    local, back_local = jax.vjp(_local, *inputs)
-    W, _, Qg, Kd, P, a = local
-    dt = W.dtype
-    dO = _chunked(do, chunk).astype(dt)
-    # what a chunk's outputs say of its U: with Qg^T dO, the part of dS
-    # that waits for no later chunk
-    dU_O = _mm(P, dO, "...ij,...iv->...jv")
-
-    def step(dS_next, chunk_):
-        W, Qg, Kd, a, dO, dU_O = chunk_
-        dU = dU_O + _mm(Kd, dS_next.astype(dt), "...cd,...dv->...cv")
-        dS = (a[..., None, None] * dS_next
-              + _mm(Qg, dO, "...cd,...cv->...dv")
-              - _mm(W, dU.astype(dt), "...cd,...cv->...dv"))
-        return dS, dS_next
-
-    zero = jnp.zeros(states.shape[1:], jnp.float32)
-    _, dS_next = lax.scan(step, zero, (W, Qg, Kd, a, dO, dU_O), reverse=True)
-    _, back_combine = jax.vjp(lambda local: _combine(local, states), local)
-    (d_local,) = back_combine((dO.astype(jnp.float32), dS_next))
-    return tuple(_unchunked(d) for d in back_local(d_local))
+    T, back_solve = jax.vjp(lambda k, g, beta: _solve(k, g, beta, chunk),
+                            k, g, beta)
+    dq, dk, dv, dg, dbeta, dT = _on_platform(
+        _backward, q, k, v, g, beta, T, states, do, chunk=chunk)
+    dk_T, dg_T, dbeta_T = back_solve(dT.astype(T.dtype))
+    return dq, dk + dk_T, dv, dg + dg_T, dbeta + dbeta_T
 
 
 gated_delta_rule.defvjp(_fwd, _bwd)

@@ -97,6 +97,116 @@ def test_bfloat16_operands_float32_state():
     assert _rel(delta_rule(*rounded, block=64), want) > 3e-2
 
 
+MODEL_CASES = [
+    # S, B, H, decay a position; heads of 128, chunks of 64, bfloat16
+    (1536, 2, 3, 0.02),  # three grid blocks of eight chunks: the scratch
+    # state crosses a block's edge, and B x H > 1 starts it anew a head
+    (1024, 1, 2, 0.0),   # two blocks, no decay
+    (512, 2, 1, 0.3),    # one block, a decay of the middle
+]
+
+
+def _model_inputs(seed, S, B, H, decay):
+    low = _inputs(seed, B, H, S, 128, 128, decay, jnp.bfloat16)
+    return low, tuple(a.astype(jnp.float32) for a in low)
+
+
+@pytest.mark.parametrize("S,B,H,decay", MODEL_CASES)
+def test_outputs_at_the_models_shapes(S, B, H, decay):
+    low, exact = _model_inputs(S, S, B, H, decay)
+    got = gated_delta_rule(*low)
+    assert got.shape == (B, H, S, 128) and got.dtype == jnp.bfloat16
+    assert _rel(got, delta_rule(*exact, block=64)) < 1e-2
+
+
+@pytest.mark.parametrize("S,B,H,decay", MODEL_CASES)
+def test_gradients_at_the_models_shapes(S, B, H, decay):
+    low, exact = _model_inputs(S + 1, S, B, H, decay)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (B, H, S, 128))
+    want = jax.grad(_weighted(lambda *a: delta_rule(*a, block=64), weight),
+                    argnums=(0, 1, 2, 3, 4))(*exact)
+    got = jax.grad(_weighted(gated_delta_rule, weight),
+                   argnums=(0, 1, 2, 3, 4))(*low)
+    for name, g, w, a in zip(("q", "k", "v", "g", "beta"), got, want, low):
+        assert g.shape == w.shape and g.dtype == a.dtype, name
+        assert _rel(g, w) < 2e-2, name
+
+
+def _rounded_to_bfloat16(name):
+    """`gated_delta._chunk` with one of a chunk's float32 quantities rounded
+    to bfloat16: "S", the state a chunk starts from, or "a", the decay over
+    the whole chunk."""
+    chunk = gated_delta._chunk
+
+    def rounded(t):
+        return t.astype(jnp.bfloat16).astype(jnp.float32)
+
+    def planted(q, k, v, g, beta, T, S):
+        if name == "S":
+            return chunk(q, k, v, g, beta, T, rounded(S))
+        x = chunk(q, k, v, g, beta, T, S)
+        return {**x, name: rounded(x[name])}
+
+    return planted
+
+
+LONG_MEMORY = 2e-5  # float32 through and through is well under it (some 1e-6)
+
+
+@pytest.mark.parametrize("fault", [None, "S", "a"])
+def test_a_long_memory_needs_a_float32_state_and_decay(fault, monkeypatch):
+    """A log decay of -0.001 a position over 2,048 positions: what the first
+    chunk wrote is still an eighth of itself at the end, through 32 chunk
+    decays and 32 states. In float32 the kernels are at the recurrence,
+    outputs and all five gradients; with the state or a chunk's decay
+    rounded to bfloat16 they are not, which the benchmark's cell cannot see
+    at its initial parameters (PERF.md, section 7)."""
+    q, k, v, g, beta = _inputs(11, 1, 2, 2048, 16, 16, 0.0)
+    g = jnp.full_like(g, -0.001)
+    weight = jax.random.normal(jax.random.PRNGKey(12), v.shape)
+    want = delta_rule(q, k, v, g, beta, block=64)
+    want_grads = jax.grad(_weighted(lambda *a: delta_rule(*a, block=64), weight),
+                          argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    if fault:
+        monkeypatch.setattr(gated_delta, "_chunk", _rounded_to_bfloat16(fault))
+    got = gated_delta_rule(q, k, v, g, beta)
+    got_grads = jax.grad(_weighted(gated_delta_rule, weight),
+                         argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    errors = [_rel(got, want)] + [_rel(a, b) for a, b in zip(got_grads, want_grads)]
+    if fault:
+        assert errors[0] > 10 * LONG_MEMORY, errors
+        assert max(errors[1:]) > 10 * LONG_MEMORY, errors
+    else:
+        assert max(errors) < LONG_MEMORY, errors
+
+
+def _primitives(jaxpr, found):
+    """Every primitive of a jaxpr and of the jaxprs its equations hold, a
+    kernel's own body left closed."""
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("what", ["rule", "gradient"])
+def test_the_rule_is_kernels_and_no_loop(what):
+    """Both passes are `pallas_call`s, with the solve's pass before them:
+    no `scan` and no `while` over the chunks is left outside a kernel."""
+    args = _inputs(2, 1, 2, 256, 16, 24, 0.1)
+    fn = gated_delta_rule if what == "rule" else jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a)), argnums=(0, 1, 2, 3, 4))
+    found = _primitives(jax.make_jaxpr(fn)(*args).jaxpr, set())
+    assert "pallas_call" in found
+    assert not found & {"scan", "while"}, found
+
+
 def test_unit_lower_inverse_and_its_derivative():
     a = jnp.tril(jax.random.normal(jax.random.PRNGKey(0), (3, 16, 16)), -1)
     eye = jnp.eye(16)
