@@ -4,6 +4,8 @@ jax: the parent of a run computes these from what the child wrote."""
 from __future__ import annotations
 
 import importlib
+import json
+import os
 
 from benchmark import manifest as mf
 from benchmark import trace_reduce
@@ -30,6 +32,38 @@ def segment_rates(record: dict) -> list:
             for i in range(0, len(done) - s, s)]
 
 
+def span_seconds(spans, name: str, start: float = float("-inf"),
+                 end: float = float("inf")) -> float:
+    """The seconds under the spans of one name, `[name, start, end, ...]`,
+    that lie between two times; None where there is no such span."""
+    found = [s[2] - s[1] for s in spans
+             if s[0] == name and start <= s[1] and s[2] <= end]
+    return sum(found) if found else None
+
+
+def backend_start_s(record: dict) -> float:
+    """The seconds the machine took to start the accelerator's backend, the
+    first `jax.devices()` of the world, on the reporting rank: the span
+    `device_plane.backend_start` where the program's ring has one (under
+    kfrun `initialize_device_plane()` makes the call, and the child's own is
+    near nothing by then), else the child's marks around its own call (the
+    one-process cells). Four launches of one tree read 13.9, 18.5, 21.5 and
+    29.2 s here (PERF.md section 5): it is the machine's, no program reaches
+    it, and it is what `setup_s` leaves out."""
+    marks = record["marks"]
+    in_ring = span_seconds(record["spans"], "device_plane.backend_start",
+                           end=marks["t_world"])
+    if in_ring is not None:
+        return in_ring
+    return marks["t_backend_1"] - marks["t_backend_0"]
+
+
+def command_to_window_s(record: dict) -> float:
+    """Start of the command to start of the window, the backend's start
+    included: what `setup_s` read until PR 37."""
+    return record["t_window"] - record["t_command"]
+
+
 def values(record: dict) -> dict:
     """Every end-to-end metric of one run. The rate is the median over the
     window's segments of the samples a segment completed over its wall
@@ -43,7 +77,11 @@ def values(record: dict) -> dict:
     Whatever slows more than half of the segments, a stall at every
     sixteenth step or oftener among them, is in the rate at its whole
     cost. `mfu_pct` is the rate in required operations over the chip's
-    peak."""
+    peak. `setup_s` is the command's start to the window's less the
+    backend's start (since PR 38): process start, imports, launcher, world
+    join, state, placement, pool, first step, warm-up and probe are the
+    program's or the benchmark's and stay in; `result_line` reports what
+    was left out, and the whole, in `device`, held to no bound."""
     steps = step_intervals(record)
     rate = percentile(segment_rates(record), 50)
     return {
@@ -51,7 +89,7 @@ def values(record: dict) -> dict:
         "step_ms_p50": percentile(steps, 50) * 1e3,
         "step_ms_p95": percentile(steps, 95) * 1e3,
         "mfu_pct": 100.0 * rate * record["flops_per_sample"] / record["peak_flops"],
-        "setup_s": record["t_window"] - record["t_command"],
+        "setup_s": command_to_window_s(record) - backend_start_s(record),
     }
 
 
@@ -79,6 +117,19 @@ def layer_values(record: dict, trace, names) -> dict:
     return out
 
 
+def merge_ranks(record: dict, out: str) -> dict:
+    """The record with every rank's marks and spans as `record["ranks"]`,
+    in rank order: each rank of the cell's world wrote its own to
+    `<out>/rank_<n>.json` before the closing barrier, and all share one
+    host and so one wall clock."""
+    ranks = []
+    for name in os.listdir(out):
+        if name.startswith("rank_") and name.endswith(".json"):
+            with open(os.path.join(out, name)) as f:
+                ranks.append(json.load(f))
+    return {**record, "ranks": sorted(ranks, key=lambda r: r["rank"])}
+
+
 def result_line(record: dict, trace, manifest: dict) -> dict:
     """The run's last line. Raises for a record that is not from a TPU: no
     CPU number is written under a device metric's name."""
@@ -96,6 +147,8 @@ def result_line(record: dict, trace, manifest: dict) -> dict:
     # peak is the larger of it and the step program's own account
     device["memory_peak_bytes"] = max(record["memory_stats_peak_bytes"],
                                       record["program_memory"]["total_bytes"])
+    device["backend_start_s"] = backend_start_s(record)
+    device["command_to_window_s"] = command_to_window_s(record)
     line = {
         "correct": bool(record["correct"]),
         "attempted": int(record["attempted"]),

@@ -4,7 +4,7 @@ that holds the paper's table (stage sizes, widths, classes, image size).
 The step cannot go through `make_train_step`: the loss carries batch-norm
 statistics as auxiliary state, which that factory has no place for (PERF.md,
 Open questions). So the family gives the per-chip body of a step itself
-(`local_step`), as `bench.py` has it — the optimizer's traced `pmean` on the
+(`local_step`) — the optimizer's traced `pmean` on the
 gradients, the batch statistics `pmean`ed like them — and the traffic's step
 factory (`benchmark/steps/`) puts it over the mesh.
 """

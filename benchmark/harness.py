@@ -28,24 +28,75 @@ PROBE_STEPS = 6  # five intervals, whose median sets the window's step count
 TRACE_STEPS = 20  # what the traced run profiles, after its window
 SAMPLE_INDEX = 1 << 20  # the reference sample's place in the seeded stream
 
+# the boundaries of the set-up, in order, as wall-clock times (`time.time()`):
+# the parent's, the child's four before `measure`, and `measure`'s own
+MARKS = ("t_command", "t_child", "t_joined", "t_backend_0", "t_backend_1",
+         "t_world", "t_init", "t_placed", "t_pool", "t_first_0", "t_first_1",
+         "t_window")
+# the spans of the program's ring that the record carries
+SPAN_PREFIXES = ("worker.", "device_plane.", "broadcast.")
+
 
 class EventCounter:
     """Counts jax.monitoring events by name from its construction on. Every
     compile request, XLA compile or persistent-cache load, raises one
     COMPILE_EVENT, so a difference of two readings counts compilations
-    (copied from chip_smoke._jax_events)."""
+    (copied from chip_smoke._jax_events). Beside each duration event's
+    count it keeps the sum of the durations and, where JAX raises the
+    event as a time span too (`dispatch.log_elapsed_time`: trace, lowering
+    and compile-or-load, on `time.time()`), the spans."""
 
     def __init__(self):
         from jax import monitoring
 
         self.counts = collections.Counter()
+        self.seconds = collections.Counter()
+        self.spans = collections.defaultdict(list)
         monitoring.register_event_listener(
             lambda event, **kw: self.counts.update([event]))
-        monitoring.register_event_duration_secs_listener(
-            lambda event, duration, **kw: self.counts.update([event]))
+        monitoring.register_event_duration_secs_listener(self._duration)
+        monitoring.register_event_time_span_listener(
+            lambda event, start, end, **kw: self.spans[event].append((start, end)))
+
+    def _duration(self, event, duration, **kw):
+        self.counts[event] += 1
+        self.seconds[event] += duration
 
     def __getitem__(self, event: str) -> int:
         return self.counts[event]
+
+    def reading(self) -> tuple:
+        """What `since` takes the difference to."""
+        return (collections.Counter(self.counts), collections.Counter(self.seconds),
+                {event: len(spans) for event, spans in self.spans.items()})
+
+    def since(self, reading: tuple) -> dict:
+        """The duration events raised since a reading, by name: how many,
+        the sum of their durations, and their time spans merged. A traced
+        function that calls jitted ones raises a trace event for each
+        inside its own, so the sum counts nested seconds once a level and
+        the merged spans once."""
+        counts, seconds, spans = reading
+        return {event: {"count": self.counts[event] - counts[event],
+                        "sum_s": self.seconds[event] - seconds[event],
+                        "spans": trace_reduce.union(
+                            self.spans[event][spans.get(event, 0):])}
+                for event in self.seconds if self.counts[event] > counts[event]}
+
+
+def ring_spans(to_wall: float) -> list:
+    """The complete spans of the program's ring under SPAN_PREFIXES as
+    [name, start, end, depth, args], in order of their start. The ring is
+    on `perf_counter`; `to_wall` is one reading of `time.time()` less one of
+    `perf_counter()`, as `tracing.chrome_trace()`'s metadata pairs them."""
+    from kungfu_tpu.telemetry import tracing
+
+    return sorted(
+        ([e.name, e.start + to_wall, e.start + e.duration + to_wall, e.depth,
+          dict(e.args or {})]
+         for e in tracing.full_events()
+         if e.phase == "X" and e.name.startswith(SPAN_PREFIXES)),
+        key=lambda span: span[1])
 
 
 def family_of(config: dict):
@@ -247,14 +298,20 @@ def reference_check(family, config: dict, seed: int, final_state,
 
 
 def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
-            trace_dir, events: EventCounter, t_command: float) -> dict:
+            trace_dir, events: EventCounter, t_command: float,
+            marks: dict | None = None) -> dict:
     """Set up, warm up, run the window, check. Returns the run's record;
     every rank of a world computes it, the reporting rank writes it.
     `trace_dir`, where given, makes this the traced run: after the window,
-    TRACE_STEPS steps more under `jax.profiler`."""
+    TRACE_STEPS steps more under `jax.profiler`. `marks` are the child's
+    own from before this call (`t_child` to `t_backend_1`); the record's
+    `marks` hold them with `t_command` and this function's, each taken
+    after a `block_until_ready` on what its phase made, so that
+    asynchronous dispatch hands no phase's seconds to the next."""
     import jax
 
-    t_world = time.time()
+    marks = {"t_command": t_command, **(marks or {}), "t_world": time.time()}
+    to_wall = time.time() - time.perf_counter()
     config, traffic = cell["config"], cell["traffic"]
     family = family_of(config)
     factory = manifest.plugin("steps", traffic["step"])
@@ -262,20 +319,29 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
     samples_per_step = traffic["per_chip_batch"] * chips
     step_fn, init_opt_state = factory.build(family, config, traffic, mesh)
 
-    state = world.place_state(family.init(config, seed), mesh)
-    state, opt_state = factory.place(state, init_opt_state(state), mesh)
+    state = jax.block_until_ready(family.init(config, seed))
+    marks["t_init"] = time.time()
+    state = world.place_state(state, mesh)
+    state, opt_state = jax.block_until_ready(
+        factory.place(state, init_opt_state(state), mesh))
+    marks["t_placed"] = time.time()
     pool = [family.host_batch(config, seed, i, samples_per_step)
             for i in range(traffic["pool"])]
     place = manifest.plugin("placements", traffic["placement"]).make(
         mesh, factory.BATCH_AXIS)
+    marks["t_pool"] = time.time()
 
     # the first step: lower, compile (or load from the cache), run
+    before_first = events.reading()
+    marks["t_first_0"] = time.time()
     t0 = time.perf_counter()
     batch = place(pool[0])
     step = step_fn.lower(state, opt_state, batch).compile()
     state, opt_state, loss = step(state, opt_state, batch)
     first_loss = float(loss)
     first_step_s = time.perf_counter() - t0
+    marks["t_first_1"] = time.time()
+    first_step_events = events.since(before_first)
     memory = program_memory(step)
 
     # warm up the one shape, then time a few steps to size the window
@@ -295,7 +361,7 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
     compiles_before = events[COMPILE_EVENT]
     # nothing is switched off for the window: what stops a user's loop (the
     # host's pauses, Python's collector) stops this one
-    t_window = time.time()
+    marks["t_window"] = time.time()
     state, opt_state, window = run_steps(
         step, state, opt_state, pool, place, n, at)
     at += n
@@ -322,6 +388,9 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
     # which scope of the program each device op belongs to, for the
     # per-layer metrics that split the traced steps by it
     scopes = trace_reduce.scope_table(step.as_text()) if trace_dir else None
+    # the program's own spans of the launch and the placement, read once,
+    # after the window: nothing timed pays for the reading
+    spans = ring_spans(to_wall)
 
     # checks, outside the window
     before = [first_loss] + warm["losses"] + probe["losses"]
@@ -368,9 +437,12 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
         "flops_per_sample": family.flops_per_sample(config),
         "peak_flops": peaks["bf16_flops"],
         "t_command": t_command,
-        "t_world": t_world,
-        "t_window": t_window,
+        "t_world": marks["t_world"],
+        "t_window": marks["t_window"],
+        "marks": marks,
+        "spans": spans,
         "first_step_s": first_step_s,
+        "first_step_events": first_step_events,
         "program_memory": memory,
         "memory_stats_peak_bytes": max(
             (s.get("peak_bytes_in_use") or 0 for s in stats), default=0),

@@ -312,7 +312,8 @@ def check_result_line(line: dict, manifest: dict, workload: str,
     if not traced:
         for name in set(units) - set(line["metrics"]):
             p.append(f"end_to_end metric {name} is missing")
-    want = {"platform", "kind", "count", "memory_peak_bytes"}
+    want = {"platform", "kind", "count", "memory_peak_bytes",
+            "backend_start_s", "command_to_window_s"}
     if traced:
         want |= {"busy_s", "window_s"}
     if set(line["device"]) != want:

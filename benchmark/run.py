@@ -109,7 +109,9 @@ def main() -> int:
               file=sys.stderr)
         return code
     with open(os.path.join(out, "record.json")) as f:
-        record = json.load(f)
+        record = end_to_end.merge_ranks(json.load(f), out)
+    with open(os.path.join(out, "record.json"), "w") as f:
+        json.dump(record, f)
     trace = None
     if args.trace:
         with open(os.path.join(out, "trace.json")) as f:

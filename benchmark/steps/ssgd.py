@@ -7,7 +7,7 @@ A family whose loss is `loss_fn(cfg)(params, batch) -> loss` goes through the
 public factory, `parallel.make_train_step`. One whose loss carries auxiliary
 state (batch-norm statistics) has no place there (PERF.md, Open questions)
 and gives the per-chip body itself, `local_step(cfg, optimizer, axis)`; it is
-put over the mesh here as `bench.py` does it.
+put over the mesh here.
 """
 
 from benchmark import manifest

@@ -14,6 +14,7 @@ import pytest
 from benchmark import end_to_end, harness, manifest as mf
 from benchmark.families import laguna
 from benchmark.launchers.none import OneProcess
+from drawn_setup import child_marks, drawn_setup
 from benchmark.layer_metrics import (attn_proj_ms, full_core_ms,
                                      full_core_roofline_pct,
                                      moe_share_dispatch_ms,
@@ -280,7 +281,8 @@ def test_measure_at_tiny_size_on_two_cpu_devices(events):
     mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
     record = harness.measure(cell, mesh, OneProcess(), {"bf16_flops": 197e12},
                              seed=2**31 + 7, seconds=0.3, trace_dir=None,
-                             events=events, t_command=time.time())
+                             events=events, t_command=time.time(),
+                             marks=child_marks())
     assert record["checks"]["no_compile_in_window"], record["window"]["compiles"]
     assert record["checks"]["loss_fell"], (record["losses_before"],
                                            record["window"]["losses"][-8:])
@@ -306,13 +308,14 @@ MS = 2_000_000  # a unit of the drawing below, in ns: 2 ms
 #   full.bwd [25, 35)  window.bwd [35, 37)  window.again [37, 38) (the
 #   sliding layers' forward kernel, run again in the backward pass)
 #   proj.bwd [38, 46)  gmm.bwd [46, 48)  shared.bwd [48, 52)  scatter [52, 53)
+#   adamw [53, 56) (under `optimizer`)
 STEP_OPS = [("proj.fwd", 0, 4), ("rope", 4, 4.5), ("window.fwd", 4.5, 5.5),
             ("full.fwd", 5.5, 9.5), ("gate", 9.5, 10), ("router", 10, 11),
             ("sort", 11, 13), ("gmm.fwd", 13, 14), ("shared.fwd", 14, 16),
             ("combine", 16, 17), ("norm", 17, 17.25), ("dense", 17.25, 22),
             ("head", 22, 25), ("full.bwd", 25, 35), ("window.bwd", 35, 37),
             ("window.again", 37, 38), ("proj.bwd", 38, 46), ("gmm.bwd", 46, 48),
-            ("shared.bwd", 48, 52), ("scatter", 52, 53)]
+            ("shared.bwd", 48, 52), ("scatter", 52, 53), ("adamw", 53, 56)]
 DRAWN = {
     "chips": [{"plane": "/device:TPU:0", "program": "jit_step",
                "steps": [[0, 60 * MS], [60 * MS, 120 * MS]],
@@ -344,6 +347,7 @@ SCOPES = {
     "norm": f"{FWD}/moe/checkpoint/rsqrt",
     "dense": f"{FWD}/ffn/dot_general",
     "head": "jit(step)/shard_map/jvp(head_loss)/dot_general",
+    "adamw": "jit(step)/shard_map/optimizer/optimizer_update/add",
 }
 
 
@@ -408,8 +412,7 @@ def test_a_program_without_the_scope_reads_nothing_run(reader):
 
 def test_the_traced_line_holds_the_eight_new_metrics():
     manifest = mf.load()
-    record = {**_record(), "traced": True, "t_command": 0.0, "t_world": 1.0,
-              "first_step_s": 1.0, "chips": 1,
+    record = {**_record(), "traced": True, **drawn_setup(), "chips": 1,
               "window": {"compiles": 0, "t_done": [1.0, 1.4, 1.8, 2.2],
                          "spans": [["bench.input", 1.0, 1.001]]},
               "program_memory": {"total_bytes": 17_300_000_000},
@@ -419,6 +422,8 @@ def test_the_traced_line_holds_the_eight_new_metrics():
     mine = {x["name"] for x in mf.metrics_of(manifest, "per_layer", CELL)}
     assert set(line["metrics"]) == mine
     assert {r.__name__.split(".")[-1] for r in READERS} <= mine
-    for name in ("optimizer_ms", "head_loss_ms", "attention_core_ms",
-                 "flash_core_ms", "moe_ms"):  # other cells' lists, as they were
-        assert name not in mine
+    for name in ("attention_core_ms", "flash_core_ms", "moe_ms"):
+        assert name not in mine  # other cells' lists, as they were
+    # head and optimizer are read here since PR 38
+    assert line["metrics"]["head_loss_ms"]["value"] == pytest.approx(2 * 3.0)
+    assert line["metrics"]["optimizer_ms"]["value"] == pytest.approx(2 * 3.0)

@@ -12,6 +12,7 @@ import pytest
 
 from benchmark import end_to_end, harness, manifest as mf
 from benchmark.launchers.none import OneProcess
+from drawn_setup import child_marks, drawn_setup
 
 TINY = {
     "bert_base": dict(hidden_size=64, num_hidden_layers=2,
@@ -130,8 +131,7 @@ def _record():
         "workload": "bert_base.ssgd_1chip", "traced": False,
         "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
         "chips": 4, "samples_per_step": 64, "flops_per_sample": 1e9,
-        "peak_flops": 1e12, "t_command": 100.0, "t_world": 112.0,
-        "t_window": 117.5, "first_step_s": 0.7,
+        "peak_flops": 1e12, **drawn_setup(),
         # five steps: four intervals of 0.1, 0.1, 0.1, 0.2 s
         "window": {"t_start": 10.0, "t_done": [10.1, 10.2, 10.3, 10.4, 10.6],
                    "spans": [["bench.input", 10.0, 10.001],
@@ -157,7 +157,8 @@ def test_end_to_end_values_by_hand():
     assert v["mfu_pct"] == pytest.approx(100 * 160.0 * 1e9 / 1e12)
     # by the wall clock, first completion to last: 4 * 16 / 0.5 = 128
     assert end_to_end.stall_share(_record()) == pytest.approx(1 - 128 / 160)
-    assert v["setup_s"] == pytest.approx(17.5)
+    # command to window 17.5 s, of which the backend's start took 8
+    assert v["setup_s"] == pytest.approx(9.5)
     assert all(x > 0 for x in v.values())
 
 
@@ -235,6 +236,10 @@ def test_result_line_of_a_chip_record():
     assert line["metrics"]["step_ms_p50"] == {"value": pytest.approx(100.0), "unit": "ms"}
     # memory_stats() misses the program's temporaries: the larger counts
     assert line["device"]["memory_peak_bytes"] == 15_000_000_000
+    # what `setup_s` leaves out, and the whole, beside it and unbounded
+    assert line["device"]["backend_start_s"] == pytest.approx(8.0)
+    assert line["device"]["command_to_window_s"] == pytest.approx(17.5)
+    assert line["metrics"]["setup_s"]["value"] == pytest.approx(9.5)
     json.dumps(line)
 
 
@@ -265,8 +270,11 @@ def test_layer_readers_that_find_nothing_are_left_out():
     # no trace: the trace's metrics are absent, the record's are there
     assert set(found) == {"launch_to_world_s", "first_step_s",
                           "compiles_in_window", "input_wait_ms_p50",
-                          "stall_share_pct", "step_program_hbm_gb"}
-    assert found["launch_to_world_s"] == pytest.approx(12.0)
+                          "stall_share_pct", "step_program_hbm_gb",
+                          "state_init_s", "state_place_s", "host_pool_s",
+                          "warmup_probe_s", "first_step_trace_lower_s",
+                          "first_step_load_or_compile_s"}
+    assert found["launch_to_world_s"] == pytest.approx(4.0)
     assert found["input_wait_ms_p50"] == pytest.approx(2.0)
     assert found["step_program_hbm_gb"] == pytest.approx(15.0)
     assert found["compiles_in_window"] == 0.0
@@ -300,7 +308,7 @@ def test_measure_at_tiny_size_on_four_cpu_devices(workload, events):
     record = harness.measure(cell, mesh, OneProcess(),
                              {"bf16_flops": 197e12}, seed=3, seconds=0.3,
                              trace_dir=None, events=events,
-                             t_command=time.time())
+                             t_command=time.time(), marks=child_marks())
     assert record["checks"]["no_compile_in_window"], record["window"]["compiles"]
     assert record["checks"]["state_spans_mesh"]
     assert record["checks"]["loss_fell"], (record["losses_before"],
@@ -359,7 +367,7 @@ def test_the_reference_check_holds_three_copies_of_the_parameters(
     cell, mesh = _tiny_cell()
     record = harness.measure(cell, mesh, world(), {"bf16_flops": 197e12},
                              seed=3, seconds=0.1, trace_dir=None, events=events,
-                             t_command=time.time())
+                             t_command=time.time(), marks=child_marks())
     assert record["correct"], record["checks"]
     x = seen["x"]
     assert x > 300_000  # the tiny model's parameters, float32
@@ -373,7 +381,7 @@ def test_measure_takes_a_mesh_of_two_axes(events):
     cell, mesh = _tiny_cell(mesh={"dp": 2, "tp": 2})
     record = harness.measure(cell, mesh, OneProcess(), {"bf16_flops": 197e12},
                              seed=3, seconds=0.1, trace_dir=None, events=events,
-                             t_command=time.time())
+                             t_command=time.time(), marks=child_marks())
     assert record["correct"], (record["checks"], record["reference"])
     assert record["chips"] == 4 and record["samples_per_step"] == 8
     assert record["scopes"] is None  # the scope table is the traced run's
@@ -396,7 +404,7 @@ def test_the_traced_run_measures_the_window_and_then_profiles(events, tmp_path):
     world = Asked()
     record = harness.measure(cell, mesh, world, {"bf16_flops": 197e12}, seed=3,
                              seconds=0.3, trace_dir=str(tmp_path), events=events,
-                             t_command=time.time())
+                             t_command=time.time(), marks=child_marks())
     window, traced = record["window"], record["traced_window"]
     n = len(window["t_done"])
     assert n == harness.steps_for(0.3, {"t_done": np.cumsum(

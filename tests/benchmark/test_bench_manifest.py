@@ -110,7 +110,7 @@ FAULTS = {
     "why_too_long": (_set(["workloads", 0, "why"], "w" * 201), "one line"),
     "run_seconds_too_long": (_set(["run_seconds"], 52), "run_seconds"),
     "run_seconds_fraction": (_set(["run_seconds"], 20.5), "run_seconds"),
-    "command_outside_paths": (_set(["command"], ["python3", "bench.py"]), "outside paths"),
+    "command_outside_paths": (_set(["command"], ["python3", "chip_smoke.py"]), "outside paths"),
     "command_absolute": (_set(["command"], ["python3", "/root/repo/benchmark/run.py"]), "leads out"),
     "path_leads_out": (_set(["paths"], ["benchmark", "../elsewhere"]), "permitted characters"),
     "path_missing": (_set(["paths"], ["benchmark", "tests/benchmark", "absent_dir"]), "no directory"),
@@ -179,9 +179,11 @@ def scratch(tmp_path_factory):
 
 def _manifest_of(sound, cells, four):
     """The tree's manifest with `cells` cells, the first `four` of them on
-    four chips, over the scratch tree's 24 traffic files; every
-    configuration is used."""
+    four chips, over the scratch tree's 24 traffic files and the first
+    `cells` of the tree's configurations, however many it has; every
+    configuration kept is used."""
     m = copy.deepcopy(sound)
+    m["configs"] = m["configs"][:cells]
     configs = [c["name"] for c in m["configs"]]
     m["workloads"] = [
         {"name": f"{configs[i % len(configs)]}.mix_{i:02d}",
@@ -212,7 +214,8 @@ def test_one_four_chip_cell_is_always_allowed(sound, scratch, cells, four, sound
 
 def _line(traced):
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
-              "memory_peak_bytes": 15e9}
+              "memory_peak_bytes": 15e9, "backend_start_s": 8.0,
+              "command_to_window_s": 24.0}
     line = {"correct": True, "attempted": 250, "failed": 0, "device": device}
     if traced:
         device.update(busy_s=1.5, window_s=1.6)
@@ -245,6 +248,8 @@ LINE_FAULTS = {
         step_ms_p50={"value": 0.08, "unit": "s"}),
     "missing_metric": lambda l: l["metrics"].pop("setup_s"),
     "device_without_memory": lambda l: l["device"].pop("memory_peak_bytes"),
+    "device_without_backend_start": lambda l: l["device"].pop("backend_start_s"),
+    "device_without_the_whole_setup": lambda l: l["device"].pop("command_to_window_s"),
     "extra_key_in_metric": lambda l: l["metrics"]["mfu_pct"].update(p95=1),
 }
 
@@ -401,11 +406,14 @@ def _module_at(bench: str):
     return find
 
 
+NEW_CELLS = 5
+
+
 def _eight_cells(sound):
     """The tree's cells and what R1 and R7 would add as files alone: a
-    third family on one chip and on a dp x tp mesh of four, eight cells,
-    two of them on four chips; and a per-layer metric for the new cells
-    only."""
+    new family on one chip and on a dp x tp mesh of four, NEW_CELLS cells
+    more (eight or more in all), two of them on four chips; and a
+    per-layer metric for the new cells only."""
     m = copy.deepcopy(sound)
     m["configs"].append({
         "name": "third", "source": "https://example.org/third/config.json",
@@ -434,7 +442,7 @@ def test_the_next_family_mesh_and_eighth_cell_come_as_files(sound, scratch, monk
     repo, bench = scratch
     m = _eight_cells(sound)
     assert mf.check(m, bench, repo) == []
-    assert len(m["workloads"]) == 8
+    assert len(m["workloads"]) == len(sound["workloads"]) + NEW_CELLS >= 8
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 2
     monkeypatch.setattr(mf, "REPO", repo)
     monkeypatch.setattr(mf, "BENCH_DIR", bench)
@@ -458,15 +466,17 @@ def test_the_next_family_mesh_and_eighth_cell_come_as_files(sound, scratch, monk
 def test_the_parents_closed_lists_refuse_the_scratch_tree(sound, scratch, monkeypatch,
                                                            closed_list, refuses):
     """The three whitelists this file held every cell to until PR 26
-    (family in (transformer, resnet), launcher in (none, kfrun), mesh ==
+    (family among the tree's own, launcher in (none, kfrun), mesh ==
     {dp: chips}): put back, each refuses the scratch tree's new cells,
     which `_holds_what_the_harness_needs` takes."""
     repo, bench = scratch
     monkeypatch.setattr(mf, "REPO", repo)
     monkeypatch.setattr(mf, "BENCH_DIR", bench)
     m = _eight_cells(sound)
+    families = {mf.cell(sound, w["name"])["config"]["family"]
+                for w in sound["workloads"]}
     parents = {
-        "family": lambda c: c["config"]["family"] in ("transformer", "resnet"),
+        "family": lambda c: c["config"]["family"] in families,
         "launcher": lambda c: c["traffic"]["launcher"] in ("none", "kfrun"),
         "mesh": lambda c: c["traffic"]["mesh"] == {"dp": c["chips"]},
     }

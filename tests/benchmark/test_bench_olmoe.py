@@ -13,6 +13,7 @@ import pytest
 from benchmark import end_to_end, harness, manifest as mf
 from benchmark.families import olmoe
 from benchmark.launchers.none import OneProcess
+from drawn_setup import child_marks, drawn_setup
 from benchmark.layer_metrics import (expert_ffn_ms, expert_matmul_peak_pct,
                                      flash_core_ms, flash_roofline_pct,
                                      moe_dispatch_ms, moe_ms)
@@ -206,7 +207,8 @@ def test_measure_at_tiny_size_on_four_cpu_devices(events):
     mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
     record = harness.measure(cell, mesh, OneProcess(), {"bf16_flops": 197e12},
                              seed=2**31 + 7, seconds=0.3, trace_dir=None,
-                             events=events, t_command=time.time())
+                             events=events, t_command=time.time(),
+                             marks=child_marks())
     assert record["checks"]["no_compile_in_window"], record["window"]["compiles"]
     assert record["checks"]["loss_fell"], (record["losses_before"],
                                            record["window"]["losses"][-8:])
@@ -231,12 +233,14 @@ MS = 1_000_000
 #   combine [7.5, 8)   norm [8, 8.25) (under `moe` alone)  head [8.25, 12)
 #   gmm.bwd [12, 22)   scatter [22, 23) flash.bwd [23, 25.5)
 #   copy [26, 27): the compiler's own, in no scope   silu [27, 27.5)
+#   adamw [27.5, 29.5) (under `optimizer`)
 # gmm.fwd and gmm.bwd are the grouped-matmul kernels, which carry XLA's name
 # and no scope; silu is the gate recomputed between them, under `moe_experts`
 STEP_OPS = [("flash.fwd", 0, 1), ("router", 1, 1.5), ("sort", 1.5, 2.5),
             ("gmm.fwd", 2.5, 7.5), ("combine", 7.5, 8), ("norm", 8, 8.25),
             ("head", 8.25, 12), ("gmm.bwd", 12, 22), ("scatter", 22, 23),
-            ("flash.bwd", 23, 25.5), ("copy", 26, 27), ("silu", 27, 27.5)]
+            ("flash.bwd", 23, 25.5), ("copy", 26, 27), ("silu", 27, 27.5),
+            ("adamw", 27.5, 29.5)]
 DRAWN = {
     "chips": [{"plane": "/device:TPU:0", "program": "jit_step",
                "steps": [[0, 30 * MS], [30 * MS, 60 * MS]],
@@ -259,6 +263,7 @@ SCOPES = {
     "scatter": f"{BWD}/moe/moe_dispatch/scatter-add",
     "norm": f"{FWD}/moe/checkpoint/rsqrt",
     "head": "jit(step)/shard_map/jvp(head_loss)/dot_general",
+    "adamw": "jit(step)/shard_map/optimizer/optimizer_update/add",
 }
 
 
@@ -318,8 +323,7 @@ def test_a_program_without_the_scope_reads_nothing_run(reader):
 
 def test_the_traced_line_holds_the_six_new_metrics():
     manifest = mf.load()
-    record = {**_record(), "traced": True, "t_command": 0.0, "t_world": 1.0,
-              "first_step_s": 1.0, "chips": 1,
+    record = {**_record(), "traced": True, **drawn_setup(), "chips": 1,
               "window": {"compiles": 0, "t_done": [1.0, 1.1, 1.2, 1.3],
                          "spans": [["bench.input", 1.0, 1.001]]},
               "program_memory": {"total_bytes": 12_400_000_000},
@@ -329,8 +333,10 @@ def test_the_traced_line_holds_the_six_new_metrics():
     mine = {x["name"] for x in mf.metrics_of(manifest, "per_layer", CELL)}
     assert set(line["metrics"]) == mine
     assert {r.__name__.split(".")[-1] for r in READERS} <= mine
-    for name in ("attention_core_ms", "head_loss_ms", "optimizer_ms"):
-        assert name not in mine  # they list the bert_base cells alone
+    assert "attention_core_ms" not in mine  # it lists the bert_base cells alone
+    # head and optimizer, near half of the step, are read here since PR 38
+    assert line["metrics"]["head_loss_ms"]["value"] == pytest.approx(3.75)
+    assert line["metrics"]["optimizer_ms"]["value"] == pytest.approx(2.0)
     units = {x["name"]: x for x in manifest["per_layer"]}
     for reader in READERS:
         entry = units[reader.__name__.split(".")[-1]]

@@ -15,6 +15,7 @@ import pytest
 from benchmark import end_to_end, harness, manifest as mf
 from benchmark.families import qwen3_next
 from benchmark.launchers.none import OneProcess
+from drawn_setup import child_marks, drawn_setup
 from benchmark.layer_metrics import (gattn_core_ms, gattn_core_roofline_pct,
                                      gdn_core_ms, gdn_core_roofline_pct,
                                      gdn_mix_ms, moe_held_ms)
@@ -72,13 +73,15 @@ def test_the_manifest_with_the_sixth_cell_is_sound():
     assert [m["name"] for m in mine] == [
         "gdn_core_ms", "gdn_core_roofline_pct", "gdn_mix_ms", "gattn_core_ms",
         "gattn_core_roofline_pct", "moe_held_ms"]
-    assert manifest["per_layer"][-6:] == mine
+    at = manifest["per_layer"].index(mine[0])
+    assert manifest["per_layer"][at:at + 6] == mine  # the six it was added with
     assert {m["source"] for m in mine} == {"device_trace"}
     assert {m["moves"] for m in mine} == {"step_ms_p50"}
     assert {m["layer"] for m in mine} == {"Kernels", "Model"}
-    # no accepted metric's list of cells was touched
-    for m in manifest["per_layer"][:-6]:
-        assert CELL not in m.get("workloads", []), m["name"]
+    # the lists this cell joined later (PR 38): head and optimizer
+    assert [m["name"] for m in manifest["per_layer"]
+            if CELL in m.get("workloads", []) and m not in mine] == [
+        "optimizer_ms", "head_loss_ms"]
 
 
 def test_the_configuration_is_the_catalogs_but_for_its_cut():
@@ -316,7 +319,8 @@ def test_measure_at_tiny_size_on_two_cpu_devices(events):
     mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
     record = harness.measure(cell, mesh, OneProcess(), {"bf16_flops": 197e12},
                              seed=2**31 + 7, seconds=0.3, trace_dir=None,
-                             events=events, t_command=time.time())
+                             events=events, t_command=time.time(),
+                             marks=child_marks())
     assert record["checks"]["no_compile_in_window"], record["window"]["compiles"]
     assert record["checks"]["loss_fell"], (record["losses_before"],
                                            record["window"]["losses"][-8:])
@@ -341,14 +345,14 @@ MS = 2_000_000  # a unit of the drawing below, in ns: 2 ms
 #   shared.fwd [23, 24)  norm [24, 24.5) (under `moe` alone)  head [24.5, 28)
 #   attn.core.bwd [28, 38)  gdn.again [38, 45) (the rule's forward, run again
 #   in the backward pass)  gdn.bwd [45, 59)  gdn.proj.bwd [59, 67)
-#   gmm.bwd [67, 71)  shared.bwd [71, 73)
+#   gmm.bwd [67, 71)  shared.bwd [71, 73)  adamw [73, 76) (under `optimizer`)
 STEP_OPS = [("gdn.proj", 0, 4), ("gdn.conv", 4, 5), ("gdn.local", 5, 8),
             ("gdn.scan", 8, 12), ("gdn.norm", 12, 13), ("attn.proj", 13, 15),
             ("attn.core.fwd", 15, 19), ("attn.gate", 19, 19.5),
             ("router", 19.5, 21), ("gmm.fwd", 21, 23), ("shared.fwd", 23, 24),
             ("norm", 24, 24.5), ("head", 24.5, 28), ("attn.core.bwd", 28, 38),
             ("gdn.again", 38, 45), ("gdn.bwd", 45, 59), ("gdn.proj.bwd", 59, 67),
-            ("gmm.bwd", 67, 71), ("shared.bwd", 71, 73)]
+            ("gmm.bwd", 67, 71), ("shared.bwd", 71, 73), ("adamw", 73, 76)]
 DRAWN = {
     "chips": [{"plane": "/device:TPU:0", "program": "jit_step",
                "steps": [[0, 80 * MS], [80 * MS, 160 * MS]],
@@ -379,6 +383,7 @@ SCOPES = {
     "shared.bwd": f"{BWD}/moe/moe_shared/dot_general",
     "norm": f"{FWD}/moe/checkpoint/rsqrt",
     "head": "jit(step)/shard_map/jvp(head_loss)/dot_general",
+    "adamw": "jit(step)/shard_map/optimizer/optimizer_update/add",
 }
 
 
@@ -452,8 +457,7 @@ def test_a_program_without_the_scope_reads_nothing_run(reader):
 
 def test_the_traced_line_holds_exactly_the_cells_metrics():
     manifest = mf.load()
-    record = {**_record(), "traced": True, "t_command": 0.0, "t_world": 1.0,
-              "first_step_s": 1.0, "chips": 1,
+    record = {**_record(), "traced": True, **drawn_setup(), "chips": 1,
               "window": {"compiles": 0, "t_done": [1.0, 1.4, 1.8, 2.2],
                          "spans": [["bench.input", 1.0, 1.001]]},
               "program_memory": {"total_bytes": 15_400_000_000},
@@ -465,4 +469,7 @@ def test_the_traced_line_holds_exactly_the_cells_metrics():
     assert {"gdn_core_ms", "gdn_core_roofline_pct", "gdn_mix_ms", "gattn_core_ms",
             "gattn_core_roofline_pct", "moe_held_ms"} <= mine
     assert not {"full_core_ms", "moe_share_ms", "moe_ms", "flash_core_ms"} & mine
+    # head and optimizer are read here since PR 38
+    assert line["metrics"]["head_loss_ms"]["value"] == pytest.approx(2 * 3.5)
+    assert line["metrics"]["optimizer_ms"]["value"] == pytest.approx(2 * 3.0)
     assert mf.check_result_line(line, manifest, CELL, traced=True) == []

@@ -11,6 +11,7 @@ import os
 import pytest
 
 from benchmark import end_to_end, manifest as mf, trace_reduce as tr
+from drawn_setup import drawn_setup
 from benchmark.layer_metrics import (allreduce_exposed_ms, allreduce_ms,
                                      attention_core_ms, bwd_ms,
                                      device_idle_pct, device_step_ms, fwd_ms,
@@ -265,7 +266,7 @@ def test_drawn_parts_and_the_exposed_all_reduce_make_up_the_step():
 def _record(workload="bert_base.ssgd_1chip"):
     return {"workload": workload, "traced": True,
             "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
-            "t_command": 0.0, "t_world": 1.0, "first_step_s": 1.0,
+            **drawn_setup(kfrun="kfrun" in workload),
             "chips": 1, "samples_per_step": 16,
             "window": {"compiles": 0, "t_done": [1.0, 1.1, 1.2, 1.3],
                        "spans": [["bench.input", 1.0, 1.001]]},
