@@ -27,7 +27,6 @@ and is no benchmark figure.
 
 from __future__ import annotations
 
-import collections
 import hashlib
 import json
 import os
@@ -60,10 +59,6 @@ KERNEL_TOL = 2e-2
 KERNEL_SEQ = 2048
 KERNEL_HEAD_DIMS = (64, 128)
 
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
-
 
 class SmokeFailure(RuntimeError):
     """A check of the smoke did not hold."""
@@ -75,27 +70,6 @@ def _check(ok: bool, what: str) -> None:
 
 
 # -- phase bodies (run in children; import jax lazily) ---------------------
-
-_events = None
-
-
-def _jax_events() -> collections.Counter:
-    """Count jax.monitoring events by name, from the first call on. Every
-    compile request — XLA compile or persistent-cache load — raises one
-    COMPILE_EVENT, so a difference of two readings is a count of
-    compilations, not an inference from step times."""
-    global _events
-    if _events is None:
-        from jax import monitoring
-
-        _events = collections.Counter()
-        monitoring.register_event_listener(
-            lambda event, **kw: _events.update([event])
-        )
-        monitoring.register_event_duration_secs_listener(
-            lambda event, duration, **kw: _events.update([event])
-        )
-    return _events
 
 
 def _device_report() -> dict:
@@ -143,19 +117,25 @@ def _loss_fn(cfg):
 def _run_steps(step, params, opt_state, batch, n_steps: int,
                devices) -> dict:
     """One compiling step and n_steps more; every step closed by
-    block_until_ready, compilations counted per step, `devices`' memory
-    read while the parameters and optimizer state are still alive."""
+    block_until_ready, compile requests (an XLA compile or a load from the
+    persistent cache each) counted per step by the program's own counter,
+    which `enable_compile_cache()` started, `devices`' memory read while
+    the parameters and optimizer state are still alive."""
     import jax
 
-    events = _jax_events()
+    from kungfu_tpu.telemetry import device
+
+    def requests() -> int:
+        return sum(device.compile_requests().values())
+
     losses, seconds, compiles = [], [], []
     for _ in range(n_steps + 1):
-        c0 = events[COMPILE_EVENT]
+        c0 = requests()
         t0 = time.perf_counter()
         params, opt_state, loss = step(params, opt_state, batch)
         jax.block_until_ready((params, opt_state, loss))
         seconds.append(time.perf_counter() - t0)
-        compiles.append(events[COMPILE_EVENT] - c0)
+        compiles.append(requests() - c0)
         losses.append(float(loss))
     _check(all(l == l and abs(l) != float("inf") for l in losses),
            f"non-finite loss: {losses}")
@@ -163,6 +143,7 @@ def _run_steps(step, params, opt_state, batch, n_steps: int,
            f"loss did not fall on the fixed batch: {losses}")
     _check(sum(compiles[1:]) == 0,
            f"compilations after the first step: {compiles}")
+    by_cache = device.compile_requests()
     return {
         "params": params,
         "losses": [round(l, 6) for l in losses],
@@ -170,8 +151,8 @@ def _run_steps(step, params, opt_state, batch, n_steps: int,
         "step_s": [round(s, 4) for s in seconds[1:]],
         "compiles_first_step": compiles[0],
         "compiles_after_first_step": sum(compiles[1:]),
-        "cache_hits": events[CACHE_HIT_EVENT],
-        "cache_misses": events[CACHE_MISS_EVENT],
+        "cache_hits": by_cache["hit"],
+        "cache_misses": by_cache["miss"],
         **_memory(devices),
     }
 

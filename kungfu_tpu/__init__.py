@@ -16,6 +16,10 @@ data-parallel training) re-designed for TPU hardware:
 Reference capability map: see SURVEY.md at the repo root.
 """
 
+import time as _time
+
+_import_began = _time.perf_counter()
+
 __version__ = "0.1.0"
 
 from kungfu_tpu import knobs as _knobs
@@ -42,11 +46,10 @@ __all__ = [
 ]
 
 
-def __getattr__(name):
-    # lazy (PEP 562): kungfu_tpu.telemetry without paying for it on
-    # import paths that never touch it
-    if name == "telemetry":
-        import kungfu_tpu.telemetry as telemetry
+# what a restarted worker pays first: this body as a span of the ring
+# (`kungfu_tpu.parallel`, which brings jax and optax, records its own)
+from kungfu_tpu.telemetry import tracing
 
-        return telemetry
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+tracing.record("worker.import", _time.perf_counter() - _import_began,
+               module=__name__)
+del tracing

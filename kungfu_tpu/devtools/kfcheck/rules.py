@@ -933,6 +933,11 @@ _SPAN_INDIRECT = frozenset({
     # walks.timed_step forwards its span_name parameter to trace.span
     "host.rs.step",
     "host.ag.step",
+    # the collector's hook appends its event itself: it may take no lock
+    "worker.gc",
+    # telemetry.device's compile watch names a stage's span by the stage
+    "device_plane.compile.trace",
+    "device_plane.compile.lower",
 })
 
 _SPAN_TABLE_HEADING = "## Span table"

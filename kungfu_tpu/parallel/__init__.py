@@ -1,3 +1,7 @@
+import time as _time
+
+_import_began = _time.perf_counter()
+
 from kungfu_tpu.parallel.mesh import DeviceSession, make_mesh
 from kungfu_tpu.parallel.dp import make_train_step
 from kungfu_tpu.parallel.pipeline import make_pp_transformer_loss
@@ -18,3 +22,9 @@ __all__ = [
     "shutdown_device_plane",
     "device_plane_initialized",
 ]
+
+from kungfu_tpu.telemetry import tracing
+
+tracing.record("worker.import", _time.perf_counter() - _import_began,
+               module=__name__)
+del tracing

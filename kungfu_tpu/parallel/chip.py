@@ -13,6 +13,8 @@ import os
 
 import jax
 
+from kungfu_tpu.telemetry import device, tracing
+
 
 @dataclasses.dataclass(frozen=True)
 class ChipSpec:
@@ -65,7 +67,13 @@ def enable_compile_cache() -> str:
     the entries. Reload-mode resizes restart every worker; this is what
     lets the restarted workers load their programs instead of compiling
     them again.
+
+    Whether they did is watched from here on, once a process (every
+    launcher comes through here before its first compile): each compile
+    request with the cache's answer, and the collector's pauses, in the ring.
     """
+    device.watch_compiles()
+    tracing.watch_gc()
     # by default the key leaves metadata out, so a program cached before a
     # `jax.named_scope` changed is loaded with its old `op_name`s and a
     # profile shows those (chip run, PR 24)

@@ -21,8 +21,13 @@ annotations (`host_tracer_level = 1`). That level still records PJRT's
 per-batch `Transpose` calls: free for a step that moves kilobytes, 9 % of
 a ResNet step that moves 38.5 MB a batch (chip run, PERF.md, PR 24).
 
-Imported by nothing at start-up: the runner and `tracing` itself import no
-jax.
+`watch_compiles()` also needs jax: JAX's own account of every compile
+request (`jax.monitoring`) as spans of the ring and counters, so that
+`/trace` says which function compiled and whether the cache served it.
+
+`parallel/chip.py` imports this module, so a worker has it from its start;
+jax itself is imported only inside the functions that need it, and the
+runner and `tracing` import neither.
 """
 
 from __future__ import annotations
@@ -34,9 +39,10 @@ import glob
 import os
 import re
 import statistics
+import threading
 from typing import Dict, Iterator, List, Tuple
 
-from kungfu_tpu.telemetry import tracing
+from kungfu_tpu.telemetry import metrics, tracing
 
 PHASES = ("forward", "backward", "optimizer", "all_reduce", "unattributed")
 
@@ -67,6 +73,106 @@ def profile(log_dir: str) -> Iterator[str]:
     finally:
         tracing._mirror = None
         jax.profiler.stop_trace()
+
+
+# JAX raises each of these through `dispatch.log_elapsed_time`: a scalar on
+# entry, a duration and a time span on exit, all with `fun_name`
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+CACHE_SAID = ("hit", "miss", "off")  # the `cache` of a compile request
+
+
+class _CompileWatch(threading.local):
+    """What a thread's compile requests are in the middle of, and the three
+    `jax.monitoring` listeners that keep it. A compile request (`backend`:
+    an XLA compile or a load from the persistent cache) is a span each,
+    with the cache's answer: inside it JAX raises
+    `compile_requests_use_cache` where it asks the cache and `cache_hits`
+    where that served it (`cache_misses` only where it also writes the
+    entry, which only process 0 of a world does). Tracing and lowering nest,
+    in themselves and in each other (2,471 trace events in ResNet's first
+    step, PERF.md; a lowering rule may trace), so only the outermost of a
+    thread is a span, with the count of those folded into it, or one model
+    floods the ring. The counters take every event, as JAX sums them. The
+    listeners run inside JAX's compile path: they raise nothing."""
+
+    def __init__(self):
+        self.depth = 0  # trace and lower events this thread is inside
+        self.nested = 0  # those that ended inside the outermost one
+        self.cache = "off"
+        requests = metrics.counter(
+            "kungfu_compile_requests_total",
+            "Compile requests by the persistent cache's answer", ("cache",))
+        seconds = metrics.counter(
+            "kungfu_compile_seconds_total",
+            "Seconds in JAX's compile stages, nested events too", ("stage",))
+        # every series from the start: a request count of 0 is information
+        self.requests = {c: requests.labels(c) for c in CACHE_SAID}
+        self.seconds = {s: seconds.labels(s) for s in _STAGES.values()}
+
+    def entered(self, event: str, value, **kw) -> None:
+        stage = _STAGES.get(event)
+        if stage == "backend":
+            self.cache = "off"
+        elif stage is not None:
+            self.depth += 1
+
+    def cache_said(self, event: str, **kw) -> None:
+        if event == _CACHE_ASKED:
+            self.cache = "miss"
+        elif event == _CACHE_HIT:
+            self.cache = "hit"
+
+    def left(self, event: str, start: float, end: float, fun_name: str = "",
+             **kw) -> None:
+        stage = _STAGES.get(event)
+        if stage is None:
+            return
+        took = max(0.0, end - start)  # the wall clock may step
+        self.seconds[stage].inc(took)
+        if stage == "backend":
+            self.requests[self.cache].inc()
+            tracing.record("device_plane.compile.backend", took,
+                           fun_name=fun_name, cache=self.cache)
+            return
+        # an exit with no entry: the watch began inside it
+        self.depth = max(0, self.depth - 1)
+        if self.depth:
+            self.nested += 1
+            return
+        tracing.record("device_plane.compile." + stage, took,
+                       fun_name=fun_name, nested=self.nested)
+        self.nested = 0
+
+
+_compile_watch = None
+
+
+def watch_compiles() -> None:
+    """Every compile request of this process into the ring and the registry
+    from now on (the spans and counters of `_CompileWatch`). Once a process,
+    however often it is called; call it before the first compile. The one
+    place in `kungfu_tpu/` that registers `jax.monitoring` listeners."""
+    global _compile_watch
+    if _compile_watch is not None:
+        return
+    from jax import monitoring
+
+    watch = _compile_watch = _CompileWatch()
+    monitoring.register_scalar_listener(watch.entered)
+    monitoring.register_event_listener(watch.cache_said)
+    monitoring.register_event_time_span_listener(watch.left)
+
+
+def compile_requests() -> Dict[str, int]:
+    """{"hit" | "miss" | "off": compile requests since `watch_compiles()`}."""
+    watch = _compile_watch
+    return {c: int(watch.requests[c].value) if watch else 0 for c in CACHE_SAID}
 
 
 def find_xplane(log_dir: str) -> str:

@@ -26,6 +26,8 @@ import time
 from bisect import bisect_left
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+from kungfu_tpu.telemetry import tracing
+
 # latency-flavoured default buckets: 100us .. 60s
 DEFAULT_BUCKETS = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
@@ -527,6 +529,7 @@ def render() -> str:
 # plane the trend line. Sampled on demand (every /metrics scrape and
 # every flight snapshot), not on a timer of their own.
 
+_gc_copy_lock = threading.Lock()
 _PROC_START = time.time()
 _PAGE_SIZE = (
     os.sysconf("SC_PAGE_SIZE") if hasattr(os, "sysconf") else 4096
@@ -585,4 +588,20 @@ def update_process_health(registry: Optional[Registry] = None) -> Dict[str, floa
         "Seconds since this process imported the metrics registry",
     ).set(uptime)
     out["uptime_seconds"] = uptime
+    # the collector's hook may take no lock of the registry's, so its totals
+    # are brought up to date here; a scrape and a flight snapshot may come at
+    # once, and reading a counter and adding the difference is one step
+    with _gc_copy_lock:
+        watched = tracing.gc_totals()
+        if watched is not None:
+            collections, pause_s = watched
+            by_generation = reg.counter(
+                "kungfu_gc_collections_total",
+                "Collections of Python's collector, watched", ("generation",))
+            for generation, n in enumerate(collections):
+                child = by_generation.labels(generation)
+                child.inc(max(0.0, n - child.value))
+            paused = reg.counter("kungfu_gc_pause_seconds_total",
+                                 "Seconds this process stood still in them")
+            paused.inc(max(0.0, pause_s - paused.value))
     return out

@@ -20,6 +20,7 @@ follows the standard Chrome trace-event format.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -215,13 +216,6 @@ def instant(name: str, **args) -> None:
     )
 
 
-def events(prefix: str = "") -> List[Tuple[str, float, float]]:
-    """(name, start, duration) tuples — the old scoped tracer's shape."""
-    return [
-        (e.name, e.start, e.duration) for e in full_events(prefix)
-    ]
-
-
 def full_events(prefix: str = "") -> List[TraceEvent]:
     with _lock:
         evs = list(_events)
@@ -233,6 +227,68 @@ def full_events(prefix: str = "") -> List[TraceEvent]:
 def clear() -> None:
     with _lock:
         _events.clear()
+
+
+# -- the collector's pauses: a pause of the host that is Python's own shows in
+# the ring beside what it interrupted; one that is not there is the machine's
+GC_SPAN_MIN_S = 1e-3  # a shorter collection of a young generation is counted only
+
+
+class _GcWatch:
+    """A `gc.callbacks` hook: every collection is counted with its seconds,
+    and one that took GC_SPAN_MIN_S or was of the oldest generation is a
+    `worker.gc` span. The collector runs wherever the interpreter checks
+    for it, also in a thread that holds `_lock` or a metric family's, so
+    the hook takes no lock: it appends to the deque itself, which is
+    atomic, and keeps plain numbers that `metrics.update_process_health`
+    copies into the registry. Collections do not overlap."""
+
+    __slots__ = ("t0", "collections", "pause_s")
+
+    def __init__(self):
+        self.t0 = 0.0
+        self.collections = [0, 0, 0]
+        self.pause_s = 0.0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self.t0 = time.perf_counter()
+            return
+        if not self.t0:  # installed in the middle of this collection
+            return
+        dt = time.perf_counter() - self.t0
+        generation = info["generation"]
+        self.collections[generation] += 1
+        self.pause_s += dt
+        if dt >= GC_SPAN_MIN_S or generation == 2:
+            _events.append(TraceEvent(
+                "worker.gc", self.t0, dt, threading.get_ident(),
+                len(getattr(_tls, "stack", ())), "X",
+                _step_args({"generation": generation,
+                            "collected": info["collected"]}),
+            ))
+        self.t0 = 0.0
+
+
+_gc_watch: Optional[_GcWatch] = None
+
+
+def watch_gc() -> None:
+    """The collector's pauses into the ring from now on; once a process. A
+    hook taken out of `gc.callbacks` (a test's) comes back with its totals."""
+    global _gc_watch
+    with _lock:
+        if _gc_watch is None:
+            _gc_watch = _GcWatch()
+        if _gc_watch not in gc.callbacks:
+            gc.callbacks.append(_gc_watch)
+
+
+def gc_totals() -> Optional[Tuple[Tuple[int, ...], float]]:
+    """(collections by generation, seconds in all of them) since
+    `watch_gc()`; None where nobody called it."""
+    w = _gc_watch
+    return None if w is None else (tuple(w.collections), w.pause_s)
 
 
 def summary_ms(prefix: str = "") -> Dict[str, float]:
@@ -283,10 +339,3 @@ def chrome_trace(prefix: str = "") -> dict:
 
 def chrome_trace_json(prefix: str = "") -> str:
     return json.dumps(chrome_trace(prefix))
-
-
-def export_chrome(path: str, prefix: str = "") -> str:
-    """Write the Chrome trace JSON to `path`; returns the path."""
-    with open(path, "w") as f:
-        f.write(chrome_trace_json(prefix))
-    return path

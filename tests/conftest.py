@@ -9,9 +9,11 @@ Note: a pytest plugin imports jax before this file runs, so plain env vars
 are too late; jax.config.update works until the backend is initialized.
 """
 
+import gc
 import os
 
 import jax
+import pytest
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
@@ -49,3 +51,37 @@ def _build_native_once() -> None:
 
 
 _build_native_once()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _the_ring_starts_empty():
+    """The span ring is the process's, and an xdist worker runs many files
+    in one process: a file reads what its own tests recorded, not another
+    file's spans nor the worker's own `worker.import`, recorded when the
+    first file was collected, minutes before."""
+    from kungfu_tpu.telemetry import tracing
+
+    tracing.clear()
+
+
+@pytest.fixture(scope="module")
+def runtime_watchers():
+    """The compile and collector watchers (ISSUE 39) for one file's tests,
+    installed by their own functions (`enable_compile_cache()` would point
+    the whole test process at the checkout's cache) and taken out after
+    them: the hook and the listeners are the process's too, and the files
+    this xdist worker runs next would find `worker.gc` and
+    `device_plane.compile.*` spans in their rings and pay for the hook in
+    every collection. The totals and the registry's counters stay."""
+    from jax import monitoring
+
+    from kungfu_tpu.telemetry import device, tracing
+
+    device.watch_compiles()
+    tracing.watch_gc()
+    yield
+    watch, device._compile_watch = device._compile_watch, None
+    monitoring.unregister_scalar_listener(watch.entered)
+    monitoring.unregister_event_listener(watch.cache_said)
+    monitoring.unregister_event_time_span_listener(watch.left)
+    gc.callbacks.remove(tracing._gc_watch)

@@ -266,7 +266,7 @@ def test_fused_group_all_reduce_two_peers():
         # (VERDICT r4 5.1 — a tracer nothing traces with is shelf-ware)
         from kungfu_tpu.telemetry import tracing as trace
 
-        names = {n for n, _, _ in trace.events()}
+        names = {e.name for e in trace.full_events()}
         assert "transport.send" in names
         assert any(n.startswith("host.walk") for n in names)
     finally:

@@ -102,8 +102,8 @@ def test_tracer_spans():
     with trace.span("t.a"):
         time.sleep(0.01)
     trace.record("t.b", 0.5)
-    evs = trace.events("t.")
-    assert [e[0] for e in evs] == ["t.a", "t.b"]
+    evs = trace.full_events("t.")
+    assert [e.name for e in evs] == ["t.a", "t.b"]
     s = trace.summary_ms("t.")
     assert s["t.a"] >= 10.0
     assert s["t.b"] == 500.0

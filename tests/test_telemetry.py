@@ -201,25 +201,27 @@ class TestTracing:
             if e["ph"] == "X":
                 assert "dur" in e
 
-    def test_export_chrome_writes_loadable_file(self, tmp_path):
+    def test_chrome_trace_json_writes_a_loadable_file(self, tmp_path):
         tracing.clear()
         with tracing.span("t_io"):
             pass
-        path = tracing.export_chrome(str(tmp_path / "trace.json"), "t_")
+        path = tmp_path / "trace.json"
+        path.write_text(tracing.chrome_trace_json("t_"))
         with open(path) as f:
             doc = json.load(f)
         assert any(e["name"] == "t_io" for e in doc["traceEvents"])
 
     def test_legacy_shim_api(self):
-        """The call shapes of the old scoped tracer (record, events,
-        summary_ms), under the one name its call sites import now."""
+        """The call shapes of the old scoped tracer (record, summary_ms)
+        and the ring's own reading, under the one name its call sites
+        import now."""
         from kungfu_tpu.telemetry import tracing as shim
 
         shim.clear()
         shim.record("t_legacy", 0.25)
         with shim.span("t_scoped"):
             pass
-        names = [n for n, _, _ in shim.events("t_")]
+        names = [e.name for e in shim.full_events("t_")]
         assert "t_legacy" in names and "t_scoped" in names
         assert shim.summary_ms("t_legacy")["t_legacy"] == pytest.approx(250.0)
         assert any(
