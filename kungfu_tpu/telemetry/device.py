@@ -94,7 +94,11 @@ class _CompileWatch(threading.local):
     with the cache's answer: inside it JAX raises
     `compile_requests_use_cache` where it asks the cache and `cache_hits`
     where that served it (`cache_misses` only where it also writes the
-    entry, which only process 0 of a world does). Tracing and lowering nest,
+    entry: JAX itself writes from process 0 of a world alone, and any other
+    process writes the programs that are its own through the seam of
+    `parallel/chip.enable_compile_cache()`, which says so here by
+    `wrote_as_peer()`: `written: "peer"` on the request's span and
+    `kungfu_compile_cache_peer_writes_total`). Tracing and lowering nest,
     in themselves and in each other (2,471 trace events in ResNet's first
     step, PERF.md; a lowering rule may trace), so only the outermost of a
     thread is a span, with the count of those folded into it, or one model
@@ -105,6 +109,7 @@ class _CompileWatch(threading.local):
         self.depth = 0  # trace and lower events this thread is inside
         self.nested = 0  # those that ended inside the outermost one
         self.cache = "off"
+        self.written = ""  # "peer" where this process wrote the entry as one
         requests = metrics.counter(
             "kungfu_compile_requests_total",
             "Compile requests by the persistent cache's answer", ("cache",))
@@ -114,11 +119,15 @@ class _CompileWatch(threading.local):
         # every series from the start: a request count of 0 is information
         self.requests = {c: requests.labels(c) for c in CACHE_SAID}
         self.seconds = {s: seconds.labels(s) for s in _STAGES.values()}
+        self.peer_writes = metrics.counter(
+            "kungfu_compile_cache_peer_writes_total",
+            "Cache entries this process wrote though it is not process 0 "
+            "of its world: programs of its own devices alone")
 
     def entered(self, event: str, value, **kw) -> None:
         stage = _STAGES.get(event)
         if stage == "backend":
-            self.cache = "off"
+            self.cache, self.written = "off", ""
         elif stage is not None:
             self.depth += 1
 
@@ -137,8 +146,9 @@ class _CompileWatch(threading.local):
         self.seconds[stage].inc(took)
         if stage == "backend":
             self.requests[self.cache].inc()
+            written = {"written": self.written} if self.written else {}
             tracing.record("device_plane.compile.backend", took,
-                           fun_name=fun_name, cache=self.cache)
+                           fun_name=fun_name, cache=self.cache, **written)
             return
         # an exit with no entry: the watch began inside it
         self.depth = max(0, self.depth - 1)
@@ -167,6 +177,15 @@ def watch_compiles() -> None:
     monitoring.register_scalar_listener(watch.entered)
     monitoring.register_event_listener(watch.cache_said)
     monitoring.register_event_time_span_listener(watch.left)
+
+
+def wrote_as_peer() -> None:
+    """The compile request this thread is inside missed, and this process,
+    not process 0 of its world, wrote the entry (`parallel/chip.py`)."""
+    watch = _compile_watch
+    if watch is not None:
+        watch.written = "peer"
+        watch.peer_writes.inc()
 
 
 def compile_requests() -> Dict[str, int]:

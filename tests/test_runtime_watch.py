@@ -2,7 +2,8 @@
 requests (`telemetry.device.watch_compiles`), the collector's pauses
 (`tracing.watch_gc`) and the package's imports (`worker.import`), as spans
 of the one ring and counters of the registry. On the CPU; the persistent
-cache's answers in subprocesses with a scratch cache directory."""
+cache's answers in subprocesses with a scratch cache directory, and a peer's
+own writes to it (ISSUE 40)."""
 
 import gc
 import json
@@ -123,11 +124,17 @@ print("REQUESTS " + json.dumps(device.compile_requests()))
 """
 
 
+def _child_env(cache_dir, **more):
+    """The cache on (`tests/conftest.py` switches it off for every child
+    through the environment) and in a scratch directory."""
+    return dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+                JAX_COMPILATION_CACHE_DIR=str(cache_dir),
+                JAX_ENABLE_COMPILATION_CACHE="true", **more)
+
+
 def _compile_in_a_child(cache_dir, process_id):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
-               JAX_COMPILATION_CACHE_DIR=str(cache_dir),
-               JAX_ENABLE_COMPILATION_CACHE="true")
-    r = subprocess.run([sys.executable, "-c", CHILD, str(process_id)], env=env,
+    r = subprocess.run([sys.executable, "-c", CHILD, str(process_id)],
+                       env=_child_env(cache_dir),
                        capture_output=True, text=True, timeout=120, cwd="/tmp")
     assert r.returncode == 0, r.stderr[-2000:]
     out = {line.split(" ", 1)[0]: json.loads(line.split(" ", 1)[1])
@@ -151,6 +158,158 @@ def test_the_caches_answer_is_on_the_request(tmp_path, process_id, second):
     assert cache == "miss" and requests["miss"] >= 1 and requests["off"] == 0
     cache, requests = _compile_in_a_child(tmp_path, process_id)
     assert cache == second and requests[second] >= 1
+
+
+# A worker as `enable_compile_cache()` leaves it (ISSUE 40): `process_id`
+# as `jax.distributed.initialize` would have set it, one jitted function.
+PEER = """
+import json, os, sys, time
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from jax._src import compilation_cache, compiler, distributed
+distributed.global_state.process_id = int(sys.argv[1])
+what = sys.argv[2]
+if what == "a_name_is_gone":  # one that JAX itself calls in process 0 alone
+    del compilation_cache.put_executable_and_time
+if what == "other_arguments":
+    theirs = compiler._compile_and_write_cache
+    compiler._compile_and_write_cache = lambda *renamed: theirs(*renamed)
+if what == "spans_processes":
+    # a program with a device of another process in it, as the seam sees one:
+    # JAX's own function compiles for the devices there are
+    class Devices:
+        is_fully_addressable = False
+        def __init__(self, mine): self.mine = mine
+    jax_writes = compiler._compile_and_write_cache
+    compiler._compile_and_write_cache = (
+        lambda backend, computation, executable_devices, compile_options,
+        host_callbacks, module_name, cache_key: jax_writes(
+            backend, computation, getattr(executable_devices, "mine", executable_devices),
+            compile_options, host_callbacks, module_name, cache_key))
+from kungfu_tpu.parallel import chip
+from kungfu_tpu.telemetry import device, metrics, tracing
+chip.enable_compile_cache()
+chip.enable_compile_cache()
+if what == "spans_processes":
+    peers_write = compiler._compile_and_write_cache
+    compiler._compile_and_write_cache = (
+        lambda backend, computation, executable_devices, *rest: peers_write(
+            backend, computation, Devices(executable_devices), *rest))
+
+@jax.jit
+def outer(x):
+    if what == "host_callback":
+        jax.debug.print("x {}", x)
+    return x * 2 + 1
+
+x = jnp.ones(3)
+if len(sys.argv) > 3:
+    print("READY", flush=True)
+    while not os.path.exists(sys.argv[3]):
+        time.sleep(0.005)
+jax.block_until_ready(outer(x))
+print("SPANS " + json.dumps([e.args for e in tracing.full_events("device_plane.compile.backend")
+                             if e.args["fun_name"] == "jit(outer)"]))
+print("PEER_WRITES " + json.dumps(
+    metrics.get_registry().get("kungfu_compile_cache_peer_writes_total").value))
+"""
+
+
+def _peer_argv(process_id, what, *more):
+    return [sys.executable, "-c", PEER, str(process_id), what, *more]
+
+
+def _peer_said(stdout):
+    out = {line.split(" ", 1)[0]: json.loads(line.split(" ", 1)[1])
+           for line in stdout.splitlines() if line.startswith(("SPANS", "PEER_WRITES"))}
+    (request,) = out["SPANS"]
+    return request, out["PEER_WRITES"]
+
+
+def _a_worker_compiles(cache_dir, process_id, what="plain", **env):
+    """((the request's span args, the peer-writes counter), stderr) of one
+    child that compiles `outer` through `enable_compile_cache()`."""
+    r = subprocess.run(_peer_argv(process_id, what), env=_child_env(cache_dir, **env),
+                       capture_output=True, text=True, timeout=120, cwd="/tmp")
+    assert r.returncode == 0, r.stderr[-2000:]
+    return _peer_said(r.stdout), r.stderr
+
+
+def _entries(cache_dir):
+    return sorted(f for f in os.listdir(cache_dir) if f.startswith("jit_outer"))
+
+
+@pytest.mark.parametrize("eviction", [{}, {"JAX_COMPILATION_CACHE_MAX_SIZE": "100000000"}],
+                         ids=["renamed_into_place", "under_the_caches_lock"])
+def test_a_peer_writes_its_own_program_once_and_loads_it_after(tmp_path, eviction):
+    """The kfrun cell's ranks 1 to 3 since ISSUE 40: the first start
+    compiles and writes, every later one is served."""
+    (request, writes), _ = _a_worker_compiles(tmp_path, 1, **eviction)
+    assert request == {"fun_name": "jit(outer)", "cache": "miss", "written": "peer"}
+    assert writes >= 1
+    (entry,) = [f for f in _entries(tmp_path) if f.endswith("-cache")]
+    assert ".peer" not in entry
+    (request, writes), _ = _a_worker_compiles(tmp_path, 1, **eviction)
+    assert request == {"fun_name": "jit(outer)", "cache": "hit"}
+    assert writes == 0
+
+
+def test_process_0_writes_as_jax_has_it_and_is_no_peer(tmp_path):
+    (request, writes), _ = _a_worker_compiles(tmp_path, 0)
+    assert request == {"fun_name": "jit(outer)", "cache": "miss"} and writes == 0
+    (request, writes), _ = _a_worker_compiles(tmp_path, 0)
+    assert request["cache"] == "hit" and writes == 0
+
+
+@pytest.mark.parametrize("what", ["host_callback", "spans_processes"])
+def test_what_is_not_a_peers_alone_is_not_a_peers_to_write(tmp_path, what):
+    """JAX's own rule for a program with a host callback is kept, and a
+    program with a device of another process in it stays process 0's."""
+    for _ in range(2):
+        (request, _), _ = _a_worker_compiles(tmp_path, 1, what)
+        assert request == {"fun_name": "jit(outer)", "cache": "miss"}
+        assert _entries(tmp_path) == []  # the eager `ones` beside it is written
+
+
+@pytest.mark.parametrize("what,named", [
+    ("a_name_is_gone", "put_executable_and_time"),
+    ("other_arguments", "_compile_and_write_cache takes ('renamed',)")])
+def test_without_jaxs_private_names_nothing_is_installed_and_one_warning_says_so(
+        tmp_path, what, named):
+    for _ in range(2):  # the second run misses as on the parent
+        (request, writes), stderr = _a_worker_compiles(tmp_path, 1, what)
+        assert request == {"fun_name": "jit(outer)", "cache": "miss"} and writes == 0
+        # `enable_compile_cache()` ran twice there
+        (said,) = [l for l in stderr.splitlines() if "compile cache:" in l]
+        assert named in said and " [W] " in said
+    assert _entries(tmp_path) == []
+
+
+def test_two_peers_writing_one_key_at_once_leave_an_entry_a_third_loads(tmp_path):
+    """Two workers with one process id (of two worlds that share a cache
+    directory; within one world the device assignment tells the keys apart,
+    on the CPU as on the TPU) compile one program under one key: each
+    writes under a name of its own and renames, so the key's file is whole."""
+    cache, go = tmp_path / "cache", tmp_path / "go"
+    cache.mkdir()
+    two = [subprocess.Popen(_peer_argv(1, "plain", str(go)), env=_child_env(cache),
+                            stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                            text=True, cwd="/tmp") for _ in range(2)]
+    try:
+        for child in two:
+            assert child.stdout.readline().strip() == "READY", child.stderr.read()[-2000:]
+        go.write_text("")
+        said = [_peer_said(child.communicate(timeout=120)[0]) for child in two]
+    finally:
+        for child in two:
+            child.kill()
+    assert all(child.returncode == 0 for child in two)
+    assert all(request["cache"] == "miss" for request, _ in said)
+    assert sum(writes for _, writes in said) >= 1
+    assert [f.endswith("-cache") for f in _entries(cache)] == [True]
+    (request, writes), _ = _a_worker_compiles(cache, 1)
+    assert request == {"fun_name": "jit(outer)", "cache": "hit"} and writes == 0
 
 
 def test_nested_events_fold_into_the_outermost_and_leave_the_ring_its_room():
