@@ -32,6 +32,18 @@ TPU-first design notes:
   `layer_kinds`. Norms with the scale 1 + w, q/k norms a head, a gate a
   feature from a q projection of twice the width and a sigmoid gate on the
   shared expert are each a field.
+- Latent attention is the third mixer (PR 41, DeepSeek-V2's MLA as
+  GLM-4.7-Flash has it): q through a normed latent, keys and values through
+  another, one rotary key shared by every head beside each head's unrotated
+  features (`latent_dims`, `_latent_attention`); k and v are laid out a head
+  for the core every other layer runs. The router's scores are a softmax
+  over the experts or a sigmoid an expert (`router_scores`), and a selection
+  bias an expert may move the choice and never the weight (`router_bias`,
+  `ops.moe.route`). A multi-token-prediction module (`mtp_depth` 1: two
+  norms, a (2D, D) projection, one further block, a final norm of its own)
+  predicts the token after the next on the shared embedding and head, and
+  `transformer_loss` is then main loss + `mtp_weight` x MTP loss from a
+  batch of S + 2 ids.
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -111,6 +123,19 @@ class TransformerConfig:
     # sigmoid(gate), one a feature, is on the core's output
     q_gate: bool = False
     shared_gate: bool = False  # sigmoid(h @ w_shared_gate (D, 1)) on the shared expert
+    # mixer "latent": (q latent rank, key/value latent rank, unrotated
+    # features a q/k head, rotated features a q/k head, features a value
+    # head); the rotated key is one for all heads, rotate-half at rope_theta
+    latent_dims: Tuple = ()
+    router_scores: str = "softmax"  # or "sigmoid": each expert's own score
+    # a leaf `router_bias` (n_experts,) added to the scores for the choice
+    # and not for the weight; the loss is constant in it
+    router_bias: bool = False
+    # multi-token prediction (DeepSeek-V3's, section 2.2): modules after the
+    # stack (0 or 1), each one further block of the last layer's kind, and
+    # the weight of their loss beside the main one
+    mtp_depth: int = 0
+    mtp_weight: float = 0.0
     # one tuple of (field, value) pairs a layer: what replaces the fields
     # above for that layer; (): every layer is the configuration's own
     layer_kinds: Tuple = ()
@@ -121,7 +146,8 @@ class TransformerConfig:
                 ("ffn", self.ffn, ("gelu", "swiglu", "moe")),
                 ("attn_core", self.attn_core, ("dense", "flash")),
                 ("gates", self.gates, ("raw", "renorm")),
-                ("mixer", self.mixer, ("attention", "gated_delta"))):
+                ("mixer", self.mixer, ("attention", "gated_delta", "latent")),
+                ("router_scores", self.router_scores, ("softmax", "sigmoid"))):
             if value not in known:
                 raise ValueError(f"{field} {value!r} is not one of {known}")
         if self.ffn == "moe" and not 1 <= self.top_k <= self.n_experts:
@@ -139,6 +165,27 @@ class TransformerConfig:
             raise ValueError("mixer 'gated_delta' needs delta_heads = (key "
                              "heads, value heads a multiple of them, head "
                              f"size), got {self.delta_heads}")
+        if self.mixer == "latent":
+            if not (len(self.latent_dims) == 5 and min(self.latent_dims) >= 1
+                    and self.latent_dims[3] % 2 == 0):
+                raise ValueError("mixer 'latent' needs latent_dims = (q rank, "
+                                 "key/value rank, unrotated, rotated (even), "
+                                 f"value features a head), got {self.latent_dims}")
+            if self.positions != "rope":
+                raise ValueError("mixer 'latent' turns its rotated features "
+                                 "by positions 'rope'")
+            _, _, nope, rope, value = self.latent_dims
+            if int((nope + rope) * (rope / (nope + rope))) != rope:
+                raise ValueError(
+                    f"{rope} rotated of {nope + rope} features is a share "
+                    "that the rotary pass (`_rope`) rounds down")
+            if self.attn_core == "flash" and nope + rope != value:
+                raise ValueError(
+                    f"the flash core has one head size: q/k heads of {nope} + "
+                    f"{rope} and value heads of {value} need the dense core")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth {self.mtp_depth}: one multi-token-"
+                             "prediction module or none")
         if self.shared_gate and not self.shared_ff:
             raise ValueError("shared_gate gates the shared expert (shared_ff)")
         if (self.window or self.kv_heads != self.n_heads) and self.attn_core != "flash":
@@ -182,6 +229,12 @@ class TransformerConfig:
         return tuple((dataclasses.replace(self, layer_kinds=(), n_layers=n,
                                           **dict(kind)), n)
                      for kind, n in runs)
+
+    @property
+    def mtp_kind(self) -> "TransformerConfig":
+        """The configuration of the multi-token-prediction module's block:
+        the last layer's kind, one layer of it."""
+        return dataclasses.replace(self.stacks[-1][0], n_layers=1)
 
     @classmethod
     def bert_base(cls) -> "TransformerConfig":
@@ -242,6 +295,8 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
             xk = jax.random.split(jax.random.fold_in(key, 1), 6)
         if cfg.mixer == "gated_delta" or cfg.shared_gate:
             gk = jax.random.split(jax.random.fold_in(key, 2), 6)
+        if cfg.mixer == "latent" or cfg.router_bias:  # PR 41's, a fourth
+            mk = jax.random.split(jax.random.fold_in(key, 3), 5)
         layer = {
             "ln1_scale": unit(cfg, (D,)),
             "ln2_scale": unit(cfg, (D,)),
@@ -268,6 +323,21 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
                 dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
                 gdn_norm_scale=jnp.ones((d,), jnp.float32),
                 wo=dense(lk[1], (Hv * d, D)))
+        elif cfg.mixer == "latent":
+            # the published layout: W_q_up's columns a head at a time, its
+            # unrotated features and then its rotated; W_kv_down's the
+            # latent and then the one rotated key; W_kv_up's a head at a
+            # time, its unrotated key features and then its value
+            rq, rkv, nope, rope, value = cfg.latent_dims
+            H = cfg.n_heads
+            layer.update(
+                w_q_down=dense(mk[0], (D, rq)),
+                q_latent_norm=unit(cfg, (rq,)),
+                w_q_up=dense(mk[1], (rq, H * (nope + rope))),
+                w_kv_down=dense(mk[2], (D, rkv + rope)),
+                kv_latent_norm=unit(cfg, (rkv,)),
+                w_kv_up=dense(mk[3], (rkv, H * (nope + value))),
+                wo=dense(lk[1], (H * value, D)))
         elif cfg.split_qkv:
             q_width, kv_width = (h * cfg.head_dim
                                  for h in (cfg.n_heads, cfg.kv_heads))
@@ -291,6 +361,12 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
             layer["w_down"] = dense(lk[4], stack + (F, D))
         if cfg.ffn == "moe":
             layer["router"] = dense(lk[5], (D, E))
+            if cfg.router_bias:
+                # no balancing step moves it here (`make_train_step` updates
+                # what gradients update): drawn small against the spread of
+                # the scores, so that choice and weight differ
+                layer["router_bias"] = 0.01 * jax.random.normal(
+                    mk[4], (E,), jnp.float32)
             if cfg.shared_ff:
                 layer["shared_gate"] = dense(xk[3], (D, cfg.shared_ff))
                 layer["shared_up"] = dense(xk[4], (D, cfg.shared_ff))
@@ -320,6 +396,15 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
     if not cfg.tied_head:
         params["lm_head"] = dense(jax.random.fold_in(keys[1], 1),
                                   (cfg.vocab_size, D))
+    if cfg.mtp_depth:
+        tk = jax.random.split(jax.random.fold_in(keys[1], 2), 2)
+        params["mtp"] = {
+            "enorm_scale": unit(cfg, (D,)),
+            "hnorm_scale": unit(cfg, (D,)),
+            "eh_proj": dense(tk[0], (2 * D, D)),
+            "layer": init_layer(tk[1], cfg.mtp_kind),
+            "ln_f_scale": unit(cfg, (D,)),
+        }
     return params
 
 
@@ -337,7 +422,11 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     configuration with `layer_kinds` has a tuple of such stacks. The q/k
     norms' scales span all of q's features, which tp splits: sharded like
     them; a head's own (split projections) are whole. A gated delta mixer's
-    fused projection and taps are column-parallel.
+    fused projection and taps are column-parallel. A latent mixer's
+    up-projections are a head at a time and column-parallel, its
+    down-projections (the one rotary key's columns among them) and the
+    latents' norms whole. A multi-token-prediction module's block is
+    sharded like a layer of its kind, its norms and projection whole.
     """
     t, e = tp_axis, ep_axis
 
@@ -354,6 +443,12 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
             layers.update(w_qkvz=P(None, None, t), w_ba=P(None, None, None),
                           conv_w=P(None, None, t), A_log=P(None, None),
                           dt_bias=P(None, None), gdn_norm_scale=P(None, None))
+        elif cfg.mixer == "latent":
+            layers.update(w_q_down=P(None, None, None), q_latent_norm=P(None, None),
+                          w_q_up=P(None, None, t),
+                          w_kv_down=P(None, None, None),
+                          kv_latent_norm=P(None, None),
+                          w_kv_up=P(None, None, t))
         elif cfg.split_qkv:  # heads over tp, as wqkv's columns are
             layers.update(wq=P(None, None, t), wk=P(None, None, t),
                           wv=P(None, None, t))
@@ -370,6 +465,8 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
             layers.update(w_gate=P(None, e, None, t), w_up=P(None, e, None, t),
                           w_down=P(None, e, t, None),
                           router=P(None, None, None))
+            if cfg.router_bias:
+                layers.update(router_bias=P(None, None))
             if cfg.shared_ff:
                 layers.update(shared_gate=P(None, None, t),
                               shared_up=P(None, None, t),
@@ -389,6 +486,13 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
         specs["pos_embed"] = P()
     if not cfg.tied_head:
         specs["lm_head"] = P(t, None)
+    if cfg.mtp_depth:
+        specs["mtp"] = {
+            "enorm_scale": P(), "hnorm_scale": P(), "eh_proj": P(None, None),
+            "ln_f_scale": P(),
+            # one layer, with no layer axis in front
+            "layer": {name: P(*spec[1:])
+                      for name, spec in stack_specs(cfg.mtp_kind).items()}}
     return specs
 
 
@@ -661,6 +765,53 @@ def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
     return ctx @ wo
 
 
+def _latent_attention(h, layer, cfg: TransformerConfig, core=None):
+    """Latent attention (MLA) on normed hidden states h (B, S, D) -> (B, S,
+    D). c_q = norm(h W_q_down) and a head's [q_nope | q_rope] = c_q W_q_up;
+    [c_kv | k_r] = h W_kv_down, c_kv normed, and a head's [k_nope | v] =
+    c_kv W_kv_up; q = [q_nope | rot(q_rope)] and every head's k = [its
+    k_nope | rot(k_r)], the one rotated key of all heads; the causal core
+    the configuration names over heads of nope + rope features, at the scale
+    1 / sqrt(nope + rope); W_o. Training lays k and v out a head, as the
+    published implementations do (absorbing W_kv_up into q is a decode
+    device). Inside, a head's rotated features stand first: the same
+    permutation of q's and k's features, made on W_q_up's columns and where
+    k is put together, leaves every q . k as it is, and puts the rotated
+    features where the one rotary pass that also lays a projection's output
+    out a head expects them (`_turned`). Scopes `mla_down`, `mla_norm`,
+    `mla_up` (the up-projections and what lays k out a head), `rope`,
+    `attn_latent` > `attn_core`."""
+    dt, eps = cfg.dtype, cfg.norm_eps
+    rq, rkv, nope, rope, value = cfg.latent_dims
+    H, hd = cfg.n_heads, nope + rope
+    B, S, _ = h.shape
+    with jax.named_scope("mla_down"):
+        c_q = h @ layer["w_q_down"].astype(dt)
+        c_kv = h @ layer["w_kv_down"].astype(dt)
+        c_kv, k_r = c_kv[..., :rkv], c_kv[..., rkv:]
+    with jax.named_scope("mla_norm"):
+        c_q = _rmsnorm(c_q, _scale(layer["q_latent_norm"], cfg), eps)
+        c_kv = _rmsnorm(c_kv, _scale(layer["kv_latent_norm"], cfg), eps)
+    with jax.named_scope("mla_up"):
+        w_q = layer["w_q_up"].astype(dt).reshape(rq, H, hd)
+        w_q = jnp.concatenate([w_q[..., nope:], w_q[..., :nope]], axis=-1)
+        q = c_q @ w_q.reshape(rq, H * hd)
+        w_kv = layer["w_kv_up"].astype(dt).reshape(rkv, H, nope + value)
+        k_nope = c_kv @ w_kv[..., :nope].reshape(rkv, H * nope)
+        v = c_kv @ w_kv[..., nope:].reshape(rkv, H * value)
+        k = jnp.concatenate(
+            [jnp.broadcast_to(k_r[:, :, None, :], (B, S, H, rope)),
+             k_nope.reshape(B, S, H, nope)], axis=-1)
+        v = v.reshape(B, S, H, value).transpose(0, 2, 1, 3)
+    with jax.named_scope("rope"):
+        q, k = _rope(q.reshape(B, S, H, hd).transpose(0, 2, 1, 3),
+                     k.transpose(0, 2, 1, 3), cfg.rope_theta, rope / hd, ())
+    with jax.named_scope("attn_latent"), jax.named_scope("attn_core"):
+        ctx = (core or attention_core_of(cfg))(q, k, v)
+    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * value)
+    return ctx @ layer["wo"].astype(dt)
+
+
 def _core_kind_scope(cfg: TransformerConfig):
     """`attn_window` or `attn_full` around the core where a model has both
     kinds of layer to tell apart (a window anywhere in it, or split
@@ -793,7 +944,9 @@ def _expert_layer(h, layer, cfg: TransformerConfig):
         h, layer["router"],
         (layer["w_gate"], layer["w_up"], layer["w_down"]),
         top_k=cfg.top_k, gates=scaled(gates, cfg.routed_scale),
-        expert_fn=swiglu_experts, held=cfg.experts_held or None)
+        expert_fn=swiglu_experts, held=cfg.experts_held or None,
+        scores=cfg.router_scores,
+        bias=layer["router_bias"] if cfg.router_bias else None)
     if cfg.shared_ff:
         with jax.named_scope("moe_shared"):
             shared = _silu_gate_out(h @ layer["shared_gate"].astype(dt),
@@ -815,6 +968,10 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
         with jax.named_scope("gdn"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
             x = x + _gated_delta_mixer(h, layer, cfg)
+    elif cfg.mixer == "latent":
+        with jax.named_scope("attn"):
+            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
+            x = x + _latent_attention(h, layer, cfg, core=core)
     else:
         with jax.named_scope("attn"):
             scales = ((_scale(layer["q_norm_scale"], cfg),
@@ -958,14 +1115,39 @@ def transformer_apply(params, tokens, cfg: TransformerConfig):
     return _head_logits(params, transformer_hidden(params, tokens, cfg), cfg)
 
 
-def transformer_loss(params, batch, cfg: TransformerConfig):
-    """Next-token cross-entropy, plus the expert layers' load-balancing and
-    router z-losses (each a mean over the layers) at the configuration's
-    coefficients. batch = tokens (B, S+1) or (tokens, targets)."""
+def _mtp_hidden(params, x, tokens_next, cfg: TransformerConfig):
+    """The multi-token-prediction module on the stack's output x (B, S, D),
+    before the final norm, and the tokens one further on (B, S): h'_i =
+    [norm_e(E(t_{i+1})) | norm_h(x_i)] W_eh, E the model's own embedding,
+    then one block of the last layer's kind -> (its output, before the
+    module's final norm; the block's aux). Scope `mtp_proj`, then the
+    block's own."""
+    mtp, kind = params["mtp"], cfg.mtp_kind
+    with jax.named_scope("mtp_proj"):
+        e = _rmsnorm(_embed(params, tokens_next, cfg),
+                     _scale(mtp["enorm_scale"], cfg), cfg.norm_eps)
+        h = _rmsnorm(x, _scale(mtp["hnorm_scale"], cfg), cfg.norm_eps)
+        h = jnp.concatenate([e, h], axis=-1) @ mtp["eh_proj"].astype(cfg.dtype)
+    return (_layer_again if kind.layer_remat else _layer)(h, mtp["layer"], kind)
+
+
+def _split_batch(batch, cfg: TransformerConfig):
+    """-> (tokens, targets, the ids two further on (B, S) or None). batch =
+    ids (B, S + 1 + mtp_depth), or (tokens, targets) where there is no
+    multi-token-prediction module."""
     if isinstance(batch, (tuple, list)):
-        tokens, targets = batch
-    else:
-        tokens, targets = batch[:, :-1], batch[:, 1:]
+        if cfg.mtp_depth:
+            raise ValueError("a multi-token-prediction module reads ids "
+                             "(B, S + 2), not (tokens, targets)")
+        return (*batch, None)
+    S = batch.shape[1] - 1 - cfg.mtp_depth
+    return batch[:, :S], batch[:, 1:S + 1], batch[:, 2:] if cfg.mtp_depth else None
+
+
+def _losses(params, batch, cfg: TransformerConfig):
+    """-> (main next-token loss, the multi-token-prediction module's loss
+    or None, the expert layers' aux of the stack or None)."""
+    tokens, targets, ahead = _split_batch(batch, cfg)
     # the leaves outside the layer scan (the stack's go through `_hidden`'s):
     # under plain S-SGD on several chips their gradients are averaged where
     # the backward pass completes them
@@ -974,11 +1156,53 @@ def transformer_loss(params, batch, cfg: TransformerConfig):
         "layers": params["layers"]}
     x, aux = _hidden(params, tokens, cfg)
     loss = lm_head_loss(params, x, targets, cfg)
+    if not cfg.mtp_depth:
+        return loss, None, aux
+    with jax.named_scope("mtp"):
+        # position i reads t_{i+1} and predicts t_{i+2}, through the
+        # module's own final norm and the model's own head
+        x, _ = _mtp_hidden(params, x, targets, cfg)
+        own = {**params, "ln_f_scale": params["mtp"]["ln_f_scale"]}
+        return loss, lm_head_loss(own, x, ahead, cfg), aux
+
+
+def transformer_loss(params, batch, cfg: TransformerConfig):
+    """Next-token cross-entropy, plus the expert layers' load-balancing and
+    router z-losses (each a mean over the layers) at the configuration's
+    coefficients, plus `mtp_weight` times the multi-token-prediction
+    module's cross-entropy where the configuration has one (both means over
+    the S positions). batch = tokens (B, S+1) or (tokens, targets); with
+    the module, ids (B, S+2)."""
+    loss, mtp_loss, aux = _losses(params, batch, cfg)
+    if mtp_loss is not None:
+        loss = loss + cfg.mtp_weight * mtp_loss
     if aux is not None and (cfg.router_aux_coef or cfg.router_z_coef):
         with jax.named_scope("moe"), jax.named_scope("moe_router"):
             loss = (loss + cfg.router_aux_coef * jnp.mean(aux.load_balance)
                     + cfg.router_z_coef * jnp.mean(aux.z_loss))
     return loss
+
+
+def transformer_losses(params, batch, cfg: TransformerConfig):
+    """The parts of `transformer_loss` on one batch, each a scalar: `main`,
+    the next-token cross-entropy, and `mtp`, the multi-token-prediction
+    module's, where the configuration has one. Jit this beside the step, as
+    `routing_stats`: the step returns their weighted sum and nothing else."""
+    loss, mtp_loss, _ = _losses(params, batch, cfg)
+    return {"main": loss} if mtp_loss is None else {"main": loss, "mtp": mtp_loss}
+
+
+def record_losses(losses, registry=None) -> None:
+    """`transformer_losses`' numbers as gauges of `telemetry.metrics`:
+    `kungfu_lm_loss` and, beside it where there is one, `kungfu_mtp_loss`."""
+    from kungfu_tpu.telemetry import metrics
+
+    reg = registry or metrics.REGISTRY
+    reg.gauge("kungfu_lm_loss", "next-token cross-entropy of the batch "
+              "read last").set(float(losses["main"]))
+    if "mtp" in losses:
+        reg.gauge("kungfu_mtp_loss", "the multi-token-prediction module's "
+                  "cross-entropy on the same batch").set(float(losses["mtp"]))
 
 
 def routing_stats(params, tokens, cfg: TransformerConfig):
@@ -990,18 +1214,31 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     expert layer has no capacity), and `max_over_mean` (L,) the busiest
     held expert's load over the mean load of all the router's experts;
     `chosen` (L, B * S, top_k) the experts each token took; `layer` (L,)
-    which of the model's layers each row is."""
-    _, aux = _hidden(params, tokens, cfg)
+    which of the model's layers each row is; under a selection bias
+    (`router_bias`) `bias_moved` (L,), the token-choices that the bias
+    changed against a choice on the scores alone. With a
+    multi-token-prediction module, tokens (B, S + 1): the module reads the
+    ids one further on, and where its block has an expert layer that is the
+    last row, `layer` = n_layers."""
+    if cfg.mtp_depth:
+        tokens, tokens_next = tokens[:, :-1], tokens[:, 1:]
+    x, aux = _hidden(params, tokens, cfg)
+    kinds = [kind for kind, n in cfg.stacks for _ in range(n)]
+    if cfg.mtp_depth and cfg.mtp_kind.ffn == "moe":
+        own = _mtp_hidden(params, x, tokens_next, cfg)[1]
+        own = jax.tree.map(lambda a: a[None], own)
+        aux = own if aux is None else jax.tree.map(
+            lambda *a: jnp.concatenate(a), aux, own)
+        kinds.append(cfg.mtp_kind)
     if aux is None:
         raise ValueError("routing_stats: the configuration has no expert layer")
-    kinds = [kind for kind, n in cfg.stacks for _ in range(n)]
     moe = [kind for kind in kinds if kind.ffn == "moe"][0]
     choices = tokens.size * moe.top_k
     first, held = moe.experts_held or (0, moe.n_experts)
     counts = aux.counts
     asked = jnp.sum((aux.chosen >= first) & (aux.chosen < first + held),
                     axis=(1, 2))
-    return {
+    stats = {
         "counts": counts,
         "held_rows": jnp.sum(counts, axis=-1),
         "dropped": asked - jnp.sum(counts, axis=-1),
@@ -1010,14 +1247,18 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
         "layer": jnp.asarray([i for i, kind in enumerate(kinds)
                               if kind.ffn == "moe"], jnp.int32),
     }
+    if aux.bias_moved is not None:
+        stats["bias_moved"] = aux.bias_moved
+    return stats
 
 
 def record_routing(stats, registry=None) -> None:
     """`routing_stats`' numbers as gauges of `telemetry.metrics`, a series
     a layer: `kungfu_moe_dropped_token_choices`, `kungfu_moe_max_over_mean_load`,
     `kungfu_moe_held_rows` and `kungfu_moe_held_share` (the token-choices
-    computed here, and their share of all the layer's), and, per expert
-    held, `kungfu_moe_expert_token_choices`."""
+    computed here, and their share of all the layer's), per expert held
+    `kungfu_moe_expert_token_choices`, and under a selection bias
+    `kungfu_moe_bias_moved_token_choices`."""
     from kungfu_tpu.telemetry import metrics
 
     reg = registry or metrics.REGISTRY
@@ -1036,6 +1277,9 @@ def record_routing(stats, registry=None) -> None:
     share = reg.gauge("kungfu_moe_held_share",
                       "held rows over all the layer's token-choices",
                       ("layer",))
+    moved = reg.gauge("kungfu_moe_bias_moved_token_choices",
+                      "token-choices the router's selection bias changed",
+                      ("layer",)) if "bias_moved" in stats else None
     choices = stats["chosen"][0].size
     for i, row in enumerate(np.asarray(stats["counts"])):
         layer = int(stats["layer"][i])
@@ -1043,6 +1287,8 @@ def record_routing(stats, registry=None) -> None:
         skew.labels(layer).set(float(stats["max_over_mean"][i]))
         rows.labels(layer).set(float(stats["held_rows"][i]))
         share.labels(layer).set(float(stats["held_rows"][i]) / choices)
+        if moved is not None:
+            moved.labels(layer).set(float(stats["bias_moved"][i]))
         for expert, n in enumerate(row):
             load.labels(layer, expert).set(float(n))
 

@@ -26,8 +26,11 @@ SURVEY §2.4). `moe_ffn` is one function for both layouts:
 
 The gate rule and the expert function are the caller's: `switch_gates`
 (top-1 raw, top-k renormalised) with `gelu_experts` by default, `raw_gates`
-with `swiglu_experts` for OLMoE. `switch_moe` (top-1, one expert per device)
-is the round-4 surface, a thin special case.
+with `swiglu_experts` for OLMoE. So is the router's rule (`route`, PR 41):
+the scores are a softmax over the experts or a sigmoid an expert, and a
+selection bias an expert may be added for the choice and not for the weight
+(GLM-4.7-Flash, after DeepSeek-V3). `switch_moe` (top-1, one expert per
+device) is the round-4 surface, a thin special case.
 """
 
 from __future__ import annotations
@@ -48,11 +51,14 @@ class MoeAux(NamedTuple):
     token-choices computed per expert (held here, under `held`), so T * top_k
     - counts.sum() is what was dropped (0 on one shard by construction, of
     the token-choices that fell on its experts); `chosen` (T, top_k) int32
-    = the experts the router took for each token."""
+    = the experts the router took for each token; `bias_moved` () int32 =
+    those of them that a selection bias changed against a choice on the
+    scores alone (`bias_moved`), None where the router has no bias."""
     load_balance: jax.Array
     z_loss: jax.Array
     counts: jax.Array
     chosen: jax.Array
+    bias_moved: jax.Array = None
 
 
 def switch_gates(top_probs):
@@ -144,27 +150,57 @@ _dispatch_rows.defvjp(
     _dispatch_rows_bwd)
 
 
-def route(x, router_w, top_k: int):
-    """(logits, probs, top_probs, top_idx) of tokens x (T, D): the softmax
-    over all E experts in float32 (the router's matmul at the highest
-    precision: it is T x D x E, nothing beside the experts', and a rounded
-    router weight moves the k-th choice of a token whose k-th and (k+1)-th
-    probabilities are close), the top_k largest taken."""
+def route(x, router_w, top_k: int, scores: str = "softmax", bias=None):
+    """(logits, scores, top_scores, top_idx) of tokens x (T, D): the scores
+    over all E experts in float32 by the rule `scores`, "softmax" over the
+    experts or "sigmoid", each expert's own (the router's matmul at the
+    highest precision: it is T x D x E, nothing beside the experts', and a
+    rounded router weight moves the k-th choice of a token whose k-th and
+    (k+1)-th scores are close), and the top_k largest taken. With `bias`
+    (E,), a number an expert (the selection bias of a router balanced
+    without an auxiliary loss, DeepSeek-V3's `noaux_tc`), the choice is the
+    top_k of scores + bias and `top_scores` are the scores of the chosen,
+    without it: the bias moves the choice and never the weight, and the
+    loss is constant in it."""
+    if scores not in ("softmax", "sigmoid"):
+        raise ValueError(f"scores {scores!r} is not 'softmax' or 'sigmoid'")
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_probs, top_idx = lax.top_k(probs, top_k)
+    probs = (jax.nn.softmax(logits, axis=-1) if scores == "softmax"
+             else jax.nn.sigmoid(logits))
+    if bias is None:
+        top_probs, top_idx = lax.top_k(probs, top_k)
+    else:
+        _, top_idx = lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        top_probs = jnp.take_along_axis(probs, top_idx, axis=-1)
     return logits, probs, top_probs, top_idx
 
 
-def _aux(logits, probs, counts, chosen) -> MoeAux:
+def bias_moved(probs, top_idx):
+    """Token-choices (of `route` under a selection bias) that the bias
+    changed: chosen on scores + bias and not among the top_k of the scores
+    alone. () int32."""
+    _, plain = lax.top_k(probs, top_idx.shape[-1])
+    same = (top_idx[:, :, None] == plain[:, None, :]).any(-1)
+    return jnp.sum(~same).astype(jnp.int32)
+
+
+def _aux(logits, probs, counts, chosen, biased: bool = False) -> MoeAux:
     """The router's losses from its own choices (`counts` of them an
-    expert, kept or not)."""
+    expert, kept or not), and where a selection bias made them (`biased`)
+    how many it changed."""
     E = probs.shape[-1]
     share = counts.astype(jnp.float32) / chosen.size
     z = jax.nn.logsumexp(logits, axis=-1)
     return MoeAux(E * jnp.sum(share * jnp.mean(probs, axis=0)),
-                  jnp.mean(jnp.square(z)), counts, chosen)
+                  jnp.mean(jnp.square(z)), counts, chosen,
+                  bias_moved(probs, chosen) if biased else None)
+
+
+def _all_in_one(top_k: int, chunk: int, T: int, sizes) -> bool:
+    """Whether one chunk holds every row that can fall on the held experts
+    (`_share_chunk` makes it so for a share of an eighth or more)."""
+    return chunk >= T * min(top_k, sizes.shape[0])
 
 
 def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
@@ -173,7 +209,11 @@ def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
     the layer: (T, D) float32. Rows past the last group are token-choices
     that fell elsewhere: they go in as zeros and weigh nothing, in both
     passes (the grouped matmul leaves whatever it finds in a row of no
-    group). A row is weighed by its gate where it lies."""
+    group). A row is weighed by its gate where it lies. In a chunk of all
+    that can fall here (`_all_in_one`) those rows are the last group's: the
+    grouped matmul costs what its groups hold (5.8 to 16.6 ms a step with
+    the rows that came: PERF.md, PR 41), and there the cost is to be the
+    buffer's whatever came."""
     T, D = x.shape
     ends = jnp.cumsum(sizes)
     lo = i * chunk
@@ -183,6 +223,8 @@ def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
     # the part of each expert's group that lies in this chunk
     here = (jnp.clip(ends, lo, lo + chunk)
             - jnp.clip(ends - sizes, lo, lo + chunk))
+    if _all_in_one(top_k, chunk, T, sizes):
+        here = here.at[-1].add(chunk - ends[-1])
     with jax.named_scope("moe_dispatch"):
         rows = jnp.where(live, x[token], 0)
     with jax.named_scope("moe_experts"):
@@ -193,7 +235,12 @@ def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
         return jnp.zeros((T, D), jnp.float32).at[token].add(y)
 
 
-def _live_chunks(chunk: int, sizes):
+def _live_chunks(top_k: int, chunk: int, T: int, sizes):
+    """Chunks of `chunk` rows that the held groups `sizes` fill. A chunk of
+    all that can fall here runs whatever came, none included: a count that
+    is no data, so the step's work does not move with the routing at all."""
+    if _all_in_one(top_k, chunk, T, sizes):
+        return 1
     return (jnp.sum(sizes) + chunk - 1) // chunk
 
 
@@ -203,16 +250,16 @@ def _held_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
     """The held experts' part of the layer, -> out (T, D). `order` lists the
     token-choices with those for the held experts first, group by group
     (`sizes` (held,) of them an expert). Dropless whatever the load: the
-    rows are taken `chunk` at a time, as many chunks as the groups fill,
-    up to all T * min(top_k, held) rows that can fall here. So the work is
-    that of the rows that came, and the memory that of one chunk: the
-    TPU's grouped matmul costs what its buffer holds, not what its groups
-    hold (8.8 ms at 65,536 rows of which 2,560 are live; PERF.md, PR 33).
+    rows are taken `chunk` at a time, as many chunks as the groups fill
+    (`_live_chunks`), up to all T * min(top_k, held) rows that can fall
+    here. So the work is
+    that of the rows that came, and the memory that of one chunk (PERF.md,
+    PR 33).
     The loop's length is data, so the backward pass is written out: the
     same loop, each chunk run again and transposed (nothing is kept but the
     arguments), its cotangents added up in float32."""
     out = lax.fori_loop(
-        0, _live_chunks(chunk, sizes),
+        0, _live_chunks(top_k, chunk, x.shape[0], sizes),
         lambda i, out: out + _chunk_part(expert_fn, top_k, chunk, x, gate,
                                          experts, order, sizes, i),
         jnp.zeros(x.shape, jnp.float32))
@@ -236,7 +283,7 @@ def _held_part_bwd(expert_fn, top_k, chunk, res, g):
                             transpose(g))
 
     sums = lax.fori_loop(
-        0, _live_chunks(chunk, sizes), one,
+        0, _live_chunks(top_k, chunk, x.shape[0], sizes), one,
         jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
                      (x, gate, experts)))
     dx, dgate, dexperts = jax.tree.map(lambda s, a: s.astype(a.dtype), sums,
@@ -253,14 +300,22 @@ def _share_chunk(T: int, top_k: int, held: int, n_experts: int) -> int:
     the usual case and a step's work does not move with the routing (a
     router with no balancing loss, trained 80 steps on the Laguna cell's
     pool, sends its held experts up to 2.6 times the balanced load, from
-    1.0 at the start: PERF.md, PR 33); never more than can fall here."""
+    1.0 at the start: PERF.md, PR 33); never more than can fall here. And
+    where two such chunks hold all that can fall here (a share of an eighth
+    of the experts or more), one chunk of all of it: routers that follow a
+    vector the positions share send a share none of a batch's rows or most
+    of them, and a loop of no, one or two chunks is a step of three
+    lengths (8 of 64 held behind full attention: PERF.md, PR 41)."""
+    most = T * min(top_k, held)
     usual = -(-4 * T * top_k * held // n_experts)
-    return min(T * min(top_k, held), -(-usual // 8) * 8)
+    usual = -(-usual // 8) * 8
+    return most if most <= 2 * usual else usual
 
 
 def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
             top_k: int = 1, capacity_factor: float = 1.25,
-            gates=switch_gates, expert_fn=gelu_experts, held=None):
+            gates=switch_gates, expert_fn=gelu_experts, held=None,
+            scores: str = "softmax", bias=None):
     """x (T, D) tokens on this shard; router_w (D, E); `experts` a tuple of
     THIS device's expert weight stacks (leading dim = experts per device,
     epd; E = axis_size * epd), handed to `expert_fn(rows, experts,
@@ -277,7 +332,11 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
     fell elsewhere (no exchange is made and nothing stands in for one).
     Dropless: up to T * min(top_k, count) rows, the most that can fall
     here, are computed, a chunk at a time (`_held_part`), and `counts` is
-    of the held experts."""
+    of the held experts.
+
+    `scores` and `bias` are the router's rule (`route`): softmax or sigmoid
+    scores, and a selection bias (E,) that moves the choice alone; `gates`
+    sees the chosen experts' scores."""
     if top_k < 1:
         raise ValueError(f"top_k must be at least 1, got {top_k}")
     T, D = x.shape
@@ -300,7 +359,8 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
         raise ValueError(f"top_k {top_k} exceeds the {E} experts")
 
     with jax.named_scope("moe_router"):
-        logits, probs, top_probs, top_idx = route(x, router_w, top_k)
+        logits, probs, top_probs, top_idx = route(x, router_w, top_k, scores,
+                                                  bias)
         gate = gates(top_probs)  # (T, top_k), float32
 
     if axis_size == 1:
@@ -327,7 +387,8 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
             out = _held_part(expert_fn, top_k, chunk, x, gate, experts, order,
                              sizes)
             with jax.named_scope("moe_router"):
-                aux = _aux(logits, probs, counts, top_idx)._replace(counts=sizes)
+                aux = _aux(logits, probs, counts, top_idx,
+                           bias is not None)._replace(counts=sizes)
             return out, aux
         with jax.named_scope("moe_dispatch"):
             rows = _dispatch_rows(x, order, back, top_k)  # (T * top_k, D)
@@ -338,7 +399,7 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
             out = jnp.sum(y.astype(jnp.float32) * gate[:, :, None],
                           axis=1).astype(x.dtype)
         with jax.named_scope("moe_router"):
-            aux = _aux(logits, probs, counts, top_idx)
+            aux = _aux(logits, probs, counts, top_idx, bias is not None)
         return out, aux
 
     C = max(1, int(capacity_factor * T / E))  # per (shard, choice) capacity
@@ -381,7 +442,7 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
     with jax.named_scope("moe_router"):
         # the losses see the router's choices, kept or not
         asked = jnp.zeros((E,), jnp.int32).at[top_idx.reshape(-1)].add(1)
-        aux = _aux(logits, probs, asked, top_idx)
+        aux = _aux(logits, probs, asked, top_idx, bias is not None)
         aux = aux._replace(load_balance=lax.pmean(aux.load_balance, axis_name),
                            z_loss=lax.pmean(aux.z_loss, axis_name),
                            counts=counts)

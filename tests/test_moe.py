@@ -318,11 +318,12 @@ def test_the_shares_parts_add_up_to_the_whole_layer(top_k, count, E):
                                    rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("E,chunks", [(16, 1), (64, 4)])
+@pytest.mark.parametrize("E,chunks", [(16, 1), (32, 1), (64, 4)])
 def test_a_share_is_dropless_under_the_worst_load(E, chunks):
     """Every token's three choices on the four experts held: all T x
     min(top_k, held) rows that can fall here are taken, in as many chunks
-    as they fill (of 64 experts a chunk is a quarter of them), none is
+    as they fill (of 64 experts a chunk is a quarter of them; of 32, where
+    two usual chunks would hold them all, one chunk is all of them), none is
     dropped, values and gradients are the whole layer's; with the choices
     all elsewhere the part is zero."""
     moe, x, router, experts, gates = _share_setup(E=E)
@@ -352,6 +353,44 @@ def test_a_share_is_dropless_under_the_worst_load(E, chunks):
     nothing, aux = _share(moe, x, router, experts, gates, 3, 8, 4)
     assert aux.counts.tolist() == [0, 0, 0, 0]
     assert float(jnp.abs(nothing).max()) == 0.0
+
+
+@pytest.mark.parametrize("E,one", [(16, True), (32, True), (64, False)])
+def test_a_chunk_of_all_that_can_fall_here_does_not_follow_the_routing(E, one):
+    """Where one chunk holds all that can fall on the held experts (a share
+    of an eighth of them or more) the step's work is no data: the program
+    has no loop whose count the routing gives, and the groups handed to the
+    experts fill the chunk whatever came, the rows of no group in the last
+    one, where they are zeros and weigh nothing (the values and gradients
+    of the tests above). A smaller share keeps its loop and its groups."""
+    moe, x, router, experts, gates = _share_setup(E=E)
+    chunk = moe._share_chunk(48, 3, 4, E)
+    assert (chunk == 48 * 3) == one
+    mine = tuple(w[4:8] for w in experts)
+
+    def part(x, router):
+        return moe.moe_ffn(x, router, mine, top_k=3, gates=gates,
+                           expert_fn=moe.swiglu_experts, held=(4, 4))[0]
+
+    assert ("while" in str(jax.make_jaxpr(part)(x, router))) == (not one)
+    seen = []
+
+    def spy(rows, experts, sizes):
+        seen.append((rows, sizes))
+        return moe.swiglu_experts(rows, experts, sizes)
+
+    order = jnp.arange(48 * 3, dtype=jnp.int32)
+    for sizes in ([5, 0, 7, 2], [0, 0, 0, 0]):  # some rows; all elsewhere
+        out = moe._chunk_part(spy, 3, chunk, x, jnp.ones((48, 3)), mine, order,
+                              jnp.array(sizes, jnp.int32), 0)
+        rows, groups = seen.pop()
+        live = sum(sizes)
+        assert float(jnp.abs(rows[live:]).max()) == 0.0
+        if one:  # the rows of no group are the last group's
+            assert groups.tolist() == sizes[:3] + [sizes[3] + chunk - live]
+        else:
+            assert groups.tolist() == sizes
+        assert (live == 0) == (float(jnp.abs(out).max()) == 0.0)
 
 
 def test_a_share_of_every_expert_is_the_layer_itself():
@@ -387,3 +426,84 @@ def test_gate_rules():
     np.testing.assert_allclose(moe.renormalised_gates(p[:, :1]), 1.0)  # top-1 too
     assert moe.scaled(moe.raw_gates, 1.0) is moe.raw_gates
     np.testing.assert_allclose(moe.scaled(moe.raw_gates, 2.5)(p), 2.5 * p)
+
+
+# --- the router's rule (PR 41): softmax or sigmoid scores, a selection bias --
+
+@pytest.mark.parametrize("rule", ["softmax", "sigmoid"])
+def test_route_scores_by_its_rule(rule):
+    from kungfu_tpu.ops import moe
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    x, router = jax.random.normal(ks[0], (40, 16)), jax.random.normal(ks[1], (16, 8))
+    logits, scores, top, idx = moe.route(x, router, 3, rule)
+    np.testing.assert_allclose(logits, x @ router, rtol=1e-5, atol=1e-5)
+    want = (jax.nn.softmax if rule == "softmax" else jax.nn.sigmoid)(logits)
+    np.testing.assert_array_equal(scores, want)
+    np.testing.assert_array_equal(top, jnp.take_along_axis(scores, idx, -1))
+    np.testing.assert_array_equal(idx, jnp.argsort(-scores, axis=-1)[:, :3])
+    # a softmax's scores sum to one over the experts; a sigmoid's are each
+    # expert's own
+    assert np.allclose(scores.sum(-1), 1.0) == (rule == "softmax")
+    if rule == "softmax":  # the rule the function always had, by default
+        for got, was in zip(moe.route(x, router, 3), (logits, scores, top, idx)):
+            np.testing.assert_array_equal(got, was)
+    with pytest.raises(ValueError, match="scores"):
+        moe.route(x, router, 3, "relu")
+
+
+@pytest.mark.parametrize("rule", ["softmax", "sigmoid"])
+def test_route_chooses_on_scores_plus_bias_and_weighs_by_the_scores(rule):
+    from kungfu_tpu.ops import moe
+
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    x, router = jax.random.normal(ks[0], (64, 16)), 0.3 * jax.random.normal(ks[1], (16, 8))
+    bias = jnp.zeros(8).at[5].set(10.0)  # expert 5 wins every choice
+    _, scores, top, idx = moe.route(x, router, 2, rule, bias)
+    assert (idx[:, 0] == 5).all()
+    np.testing.assert_array_equal(top, jnp.take_along_axis(scores, idx, -1))
+    assert float(top.max()) <= 1.0  # the bias is in no weight
+    _, _, plain_top, plain_idx = moe.route(x, router, 2, rule)
+    # the second choice is the plain first, unless that was expert 5
+    first = np.asarray(plain_idx[:, 0])
+    second = np.where(first == 5, np.asarray(plain_idx[:, 1]), first)
+    np.testing.assert_array_equal(idx[:, 1], second)
+    # a bias of zero moves nothing; the count is of the choices it changed
+    _, _, _, same = moe.route(x, router, 2, rule, jnp.zeros(8))
+    np.testing.assert_array_equal(same, plain_idx)
+    assert int(moe.bias_moved(scores, same)) == 0
+    changed = sum(5 not in row for row in np.asarray(plain_idx).tolist())
+    assert int(moe.bias_moved(scores, idx)) == changed > 0
+
+
+@pytest.mark.parametrize("held", [None, (4, 4)])
+def test_moe_ffn_under_sigmoid_scores_and_a_bias_matches_dense(held):
+    """The layer under the GLM-4.7-Flash rule against every expert run over
+    every token and weighed by hand: the whole layer, and one share."""
+    moe, x, router, experts, _ = _share_setup(E=16)
+    gates = moe.scaled(moe.renormalised_gates, 1.8)
+    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(9), (16,))
+    mine = experts if held is None else tuple(w[4:8] for w in experts)
+    got, aux = moe.moe_ffn(x, router, mine, top_k=3, gates=gates,
+                           expert_fn=moe.swiglu_experts, held=held,
+                           scores="sigmoid", bias=bias)
+    scores = jax.nn.sigmoid(x @ router)
+    idx = jnp.argsort(-(scores + bias), axis=-1)[:, :3]
+    top = jnp.take_along_axis(scores, idx, -1)
+    weights = 1.8 * top / top.sum(-1, keepdims=True)
+    first, count = held or (0, 16)
+    want = jnp.zeros_like(x)
+    for e in range(first, first + count):
+        w_gate, w_up, w_down = (w[e] for w in experts)
+        y = (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+        want = want + jnp.sum(jnp.where(idx == e, weights, 0.0), -1)[:, None] * y
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    assert aux.counts.tolist() == np.bincount(
+        np.asarray(idx).ravel(), minlength=16)[first:first + count].tolist()
+    assert int(aux.bias_moved) == int(moe.bias_moved(scores, idx)) > 0
+    # without a bias the aux says nothing of one
+    plain = moe.moe_ffn(x, router, mine, top_k=3, gates=gates,
+                        expert_fn=moe.swiglu_experts, held=held,
+                        scores="sigmoid")[1]
+    assert plain.bias_moved is None
