@@ -7,6 +7,22 @@ matrix never materializes in HBM, so peak memory is O(BLK_Q x S_block)
 instead of O(S^2). Causal programs stop at their diagonal block (the
 upper-triangular half is never computed at all).
 
+A grid step is one (q-block, k-block) pair, and in the forward kernel the
+block's own offsets say which of three it is (`_interior`): **dead**, above
+the diagonal or wholly behind the window, and skipped, fetch and all;
+**interior**, every key of it seen by every query of it (120 of a head's 136
+visited blocks at 8,192 positions and blocks of 512, `block_counts`),
+computed with no mask at all; or **edge**, crossed by the diagonal or by the
+window's far side (every block of a band core at window 512), computed under
+`_mask`. The two bodies are one function, and give the same bits: on an
+interior block the select is the identity and the product is by 1.0. The
+two backward kernels mask every live block: they stand at their matmuls'
+time and a second body measured nothing there. The forward kernel's running
+statistics m and l live as whole (blk_q, 128) lane-replicated tiles and meet
+the (blk_q, blk_k) scores as copies side by side (`_across`): read as column
+0 and broadcast a step they, not the masks and not the MXU, were the largest
+cost of a forward block (PERF.md, PR 42).
+
 Differentiable via custom_vjp: the forward kernel also emits the per-row
 log-sum-exp, and the backward runs two fused Pallas kernels (dq over
 k-blocks; dk/dv over q-blocks) that recompute exact block probabilities
@@ -97,6 +113,71 @@ def _q_steps(S: int, blk_q: int, blk_k: int, window) -> int:
                for c in range(0, S, blk_k))
 
 
+def _interior(q_off, k_off, blk_q: int, blk_k: int, window):
+    """Whether every key of the block at (q_off, k_off) is seen by every
+    query of it: the newest key is no later than the oldest query and, under
+    a window, the oldest key is inside the newest query's. `_mask` is all
+    true there and the forward kernel leaves it out. Offsets traced or plain."""
+    inside = k_off + blk_k - 1 <= q_off
+    if window is not None:
+        inside &= q_off + blk_q - 1 - k_off < window
+    return inside
+
+
+def block_counts(S: int, blk_q: int, blk_k: int, window=None):
+    """(visited, edge) blocks a head of a causal core: the live blocks of a
+    sweep, and those of them that a mask's edge crosses, the only ones the
+    forward kernel masks; the rest are interior. The forward/dQ sweep and the dK/dV sweep
+    visit the same blocks. 136 and 16 at 8,192 positions and blocks of 512,
+    528 and 32 at 16,384, 36 and 8 at 4,096; under window 512 every visited
+    block is an edge block."""
+    visited = edge = 0
+    for q_off in range(0, S, blk_q):
+        for k_off in range(0, S, blk_k):
+            if k_off > q_off + blk_q - 1 or (
+                    window is not None and q_off - (k_off + blk_k - 1) >= window):
+                continue  # dead: above the diagonal, or wholly behind the band
+            visited += 1
+            edge += not _interior(q_off, k_off, blk_q, blk_k, window)
+    return visited, edge
+
+
+def _count_blocks(kernels, heads: int, S, blk_q, blk_k, causal, window,
+                  interior_unmasked: bool):
+    """At trace time, what a run of each of the `kernels` being built visits
+    and masks over its heads, added to the counters
+    `kungfu_flash_blocks_visited_total` and `kungfu_flash_blocks_masked_total`
+    (docs/telemetry.md): a sum over the kernels traced, not over their runs."""
+    from kungfu_tpu.telemetry import metrics
+
+    if causal:
+        visited, edge = block_counts(S, blk_q, blk_k, window)
+        masked = edge if interior_unmasked else visited
+    else:
+        visited, masked = (S // blk_q) * (S // blk_k), 0
+    for name, text, blocks in (
+            ("kungfu_flash_blocks_visited_total",
+             "blocks a run of each flash kernel traced so far visits, by kernel",
+             visited),
+            ("kungfu_flash_blocks_masked_total",
+             "those of them computed under the mask", masked)):
+        family = metrics.counter(name, text, ("kernel",))
+        for kernel in kernels:
+            family.labels(kernel).inc(heads * blocks)
+
+
+def _across(stat, n: int):
+    """A (rows, 128) lane-replicated row statistic, m, l or a correction, as
+    wide as the (rows, n) tile it meets: itself at 128 lanes, whole copies
+    side by side at a multiple of them (no lane is moved), and column 0 to
+    broadcast at any other width (the tests' small blocks and heads)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if n % stat.shape[-1]:
+        return stat[:, :1]
+    return stat if n == stat.shape[-1] else pltpu.repeat(stat, n // stat.shape[-1], 1)
+
+
 def _mask(q_off, k_off, blk_q: int, blk_k: int, window):
     """(blk_q, blk_k) bool: key seen by query, causal and inside the window."""
     qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
@@ -133,37 +214,41 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     # causal: blocks fully above the diagonal contribute nothing
     live = (k_off <= q_off + blk_q - 1) if causal else (kb >= 0)
 
-    @pl.when(live)
-    def _compute():
+    def _compute(masked: bool):
         q, k, v = q_ref[0], k_ref[0], v_ref[0]
         s = _nt(q, k) * sm_scale
-        if causal:
+        if masked:
             mask = _mask(q_off, k_off, blk_q, blk_k, window)
             s = jnp.where(mask, s, NEG_INF)
-            maskf = mask.astype(jnp.float32)
-        else:
-            maskf = 1.0
-        m = m_scr[:, :1]
+        m = m_scr[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new) * maskf
+        p = jnp.exp(s - _across(m_new, blk_k))
+        if masked:  # a row with no key in this block nor before it: s - m is 0
+            p = p * mask.astype(jnp.float32)
         corr = jnp.exp(m - m_new)
-        l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jnp.dot(
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * _across(corr, acc_scr.shape[-1]) + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
-        m_scr[:, :1] = m_new
+        m_scr[...] = m_new
+
+    # one of the two runs: under the mask where an edge crosses the block,
+    # without where it is interior (every block of a call that is not causal)
+    interior = _interior(q_off, k_off, blk_q, blk_k, window) if causal else True
+    pl.when(live & interior)(lambda: _compute(False))
+    if causal:
+        pl.when(live & jnp.logical_not(interior))(lambda: _compute(True))
 
     @pl.when(step == n_kb - 1)
     def _finalize():
-        o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+        l = l_scr[...]
+        o_ref[0] = (acc_scr[...] / _across(l, acc_scr.shape[-1])).astype(o_ref.dtype)
         # log-sum-exp per row: the backward recomputes exact block probs
         # as exp(s - lse) without re-running the online max/sum recurrence.
         # Stored 8-lane-replicated: Mosaic wants the last block dim ==
         # the array dim (8) and the stats are sublane-oriented anyway,
         # so this layout round-trips with zero relayouts.
-        lse_ref[0] = jnp.broadcast_to(
-            m_scr[:, :1] + jnp.log(l_scr[:, :1]), lse_ref[0].shape
-        )
+        lse_ref[0] = (m_scr[...] + jnp.log(l))[:, :lse_ref.shape[-1]]
 
 
 def _blocks(S: int, blk_q: int, blk_k: int):
@@ -222,6 +307,7 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
     kf = k.reshape(B * H // g, S, hd)
     vf = v.reshape(B * H // g, S, hd)
     kv_index = functools.partial(_kv_index, blk_q, blk_k, causal, window, g)
+    _count_blocks(("forward",), B * H, S, blk_q, blk_k, causal, window, True)
     out, lse = pl.pallas_call(
         functools.partial(_kernel, blk_q=blk_q, blk_k=blk_k, causal=causal,
                           sm_scale=sm_scale, window=window),
@@ -240,7 +326,7 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
             pl.BlockSpec((1, blk_q, 8), lambda b, i, j: (b, i, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_q, 128), jnp.float32),  # m (lane-replicated col 0)
+            pltpu.VMEM((blk_q, 128), jnp.float32),  # m, every lane a copy
             pltpu.VMEM((blk_q, 128), jnp.float32),  # l
             pltpu.VMEM((blk_q, hd), jnp.float32),  # acc
         ],
@@ -394,6 +480,7 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
         functools.partial(_kv_index, blk_q, blk_k, causal, window, group)
     )
     row_spec = pl.BlockSpec((1, blk_q, 8), lambda b, i, j: (b, i, 0))
+    _count_blocks(("dq", "dkv"), B * H, S, blk_q, blk_k, causal, window, False)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, blk_q=blk_q, blk_k=blk_k,
@@ -451,17 +538,29 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
     dq streams k-blocks, dk/dv stream q-blocks, block probs recomputed
     from the forward's saved row log-sum-exp). S must be a multiple of
     both block sizes (each clamped to S). q, k, v go to the MXU in the type
-    they come in (bfloat16 in the model), accumulated in float32. On the
-    v5e at (2, 16, 4096, 128) bfloat16 and 512 x 512 blocks, inside the
-    layer scan under `value_and_grad`: 2.39 ms forward, 4.21 ms backward,
-    31.7 % of the bf16 peak for the causal core's required operations
-    (`flash_roofline_pct`, cell `olmoe_1b_7b.ssgd_seq4096_1chip`; PERF.md,
-    PR 27). A dense core's float32 scores are 1.07 GB a sequence there.
-    At (1, 48, 8192, 128) on 8 key/value heads, a layer: 12.7 ms forward,
-    12.5 dK/dV, 10.4 dQ, 33.9 % of the peak; at 72 heads and window 512:
-    4.4, 4.0, 3.3 ms, 19 % of the peak for the band's operations, two
-    blocks computed a row where the band's area is one (cell
-    `laguna_s_2_1.ssgd_1seq_1chip`; PERF.md, PR 33).
+    they come in (bfloat16 in the model), accumulated in float32.
+
+    On the v5e, bfloat16, 512 x 512 blocks, a run of each kernel inside the
+    cells' steps (their traced runs, PERF.md, PR 42): ms, and ps a score of
+    a visited block beside what the MXU needs for the kernel's 2, 3 and 4
+    matmuls a score:
+
+        (B, H on Hkv, S, hd)            forward        dQ             dK/dV
+        (2, 16 on 16,  4096, 128)      1.39  4.6/2.6   2.06  6.8/3.9   2.22  7.4/5.2
+        (1, 48 on 8,   8192, 128)      7.50  4.4/2.6  10.54  6.2/3.9  12.52  7.3/5.2
+        (1, 72 on 8,   8192, 128) w512 2.98  5.1/2.6   3.42  5.8/3.9   4.14  7.1/5.2
+        (1, 20 on 20,  8192, 256)      5.49  7.7/5.2   7.34 10.3/7.8   8.69 12.2/10.4
+        (1, 16 on 2,  16384, 256)     15.49  7.0/5.2  21.02  9.5/7.8  26.77 12.1/10.4
+
+    (`olmoe_1b_7b.ssgd_seq4096_1chip`; `laguna_s_2_1.ssgd_1seq_1chip`, a full
+    and a window layer; `glm_4_7_flash.ssgd_mtp_8k_1chip`;
+    `qwen3_next_80b_a3b.ssgd_longseq_1chip`). Timed alone, the forward kernel
+    with nothing but its two matmuls in it takes 93 % of its time at head 128
+    and 95 % at 256: what a live grid step takes over the MXU's time, 0.55
+    to 0.9 us whatever the head size, is the step's own; the two backward
+    kernels are at that floor too, and
+    a dead step costs 0.19 us. A dense core's float32 scores are 1.07 GB a
+    sequence of 4,096.
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)

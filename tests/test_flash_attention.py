@@ -15,10 +15,15 @@ def _qkv(B=2, H=3, S=64, hd=16, seed=0, dtype=jnp.float32):
     )
 
 
+# blocks of 128 and 256 at head size 128 are as wide as the chip's lanes and
+# twice that: the running maximum and sum meet the scores as they do on the
+# chip, the tile itself or copies side by side (`_across`), where the narrow
+# blocks broadcast column 0
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("blk", [16, 32, 64])
-def test_flash_matches_dense(causal, blk):
-    q, k, v = _qkv()
+@pytest.mark.parametrize("blk,S,hd", [(16, 64, 16), (32, 64, 16), (64, 64, 16),
+                                      (128, 512, 128), (256, 512, 128)])
+def test_flash_matches_dense(causal, blk, S, hd):
+    q, k, v = _qkv(S=S, hd=hd)
     out = flash_attention(q, k, v, causal, None, blk, blk, True)
     ref = _dense_reference(q, k, v, causal, 1.0 / np.sqrt(q.shape[-1]))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -136,10 +141,10 @@ def _grouped_qkv(S, g, Hkv=2, hd=8, B=2, seed=0):
     return q, k, v, jax.random.normal(ks[3], q.shape)
 
 
-def _flash_and_dense(S, blk_q, blk_k, window, g):
+def _flash_and_dense(S, blk_q, blk_k, window, g, hd=8):
     """(loss, gradients) of the flash core and of the dense masked one, on
     a weighted sum of the output so that every row's gradient differs."""
-    q, k, v, weigh = _grouped_qkv(S, g)
+    q, k, v, weigh = _grouped_qkv(S, g, hd=hd)
 
     def flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, True, None, blk_q, blk_k, True,
@@ -154,13 +159,15 @@ def _flash_and_dense(S, blk_q, blk_k, window, g):
 
 
 # blocks of 16: half a block, one block, three blocks; 80 is no multiple of
-# 48 (nor 64 of 24); the last two cases have blocks that differ
+# 48 (nor 64 of 24); the last two cases of head size 8 have blocks that
+# differ; those of head size 128 have lane-wide blocks, as above
 @pytest.mark.parametrize("g", [1, 3])
-@pytest.mark.parametrize("S,blk_q,blk_k,window", [
-    (64, 16, 16, 8), (64, 16, 16, 16), (80, 16, 16, 48), (64, 16, 16, None),
-    (64, 16, 32, 24), (64, 32, 16, 20)])
-def test_windowed_grouped_flash_matches_dense(S, blk_q, blk_k, window, g):
-    (loss, grads), (want_loss, want) = _flash_and_dense(S, blk_q, blk_k, window, g)
+@pytest.mark.parametrize("S,blk_q,blk_k,window,hd", [
+    (64, 16, 16, 8, 8), (64, 16, 16, 16, 8), (80, 16, 16, 48, 8),
+    (64, 16, 16, None, 8), (64, 16, 32, 24, 8), (64, 32, 16, 20, 8),
+    (512, 128, 256, None, 128), (512, 256, 128, 200, 128), (512, 128, 128, 200, 128)])
+def test_windowed_grouped_flash_matches_dense(S, blk_q, blk_k, window, hd, g):
+    (loss, grads), (want_loss, want) = _flash_and_dense(S, blk_q, blk_k, window, g, hd)
     assert abs(float(loss) - float(want_loss)) <= 1e-4 * abs(float(want_loss)) + 1e-4
     for got, ref in zip(grads, want):
         assert got.shape == ref.shape
@@ -214,3 +221,189 @@ def test_grouped_shapes_that_do_not_fit_raise():
         flash_attention(q, k, v, False, None, 16, 16, True, 8)
     with pytest.raises(ValueError, match="a window is causal"):
         flash_attention(q, k, v, True, None, 16, 16, True, 0)
+
+
+# --- interior and edge blocks (PR 42) ----------------------------------------
+
+def _fa():
+    import importlib
+
+    return importlib.import_module("kungfu_tpu.ops.flash_attention")
+
+
+def _swept(fa, S, blk_q, blk_k, window):
+    """The (q-block, k-block) pairs each sweep computes, by the kernels' own
+    grid arithmetic: forward and dQ from the q-blocks, dK/dV from the k-blocks."""
+    by_q, by_k = set(), set()
+    for i in range(S // blk_q):
+        first = 0 if window is None else int(fa._first_kv_block(blk_q, blk_k, window, i))
+        for step in range(fa._kv_steps(S, blk_q, blk_k, window)):
+            if (first + step) * blk_k <= i * blk_q + blk_q - 1:
+                by_q.add((i, first + step))
+    for j in range(S // blk_k):
+        first = 0 if window is None else (j * blk_k) // blk_q
+        for step in range(fa._q_steps(S, blk_q, blk_k, window)):
+            i = first + step
+            live = (i * blk_q + blk_q - 1 >= j * blk_k if window is None
+                    else i <= int(fa._last_q_block(blk_q, blk_k, window, S, j)))
+            if live:
+                by_k.add((i, j))
+    return by_q, by_k
+
+
+@pytest.mark.parametrize("S,blk_q,blk_k,window", [
+    (128, 16, 16, None), (128, 16, 16, 1), (128, 16, 16, 16), (128, 16, 16, 17),
+    (128, 16, 16, 40), (128, 16, 16, 512), (128, 16, 16, 1000),
+    (128, 32, 16, None), (128, 16, 32, None), (128, 32, 16, 24),
+    (128, 16, 32, 24), (128, 64, 16, 48), (96, 16, 48, 33), (96, 48, 16, 512),
+    (4096, 512, 512, None), (4096, 512, 512, 512), (2048, 256, 512, 1),
+    (2048, 512, 256, 700)])
+def test_interior_blocks_need_no_mask_and_skipped_ones_are_dead(S, blk_q, blk_k, window):
+    """Against brute force: a block the predicate calls interior has an
+    all-true mask, a block the sweeps skip an all-false one, both sweeps
+    visit the same blocks, and `block_counts` counts them."""
+    fa = _fa()
+    by_q, by_k = _swept(fa, S, blk_q, blk_k, window)
+    assert by_q == by_k
+    edge = 0
+    for i in range(S // blk_q):
+        for j in range(S // blk_k):
+            mask = np.asarray(fa._mask(i * blk_q, j * blk_k, blk_q, blk_k, window))
+            if (i, j) not in by_q:
+                assert not mask.any(), (i, j)
+            elif fa._interior(i * blk_q, j * blk_k, blk_q, blk_k, window):
+                assert mask.all(), (i, j)
+            else:
+                assert not mask.all(), (i, j)  # an edge block earns its mask
+                edge += 1
+    assert fa.block_counts(S, blk_q, blk_k, window) == (len(by_q), edge)
+
+
+@pytest.mark.parametrize("S,blk,window,visited,edge", [
+    (8192, 512, None, 136, 16), (16384, 512, None, 528, 32),
+    (4096, 512, None, 36, 8), (8192, 512, 512, 31, 31)])
+def test_block_counts_at_the_cells_shapes(S, blk, window, visited, edge):
+    assert _fa().block_counts(S, blk, blk, window) == (visited, edge)
+
+
+# g, blk_q, blk_k, window, S: blocks of both kinds in each (asserted where
+# they run). The last three have blocks as wide as the chip's lanes or twice
+# that, where the running maximum meets the scores as whole tiles side by
+# side (`_across`'s other branch: the narrow blocks broadcast column 0)
+_BIT_CALLS = {
+    "causal": (1, 16, 16, None, 96), "grouped": (3, 16, 16, None, 96),
+    "window": (1, 16, 16, 40, 96), "grouped-window": (2, 16, 16, 56, 96),
+    "tall-blocks": (2, 32, 16, None, 96), "wide-blocks-window": (1, 16, 32, 48, 96),
+    "lane-blocks": (1, 128, 128, None, 512),
+    "lane-blocks-grouped-window": (2, 128, 128, 300, 512),
+    "two-lane-blocks": (1, 128, 256, None, 512)}
+_BIT_CASES = [(call, hd, dtype) for call in _BIT_CALLS for hd in (64, 128, 256)
+              for dtype in ("float32", "bfloat16")]
+
+
+def _five_of_both_forms(call, hd, dtype):
+    """{output: the same bits?} of one interpreted call, the kernels as they
+    are against the kernels with every live block called an edge block, the
+    parent's behaviour (and the backward kernels' still: dq, dk and dv say
+    that the row sums they are given have not moved)."""
+    fa = _fa()
+    g, blk_q, blk_k, window, S = _BIT_CALLS[call]
+    visited, edge = fa.block_counts(S, blk_q, blk_k, window)
+    assert visited > edge > 0
+    ks = jax.random.split(jax.random.PRNGKey(hd + g), 4)
+    q = jax.random.normal(ks[0], (1, 2 * g, S, hd), dtype)
+    k = jax.random.normal(ks[1], (1, 2, S, hd), dtype)
+    v = jax.random.normal(ks[2], (1, 2, S, hd), dtype)
+    do = jax.random.normal(ks[3], q.shape, dtype)
+
+    def five():
+        scale = 1.0 / np.sqrt(hd)
+        out, lse = fa._forward(q, k, v, True, scale, blk_q, blk_k, True,
+                               with_lse=True, window=window)
+        return (out, lse) + fa._backward_kernels(
+            q, k, v, out, lse, do, True, scale, blk_q, blk_k, True, window=window)
+
+    got = five()
+    interior, fa._interior = fa._interior, lambda q_off, *rest: q_off < 0
+    try:
+        want = five()
+    finally:
+        fa._interior = interior
+    return {name: a.dtype == b.dtype and bool(jnp.array_equal(a, b))
+            for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want)}
+
+
+@pytest.fixture(scope="module")
+def both_forms_bits():
+    """Every case of `_BIT_CASES` in one process of its own whose compiler
+    has no fused multiply-add. With one, XLA's CPU backend contracts
+    `x * scale - m` wherever no select stands between the two, so the two
+    bodies round differently at a scale that is no power of two (head 128):
+    the interpreter's doing, not the kernels' (the chip's agree bit for bit,
+    PERF.md, PR 42)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root, XLA_FLAGS=(
+        os.environ.get("XLA_FLAGS", "") + " --xla_cpu_max_isa=AVX").strip())
+    done = subprocess.run([sys.executable, os.path.abspath(__file__)], env=env,
+                          capture_output=True, text=True, timeout=1200)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("call,hd,dtype", _BIT_CASES,
+                         ids=["-".join(map(str, c)) for c in _BIT_CASES])
+def test_unmasked_interior_blocks_change_no_bit(both_forms_bits, call, hd, dtype):
+    """On an interior block the select is the identity and the product is
+    by 1.0: output, row log-sum-exp, dq, dk and dv keep every bit."""
+    same = both_forms_bits[f"{call}-{hd}-{dtype}"]
+    assert all(same.values()) and len(same) == 5, same
+
+
+def test_the_block_counters_reach_the_metrics():
+    """Tracing a core raises `kungfu_flash_blocks_visited_total` and
+    `kungfu_flash_blocks_masked_total` by `block_counts`' numbers a head,
+    for each kernel it builds: the forward kernel masks the edge blocks, the
+    two backward kernels every block they visit."""
+    from kungfu_tpu.telemetry import metrics
+
+    fa = _fa()
+    visited, edge = fa.block_counts(1024, 256, 256)
+    assert (visited, edge) == (10, 4)
+
+    def read():
+        return {(name, kernel): metrics.counter(
+            name, labelnames=("kernel",)).labels(kernel).value
+            for name in ("kungfu_flash_blocks_visited_total",
+                         "kungfu_flash_blocks_masked_total")
+            for kernel in ("forward", "dq", "dkv")}
+
+    q = jnp.zeros((1, 3, 1024, 64))
+    before = read()
+    jax.jit(jax.grad(lambda q: jnp.sum(
+        fa.flash_attention(q, q, q, True, None, 256, 256, True)))).lower(q)
+    after = read()
+    for (name, kernel), was in before.items():
+        masked = edge if kernel == "forward" else visited
+        want = 3 * (visited if "visited" in name else masked)
+        assert after[(name, kernel)] - was == want, (name, kernel)
+    # a band core: every visited block is masked
+    before = after
+    jax.jit(lambda q: fa.flash_attention(q, q, q, True, None, 256, 256, True, 256)
+            ).lower(q)
+    after = read()
+    assert after[("kungfu_flash_blocks_visited_total", "forward")] - before[
+        ("kungfu_flash_blocks_visited_total", "forward")] == 3 * 7
+    assert after[("kungfu_flash_blocks_masked_total", "forward")] - before[
+        ("kungfu_flash_blocks_masked_total", "forward")] == 3 * 7
+
+
+if __name__ == "__main__":  # `both_forms_bits`' own process
+    import json
+
+    print(json.dumps({f"{call}-{hd}-{dtype}": _five_of_both_forms(call, hd, dtype)
+                      for call, hd, dtype in _BIT_CASES}))
