@@ -44,6 +44,18 @@ TPU-first design notes:
   predicts the token after the next on the shared embedding and head, and
   `transformer_loss` is then main loss + `mtp_weight` x MTP loss from a
   batch of S + 2 ids.
+- A layer may be one residual branch alone (PR 43, Nemotron-H's): `mixer`
+  "none" is a layer that is a feed-forward behind its one norm, `ffn`
+  "none" a layer that is a mixer behind its one, and such a layer has that
+  branch's norm leaf and no other. The Mamba-2 mixer is the fourth
+  (`mixer="mamba2"`, `ssm_dims`, `_mamba2_mixer`): one fused projection to
+  [z | x B C | dt], a causal depthwise convolution with a bias, a selective
+  step Delta = softplus(dt + dt_bias) and the diagonal state-space
+  recurrence H_t = exp(Delta_t A) H_{t-1} + Delta_t x_t B_t^T, y_t = H_t C_t
+  + D x_t (`ops.ssm_scan`, the chunked scan's second rule), a norm over
+  groups of features behind the gate silu(z), W_out. `positions` "none" adds
+  no position signal anywhere, and `expert_act` "relu2" makes the routed
+  experts and the shared expert two matrices, W_down (relu(W_up x))^2.
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -76,11 +88,15 @@ class TransformerConfig:
     max_seq: int = 512
     dtype: Any = jnp.bfloat16
     # what the layer is; every default is the repo's own block
-    positions: str = "learned"  # or "rope": rotate-half over the whole head
+    # "learned", "rope" (rotate-half over the whole head) or "none": no
+    # position signal of any kind
+    positions: str = "learned"
     rope_theta: float = 10000.0
     qk_norm: bool = False  # RMSNorm over all of q and of k, before the heads split
     norm_eps: float = 1e-6
-    ffn: str = "gelu"  # "gelu" (w_in, w_out) | "swiglu" (gated silu) | "moe"
+    # "gelu" (w_in, w_out) | "swiglu" (gated silu) | "moe" | "none": the
+    # layer is its mixer alone, behind its one norm
+    ffn: str = "gelu"
     n_experts: int = 0  # ffn == "moe": experts of width d_ff, gated silu
     top_k: int = 0  # experts a token; raw softmax probabilities gate them
     router_aux_coef: float = 0.0  # x load-balancing loss, added to the loss
@@ -112,10 +128,15 @@ class TransformerConfig:
     # the layer's token mixer: softmax "attention" over the fields above, or
     # "gated_delta", the gated delta rule (`ops.gated_delta`) over
     # `delta_heads` = (key heads, value heads, head size) behind a causal
-    # depthwise convolution of `conv_taps` taps
+    # depthwise convolution of `conv_taps` taps; "mamba2", the Mamba-2
+    # state-space mixer (`ops.ssm_scan`) over `ssm_dims` = (heads, head size,
+    # state size, groups of heads that share B and C), its convolution with
+    # a bias; or "none": the layer is its feed-forward alone, behind its one
+    # norm
     mixer: str = "attention"
     delta_heads: Tuple = ()
     conv_taps: int = 4
+    ssm_dims: Tuple = ()
     norm_offset: bool = False  # every RMSNorm's scale is 1 + w, w from 0
     # with wq, wk, wv of their own (`split_qkv`), `qk_norm` is an RMSNorm a
     # head over the head size, q's and k's scales (head size,) each.
@@ -131,6 +152,10 @@ class TransformerConfig:
     # a leaf `router_bias` (n_experts,) added to the scores for the choice
     # and not for the weight; the loss is constant in it
     router_bias: bool = False
+    # the routed experts' function, and the shared expert's: "swiglu", three
+    # matrices, w_down (silu(w_gate x) * w_up x), or "relu2", two, w_down
+    # (relu(w_up x))^2
+    expert_act: str = "swiglu"
     # multi-token prediction (DeepSeek-V3's, section 2.2): modules after the
     # stack (0 or 1), each one further block of the last layer's kind, and
     # the weight of their loss beside the main one
@@ -142,11 +167,13 @@ class TransformerConfig:
 
     def __post_init__(self):
         for field, value, known in (
-                ("positions", self.positions, ("learned", "rope")),
-                ("ffn", self.ffn, ("gelu", "swiglu", "moe")),
+                ("positions", self.positions, ("learned", "rope", "none")),
+                ("ffn", self.ffn, ("gelu", "swiglu", "moe", "none")),
+                ("expert_act", self.expert_act, ("swiglu", "relu2")),
                 ("attn_core", self.attn_core, ("dense", "flash")),
                 ("gates", self.gates, ("raw", "renorm")),
-                ("mixer", self.mixer, ("attention", "gated_delta", "latent")),
+                ("mixer", self.mixer, ("attention", "gated_delta", "latent",
+                                       "mamba2", "none")),
                 ("router_scores", self.router_scores, ("softmax", "sigmoid"))):
             if value not in known:
                 raise ValueError(f"{field} {value!r} is not one of {known}")
@@ -165,6 +192,15 @@ class TransformerConfig:
             raise ValueError("mixer 'gated_delta' needs delta_heads = (key "
                              "heads, value heads a multiple of them, head "
                              f"size), got {self.delta_heads}")
+        if self.mixer == "none" and self.ffn == "none":
+            raise ValueError("a layer is a mixer, a feed-forward or both: "
+                             "mixer 'none' with ffn 'none' is no layer")
+        if self.mixer == "mamba2" and not (
+                len(self.ssm_dims) == 4 and min(self.ssm_dims) >= 1
+                and self.ssm_dims[0] % self.ssm_dims[3] == 0):
+            raise ValueError("mixer 'mamba2' needs ssm_dims = (heads, head "
+                             "size, state size, groups that divide the "
+                             f"heads), got {self.ssm_dims}")
         if self.mixer == "latent":
             if not (len(self.latent_dims) == 5 and min(self.latent_dims) >= 1
                     and self.latent_dims[3] % 2 == 0):
@@ -297,11 +333,38 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
             gk = jax.random.split(jax.random.fold_in(key, 2), 6)
         if cfg.mixer == "latent" or cfg.router_bias:  # PR 41's, a fourth
             mk = jax.random.split(jax.random.fold_in(key, 3), 5)
-        layer = {
-            "ln1_scale": unit(cfg, (D,)),
-            "ln2_scale": unit(cfg, (D,)),
-        }
-        if cfg.mixer == "gated_delta":
+        # a layer of one branch has that branch's norm alone (PR 43)
+        layer = {}
+        if cfg.mixer != "none":
+            layer["ln1_scale"] = unit(cfg, (D,))
+        if cfg.ffn != "none":
+            layer["ln2_scale"] = unit(cfg, (D,))
+        if cfg.mixer == "none":
+            pass
+        elif cfg.mixer == "mamba2":
+            H, hp, N, G = cfg.ssm_dims
+            K, conv = cfg.conv_taps, H * hp + 2 * G * N
+            sk = jax.random.split(jax.random.fold_in(key, 4), 5)  # a fifth
+            # Mamba-2's own start (its `time_step_min`, `_max`, `_floor` and
+            # `A_init_range`): A uniform on [1, 16], the step log-uniform on
+            # [0.001, 0.1] and at least 1e-4, dt_bias its inverse softplus, D
+            # 1; taps and bias as a depthwise Conv1d's default, uniform within
+            # 1 / sqrt(K)
+            dt = jnp.maximum(jnp.exp(jax.random.uniform(
+                sk[3], (H,), jnp.float32, math.log(0.001), math.log(0.1))), 1e-4)
+            layer.update(
+                w_ssm_in=dense(sk[0], (D, H * hp + conv + H)),
+                conv_w=jax.random.uniform(sk[1], (K, conv), jnp.float32,
+                                          -K ** -0.5, K ** -0.5),
+                conv_b=jax.random.uniform(sk[2], (conv,), jnp.float32,
+                                          -K ** -0.5, K ** -0.5),
+                A_log=jnp.log(jax.random.uniform(sk[4], (H,), jnp.float32,
+                                                 1.0, 16.0)),
+                dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+                D_skip=jnp.ones((H,), jnp.float32),
+                ssm_norm_scale=jnp.ones((H * hp,), jnp.float32),
+                wo=dense(lk[1], (H * hp, D)))
+        elif cfg.mixer == "gated_delta":
             Hk, Hv, d = cfg.delta_heads
             K = cfg.conv_taps
             # the decay's parameters as the Gated DeltaNet reference
@@ -350,13 +413,15 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
             layer["wo"] = dense(lk[1], (D, D))
         if cfg.head_gate and cfg.mixer == "attention":
             layer["w_head_gate"] = dense(xk[2], (D, cfg.n_heads))
+        gated = cfg.ffn == "swiglu" or cfg.expert_act == "swiglu"
         if cfg.ffn == "gelu":
             layer["w_in"] = dense(lk[2], (D, F))
             layer["w_out"] = dense(lk[3], (F, D))
-        else:
+        elif cfg.ffn != "none":
             held = cfg.experts_held[1] if cfg.experts_held else E
             stack = (held,) if cfg.ffn == "moe" else ()
-            layer["w_gate"] = dense(lk[2], stack + (D, F))
+            if gated:
+                layer["w_gate"] = dense(lk[2], stack + (D, F))
             layer["w_up"] = dense(lk[3], stack + (D, F))
             layer["w_down"] = dense(lk[4], stack + (F, D))
         if cfg.ffn == "moe":
@@ -368,7 +433,8 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
                 layer["router_bias"] = 0.01 * jax.random.normal(
                     mk[4], (E,), jnp.float32)
             if cfg.shared_ff:
-                layer["shared_gate"] = dense(xk[3], (D, cfg.shared_ff))
+                if gated:
+                    layer["shared_gate"] = dense(xk[3], (D, cfg.shared_ff))
                 layer["shared_up"] = dense(xk[4], (D, cfg.shared_ff))
                 layer["shared_down"] = dense(xk[5], (cfg.shared_ff, D))
             if cfg.shared_gate:
@@ -416,7 +482,10 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     Column-parallel wqkv (or wq, wk, wv and the head gate)/w_in/w_gate/w_up
     (shard output features over tp), row-parallel wo/w_out/w_down (shard
     input features over tp), a shared expert like a gated-silu
-    feed-forward; embedding and an untied head sharded over vocab; an
+    feed-forward (two matrices each under `expert_act` "relu2"); a layer of
+    one branch has that branch's leaves alone; a Mamba-2 mixer's fused
+    projection, taps, bias and gated norm are column-parallel, its numbers a
+    head whole; embedding and an untied head sharded over vocab; an
     expert stack over `ep_axis` on its expert dimension, the router whole.
     Layer-stacked leaves have a leading layer axis (unsharded); a
     configuration with `layer_kinds` has a tuple of such stacks. The q/k
@@ -431,12 +500,22 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     t, e = tp_axis, ep_axis
 
     def stack_specs(cfg):
-        layers = {
-            "ln1_scale": P(None),
-            "ln2_scale": P(None),
-            "wo": P(None, t, None),
-        }
-        if cfg.mixer == "gated_delta":
+        layers = {}
+        if cfg.mixer != "none":
+            layers.update(ln1_scale=P(None), wo=P(None, t, None))
+        if cfg.ffn != "none":
+            layers.update(ln2_scale=P(None))
+        if cfg.mixer == "none":
+            pass
+        elif cfg.mixer == "mamba2":
+            # the fused projection's, the convolution's and the gated norm's
+            # channels over tp like any column-parallel matrix's; a number a
+            # head whole
+            layers.update(w_ssm_in=P(None, None, t), conv_w=P(None, None, t),
+                          conv_b=P(None, t), A_log=P(None, None),
+                          dt_bias=P(None, None), D_skip=P(None, None),
+                          ssm_norm_scale=P(None, t))
+        elif cfg.mixer == "gated_delta":
             # the fused projection's and the convolution's channels over tp
             # like any column-parallel matrix; a number a head and the
             # norm's scale whole
@@ -461,16 +540,19 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
         elif cfg.ffn == "swiglu":
             layers.update(w_gate=P(None, None, t), w_up=P(None, None, t),
                           w_down=P(None, t, None))
-        else:
-            layers.update(w_gate=P(None, e, None, t), w_up=P(None, e, None, t),
-                          w_down=P(None, e, t, None),
+        elif cfg.ffn == "moe":
+            gated = cfg.expert_act == "swiglu"
+            layers.update(w_up=P(None, e, None, t), w_down=P(None, e, t, None),
                           router=P(None, None, None))
+            if gated:
+                layers.update(w_gate=P(None, e, None, t))
             if cfg.router_bias:
                 layers.update(router_bias=P(None, None))
             if cfg.shared_ff:
-                layers.update(shared_gate=P(None, None, t),
-                              shared_up=P(None, None, t),
+                layers.update(shared_up=P(None, None, t),
                               shared_down=P(None, t, None))
+                if gated:
+                    layers.update(shared_gate=P(None, None, t))
             if cfg.shared_gate:
                 layers.update(w_shared_gate=P(None, None, None))
         if cfg.qk_norm and cfg.mixer == "attention":
@@ -644,6 +726,13 @@ def _silu_gate_out(gate, up, w_down):
     """(silu(gate) * up) @ w_down. Keeps gate, up and w_down; the silu and
     the product are recomputed, as `_gelu_out` recomputes its gelu."""
     return (jax.nn.silu(gate) * up) @ w_down
+
+
+@_recompute
+def _relu2_out(up, w_down):
+    """relu(up)^2 @ w_down. Keeps up and w_down; the square, the matmul's
+    operand, is recomputed, as `_gelu_out` recomputes its gelu."""
+    return jnp.square(jax.nn.relu(up)) @ w_down
 
 
 def attention_core_of(cfg: TransformerConfig):
@@ -925,6 +1014,66 @@ def _gated_delta_mixer(h, layer, cfg: TransformerConfig):
     return out.astype(h.dtype)
 
 
+def _grouped_gated_norm(y, z, scale, groups: int, eps):
+    """rms(y * silu(z)) * scale, the gate first and the mean square over each
+    of `groups` equal groups of the last axis, in float32, the result in z's
+    type (Mamba-2's `RMSNormGated` with `norm_before_gate` false). Under its
+    checkpoint (`_grouped_gated_norm_kept`) it keeps y, z and the scale."""
+    f32 = jnp.float32
+    t = y.astype(f32) * jax.nn.silu(z.astype(f32))
+    t = t.reshape(t.shape[:-1] + (groups, -1))
+    t = t * jax.lax.rsqrt(jnp.mean(jnp.square(t), axis=-1, keepdims=True) + eps)
+    return (t.reshape(y.shape) * scale.astype(f32)).astype(z.dtype)
+
+
+_grouped_gated_norm_kept = _recompute(_grouped_gated_norm, static_argnums=(3, 4))
+
+
+def _mamba2_mixer(h, layer, cfg: TransformerConfig):
+    """The Mamba-2 mixer on normed hidden states h (B, S, D): H heads of P
+    features, a state of N a feature, G groups of H / G heads that share B
+    and C (`ssm_dims`). [z | x B C | dt] = h W_in (H P + (H P + 2 G N) + H
+    columns); [x | B | C] through the causal convolution with its bias and a
+    silu; the step Delta = softplus(dt + dt_bias) and the log decay g = Delta
+    A, A = -exp(A_log), a number a head and position, float32 from a float32
+    projection as the router's is; the state-space recurrence (`ops.ssm_scan`)
+    with q = C, k = B (a group's, never repeated a head) and v = Delta x;
+    + D x; the gate silu(z) and then an RMSNorm over each group's features;
+    W_out. Scopes `ssm_proj`, `ssm_conv`, `ssm_core`, `ssm_norm`."""
+    from kungfu_tpu.ops.ssm_scan import CHUNK, causal_conv_bias, ssm_scan
+
+    H, hp, N, G = cfg.ssm_dims
+    inner, bc = H * hp, G * N
+    dt, f32 = cfg.dtype, jnp.float32
+    B, S, _ = h.shape
+    w_in = layer["w_ssm_in"]
+    with jax.named_scope("ssm_proj"):
+        zxbc = h @ w_in[:, :2 * inner + 2 * bc].astype(dt)
+        z, xbc = zxbc[..., :inner], zxbc[..., inner:]
+        step = jnp.dot(h.astype(f32), w_in[:, 2 * inner + 2 * bc:].astype(f32),
+                       precision=jax.lax.Precision.HIGHEST)  # (B, S, H)
+    with jax.named_scope("ssm_conv"):
+        xbc = jax.nn.silu(causal_conv_bias(xbc, layer["conv_w"], layer["conv_b"]))
+        x = xbc[..., :inner].reshape(B, S, H, hp)
+        b, c = (xbc[..., at:at + bc].reshape(B, S, G, N).transpose(0, 2, 1, 3)
+                for at in (inner, inner + bc))
+        delta = jax.nn.softplus(step + layer["dt_bias"].astype(f32))
+        g = (delta * -jnp.exp(layer["A_log"].astype(f32))).transpose(0, 2, 1)
+        v = (x.astype(f32) * delta[..., None]).astype(dt).transpose(0, 2, 1, 3)
+    with jax.named_scope("ssm_core"):
+        # the published chunk, or the largest power of two under it that
+        # divides a shorter sequence: the result does not depend on it
+        o = ssm_scan(c, b, v, g, math.gcd(S, CHUNK))  # (B, H, S, hp)
+    with jax.named_scope("ssm_norm"):
+        y = (o.transpose(0, 2, 1, 3).astype(f32)
+             + layer["D_skip"].astype(f32)[:, None] * x.astype(f32))
+        norm = _grouped_gated_norm if cfg.layer_remat else _grouped_gated_norm_kept
+        y = norm(y.astype(dt).reshape(B, S, inner), z,
+                 layer["ssm_norm_scale"], G, cfg.norm_eps)
+    with jax.named_scope("ssm_proj"):
+        return y @ layer["wo"].astype(dt)
+
+
 def _scale(w, cfg: TransformerConfig):
     """A norm's scale from its weight: the weight, or 1 + it."""
     return 1.0 + w if cfg.norm_offset else w
@@ -934,23 +1083,29 @@ def _expert_layer(h, layer, cfg: TransformerConfig):
     """The expert layer on normed tokens h (T, D) -> (y (T, D), aux): the
     routed experts held here through `ops.moe.moe_ffn`, and the shared
     expert where the configuration has one, behind its sigmoid gate where it
-    has that."""
-    from kungfu_tpu.ops.moe import (moe_ffn, raw_gates, renormalised_gates,
-                                    scaled, swiglu_experts)
+    has that; both gated silu or two-matrix relu^2 (`expert_act`)."""
+    from kungfu_tpu.ops.moe import (moe_ffn, raw_gates, relu2_experts,
+                                    renormalised_gates, scaled, swiglu_experts)
 
     dt = cfg.dtype
     gates = raw_gates if cfg.gates == "raw" else renormalised_gates
+    gated = cfg.expert_act == "swiglu"
     y, aux = moe_ffn(
         h, layer["router"],
-        (layer["w_gate"], layer["w_up"], layer["w_down"]),
+        tuple(layer[w] for w in (("w_gate", "w_up", "w_down") if gated
+                                 else ("w_up", "w_down"))),
         top_k=cfg.top_k, gates=scaled(gates, cfg.routed_scale),
-        expert_fn=swiglu_experts, held=cfg.experts_held or None,
-        scores=cfg.router_scores,
+        expert_fn=swiglu_experts if gated else relu2_experts,
+        held=cfg.experts_held or None, scores=cfg.router_scores,
         bias=layer["router_bias"] if cfg.router_bias else None)
     if cfg.shared_ff:
         with jax.named_scope("moe_shared"):
-            shared = _silu_gate_out(h @ layer["shared_gate"].astype(dt),
-                                    h @ layer["shared_up"].astype(dt),
+            if gated:
+                shared = _silu_gate_out(h @ layer["shared_gate"].astype(dt),
+                                        h @ layer["shared_up"].astype(dt),
+                                        layer["shared_down"].astype(dt))
+            else:
+                shared = _relu2_out(h @ layer["shared_up"].astype(dt),
                                     layer["shared_down"].astype(dt))
             if cfg.shared_gate:
                 shared = shared * jax.nn.sigmoid(
@@ -961,10 +1116,18 @@ def _expert_layer(h, layer, cfg: TransformerConfig):
 
 
 def _layer(x, layer, cfg: TransformerConfig, core=None):
-    """One layer -> (x, aux): aux is the expert layer's `ops.moe.MoeAux`
-    (router losses and token-choices per expert), None of any other."""
+    """One layer -> (x, aux): a mixer and a feed-forward, each a residual
+    branch behind its own norm, or one of the two alone. aux is the expert
+    layer's `ops.moe.MoeAux` (router losses and token-choices per expert),
+    None of any other."""
     dt, eps = cfg.dtype, cfg.norm_eps
-    if cfg.mixer == "gated_delta":
+    if cfg.mixer == "none":
+        pass
+    elif cfg.mixer == "mamba2":
+        with jax.named_scope("ssm"):
+            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
+            x = x + _mamba2_mixer(h, layer, cfg)
+    elif cfg.mixer == "gated_delta":
         with jax.named_scope("gdn"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
             x = x + _gated_delta_mixer(h, layer, cfg)
@@ -984,6 +1147,8 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
                                cfg, core=core, qk_scales=scales,
                                w_head_gate=(layer["w_head_gate"].astype(dt)
                                             if cfg.head_gate else None))
+    if cfg.ffn == "none":
+        return x, None
     if cfg.ffn == "moe":
         with jax.named_scope("moe"):
             B, S, D = x.shape

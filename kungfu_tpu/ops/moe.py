@@ -26,7 +26,10 @@ SURVEY §2.4). `moe_ffn` is one function for both layouts:
 
 The gate rule and the expert function are the caller's: `switch_gates`
 (top-1 raw, top-k renormalised) with `gelu_experts` by default, `raw_gates`
-with `swiglu_experts` for OLMoE. So is the router's rule (`route`, PR 41):
+with `swiglu_experts` for OLMoE, renormalised and scaled gates with
+`relu2_experts` (two matrices, w_down (relu(w_up x))^2, their widths filled
+with zeros to what the grouped matmul runs well at) for Nemotron-H. So is
+the router's rule (`route`, PR 41):
 the scores are a softmax over the experts or a sigmoid an expert, and a
 selection bias an expert may be added for the choice and not for the weight
 (GLM-4.7-Flash, after DeepSeek-V3). `switch_moe` (top-1, one expert per
@@ -112,6 +115,42 @@ def swiglu_experts(rows, experts, group_sizes):
     gate = lax.ragged_dot(rows, w_gate, group_sizes)
     up = lax.ragged_dot(rows, w_up, group_sizes)
     return _silu_gate_down(gate, up, w_down, group_sizes)
+
+
+@functools.partial(jax.checkpoint, prevent_cse=False)
+def _relu2_down(up, w_down, group_sizes):
+    """relu(up)^2 @ w_down over the groups. Keeps up and w_down; the square,
+    the matmul's operand, is recomputed, as `_silu_gate_down`'s is."""
+    return lax.ragged_dot(jnp.square(jax.nn.relu(up)), w_down, group_sizes)
+
+
+GROUPED_WIDTH = 512  # `relu2_experts` feeds the grouped matmul multiples of it
+
+
+def _to_width(a, axis: int):
+    """`a` with zeros after it along `axis`, up to the next multiple of
+    `GROUPED_WIDTH`."""
+    pad = -a.shape[axis] % GROUPED_WIDTH
+    if not pad:
+        return a
+    return jnp.pad(a, [(0, pad if i == axis else 0) for i in range(a.ndim)])
+
+
+def relu2_experts(rows, experts, group_sizes):
+    """Two-matrix squared-relu experts (Nemotron-H's): experts = (w_up (e, D,
+    F), w_down (e, F, D)); y = w_down (relu(w_up x))^2, no gate matrix. The
+    grouped matmul is fed widths that are multiples of `GROUPED_WIDTH`, D
+    and F filled with zeros where the weights are cast (relu(0)^2 = 0 meets
+    zero rows of w_down, and the output's further columns are cut): XLA's
+    grouped matmul on the TPU takes 2.0 ms for 3,072 rows of 2,688 x 1,856
+    and 0.7 for 2,688 x 2,048, and a share's layer, forward and backward,
+    50.1 ms at 2,688 and 1,856 and 24.2 at 3,072 and 2,048, to the last bit
+    the same numbers (PERF.md, PR 43)."""
+    D = rows.shape[-1]
+    w_up, w_down = (_to_width(_to_width(w.astype(rows.dtype), 1), 2)
+                    for w in experts)
+    up = lax.ragged_dot(_to_width(rows, 1), w_up, group_sizes)
+    return _relu2_down(up, w_down, group_sizes)[:, :D]
 
 
 @jax.custom_vjp
@@ -204,7 +243,7 @@ def _all_in_one(top_k: int, chunk: int, T: int, sizes) -> bool:
 
 
 def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
-                sizes, i):
+                sizes, i, whole: bool = False):
     """What rows i * chunk to (i + 1) * chunk of a share's row order add to
     the layer: (T, D) float32. Rows past the last group are token-choices
     that fell elsewhere: they go in as zeros and weigh nothing, in both
@@ -213,7 +252,8 @@ def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
     that can fall here (`_all_in_one`) those rows are the last group's: the
     grouped matmul costs what its groups hold (5.8 to 16.6 ms a step with
     the rows that came: PERF.md, PR 41), and there the cost is to be the
-    buffer's whatever came."""
+    buffer's whatever came. So it is in every chunk that runs `whole`
+    (`moe_ffn`: a share under a selection bias)."""
     T, D = x.shape
     ends = jnp.cumsum(sizes)
     lo = i * chunk
@@ -225,6 +265,8 @@ def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
             - jnp.clip(ends - sizes, lo, lo + chunk))
     if _all_in_one(top_k, chunk, T, sizes):
         here = here.at[-1].add(chunk - ends[-1])
+    elif whole:
+        here = here.at[-1].add(lo + chunk - jnp.clip(ends[-1], lo, lo + chunk))
     with jax.named_scope("moe_dispatch"):
         rows = jnp.where(live, x[token], 0)
     with jax.named_scope("moe_experts"):
@@ -244,40 +286,44 @@ def _live_chunks(top_k: int, chunk: int, T: int, sizes):
     return (jnp.sum(sizes) + chunk - 1) // chunk
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _held_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
-               sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _held_part(expert_fn, top_k: int, chunk: int, whole: bool, x, gate,
+               experts, order, sizes):
     """The held experts' part of the layer, -> out (T, D). `order` lists the
     token-choices with those for the held experts first, group by group
     (`sizes` (held,) of them an expert). Dropless whatever the load: the
     rows are taken `chunk` at a time, as many chunks as the groups fill
     (`_live_chunks`), up to all T * min(top_k, held) rows that can fall
     here. So the work is
-    that of the rows that came, and the memory that of one chunk (PERF.md,
-    PR 33).
+    that of the rows that came (of the chunks they fill, where a chunk runs
+    `whole`: `_chunk_part`), and the memory that of one chunk (PERF.md, PR
+    33).
     The loop's length is data, so the backward pass is written out: the
     same loop, each chunk run again and transposed (nothing is kept but the
     arguments), its cotangents added up in float32."""
     out = lax.fori_loop(
         0, _live_chunks(top_k, chunk, x.shape[0], sizes),
         lambda i, out: out + _chunk_part(expert_fn, top_k, chunk, x, gate,
-                                         experts, order, sizes, i),
+                                         experts, order, sizes, i, whole),
         jnp.zeros(x.shape, jnp.float32))
     return out.astype(x.dtype)
 
 
-def _held_part_fwd(expert_fn, top_k, chunk, x, gate, experts, order, sizes):
-    return (_held_part(expert_fn, top_k, chunk, x, gate, experts, order, sizes),
+def _held_part_fwd(expert_fn, top_k, chunk, whole, x, gate, experts, order,
+                   sizes):
+    return (_held_part(expert_fn, top_k, chunk, whole, x, gate, experts, order,
+                       sizes),
             (x, gate, experts, order, sizes))
 
 
-def _held_part_bwd(expert_fn, top_k, chunk, res, g):
+def _held_part_bwd(expert_fn, top_k, chunk, whole, res, g):
     x, gate, experts, order, sizes = res
     g = g.astype(jnp.float32)
 
     def one(i, sums):
         _, transpose = jax.vjp(
-            lambda *a: _chunk_part(expert_fn, top_k, chunk, *a, order, sizes, i),
+            lambda *a: _chunk_part(expert_fn, top_k, chunk, *a, order, sizes, i,
+                                   whole),
             x, gate, experts)
         return jax.tree.map(lambda s, d: s + d.astype(jnp.float32), sums,
                             transpose(g))
@@ -336,7 +382,9 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
 
     `scores` and `bias` are the router's rule (`route`): softmax or sigmoid
     scores, and a selection bias (E,) that moves the choice alone; `gates`
-    sees the chosen experts' scores."""
+    sees the chosen experts' scores. A share (`held`) under a bias computes
+    every chunk its rows reach whole, the rows of no group as zeros in the
+    last group: its cost is its chunks', not its rows' (PERF.md, PR 43)."""
     if top_k < 1:
         raise ValueError(f"top_k must be at least 1, got {top_k}")
     T, D = x.shape
@@ -384,8 +432,14 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
             # every chunk of the rows that can fall here lies inside `order`
             n = -(-T * min(top_k, count) // chunk) * chunk
             order = jnp.pad(order, (0, max(0, n - T * top_k)))
-            out = _held_part(expert_fn, top_k, chunk, x, gate, experts, order,
-                             sizes)
+            # Under a selection bias the layer's balance is a step's that
+            # no gradient makes and this program does not run (ROADMAP
+            # S19): from the initial parameters such routers send a share
+            # a third of the balanced load or three times it within a few
+            # steps, and the grouped matmul costs the rows its groups hold.
+            # There a live chunk costs its buffer whatever came.
+            out = _held_part(expert_fn, top_k, chunk, bias is not None, x,
+                             gate, experts, order, sizes)
             with jax.named_scope("moe_router"):
                 aux = _aux(logits, probs, counts, top_idx,
                            bias is not None)._replace(counts=sizes)

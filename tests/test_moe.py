@@ -507,3 +507,198 @@ def test_moe_ffn_under_sigmoid_scores_and_a_bias_matches_dense(held):
                         expert_fn=moe.swiglu_experts, held=held,
                         scores="sigmoid")[1]
     assert plain.bias_moved is None
+
+
+# --- two-matrix relu^2 experts (PR 43) ----------------------------------------
+
+def test_relu2_experts_are_two_matrices_over_the_groups():
+    """`relu2_experts`: rows in groups, one group an expert, y = w_down
+    (relu(w_up x))^2 with no gate matrix, values and every gradient against
+    a loop over the experts; rows past the groups' sum give nothing."""
+    from kungfu_tpu.ops import moe
+
+    E, D, F = 4, 16, 8
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    sizes = jnp.asarray([5, 0, 7, 3], jnp.int32)
+    rows = jax.random.normal(ks[0], (20, D))  # 15 in groups, 5 in none
+    w_up = 0.5 * jax.random.normal(ks[1], (E, D, F))
+    w_down = 0.5 * jax.random.normal(ks[2], (E, F, D))
+
+    def by_hand(rows, w_up, w_down):
+        out, at = [], 0
+        for e, n in enumerate(sizes.tolist()):
+            x = rows[at:at + n]
+            out.append(jnp.square(jnp.maximum(x @ w_up[e], 0.0)) @ w_down[e])
+            at += n
+        return jnp.concatenate(out + [jnp.zeros((20 - at, D))])
+
+    got = moe.relu2_experts(rows, (w_up, w_down), sizes)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(by_hand(rows, w_up, w_down)),
+                               rtol=1e-5, atol=1e-6)
+    assert not np.asarray(got[15:]).any()
+    weight = jax.random.normal(jax.random.PRNGKey(1), (20, D))
+    grads = jax.grad(lambda *a: jnp.sum(moe.relu2_experts(a[0], a[1:], sizes) * weight),
+                     (0, 1, 2))(rows, w_up, w_down)
+    wants = jax.grad(lambda *a: jnp.sum(by_hand(*a) * weight), (0, 1, 2))(
+        rows, w_up, w_down)
+    for g, w in zip(grads, wants):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-5)
+    assert not np.asarray(grads[1][1]).any()  # the expert with no row
+    # bfloat16 rows: the weights are met in the rows' type, the result is theirs
+    low = moe.relu2_experts(rows.astype(jnp.bfloat16), (w_up, w_down), sizes)
+    assert low.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(low, np.float32), np.asarray(got),
+                               rtol=0.1, atol=0.05)
+
+
+@pytest.mark.parametrize("held", [None, (4, 4), (8, 8)])
+def test_moe_ffn_with_relu2_experts_matches_dense(held):
+    """The layer under Nemotron-H's rule (sigmoid scores, a selection bias,
+    the chosen renormalised and scaled by 2.5, two-matrix relu^2 experts)
+    against every expert run over every token and weighed by hand: the whole
+    layer, and a share of it."""
+    moe, x, router, experts, gates = _share_setup(E=16)
+    experts = experts[1:]  # (w_up, w_down): no gate matrix
+    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(9), (16,))
+    first, count = held or (0, 16)
+    mine = tuple(w[first:first + count] for w in experts)
+
+    def layer(x, router, mine):
+        return moe.moe_ffn(x, router, mine, top_k=6, gates=gates,
+                           expert_fn=moe.relu2_experts, held=held,
+                           scores="sigmoid", bias=bias)
+
+    def by_hand(x, router, mine):
+        scores = jax.nn.sigmoid(x @ router)
+        idx = jnp.argsort(-(scores + bias), axis=-1)[:, :6]
+        top = jnp.take_along_axis(scores, idx, -1)
+        weights = 2.5 * top / top.sum(-1, keepdims=True)
+        out = jnp.zeros_like(x)
+        for e in range(count):
+            y = jnp.square(jnp.maximum(x @ mine[0][e], 0.0)) @ mine[1][e]
+            out = out + jnp.sum(jnp.where(idx == first + e, weights, 0.0),
+                                -1)[:, None] * y
+        return out, idx
+
+    got, aux = layer(x, router, mine)
+    want, idx = by_hand(x, router, mine)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    assert aux.counts.tolist() == np.bincount(
+        np.asarray(idx).ravel(), minlength=16)[first:first + count].tolist()
+    grads = jax.grad(lambda *a: jnp.sum(layer(*a)[0] ** 2), (0, 1, 2))(x, router, mine)
+    wants = jax.grad(lambda *a: jnp.sum(by_hand(*a)[0] ** 2), (0, 1, 2))(x, router, mine)
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(wants)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=1e-4)
+
+
+def test_relu2_keeps_its_input_and_recomputes_the_square():
+    """As `_silu_gate_down`: the backward pass keeps `up` and `w_down`, not
+    the squared activation, the matmul's operand."""
+    from kungfu_tpu.ops import moe
+
+    sizes = jnp.asarray([8, 8], jnp.int32)
+    up = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
+    w_down = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 4))
+    _, vjp = jax.vjp(lambda up, w: moe._relu2_down(up, w, sizes), up, w_down)
+    kept = [leaf for leaf in jax.tree.leaves(vjp) if hasattr(leaf, "shape")]
+    assert sorted(leaf.shape for leaf in kept if leaf.ndim >= 2) == [
+        (2, 8, 4), (16, 8)]
+
+
+@pytest.mark.parametrize("biased", [False, True])
+def test_under_a_selection_bias_a_live_chunk_costs_its_buffer(biased):
+    """A share of less than an eighth keeps its chunks and its loop. Under a
+    selection bias (the layer's balance is a step's that this program does
+    not run) the groups handed to the experts fill every chunk the rows
+    reach, the rows of no group in the last one, zeros that weigh nothing;
+    without one the groups are the rows that came. Values and gradients are
+    the same either way."""
+    moe, x, router, experts, gates = _share_setup(E=64)
+    chunk = moe._share_chunk(48, 3, 4, 64)
+    assert chunk < 48 * 3 and not moe._all_in_one(3, chunk, 48, jnp.zeros(4))
+    mine = tuple(w[4:8] for w in experts)
+    seen = []
+
+    def spy(rows, experts, sizes):
+        seen.append((rows, sizes))
+        return moe.swiglu_experts(rows, experts, sizes)
+
+    order = jnp.arange(48 * 3, dtype=jnp.int32)
+    for sizes in ([5, 0, 7, 2], [0, 0, 0, 0], [chunk, 3, 0, chunk - 7]):
+        sizes = jnp.array(sizes, jnp.int32)
+        for i in range(-(-int(sizes.sum()) // chunk) or 1):
+            moe._chunk_part(spy, 3, chunk, x, jnp.ones((48, 3)), mine, order,
+                            sizes, i, biased)
+            rows, groups = seen.pop()
+            live = int(np.clip(int(sizes.sum()) - i * chunk, 0, chunk))
+            assert float(jnp.abs(rows[live:]).max(initial=0.0)) == 0.0
+            assert int(groups.sum()) == (chunk if biased else live)
+            # each group's part of this chunk, but for the last group's filling
+            assert groups[:3].tolist() == jnp.diff(
+                jnp.clip(jnp.cumsum(sizes), i * chunk, (i + 1) * chunk),
+                prepend=i * chunk)[:3].tolist()
+    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(9), (64,))
+
+    def part(x, router, mine, bias):
+        return moe.moe_ffn(x, router, mine, top_k=3, gates=gates,
+                           expert_fn=moe.swiglu_experts, held=(4, 4),
+                           scores="sigmoid", bias=bias)[0]
+
+    def by_hand(x, router, mine, bias):
+        scores = jax.nn.sigmoid(x @ router)
+        idx = jnp.argsort(-(scores + bias), axis=-1)[:, :3]
+        top = jnp.take_along_axis(scores, idx, -1)
+        weights = 2.5 * top / top.sum(-1, keepdims=True)
+        out = jnp.zeros_like(x)
+        for e in range(4):
+            y = (jax.nn.silu(x @ mine[0][e]) * (x @ mine[1][e])) @ mine[2][e]
+            out = out + jnp.sum(jnp.where(idx == 4 + e, weights, 0.0), -1)[:, None] * y
+        return out
+
+    assert "while" in str(jax.make_jaxpr(part)(x, router, mine, bias))
+    np.testing.assert_allclose(np.asarray(part(x, router, mine, bias)),
+                               np.asarray(by_hand(x, router, mine, bias)),
+                               rtol=1e-4, atol=1e-5)
+    grads = jax.grad(lambda *a: jnp.sum(part(*a) ** 2), (0, 2))(x, router, mine, bias)
+    wants = jax.grad(lambda *a: jnp.sum(by_hand(*a) ** 2), (0, 2))(x, router, mine, bias)
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(wants)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=1e-4)
+
+
+def test_relu2_experts_feed_the_grouped_matmul_whole_widths(monkeypatch):
+    """D and F go into `lax.ragged_dot` filled with zeros to multiples of
+    `GROUPED_WIDTH` (3,072 and 2,048 for Nemotron-3-Nano's 2,688 and 1,856),
+    and the result is the unfilled one's to the last bit."""
+    from kungfu_tpu.ops import moe
+
+    assert moe.GROUPED_WIDTH == 512
+    assert [-(-w // 512) * 512 for w in (2688, 1856)] == [3072, 2048]
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    rows = jax.random.normal(ks[0], (12, 10))
+    experts = (jax.random.normal(ks[1], (2, 10, 6)), jax.random.normal(ks[2], (2, 6, 10)))
+    sizes = jnp.asarray([7, 4], jnp.int32)
+
+    def shapes():
+        jaxpr = jax.make_jaxpr(lambda r, e: moe.relu2_experts(r, e, sizes))(rows, experts)
+        return [[v.aval.shape for v in eqn.invars[:2]] for eqn in _all_eqns(jaxpr.jaxpr)
+                if eqn.primitive.name == "ragged_dot_general"]
+
+    monkeypatch.setattr(moe, "GROUPED_WIDTH", 8)
+    filled = moe.relu2_experts(rows, experts, sizes)
+    assert shapes() == [[(12, 16), (2, 16, 8)], [(12, 8), (2, 8, 16)]]
+    assert filled.shape == (12, 10)
+    monkeypatch.setattr(moe, "GROUPED_WIDTH", 1)
+    assert shapes() == [[(12, 10), (2, 10, 6)], [(12, 6), (2, 6, 10)]]
+    np.testing.assert_array_equal(np.asarray(filled),
+                                  np.asarray(moe.relu2_experts(rows, experts, sizes)))
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _all_eqns(sub)

@@ -1,0 +1,122 @@
+"""Each mechanism of Nemotron-3-Nano's layers knocked out in turn (PR 43): the
+float32 program with the fault against the plain reference on the trained-like
+state of `tests/test_nemotron_h.py`, whose helpers these are; every fault has
+to read far over what the bfloat16 program is allowed. A file of its own so
+that the suite's workers share the compiles."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark import harness
+from benchmark.families import nemotron_h as family
+from kungfu_tpu.models import transformer
+from kungfu_tpu.ops import moe, ssm_scan
+from test_nemotron_h import (CONFIG, _reference, _sample, _state,  # noqa: F401
+                             fresh_traces)
+
+_model_config = family.model_config
+
+
+def _as(**changes):
+    return lambda m: m.setattr(
+        family, "model_config",
+        lambda cfg: dataclasses.replace(_model_config(cfg), **changes))
+
+
+def _gated_norm(m, norm):
+    """`norm(y, z, scale, groups, eps)` in the place of the mixer's gated
+    norm, kept or run again."""
+    m.setattr(transformer, "_grouped_gated_norm", norm)
+    m.setattr(transformer, "_grouped_gated_norm_kept", norm)
+
+
+def _gate_after_the_norm(m):
+    def norm_then_gate(y, z, scale, groups, eps):
+        y = y.astype(jnp.float32).reshape(y.shape[:-1] + (groups, -1))
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + eps)
+        return y.reshape(z.shape) * scale * jax.nn.silu(z)
+
+    _gated_norm(m, norm_then_gate)
+
+
+def _norm_over_all_features(m):
+    norm = transformer._grouped_gated_norm
+    _gated_norm(m, lambda y, z, scale, groups, eps: norm(y, z, scale, 1, eps))
+
+
+def _b_and_c_of_the_wrong_group(m):
+    scan = ssm_scan.ssm_scan
+    m.setattr(ssm_scan, "ssm_scan", lambda q, k, v, g, chunk: scan(
+        jnp.roll(q, 1, axis=1), jnp.roll(k, 1, axis=1), v, g, chunk))
+
+
+def _no_softplus(m):
+    m.setattr(transformer.jax.nn, "softplus", lambda x: x)
+
+
+def _expert_function(m, act):
+    """`act(up)` in the place of relu(up)^2, in the routed experts and the
+    shared expert alike."""
+    m.setattr(moe, "_relu2_down", lambda up, w_down, sizes: jax.lax.ragged_dot(
+        act(up), w_down, sizes))
+    m.setattr(transformer, "_relu2_out", lambda up, w_down: act(up) @ w_down)
+
+
+def _bias_in_the_weight(m):
+    def route(x, router_w, top_k, scores="softmax", bias=None):
+        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+        biased = jax.nn.sigmoid(logits) + bias
+        top, idx = jax.lax.top_k(biased, top_k)
+        return logits, biased, top, idx
+
+    m.setattr(moe, "route", route)
+
+
+FAULTS = {
+    "eight_bit_operands": lambda m: None,
+    "no_d_x": lambda m: None,
+    "gate_after_the_norm": _gate_after_the_norm,
+    "norm_over_all_features_and_not_a_group": _norm_over_all_features,
+    "b_and_c_of_the_wrong_group": _b_and_c_of_the_wrong_group,
+    "delta_without_the_softplus": _no_softplus,
+    "a_rotary_pass": _as(positions="rope"),
+    "gated_silu_in_the_experts": lambda m: _expert_function(
+        m, lambda up: jax.nn.silu(up) * up),
+    "relu_in_place_of_relu2": lambda m: _expert_function(m, jax.nn.relu),
+    "bias_in_the_weight": _bias_in_the_weight,
+    "scale_1_in_place_of_2_5": _as(routed_scale=1.0),
+}
+
+
+def _eight_bit(state):
+    """Every matrix rounded to float8_e4m3 (3 mantissa bits): what 8-bit
+    operands do to the matmuls."""
+    return jax.tree.map(
+        lambda w: w.astype(jnp.float8_e4m3fn).astype(w.dtype) if w.ndim >= 2 else w,
+        state)
+
+
+def _no_d_x(state):
+    """D = 0 in every Mamba-2 layer: `y_t = H_t C_t` without `+ D x_t`."""
+    return {**state, "layers": tuple(
+        {**stack, "D_skip": jnp.zeros_like(stack["D_skip"])}
+        if "D_skip" in stack else stack for stack in state["layers"])}
+
+
+STATES = {"eight_bit_operands": _eight_bit, "no_d_x": _no_d_x}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_a_fault_fails_the_familys_tolerance(fault, monkeypatch, fresh_traces):
+    """Each in float32 compute, so that nothing but the fault is in the
+    error: it has to be far over what the bfloat16 program is allowed."""
+    state, sample = _state(), _sample()
+    FAULTS[fault](monkeypatch)
+    program_state = STATES.get(fault, lambda s: s)(state)
+    _, want = _reference()
+    loss, grads = family.program_loss_and_grads(CONFIG)(program_state, sample)
+    error = harness.relative_error(grads, want)
+    assert error > 2 * family.GRAD_RTOL, (fault, error)
