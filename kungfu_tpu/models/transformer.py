@@ -1166,9 +1166,10 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
 
 
 # `layer_remat`: the scan keeps the layer's input and, of what the layer
-# computes, the flash core's output and row sums alone (0.15 GB a layer of
-# 72 heads at 8,192 positions): the projections, the rotation and the
-# feed-forward are run again in the backward pass, the forward kernel is not
+# computes, the flash core's output and row sums alone, whatever the call
+# (0.15 GB a layer of 72 heads of 128 at 8,192 positions, 0.085 GB a layer
+# of 20 heads of 256): the projections, the rotation and the feed-forward
+# are run again in the backward pass, the forward kernel is not
 _layer_again = jax.checkpoint(
     _layer, static_argnums=(2,), prevent_cse=False,
     policy=jax.checkpoint_policies.save_only_these_names("flash_out", "flash_lse"))

@@ -28,7 +28,11 @@ log-sum-exp, and the backward runs two fused Pallas kernels (dq over
 k-blocks; dk/dv over q-blocks) that recompute exact block probabilities
 from it — the standard two-pass flash backward. Neither direction ever
 materializes an (S, S) tensor. A sequence length the blocks do not
-divide is an error, not a dense fallback.
+divide is an error, not a dense fallback. Between the passes every call
+keeps q, k, v, its output and the log-sum-exp as one number a row, the last
+two under the names `flash_out` and `flash_lse`: a `jax.checkpoint` around
+the caller whose policy keeps those names runs everything else again and the
+forward kernel once (`models/transformer._layer_again`; PERF.md, PR 45).
 
 Two things a layer may ask beside the causal mask (ROADMAP D4, PR 33).
 **Grouped heads**: k and v with fewer heads than q, H = g x Hkv; query head
@@ -38,8 +42,8 @@ g query heads of a group inside its sequential sweep, so dk, dv are summed
 in VMEM and written once. **A window**: key j is seen by query i iff
 0 <= i - j < window; every kernel's sweep then covers the blocks that
 touch the band and no others (`_kv_steps`, `_q_steps`: 2 of 16 at blocks
-of 512, window 512 and 8,192 positions). A call with neither is the
-program it was before both.
+of 512, window 512 and 8,192 positions). A call with neither runs the
+kernels it ran before both.
 
 The kernels compile with Mosaic unless the caller passes
 `interpret=True` (the tests, on the CPU mesh); the backend is never
@@ -469,7 +473,7 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
     kf = k.reshape(B * Hkv, S, hd)
     vf = v.reshape(B * Hkv, S, hd)
     gf = g.reshape(B * H, S, hd)
-    lsef = lse  # (B*H, S, 8) straight from the forward kernel
+    lsef = lse  # (B*H, S, 8), as the forward kernel writes it
     deltaf = jnp.broadcast_to(
         delta.reshape(B * H, S)[:, :, None], (B * H, S, 8)
     )
@@ -575,17 +579,15 @@ def _fwd(q, k, v, causal, sm_scale, blk_q, blk_k, interpret, window):
         q, k, v, causal, sm_scale, blk_q, blk_k, interpret, with_lse=True,
         window=window
     )
-    if window is not None or q.shape[1] != k.shape[1]:
-        # kept between the passes as one number a row: the 8 replicated
-        # lanes are padded to 128 in HBM, 302 MB a layer of 72 heads at
-        # 8,192 positions for 2.4 MB of numbers. The plain call keeps what
-        # it kept, text for text.
-        lse = lse[:, :, 0]
-        # names for a checkpoint around the caller that runs the layer
-        # again and keeps these two, so that the forward kernel runs once
-        # (`models/transformer._layer_again`)
-        out = checkpoint_name(out, "flash_out")
-        lse = checkpoint_name(lse, "flash_lse")
+    # kept between the passes as one number a row: the 8 replicated lanes
+    # are padded to 128 in HBM, 302 MB a layer of 72 heads at 8,192
+    # positions for 2.4 MB of numbers (`_bwd` lays them out again)
+    lse = lse[:, :, 0]
+    # names for a checkpoint around the caller that runs the layer again
+    # and keeps these two, so that the forward kernel runs once
+    # (`models/transformer._layer_again`); the identity anywhere else
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
@@ -593,8 +595,7 @@ def _bwd(causal, sm_scale, blk_q, blk_k, interpret, window, res, g):
     q, k, v, o, lse = res
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    if lse.ndim == 2:
-        lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (8,))
+    lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (8,))
     # fused two-pass flash backward kernels (dq, then dk/dv)
     blk_q, blk_k = _blocks(q.shape[2], blk_q, blk_k)
     return _backward_kernels(

@@ -402,6 +402,59 @@ def test_the_block_counters_reach_the_metrics():
         ("kungfu_flash_blocks_masked_total", "forward")] == 3 * 7
 
 
+def _pallas_calls(jaxpr, recomputed=False):
+    """[(kernel's function, inside a checkpoint's recomputed part?)] of every
+    `pallas_call` of a jaxpr, through every equation that holds one."""
+    from kungfu_tpu.telemetry import device
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["jaxpr"].debug_info.func_name, recomputed))
+        for sub in device._sub_jaxprs(eqn):
+            found += _pallas_calls(sub, recomputed or eqn.primitive.name == "remat2")
+    return found
+
+
+# the three kinds of call: (query heads to a key/value head, window)
+@pytest.mark.parametrize("g,window", [(1, None), (3, None), (1, 24)],
+                         ids=["plain", "grouped", "window"])
+def test_a_core_that_is_run_again_runs_its_forward_kernel_once(g, window):
+    """Wrapped as `models/transformer._layer_again` wraps a layer, every
+    kind of call keeps its output and one row sum a row under the policy's
+    two names: the gradient holds the forward kernel once, outside the
+    recomputed part, and is the gradient of the bare call bit for bit."""
+    q, k, v, weigh = _grouped_qkv(64, g)
+
+    def core(q, k, v):
+        return flash_attention(q, k, v, True, None, 16, 16, True, window)
+
+    again = jax.checkpoint(
+        core, prevent_cse=False,
+        policy=jax.checkpoint_policies.save_only_these_names("flash_out", "flash_lse"))
+
+    def out_and_grads(core):
+        def weighed(q, k, v):
+            out = core(q, k, v)
+            return jnp.sum(out * weigh), out
+
+        return jax.value_and_grad(weighed, argnums=(0, 1, 2), has_aux=True)
+
+    jaxpr = jax.make_jaxpr(out_and_grads(again))(q, k, v).jaxpr
+    assert sorted(_pallas_calls(jaxpr)) == [
+        ("_dkv_kernel", True), ("_dq_kernel", True), ("_kernel", False)]
+    kept = {eqn.params["name"]: eqn.outvars[0] for eqn in jaxpr.eqns
+            if eqn.primitive.name == "name"}
+    assert kept["flash_out"].aval.shape == q.shape
+    assert kept["flash_lse"].aval.shape == (q.shape[0] * q.shape[1], q.shape[2])
+    (backward,) = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "remat2"]
+    assert set(kept.values()) <= set(backward.invars)
+    ((_, out), grads), ((_, want_out), want) = (
+        jax.jit(out_and_grads(f))(q, k, v) for f in (again, core))
+    for got, ref in zip((out, *grads), (want_out, *want)):
+        assert got.dtype == ref.dtype and bool(jnp.array_equal(got, ref))
+
+
 if __name__ == "__main__":  # `both_forms_bits`' own process
     import json
 

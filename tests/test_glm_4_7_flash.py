@@ -27,6 +27,7 @@ from kungfu_tpu.models.transformer import (TransformerConfig, init_transformer,
                                            param_pspecs)
 from kungfu_tpu.ops import moe
 from kungfu_tpu.telemetry import metrics
+from test_flash_attention import _pallas_calls
 
 # the cell's stack in small: a dense layer and two expert layers, then the
 # multi-token-prediction module; hidden 64; 4 heads of 24 unrotated + 8
@@ -265,6 +266,29 @@ def test_the_recomputed_layers_change_no_number(recomputed):
     want_loss, want = family.program_loss_and_grads(other)(state, sample)
     assert abs(float(loss) - float(want_loss)) <= 1e-6 * abs(float(want_loss))
     assert harness.relative_error(grads, want) <= 1e-5
+
+
+def test_no_layer_that_is_run_again_runs_its_forward_kernel_again():
+    """The cell's own setting, the expert layers and the module's block run
+    again in the backward pass: latent attention is the flash core's plain
+    call, 4 heads on 4 with no window, and its output and row sums are kept
+    under `_layer_again`'s two names. One forward kernel a scan body and
+    one of the module's block, none in a recomputed part, and every number
+    of the step is the number of the step that keeps its layers."""
+    assert CONFIG["recomputed_layer_types"] == [family.SPARSE]
+    state, sample = _state(), _sample()
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(family.loss_fn(CONFIG)))(
+        state, sample).jaxpr
+    # the dense scan's, the expert scan's and the module's
+    assert [where for kernel, where in _pallas_calls(jaxpr)
+            if kernel == "_kernel"] == [False] * 3
+    kept = tiny_config(recomputed_layer_types=[])
+    loss, grads = family.program_loss_and_grads(CONFIG)(state, sample)
+    want_loss, want = family.program_loss_and_grads(kept)(state, sample)
+    assert float(loss) == float(want_loss)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(want), strict=True):
+        assert bool(jnp.array_equal(g, w)), jax.tree_util.keystr(path)
 
 
 @pytest.mark.parametrize("core", ["flash", "dense"])
