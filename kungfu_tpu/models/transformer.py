@@ -73,6 +73,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from kungfu_tpu.optimizers import core as grad_sync
@@ -990,8 +991,13 @@ def _gated_delta_mixer(h, layer, cfg: TransformerConfig):
     another, each block run again in the backward pass (`_delta_heads`):
     the rule's kernels hold a chunk in VMEM (PR 37; XLA's temporaries were
     0.15 GB a head), but a block still keeps q, k, v, z and the chunks'
-    states, and dropping the blocks needs its own account of memory (ROADMAP
-    S17). Scopes `gdn_proj`, `gdn_conv`, `gdn_core`, `gdn_norm`."""
+    states, and without the blocks' checkpoint the step does not fit the
+    chip (ROADMAP S17). The result carries the name `gdn_mix`, which a layer
+    that is run again keeps (`_layer_again`): the second run of such a layer
+    has no reader for the blocks, so the mixer's forward runs twice a step,
+    in the forward pass and once for each block's gradients, not three
+    times; the identity anywhere else. Scopes `gdn_proj`, `gdn_conv`,
+    `gdn_core`, `gdn_norm`."""
     Hk, Hv, _ = cfg.delta_heads
     r = Hv // Hk
     kb = max(b for b in range(1, Hk + 1)
@@ -1011,7 +1017,7 @@ def _gated_delta_mixer(h, layer, cfg: TransformerConfig):
                                   ).astype(jnp.float32), None
 
     out, _ = jax.lax.scan(one, jnp.zeros(h.shape, jnp.float32), parts)
-    return out.astype(h.dtype)
+    return checkpoint_name(out.astype(h.dtype), "gdn_mix")
 
 
 def _grouped_gated_norm(y, z, scale, groups: int, eps):
@@ -1166,13 +1172,17 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
 
 
 # `layer_remat`: the scan keeps the layer's input and, of what the layer
-# computes, the flash core's output and row sums alone, whatever the call
-# (0.15 GB a layer of 72 heads of 128 at 8,192 positions, 0.085 GB a layer
-# of 20 heads of 256): the projections, the rotation and the feed-forward
-# are run again in the backward pass, the forward kernel is not
+# computes, the flash core's output and row sums, whatever the call (0.15 GB
+# a layer of 72 heads of 128 at 8,192 positions, 0.085 GB a layer of 20
+# heads of 256), and a Gated DeltaNet mixer's output (`gdn_mix`: 0.067 GB a
+# layer of 16,384 positions of 2,048): the projections, the rotation and the
+# feed-forward are run again in the backward pass, the forward kernel and
+# the DeltaNet mixer's head blocks are not (the blocks run their forward
+# once more for their own gradients, `_delta_heads`: twice a step in all)
 _layer_again = jax.checkpoint(
     _layer, static_argnums=(2,), prevent_cse=False,
-    policy=jax.checkpoint_policies.save_only_these_names("flash_out", "flash_lse"))
+    policy=jax.checkpoint_policies.save_only_these_names(
+        "flash_out", "flash_lse", "gdn_mix"))
 
 
 def _block(x, layer, cfg: TransformerConfig, core=None):

@@ -9,6 +9,7 @@ in `tests/test_qwen3_next_faults.py`."""
 
 import copy
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,8 @@ from benchmark.reference import qwen3_next as ref
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import (TransformerConfig, init_transformer,
                                            param_pspecs)
+import test_glm_4_7_flash
+import test_laguna_layers
 
 CELL = "qwen3_next_80b_a3b.ssgd_longseq_1chip"
 # one period as the cell's: three Gated DeltaNet layers (2 key and 4 value
@@ -210,6 +213,69 @@ def test_the_mixers_head_blocks_change_no_number(block, monkeypatch, fresh_trace
     want_loss, want = family.program_loss_and_grads(CONFIG)(state, sample)
     assert abs(float(loss) - float(want_loss)) <= 1e-6 * abs(float(want_loss))
     assert harness.relative_error(grads, want) <= 1e-4
+
+
+def _layer_again_keeping(*names):
+    """`models/transformer._layer_again` with a policy of these names."""
+    return jax.checkpoint(
+        transformer._layer, static_argnums=(2,), prevent_cse=False,
+        policy=jax.checkpoint_policies.save_only_these_names(*names))
+
+
+def test_a_layer_run_again_runs_its_mixers_forward_twice_a_step(monkeypatch,
+                                                                fresh_traces):
+    """`layer_remat` keeps the mixer's output under the name `gdn_mix`, so
+    the backward scan's second run of a DeltaNet layer has no reader for its
+    blocks and the compiled step holds the q, k, v, z projection of a block
+    twice: the forward scan's and the one a block's own checkpoint runs for
+    its gradients. With the name out of `_layer_again`'s policy it holds a
+    third. Two blocks of eight value heads, as the cell's four: a scan of
+    one block XLA unrolls, and merges the two later runs by itself."""
+    config = tiny_config(linear_num_key_heads=8, linear_num_value_heads=16)
+    assert transformer.DELTA_HEAD_BLOCK == 8
+    assert [kind.layer_remat for kind, _ in family.model_config(config).stacks
+            ] == [True, False]
+    state = jax.eval_shape(lambda: family.init(config, 0))
+    # (tokens, a block's 4 key heads x (q, k, 2 v, 2 z) x 16)
+    projection = re.compile(
+        r'= f32\[256,384\]\S* dot\(.*op_name="[^"]*gdn_proj/dot_general"')
+
+    def projections():
+        jax.clear_caches()
+        text = family.program_loss_and_grads(config).lower(
+            state, _sample()).compile().as_text()
+        return len(projection.findall(text))
+
+    assert projections() == 2
+    monkeypatch.setattr(transformer, "_layer_again",
+                        _layer_again_keeping("flash_out", "flash_lse"))
+    assert projections() == 3
+
+
+@pytest.mark.parametrize("other", [test_laguna_layers, test_glm_4_7_flash],
+                         ids=["laguna", "glm_4_7_flash"])
+def test_a_program_with_no_delta_layer_does_not_feel_the_name(other, monkeypatch,
+                                                              fresh_traces):
+    """`_layer_again`'s policy names a value that only a DeltaNet mixer
+    makes: a program whose layers are run again and have no such mixer
+    lowers to the same text with the name and without it, and to another
+    with no name at all (so the policy patched in is the one traced)."""
+    config, mc = other.CONFIG, other.family.model_config(other.CONFIG)
+    kinds = [kind for kind, _ in mc.stacks]
+    assert any(kind.layer_remat for kind in kinds)
+    assert not any(kind.mixer == "gated_delta" for kind in kinds)
+    state = jax.eval_shape(lambda: other.family.init(config, 0))
+
+    def lowered():
+        jax.clear_caches()
+        return other.family.program_loss_and_grads(config).lower(
+            state, other._sample()).as_text()
+
+    text = lowered()
+    for names, same in ((("flash_out", "flash_lse"), True), ((), False)):
+        monkeypatch.setattr(transformer, "_layer_again",
+                            _layer_again_keeping(*names))
+        assert (lowered() == text) == same, names
 
 
 def test_the_sixteen_shares_add_up_to_the_uncut_layer():
