@@ -76,7 +76,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from kungfu_tpu.optimizers import core as grad_sync
+from kungfu_tpu.ops import collective
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1046,7 +1046,8 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig):
     with q = C, k = B (a group's, never repeated a head) and v = Delta x;
     + D x; the gate silu(z) and then an RMSNorm over each group's features;
     W_out. Scopes `ssm_proj`, `ssm_conv`, `ssm_core`, `ssm_norm`."""
-    from kungfu_tpu.ops.ssm_scan import CHUNK, causal_conv_bias, ssm_scan
+    from kungfu_tpu.ops.gated_delta import causal_conv
+    from kungfu_tpu.ops.ssm_scan import CHUNK, ssm_scan
 
     H, hp, N, G = cfg.ssm_dims
     inner, bc = H * hp, G * N
@@ -1059,7 +1060,7 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig):
         step = jnp.dot(h.astype(f32), w_in[:, 2 * inner + 2 * bc:].astype(f32),
                        precision=jax.lax.Precision.HIGHEST)  # (B, S, H)
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(causal_conv_bias(xbc, layer["conv_w"], layer["conv_b"]))
+        xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"], layer["conv_b"]))
         x = xbc[..., :inner].reshape(B, S, H, hp)
         b, c = (xbc[..., at:at + bc].reshape(B, S, G, N).transpose(0, 2, 1, 3)
                 for at in (inner, inner + bc))
@@ -1260,7 +1261,7 @@ def _hidden(params, tokens, cfg: TransformerConfig):
     """-> (final hidden states, the expert layers' stacked aux or None),
     one scan for each stack of layers of one kind. Under plain S-SGD on
     several chips a layer's gradients are averaged in the iteration of the
-    backward scan that produces them (`optimizers.core.reduce_in_backward`,
+    backward scan that produces them (`ops.collective.reduce_in_backward`,
     the identity otherwise)."""
     x = _embed(params, tokens, cfg)
     stacks = params["layers"] if cfg.layer_kinds else (params["layers"],)
@@ -1271,7 +1272,7 @@ def _hidden(params, tokens, cfg: TransformerConfig):
 
         def body(x, layer, kind=kind, stacked=stacked, run=run):
             return run(
-                x, grad_sync.reduce_in_backward(layer, of=stacked), kind)
+                x, collective.reduce_in_backward(layer, of=stacked), kind)
 
         x, aux = jax.lax.scan(body, x, stacked)
         if aux is not None:
@@ -1327,7 +1328,7 @@ def _losses(params, batch, cfg: TransformerConfig):
     # the leaves outside the layer scan (the stack's go through `_hidden`'s):
     # under plain S-SGD on several chips their gradients are averaged where
     # the backward pass completes them
-    params = {**grad_sync.reduce_in_backward(
+    params = {**collective.reduce_in_backward(
         {k: v for k, v in params.items() if k != "layers"}),
         "layers": params["layers"]}
     x, aux = _hidden(params, tokens, cfg)

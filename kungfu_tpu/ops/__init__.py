@@ -1,3 +1,9 @@
+"""The device plane's operations, a module each: import the one you use
+(`from kungfu_tpu.ops import moe`). The package itself brings in the
+collectives alone, which import nothing beyond JAX, so that a model that
+reduces in its backward pass (`collective.reduce_in_backward`) pulls in no
+kernel and no Pallas with them (`tests/test_rope.py` holds the line)."""
+
 from kungfu_tpu.ops.collective import (
     all_gather,
     all_reduce,
@@ -7,15 +13,6 @@ from kungfu_tpu.ops.collective import (
     group_all_reduce,
     subset_all_reduce,
 )
-from kungfu_tpu.ops.hierarchical import (
-    CrossSliceReducer,
-    cross_slice_mean,
-    make_hier_train_step,
-    synchronous_sgd_hierarchical,
-)
-from kungfu_tpu.ops.flash_attention import flash_attention
-from kungfu_tpu.ops.moe import moe_ffn, switch_moe
-from kungfu_tpu.ops.ring_attention import ring_self_attention
 
 __all__ = [
     "all_gather",
@@ -25,12 +22,4 @@ __all__ = [
     "fuse",
     "group_all_reduce",
     "subset_all_reduce",
-    "CrossSliceReducer",
-    "cross_slice_mean",
-    "make_hier_train_step",
-    "synchronous_sgd_hierarchical",
-    "ring_self_attention",
-    "moe_ffn",
-    "switch_moe",
-    "flash_attention",
 ]

@@ -1,5 +1,6 @@
 """The gated delta rule in Pallas kernels, forward and backward, and the
-causal depthwise convolution that stands before it in a Gated DeltaNet layer.
+causal depthwise convolution that stands before it in a Gated DeltaNet layer
+and, with a bias, before the state-space scan of a Mamba-2 layer.
 
 The definition is a recurrence over the sequence with a (dk, dv) state a
 head (arXiv:2412.06464, Gated Delta Networks), S_0 = 0:
@@ -94,22 +95,26 @@ def _taps_over(padded, taps, S: int):
 
 
 @jax.custom_vjp
-def causal_conv(x, taps):
-    """Causal depthwise convolution over the sequence, no bias: x (B, S,
-    channels), taps (K, channels); y_t = sum_i taps_i x_{t - (K - 1) + i},
-    zeros before the start. The products and their sum in float32, the
-    result in x's type. The backward pass is written out, the same K
-    windows over the cotangent padded at its end and a reduction for the
-    taps, and keeps x and the taps alone: autodiff keeps each of the K
-    windows in float32 as the taps' residual (0.5 GB each at 16,384
-    positions and 8,192 channels) and pads a float32 cotangent a window."""
+def causal_conv(x, taps, bias=None):
+    """Causal depthwise convolution over the sequence: x (B, S, channels),
+    taps (K, channels), bias (channels,) or none; y_t = sum_i taps_i
+    x_{t - (K - 1) + i} + bias, zeros before the start. The products, their
+    sum and the bias in float32, the result in x's type. The backward pass
+    is written out, the same K windows over the cotangent padded at its end,
+    a reduction for the taps and one for the bias, and keeps x, the taps
+    and the bias alone: autodiff keeps each of the K windows in float32 as
+    the taps' residual (0.5 GB each at 16,384 positions and 8,192 channels)
+    and pads a float32 cotangent a window."""
     K = taps.shape[0]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return _taps_over(padded, taps, x.shape[1]).astype(x.dtype)
+    y = _taps_over(padded, taps, x.shape[1])
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(x.dtype)
 
 
 def _causal_conv_bwd(res, dy):
-    x, taps = res
+    x, taps, bias = res
     K, S = taps.shape[0], x.shape[1]
     # dx_t = sum_i taps_i dy_{t + (K - 1) - i}: the taps in reverse over dy
     # with zeros after its end
@@ -119,11 +124,13 @@ def _causal_conv_bwd(res, dy):
     dtaps = jnp.stack([
         jnp.sum(dy32 * padded[:, i:i + S].astype(jnp.float32), axis=(0, 1))
         for i in range(K)])
-    return dx.astype(x.dtype), dtaps.astype(taps.dtype)
+    dbias = None if bias is None else jnp.sum(dy32, axis=(0, 1)).astype(bias.dtype)
+    return dx.astype(x.dtype), dtaps.astype(taps.dtype), dbias
 
 
-causal_conv.defvjp(lambda x, taps: (causal_conv(x, taps), (x, taps)),
-                   _causal_conv_bwd)
+causal_conv.defvjp(
+    lambda x, taps, bias=None: (causal_conv(x, taps, bias), (x, taps, bias)),
+    _causal_conv_bwd)
 
 
 def _on_platform(kernel, *args, **static):

@@ -1,6 +1,6 @@
 """The state-space scan of a Mamba-2 layer in Pallas kernels, forward and
-backward: the second rule behind `ops.gated_delta`'s chunking, and the causal
-depthwise convolution with a bias that stands before it.
+backward: the second rule behind `ops.gated_delta`'s chunking (the causal
+depthwise convolution that stands before it is that module's, with a bias).
 
 The definition is a diagonal recurrence over the sequence with an (N, P)
 state a head (arXiv:2405.21060, Mamba-2's state-space duality), S_0 = 0:
@@ -59,35 +59,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kungfu_tpu.ops.gated_delta import (_NT, _PARAMS, _TN, BLOCK_HEADS,
-                                        _block_chunks, _causal_conv_bwd, _dot,
-                                        _on_platform, _rows, _taps_over,
-                                        _to_column, _to_row)
+                                        _block_chunks, _dot, _on_platform,
+                                        _rows, _to_column, _to_row)
 
 CHUNK = 128  # Mamba-2's published chunk_size; the result does not depend on it
-
-
-@jax.custom_vjp
-def causal_conv_bias(x, taps, bias):
-    """`gated_delta.causal_conv` plus a bias a channel: y_t = sum_i taps_i
-    x_{t - (K - 1) + i} + bias, zeros before the start; x (B, S, channels),
-    taps (K, channels), bias (channels,). Products, sum and bias in float32,
-    the result in x's type; the backward pass is that convolution's, written
-    out, and a reduction for the bias."""
-    K = taps.shape[0]
-    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return (_taps_over(padded, taps, x.shape[1])
-            + bias.astype(jnp.float32)).astype(x.dtype)
-
-
-def _causal_conv_bias_bwd(res, dy):
-    x, taps, bias = res
-    dx, dtaps = _causal_conv_bwd((x, taps), dy)
-    return dx, dtaps, jnp.sum(dy.astype(jnp.float32), axis=(0, 1)).astype(bias.dtype)
-
-
-causal_conv_bias.defvjp(
-    lambda x, taps, bias: (causal_conv_bias(x, taps, bias), (x, taps, bias)),
-    _causal_conv_bias_bwd)
 
 
 def _shared(q, k):

@@ -16,7 +16,7 @@ program, so the gradients' all-reduce is exposed whole wherever it stands
 backward scan, 7.39 ms inside it; PERF.md, PR 29). For plain S-SGD
 (`optimizers.synchronous_sgd` over `axis_name`, more than one member on the
 axis) the step lets the loss reduce each gradient where its backward pass
-produces it (`optimizers.core.reduce_in_backward`, which
+produces it (`ops.collective.reduce_in_backward`, which
 `models.transformer` calls in its layer scan) and applies the base update
 to what comes out. A loss that does not, any other optimizer and an axis of
 one member get the step as it always was.
@@ -48,6 +48,7 @@ def make_train_step(
     if batch_spec is None:
         batch_spec = P(axis_name)
     # deferred: nothing of the optimizers is imported before a step is built
+    from kungfu_tpu.ops import collective
     from kungfu_tpu.optimizers import core
 
     in_backward = (isinstance(optimizer, core.SynchronousSGD)
@@ -57,7 +58,7 @@ def make_train_step(
     def local_step(params, opt_state, batch):
         reduced = False
         if in_backward:
-            with core.reducing_in_backward(axis_name) as sync:
+            with collective.reducing_in_backward(axis_name) as sync:
                 loss, grads = jax.value_and_grad(sync.watching(loss_fn))(
                     params, batch)
             reduced = sync.covers_all()

@@ -65,6 +65,15 @@ def _the_ring_starts_empty():
     tracing.clear()
 
 
+@pytest.fixture
+def fresh_traces():
+    """`jax.jit` and `jax.checkpoint` keep the traces of the functions a
+    test patches: none from before it, and none of its own after it."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
 @pytest.fixture(scope="module")
 def runtime_watchers():
     """The compile and collector watchers (ISSUE 39) for one file's tests,

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jaxprs import pallas_calls
 from kungfu_tpu.ops.flash_attention import _dense_reference, flash_attention
 
 
@@ -299,6 +300,7 @@ _BIT_CALLS = {
     "two-lane-blocks": (1, 128, 256, None, 512)}
 _BIT_CASES = [(call, hd, dtype) for call in _BIT_CALLS for hd in (64, 128, 256)
               for dtype in ("float32", "bfloat16")]
+_BIT_CHILDREN = 3
 
 
 def _five_of_both_forms(call, hd, dtype):
@@ -335,12 +337,13 @@ def _five_of_both_forms(call, hd, dtype):
 
 @pytest.fixture(scope="module")
 def both_forms_bits():
-    """Every case of `_BIT_CASES` in one process of its own whose compiler
+    """Every case of `_BIT_CASES` in processes of their own whose compiler
     has no fused multiply-add. With one, XLA's CPU backend contracts
     `x * scale - m` wherever no select stands between the two, so the two
     bodies round differently at a scale that is no power of two (head 128):
     the interpreter's doing, not the kernels' (the chip's agree bit for bit,
-    PERF.md, PR 42)."""
+    PERF.md, PR 42). Three children at once, every third case each: a case
+    is six interpreted kernels to compile, 3 s whatever its shape (PR 47)."""
     import json
     import os
     import subprocess
@@ -349,10 +352,16 @@ def both_forms_bits():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root, XLA_FLAGS=(
         os.environ.get("XLA_FLAGS", "") + " --xla_cpu_max_isa=AVX").strip())
-    done = subprocess.run([sys.executable, os.path.abspath(__file__)], env=env,
-                          capture_output=True, text=True, timeout=1200)
-    assert done.returncode == 0, done.stderr[-2000:]
-    return json.loads(done.stdout.splitlines()[-1])
+    children = [subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__), str(i)], env=env,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        for i in range(_BIT_CHILDREN)]
+    bits = {}
+    for child in children:
+        out, err = child.communicate(timeout=1200)
+        assert child.returncode == 0, err[-2000:]
+        bits.update(json.loads(out.splitlines()[-1]))
+    return bits
 
 
 @pytest.mark.parametrize("call,hd,dtype", _BIT_CASES,
@@ -402,20 +411,6 @@ def test_the_block_counters_reach_the_metrics():
         ("kungfu_flash_blocks_masked_total", "forward")] == 3 * 7
 
 
-def _pallas_calls(jaxpr, recomputed=False):
-    """[(kernel's function, inside a checkpoint's recomputed part?)] of every
-    `pallas_call` of a jaxpr, through every equation that holds one."""
-    from kungfu_tpu.telemetry import device
-
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append((eqn.params["jaxpr"].debug_info.func_name, recomputed))
-        for sub in device._sub_jaxprs(eqn):
-            found += _pallas_calls(sub, recomputed or eqn.primitive.name == "remat2")
-    return found
-
-
 # the three kinds of call: (query heads to a key/value head, window)
 @pytest.mark.parametrize("g,window", [(1, None), (3, None), (1, 24)],
                          ids=["plain", "grouped", "window"])
@@ -441,7 +436,7 @@ def test_a_core_that_is_run_again_runs_its_forward_kernel_once(g, window):
         return jax.value_and_grad(weighed, argnums=(0, 1, 2), has_aux=True)
 
     jaxpr = jax.make_jaxpr(out_and_grads(again))(q, k, v).jaxpr
-    assert sorted(_pallas_calls(jaxpr)) == [
+    assert sorted(pallas_calls(jaxpr)) == [
         ("_dkv_kernel", True), ("_dq_kernel", True), ("_kernel", False)]
     kept = {eqn.params["name"]: eqn.outvars[0] for eqn in jaxpr.eqns
             if eqn.primitive.name == "name"}
@@ -455,8 +450,9 @@ def test_a_core_that_is_run_again_runs_its_forward_kernel_once(g, window):
         assert got.dtype == ref.dtype and bool(jnp.array_equal(got, ref))
 
 
-if __name__ == "__main__":  # `both_forms_bits`' own process
+if __name__ == "__main__":  # one of `both_forms_bits`' own processes
     import json
+    import sys
 
     print(json.dumps({f"{call}-{hd}-{dtype}": _five_of_both_forms(call, hd, dtype)
-                      for call, hd, dtype in _BIT_CASES}))
+                      for call, hd, dtype in _BIT_CASES[int(sys.argv[1])::_BIT_CHILDREN]}))

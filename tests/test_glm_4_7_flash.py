@@ -9,114 +9,40 @@ glm_4_7_flash.py` at a small size on the CPU, the shares of one expert layer
 added up; each mechanism knocked out in turn in
 `tests/test_glm_4_7_flash_faults.py`."""
 
-import functools
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec
 
-from benchmark import harness, manifest as mf
-from benchmark.families import glm4_moe_lite as family
+import family_cases as fc
+from benchmark import harness
 from benchmark.reference import glm_4_7_flash as ref
+from family_cases import *  # noqa: F401,F403  the shared cases
+from jaxprs import pallas_calls
 from kungfu_tpu.models import transformer
-from kungfu_tpu.models.transformer import (TransformerConfig, init_transformer,
-                                           param_pspecs)
+from kungfu_tpu.models.transformer import TransformerConfig, init_transformer
 from kungfu_tpu.ops import moe
 from kungfu_tpu.telemetry import metrics
-from test_flash_attention import _pallas_calls
-
-# the cell's stack in small: a dense layer and two expert layers, then the
-# multi-token-prediction module; hidden 64; 4 heads of 24 unrotated + 8
-# rotated q/k features and 32 value features, latents of 24 and 16; 16
-# experts of width 32 of which numbers 4 to 11 are held, 4 a token; vocabulary
-# 256; 64 positions (66 ids); flash in interpret mode; the routers trained,
-# so that every leaf but the bias has a gradient to compare
-TINY = dict(hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
-            num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
-            q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=24,
-            qk_rope_head_dim=8, v_head_dim=32, n_routed_experts=8,
-            first_expert_held=4, published={"n_routed_experts": 16},
-            vocab_size=256, sequence_length=64, flash_blocks=[32, 32],
-            flash_interpret=True, compute_dtype="float32", routers_trained=True)
-SEED = 5
 
 
-def real_config():
-    """The configuration file as it is."""
-    with open(os.path.join(mf.BENCH_DIR, "configs", "glm_4_7_flash.json")) as f:
-        return json.load(f)
+def _named_specs(specs):
+    sparse, module = specs["layers"][1], specs["mtp"]["layer"]
+    # up-projections a head at a time and W_o over tp, as wq and wo are; the
+    # down-projections, the latents' norms and the bias whole
+    assert sparse["w_q_up"] == sparse["w_kv_up"] == PartitionSpec(None, None, "tp")
+    assert sparse["wo"] == PartitionSpec(None, "tp", None)
+    assert sparse["w_q_down"] == sparse["w_kv_down"] == PartitionSpec(None, None, None)
+    assert sparse["q_latent_norm"] == sparse["router_bias"] == PartitionSpec(None, None)
+    assert module["w_q_up"] == PartitionSpec(None, "tp")
+    assert module["wo"] == PartitionSpec("tp", None)
+    assert module["w_gate"] == PartitionSpec("ep", None, "tp")
+    assert specs["mtp"]["eh_proj"] == PartitionSpec(None, None)
 
 
-def tiny_config(**changes):
-    config = real_config()
-    config.update(TINY)
-    config.update(changes)
-    return config
-
-
-CONFIG = tiny_config()
-
-_SCALES = {"w_q_down": 6.0, "w_q_up": 6.0, "w_kv_down": 6.0, "w_kv_up": 6.0,
-           "router": 20.0, "router_bias": 40.0, "w_gate": 8.0, "w_up": 8.0,
-           "w_down": 8.0, "shared_gate": 3.0, "shared_up": 3.0,
-           "shared_down": 3.0}
-_NORMS = ("ln1_scale", "ln2_scale", "q_latent_norm", "kv_latent_norm")
-
-
-def _trained(layer, key):
-    layer = {name: leaf * _SCALES.get(name, 1.0) for name, leaf in layer.items()}
-    for i, name in enumerate(_NORMS):
-        layer[name] = layer[name] + 0.4 * jax.random.normal(
-            jax.random.fold_in(key, i), layer[name].shape)
-    return layer
-
-
-def _state(seed=SEED, config=CONFIG):
-    """A state as after some training, so that no fault can hide behind the
-    initial values: norm scales off one, sharp attention, a router with
-    preferences and a bias that moves choices, experts that weigh, a
-    projection of the module that mixes both of its halves."""
-    state = family.init(config, seed)
-    key = jax.random.PRNGKey(seed + 100)
-    stacks = tuple(_trained(stack, jax.random.fold_in(key, 10 + s))
-                   for s, stack in enumerate(state["layers"]))
-    mtp = state["mtp"]
-    mtp = {**mtp, "eh_proj": 4.0 * mtp["eh_proj"],
-           "layer": _trained(mtp["layer"], jax.random.fold_in(key, 20)),
-           **{name: mtp[name] + 0.4 * jax.random.normal(
-               jax.random.fold_in(key, 30 + i), mtp[name].shape)
-              for i, name in enumerate(("enorm_scale", "hnorm_scale", "ln_f_scale"))}}
-    return {**state, "layers": stacks, "mtp": mtp,
-            "ln_f_scale": state["ln_f_scale"] + 0.3 * jax.random.normal(
-                key, state["ln_f_scale"].shape)}
-
-
-@pytest.fixture
-def fresh_traces():
-    """`jax.jit` and `jax.checkpoint` keep the traces of the functions a
-    test patches: none from before it, and none of its own after it."""
-    jax.clear_caches()
-    yield
-    jax.clear_caches()
-
-
-def _sample(n=2):
-    return family.host_batch(CONFIG, SEED, 0, n)
-
-
-@functools.lru_cache(maxsize=None)
-def _reference():
-    """The reference's loss and gradients on `_state()` and `_sample()`,
-    computed once for the tests of this module and of the faults'."""
-    return family.reference_loss_and_grads(CONFIG, _state(), _sample())
-
-
-def _reference_parts(config, state, sample):
-    return [float(x) for x in ref.losses(state, sample, **family._hyper(config))]
+FAMILY = fc.GLM_4_7_FLASH.with_cases(
+    named_specs=_named_specs, tp_leaf=("layers", 1, "w_q_up"))
+family, tiny_config, CONFIG = FAMILY.module, FAMILY.tiny_config, FAMILY.config
 
 
 def test_the_stacks_are_the_models_layers_in_order():
@@ -156,70 +82,10 @@ def test_the_stacks_are_the_models_layers_in_order():
     assert "lm_head" in state and "lm_head" not in state["mtp"]
 
 
-def test_param_pspecs_cover_every_leaf():
-    mc = family.model_config(CONFIG)
-    state = jax.eval_shape(lambda: family.init(CONFIG, 0))
-    specs = param_pspecs(mc)
-    assert jax.tree.structure(
-        jax.tree.map(lambda s: 0, specs,
-                     is_leaf=lambda s: isinstance(s, PartitionSpec))
-    ) == jax.tree.structure(jax.tree.map(lambda s: 0, state))
-    for spec, leaf in zip(
-            jax.tree.leaves(specs, is_leaf=lambda s: isinstance(s, PartitionSpec)),
-            jax.tree.leaves(state)):
-        assert len(spec) <= leaf.ndim, (spec, leaf.shape)
-    sparse, module = specs["layers"][1], specs["mtp"]["layer"]
-    # up-projections a head at a time and W_o over tp, as wq and wo are; the
-    # down-projections, the latents' norms and the bias whole
-    assert sparse["w_q_up"] == sparse["w_kv_up"] == PartitionSpec(None, None, "tp")
-    assert sparse["wo"] == PartitionSpec(None, "tp", None)
-    assert sparse["w_q_down"] == sparse["w_kv_down"] == PartitionSpec(None, None, None)
-    assert sparse["q_latent_norm"] == sparse["router_bias"] == PartitionSpec(None, None)
-    assert module["w_q_up"] == PartitionSpec(None, "tp")
-    assert module["wo"] == PartitionSpec("tp", None)
-    assert module["w_gate"] == PartitionSpec("ep", None, "tp")
-    assert specs["mtp"]["eh_proj"] == PartitionSpec(None, None)
-
-
-def test_a_tp_mesh_of_two_gives_the_same_loss():
-    from kungfu_tpu.parallel import make_mesh
-    from kungfu_tpu.parallel.sharded import shard_params
-
-    config = tiny_config(attention_core="dense")
-    mc = family.model_config(config)
-    state, sample = _state(config=config), _sample()
-    loss = family.loss_fn(config)
-    want = float(jax.jit(loss)(state, sample))
-    mesh = make_mesh({"dp": 1, "tp": 2, "ep": 1}, devices=jax.devices()[:2])
-    placed = shard_params(state, mesh, param_pspecs(mc))
-    assert len(placed["layers"][1]["w_q_up"].sharding.device_set) == 2
-    with mesh:
-        got = float(jax.jit(loss)(placed, sample))
-    assert got == pytest.approx(want, rel=1e-5)
-
-
-def test_float32_program_equals_the_reference():
-    state, sample = _state(), _sample()
-    loss, grads = family.program_loss_and_grads(CONFIG)(state, sample)
-    want_loss, want = _reference()
-    assert abs(float(loss) - float(want_loss)) <= 1e-5 * abs(float(want_loss))
-    assert harness.relative_error(grads, want) <= 1e-4
-    assert jax.tree.structure(grads) == jax.tree.structure(want)
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
-                            jax.tree.leaves(want)):
-        name = jax.tree_util.keystr(path)
-        if "router_bias" in name:  # a constant of the loss, in both
-            assert not np.asarray(g).any() and not np.asarray(w).any(), name
-            continue
-        assert float(jnp.abs(g).max()) > 0, name
-        assert harness.relative_error(g, w) <= 1e-3, name
-    assert family.differing_choices(CONFIG, state, sample) == 0
-
-
 def test_main_and_mtp_losses_equal_the_references():
-    state, sample = _state(), _sample()
+    state, sample = FAMILY.state(), FAMILY.sample()
     got = family.program_losses(CONFIG, state, sample)
-    main, mtp = _reference_parts(CONFIG, state, sample)
+    main, mtp = map(float, ref.losses(state, sample, **family._hyper(CONFIG)))
     assert got["main"] == pytest.approx(main, rel=1e-5)
     assert got["mtp"] == pytest.approx(mtp, rel=1e-5)
     assert abs(main - mtp) > 1e-3  # two losses, not one twice
@@ -228,7 +94,7 @@ def test_main_and_mtp_losses_equal_the_references():
 
 
 def test_the_losses_reach_the_metrics_registry():
-    state, sample = _state(), _sample()
+    state, sample = FAMILY.state(), FAMILY.sample()
     mc = family.model_config(CONFIG)
     losses = jax.jit(lambda p, b: transformer.transformer_losses(p, b, mc))(
         state, sample)
@@ -244,30 +110,6 @@ def test_the_losses_reach_the_metrics_registry():
     assert set(only) == {"main"}
 
 
-def test_bfloat16_program_is_within_the_familys_tolerances():
-    config = tiny_config(compute_dtype="bfloat16")
-    state, sample = family.init(config, SEED), _sample()
-    loss, grads = family.program_loss_and_grads(config)(state, sample)
-    want_loss, want = family.reference_loss_and_grads(CONFIG, state, sample)
-    assert abs(float(loss) - float(want_loss)) <= family.LOSS_RTOL * abs(float(want_loss))
-    error = harness.relative_error(grads, want)
-    assert 1e-4 < error <= family.GRAD_RTOL, error
-    assert 0 < family.LOSS_RTOL < family.GRAD_RTOL < 0.1
-
-
-@pytest.mark.parametrize("recomputed", [[], [family.DENSE, family.SPARSE]])
-def test_the_recomputed_layers_change_no_number(recomputed):
-    """`recomputed_layer_types` says what the backward pass keeps, not what
-    it computes, in the stack and in the module's block alike."""
-    state, sample = _state(), _sample()
-    other = tiny_config(recomputed_layer_types=recomputed)
-    assert family.model_config(other).mtp_kind.layer_remat == bool(recomputed)
-    loss, grads = family.program_loss_and_grads(CONFIG)(state, sample)
-    want_loss, want = family.program_loss_and_grads(other)(state, sample)
-    assert abs(float(loss) - float(want_loss)) <= 1e-6 * abs(float(want_loss))
-    assert harness.relative_error(grads, want) <= 1e-5
-
-
 def test_no_layer_that_is_run_again_runs_its_forward_kernel_again():
     """The cell's own setting, the expert layers and the module's block run
     again in the backward pass: latent attention is the flash core's plain
@@ -276,15 +118,14 @@ def test_no_layer_that_is_run_again_runs_its_forward_kernel_again():
     one of the module's block, none in a recomputed part, and every number
     of the step is the number of the step that keeps its layers."""
     assert CONFIG["recomputed_layer_types"] == [family.SPARSE]
-    state, sample = _state(), _sample()
+    state, sample = FAMILY.state(), FAMILY.sample()
     jaxpr = jax.make_jaxpr(jax.value_and_grad(family.loss_fn(CONFIG)))(
         state, sample).jaxpr
     # the dense scan's, the expert scan's and the module's
-    assert [where for kernel, where in _pallas_calls(jaxpr)
+    assert [where for kernel, where in pallas_calls(jaxpr)
             if kernel == "_kernel"] == [False] * 3
-    kept = tiny_config(recomputed_layer_types=[])
-    loss, grads = family.program_loss_and_grads(CONFIG)(state, sample)
-    want_loss, want = family.program_loss_and_grads(kept)(state, sample)
+    loss, grads = FAMILY.baseline()
+    want_loss, want = FAMILY.baseline(recomputed=())
     assert float(loss) == float(want_loss)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
                             jax.tree.leaves(want), strict=True):
@@ -300,7 +141,7 @@ def test_latent_attention_alone_against_a_plain_softmax_over_materialised_heads(
     a softmax over the full score matrix)."""
     config = tiny_config(attention_core=core)
     mc = family.model_config(config).stacks[1][0]
-    layer = jax.tree.map(lambda a: a[0], _state()["layers"][1])
+    layer = jax.tree.map(lambda a: a[0], FAMILY.state()["layers"][1])
     h = jax.random.normal(jax.random.PRNGKey(2), (2, 64, 64))
     got = jax.jit(lambda h, w: transformer._latent_attention(h, w, mc))(h, layer)
     with jax.default_matmul_precision("highest"):
@@ -313,13 +154,10 @@ def test_latent_attention_alone_against_a_plain_softmax_over_materialised_heads(
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer():
-    """Model-configs guide, section 4: one expert layer of 64 experts, 4 a
-    token by sigmoid scores and a selection bias, renormalised and scaled by
-    1.8, cut into 8 shares of 8. Each share routes over all 64 and computes
-    its own experts' part and the shared expert, which every chip computes
-    alike; the parts of all 8, the shared expert counted once, are what the
-    uncut reference gives for the whole layer."""
-    E, held, D, F, T = 64, 8, 64, 32, 96
+    """One expert layer of 64 experts, 4 a token by sigmoid scores and a
+    selection bias, renormalised and scaled by 1.8, cut into 8 shares of 8
+    that each compute the shared expert (`fc.shares_add_up`)."""
+    E, D, F, T = 64, 64, 32, 96
     ks = jax.random.split(jax.random.PRNGKey(3), 10)
     n = jax.random.normal(ks[0], (T, D))
     w = {"router": 0.5 * jax.random.normal(ks[1], (D, E)),
@@ -332,31 +170,11 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
          "shared_down": 0.3 * jax.random.normal(ks[7], (F, D))}
     want, chosen = ref.experts(n, w, dict(top_k=4, routed_scale=1.8, first_held=0))
     shared = ref._swiglu(n, w["shared_gate"], w["shared_up"], w["shared_down"])
-
-    def share(first):
-        cfg = TransformerConfig(
-            d_model=D, d_ff=F, dtype=jnp.float32, ffn="moe", n_experts=E,
-            top_k=4, gates="renorm", routed_scale=1.8,
-            experts_held=(first, held), shared_ff=F, router_scores="sigmoid",
-            router_bias=True)
-        mine = {**w, **{name: w[name][first:first + held]
-                        for name in ("w_gate", "w_up", "w_down")}}
-        return transformer._expert_layer(n, mine, cfg)
-
-    parts = [share(first) for first in range(0, E, held)]
-    assert len(parts) == 8
-    total = sum(y for y, _ in parts) - 7 * shared
-    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
-                               rtol=2e-4, atol=2e-5)
-    counts = np.concatenate([np.asarray(aux.counts) for _, aux in parts])
-    assert counts.tolist() == np.bincount(np.asarray(chosen).ravel(),
-                                          minlength=E).tolist()
-    assert counts.sum() == 4 * T
-    # every share sees the same router: the bias moved the same choices
-    moved = {int(aux.bias_moved) for _, aux in parts}
-    assert len(moved) == 1 and 0 < moved.pop() < 4 * T
-    # one share alone is not the layer: the cut is real
-    assert not np.allclose(np.asarray(parts[0][0]), np.asarray(want), atol=1e-2)
+    cfg = TransformerConfig(
+        d_model=D, d_ff=F, dtype=jnp.float32, ffn="moe", n_experts=E, top_k=4,
+        gates="renorm", routed_scale=1.8, shared_ff=F, router_scores="sigmoid",
+        router_bias=True)
+    assert fc.shares_add_up(n, w, cfg, 8, want, chosen, shared) == 8
 
 
 def test_the_bias_moves_the_choice_and_never_the_weight():
@@ -400,58 +218,28 @@ def test_the_bias_moves_the_choice_and_never_the_weight():
     assert float(out(bias)) != pytest.approx(float(out(jnp.zeros(E))), rel=1e-3)
 
 
-def test_the_share_drops_nothing_and_counts_what_the_bias_moved():
-    state, sample = _state(), _sample()
-    stats = family.routing_stats(CONFIG, state, sample)
-    # two expert layers and the module's, the last row
-    assert stats["dropped"] == [0, 0, 0] and stats["layer"] == [1, 2, 3]
-    counts = np.asarray(stats["counts"])
-    assert counts.shape == (3, 8)
-    assert stats["held_rows"] == counts.sum(axis=1).tolist()
-    # 4 of 16 experts a token, 8 held: half of the choices, about
-    assert 0.3 < counts.sum() / (3 * 128 * 4) < 0.7
-    assert len(stats["bias_moved"]) == 3 and all(
-        0 < n < 128 * 4 for n in stats["bias_moved"])
-    mc = family.model_config(CONFIG)
-    full = jax.jit(lambda p, t: transformer.routing_stats(p, t, mc))(
-        state, sample[:, :-1])
-    registry = metrics.Registry()
-    transformer.record_routing(full, registry)
-    text = registry.render()
-    assert 'kungfu_moe_bias_moved_token_choices{layer="3"}' in text
-    assert 'kungfu_moe_dropped_token_choices{layer="1"} 0' in text
-    assert 'kungfu_moe_held_rows{layer="2"}' in text
-
-
 def test_the_initial_bias_is_small_and_moves_some_choices():
     config = tiny_config(hidden_size=256, q_lora_rank=32)
-    state = family.init(config, SEED)
+    state = family.init(config, FAMILY.seed)
     bias = np.asarray(state["layers"][1]["router_bias"])
     assert bias.shape == (2, 16) and 0.002 < np.abs(bias).mean() < 0.03
-    stats = family.routing_stats(config, state, family.host_batch(config, SEED, 0, 2))
+    stats = family.routing_stats(
+        config, state, family.host_batch(config, FAMILY.seed, 0, 2))
     assert all(0 < n < 0.5 * 128 * 4 for n in stats["bias_moved"]), stats["bias_moved"]
 
 
 def test_the_new_fields_refuse_what_they_cannot_mean():
-    with pytest.raises(ValueError, match="mixer"):
-        TransformerConfig(mixer="mla")
-    with pytest.raises(ValueError, match="latent_dims"):
-        TransformerConfig(mixer="latent", positions="rope")
-    with pytest.raises(ValueError, match="latent_dims"):
-        TransformerConfig(mixer="latent", positions="rope",
-                          latent_dims=(8, 8, 8, 3, 8))
-    with pytest.raises(ValueError, match="rope"):
-        TransformerConfig(mixer="latent", latent_dims=(8, 8, 8, 4, 12))
-    with pytest.raises(ValueError, match="rounds down"):  # 44 * (30 / 44) < 30
-        TransformerConfig(mixer="latent", positions="rope",
-                          latent_dims=(8, 8, 14, 30, 44))
-    with pytest.raises(ValueError, match="one head size"):
-        TransformerConfig(mixer="latent", positions="rope", attn_core="flash",
-                          latent_dims=(8, 8, 8, 4, 16))
-    with pytest.raises(ValueError, match="router_scores"):
-        TransformerConfig(router_scores="tanh")
-    with pytest.raises(ValueError, match="mtp_depth"):
-        TransformerConfig(mtp_depth=2)
+    fc.refused("mixer", mixer="mla")
+    fc.refused("latent_dims", mixer="latent", positions="rope")
+    fc.refused("latent_dims", mixer="latent", positions="rope",
+               latent_dims=(8, 8, 8, 3, 8))
+    fc.refused("rope", mixer="latent", latent_dims=(8, 8, 8, 4, 12))
+    fc.refused("rounds down", mixer="latent", positions="rope",
+               latent_dims=(8, 8, 14, 30, 44))  # 44 * (30 / 44) < 30
+    fc.refused("one head size", mixer="latent", positions="rope",
+               attn_core="flash", latent_dims=(8, 8, 8, 4, 16))
+    fc.refused("router_scores", router_scores="tanh")
+    fc.refused("mtp_depth", mtp_depth=2)
     with pytest.raises(ValueError, match="scores"):
         moe.route(jnp.zeros((4, 8)), jnp.zeros((8, 4)), 2, "tanh")
     cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=1,
@@ -465,47 +253,12 @@ def test_the_new_fields_refuse_what_they_cannot_mean():
     assert np.isfinite(float(loss))
 
 
-def test_the_new_scopes_are_in_the_program():
-    """`attn` with `mla_down`, `mla_norm`, `mla_up`, `rope` and `attn_latent`
-    > `attn_core` inside it; `mtp` with `mtp_proj`, the block's own scopes
-    and `head_loss`; `moe` > `moe_router` as it was: what the cell's
-    per-layer metrics read."""
-    state = jax.eval_shape(lambda: family.init(CONFIG, 0))
-    text = family.program_loss_and_grads(CONFIG).lower(
-        state, _sample()).as_text(debug_info=True)
-    for scope in ("attn/mla_down", "attn/mla_norm", "attn/mla_up", "attn/rope",
-                  "attn/attn_latent/attn_core", "moe/moe_router",
-                  "moe/moe_shared", "moe/moe_dispatch", "moe_experts/",
-                  "moe_combine/", "head_loss", "mtp_proj/"):
-        assert scope in text, scope
-    # a scope at the top of the differentiated function is written
-    # `jvp(mtp)`, and `transpose(jvp(mtp))` in the backward pass
-    lines = [line for line in text.splitlines() if "(mtp)" in line]
+def test_the_modules_scopes_are_under_its_own():
+    """`mtp` with `mtp_proj`, the block's own scopes and `head_loss`: a
+    scope at the top of the differentiated function is written `jvp(mtp)`,
+    and `transpose(jvp(mtp))` in the backward pass."""
+    lines = [line for line in FAMILY.lowered().splitlines() if "(mtp)" in line]
     assert any("transpose(jvp(mtp))" in line for line in lines)
     for scope in ("mtp_proj/", "attn/mla_up", "attn/attn_latent/attn_core",
                   "moe/moe_router", "head_loss"):
         assert any(scope in line for line in lines), scope
-
-
-def test_routers_that_are_not_trained_get_no_gradient_and_change_no_other():
-    """The cell's own setting: the routers' matrices are constants of the
-    loss, in the program and in the reference alike, the module's among
-    them; every other leaf's gradient is what it is with the routers
-    trained."""
-    config = tiny_config(routers_trained=False)
-    assert real_config()["routers_trained"] is False
-    state, sample = _state(), _sample()
-    loss, grads = family.program_loss_and_grads(config)(state, sample)
-    want_loss, want = family.reference_loss_and_grads(config, state, sample)
-    trained_loss, trained = _reference()
-    assert float(want_loss) == float(trained_loss)
-    assert abs(float(loss) - float(want_loss)) <= 1e-5 * abs(float(want_loss))
-    assert harness.relative_error(grads, want) <= 1e-4
-    layers = lambda tree: [tree["layers"][1], tree["mtp"]["layer"]]
-    for got, reference, full in zip(layers(grads), layers(want), layers(trained)):
-        assert not np.asarray(got["router"]).any()
-        assert not np.asarray(reference["router"]).any()
-        assert np.asarray(full["router"]).any()
-        for name in reference:
-            if name != "router":
-                np.testing.assert_array_equal(reference[name], full[name])

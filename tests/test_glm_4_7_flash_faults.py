@@ -1,29 +1,17 @@
 """Each mechanism of GLM-4.7-Flash's layers knocked out in turn (PR 41): the
-float32 program with the fault against the plain reference on the trained-like
-state of `tests/test_glm_4_7_flash.py`, whose helpers these are; every fault
-has to read far over what the bfloat16 program is allowed. A file of its own
-so that the suite's workers share the compiles."""
+float32 program with the fault against the plain reference on the family's
+trained-like state (`tests/family_cases.py`); every fault has to read far over
+what the bfloat16 program is allowed. A file of its own so that the suite's
+workers share the compiles."""
 
-import dataclasses
-
-import jax
 import jax.numpy as jnp
-import pytest
 
-from benchmark import harness
-from benchmark.families import glm4_moe_lite as family
+import family_cases as fc
+from family_cases import (  # noqa: F401  the shared case
+    pytest_generate_tests, test_a_fault_fails_the_familys_tolerance)
 from kungfu_tpu.models import transformer
-from kungfu_tpu.ops import moe
-from test_glm_4_7_flash import (CONFIG, _reference, _sample, _state,  # noqa: F401
-                                fresh_traces)
 
-_model_config = family.model_config
-
-
-def _as(**changes):
-    return lambda m: m.setattr(
-        family, "model_config",
-        lambda cfg: dataclasses.replace(_model_config(cfg), **changes))
+_as = lambda **changes: fc.model_changed(fc.GLM_4_7_FLASH.module, **changes)
 
 
 def _no_latent_norm(m):
@@ -51,16 +39,6 @@ def _rotary_key_not_shared(m):
     m.setattr(transformer.jnp, "broadcast_to", apart)
 
 
-def _bias_in_the_weight(m):
-    def route(x, router_w, top_k, scores="softmax", bias=None):
-        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
-        biased = jax.nn.sigmoid(logits) + bias
-        top, idx = jax.lax.top_k(biased, top_k)
-        return logits, biased, top, idx
-
-    m.setattr(moe, "route", route)
-
-
 def _mtp_fed_the_same_token(m):
     hidden = transformer._mtp_hidden
 
@@ -84,11 +62,10 @@ def _mtp_with_a_head_of_its_own(m):
 
 
 FAULTS = {
-    "eight_bit_operands": lambda m: None,
     "no_latent_norm": _no_latent_norm,
     "rotary_key_not_shared": _rotary_key_not_shared,
     "softmax_in_place_of_sigmoid": _as(router_scores="softmax"),
-    "bias_in_the_weight": _bias_in_the_weight,
+    "bias_in_the_weight": fc.bias_in_the_weight,
     "scale_1_in_place_of_1_8": _as(routed_scale=1.0),
     "no_selection_bias": _as(router_bias=False),
     "mtp_fed_t_i_in_place_of_t_i_plus_1": _mtp_fed_the_same_token,
@@ -97,22 +74,4 @@ FAULTS = {
 }
 
 
-def _eight_bit(state):
-    """Every matrix rounded to float8_e4m3 (3 mantissa bits): what 8-bit
-    operands do to the matmuls."""
-    return jax.tree.map(
-        lambda w: w.astype(jnp.float8_e4m3fn).astype(w.dtype) if w.ndim >= 2 else w,
-        state)
-
-
-@pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_a_fault_fails_the_familys_tolerance(fault, monkeypatch, fresh_traces):
-    """Each in float32 compute, so that nothing but the fault is in the
-    error: it has to be far over what the bfloat16 program is allowed."""
-    state, sample = _state(), _sample()
-    FAULTS[fault](monkeypatch)
-    program_state = _eight_bit(state) if fault == "eight_bit_operands" else state
-    _, want = _reference()
-    loss, grads = family.program_loss_and_grads(CONFIG)(program_state, sample)
-    error = harness.relative_error(grads, want)
-    assert error > 2 * family.GRAD_RTOL, (fault, error)
+FAMILY = fc.GLM_4_7_FLASH.with_cases(faults=FAULTS)

@@ -1,5 +1,5 @@
 """Plain S-SGD through `make_train_step` reduces each gradient inside the
-backward pass (`optimizers.core.reduce_in_backward`, which
+backward pass (`ops.collective.reduce_in_backward`, which
 `models.transformer` calls in its layer scan), exactly once, to the values
 the optimizer's own `pmean` gives; and every caller that is not that (one
 member on the axis, another wrapper, `synchronous_sgd.update` by hand, a
@@ -20,6 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 from kungfu_tpu.models.transformer import (TransformerConfig, init_transformer,
                                            transformer_loss)
+from kungfu_tpu.ops import collective
 from kungfu_tpu.ops.hierarchical import synchronous_sgd_hierarchical
 from kungfu_tpu.optimizers import (adaptive_sgd, core, synchronous_averaging,
                                    synchronous_sgd, zero_sharded)
@@ -56,9 +57,9 @@ def _transformer(cfg, optimizer, n):
 def _mechanism_off(monkeypatch):
     """For the rest of the test no axis is ever declared and the identity
     is Python's: the code the parent had."""
-    monkeypatch.setattr(core, "reducing_in_backward", contextlib.contextmanager(
-        lambda axis_name: (yield core._GradSync(axis_name))))
-    monkeypatch.setattr(core, "reduce_in_backward", lambda params, of=None: params)
+    monkeypatch.setattr(collective, "reducing_in_backward", contextlib.contextmanager(
+        lambda axis_name: (yield collective._GradSync(axis_name))))
+    monkeypatch.setattr(collective, "reduce_in_backward", lambda params, of=None: params)
 
 
 def _nbytes(tree) -> int:
@@ -301,12 +302,12 @@ def _mlp_loss(params, batch):
 
 def _half_reduced_loss(params, batch):
     """Reduces one of its two leaves itself: not everything."""
-    return _mlp_loss({"w_in": core.reduce_in_backward(params["w_in"]),
+    return _mlp_loss({"w_in": collective.reduce_in_backward(params["w_in"]),
                       "w_out": params["w_out"]}, batch)
 
 
 def _fully_reduced_loss(params, batch):
-    return _mlp_loss(core.reduce_in_backward(params), batch)
+    return _mlp_loss(collective.reduce_in_backward(params), batch)
 
 
 @pytest.mark.parametrize("loss_fn,expected", [
@@ -347,17 +348,17 @@ def test_a_loss_decides_by_what_it_reduces(loss_fn, expected):
 
 def test_a_slice_must_be_a_slice_of_what_it_names():
     stacked = {"w": jnp.zeros((3, 4, 5))}
-    with core.reducing_in_backward("dp"):
+    with collective.reducing_in_backward("dp"):
         with pytest.raises(ValueError, match="no slice"):
-            core.reduce_in_backward({"w": jnp.zeros((5, 4))}, of=stacked)
+            collective.reduce_in_backward({"w": jnp.zeros((5, 4))}, of=stacked)
 
 
 def test_no_axis_declared_means_nothing_is_traced():
     tree = {"w": jnp.ones((2, 3))}
-    assert core.reduce_in_backward(tree) is tree
-    assert core.reduce_in_backward(tree["w"], of=tree) is tree["w"]
+    assert collective.reduce_in_backward(tree) is tree
+    assert collective.reduce_in_backward(tree["w"], of=tree) is tree["w"]
     jaxpr = jax.make_jaxpr(jax.grad(
-        lambda p: jnp.sum(core.reduce_in_backward(p)["w"])))(tree)
+        lambda p: jnp.sum(collective.reduce_in_backward(p)["w"])))(tree)
     assert "custom_vjp" not in str(jaxpr) and "psum" not in str(jaxpr)
 
 

@@ -22,9 +22,12 @@ PORTS = kfrun_ports()  # this xdist worker's block, not kfrun's defaults
 DEBUG_PORT = PORTS.spare(0)
 
 
-def _poll_links(base_url, proc, np_, timeout_s=120.0):
-    """Wait until every peer's source row appears with at least one
-    bandwidth-estimated edge overall."""
+def _poll_links(base_url, proc, np_, timeout_s=240.0):
+    """Wait until the matrix is the one the test asserts: every peer's
+    source row there with an edge that carried bytes, a bandwidth estimate
+    overall and every peer's clock offset. The rows arrive a scrape at a
+    time, later on a loaded machine, and the agents keep them warm until
+    the harness has seen them all."""
     deadline = time.time() + timeout_s
     last = None
     while time.time() < deadline:
@@ -36,10 +39,14 @@ def _poll_links(base_url, proc, np_, timeout_s=120.0):
             ) as r:
                 doc = json.loads(r.read().decode())
             last = doc
+            edges = doc.get("edges", {})
             if (
                 len(doc.get("peers", [])) == np_
-                and len(doc.get("edges", {})) == np_
+                and len(edges) == np_
                 and doc.get("min_bw")
+                and all(row and all(e["tx_bytes"] > 0 for e in row.values())
+                        for row in edges.values())
+                and len(doc.get("clock_offset_us", {})) == np_
             ):
                 return doc, None
         except (OSError, ValueError):

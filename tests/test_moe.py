@@ -98,7 +98,7 @@ def test_switch_moe_differentiable():
             check_vma=False,
         )(x, rw, wi, wo)
 
-    g = jax.grad(loss)((router_w, w_in, w_out), x)
+    g = jax.jit(jax.grad(loss))((router_w, w_in, w_out), x)
     for leaf in jax.tree.leaves(g):
         assert np.isfinite(np.asarray(leaf)).all()
     # expert weights receive gradient (tokens actually flowed through)
@@ -312,8 +312,9 @@ def test_the_shares_parts_add_up_to_the_whole_layer(top_k, count, E):
     def loss(fn):
         return lambda *a: jnp.sum(fn(*a)[0] ** 2)
 
-    for g, w in zip(jax.tree.leaves(jax.grad(loss(parts), (0, 1, 2))(x, router, experts)),
-                    jax.tree.leaves(jax.grad(loss(whole), (0, 1, 2))(x, router, experts))):
+    grads = lambda fn: jax.tree.leaves(
+        jax.jit(jax.grad(loss(fn), (0, 1, 2)))(x, router, experts))
+    for g, w in zip(grads(parts), grads(whole)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-5)
 

@@ -1,29 +1,19 @@
 """Each mechanism of Nemotron-3-Nano's layers knocked out in turn (PR 43): the
-float32 program with the fault against the plain reference on the trained-like
-state of `tests/test_nemotron_h.py`, whose helpers these are; every fault has
-to read far over what the bfloat16 program is allowed. A file of its own so
-that the suite's workers share the compiles."""
-
-import dataclasses
+float32 program with the fault against the plain reference on the family's
+trained-like state (`tests/family_cases.py`); every fault has to read far over
+what the bfloat16 program is allowed. A file of its own so that the suite's
+workers share the compiles."""
 
 import jax
 import jax.numpy as jnp
-import pytest
 
-from benchmark import harness
-from benchmark.families import nemotron_h as family
+import family_cases as fc
+from family_cases import (  # noqa: F401  the shared case
+    pytest_generate_tests, test_a_fault_fails_the_familys_tolerance)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.ops import moe, ssm_scan
-from test_nemotron_h import (CONFIG, _reference, _sample, _state,  # noqa: F401
-                             fresh_traces)
 
-_model_config = family.model_config
-
-
-def _as(**changes):
-    return lambda m: m.setattr(
-        family, "model_config",
-        lambda cfg: dataclasses.replace(_model_config(cfg), **changes))
+_as = lambda **changes: fc.model_changed(fc.NEMOTRON_H.module, **changes)
 
 
 def _gated_norm(m, norm):
@@ -65,19 +55,7 @@ def _expert_function(m, act):
     m.setattr(transformer, "_relu2_out", lambda up, w_down: act(up) @ w_down)
 
 
-def _bias_in_the_weight(m):
-    def route(x, router_w, top_k, scores="softmax", bias=None):
-        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
-        biased = jax.nn.sigmoid(logits) + bias
-        top, idx = jax.lax.top_k(biased, top_k)
-        return logits, biased, top, idx
-
-    m.setattr(moe, "route", route)
-
-
 FAULTS = {
-    "eight_bit_operands": lambda m: None,
-    "no_d_x": lambda m: None,
     "gate_after_the_norm": _gate_after_the_norm,
     "norm_over_all_features_and_not_a_group": _norm_over_all_features,
     "b_and_c_of_the_wrong_group": _b_and_c_of_the_wrong_group,
@@ -86,17 +64,9 @@ FAULTS = {
     "gated_silu_in_the_experts": lambda m: _expert_function(
         m, lambda up: jax.nn.silu(up) * up),
     "relu_in_place_of_relu2": lambda m: _expert_function(m, jax.nn.relu),
-    "bias_in_the_weight": _bias_in_the_weight,
+    "bias_in_the_weight": fc.bias_in_the_weight,
     "scale_1_in_place_of_2_5": _as(routed_scale=1.0),
 }
-
-
-def _eight_bit(state):
-    """Every matrix rounded to float8_e4m3 (3 mantissa bits): what 8-bit
-    operands do to the matmuls."""
-    return jax.tree.map(
-        lambda w: w.astype(jnp.float8_e4m3fn).astype(w.dtype) if w.ndim >= 2 else w,
-        state)
 
 
 def _no_d_x(state):
@@ -106,17 +76,5 @@ def _no_d_x(state):
         if "D_skip" in stack else stack for stack in state["layers"])}
 
 
-STATES = {"eight_bit_operands": _eight_bit, "no_d_x": _no_d_x}
-
-
-@pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_a_fault_fails_the_familys_tolerance(fault, monkeypatch, fresh_traces):
-    """Each in float32 compute, so that nothing but the fault is in the
-    error: it has to be far over what the bfloat16 program is allowed."""
-    state, sample = _state(), _sample()
-    FAULTS[fault](monkeypatch)
-    program_state = STATES.get(fault, lambda s: s)(state)
-    _, want = _reference()
-    loss, grads = family.program_loss_and_grads(CONFIG)(program_state, sample)
-    error = harness.relative_error(grads, want)
-    assert error > 2 * family.GRAD_RTOL, (fault, error)
+FAMILY = fc.NEMOTRON_H.with_cases(faults=FAULTS,
+                                  state_faults={"no_d_x": _no_d_x})

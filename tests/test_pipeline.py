@@ -50,8 +50,8 @@ def test_pp_gradients_match_dense():
     params = init_transformer(jax.random.PRNGKey(0), cfg)
     batch = _batch(cfg)
     loss_fn = make_pp_transformer_loss(cfg, _pp_mesh(4), n_micro=4)
-    g_pipe = jax.grad(lambda p: loss_fn(p, batch))(params)
-    g_dense = jax.grad(lambda p: transformer_loss(p, batch, cfg))(params)
+    g_pipe = jax.jit(jax.grad(lambda p: loss_fn(p, batch)))(params)
+    g_dense = jax.jit(jax.grad(lambda p: transformer_loss(p, batch, cfg)))(params)
     for a, b in zip(jax.tree.leaves(g_pipe), jax.tree.leaves(g_dense)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-5)
@@ -69,8 +69,8 @@ def test_pp_composes_with_dp():
     assert abs(dense - pipe) < 1e-5, (dense, pipe)
     # gradients too: the subtle transpose path is the dp pmean composed
     # with pp-sharded layer params under shard_map
-    g_pipe = jax.grad(lambda p: loss_fn(p, batch))(params)
-    g_dense = jax.grad(lambda p: transformer_loss(p, batch, cfg))(params)
+    g_pipe = jax.jit(jax.grad(lambda p: loss_fn(p, batch)))(params)
+    g_dense = jax.jit(jax.grad(lambda p: transformer_loss(p, batch, cfg)))(params)
     for a, b in zip(jax.tree.leaves(g_pipe), jax.tree.leaves(g_dense)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-5)

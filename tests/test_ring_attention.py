@@ -100,8 +100,10 @@ def test_ring_transformer_loss_matches_dense():
     ring = float(jax.jit(ring_loss)(params, (tokens, targets)))
     assert abs(dense - ring) < 1e-4, (dense, ring)
 
-    g = jax.grad(lambda p: ring_loss(p, (tokens, targets)))(params)
-    gd = jax.grad(lambda p: transformer_loss(p, (tokens, targets), cfg))(params)
+    # compiled, as a step runs them: an operation at a time the ring's
+    # gradient alone took 19 s idle (PR 47)
+    g = jax.jit(jax.grad(lambda p: ring_loss(p, (tokens, targets))))(params)
+    gd = jax.jit(jax.grad(lambda p: transformer_loss(p, (tokens, targets), cfg)))(params)
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(gd)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
@@ -178,8 +180,8 @@ def test_ring_blockwise_inner_loop(blk_k):
         np.asarray(out), np.asarray(_full_causal_attention(q, k, v)),
         rtol=1e-5, atol=1e-5,
     )
-    gr = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.jit(jax.grad(dense_loss, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gr, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)

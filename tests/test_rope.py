@@ -235,8 +235,9 @@ def test_both_passes_carry_the_rope_scope():
 
 def test_learned_positions_import_nothing_of_the_rotary_path():
     """On the way from the command to the window of a configuration with
-    learned positions nothing is new: no Pallas, no module of
-    `kungfu_tpu.ops` (the parent imports none there either)."""
+    learned positions nothing is new: no Pallas, and of `kungfu_tpu.ops` the
+    collectives alone, which the model offers its gradients to
+    (`collective.reduce_in_backward`, PR 47) and which import JAX only."""
     code = """
 import sys
 import jax, jax.numpy as jnp
@@ -253,4 +254,5 @@ print("MODULES", sorted(m for m in sys.modules
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=120, check=True,
                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert "MODULES []" in out.stdout, out.stdout + out.stderr
+    assert "MODULES ['kungfu_tpu.ops', 'kungfu_tpu.ops.collective']" in out.stdout, (
+        out.stdout + out.stderr)

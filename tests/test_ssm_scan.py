@@ -12,7 +12,7 @@ import pytest
 from benchmark.reference.nemotron_h import conv as plain_conv, ssm_recurrence
 from kungfu_tpu.ops import ssm_scan as module
 from kungfu_tpu.ops.gated_delta import causal_conv
-from kungfu_tpu.ops.ssm_scan import causal_conv_bias, ssm_scan
+from kungfu_tpu.ops.ssm_scan import ssm_scan
 
 
 def recurrence(q, k, v, g):
@@ -304,7 +304,7 @@ def test_causal_conv_with_a_bias_against_a_plain_loop(taps, dtype):
         for i in range(taps):
             if t - (taps - 1) + i >= 0:
                 want[:, t] += cs[i] * xs[:, t - (taps - 1) + i]
-    got = causal_conv_bias(x, c, bias)
+    got = causal_conv(x, c, bias)
     tol = 1e-6 if dtype == jnp.float32 else 1e-2
     assert got.dtype == dtype and got.shape == x.shape
     assert _rel(got, want) < tol
@@ -312,7 +312,7 @@ def test_causal_conv_with_a_bias_against_a_plain_loop(taps, dtype):
     assert _rel(plain_conv(x.astype(jnp.float32), c, bias), want) < 1e-6
     assert _rel(causal_conv(x, c).astype(jnp.float32) + bias, want) < tol
     # causal: a change at position 7 moves nothing before it
-    moved = causal_conv_bias(x.at[:, 7].add(1.0), c, bias)
+    moved = causal_conv(x.at[:, 7].add(1.0), c, bias)
     assert np.array_equal(np.asarray(moved[:, :7]), np.asarray(got[:, :7]))
     assert not np.array_equal(np.asarray(moved[:, 7]), np.asarray(got[:, 7]))
 
@@ -336,7 +336,7 @@ def test_the_biased_convs_written_out_backward_is_autodiffs(taps, dtype, tol):
             for i in range(taps))
 
     want = jax.grad(lambda *a: jnp.sum(plain(*a) * weight), (0, 1, 2))(x, c, bias)
-    got = jax.grad(lambda *a: jnp.sum(causal_conv_bias(*a).astype(jnp.float32)
+    got = jax.grad(lambda *a: jnp.sum(causal_conv(*a).astype(jnp.float32)
                                       * weight), (0, 1, 2))(x, c, bias)
     assert got[0].dtype == dtype and got[1].dtype == got[2].dtype == jnp.float32
     for g, w in zip(got, want):
