@@ -56,6 +56,21 @@ TPU-first design notes:
   groups of features behind the gate silu(z), W_out. `positions` "none" adds
   no position signal anywhere, and `expert_act` "relu2" makes the routed
   experts and the shared expert two matrices, W_down (relu(W_up x))^2.
+- The stacks may be run more than once a forward pass (PR 48, Ouro's looped
+  model): `loop_steps` T > 1 makes the layer scans the body of an outer scan
+  of T iterations over the one set of weights, the model's final norm at the
+  end of every loop step and the normed state what the next one reads
+  (`_hidden`, scope `loop_norm`); a shared leaf's gradient is the sum over
+  its T uses, so under S-SGD on several chips the stacks are averaged whole
+  and once, after the backward pass, and not a layer an iteration. The loss
+  is then the expected cross-entropy over T head passes on the shared head
+  under an exit distribution from a gate on the normed states
+  (`exit_gate_w`, `exit_gate_b`), less `exit_entropy_coef` times that
+  distribution's entropy (`_loop_losses`, scope `exit_gate`); each head
+  pass is run again in the backward pass (`_loop_step_rows`), and
+  `transformer_apply` gives the last loop step's logits. `post_norms` puts
+  a second RMSNorm behind each branch of a layer, on the branch's output
+  (`ln1_post_scale`, `ln2_post_scale`, scope `post_norm`).
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -162,6 +177,16 @@ class TransformerConfig:
     # the weight of their loss beside the main one
     mtp_depth: int = 0
     mtp_weight: float = 0.0
+    # a looped model (Ouro's, arXiv:2510.25741): the stacks are run this many
+    # times on the one set of weights, the final norm at the end of every
+    # loop step; more than 1 brings an exit gate (`exit_gate_w`, `_b`) and
+    # the expected loss over the loop steps' head passes, less
+    # `exit_entropy_coef` times the entropy of the exit distribution
+    loop_steps: int = 1
+    exit_entropy_coef: float = 0.0
+    # a second RMSNorm a branch, on the branch's output before the residual
+    # takes it (`ln1_post_scale`, `ln2_post_scale`)
+    post_norms: bool = False
     # one tuple of (field, value) pairs a layer: what replaces the fields
     # above for that layer; (): every layer is the configuration's own
     layer_kinds: Tuple = ()
@@ -225,6 +250,17 @@ class TransformerConfig:
                              "prediction module or none")
         if self.shared_gate and not self.shared_ff:
             raise ValueError("shared_gate gates the shared expert (shared_ff)")
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps}: the stacks are "
+                             "run once or more")
+        if self.exit_entropy_coef and self.loop_steps == 1:
+            raise ValueError("exit_entropy_coef weighs the exit distribution "
+                             "of a loop (loop_steps > 1)")
+        if self.loop_steps > 1 and (self.mtp_depth or (
+                self.ffn == "moe" and not self.layer_kinds)):
+            raise ValueError("a loop (loop_steps > 1) has no place for an "
+                             "expert layer's losses and counters a loop step, "
+                             "nor for a multi-token-prediction module")
         if (self.window or self.kv_heads != self.n_heads) and self.attn_core != "flash":
             raise ValueError("a window and grouped heads are the flash core's "
                              "(attn_core 'flash'); the dense core has neither")
@@ -340,6 +376,9 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
             layer["ln1_scale"] = unit(cfg, (D,))
         if cfg.ffn != "none":
             layer["ln2_scale"] = unit(cfg, (D,))
+        if cfg.post_norms:  # one behind each branch the layer has
+            for pre in list(layer):
+                layer[pre.replace("_scale", "_post_scale")] = unit(cfg, (D,))
         if cfg.mixer == "none":
             pass
         elif cfg.mixer == "mamba2":
@@ -463,6 +502,9 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
     if not cfg.tied_head:
         params["lm_head"] = dense(jax.random.fold_in(keys[1], 1),
                                   (cfg.vocab_size, D))
+    if cfg.loop_steps > 1:
+        params["exit_gate_w"] = dense(jax.random.fold_in(keys[1], 3), (D, 1))
+        params["exit_gate_b"] = jnp.zeros((), jnp.float32)
     if cfg.mtp_depth:
         tk = jax.random.split(jax.random.fold_in(keys[1], 2), 2)
         params["mtp"] = {
@@ -506,6 +548,9 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
             layers.update(ln1_scale=P(None), wo=P(None, t, None))
         if cfg.ffn != "none":
             layers.update(ln2_scale=P(None))
+        if cfg.post_norms:
+            layers.update({name.replace("_scale", "_post_scale"): P(None)
+                           for name in list(layers) if name.startswith("ln")})
         if cfg.mixer == "none":
             pass
         elif cfg.mixer == "mamba2":
@@ -569,6 +614,8 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
         specs["pos_embed"] = P()
     if not cfg.tied_head:
         specs["lm_head"] = P(t, None)
+    if cfg.loop_steps > 1:  # the exit gate whole on every chip
+        specs.update(exit_gate_w=P(None, None), exit_gate_b=P())
     if cfg.mtp_depth:
         specs["mtp"] = {
             "enorm_scale": P(), "hnorm_scale": P(), "eh_proj": P(None, None),
@@ -1122,26 +1169,39 @@ def _expert_layer(h, layer, cfg: TransformerConfig):
     return y, aux
 
 
+def _behind(y, layer, norm: str, cfg: TransformerConfig):
+    """A branch's output y behind its second norm where the configuration
+    has one (`post_norms`; scope `post_norm`), else as it is."""
+    if not cfg.post_norms:
+        return y
+    with jax.named_scope("post_norm"):
+        return _rmsnorm(y, _scale(layer[norm], cfg), cfg.norm_eps)
+
+
 def _layer(x, layer, cfg: TransformerConfig, core=None):
     """One layer -> (x, aux): a mixer and a feed-forward, each a residual
-    branch behind its own norm, or one of the two alone. aux is the expert
-    layer's `ops.moe.MoeAux` (router losses and token-choices per expert),
-    None of any other."""
+    branch behind its own norm, or one of the two alone; with `post_norms`
+    the branch's output goes through a second norm before the residual takes
+    it. aux is the expert layer's `ops.moe.MoeAux` (router losses and
+    token-choices per expert), None of any other."""
     dt, eps = cfg.dtype, cfg.norm_eps
     if cfg.mixer == "none":
         pass
     elif cfg.mixer == "mamba2":
         with jax.named_scope("ssm"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = x + _mamba2_mixer(h, layer, cfg)
+            x = x + _behind(_mamba2_mixer(h, layer, cfg), layer,
+                            "ln1_post_scale", cfg)
     elif cfg.mixer == "gated_delta":
         with jax.named_scope("gdn"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = x + _gated_delta_mixer(h, layer, cfg)
+            x = x + _behind(_gated_delta_mixer(h, layer, cfg), layer,
+                            "ln1_post_scale", cfg)
     elif cfg.mixer == "latent":
         with jax.named_scope("attn"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = x + _latent_attention(h, layer, cfg, core=core)
+            x = x + _behind(_latent_attention(h, layer, cfg, core=core), layer,
+                            "ln1_post_scale", cfg)
     else:
         with jax.named_scope("attn"):
             scales = ((_scale(layer["q_norm_scale"], cfg),
@@ -1150,10 +1210,12 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
             wqkv = (tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv"))
                     if cfg.split_qkv else layer["wqkv"].astype(dt))
-            x = x + _attention(h, wqkv, layer["wo"].astype(dt),
-                               cfg, core=core, qk_scales=scales,
-                               w_head_gate=(layer["w_head_gate"].astype(dt)
-                                            if cfg.head_gate else None))
+            x = x + _behind(
+                _attention(h, wqkv, layer["wo"].astype(dt),
+                           cfg, core=core, qk_scales=scales,
+                           w_head_gate=(layer["w_head_gate"].astype(dt)
+                                        if cfg.head_gate else None)),
+                layer, "ln1_post_scale", cfg)
     if cfg.ffn == "none":
         return x, None
     if cfg.ffn == "moe":
@@ -1161,15 +1223,18 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
             B, S, D = x.shape
             h = _rmsnorm(x, _scale(layer["ln2_scale"], cfg), eps).reshape(B * S, D)
             y, aux = _expert_layer(h, layer, cfg)
-            return x + y.reshape(B, S, D), aux
+            return x + _behind(y.reshape(B, S, D), layer, "ln2_post_scale",
+                               cfg), aux
     with jax.named_scope("ffn"):
         h = _rmsnorm(x, _scale(layer["ln2_scale"], cfg), eps)
         if cfg.ffn == "swiglu":
-            return x + _silu_gate_out(h @ layer["w_gate"].astype(dt),
-                                      h @ layer["w_up"].astype(dt),
-                                      layer["w_down"].astype(dt)), None
-        pre = h @ layer["w_in"].astype(dt)
-        return x + _gelu_out(pre, layer["w_out"].astype(dt)), None
+            y = _silu_gate_out(h @ layer["w_gate"].astype(dt),
+                               h @ layer["w_up"].astype(dt),
+                               layer["w_down"].astype(dt))
+        else:
+            y = _gelu_out(h @ layer["w_in"].astype(dt),
+                          layer["w_out"].astype(dt))
+        return x + _behind(y, layer, "ln2_post_scale", cfg), None
 
 
 # `layer_remat`: the scan keeps the layer's input and, of what the layer
@@ -1179,7 +1244,12 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
 # layer of 16,384 positions of 2,048): the projections, the rotation and the
 # feed-forward are run again in the backward pass, the forward kernel and
 # the DeltaNet mixer's head blocks are not (the blocks run their forward
-# once more for their own gradients, `_delta_heads`: twice a step in all)
+# once more for their own gradients, `_delta_heads`: twice a step in all).
+# Under a loop every application of a layer keeps its own: the Ouro cell's 32
+# applications (8 layers x 4 loop steps) of 16 heads of 128 at 4,096 positions
+# keep 2 x 16.8 MB each and the row sums, 1.08 GB a step beside the 0.82 GB of
+# bfloat16 copies of the 8 layers' weights: kept activations to weights four
+# times any other cell's
 _layer_again = jax.checkpoint(
     _layer, static_argnums=(2,), prevent_cse=False,
     policy=jax.checkpoint_policies.save_only_these_names(
@@ -1193,10 +1263,12 @@ def _block(x, layer, cfg: TransformerConfig, core=None):
     return _layer(x, layer, cfg, core=core)[0]
 
 
-def _head_logits(params, x, cfg: TransformerConfig):
+def _head_logits(params, x, cfg: TransformerConfig, normed: bool = False):
     """Final norm and the LM head, tied to the embedding or `lm_head` of its
-    own, in float32."""
-    h = _rmsnorm(x, _scale(params["ln_f_scale"], cfg), cfg.norm_eps)
+    own, in float32; `normed`: x has been through the final norm already (a
+    loop step's state), and the head is all there is to do."""
+    h = x if normed else _rmsnorm(x, _scale(params["ln_f_scale"], cfg),
+                                  cfg.norm_eps)
     head = params["embed"] if cfg.tied_head else params["lm_head"]
     return h.astype(jnp.float32) @ head.astype(jnp.float32).T
 
@@ -1225,21 +1297,34 @@ def _xent(logits, targets):
     return _xent_fwd(logits, targets)[0]
 
 
-def _xent_fwd(logits, targets):
+def _xent_rows_fwd(logits, targets):
+    """`_xent` before its mean, a number a row, and its residuals."""
     lse = jax.nn.logsumexp(logits, axis=-1)  # shifted by the row maximum
     picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - picked), (logits, lse, targets)
+    return lse - picked, (logits, lse, targets)
 
 
-def _xent_bwd(res, g):
-    """(softmax - onehot) * g / rows, elementwise in the residuals, the
-    one-hot as a comparison with an iota and not a scatter: XLA fuses it
-    into the operands of the two backward matmuls."""
+def _xent_fwd(logits, targets):
+    rows, res = _xent_rows_fwd(logits, targets)
+    return jnp.mean(rows), res
+
+
+def _dlogits(res, weight):
+    """(softmax - onehot) * weight(), a scalar or a number a row (..., 1),
+    elementwise in the residuals, the one-hot as a comparison with an iota
+    and not a scatter: XLA fuses it into the operands of the two backward
+    matmuls. (`weight` is called where the product wants it, so that
+    `_xent`'s backward pass traces as it always did.)"""
     logits, lse, targets = res
     hot = jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, logits.ndim - 1) == targets[..., None]
-    dlogits = (jnp.exp(logits - lse[..., None]) - hot) * (g / lse.size)
+    dlogits = (jnp.exp(logits - lse[..., None]) - hot) * weight()
     return dlogits.astype(logits.dtype), None
+
+
+def _xent_bwd(res, g):
+    """(softmax - onehot) * g / rows."""
+    return _dlogits(res, lambda: g / res[1].size)
 
 
 _xent.defvjp(_xent_fwd, _xent_bwd)
@@ -1257,39 +1342,84 @@ def _embed(params, tokens, cfg: TransformerConfig):
         return x
 
 
-def _hidden(params, tokens, cfg: TransformerConfig):
+def _loop_step_end(u, params, cfg: TransformerConfig, each):
+    """The end of a loop step on the stacks' output u: the model's final
+    norm (scope `loop_norm`) -> (what the next loop step reads, what the
+    loop hands back of this one), both of the normed state."""
+    with jax.named_scope("loop_norm"):
+        x = _rmsnorm(u, _scale(params["ln_f_scale"], cfg), cfg.norm_eps)
+    return x, each(x) if each else x
+
+
+def _hidden(params, tokens, cfg: TransformerConfig, each=None):
     """-> (final hidden states, the expert layers' stacked aux or None),
     one scan for each stack of layers of one kind. Under plain S-SGD on
     several chips a layer's gradients are averaged in the iteration of the
     backward scan that produces them (`ops.collective.reduce_in_backward`,
-    the identity otherwise)."""
+    the identity otherwise).
+
+    Under a loop (`loop_steps` T > 1) the scans are the body of an outer
+    `lax.scan` of T iterations over the same stacked trees, with the final
+    norm at the end of every loop step (scope `loop_norm`): the normed state
+    is what the next loop step reads, and the T of them, (T, B, S, D), are
+    handed back in the place of the one un-normed state, or, where the
+    caller gives `each`, what `each(normed state)` makes of them, computed
+    inside the loop step and stacked (the loss's head passes: one after
+    another, each beside the backward pass of its own loop step, and no
+    schedule of XLA's choosing with four logits arrays alive). A shared leaf's
+    gradient is the sum over its T uses, which the outer scan's backward
+    pass carries and adds to, so there the stacks go through
+    `reduce_in_backward` whole and once, before the loop: each leaf is
+    averaged once a step, after the sum. (T scans in a Python loop leave the
+    order of the T backward scans and their head passes to XLA: the Ouro
+    cell's step then wants 19.3 GB of the chip's 16.9 and the outer scan
+    15.2, `benchmark/aot_check.py`, PR 48.)"""
     x = _embed(params, tokens, cfg)
     stacks = params["layers"] if cfg.layer_kinds else (params["layers"],)
-    auxes = []
-    for (kind, _), stacked in zip(cfg.stacks, stacks, strict=True):
+    looped = cfg.loop_steps > 1
+    if looped:
+        stacks = collective.reduce_in_backward(stacks)
 
-        run = _layer_again if kind.layer_remat else _layer
+    def run_stacks(x):
+        auxes = []
+        for (kind, _), stacked in zip(cfg.stacks, stacks, strict=True):
 
-        def body(x, layer, kind=kind, stacked=stacked, run=run):
-            return run(
-                x, collective.reduce_in_backward(layer, of=stacked), kind)
+            run = _layer_again if kind.layer_remat else _layer
 
-        x, aux = jax.lax.scan(body, x, stacked)
-        if aux is not None:
-            auxes.append(aux)
+            def body(x, layer, kind=kind, stacked=stacked, run=run):
+                if not looped:
+                    layer = collective.reduce_in_backward(layer, of=stacked)
+                return run(x, layer, kind)
+
+            x, aux = jax.lax.scan(body, x, stacked)
+            if aux is not None:
+                auxes.append(aux)
+        return x, auxes
+
+    if looped:
+        def loop_step(x, _):
+            return _loop_step_end(run_stacks(x)[0], params, cfg, each)
+
+        return jax.lax.scan(loop_step, x, None, length=cfg.loop_steps)[1], None
+    x, auxes = run_stacks(x)
     if len(auxes) > 1:  # the expert layers' aux, stack after stack
         return x, jax.tree.map(lambda *a: jnp.concatenate(a), *auxes)
     return x, auxes[0] if auxes else None
 
 
 def transformer_hidden(params, tokens, cfg: TransformerConfig):
-    """tokens (B, S) int32 -> final hidden states (B, S, D) pre-norm."""
-    return _hidden(params, tokens, cfg)[0]
+    """tokens (B, S) int32 -> final hidden states (B, S, D) pre-norm; of a
+    loop, the last loop step's, which the final norm has been over."""
+    x = _hidden(params, tokens, cfg)[0]
+    return x[-1] if cfg.loop_steps > 1 else x
 
 
 def transformer_apply(params, tokens, cfg: TransformerConfig):
-    """tokens (B, S) int32 -> logits (B, S, V) in f32."""
-    return _head_logits(params, transformer_hidden(params, tokens, cfg), cfg)
+    """tokens (B, S) int32 -> logits (B, S, V) in f32; of a loop those of
+    its last loop step (no early exit: the published
+    `early_exit_threshold` 1)."""
+    return _head_logits(params, transformer_hidden(params, tokens, cfg), cfg,
+                        normed=cfg.loop_steps > 1)
 
 
 def _mtp_hidden(params, x, tokens_next, cfg: TransformerConfig):
@@ -1321,16 +1451,21 @@ def _split_batch(batch, cfg: TransformerConfig):
     return batch[:, :S], batch[:, 1:S + 1], batch[:, 2:] if cfg.mtp_depth else None
 
 
+def _reduced_outside_the_stacks(params):
+    """`params` with the leaves outside the layer scan (the stacks' go
+    through `_hidden`'s) passed through `reduce_in_backward`: under plain
+    S-SGD on several chips their gradients are averaged where the backward
+    pass completes them."""
+    return {**collective.reduce_in_backward(
+        {k: v for k, v in params.items() if k != "layers"}),
+        "layers": params["layers"]}
+
+
 def _losses(params, batch, cfg: TransformerConfig):
     """-> (main next-token loss, the multi-token-prediction module's loss
     or None, the expert layers' aux of the stack or None)."""
     tokens, targets, ahead = _split_batch(batch, cfg)
-    # the leaves outside the layer scan (the stack's go through `_hidden`'s):
-    # under plain S-SGD on several chips their gradients are averaged where
-    # the backward pass completes them
-    params = {**collective.reduce_in_backward(
-        {k: v for k, v in params.items() if k != "layers"}),
-        "layers": params["layers"]}
+    params = _reduced_outside_the_stacks(params)
     x, aux = _hidden(params, tokens, cfg)
     loss = lm_head_loss(params, x, targets, cfg)
     if not cfg.mtp_depth:
@@ -1343,13 +1478,102 @@ def _losses(params, batch, cfg: TransformerConfig):
         return loss, lm_head_loss(own, x, ahead, cfg), aux
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _loop_step_rows(head, x, targets, cfg: TransformerConfig):
+    """A loop step's head pass on its normed state x -> the cross-entropy a
+    position, `_xent` before its mean: logsumexp(logits) - logits[target] of
+    `_head_logits`, `head` the leaf it reads, for a loss that weighs the rows
+    itself. Keeps x, the head, the targets and the rows' log-sum-exp, and
+    makes the logits again in the backward pass: a float32 logits array of
+    the Ouro cell is 0.81e9 bytes, and with the four of a step kept the step
+    does not fit the chip (15.95 GiB of its 15.75, `benchmark/aot_check.py`,
+    PR 48); the head's product once more a loop step is 0.8 of the step's 57
+    TFLOP each. A `custom_vjp` of its own where `_recompute` would do, for
+    the log-sum-exp it keeps: the checkpoint makes that again too, a second
+    pass over the logits, and the cell's step reads 605.3 ms for 586.7
+    (`head_loss_ms` 94.4 for 75.8; my chip runs, PR 48). The barrier is for a
+    caller outside a scan, where XLA would merge the second product with the
+    first and keep the logits."""
+    return _xent_rows_fwd(_head_logits(head, x, cfg, normed=True), targets)[0]
+
+
+def _loop_step_rows_fwd(head, x, targets, cfg):
+    rows, (_, lse, _) = _xent_rows_fwd(
+        _head_logits(head, x, cfg, normed=True), targets)
+    return rows, (head, x, targets, lse)
+
+
+def _loop_step_rows_bwd(cfg, res, g):
+    head, x, targets, lse = res
+    x, g = jax.lax.optimization_barrier((x, g))
+    logits, pull = jax.vjp(
+        lambda head, x: _head_logits(head, x, cfg, normed=True), head, x)
+    return (*pull(_dlogits((logits, lse, targets),
+                           lambda: g[..., None])[0]), None)
+
+
+_loop_step_rows.defvjp(_loop_step_rows_fwd, _loop_step_rows_bwd)
+
+
+def _exit_log_shares(gates):
+    """The exit distribution of a loop from its gates g_1..g_{T-1}, (T - 1,
+    ...) float32, as log p_1..log p_T (T, ...): lambda_t = sigmoid(g_t), p_t
+    = lambda_t prod_{j<t} (1 - lambda_j), and p_T = prod_{j<T} (1 -
+    lambda_j) takes what is left, so the T sum to one; in logarithms, where
+    a gate far from 0 loses nothing."""
+    left = jnp.cumsum(jax.nn.log_sigmoid(-gates), axis=0)  # j <= t
+    before = jnp.concatenate([jnp.zeros_like(left[:1]), left[:-1]])  # j < t
+    return jnp.concatenate([jax.nn.log_sigmoid(gates) + before, left[-1:]])
+
+
+def _loop_losses(params, batch, cfg: TransformerConfig):
+    """The parts of a loop's loss, each a float32 scalar or one a loop step
+    (T,): `loss` = mean over positions of [sum_t p_t l_t - beta H(p)], l_t
+    the next-token cross-entropy of loop step t's head pass (`lm_head_loss`'s
+    head and `_xent`'s residuals, a pass a loop step one after another, on
+    the state the final norm has been over), p the exit distribution from
+    the gates g_t = x_t w_g + b_g on the same states (float32; the last loop
+    step's gate is read by nothing and not computed), H(p) = -sum_t p_t log
+    p_t and beta `exit_entropy_coef`: the first-stage objective of Ouro's
+    paper (arXiv:2510.25741); `loop` the mean l_t, `exit_share` the mean p_t,
+    `exit_entropy` the mean H. Scopes `head_loss` over the passes, and
+    `exit_gate` for the gates' product, the distribution, the expected loss
+    and the entropy."""
+    tokens, targets, _ = _split_batch(batch, cfg)
+    params = _reduced_outside_the_stacks(params)
+    f32 = jnp.float32
+    head = {k: params[k] for k in ("embed" if cfg.tied_head else "lm_head",)}
+
+    def head_pass(x):
+        with jax.named_scope("head_loss"):
+            return x, _loop_step_rows(head, x, targets, cfg)
+
+    (states, rows), _ = _hidden(params, tokens, cfg, each=head_pass)  # (T, B, S, ...)
+    with jax.named_scope("exit_gate"):
+        gates = jnp.dot(states[:-1].astype(f32),
+                        params["exit_gate_w"].astype(f32),
+                        precision=jax.lax.Precision.HIGHEST)[..., 0]
+        logp = _exit_log_shares(gates + params["exit_gate_b"].astype(f32))
+        p = jnp.exp(logp)
+        entropy = -jnp.sum(p * logp, axis=0)
+        expected = jnp.sum(p * rows, axis=0)
+        return {"loss": jnp.mean(expected - cfg.exit_entropy_coef * entropy),
+                "loop": jnp.mean(rows, axis=(1, 2)),
+                "exit_share": jnp.mean(p, axis=(1, 2)),
+                "exit_entropy": jnp.mean(entropy)}
+
+
 def transformer_loss(params, batch, cfg: TransformerConfig):
     """Next-token cross-entropy, plus the expert layers' load-balancing and
     router z-losses (each a mean over the layers) at the configuration's
     coefficients, plus `mtp_weight` times the multi-token-prediction
     module's cross-entropy where the configuration has one (both means over
-    the S positions). batch = tokens (B, S+1) or (tokens, targets); with
-    the module, ids (B, S+2)."""
+    the S positions); of a loop (`loop_steps` > 1) the expected
+    cross-entropy over its exit distribution less `exit_entropy_coef` times
+    that distribution's entropy (`_loop_losses`). batch = tokens (B, S+1) or
+    (tokens, targets); with the module, ids (B, S+2)."""
+    if cfg.loop_steps > 1:
+        return _loop_losses(params, batch, cfg)["loss"]
     loss, mtp_loss, aux = _losses(params, batch, cfg)
     if mtp_loss is not None:
         loss = loss + cfg.mtp_weight * mtp_loss
@@ -1361,17 +1585,26 @@ def transformer_loss(params, batch, cfg: TransformerConfig):
 
 
 def transformer_losses(params, batch, cfg: TransformerConfig):
-    """The parts of `transformer_loss` on one batch, each a scalar: `main`,
-    the next-token cross-entropy, and `mtp`, the multi-token-prediction
-    module's, where the configuration has one. Jit this beside the step, as
-    `routing_stats`: the step returns their weighted sum and nothing else."""
+    """The parts of `transformer_loss` on one batch: `main`, the next-token
+    cross-entropy (of a loop, its last loop step's), and `mtp`, the
+    multi-token-prediction module's, where the configuration has one, each a
+    scalar; of a loop also `loop`, every loop step's cross-entropy, and
+    `exit_share`, the batch's mean exit share of each, (T,) both, and
+    `exit_entropy`. Jit this beside the step, as `routing_stats`: the step
+    returns their weighted sum and nothing else."""
+    if cfg.loop_steps > 1:
+        parts = _loop_losses(params, batch, cfg)
+        return {"main": parts["loop"][-1], **{
+            k: parts[k] for k in ("loop", "exit_share", "exit_entropy")}}
     loss, mtp_loss, _ = _losses(params, batch, cfg)
     return {"main": loss} if mtp_loss is None else {"main": loss, "mtp": mtp_loss}
 
 
 def record_losses(losses, registry=None) -> None:
     """`transformer_losses`' numbers as gauges of `telemetry.metrics`:
-    `kungfu_lm_loss` and, beside it where there is one, `kungfu_mtp_loss`."""
+    `kungfu_lm_loss` and, beside it where there is one, `kungfu_mtp_loss`;
+    of a loop `kungfu_loop_loss` and `kungfu_exit_share`, a series a loop
+    step (`step`, from 1), and `kungfu_exit_entropy`."""
     from kungfu_tpu.telemetry import metrics
 
     reg = registry or metrics.REGISTRY
@@ -1380,6 +1613,17 @@ def record_losses(losses, registry=None) -> None:
     if "mtp" in losses:
         reg.gauge("kungfu_mtp_loss", "the multi-token-prediction module's "
                   "cross-entropy on the same batch").set(float(losses["mtp"]))
+    if "loop" in losses:
+        loop = reg.gauge("kungfu_loop_loss", "a loop step's next-token "
+                         "cross-entropy on the same batch", ("step",))
+        share = reg.gauge("kungfu_exit_share", "the batch's mean share of "
+                          "the exit distribution at a loop step", ("step",))
+        for t, (l, p) in enumerate(zip(np.asarray(losses["loop"]),
+                                       np.asarray(losses["exit_share"])), 1):
+            loop.labels(t).set(float(l))
+            share.labels(t).set(float(p))
+        reg.gauge("kungfu_exit_entropy", "the batch's mean entropy of the "
+                  "exit distribution").set(float(losses["exit_entropy"]))
 
 
 def routing_stats(params, tokens, cfg: TransformerConfig):

@@ -19,7 +19,8 @@ import pytest
 from jax.sharding import PartitionSpec
 
 from benchmark import harness, manifest as mf
-from benchmark.families import glm4_moe_lite, laguna, nemotron_h, qwen3_next
+from benchmark.families import (glm4_moe_lite, laguna, nemotron_h, ouro,
+                                qwen3_next)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import param_pspecs
 from kungfu_tpu.ops import moe
@@ -93,9 +94,12 @@ class Family:
         gates off one half, routers with preferences, experts that weigh."""
         state = self.module.init(self.config, self.seed)
         key = jax.random.PRNGKey(self.seed + 100)
+        stacks = state["layers"]  # a tuple of stacks, or the one stack
         state = {**state,
-                 "layers": tuple(self.trained(stack, key, s)
-                                 for s, stack in enumerate(state["layers"])),
+                 "layers": (self.trained(stacks, key, 0)
+                            if isinstance(stacks, dict) else
+                            tuple(self.trained(stack, key, s)
+                                  for s, stack in enumerate(stacks))),
                  "ln_f_scale": state["ln_f_scale"] + 0.3 * jax.random.normal(
                      key, state["ln_f_scale"].shape)}
         return self.trained_more(self, state, key) if self.trained_more else state
@@ -280,7 +284,36 @@ NEMOTRON_H = Family(
     constants=("router_bias",),
     recomputed=((), tuple(nemotron_h.LAYER_NAMES.values())))
 
-FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H)
+
+
+def _ouro_gate(family, state, key):
+    """A gate with preferences (g of a position some units from 0, off
+    centre) and a head whose logits weigh, so that the exit shares differ a
+    position and a loop step and the gate's gradient is a part of the
+    whole."""
+    return {**state, "exit_gate_w": 12.0 * state["exit_gate_w"],
+            "exit_gate_b": state["exit_gate_b"] - 0.7,
+            "lm_head": 4.0 * state["lm_head"]}
+
+
+# the cell's model in small: two layers run four times, 4 heads of 16, a
+# feed-forward of 96, 64 positions; the entropy's weight 40 times the cell's,
+# so that its sign and the gate's gradient weigh in the whole gradient
+OURO = Family(
+    name="ouro", cell="ouro_2_6b.ssgd_loop4_4k_1chip", module=ouro,
+    tiny=dict(hidden_size=64, intermediate_size=96, head_dim=16,
+              num_attention_heads=4, num_key_value_heads=4,
+              num_hidden_layers=2, vocab_size=256, sequence_length=64,
+              exit_entropy_coef=2.0, flash_blocks=[32, 32],
+              flash_interpret=True, compute_dtype="float32"),
+    scales={"wq": 6.0, "wk": 6.0, "wv": 3.0},
+    norms=("ln1_scale", "ln2_scale", "ln1_post_scale", "ln2_post_scale"),
+    trained_more=_ouro_gate,
+    scopes=("attn/attn_full/attn_core", "attn/post_norm", "ffn/post_norm",
+            "rope/", "loop_norm", "head_loss", "exit_gate", "ffn"),
+    recomputed=((), (ouro.FULL,)))
+
+FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H, OURO)
 
 
 def pytest_generate_tests(metafunc):
@@ -315,8 +348,9 @@ def test_float32_program_equals_the_reference(family):
             continue
         assert float(jnp.abs(g).max()) > 0, name
         assert harness.relative_error(g, w) <= 1e-3, name
-    assert family.module.differing_choices(
-        family.config, family.state(), family.sample()) == 0
+    if family.expert_layers:
+        assert family.module.differing_choices(
+            family.config, family.state(), family.sample()) == 0
 
 
 def test_bfloat16_program_is_within_the_familys_tolerances(family):
@@ -386,6 +420,10 @@ def test_the_share_drops_nothing_and_counts_its_rows(family):
     module, config = family.module, family.config
     state, sample = family.state(), family.sample()
     mc = module.model_config(config)
+    if not family.expert_layers:  # no router, and the program says so
+        with pytest.raises(ValueError, match="no expert layer"):
+            transformer.routing_stats(state, sample[:, :-1], mc)
+        return
     layers, held = list(family.expert_layers), mc.experts_held[1]
     tokens = 2 * config["sequence_length"]
     stats = module.routing_stats(config, state, sample)
