@@ -703,3 +703,122 @@ def _all_eqns(jaxpr):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
                     yield from _all_eqns(sub)
+
+
+# --- the count of the token-choices an expert (PR 50) --------------------------
+
+# routings made to break a count, over 8 experts: (top_k, the experts that
+# every token prefers by far; the rest of its choices fall where noise says)
+COUNT_ROUTINGS = {
+    "every_choice_on_one_expert": (1, [5]),
+    "experts_that_get_none": (2, [1, 2, 6]),
+    "top_k_is_every_expert": (8, []),
+}
+# the whole layer, a share's window at the first, a middle and the last
+# experts, and the expert axis with room for every choice and with room
+# for one a bucket
+COUNT_PATHS = {"whole": None, "held_first": (0, 2), "held_middle": (3, 2),
+               "held_last": (6, 2), "axis": 8.0, "axis_dropping": 1.0}
+
+
+def _routed(preferred, T=32, D=8, F=4, E=8):
+    """Positive tokens and a router whose `preferred` columns outweigh all
+    others for every token."""
+    ks = jax.random.split(jax.random.PRNGKey(len(preferred)), 4)
+    x = 1.0 + jnp.abs(jax.random.normal(ks[0], (T, D)))
+    router = 0.05 * jax.random.normal(ks[1], (D, E))
+    router = router.at[:, jnp.asarray(preferred, jnp.int32)].add(1.0)
+    w_in = jax.random.normal(ks[2], (E, D, F)) * 0.3
+    w_out = jax.random.normal(ks[3], (E, F, D)) * 0.3
+    return x, router, w_in, w_out
+
+
+@pytest.mark.parametrize("path", COUNT_PATHS)
+@pytest.mark.parametrize("routing", COUNT_ROUTINGS)
+def test_counts_are_the_bincount_of_the_chosen_experts(routing, path):
+    """`MoeAux.counts` is numpy's `bincount` of `MoeAux.chosen`: of all E
+    experts in the whole layer, of the window held in a share (its `sizes`,
+    the grouped matmuls' groups), and across an expert axis of the choices
+    kept, a shard's own (at most the capacity of each (expert, choice)
+    bucket)."""
+    from kungfu_tpu.ops.moe import moe_ffn
+
+    top_k, preferred = COUNT_ROUTINGS[routing]
+    T, E = 32, 8
+    x, router, w_in, w_out = _routed(preferred, T=T, E=E)
+    how = COUNT_PATHS[path]
+    if not path.startswith("axis"):
+        first, count = how or (0, E)
+        _, aux = jax.jit(lambda x, r, w: moe_ffn(
+            x, r, w, top_k=top_k, held=how))(
+                x, router, (w_in[first:first + count], w_out[first:first + count]))
+        chosen = np.asarray(aux.chosen)
+        want = np.bincount(chosen.ravel(), minlength=E)
+        assert set(preferred[:top_k]) <= set(chosen[0].tolist())
+        assert aux.counts.dtype == jnp.int32
+        assert aux.counts.tolist() == want[first:first + count].tolist()
+        return
+    ep = 4
+    mesh = _ep_mesh(ep)
+
+    def shard_fn(x_sh, router, w_in_sh, w_out_sh):
+        _, aux = moe_ffn(x_sh, router, (w_in_sh, w_out_sh), "ep", ep,
+                         top_k=top_k, capacity_factor=how)
+        return aux.counts[None], aux.chosen, aux.load_balance
+
+    counts, chosen, balance = jax.jit(shard_map(
+        shard_fn, mesh=mesh, in_specs=(P("ep"), P(), P("ep"), P("ep")),
+        out_specs=(P("ep"), P("ep"), P()), check_vma=False))(
+            x, router, w_in, w_out)
+    chosen = np.asarray(chosen).reshape(ep, T // ep, top_k)
+    capacity = max(1, int(how * (T // ep) / E))
+    want = [sum(np.minimum(np.bincount(mine[:, j], minlength=E), capacity)
+                for j in range(top_k)) for mine in chosen]
+    assert np.asarray(counts).tolist() == [w.tolist() for w in want]
+    dropped = T * top_k - int(np.asarray(counts).sum())
+    assert (dropped > 0) == (path == "axis_dropping")
+    # the balance loss sees every choice, kept or not: the mean of the shards'
+    probs = jax.nn.softmax(jnp.dot(x, router, precision="highest"), -1)
+    asked = np.stack([np.bincount(mine.ravel(), minlength=E) for mine in chosen])
+    mean_probs = np.asarray(probs).reshape(ep, T // ep, E).mean(1)
+    assert float(balance) == pytest.approx(float(np.mean(
+        E * np.sum(asked / (T // ep * top_k) * mean_probs, -1))), rel=1e-5)
+
+
+@pytest.mark.parametrize("chosen,E", [
+    ([[3, 0], [3, 3], [1, 0]], 4),      # two dimensions, an expert with none
+    ([7, 7, 7, 8, -1, 2], 8),           # 8 and -1 name no expert
+    ([], 3),
+])
+def test_count_choices_is_numpys_bincount(chosen, E):
+    from kungfu_tpu.ops.moe import _count_choices
+
+    chosen = np.asarray(chosen, np.int32)
+    inside = chosen[(chosen >= 0) & (chosen < E)]
+    got = _count_choices(jnp.asarray(chosen), E)
+    assert got.dtype == jnp.int32 and got.shape == (E,)
+    assert got.tolist() == np.bincount(inside, minlength=E).tolist()
+
+
+@pytest.mark.parametrize("held", [None, (4, 4)], ids=["whole", "held"])
+def test_the_forward_pass_counts_without_a_scatter(held):
+    """No `scatter-add` of one scalar a token-choice is left in the
+    layer's forward pass (the v5e applies such updates one after another:
+    `ops/moe._count_choices`). What stays: the whole layer's inverse order
+    (a `scatter`, no sum), and in a share `_chunk_part`'s one-element
+    `here.at[-1].add` and its rows' way back, (chunk, D) into (T, D)."""
+    moe, x, router, experts, gates = _share_setup(E=16)
+    first, count = held or (0, 16)
+    mine = tuple(w[first:first + count] for w in experts)
+    jaxpr = jax.make_jaxpr(lambda x, r, w: moe.moe_ffn(
+        x, r, w, top_k=3, gates=gates, expert_fn=moe.swiglu_experts,
+        held=held))(x, router, mine)
+    adds = [[v.aval.shape for v in eqn.invars] for eqn in _all_eqns(jaxpr.jaxpr)
+            if eqn.primitive.name == "scatter-add"]
+    scalars = [shapes for shapes in adds if len(shapes[2]) < 2]
+    assert all(int(np.prod(updates)) == 1 for _, _, updates in scalars), scalars
+    if held is None:
+        assert adds == []
+    else:  # the walk reaches the share's loop
+        assert [operand for operand, _, _ in scalars] == [(count,)]
+        assert [operand for operand, _, _ in adds if len(operand) == 2] == [x.shape]
