@@ -193,4 +193,5 @@ def test_every_worker_records_the_launcher_and_placement_spans(
 @pytest.mark.parametrize("rank", [0, 1])
 def test_the_broadcast_span_carries_bytes_and_leaves(kfrun_spans, rank):
     args = kfrun_spans[rank]["broadcast.one_to_all"]["args"]
-    assert args == {"leaves": 2, "bytes": 64 * 32 * 4 + 32 * 4}
+    nbytes = 64 * 32 * 4 + 32 * 4  # numpy leaves: all of it from the host
+    assert args == {"leaves": 2, "bytes": nbytes, "host_bytes": nbytes}
