@@ -71,6 +71,23 @@ TPU-first design notes:
   `transformer_apply` gives the last loop step's logits. `post_norms` puts
   a second RMSNorm behind each branch of a layer, on the branch's output
   (`ln1_post_scale`, `ln2_post_scale`, scope `post_norm`).
+- Four multipliers (PR 52, Granite 4.0's): the embedding's rows times
+  `embedding_multiplier`, a branch's output times `residual_multiplier`
+  where the residual takes it, the attention scores times
+  `attention_multiplier` in the place of 1 / sqrt(head size), the logits over
+  `logits_scaling`; each 1 (or unset) leaves the program as it was. A Mamba-2
+  mixer may have a feed-forward behind it in one layer, and a tied head may
+  stand behind a state-space stack.
+- A row may be several documents (PR 52): `end_of_document` names the id that
+  ends one, and the ids are the only carrier. `_segments` numbers each
+  position's document (scope `segments`), and the numbers go to every mixer
+  of the stack, through the layer scans and `_layer_again` alike: a
+  convolution tap does not reach into an earlier document
+  (`ops.gated_delta.causal_conv`), the scan's state is zero before a
+  document's first position (`ops.ssm_scan`), and a query sees the keys of
+  its own document (`ops.flash_attention`). The loss is over every position.
+  `packing_stats` says what a batch is made of. Without the id every program
+  is what it was.
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -187,6 +204,19 @@ class TransformerConfig:
     # a second RMSNorm a branch, on the branch's output before the residual
     # takes it (`ln1_post_scale`, `ln2_post_scale`)
     post_norms: bool = False
+    # Granite's four multipliers: the embedding's rows times the first, a
+    # branch's output times `residual_multiplier` where the residual takes
+    # it, the attention scores times `attention_multiplier` in the place of
+    # 1 / sqrt(head size) (0: that), the logits over `logits_scaling`
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # packed documents: the id that is a document's last position. Each
+    # position's document is numbered from the ids (`_segments`) and every
+    # mixer keeps to it: no tap, state or key of another document. None: a
+    # row is one document
+    end_of_document: int | None = None
     # one tuple of (field, value) pairs a layer: what replaces the fields
     # above for that layer; (): every layer is the configuration's own
     layer_kinds: Tuple = ()
@@ -261,9 +291,19 @@ class TransformerConfig:
             raise ValueError("a loop (loop_steps > 1) has no place for an "
                              "expert layer's losses and counters a loop step, "
                              "nor for a multi-token-prediction module")
-        if (self.window or self.kv_heads != self.n_heads) and self.attn_core != "flash":
-            raise ValueError("a window and grouped heads are the flash core's "
-                             "(attn_core 'flash'); the dense core has neither")
+        if (self.window or self.kv_heads != self.n_heads
+                or self.attention_multiplier) and self.attn_core != "flash":
+            raise ValueError("a window, grouped heads and a scale of the "
+                             "scores' own are the flash core's (attn_core "
+                             "'flash'); the dense core has none of them")
+        if self.end_of_document is not None and (
+                self.mtp_depth or self.mixer in ("gated_delta", "latent")
+                or (self.mixer == "attention" and self.attn_core != "flash")):
+            raise ValueError(
+                "packed documents (end_of_document) are kept apart by the "
+                "Mamba-2 mixer and by the flash core of softmax attention: "
+                f"not by mixer {self.mixer!r} on the {self.attn_core} core, "
+                "nor by a multi-token-prediction module")
         if self.layer_kinds and len(self.layer_kinds) != self.n_layers:
             raise ValueError(f"{len(self.layer_kinds)} layer kinds for "
                              f"{self.n_layers} layers")
@@ -790,9 +830,9 @@ def attention_core_of(cfg: TransformerConfig):
     from kungfu_tpu.ops.flash_attention import flash_attention
 
     blk_q, blk_k = cfg.flash_blocks
-    return lambda q, k, v: flash_attention(q, k, v, True, None, blk_q, blk_k,
-                                           cfg.flash_interpret,
-                                           cfg.window or None)
+    return lambda q, k, v, *segments: flash_attention(
+        q, k, v, True, cfg.attention_multiplier or None, blk_q, blk_k,
+        cfg.flash_interpret, cfg.window or None, *segments)
 
 
 def _gated_out(ctx, pre, wo):
@@ -859,7 +899,7 @@ _feature_gated_out_kept = _recompute(_feature_gated_out)
 
 
 def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
-               w_head_gate=None):
+               w_head_gate=None, segments=()):
     """QKV projection + head reshape around a pluggable (q,k,v)->ctx core
     (the configuration's by default, the ring core for sequence parallelism
     — ONE copy of the projection plumbing for every path). `wqkv` is the
@@ -868,7 +908,8 @@ def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
     a gate a feature (`q_gate`). `qk_scales` = (q_norm_scale, k_norm_scale)
     where the configuration norms q and k, over all of their features or,
     with split projections, a head; `w_head_gate` (D, H) where it gates each
-    head's output."""
+    head's output; `segments`, (the documents' numbers,) of packed rows, go
+    to the core."""
     B, S, _ = x.shape
     H, hd = cfg.n_heads, cfg.head_dim
     gate = None
@@ -888,7 +929,7 @@ def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
             with jax.named_scope("rope"):
                 q, k = _rope(q, k, cfg.rope_theta, cfg.rotary_share, cfg.yarn)
     with _core_kind_scope(cfg), jax.named_scope("attn_core"):
-        ctx = (core or attention_core_of(cfg))(q, k, v)
+        ctx = (core or attention_core_of(cfg))(q, k, v, *segments)
     if cfg.head_gate:
         with jax.named_scope("attn_gate"):
             gated_out = _gated_out if cfg.layer_remat else _gated_out_kept
@@ -1082,7 +1123,7 @@ def _grouped_gated_norm(y, z, scale, groups: int, eps):
 _grouped_gated_norm_kept = _recompute(_grouped_gated_norm, static_argnums=(3, 4))
 
 
-def _mamba2_mixer(h, layer, cfg: TransformerConfig):
+def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=()):
     """The Mamba-2 mixer on normed hidden states h (B, S, D): H heads of P
     features, a state of N a feature, G groups of H / G heads that share B
     and C (`ssm_dims`). [z | x B C | dt] = h W_in (H P + (H P + 2 G N) + H
@@ -1092,7 +1133,10 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig):
     projection as the router's is; the state-space recurrence (`ops.ssm_scan`)
     with q = C, k = B (a group's, never repeated a head) and v = Delta x;
     + D x; the gate silu(z) and then an RMSNorm over each group's features;
-    W_out. Scopes `ssm_proj`, `ssm_conv`, `ssm_core`, `ssm_norm`."""
+    W_out. `segments`, (the documents' numbers (B, S),) of packed rows, go
+    to the convolution and to the scan, and nothing else of the mixer looks
+    beyond its own position. Scopes `ssm_proj`, `ssm_conv`, `ssm_core`,
+    `ssm_norm`."""
     from kungfu_tpu.ops.gated_delta import causal_conv
     from kungfu_tpu.ops.ssm_scan import CHUNK, ssm_scan
 
@@ -1107,7 +1151,8 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig):
         step = jnp.dot(h.astype(f32), w_in[:, 2 * inner + 2 * bc:].astype(f32),
                        precision=jax.lax.Precision.HIGHEST)  # (B, S, H)
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"], layer["conv_b"]))
+        xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"], layer["conv_b"],
+                                      *segments))
         x = xbc[..., :inner].reshape(B, S, H, hp)
         b, c = (xbc[..., at:at + bc].reshape(B, S, G, N).transpose(0, 2, 1, 3)
                 for at in (inner, inner + bc))
@@ -1117,7 +1162,7 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig):
     with jax.named_scope("ssm_core"):
         # the published chunk, or the largest power of two under it that
         # divides a shorter sequence: the result does not depend on it
-        o = ssm_scan(c, b, v, g, math.gcd(S, CHUNK))  # (B, H, S, hp)
+        o = ssm_scan(c, b, v, g, math.gcd(S, CHUNK), *segments)  # (B, H, S, hp)
     with jax.named_scope("ssm_norm"):
         y = (o.transpose(0, 2, 1, 3).astype(f32)
              + layer["D_skip"].astype(f32)[:, None] * x.astype(f32))
@@ -1178,30 +1223,41 @@ def _behind(y, layer, norm: str, cfg: TransformerConfig):
         return _rmsnorm(y, _scale(layer[norm], cfg), cfg.norm_eps)
 
 
-def _layer(x, layer, cfg: TransformerConfig, core=None):
+def _taken(x, y, layer, norm: str, cfg: TransformerConfig):
+    """The residual stream x with a branch's output y in it: y behind its
+    second norm where the configuration has one (`_behind`), times
+    `residual_multiplier` where that is not 1."""
+    y = _behind(y, layer, norm, cfg)
+    return x + (y if cfg.residual_multiplier == 1.0
+                else y * cfg.residual_multiplier)
+
+
+def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
     """One layer -> (x, aux): a mixer and a feed-forward, each a residual
     branch behind its own norm, or one of the two alone; with `post_norms`
     the branch's output goes through a second norm before the residual takes
-    it. aux is the expert layer's `ops.moe.MoeAux` (router losses and
-    token-choices per expert), None of any other."""
+    it, and `residual_multiplier` scales what it takes. aux is the expert
+    layer's `ops.moe.MoeAux` (router losses and token-choices per expert),
+    None of any other. `segments`: (the documents' numbers (B, S),) of
+    packed rows, for the mixer; () where a row is one document."""
     dt, eps = cfg.dtype, cfg.norm_eps
     if cfg.mixer == "none":
         pass
     elif cfg.mixer == "mamba2":
         with jax.named_scope("ssm"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = x + _behind(_mamba2_mixer(h, layer, cfg), layer,
-                            "ln1_post_scale", cfg)
+            x = _taken(x, _mamba2_mixer(h, layer, cfg, segments), layer,
+                       "ln1_post_scale", cfg)
     elif cfg.mixer == "gated_delta":
         with jax.named_scope("gdn"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = x + _behind(_gated_delta_mixer(h, layer, cfg), layer,
-                            "ln1_post_scale", cfg)
+            x = _taken(x, _gated_delta_mixer(h, layer, cfg), layer,
+                       "ln1_post_scale", cfg)
     elif cfg.mixer == "latent":
         with jax.named_scope("attn"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = x + _behind(_latent_attention(h, layer, cfg, core=core), layer,
-                            "ln1_post_scale", cfg)
+            x = _taken(x, _latent_attention(h, layer, cfg, core=core), layer,
+                       "ln1_post_scale", cfg)
     else:
         with jax.named_scope("attn"):
             scales = ((_scale(layer["q_norm_scale"], cfg),
@@ -1210,11 +1266,12 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
             wqkv = (tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv"))
                     if cfg.split_qkv else layer["wqkv"].astype(dt))
-            x = x + _behind(
-                _attention(h, wqkv, layer["wo"].astype(dt),
-                           cfg, core=core, qk_scales=scales,
-                           w_head_gate=(layer["w_head_gate"].astype(dt)
-                                        if cfg.head_gate else None)),
+            x = _taken(
+                x, _attention(h, wqkv, layer["wo"].astype(dt),
+                              cfg, core=core, qk_scales=scales,
+                              w_head_gate=(layer["w_head_gate"].astype(dt)
+                                           if cfg.head_gate else None),
+                              segments=segments),
                 layer, "ln1_post_scale", cfg)
     if cfg.ffn == "none":
         return x, None
@@ -1223,8 +1280,8 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
             B, S, D = x.shape
             h = _rmsnorm(x, _scale(layer["ln2_scale"], cfg), eps).reshape(B * S, D)
             y, aux = _expert_layer(h, layer, cfg)
-            return x + _behind(y.reshape(B, S, D), layer, "ln2_post_scale",
-                               cfg), aux
+            return _taken(x, y.reshape(B, S, D), layer, "ln2_post_scale",
+                          cfg), aux
     with jax.named_scope("ffn"):
         h = _rmsnorm(x, _scale(layer["ln2_scale"], cfg), eps)
         if cfg.ffn == "swiglu":
@@ -1234,7 +1291,7 @@ def _layer(x, layer, cfg: TransformerConfig, core=None):
         else:
             y = _gelu_out(h @ layer["w_in"].astype(dt),
                           layer["w_out"].astype(dt))
-        return x + _behind(y, layer, "ln2_post_scale", cfg), None
+        return _taken(x, y, layer, "ln2_post_scale", cfg), None
 
 
 # `layer_remat`: the scan keeps the layer's input and, of what the layer
@@ -1270,7 +1327,10 @@ def _head_logits(params, x, cfg: TransformerConfig, normed: bool = False):
     h = x if normed else _rmsnorm(x, _scale(params["ln_f_scale"], cfg),
                                   cfg.norm_eps)
     head = params["embed"] if cfg.tied_head else params["lm_head"]
-    return h.astype(jnp.float32) @ head.astype(jnp.float32).T
+    h = h.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:  # the logits over it: on the narrow side
+        h = h / cfg.logits_scaling
+    return h @ head.astype(jnp.float32).T
 
 
 def lm_head_loss(params, x, targets, cfg: TransformerConfig):
@@ -1337,9 +1397,25 @@ def _embed(params, tokens, cfg: TransformerConfig):
     dt = cfg.dtype
     with jax.named_scope("embed"):
         x = params["embed"].astype(dt)[tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if cfg.positions == "learned":
             x = x + params["pos_embed"].astype(dt)[:S]
         return x
+
+
+def _segments(tokens, cfg: TransformerConfig):
+    """(the documents' numbers (B, S) int32,) of packed rows, or () where a
+    row is one document (`end_of_document` None): position t is of the
+    document that its id ends or continues, so the number rises by one
+    behind every `end_of_document` id, and a row's head is a document of its
+    own, number 0. What every mixer is handed; scope `segments`."""
+    if cfg.end_of_document is None:
+        return ()
+    with jax.named_scope("segments"):
+        behind_an_end = jnp.pad(tokens[:, :-1] == cfg.end_of_document,
+                                ((0, 0), (1, 0)))
+        return (jnp.cumsum(behind_an_end.astype(jnp.int32), axis=1),)
 
 
 def _loop_step_end(u, params, cfg: TransformerConfig, each):
@@ -1375,6 +1451,9 @@ def _hidden(params, tokens, cfg: TransformerConfig, each=None):
     cell's step then wants 19.3 GB of the chip's 16.9 and the outer scan
     15.2, `benchmark/aot_check.py`, PR 48.)"""
     x = _embed(params, tokens, cfg)
+    # the documents of packed rows: constants of every layer scan
+    packed = _segments(tokens, cfg)
+    packed = (None, packed) if packed else ()  # no core plugged, then they
     stacks = params["layers"] if cfg.layer_kinds else (params["layers"],)
     looped = cfg.loop_steps > 1
     if looped:
@@ -1389,7 +1468,7 @@ def _hidden(params, tokens, cfg: TransformerConfig, each=None):
             def body(x, layer, kind=kind, stacked=stacked, run=run):
                 if not looped:
                     layer = collective.reduce_in_backward(layer, of=stacked)
-                return run(x, layer, kind)
+                return run(x, layer, kind, *packed)
 
             x, aux = jax.lax.scan(body, x, stacked)
             if aux is not None:
@@ -1712,6 +1791,53 @@ def record_routing(stats, registry=None) -> None:
             moved.labels(layer).set(float(stats["bias_moved"][i]))
         for expert, n in enumerate(row):
             load.labels(layer, expert).set(float(n))
+
+
+def packing_stats(tokens, cfg: TransformerConfig):
+    """What tokens (B, S) of packed rows are made of, a number a row (B,):
+    jit this beside the step, as `routing_stats`. `documents`, how many the
+    row holds (a row's head and its tail are documents of their own);
+    `shortest` and `longest`, their lengths in positions; and
+    `within_document_pairs`, the share of the row's causal (query, key)
+    pairs, the diagonal among them, whose two positions are of one document:
+    what fraction of a full causal sweep the attention needs."""
+    if cfg.end_of_document is None:
+        raise ValueError("packing_stats: the configuration names no "
+                         "end_of_document id, so a row is one document")
+    (segments,) = _segments(tokens, cfg)
+    S = tokens.shape[1]
+    lengths = jax.vmap(lambda row: jnp.bincount(row, length=S))(segments)
+    pairs = jnp.sum(lengths * (lengths + 1) // 2, axis=-1)
+    return {
+        "documents": segments[:, -1] + 1,
+        "shortest": jnp.min(jnp.where(lengths > 0, lengths, S), axis=-1),
+        "longest": jnp.max(lengths, axis=-1),
+        "within_document_pairs": pairs / (S * (S + 1) // 2),
+    }
+
+
+def record_packing(stats, registry=None) -> None:
+    """`packing_stats`' numbers as gauges of `telemetry.metrics` over the
+    batch read last: `kungfu_packed_documents_per_row` (the mean),
+    `kungfu_packed_shortest_document` and `kungfu_packed_longest_document`
+    (positions, over all rows) and `kungfu_packed_within_document_pairs`
+    (the share, over all rows)."""
+    from kungfu_tpu.telemetry import metrics
+
+    reg = registry or metrics.REGISTRY
+    for name, text, value in (
+            ("kungfu_packed_documents_per_row",
+             "documents a packed row holds, the mean",
+             np.mean(stats["documents"])),
+            ("kungfu_packed_shortest_document",
+             "positions of the shortest document", np.min(stats["shortest"])),
+            ("kungfu_packed_longest_document",
+             "positions of the longest document", np.max(stats["longest"])),
+            ("kungfu_packed_within_document_pairs",
+             "the share of causal pairs that lie within one document",
+             np.mean(stats["within_document_pairs"]))):
+        reg.gauge(name, text + ", of the batch read last").set(float(value))
+
 
 # ---------------------------------------------------------------------------
 # sequence-parallel (ring attention) path: the long-context mode. The whole

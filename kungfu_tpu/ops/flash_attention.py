@@ -117,14 +117,20 @@ def _q_steps(S: int, blk_q: int, blk_k: int, window) -> int:
                for c in range(0, S, blk_k))
 
 
-def _interior(q_off, k_off, blk_q: int, blk_k: int, window):
+def _interior(q_off, k_off, blk_q: int, blk_k: int, window, docs=None):
     """Whether every key of the block at (q_off, k_off) is seen by every
-    query of it: the newest key is no later than the oldest query and, under
-    a window, the oldest key is inside the newest query's. `_mask` is all
-    true there and the forward kernel leaves it out. Offsets traced or plain."""
+    query of it: the newest key is no later than the oldest query, under a
+    window the oldest key is inside the newest query's, and in a packed
+    sequence (`docs`, the block's `_documents`) the oldest key is of the
+    newest query's document: the numbers never fall, so every position
+    between them is of it too. `_mask` is all true there and the forward
+    kernel leaves it out. Offsets traced or plain."""
     inside = k_off + blk_k - 1 <= q_off
     if window is not None:
         inside &= q_off + blk_q - 1 - k_off < window
+    if docs is not None:
+        of_query, of_key = docs
+        inside &= jnp.max(of_query) == jnp.min(of_key)
     return inside
 
 
@@ -182,26 +188,41 @@ def _across(stat, n: int):
     return stat if n == stat.shape[-1] else pltpu.repeat(stat, n // stat.shape[-1], 1)
 
 
-def _mask(q_off, k_off, blk_q: int, blk_k: int, window):
-    """(blk_q, blk_k) bool: key seen by query, causal and inside the window."""
+def _mask(q_off, k_off, blk_q: int, blk_k: int, window, docs=None):
+    """(blk_q, blk_k) bool: key seen by query: causal, inside the window,
+    and in a packed sequence of the query's own document (`docs`)."""
     qpos = q_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
     kpos = k_off + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-    if window is None:
-        return kpos <= qpos
-    return (kpos <= qpos) & (qpos - kpos < window)
+    seen = kpos <= qpos
+    if window is not None:
+        seen &= qpos - kpos < window
+    if docs is not None:
+        of_query, of_key = docs
+        seen &= of_query == of_key
+    return seen
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-            blk_q: int, blk_k: int, causal: bool, sm_scale: float,
-            window=None):
+def _documents(of_query_ref, of_key_ref):
+    """A block's documents in a packed sequence: the queries' numbers as a
+    column (blk_q, 1) and the keys' as a row (1, blk_k), from the lane- and
+    sublane-replicated blocks the kernels are handed (`_packed`)."""
+    return of_query_ref[0][:, :1], of_key_ref[0][:1, :]
+
+
+def _kernel(q_ref, k_ref, v_ref, *refs, blk_q: int, blk_k: int, causal: bool,
+            sm_scale: float, window=None):
     """One (bh, q-block, k-block) grid program. The TPU grid runs the
     LAST dimension sequentially on one core, so the (m, l, acc) flash
     accumulators live in VMEM scratch across the k-block sweep; K/V
     arrive one block at a time via BlockSpec streaming — VMEM holds
     O(blk) state regardless of S. Under a window the sweep starts at the
-    q-block's first live k-block."""
+    q-block's first live k-block. `refs`: the two blocks of a packed
+    sequence's document numbers first, where there are any (`_packed`), then
+    o, the log-sum-exp and the scratch."""
     from jax.experimental import pallas as pl
 
+    *packed, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    docs = _documents(*packed) if packed else None
     step = pl.program_id(2)
     qi = pl.program_id(1)
     n_kb = pl.num_programs(2)
@@ -222,7 +243,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         q, k, v = q_ref[0], k_ref[0], v_ref[0]
         s = _nt(q, k) * sm_scale
         if masked:
-            mask = _mask(q_off, k_off, blk_q, blk_k, window)
+            mask = _mask(q_off, k_off, blk_q, blk_k, window, docs)
             s = jnp.where(mask, s, NEG_INF)
         m = m_scr[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -238,7 +259,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
     # one of the two runs: under the mask where an edge crosses the block,
     # without where it is interior (every block of a call that is not causal)
-    interior = _interior(q_off, k_off, blk_q, blk_k, window) if causal else True
+    interior = (_interior(q_off, k_off, blk_q, blk_k, window, docs)
+                if causal else True)
     pl.when(live & interior)(lambda: _compute(False))
     if causal:
         pl.when(live & jnp.logical_not(interior))(lambda: _compute(True))
@@ -267,9 +289,14 @@ def _blocks(S: int, blk_q: int, blk_k: int):
     return blk_q, blk_k
 
 
-def _group(q, k, v, causal: bool, window) -> int:
+def _group(q, k, v, causal: bool, window, segments=None) -> int:
     """Query heads to a key/value head; raises for shapes and masks the
     kernels do not run."""
+    if segments is not None and not (
+            causal and segments.shape == q.shape[:1] + q.shape[2:3]):
+        raise ValueError(
+            f"flash_attention: segments {segments.shape} number the documents "
+            f"of a causal core's positions, (B, S) = {q.shape[:1] + q.shape[2:3]}")
     H, Hkv = q.shape[1], k.shape[1]
     if k.shape != v.shape or H % Hkv or (
             k.shape[:1] + k.shape[2:] != q.shape[:1] + q.shape[2:]):
@@ -280,6 +307,40 @@ def _group(q, k, v, causal: bool, window) -> int:
         raise ValueError("flash_attention: a window is causal (0 <= i - j < "
                          f"window) and at least 1, got {window}")
     return H // Hkv
+
+
+def _packed(segments):
+    """What the kernels read of a packed sequence, or nothing: the
+    documents' numbers (B, S) as the queries', (B, S, 8) with every lane a
+    copy, and as the keys', (B, 8, S) with every sublane one, so that a block
+    of either is a tile Mosaic takes and their comparison needs no
+    transposition (`_documents`)."""
+    if segments is None:
+        return ()
+    B, S = segments.shape
+    numbers = segments.astype(jnp.int32)
+    return (jnp.broadcast_to(numbers[:, :, None], (B, S, 8)),
+            jnp.broadcast_to(numbers[:, None, :], (B, 8, S)))
+
+
+def _packed_specs(segments, blk_q: int, blk_k: int, of_query, of_key):
+    """The block specs of `_packed`'s two arrays, or none: `of_query` and
+    `of_key` give a grid step's (batch, block) of each."""
+    from jax.experimental import pallas as pl
+
+    if segments is None:
+        return []
+
+    def queries(*at):
+        b, i = of_query(*at)
+        return (b, i, 0)
+
+    def keys(*at):
+        b, j = of_key(*at)
+        return (b, 0, j)
+
+    return [pl.BlockSpec((1, blk_q, 8), queries),
+            pl.BlockSpec((1, 8, blk_k), keys)]
 
 
 def _kv_head(g: int, b):
@@ -300,12 +361,13 @@ def _kv_index(blk_q, blk_k, causal, window, g, b, i, j):
 
 
 def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
-             blk_k: int, interpret, with_lse: bool = False, window=None):
+             blk_k: int, interpret, with_lse: bool = False, window=None,
+             segments=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, hd = q.shape
-    g = _group(q, k, v, causal, window)
+    g = _group(q, k, v, causal, window, segments)
     blk_q, blk_k = _blocks(S, blk_q, blk_k)
     qf = q.reshape(B * H, S, hd)
     kf = k.reshape(B * H // g, S, hd)
@@ -324,7 +386,9 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
             pl.BlockSpec((1, blk_q, hd), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_k, hd), kv_index),
             pl.BlockSpec((1, blk_k, hd), kv_index),
-        ],
+        ] + _packed_specs(segments, blk_q, blk_k,
+                          lambda b, i, j: (b // H, i),
+                          lambda b, i, j: (b // H, kv_index(b, i, j)[1])),
         out_specs=[
             pl.BlockSpec((1, blk_q, hd), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_q, 8), lambda b, i, j: (b, i, 0)),
@@ -335,25 +399,29 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
             pltpu.VMEM((blk_q, hd), jnp.float32),  # acc
         ],
         interpret=interpret,
-    )(qf, kf, vf)
+    )(qf, kf, vf, *_packed(segments))
     out = out.reshape(B, H, S, hd)
     if with_lse:
         return out, lse  # (B*H, S, 8), lane-replicated
     return out
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
-               dq_scr, *, blk_q: int, blk_k: int, causal: bool,
-               sm_scale: float, window=None):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *refs,
+               blk_q: int, blk_k: int, causal: bool, sm_scale: float,
+               window=None):
     """dQ: per (bh, q-block) program, k-blocks stream sequentially.
     Block probs are recomputed exactly from the saved row LSE (standard
     two-pass flash backward), so no (S, S) tensor exists anywhere:
         p  = exp(q k^T * scale - lse)
         ds = p * (dO v^T - delta)
         dq += ds @ k * scale
+    `refs`: a packed sequence's document numbers first (`_packed`), then dq
+    and the scratch.
     """
     from jax.experimental import pallas as pl
 
+    *packed, dq_ref, dq_scr = refs
+    docs = _documents(*packed) if packed else None
     step = pl.program_id(2)
     qi = pl.program_id(1)
     n_kb = pl.num_programs(2)
@@ -375,7 +443,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         s = _nt(q, k) * sm_scale
         p = jnp.exp(s - lse)
         if causal:
-            p = jnp.where(_mask(q_off, k_off, blk_q, blk_k, window), p, 0.0)
+            p = jnp.where(_mask(q_off, k_off, blk_q, blk_k, window, docs), p, 0.0)
         ds = p * (_nt(do, v) - delta)
         dq_scr[...] += jnp.dot(ds.astype(k.dtype), k,
                                preferred_element_type=jnp.float32) * sm_scale
@@ -385,10 +453,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
-                dv_ref, dk_scr, dv_scr, *, blk_q: int, blk_k: int,
-                causal: bool, sm_scale: float, window=None, n_qb=None,
-                seq=None):
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *refs,
+                blk_q: int, blk_k: int, causal: bool, sm_scale: float,
+                window=None, n_qb=None, seq=None):
     """dK/dV: per (key/value head, k-block) program, the q-blocks of each
     of the group's query heads stream sequentially (`n_qb` steps a head,
     all of the sweep where the heads are not grouped), so a group's dk
@@ -397,9 +464,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
         dv += p^T @ dO
         ds  = p * (dO v^T - delta)
         dk += ds^T @ q * scale
+    `refs`: a packed sequence's document numbers first (`_packed`), then dk,
+    dv and the scratch.
     """
     from jax.experimental import pallas as pl
 
+    *packed, dk_ref, dv_ref, dk_scr, dv_scr = refs
+    docs = _documents(*packed) if packed else None
     step = pl.program_id(2)
     kj = pl.program_id(1)
     n_steps = pl.num_programs(2)
@@ -427,7 +498,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
         s = _nt(q, k) * sm_scale
         p = jnp.exp(s - lse)
         if causal:
-            p = jnp.where(_mask(q_off, k_off, blk_q, blk_k, window), p, 0.0)
+            p = jnp.where(_mask(q_off, k_off, blk_q, blk_k, window, docs), p, 0.0)
         # transposed in float32, then cast: Mosaic transposes 32-bit tiles
         dv_scr[...] += jnp.dot(p.T.astype(do.dtype), do,
                                preferred_element_type=jnp.float32)
@@ -457,12 +528,12 @@ def _q_index(blk_q, blk_k, causal, window, g, n_qb, S, b, j, i):
 
 
 def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
-                      interpret, window=None):
+                      interpret, window=None, segments=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, hd = q.shape
-    group = _group(q, k, v, causal, window)
+    group = _group(q, k, v, causal, window, segments)
     Hkv = H // group
     # delta = rowsum(dO * O): one fused elementwise+reduce pass, XLA's
     # job; 8-lane-replicated to match the LSE layout (see _finalize)
@@ -479,10 +550,9 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
     )
 
     q_spec = pl.BlockSpec((1, blk_q, hd), lambda b, i, j: (b, i, 0))
-    kv_spec = pl.BlockSpec(
-        (1, blk_k, hd),
-        functools.partial(_kv_index, blk_q, blk_k, causal, window, group)
-    )
+    kv_index = functools.partial(_kv_index, blk_q, blk_k, causal, window, group)
+    kv_spec = pl.BlockSpec((1, blk_k, hd), kv_index)
+    packed = _packed(segments)
     row_spec = pl.BlockSpec((1, blk_q, 8), lambda b, i, j: (b, i, 0))
     _count_blocks(("dq", "dkv"), B * H, S, blk_q, blk_k, causal, window, False)
 
@@ -491,11 +561,14 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
                           causal=causal, sm_scale=sm_scale, window=window),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
         grid=(B * H, S // blk_q, _kv_steps(S, blk_q, blk_k, window)),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+        + _packed_specs(segments, blk_q, blk_k,
+                        lambda b, i, j: (b // H, i),
+                        lambda b, i, j: (b // H, kv_index(b, i, j)[1])),
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((blk_q, hd), jnp.float32)],
         interpret=interpret,
-    )(qf, kf, vf, gf, lsef, deltaf)
+    )(qf, kf, vf, gf, lsef, deltaf, *packed)
 
     # one sweep over a k-block's q-blocks for each query head of the group
     n_qb = _q_steps(S, blk_q, blk_k, window)
@@ -513,14 +586,18 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
             jax.ShapeDtypeStruct((B * Hkv, S, hd), v.dtype),
         ],
         grid=(B * Hkv, S // blk_k, group * n_qb),
-        in_specs=[qi_spec, kj_spec, kj_spec, qi_spec, row_i_spec, row_i_spec],
+        in_specs=[qi_spec, kj_spec, kj_spec, qi_spec, row_i_spec, row_i_spec]
+        + _packed_specs(segments, blk_q, blk_k,
+                        lambda b, j, i: (q_index(b, j, i)[0] // H,
+                                         q_index(b, j, i)[1]),
+                        lambda b, j, i: (b // Hkv, j)),
         out_specs=[kj_spec, kj_spec],
         scratch_shapes=[
             pltpu.VMEM((blk_k, hd), jnp.float32),
             pltpu.VMEM((blk_k, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qf, kf, vf, gf, lsef, deltaf)
+    )(qf, kf, vf, gf, lsef, deltaf, *packed)
 
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
@@ -528,7 +605,8 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
                     blk_q: int = 512, blk_k: int = 512,
-                    interpret: bool = False, window: int = None):
+                    interpret: bool = False, window: int = None,
+                    segments=None):
     """Fused causal attention for (B, H, S, hd) q and (B, Hkv, S, hd) k, v,
     H a multiple of Hkv (equal: plain multi-head); drop-in for the
     transformer's pluggable attention core:
@@ -537,6 +615,13 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
 
     `window`: key j is seen by query i iff 0 <= i - j < window (None: every
     earlier key), and only the blocks of that band are visited.
+
+    `segments` (B, S) whole numbers that never fall along the sequence
+    number each position's document in a packed row: key j is seen by query
+    i iff besides both are of one document, so a packed row is its documents
+    run one at a time, values and gradients. The kernels mask: a block whose
+    keys are all of earlier documents than its queries is visited and
+    computed to nothing (ROADMAP R3(b) has what skipping them would save).
 
     Forward AND backward are Pallas kernels (two-pass flash backward:
     dq streams k-blocks, dk/dv stream q-blocks, block probs recomputed
@@ -569,15 +654,16 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     return _forward(q, k, v, causal, sm_scale, blk_q, blk_k, interpret,
-                    window=window)
+                    window=window, segments=segments)
 
 
-def _fwd(q, k, v, causal, sm_scale, blk_q, blk_k, interpret, window):
+def _fwd(q, k, v, causal, sm_scale, blk_q, blk_k, interpret, window,
+         segments=None):
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     out, lse = _forward(
         q, k, v, causal, sm_scale, blk_q, blk_k, interpret, with_lse=True,
-        window=window
+        window=window, segments=segments
     )
     # kept between the passes as one number a row: the 8 replicated lanes
     # are padded to 128 in HBM, 302 MB a layer of 72 heads at 8,192
@@ -588,11 +674,11 @@ def _fwd(q, k, v, causal, sm_scale, blk_q, blk_k, interpret, window):
     # (`models/transformer._layer_again`); the identity anywhere else
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, out, lse, segments)
 
 
 def _bwd(causal, sm_scale, blk_q, blk_k, interpret, window, res, g):
-    q, k, v, o, lse = res
+    q, k, v, o, lse, segments = res
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (8,))
@@ -600,8 +686,8 @@ def _bwd(causal, sm_scale, blk_q, blk_k, interpret, window, res, g):
     blk_q, blk_k = _blocks(q.shape[2], blk_q, blk_k)
     return _backward_kernels(
         q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k, interpret,
-        window=window
-    )
+        window=window, segments=segments
+    ) + (None,)
 
 
 flash_attention.defvjp(_fwd, _bwd)

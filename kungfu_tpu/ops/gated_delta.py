@@ -87,49 +87,77 @@ VMEM_LIMIT = 64 << 20  # of the chip's 128 MiB; the default scope is 16
 _HIGHEST = lax.Precision.HIGHEST
 
 
-def _taps_over(padded, taps, S: int):
+def _taps_over(padded, taps, S: int, seen=None):
     """sum_i taps_i padded[:, i:i + S] in float32: the K windows are read
-    from the one padded array in its own type inside one fused pass."""
-    return sum(padded[:, i:i + S].astype(jnp.float32) * taps[i].astype(jnp.float32)
+    from the one padded array in its own type inside one fused pass. `seen`,
+    where given, is a (B, S, 1) mask a tap: a window's positions that the
+    tap may read, zero in the others' place."""
+    windows = (padded[:, i:i + S].astype(jnp.float32) * taps[i].astype(jnp.float32)
                for i in range(taps.shape[0]))
+    if seen is None:
+        return sum(windows)
+    return sum(jnp.where(mask, window, 0.0)
+               for mask, window in zip(seen, windows, strict=True))
+
+
+def _same_document(segments, K: int, ahead: bool):
+    """K masks (B, S, 1), one a window of `_taps_over`: window i of the
+    sequence padded with K - 1 positions at its start (at its end, `ahead`)
+    is position t - (K - 1) + i (t + i), and a tap reads it where it is of
+    position t's own document."""
+    if segments is None:
+        return None
+    S = segments.shape[1]
+    padded = jnp.pad(segments, ((0, 0), (0, K - 1) if ahead else (K - 1, 0)),
+                     constant_values=-1)
+    return [(padded[:, i:i + S] == segments)[..., None] for i in range(K)]
 
 
 @jax.custom_vjp
-def causal_conv(x, taps, bias=None):
+def causal_conv(x, taps, bias=None, segments=None):
     """Causal depthwise convolution over the sequence: x (B, S, channels),
     taps (K, channels), bias (channels,) or none; y_t = sum_i taps_i
-    x_{t - (K - 1) + i} + bias, zeros before the start. The products, their
-    sum and the bias in float32, the result in x's type. The backward pass
-    is written out, the same K windows over the cotangent padded at its end,
-    a reduction for the taps and one for the bias, and keeps x, the taps
-    and the bias alone: autodiff keeps each of the K windows in float32 as
-    the taps' residual (0.5 GB each at 16,384 positions and 8,192 channels)
-    and pads a float32 cotangent a window."""
+    x_{t - (K - 1) + i} + bias, zeros before the start. `segments` (B, S)
+    whole numbers, where given, say which document a position is of: a tap
+    that would read a position of another document reads zero, so a packed
+    row is its documents run one at a time. The products, their sum and the
+    bias in float32, the result in x's type. The backward pass is written
+    out, the same K windows over the cotangent padded at its end, a
+    reduction for the taps and one for the bias, and keeps x, the taps, the
+    bias and the segments alone: autodiff keeps each of the K windows in
+    float32 as the taps' residual (0.5 GB each at 16,384 positions and 8,192
+    channels) and pads a float32 cotangent a window."""
     K = taps.shape[0]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    y = _taps_over(padded, taps, x.shape[1])
+    y = _taps_over(padded, taps, x.shape[1], _same_document(segments, K, False))
     if bias is not None:
         y = y + bias.astype(jnp.float32)
     return y.astype(x.dtype)
 
 
 def _causal_conv_bwd(res, dy):
-    x, taps, bias = res
+    x, taps, bias, segments = res
     K, S = taps.shape[0], x.shape[1]
     # dx_t = sum_i taps_i dy_{t + (K - 1) - i}: the taps in reverse over dy
     # with zeros after its end
-    dx = _taps_over(jnp.pad(dy, ((0, 0), (0, K - 1), (0, 0))), taps[::-1], S)
+    dx = _taps_over(jnp.pad(dy, ((0, 0), (0, K - 1), (0, 0))), taps[::-1], S,
+                    _same_document(segments, K, True))
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     dy32 = dy.astype(jnp.float32)
-    dtaps = jnp.stack([
-        jnp.sum(dy32 * padded[:, i:i + S].astype(jnp.float32), axis=(0, 1))
-        for i in range(K)])
+    seen = _same_document(segments, K, False)
+
+    def read_by(i):  # dy_t x_{t - (K - 1) + i} where tap i reads it
+        product = dy32 * padded[:, i:i + S].astype(jnp.float32)
+        return product if seen is None else jnp.where(seen[i], product, 0.0)
+
+    dtaps = jnp.stack([jnp.sum(read_by(i), axis=(0, 1)) for i in range(K)])
     dbias = None if bias is None else jnp.sum(dy32, axis=(0, 1)).astype(bias.dtype)
-    return dx.astype(x.dtype), dtaps.astype(taps.dtype), dbias
+    return dx.astype(x.dtype), dtaps.astype(taps.dtype), dbias, None
 
 
 causal_conv.defvjp(
-    lambda x, taps, bias=None: (causal_conv(x, taps, bias), (x, taps, bias)),
+    lambda x, taps, bias=None, segments=None: (
+        causal_conv(x, taps, bias, segments), (x, taps, bias, segments)),
     _causal_conv_bwd)
 
 

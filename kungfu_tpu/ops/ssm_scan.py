@@ -65,26 +65,43 @@ from kungfu_tpu.ops.gated_delta import (_NT, _PARAMS, _TN, BLOCK_HEADS,
 CHUNK = 128  # Mamba-2's published chunk_size; the result does not depend on it
 
 
-def _shared(q, k):
+def _shared(q, k, marks=None):
     """What a chunk's heads share: q, k (C, N) in their type -> the masks
-    and q.k^T (C, C) float32."""
+    and q.k^T (C, C) float32. `marks`, of a packed sequence, are the chunk's
+    three rows of `_marks`, (1, C) each: `within`, key j is no later than
+    query i and of its document; as columns `reads`, the position is of the
+    document the chunk's first state is of, and `ends`, it is of the
+    document the chunk ends in; `carries`, that state outlives the chunk."""
     C = q.shape[0]
     at_row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
     at_col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    return dict(lower=at_col <= at_row, eye=at_col == at_row,
-                qk=_dot(q, k, _NT), q32=q.astype(jnp.float32),
-                k32=k.astype(jnp.float32))
+    x = dict(lower=at_col <= at_row, eye=at_col == at_row,
+             qk=_dot(q, k, _NT), q32=q.astype(jnp.float32),
+             k32=k.astype(jnp.float32))
+    if marks is not None:
+        document, reads, ends = marks
+        x.update(within=x["lower"] & (_to_column(document, x["eye"]) == document),
+                 reads=_to_column(reads, x["eye"]),
+                 ends=_to_column(ends, x["eye"]),
+                 carries=jnp.min(reads, axis=1, keepdims=True))
+    return x
 
 
 def _head(x, g, dtype):
     """One head's part of a chunk: g (1, C) float32 as a row -> its decays
     and the operands they scale, every decay an exponential of a difference
-    of running sums, in float32."""
+    of running sums, in float32. Of a packed sequence (`_shared`'s marks) a
+    decay that would cross a document's first position is 0, exactly: the
+    state is zero before it. The running sums run on across it, and a
+    difference of two inside one document is that document's own."""
     lower, eye = x["lower"], x["eye"]
     G = jnp.sum(jnp.where(lower, g, 0.0), axis=1, keepdims=True)  # (C, 1)
     G_end = jnp.sum(g, axis=1, keepdims=True)  # (1, 1)
-    decay = jnp.exp(jnp.where(lower, G - _to_row(G, eye), -jnp.inf))
+    decay = jnp.exp(jnp.where(x.get("within", lower), G - _to_row(G, eye),
+                              -jnp.inf))
     eG, to_end, a = jnp.exp(G), jnp.exp(G_end - G), jnp.exp(G_end)
+    if "within" in x:
+        eG, to_end, a = eG * x["reads"], to_end * x["ends"], a * x["carries"]
     return dict(decay=decay, eG=eG, to_end=to_end, a=a,
                 P=(decay * x["qk"]).astype(dtype),
                 Qg=(x["q32"] * eG).astype(dtype),
@@ -97,12 +114,22 @@ def _next_state(a, S, Kd, v):
     return a * S + _dot(Kd, v, _TN)
 
 
-def _forward_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, states_ref, S_scr, *,
-                    chunk: int):
+def _chunk_marks(marks_ref, row):
+    """A chunk's three rows of `_marks`, or None where the sequence is one
+    document."""
+    if marks_ref is None:
+        return None
+    return tuple(marks_ref[0, i, row, :] for i in range(3))
+
+
+def _forward_kernel(q_ref, k_ref, v_ref, g_ref, *refs, chunk: int):
     """A block of chunks of some heads of one group, first to last; each
     head's state in `S_scr` from one grid step to the next along the
     sequence, and in `states_ref` as each chunk starts from it, for the
-    backward pass."""
+    backward pass. `refs`: the marks of a packed sequence first, where there
+    are any, then o, the states and the scratch."""
+    *marks_ref, o_ref, states_ref, S_scr = refs
+    marks_ref = marks_ref[0] if marks_ref else None
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -110,7 +137,8 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, states_ref, S_scr, *,
 
     def one(c, carry):
         rows, row = _rows(c, chunk), pl.ds(c, 1)
-        x = _shared(q_ref[0, 0, rows, :], k_ref[0, 0, rows, :])
+        x = _shared(q_ref[0, 0, rows, :], k_ref[0, 0, rows, :],
+                    _chunk_marks(marks_ref, row))
         for h in range(S_scr.shape[0]):  # independent chains, side by side
             S = S_scr[h]
             states_ref[0, h, c] = S
@@ -124,12 +152,17 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, states_ref, S_scr, *,
     lax.fori_loop(0, g_ref.shape[2], one, None)
 
 
-def _backward_kernel(q_ref, k_ref, v_ref, g_ref, states_ref, do_ref, dq_ref,
-                     dk_ref, dv_ref, dg_ref, dS_scr, *, chunk: int):
+def _backward_kernel(q_ref, k_ref, v_ref, g_ref, states_ref, do_ref, *refs,
+                     chunk: int):
     """The same block last chunk to first, the grid's blocks last to first
     (the index maps), the state's cotangent in `dS_scr`. Each chunk's local
     quantities are made again from the inputs and the kept state; dq and dk
-    are this block of heads' sums, float32."""
+    are this block of heads' sums, float32. `refs`: the marks of a packed
+    sequence first, where there are any, then dq, dk, dv, dg and the scratch:
+    every mask is a factor of a decay, so the cotangents below are the
+    unpacked sequence's with the masked decays in them."""
+    *marks_ref, dq_ref, dk_ref, dv_ref, dg_ref, dS_scr = refs
+    marks_ref = marks_ref[0] if marks_ref else None
     blocks = g_ref.shape[2]
 
     @pl.when(pl.program_id(2) == 0)
@@ -143,7 +176,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, states_ref, do_ref, dq_ref,
         c = blocks - 1 - i
         rows, row = _rows(c, chunk), pl.ds(c, 1)
         q, k = q_ref[0, 0, rows, :], k_ref[0, 0, rows, :]
-        x = _shared(q, k)
+        x = _shared(q, k, _chunk_marks(marks_ref, row))
         dq = jnp.zeros(q.shape, jnp.float32)
         dk = jnp.zeros(k.shape, jnp.float32)
         for h in range(dS_scr.shape[0]):  # independent chains, side by side
@@ -202,7 +235,11 @@ def _specs(B, H, groups, S, N, P, chunk, back: bool):
                      last - s if back else s) + tail)
         return index
 
+    def marks(b, h, s):  # every head's alike
+        return (b, 0, last - s if back else s, 0)
+
     return (B, H // heads, n_chunks // n), dict(
+        marks=pl.BlockSpec((1, 3, n, chunk), marks),
         group=pl.BlockSpec((1, 1, n * chunk, N), at(0, group=True)),
         sum=pl.BlockSpec((1, 1, n * chunk, N), at(0)),
         v=pl.BlockSpec((1, heads, n * chunk, P), at(0)),
@@ -211,27 +248,47 @@ def _specs(B, H, groups, S, N, P, chunk, back: bool):
         scratch=pltpu.VMEM((heads, N, P), jnp.float32))
 
 
-def _forward(q, k, v, g, *, chunk: int, interpret: bool):
+def _marks(segments, chunk: int):
+    """What the kernels read of a packed sequence, (B, 3, S / chunk, chunk)
+    float32 (a whole number below 2^24 is one exactly) from `segments` (B,
+    S), the number of each position's document, which never falls along the
+    sequence: the number itself; 1 where the position is of the document
+    that the last position before the chunk is of (none before the first
+    chunk: the state is zero there anyway), so that it reads the state the
+    chunk starts from; 1 where it is of the document the chunk's last
+    position is of, so that what it writes is in the state the chunk ends
+    with."""
+    B, S = segments.shape
+    document = segments.reshape(B, S // chunk, chunk)
+    last = document[..., -1:]
+    before = jnp.concatenate([jnp.full_like(last[:, :1], -1), last[:, :-1]],
+                             axis=1)
+    return jnp.stack([document, document == before, document == last],
+                     axis=1).astype(jnp.float32)
+
+
+def _forward(q, k, v, g, *marks, chunk: int, interpret: bool):
     """-> (o, the state at each chunk's start (B, H, S / chunk, N, P)
-    float32)."""
+    float32). `marks`: `_marks` of a packed sequence, or nothing."""
     B, groups, S, N = q.shape
     H, P = v.shape[1], v.shape[-1]
     grid, spec = _specs(B, H, groups, S, N, P, chunk, back=False)
     return pl.pallas_call(
         functools.partial(_forward_kernel, chunk=chunk),
         grid=grid,
-        in_specs=[spec["group"], spec["group"], spec["v"], spec["row"]],
+        in_specs=[spec["group"], spec["group"], spec["v"], spec["row"]]
+        + [spec["marks"]] * len(marks),
         out_specs=[spec["v"], spec["state"]],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((B, H, S // chunk, N, P), jnp.float32)],
         scratch_shapes=[spec["scratch"]],
         interpret=interpret, name="ssm_scan_forward", **_PARAMS,
-    )(q, k, v, g.reshape(B, H, S // chunk, chunk))
+    )(q, k, v, g.reshape(B, H, S // chunk, chunk), *marks)
 
 
-def _backward(q, k, v, g, states, do, *, chunk: int, interpret: bool):
+def _backward(q, k, v, g, states, do, *marks, chunk: int, interpret: bool):
     """-> (dq, dk (B, blocks of heads, S, N) float32, a block of heads'
-    sum each; dv; dg)."""
+    sum each; dv; dg). `marks` as `_forward`'s."""
     B, groups, S, N = q.shape
     H, P = v.shape[1], v.shape[-1]
     grid, spec = _specs(B, H, groups, S, N, P, chunk, back=True)
@@ -241,13 +298,13 @@ def _backward(q, k, v, g, states, do, *, chunk: int, interpret: bool):
         functools.partial(_backward_kernel, chunk=chunk),
         grid=grid,
         in_specs=[spec["group"], spec["group"], spec["v"], spec["row"],
-                  spec["state"], spec["v"]],
+                  spec["state"], spec["v"]] + [spec["marks"]] * len(marks),
         out_specs=[spec["sum"], spec["sum"], spec["v"], spec["row"]],
         out_shape=[sums, sums, jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(rows, jnp.float32)],
         scratch_shapes=[spec["scratch"]],
         interpret=interpret, name="ssm_scan_backward", **_PARAMS,
-    )(q, k, v, g.reshape(rows), states, do)
+    )(q, k, v, g.reshape(rows), states, do, *marks)
     return dq, dk, dv, dg.reshape(g.shape)
 
 
@@ -272,17 +329,21 @@ def _count(which: str, B: int, H: int, S: int, chunk: int, N: int, P: int):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def ssm_scan(q, k, v, g, chunk: int = CHUNK):
+def ssm_scan(q, k, v, g, chunk: int = CHUNK, segments=None):
     """q, k (B, groups, S, N), v (B, H, S, P), g (B, H, S) float32, the log
     decay <= 0 -> o (B, H, S, P) in v's type: the recurrence of the module's
     head, S_0 = 0, in its chunkwise form; head j reads q and k of group j //
-    (H / groups). `chunk` is a power of two that divides S, or this raises."""
-    return _fwd(q, k, v, g, chunk)[0]
+    (H / groups). `chunk` is a power of two that divides S, or this raises.
+    `segments` (B, S) whole numbers that never fall along the sequence, where
+    given, number each position's document: the state is zero before a
+    document's first position, wherever in a chunk it stands, so a packed row
+    is its documents run one at a time, values and gradients."""
+    return _fwd(q, k, v, g, chunk, segments)[0]
 
 
-def _fwd(q, k, v, g, chunk):
-    """-> (o, what the backward pass keeps: the inputs and the states at the
-    chunks' starts)."""
+def _fwd(q, k, v, g, chunk, segments=None):
+    """-> (o, what the backward pass keeps: the inputs, the states at the
+    chunks' starts and the marks of a packed sequence)."""
     (B, groups, S, N), (_, H, _, P) = q.shape, v.shape
     if chunk & (chunk - 1) or S % chunk:
         raise ValueError(f"ssm_scan: the sequence length {S} is no multiple "
@@ -291,20 +352,22 @@ def _fwd(q, k, v, g, chunk):
         raise ValueError(f"ssm_scan: {H} heads are no multiple of {groups} "
                          "groups")
     _count("forward", B, H, S, chunk, N, P)
-    o, states = _on_platform(_forward, q, k, v, g, chunk=chunk)
-    return o, (q, k, v, g, states)
+    marks = () if segments is None else (_marks(segments, chunk),)
+    o, states = _on_platform(_forward, q, k, v, g, *marks, chunk=chunk)
+    return o, (q, k, v, g, states, marks)
 
 
 def _bwd(chunk, res, do):
-    q, k, v, g, states = res
+    q, k, v, g, states, marks = res
     (B, groups, S, N), (_, H, _, P) = q.shape, v.shape
     _count("backward", B, H, S, chunk, N, P)
-    dq, dk, dv, dg = _on_platform(_backward, q, k, v, g, states, do, chunk=chunk)
+    dq, dk, dv, dg = _on_platform(_backward, q, k, v, g, states, do, *marks,
+                                  chunk=chunk)
 
     def of_group(d):  # a group's blocks of heads, added up
         return jnp.sum(d.reshape(B, groups, -1, S, N), axis=2).astype(q.dtype)
 
-    return of_group(dq), of_group(dk), dv, dg
+    return of_group(dq), of_group(dk), dv, dg, None
 
 
 ssm_scan.defvjp(_fwd, _bwd)

@@ -19,8 +19,8 @@ import pytest
 from jax.sharding import PartitionSpec
 
 from benchmark import harness, manifest as mf
-from benchmark.families import (glm4_moe_lite, laguna, nemotron_h, ouro,
-                                qwen3_next)
+from benchmark.families import (glm4_moe_lite, granite_hybrid, laguna,
+                                nemotron_h, ouro, qwen3_next)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import param_pspecs
 from kungfu_tpu.ops import moe
@@ -47,6 +47,7 @@ class Family:
     seed: int = 5
     configured: Callable = None  # the tiny configuration, edited in place
     trained_more: Callable = None
+    sampled: Callable = None  # what a family makes of its drawn sample
     expert_layers: tuple = ()  # the model's layers that route, a module's last
     held_share: tuple = ()  # bounds of the held experts' share of the choices
     scopes: tuple = ()  # what the cell's per-layer metrics read
@@ -106,7 +107,8 @@ class Family:
 
     @functools.cache
     def sample(self):
-        return self.module.host_batch(self.config, self.seed, 0, 2)
+        batch = self.module.host_batch(self.config, self.seed, 0, 2)
+        return self.sampled(self, batch) if self.sampled else batch
 
     @functools.cache
     def reference(self):
@@ -313,7 +315,52 @@ OURO = Family(
             "rope/", "loop_norm", "head_loss", "exit_gate", "ffn"),
     recomputed=((), (ouro.FULL,)))
 
-FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H, OURO)
+
+
+def _granite_boundaries(family, batch):
+    """The drawn rows with ends of documents of the test's own choosing in
+    the place of the drawn ones: in row 0 documents that end inside a scan
+    chunk of the tests' 32 positions and inside a flash block (position 9,
+    70), on a chunk's and a block's edge (31, so that position 32 is a
+    document's first), one of a single position (32) and one that spans a
+    whole chunk and more (71 to 127); row 1 two documents, the boundary at
+    position 100."""
+    end = granite_hybrid.end_of_document(family.config)
+    batch = np.where(batch == end, 1, batch)
+    batch[0, [9, 31, 32, 70]] = end
+    batch[1, 99] = end
+    return batch
+
+
+# three layers as the cell's, Mamba-2, attention, Mamba-2, each with its
+# gated feed-forward: 8 Mamba-2 heads of 16 on one group's B and C of 16; 4
+# query heads on 2 key/value heads of 16 under the published scale 1/64; the
+# four multipliers as published; a tied head; 128 packed positions, four
+# chunks of the tests' scan (`GRANITE_CHUNK`)
+GRANITE_HYBRID = Family(
+    name="granite_hybrid", cell="granite_4_0_h_micro.ssgd_packed_1chip",
+    module=granite_hybrid,
+    tiny=dict(hidden_size=64, intermediate_size=96, shared_intermediate_size=96,
+              num_hidden_layers=3, layer_types=["mamba", "attention", "mamba"],
+              num_attention_heads=4, num_key_value_heads=2, mamba_n_heads=8,
+              mamba_d_head=16, mamba_d_state=16, vocab_size=320,
+              sequence_length=128,
+              documents=dict(distribution="lognormal", median=24, sigma=1.0,
+                             shortest=4, longest=128, end_of_document_id=0),
+              flash_blocks=[32, 32], flash_interpret=True,
+              compute_dtype="float32"),
+    scales={"w_ssm_in": 4.0, "wq": 40.0, "wk": 40.0, "wv": 8.0, "wo": 4.0,
+            "w_gate": 4.0, "w_up": 4.0, "w_down": 4.0},
+    norms=("ln1_scale", "ln2_scale", "ssm_norm_scale"),
+    trained_more=_nemotron_memory, sampled=_granite_boundaries,
+    scopes=("segments", "ssm/ssm_proj/", "ssm/ssm_conv/", "ssm/ssm_core/",
+            "ssm/ssm_norm/", "attn/attn_full/attn_core/", "ffn", "embed",
+            "head_loss"),
+    recomputed=((), (granite_hybrid.MAMBA, granite_hybrid.ATTENTION)))
+GRANITE_CHUNK = 32  # the scan's chunk in the family's tests (`ssm_scan.CHUNK`)
+
+FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H, OURO,
+            GRANITE_HYBRID)
 
 
 def pytest_generate_tests(metafunc):

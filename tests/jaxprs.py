@@ -13,3 +13,15 @@ def pallas_calls(jaxpr, recomputed=False):
         for sub in device._sub_jaxprs(eqn):
             found += pallas_calls(sub, recomputed or eqn.primitive.name == "remat2")
     return found
+
+
+def pallas_operands(jaxpr) -> dict:
+    """{kernel's function: how many operands its `pallas_call` takes} over a
+    jaxpr, through every equation that holds one."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["jaxpr"].debug_info.func_name] = len(eqn.invars)
+        for sub in device._sub_jaxprs(eqn):
+            found.update(pallas_operands(sub))
+    return found

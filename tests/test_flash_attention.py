@@ -456,3 +456,74 @@ if __name__ == "__main__":  # one of `both_forms_bits`' own processes
 
     print(json.dumps({f"{call}-{hd}-{dtype}": _five_of_both_forms(call, hd, dtype)
                       for call, hd, dtype in _BIT_CASES[int(sys.argv[1])::_BIT_CHILDREN]}))
+
+
+# -- packed rows (PR 52): `segments` number each position's document ---------
+
+# five documents in 256 positions: boundaries inside a block of 64 x 32 (40,
+# 228), on a key block's edge (64) and on both blocks' edge (128)
+PACKED = (40, 24, 64, 100, 28)
+
+
+@pytest.mark.parametrize("hd,g,window", [(64, 4, None), (64, 4, 48),
+                                         (16, 1, None), (16, 3, 24)])
+def test_a_packed_row_is_its_documents_run_one_at_a_time(hd, g, window):
+    """Values and the three gradients, to float32's rounding, under a scale
+    of the scores that is not 1 / sqrt(head size): a query sees the keys of
+    its own document, interior blocks of one document go unmasked, and a
+    block of two is masked."""
+    S, Hkv, scale = sum(PACKED), 2, 1.0 / 64
+    edges = np.cumsum((0,) + PACKED)
+    segments = jnp.asarray(np.repeat(np.arange(len(PACKED)), PACKED))[None]
+    ks = jax.random.split(jax.random.PRNGKey(hd + g), 4)
+    q = 4 * jax.random.normal(ks[0], (1, g * Hkv, S, hd))
+    k = 4 * jax.random.normal(ks[1], (1, Hkv, S, hd))
+    v = jax.random.normal(ks[2], (1, Hkv, S, hd))
+    weight = jax.random.normal(ks[3], q.shape)
+
+    def packed(q, k, v):
+        return flash_attention(q, k, v, True, scale, 64, 32, True, window, segments)
+
+    def alone(q, k, v):
+        return jnp.concatenate([
+            flash_attention(q[:, :, a:b], k[:, :, a:b], v[:, :, a:b], True,
+                            scale, 4, 4, True, window)
+            for a, b in zip(edges[:-1], edges[1:])], axis=2)
+
+    def rel(got, want):
+        return float(jnp.linalg.norm((got - want).ravel())
+                     / jnp.linalg.norm(want.ravel()))
+
+    assert rel(packed(q, k, v), alone(q, k, v)) < 2e-6
+    got = jax.grad(lambda *a: jnp.sum(packed(*a) * weight), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(alone(*a) * weight), (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        assert rel(a, b) < 2e-6, name
+    one_document = flash_attention(q, k, v, True, scale, 64, 32, True, window)
+    assert rel(one_document, packed(q, k, v)) > 0.1
+
+
+def test_without_segments_the_kernels_are_the_program_they_were():
+    """No operand and no equation more without segments, forward and
+    backward; a packed call's three kernels take two operands more, the
+    documents' numbers for the queries and for the keys; and segments of a
+    core that is not causal, or of another shape, are refused."""
+    from jaxprs import pallas_operands
+
+    q, k, v = _qkv(B=1, H=2, S=64, hd=16)
+    segments = jnp.asarray(np.repeat([0, 1], [40, 24]))[None]
+
+    def both(*extra):
+        return jax.make_jaxpr(jax.value_and_grad(
+            lambda *a: jnp.sum(flash_attention(*a, True, None, 32, 32, True,
+                                               None, *extra)), (0, 1, 2)))(q, k, v)
+
+    assert str(both()) == str(both(None))
+    assert pallas_operands(both().jaxpr) == {
+        "_kernel": 3, "_dq_kernel": 6, "_dkv_kernel": 6}
+    assert pallas_operands(both(segments).jaxpr) == {
+        "_kernel": 5, "_dq_kernel": 8, "_dkv_kernel": 8}
+    with pytest.raises(ValueError, match="segments"):
+        flash_attention(q, k, v, False, None, 32, 32, True, None, segments)
+    with pytest.raises(ValueError, match="segments"):
+        flash_attention(q, k, v, True, None, 32, 32, True, None, segments[:, :32])

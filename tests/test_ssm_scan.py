@@ -341,3 +341,141 @@ def test_the_biased_convs_written_out_backward_is_autodiffs(taps, dtype, tol):
     assert got[0].dtype == dtype and got[1].dtype == got[2].dtype == jnp.float32
     for g, w in zip(got, want):
         assert _rel(g, w) < tol
+
+
+# -- packed rows (PR 52): `segments` number each position's document ---------
+
+# five documents in 256 positions: boundaries inside a chunk (40, 228), on the
+# edge of a chunk of 64 (64) and of both 64 and 128 (128)
+PACKED = (40, 24, 64, 100, 28)
+
+
+def _documents(lengths=PACKED):
+    """(segments (1, S), [(first, end)] a document)."""
+    edges = np.cumsum((0,) + tuple(lengths))
+    segments = jnp.asarray(np.repeat(np.arange(len(lengths)), lengths))[None]
+    return segments, list(zip(edges[:-1], edges[1:]))
+
+
+def _padded(t, axis, to):
+    """t with zeros behind it along `axis`, up to a multiple of `to`."""
+    pad = [(0, 0)] * t.ndim
+    pad[axis] = (0, -t.shape[axis] % to)
+    return jnp.pad(t, pad)
+
+
+@pytest.mark.parametrize("chunk,H,G", [(64, 8, 1), (128, 8, 1), (64, 4, 2)])
+def test_a_packed_row_is_its_documents_run_one_at_a_time(chunk, H, G):
+    """Values and all four gradients, to float32's rounding: the state is
+    zero before a document's first position wherever in a chunk it stands,
+    and nothing of a later document reaches an earlier one's cotangents."""
+    S = sum(PACKED)
+    segments, documents = _documents()
+    args = _inputs(chunk + H, 1, H, G, S, 16, 24, 0.05)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (1, H, S, 24))
+
+    def packed(*a):
+        return jnp.sum(ssm_scan(*a, chunk, segments) * weight)
+
+    def alone(*a):
+        total = 0.0
+        for first, end in documents:
+            mine = [_padded(t[:, :, first:end], 2, chunk) for t in a]
+            total += jnp.sum(ssm_scan(*mine, chunk)[:, :, :end - first]
+                             * weight[:, :, first:end])
+        return total
+
+    one_at_a_time = jnp.concatenate([
+        ssm_scan(*(_padded(t[:, :, first:end], 2, chunk) for t in args),
+                 chunk)[:, :, :end - first] for first, end in documents], axis=2)
+    assert _rel(ssm_scan(*args, chunk, segments), one_at_a_time) < 2e-6
+    got = jax.grad(packed, argnums=ALL)(*args)
+    want = jax.grad(alone, argnums=ALL)(*args)
+    for name, g, w in zip(("q", "k", "v", "g"), got, want):
+        assert _rel(g, w) < 5e-6, name
+    # a document's first position gives its log decay no gradient: the state
+    # it would decay is zero
+    firsts = [first for first, _ in documents]
+    dg = np.abs(np.asarray(got[3]))
+    assert dg[:, :, firsts].max() < 1e-4 * dg.max()  # sums that cancel
+    # and the same row as one document reads otherwise
+    assert _rel(ssm_scan(*args, chunk), ssm_scan(*args, chunk, segments)) > 0.05
+
+
+def test_a_packed_scan_is_the_recurrence_with_the_state_set_to_zero():
+    """Against the definition, not against the kernels' own unpacked run:
+    the benchmark's reference recurrence a position at a time, its state
+    zeroed at every document's first position."""
+    from benchmark.reference.granite_hybrid import recurrence as zeroing
+
+    S, H, N, P = sum(PACKED), 4, 16, 24
+    segments, documents = _documents()
+    q, k, v, g = _inputs(3, 1, H, 1, S, N, P, 0.05)
+    first = np.zeros((1, S), bool)
+    first[0, [a for a, _ in documents]] = True
+    # the reference's decay is exp(delta_t A) with one A a head: delta 1 and
+    # A 0, no decay, a head at a time; what is tested is where the state ends
+    want = jnp.stack([
+        zeroing(v[:, h:h + 1].transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                q.transpose(0, 2, 1, 3), jnp.ones((1, S, 1)), jnp.zeros((1,)),
+                jnp.asarray(first), 64)[:, :, 0]
+        for h in range(H)], axis=1)
+    got = ssm_scan(q, k, v, jnp.zeros_like(g), 64, segments)
+    assert _rel(got, want) < 2e-5
+
+
+def test_without_segments_the_scan_is_the_program_it_was():
+    """No operand, no mask and no equation more: the jaxpr of a call without
+    segments, forward and backward, is that of a call that has never heard
+    of them, and a packed call's kernels take one operand more."""
+    from jaxprs import pallas_operands
+
+    args = _inputs(0, 1, 4, 1, 128, 16, 24, 0.05)
+    segments = _documents((100, 28))[0]
+
+    def both(*extra):
+        return jax.make_jaxpr(jax.value_and_grad(
+            lambda *a: jnp.sum(ssm_scan(*a, 64, *extra)), argnums=ALL))(*args)
+
+    assert str(both()) == str(both(None))
+    assert pallas_operands(both().jaxpr) == {"ssm_scan_forward": 4,
+                                             "ssm_scan_backward": 6}
+    assert pallas_operands(both(segments).jaxpr) == {"ssm_scan_forward": 5,
+                                                     "ssm_scan_backward": 7}
+
+
+@pytest.mark.parametrize("taps,bias", [(4, True), (2, True), (4, False)])
+def test_a_packed_rows_convolution_is_its_documents_one_at_a_time(taps, bias):
+    """A tap that would read a position of an earlier document reads zero,
+    forward and in the written-out backward pass."""
+    S, C = 64, 6
+    segments, documents = _documents((10, 3, 1, 30, 20))
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, S, C))
+    c = jax.random.normal(jax.random.PRNGKey(3), (taps, C))
+    b = (jax.random.normal(jax.random.PRNGKey(5), (C,)),) if bias else ()
+    weight = jax.random.normal(jax.random.PRNGKey(4), (1, S, C))
+
+    def packed(x, c, *b):
+        return jnp.sum(causal_conv(x, c, *(b or (None,)), segments) * weight)
+
+    def alone(x, c, *b):
+        return sum(jnp.sum(causal_conv(x[:, first:end], c, *b)
+                           * weight[:, first:end]) for first, end in documents)
+
+    which = tuple(range(2 + len(b)))
+    got = jax.grad(packed, which)(x, c, *b)
+    want = jax.grad(alone, which)(x, c, *b)
+    assert abs(float(packed(x, c, *b)) - float(alone(x, c, *b))) < 1e-5
+    for g, w in zip(got, want):
+        assert _rel(g, w) < 1e-6
+    assert _rel(causal_conv(x, c, *b), causal_conv(x, c, *(b or (None,)), segments)) > 0.05
+    # the reference's convolution keeps to the documents the same way
+    from benchmark.reference.granite_hybrid import conv as packed_conv
+    want = packed_conv(x, c, b[0] if b else jnp.zeros((C,)), segments)
+    assert _rel(causal_conv(x, c, *(b or (None,)), segments), want) < 1e-6
+    # and without segments the convolution is the program it was
+    def both(*extra):
+        return str(jax.make_jaxpr(jax.grad(
+            lambda x, c: jnp.sum(causal_conv(x, c, *(b or (None,)), *extra)),
+            (0, 1)))(x, c))
+    assert both() == both(None) != both(segments)
