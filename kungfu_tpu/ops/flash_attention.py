@@ -5,16 +5,30 @@ head, q-block) grid program, the q tile stays in VMEM while K/V stream
 through block by block with an online (flash) softmax — the (S, S) score
 matrix never materializes in HBM, so peak memory is O(BLK_Q x S_block)
 instead of O(S^2). Causal programs stop at their diagonal block (the
-upper-triangular half is never computed at all).
+upper-triangular half is never computed at all, nor stepped over).
 
 A grid step is one (q-block, k-block) pair, and in the forward kernel the
-block's own offsets say which of three it is (`_interior`): **dead**, above
-the diagonal or wholly behind the window, and skipped, fetch and all;
-**interior**, every key of it seen by every query of it (120 of a head's 136
-visited blocks at 8,192 positions and blocks of 512, `block_counts`),
-computed with no mask at all; or **edge**, crossed by the diagonal or by the
-window's far side (every block of a band core at window 512), computed under
-`_mask`. The two bodies are one function, and give the same bits: on an
+block's own offsets say which of three it is (`_interior`): **dead**, no
+query of it sees a key of it; **interior**, every key of it seen by every
+query of it (120 of a head's 136 visited blocks at 8,192 positions and blocks
+of 512, `block_counts`), computed with no mask at all; or **edge**, crossed
+by the diagonal, by the window's far side (every block of a band core at
+window 512) or by a document's boundary, computed under `_mask`. Dead names
+three cases, and none runs a kernel's body (PR 53). A block **above the
+diagonal** of a causal core without a window is no grid step at all: the
+sweeps run over a table of their live pairs that the kernels and their index
+maps read from scalar memory (`_live_pairs`: 136 steps a head at 8,192
+positions where a square grid has 256, 528 of 1,024 at 16,384, 36 of 64 at
+4,096). A block **wholly outside the band** of a windowed core is left out by
+the sweep's length (`_kv_steps`, `_q_steps`), and what that leaves above the
+diagonal in the first rows is a step that computes nothing and fetches
+nothing (its index is clamped to the last live block's). A block **of other
+documents** in a packed sequence, every key of an earlier document than every
+query, is known by its data alone (`_meet`, on the blocks' oldest and newest
+documents, which `_packed` lays into scalar memory): it takes its grid step,
+runs no body in any of the three kernels and fetches nothing, its index
+clamped to its sweep's nearest live block's. The two bodies are one function,
+and give the same bits: on an
 interior block the select is the identity and the product is by 1.0. The
 two backward kernels mask every live block: they stand at their matmuls'
 time and a second body measured nothing there. The forward kernel's running
@@ -43,7 +57,7 @@ in VMEM and written once. **A window**: key j is seen by query i iff
 0 <= i - j < window; every kernel's sweep then covers the blocks that
 touch the band and no others (`_kv_steps`, `_q_steps`: 2 of 16 at blocks
 of 512, window 512 and 8,192 positions). A call with neither runs the
-kernels it ran before both.
+same kernels.
 
 The kernels compile with Mosaic unless the caller passes
 `interpret=True` (the tests, on the CPU mesh); the backend is never
@@ -117,47 +131,126 @@ def _q_steps(S: int, blk_q: int, blk_k: int, window) -> int:
                for c in range(0, S, blk_k))
 
 
-def _interior(q_off, k_off, blk_q: int, blk_k: int, window, docs=None):
+def _by_table(causal: bool, window) -> bool:
+    """Whether a call's sweeps run over a table of their live block pairs
+    (`_live_pairs`): a causal core without a window, whose square grid would
+    hold a dead step for every block above the diagonal. A band's sweeps are
+    as long as the band is wide already, and every pair of a core that is
+    not causal is live."""
+    return causal and window is None
+
+
+def _live_pairs(S: int, blk_q: int, blk_k: int, group=None):
+    """The grid of a causal sweep without a window, one step a live block
+    pair, as the int32 table the kernels and their index maps read by scalar
+    prefetch: the shapes' alone, numpy, a constant of the program. Forward
+    and dQ (`group` None): rows (q-block, k-block), a q-block's k-blocks
+    first to diagonal. dK/dV: rows (k-block, query head of the group,
+    q-block), a k-block's q-blocks diagonal to last, once a query head of
+    the `group`. Flat, row after row: one dimension is what scalar memory
+    pads least."""
+    import numpy as np
+
+    n_q = S // blk_q
+    if group is None:
+        steps = [(i, j) for i in range(n_q)
+                 for j in range((i * blk_q + blk_q - 1) // blk_k + 1)]
+    else:
+        steps = [(j, h, i) for j in range(S // blk_k) for h in range(group)
+                 for i in range(j * blk_k // blk_q, n_q)]
+    return np.asarray(steps, np.int32).T.reshape(-1)
+
+
+def _row(table, r: int, rows: int, step):
+    """Entry `step` of row r of a flat table of `rows` rows."""
+    return table[r * (table.shape[0] // rows) + step]
+
+
+def _interior(q_off, k_off, blk_q: int, blk_k: int, window, ends=None):
     """Whether every key of the block at (q_off, k_off) is seen by every
     query of it: the newest key is no later than the oldest query, under a
     window the oldest key is inside the newest query's, and in a packed
-    sequence (`docs`, the block's `_documents`) the oldest key is of the
+    sequence (`ends`, the block's `_ends`) the oldest key is of the
     newest query's document: the numbers never fall, so every position
     between them is of it too. `_mask` is all true there and the forward
     kernel leaves it out. Offsets traced or plain."""
     inside = k_off + blk_k - 1 <= q_off
     if window is not None:
         inside &= q_off + blk_q - 1 - k_off < window
-    if docs is not None:
-        of_query, of_key = docs
-        inside &= jnp.max(of_query) == jnp.min(of_key)
+    if ends is not None:
+        _, newest_query, oldest_key, _ = ends
+        inside &= newest_query == oldest_key
     return inside
 
 
-def block_counts(S: int, blk_q: int, blk_k: int, window=None):
+def _meet(ends):
+    """Whether a block of a packed sequence may hold a key of a query's
+    document: its newest key's document is no earlier than its oldest
+    query's (`ends`, the block's `_ends`). The numbers never fall along the
+    sequence, so under the causal mask this is exact: a key before a query
+    is of no later document, and where every key is of an earlier one
+    `_mask` is false everywhere. Such a block is dead and runs no kernel's
+    body."""
+    oldest_query, _, _, newest_key = ends
+    return newest_key >= oldest_query
+
+
+def _ends(documents, q_off: int, k_off: int, blk_q: int, blk_k: int):
+    """(oldest query's, newest query's, oldest key's, newest key's) document
+    of the block at (q_off, k_off), from a row's numbers: what `_meet` and
+    `_interior` go by, and what the kernels read as four scalars (`_packed`)."""
+    return (documents[q_off], documents[q_off + blk_q - 1],
+            documents[k_off], documents[k_off + blk_k - 1])
+
+
+def block_counts(S: int, blk_q: int, blk_k: int, window=None, documents=None):
     """(visited, edge) blocks a head of a causal core: the live blocks of a
     sweep, and those of them that a mask's edge crosses, the only ones the
     forward kernel masks; the rest are interior. The forward/dQ sweep and the dK/dV sweep
     visit the same blocks. 136 and 16 at 8,192 positions and blocks of 512,
     528 and 32 at 16,384, 36 and 8 at 4,096; under window 512 every visited
-    block is an edge block."""
+    block is an edge block. `documents`, the numbers of a packed row's S
+    positions (numpy), leaves the blocks of other documents out of the
+    visited (`_meet`) and counts a block that a boundary crosses as an edge
+    block: what the kernels do for that row, which their traced program
+    cannot know."""
     visited = edge = 0
     for q_off in range(0, S, blk_q):
         for k_off in range(0, S, blk_k):
+            ends = None if documents is None else _ends(
+                documents, q_off, k_off, blk_q, blk_k)
             if k_off > q_off + blk_q - 1 or (
                     window is not None and q_off - (k_off + blk_k - 1) >= window):
                 continue  # dead: above the diagonal, or wholly behind the band
+            if ends is not None and not _meet(ends):
+                continue  # dead: every key of an earlier document than every query
             visited += 1
-            edge += not _interior(q_off, k_off, blk_q, blk_k, window)
+            edge += not _interior(q_off, k_off, blk_q, blk_k, window, ends)
     return visited, edge
+
+
+def grid_steps(S: int, blk_q: int, blk_k: int, causal: bool, window=None):
+    """Grid steps a head of each kernel's sweep, dead ones included: the
+    live pairs alone of a causal core without a window (`_live_pairs`:
+    `block_counts`' visited, 136 at 8,192 positions and blocks of 512 where
+    the square grid had 256), the band's width a row of a windowed one
+    (`_kv_steps`, `_q_steps`), every pair of a core that is not causal.
+    (forward and dQ, dK/dV)."""
+    if _by_table(causal, window):  # both tables list the same pairs
+        return (_live_pairs(S, blk_q, blk_k).size // 2,) * 2
+    return ((S // blk_q) * _kv_steps(S, blk_q, blk_k, window),
+            (S // blk_k) * _q_steps(S, blk_q, blk_k, window))
 
 
 def _count_blocks(kernels, heads: int, S, blk_q, blk_k, causal, window,
                   interior_unmasked: bool):
     """At trace time, what a run of each of the `kernels` being built visits
-    and masks over its heads, added to the counters
-    `kungfu_flash_blocks_visited_total` and `kungfu_flash_blocks_masked_total`
-    (docs/telemetry.md): a sum over the kernels traced, not over their runs."""
+    and masks over its heads and how many grid steps it takes to, added to
+    the counters `kungfu_flash_blocks_visited_total`,
+    `kungfu_flash_blocks_masked_total` and `kungfu_flash_grid_steps_total`
+    (docs/telemetry.md): a sum over the kernels traced, not over their runs.
+    What is known at trace time: a packed row's blocks of other documents
+    count as visited here and are skipped by its data."""
     from kungfu_tpu.telemetry import metrics
 
     if causal:
@@ -165,15 +258,18 @@ def _count_blocks(kernels, heads: int, S, blk_q, blk_k, causal, window,
         masked = edge if interior_unmasked else visited
     else:
         visited, masked = (S // blk_q) * (S // blk_k), 0
-    for name, text, blocks in (
-            ("kungfu_flash_blocks_visited_total",
-             "blocks a run of each flash kernel traced so far visits, by kernel",
-             visited),
-            ("kungfu_flash_blocks_masked_total",
-             "those of them computed under the mask", masked)):
-        family = metrics.counter(name, text, ("kernel",))
-        for kernel in kernels:
-            family.labels(kernel).inc(heads * blocks)
+    by_q, by_k = grid_steps(S, blk_q, blk_k, causal, window)
+    for kernel in kernels:
+        for name, text, n in (
+                ("kungfu_flash_blocks_visited_total",
+                 "blocks a run of each flash kernel traced so far visits, by kernel",
+                 visited),
+                ("kungfu_flash_blocks_masked_total",
+                 "those of them computed under the mask", masked),
+                ("kungfu_flash_grid_steps_total",
+                 "grid steps it takes to visit them, the dead ones among them",
+                 by_k if kernel == "dkv" else by_q)):
+            metrics.counter(name, text, ("kernel",)).labels(kernel).inc(heads * n)
 
 
 def _across(stat, n: int):
@@ -209,24 +305,93 @@ def _documents(of_query_ref, of_key_ref):
     return of_query_ref[0][:, :1], of_key_ref[0][:1, :]
 
 
-def _kernel(q_ref, k_ref, v_ref, *refs, blk_q: int, blk_k: int, causal: bool,
-            sm_scale: float, window=None):
+# `_packed`'s bounds, three a q-block and three a k-block of a row
+OLDEST_Q, NEWEST_Q, FIRST_LIVE_K, OLDEST_K, NEWEST_K, LAST_LIVE_Q = range(6)
+
+
+def _bound(bounds, S: int, blk_q: int, blk_k: int, batch, which: int, block):
+    """Entry `which` of `_packed`'s bounds for a `block` of row `batch`: a
+    read of scalar memory, in a kernel or an index map."""
+    n_q, n_k = S // blk_q, S // blk_k
+    start = which * n_q if which < OLDEST_K else 3 * n_q + (which - OLDEST_K) * n_k
+    return bounds[batch * 3 * (n_q + n_k) + start + block]
+
+
+def _block_ends(bounds, packed, blk_q: int, blk_k: int, qi, kb):
+    """Inside a kernel: a packed sequence's `_ends` of the block pair (qi,
+    kb), four scalars of `_packed`'s bounds; `packed`: (the grid's rows a
+    batch row, S). A step that is dead by shape may name a block past the
+    last: it reads the last one's, and is dead whatever they say."""
+    from jax.experimental import pallas as pl
+
+    rows, S = packed
+    batch = pl.program_id(0) // rows
+    qi, kb = jnp.minimum(qi, S // blk_q - 1), jnp.minimum(kb, S // blk_k - 1)
+    return tuple(_bound(bounds, S, blk_q, blk_k, batch, which,
+                        qi if which < OLDEST_K else kb)
+                 for which in (OLDEST_Q, NEWEST_Q, OLDEST_K, NEWEST_K))
+
+
+def _live_kv(q_off, k_off, kb, blk_q: int, causal: bool, window, ends):
+    """Whether the forward and the dQ kernel compute this grid step: under
+    the diagonal (every step of a table is; a square grid's upper half and a
+    band's last steps of its first rows are not), and in a packed sequence
+    not a block of other documents (`_meet`). Behind the band no step
+    falls: a band's sweep starts at its first live block."""
+    if _by_table(causal, window):
+        live = True
+    else:  # causal: blocks fully above the diagonal contribute nothing
+        live = (k_off <= q_off + blk_q - 1) if causal else (kb >= 0)
+    if ends is not None:
+        live &= _meet(ends)
+    return live
+
+
+def _kv_sweep_step(refs, blk_q: int, blk_k: int, causal: bool, window, packed):
+    """Inside the forward and the dQ kernel: the grid step's q-block and
+    k-block, where it stands in the q-block's sweep and how long that is,
+    the block's documents' `_ends` (None: one document a row), and the
+    kernel's other refs. By the table (`_by_table`) the sweep is the row's
+    live k-blocks, 0 to the diagonal; else the grid's last axis, under a
+    window from the q-block's first live k-block. The refs in scalar memory
+    come first: the table, then a packed sequence's bounds."""
+    from jax.experimental import pallas as pl
+
+    if _by_table(causal, window):
+        table, *refs = refs
+        pair = pl.program_id(1)
+        qi, kb = _row(table, 0, 2, pair), _row(table, 1, 2, pair)
+        step, n_kb = kb, (qi * blk_q + blk_q - 1) // blk_k + 1
+    else:
+        step = pl.program_id(2)
+        qi = pl.program_id(1)
+        n_kb = pl.num_programs(2)
+        kb = step if window is None else step + _first_kv_block(blk_q, blk_k, window, qi)
+    ends = None
+    if packed:
+        bounds, *refs = refs
+        ends = _block_ends(bounds, packed, blk_q, blk_k, qi, kb)
+    return qi, kb, step, n_kb, ends, refs
+
+
+def _kernel(*refs, blk_q: int, blk_k: int, causal: bool, sm_scale: float,
+            window=None, packed=None):
     """One (bh, q-block, k-block) grid program. The TPU grid runs the
     LAST dimension sequentially on one core, so the (m, l, acc) flash
     accumulators live in VMEM scratch across the k-block sweep; K/V
     arrive one block at a time via BlockSpec streaming — VMEM holds
     O(blk) state regardless of S. Under a window the sweep starts at the
-    q-block's first live k-block. `refs`: the two blocks of a packed
-    sequence's document numbers first, where there are any (`_packed`), then
-    o, the log-sum-exp and the scratch."""
+    q-block's first live k-block. `refs`: what lies in scalar memory first
+    (`_kv_sweep_step`), then q, k, v, then the two blocks of a packed
+    sequence's document numbers, where there are any (`_packed`; `packed`
+    says so: (heads a batch row, S)), then o, the log-sum-exp and the
+    scratch."""
     from jax.experimental import pallas as pl
 
-    *packed, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    docs = _documents(*packed) if packed else None
-    step = pl.program_id(2)
-    qi = pl.program_id(1)
-    n_kb = pl.num_programs(2)
-    kb = step if window is None else step + _first_kv_block(blk_q, blk_k, window, qi)
+    qi, kb, step, n_kb, ends, refs = _kv_sweep_step(
+        refs, blk_q, blk_k, causal, window, packed)
+    q_ref, k_ref, v_ref, *numbers, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    docs = _documents(*numbers) if packed else None
     q_off = qi * blk_q
     k_off = kb * blk_k
 
@@ -236,8 +401,7 @@ def _kernel(q_ref, k_ref, v_ref, *refs, blk_q: int, blk_k: int, causal: bool,
         l_scr[...] = jnp.zeros_like(l_scr[...])
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
-    # causal: blocks fully above the diagonal contribute nothing
-    live = (k_off <= q_off + blk_q - 1) if causal else (kb >= 0)
+    live = _live_kv(q_off, k_off, kb, blk_q, causal, window, ends)
 
     def _compute(masked: bool):
         q, k, v = q_ref[0], k_ref[0], v_ref[0]
@@ -259,7 +423,7 @@ def _kernel(q_ref, k_ref, v_ref, *refs, blk_q: int, blk_k: int, causal: bool,
 
     # one of the two runs: under the mask where an edge crosses the block,
     # without where it is interior (every block of a call that is not causal)
-    interior = (_interior(q_off, k_off, blk_q, blk_k, window, docs)
+    interior = (_interior(q_off, k_off, blk_q, blk_k, window, ends)
                 if causal else True)
     pl.when(live & interior)(lambda: _compute(False))
     if causal:
@@ -309,23 +473,40 @@ def _group(q, k, v, causal: bool, window, segments=None) -> int:
     return H // Hkv
 
 
-def _packed(segments):
-    """What the kernels read of a packed sequence, or nothing: the
-    documents' numbers (B, S) as the queries', (B, S, 8) with every lane a
-    copy, and as the keys', (B, 8, S) with every sublane one, so that a block
-    of either is a tile Mosaic takes and their comparison needs no
-    transposition (`_documents`)."""
+def _packed(segments, blk_q: int, blk_k: int):
+    """What the kernels read of a packed sequence, or nothing:
+    ([bounds], [the queries' numbers, the keys']). The numbers (B, S) as the
+    queries', (B, S, 8) with every lane a copy, and as the keys', (B, 8, S)
+    with every sublane one, so that a block of either is a tile Mosaic takes
+    and their comparison needs no transposition (`_documents`): the mask's
+    operands. The bounds, for scalar memory (`_bound`): a row after the
+    other, every q-block's oldest and newest document and the first k-block
+    that holds a key of its oldest document or a later one, then every
+    k-block's oldest and newest document and the last q-block that holds a
+    query of its newest document or an earlier one. By the two block
+    numbers the index maps give a block of other documents (`_meet` false:
+    k-blocks before the first, q-blocks behind the last) the index of its
+    sweep's nearest live block, so that nothing is fetched for it; by the
+    four documents the kernels know such a block, and an interior one,
+    without a reduction over the tiles."""
     if segments is None:
-        return ()
+        return [], []
     B, S = segments.shape
     numbers = segments.astype(jnp.int32)
-    return (jnp.broadcast_to(numbers[:, :, None], (B, S, 8)),
-            jnp.broadcast_to(numbers[:, None, :], (B, 8, S)))
+    oldest_q, newest_q = numbers[:, ::blk_q], numbers[:, blk_q - 1::blk_q]
+    oldest_k, newest_k = numbers[:, ::blk_k], numbers[:, blk_k - 1::blk_k]
+    first_live_k = jnp.sum(newest_k[:, None, :] < oldest_q[:, :, None], axis=2)
+    last_live_q = jnp.sum(oldest_q[:, None, :] <= newest_k[:, :, None], axis=2) - 1
+    bounds = jnp.concatenate([oldest_q, newest_q, first_live_k,
+                              oldest_k, newest_k, last_live_q], axis=1)
+    return ([bounds.astype(jnp.int32).reshape(-1)],
+            [jnp.broadcast_to(numbers[:, :, None], (B, S, 8)),
+             jnp.broadcast_to(numbers[:, None, :], (B, 8, S))])
 
 
 def _packed_specs(segments, blk_q: int, blk_k: int, of_query, of_key):
-    """The block specs of `_packed`'s two arrays, or none: `of_query` and
-    `of_key` give a grid step's (batch, block) of each."""
+    """The block specs of `_packed`'s two arrays of numbers, or none:
+    `of_query` and `of_key` give a grid step's (batch, block) of each."""
     from jax.experimental import pallas as pl
 
     if segments is None:
@@ -349,15 +530,70 @@ def _kv_head(g: int, b):
     return b if g == 1 else lax.div(b, g)
 
 
-def _kv_index(blk_q, blk_k, causal, window, g, b, i, j):
+def _swept_for_index(rows: int, causal, window, b, *step):
+    """The block a sweep runs for, at a grid step: the q-block of the forward
+    and the dQ kernel, the k-block of the dK/dV kernel: the grid's second
+    axis, or by the table (`_by_table`: step = (entry, table), `rows` rows)
+    the entry's first number."""
+    if _by_table(causal, window):
+        at, table = step[:2]
+        return (b, _row(table, 0, rows, at), 0)
+    return (b, step[0], 0)
+
+
+def _kv_index(blk_q, blk_k, causal, window, g, packed, b, *step):
     """Step j of q-block i's sweep: its k-block, clamped at the diagonal so
     that a dead step repeats the last live index and Pallas skips the
-    fetch (`pl.when` already skips the compute)."""
-    if not causal:
-        return (_kv_head(g, b), j, 0)
-    diag = (i * blk_q + blk_q - 1) // blk_k  # last live k-block for q-block i
-    j = j if window is None else j + _first_kv_block(blk_q, blk_k, window, i)
-    return (_kv_head(g, b), jnp.minimum(j, diag), 0)
+    fetch (`pl.when` already skips the compute). By the table (`_by_table`:
+    step = (pair, table)) no step is dead by shape and the pair's second
+    entry is the block. In a packed sequence (`packed`: (heads a batch row,
+    S); the bounds are the step's last entry) a block of other documents
+    takes the index of the q-block's first live one, which follows it."""
+    if _by_table(causal, window):
+        pair, table = step[:2]
+        i, block = _row(table, 0, 2, pair), _row(table, 1, 2, pair)
+        head = _kv_head(g, b)
+    else:
+        i, j = step[:2]
+        if not causal:
+            return (_kv_head(g, b), j, 0)
+        diag = (i * blk_q + blk_q - 1) // blk_k  # last live k-block for q-block i
+        j = j if window is None else j + _first_kv_block(blk_q, blk_k, window, i)
+        head = _kv_head(g, b)
+        block = jnp.minimum(j, diag)
+    if packed:
+        heads, S = packed
+        block = jnp.maximum(block, _bound(step[-1], S, blk_q, blk_k, b // heads,
+                                          FIRST_LIVE_K, i))
+    return (head, block, 0)
+
+
+def _kv_grid(rows: int, S, blk_q, blk_k, causal, window):
+    """(grid, [table]) of the forward and the dQ kernel over `rows` heads:
+    the live pairs a head and their table (`_by_table`), or q-blocks by
+    k-blocks (the band's width under a window) and no table."""
+    if _by_table(causal, window):
+        table = _live_pairs(S, blk_q, blk_k)
+        return (rows, table.size // 2), [table]
+    return (rows, S // blk_q, _kv_steps(S, blk_q, blk_k, window)), []
+
+
+def _sweep_call(kernel, grid, prefetched, **call):
+    """`pl.pallas_call(kernel, grid=grid, **call)`; where anything is
+    `prefetched` (the table of live pairs, a packed sequence's bounds), the
+    call with those as its first operands, in scalar memory before the grid
+    runs: the kernel's first refs and every index map's last arguments."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if not prefetched:
+        return pl.pallas_call(kernel, grid=grid, **call)
+    specs = {name: call.pop(name)
+             for name in ("in_specs", "out_specs", "scratch_shapes")}
+    return functools.partial(pl.pallas_call(
+        kernel, grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetched), grid=grid, **specs), **call),
+        *prefetched)
 
 
 def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
@@ -372,26 +608,30 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
     qf = q.reshape(B * H, S, hd)
     kf = k.reshape(B * H // g, S, hd)
     vf = v.reshape(B * H // g, S, hd)
-    kv_index = functools.partial(_kv_index, blk_q, blk_k, causal, window, g)
+    packed = None if segments is None else (H, S)
+    bounds, numbers = _packed(segments, blk_q, blk_k)
+    q_index = functools.partial(_swept_for_index, 2, causal, window)
+    kv_index = functools.partial(_kv_index, blk_q, blk_k, causal, window, g, packed)
     _count_blocks(("forward",), B * H, S, blk_q, blk_k, causal, window, True)
-    out, lse = pl.pallas_call(
+    grid, table = _kv_grid(B * H, S, blk_q, blk_k, causal, window)
+    out, lse = _sweep_call(
         functools.partial(_kernel, blk_q=blk_q, blk_k=blk_k, causal=causal,
-                          sm_scale=sm_scale, window=window),
+                          sm_scale=sm_scale, window=window, packed=packed),
+        grid, table + bounds,
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
             jax.ShapeDtypeStruct((B * H, S, 8), jnp.float32),
         ],
-        grid=(B * H, S // blk_q, _kv_steps(S, blk_q, blk_k, window)),
         in_specs=[
-            pl.BlockSpec((1, blk_q, hd), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk_q, hd), q_index),
             pl.BlockSpec((1, blk_k, hd), kv_index),
             pl.BlockSpec((1, blk_k, hd), kv_index),
         ] + _packed_specs(segments, blk_q, blk_k,
-                          lambda b, i, j: (b // H, i),
-                          lambda b, i, j: (b // H, kv_index(b, i, j)[1])),
+                          lambda b, *step: (b // H, q_index(b, *step)[1]),
+                          lambda b, *step: (b // H, kv_index(b, *step)[1])),
         out_specs=[
-            pl.BlockSpec((1, blk_q, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q, 8), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk_q, hd), q_index),
+            pl.BlockSpec((1, blk_q, 8), q_index),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, 128), jnp.float32),  # m, every lane a copy
@@ -399,33 +639,31 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
             pltpu.VMEM((blk_q, hd), jnp.float32),  # acc
         ],
         interpret=interpret,
-    )(qf, kf, vf, *_packed(segments))
+    )(qf, kf, vf, *numbers)
     out = out.reshape(B, H, S, hd)
     if with_lse:
         return out, lse  # (B*H, S, 8), lane-replicated
     return out
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *refs,
-               blk_q: int, blk_k: int, causal: bool, sm_scale: float,
-               window=None):
+def _dq_kernel(*refs, blk_q: int, blk_k: int, causal: bool, sm_scale: float,
+               window=None, packed=None):
     """dQ: per (bh, q-block) program, k-blocks stream sequentially.
     Block probs are recomputed exactly from the saved row LSE (standard
     two-pass flash backward), so no (S, S) tensor exists anywhere:
         p  = exp(q k^T * scale - lse)
         ds = p * (dO v^T - delta)
         dq += ds @ k * scale
-    `refs`: a packed sequence's document numbers first (`_packed`), then dq
-    and the scratch.
+    `refs`: what lies in scalar memory first (`_kv_sweep_step`), then q, k,
+    v, dO, the log-sum-exp and delta, then a packed sequence's document
+    numbers (`_packed`), then dq and the scratch.
     """
     from jax.experimental import pallas as pl
 
-    *packed, dq_ref, dq_scr = refs
-    docs = _documents(*packed) if packed else None
-    step = pl.program_id(2)
-    qi = pl.program_id(1)
-    n_kb = pl.num_programs(2)
-    kb = step if window is None else step + _first_kv_block(blk_q, blk_k, window, qi)
+    qi, kb, step, n_kb, ends, refs = _kv_sweep_step(
+        refs, blk_q, blk_k, causal, window, packed)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *numbers, dq_ref, dq_scr = refs
+    docs = _documents(*numbers) if packed else None
     q_off = qi * blk_q
     k_off = kb * blk_k
 
@@ -433,7 +671,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *refs,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
 
-    live = (k_off <= q_off + blk_q - 1) if causal else (kb >= 0)
+    live = _live_kv(q_off, k_off, kb, blk_q, causal, window, ends)
 
     @pl.when(live)
     def _compute():
@@ -453,30 +691,62 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *refs,
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *refs,
-                blk_q: int, blk_k: int, causal: bool, sm_scale: float,
-                window=None, n_qb=None, seq=None):
+def _q_sweep_step(refs, blk_q: int, blk_k: int, causal: bool, window,
+                  group: int, seq: int, packed):
+    """Inside the dK/dV kernel: the grid step's k-block and q-block, where it
+    stands in the k-block's sweep and how long that is, the block's
+    documents' `_ends` (None: one document a row), and the kernel's other
+    refs. By the table (`_by_table`) the sweep is the k-block's live
+    q-blocks, diagonal to last, once a query head of the `group`; else the
+    grid's last axis, `_q_steps` a head, from the k-block's first live
+    q-block under a window. The refs in scalar memory come first: the
+    table, then a packed sequence's bounds."""
+    from jax.experimental import pallas as pl
+
+    if _by_table(causal, window):
+        table, *refs = refs
+        at = pl.program_id(1)
+        kj, head, qi = (_row(table, r, 3, at) for r in range(3))
+        first = (kj * blk_k) // blk_q
+        per_head = seq // blk_q - first
+        step, n_steps = head * per_head + qi - first, group * per_head
+    else:
+        step = pl.program_id(2)
+        kj = pl.program_id(1)
+        n_steps = pl.num_programs(2)
+        qi = step if group == 1 else lax.rem(
+            step, _q_steps(seq, blk_q, blk_k, window))
+        if window is not None:
+            qi = qi + (kj * blk_k) // blk_q  # the k-block's first live q-block
+    ends = None
+    if packed:
+        bounds, *refs = refs
+        ends = _block_ends(bounds, packed, blk_q, blk_k, qi, kj)
+    return kj, qi, step, n_steps, ends, refs
+
+
+def _dkv_kernel(*refs, blk_q: int, blk_k: int, causal: bool, sm_scale: float,
+                window=None, group=1, seq=None, packed=None):
     """dK/dV: per (key/value head, k-block) program, the q-blocks of each
-    of the group's query heads stream sequentially (`n_qb` steps a head,
-    all of the sweep where the heads are not grouped), so a group's dk
+    of the `group`'s query heads stream sequentially (a sweep a head, one
+    after the other), so a group's dk
     and dv are summed in the scratch and written once:
         p   = exp(q k^T * scale - lse)
         dv += p^T @ dO
         ds  = p * (dO v^T - delta)
         dk += ds^T @ q * scale
-    `refs`: a packed sequence's document numbers first (`_packed`), then dk,
-    dv and the scratch.
+    `refs`: what lies in scalar memory first (`_q_sweep_step`), then q, k,
+    v, dO, the log-sum-exp and delta, then a packed sequence's document
+    numbers (`_packed`; `packed`: (key/value heads a batch row, S)), then
+    dk, dv and the scratch.
     """
     from jax.experimental import pallas as pl
 
-    *packed, dk_ref, dv_ref, dk_scr, dv_scr = refs
-    docs = _documents(*packed) if packed else None
-    step = pl.program_id(2)
-    kj = pl.program_id(1)
-    n_steps = pl.num_programs(2)
-    qi = step if n_qb is None else lax.rem(step, n_qb)
-    if window is not None:
-        qi = qi + (kj * blk_k) // blk_q  # the k-block's first live q-block
+    kj, qi, step, n_steps, ends, refs = _q_sweep_step(
+        refs, blk_q, blk_k, causal, window, group, seq, packed)
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *numbers,
+     dk_ref, dv_ref, dk_scr, dv_scr) = refs
+    docs = _documents(*numbers) if packed else None
     q_off = qi * blk_q
     k_off = kj * blk_k
 
@@ -485,10 +755,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *refs,
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
 
-    if window is not None:  # from the diagonal, as far as the last reader
+    if _by_table(causal, window):
+        live = True  # every step of the table is under the diagonal
+    elif window is not None:  # from the diagonal, as far as the last reader
         live = qi <= _last_q_block(blk_q, blk_k, window, seq, kj)
     else:
         live = (q_off + blk_q - 1 >= k_off) if causal else (qi >= 0)
+    if ends is not None:
+        live &= _meet(ends)
 
     @pl.when(live)
     def _compute():
@@ -512,19 +786,35 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *refs,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _q_index(blk_q, blk_k, causal, window, g, n_qb, S, b, j, i):
+def _q_index(blk_q, blk_k, causal, window, g, n_qb, S, packed, b, *step):
     """dK/dV grid, step i of k-block j's sweep: which query head of the
     group (step // n_qb) and which of its q-blocks. Dead fetches are
     clamped: above the diagonal at the k-block's first live q-block
-    (mirror of _kv_index), past the window at its last."""
-    if g > 1:
-        b, i = b * g + lax.div(i, n_qb), lax.rem(i, n_qb)
-    if not causal:
-        return (b, i, 0)
-    lo = (j * blk_k) // blk_q
-    if window is None:
-        return (b, jnp.maximum(i, lo), 0)
-    return (b, jnp.minimum(i + lo, _last_q_block(blk_q, blk_k, window, S, j)), 0)
+    (mirror of _kv_index), past the window at its last. By the table
+    (`_by_table`: step = (entry, table)) no step is dead by shape, and the
+    entry names the head and the q-block. In a packed sequence (`packed`:
+    (key/value heads a batch row, S); the bounds are the step's last entry)
+    a block of other documents takes the index of the k-block's last live
+    one, which precedes it."""
+    batch = b // packed[0] if packed else None
+    if _by_table(causal, window):
+        at, table = step[:2]
+        j, b, i = (_row(table, 0, 3, at), b * g + _row(table, 1, 3, at),
+                   _row(table, 2, 3, at))
+    else:
+        j, i = step[:2]
+        if g > 1:
+            b, i = b * g + lax.div(i, n_qb), lax.rem(i, n_qb)
+        if not causal:
+            return (b, i, 0)
+        lo = (j * blk_k) // blk_q
+        if window is None:
+            i = jnp.maximum(i, lo)
+        else:
+            i = jnp.minimum(i + lo, _last_q_block(blk_q, blk_k, window, S, j))
+    if packed:
+        i = jnp.minimum(i, _bound(step[-1], S, blk_q, blk_k, batch, LAST_LIVE_Q, j))
+    return (b, i, 0)
 
 
 def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
@@ -549,55 +839,66 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
         delta.reshape(B * H, S)[:, :, None], (B * H, S, 8)
     )
 
-    q_spec = pl.BlockSpec((1, blk_q, hd), lambda b, i, j: (b, i, 0))
-    kv_index = functools.partial(_kv_index, blk_q, blk_k, causal, window, group)
+    packed = None if segments is None else (H, S)
+    bounds, numbers = _packed(segments, blk_q, blk_k)
+    q_index = functools.partial(_swept_for_index, 2, causal, window)
+    q_spec = pl.BlockSpec((1, blk_q, hd), q_index)
+    kv_index = functools.partial(_kv_index, blk_q, blk_k, causal, window, group,
+                                 packed)
     kv_spec = pl.BlockSpec((1, blk_k, hd), kv_index)
-    packed = _packed(segments)
-    row_spec = pl.BlockSpec((1, blk_q, 8), lambda b, i, j: (b, i, 0))
+    row_spec = pl.BlockSpec((1, blk_q, 8), q_index)
     _count_blocks(("dq", "dkv"), B * H, S, blk_q, blk_k, causal, window, False)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, blk_q=blk_q, blk_k=blk_k,
-                          causal=causal, sm_scale=sm_scale, window=window),
+    grid, table = _kv_grid(B * H, S, blk_q, blk_k, causal, window)
+    dq = _sweep_call(
+        functools.partial(_dq_kernel, blk_q=blk_q, blk_k=blk_k, causal=causal,
+                          sm_scale=sm_scale, window=window, packed=packed),
+        grid, table + bounds,
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-        grid=(B * H, S // blk_q, _kv_steps(S, blk_q, blk_k, window)),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
         + _packed_specs(segments, blk_q, blk_k,
-                        lambda b, i, j: (b // H, i),
-                        lambda b, i, j: (b // H, kv_index(b, i, j)[1])),
+                        lambda b, *step: (b // H, q_index(b, *step)[1]),
+                        lambda b, *step: (b // H, kv_index(b, *step)[1])),
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((blk_q, hd), jnp.float32)],
         interpret=interpret,
-    )(qf, kf, vf, gf, lsef, deltaf, *packed)
+    )(qf, kf, vf, gf, lsef, deltaf, *numbers)
 
     # one sweep over a k-block's q-blocks for each query head of the group
     n_qb = _q_steps(S, blk_q, blk_k, window)
+    packed = None if segments is None else (Hkv, S)
+    if _by_table(causal, window):
+        table = [_live_pairs(S, blk_q, blk_k, group)]
+        grid = (B * Hkv, table[0].size // 3)
+    else:
+        table, grid = [], (B * Hkv, S // blk_k, group * n_qb)
     q_index = functools.partial(_q_index, blk_q, blk_k, causal, window,
-                                group, n_qb, S)
+                                group, n_qb, S, packed)
+    k_index = functools.partial(_swept_for_index, 3, causal, window)
     qi_spec = pl.BlockSpec((1, blk_q, hd), q_index)
     row_i_spec = pl.BlockSpec((1, blk_q, 8), q_index)
-    kj_spec = pl.BlockSpec((1, blk_k, hd), lambda b, j, i: (b, j, 0))
-    dk, dv = pl.pallas_call(
+    kj_spec = pl.BlockSpec((1, blk_k, hd), k_index)
+    dk, dv = _sweep_call(
         functools.partial(_dkv_kernel, blk_q=blk_q, blk_k=blk_k,
                           causal=causal, sm_scale=sm_scale, window=window,
-                          n_qb=n_qb if group > 1 else None, seq=S),
+                          group=group, seq=S, packed=packed),
+        grid, table + bounds,
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, S, hd), k.dtype),
             jax.ShapeDtypeStruct((B * Hkv, S, hd), v.dtype),
         ],
-        grid=(B * Hkv, S // blk_k, group * n_qb),
         in_specs=[qi_spec, kj_spec, kj_spec, qi_spec, row_i_spec, row_i_spec]
         + _packed_specs(segments, blk_q, blk_k,
-                        lambda b, j, i: (q_index(b, j, i)[0] // H,
-                                         q_index(b, j, i)[1]),
-                        lambda b, j, i: (b // Hkv, j)),
+                        lambda b, *step: (q_index(b, *step)[0] // H,
+                                          q_index(b, *step)[1]),
+                        lambda b, *step: (b // Hkv, k_index(b, *step)[1])),
         out_specs=[kj_spec, kj_spec],
         scratch_shapes=[
             pltpu.VMEM((blk_k, hd), jnp.float32),
             pltpu.VMEM((blk_k, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qf, kf, vf, gf, lsef, deltaf, *packed)
+    )(qf, kf, vf, gf, lsef, deltaf, *numbers)
 
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
@@ -619,9 +920,10 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
     `segments` (B, S) whole numbers that never fall along the sequence
     number each position's document in a packed row: key j is seen by query
     i iff besides both are of one document, so a packed row is its documents
-    run one at a time, values and gradients. The kernels mask: a block whose
-    keys are all of earlier documents than its queries is visited and
-    computed to nothing (ROADMAP R3(b) has what skipping them would save).
+    run one at a time, values and gradients. A block whose keys are all of
+    earlier documents than its queries takes its grid step, runs no body,
+    forward or backward, and fetches nothing (`_meet`, `_packed`): it added
+    exact zeros, so the bits are those of computing it.
 
     Forward AND backward are Pallas kernels (two-pass flash backward:
     dq streams k-blocks, dk/dv stream q-blocks, block probs recomputed
@@ -647,9 +949,26 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
     with nothing but its two matmuls in it takes 93 % of its time at head 128
     and 95 % at 256: what a live grid step takes over the MXU's time, 0.55
     to 0.9 us whatever the head size, is the step's own; the two backward
-    kernels are at that floor too, and
-    a dead step costs 0.19 us. A dense core's float32 scores are 1.07 GB a
-    sequence of 4,096.
+    kernels are at that floor too. A dense core's float32 scores are 1.07 GB
+    a sequence of 4,096.
+
+    Since PR 53 a causal sweep without a window holds no step above the
+    diagonal. The kernels alone before and after (a probe's host clock over
+    40 calls, some 5 % over the traces' readings; dQ and dK/dV with the row
+    sums' pass; PERF.md, PR 53), ms:
+
+        (B, H on Hkv, S, hd)          forward          dQ               dK/dV
+        (1, 20 on 20,  8192, 256)     5.58 ->  4.98    7.97 ->  7.23    9.36 ->  9.27
+        (1, 16 on 2,  16384, 256)    16.90 -> 15.47   21.84 -> 21.03   27.52 -> 27.41
+        (1, 48 on 8,   8192, 128)     8.60 ->  7.77   11.57 -> 10.33   13.56 -> 13.51
+        (1, 32 on 8,   8192, 64) *    9.90 ->  3.85    8.01 ->  3.69   10.15 ->  5.35
+
+    (* a packed row with 46 of its 136 blocks a head live, the Granite cell's
+    shape: 5.42, 5.10 and 7.05 with 82 live). A step dead by shape cost 0.10
+    to 0.31 us where it followed its sweep's live steps (forward, dQ) and
+    0.01 to 0.04 where it led them (dK/dV); a block of other documents still
+    takes a step of 0.4 (forward, dQ) to 0.7 us (dK/dV), its index maps' and
+    the pipeline's own.
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)

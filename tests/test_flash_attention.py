@@ -312,27 +312,140 @@ def _five_of_both_forms(call, hd, dtype):
     g, blk_q, blk_k, window, S = _BIT_CALLS[call]
     visited, edge = fa.block_counts(S, blk_q, blk_k, window)
     assert visited > edge > 0
-    ks = jax.random.split(jax.random.PRNGKey(hd + g), 4)
-    q = jax.random.normal(ks[0], (1, 2 * g, S, hd), dtype)
-    k = jax.random.normal(ks[1], (1, 2, S, hd), dtype)
-    v = jax.random.normal(ks[2], (1, 2, S, hd), dtype)
-    do = jax.random.normal(ks[3], q.shape, dtype)
-
-    def five():
-        scale = 1.0 / np.sqrt(hd)
-        out, lse = fa._forward(q, k, v, True, scale, blk_q, blk_k, True,
-                               with_lse=True, window=window)
-        return (out, lse) + fa._backward_kernels(
-            q, k, v, out, lse, do, True, scale, blk_q, blk_k, True, window=window)
-
-    got = five()
+    args = _inputs(g, S, hd, dtype) + (blk_q, blk_k, window)
+    got = _five(fa, *args)
     interior, fa._interior = fa._interior, lambda q_off, *rest: q_off < 0
     try:
-        want = five()
+        want = _five(fa, *args)
     finally:
         fa._interior = interior
+    return _same_bits(got, want)
+
+
+# -- dead block pairs (PR 53): no grid step above the diagonal, no body for a
+# -- block of other documents --------------------------------------------------
+
+def _as_the_parent_ran_them(fa, *rules):
+    """Context: the kernels under the parent commit's rules, the test's local
+    reference. `grid`: a causal sweep's grid is square, dead steps clamped
+    at the diagonal (`_by_table` false). `documents`: a block of a packed row
+    is live whatever its documents (`_meet` true), fetched (its sweep's
+    first live k-block is the first, last live q-block the last) and
+    computed under its all-false mask."""
+    import contextlib
+
+    def fetch_every_block(segments, blk_q, blk_k):
+        bounds, numbers = packed(segments, blk_q, blk_k)
+        if bounds:
+            n_q, n_k = segments.shape[1] // blk_q, segments.shape[1] // blk_k
+            rows = bounds[0].reshape(segments.shape[0], 3 * (n_q + n_k))
+            rows = rows.at[:, 2 * n_q:3 * n_q].set(0).at[:, 3 * n_q + 2 * n_k:].set(n_q - 1)
+            bounds = [rows.reshape(-1)]
+        return bounds, numbers
+
+    @contextlib.contextmanager
+    def patched():
+        if "grid" in rules:
+            fa._by_table = lambda causal, window: False
+        if "documents" in rules:
+            fa._meet, fa._packed = lambda ends: True, fetch_every_block
+        try:
+            yield
+        finally:
+            fa._by_table, fa._meet, fa._packed = was
+
+    was = fa._by_table, fa._meet, packed = fa._by_table, fa._meet, fa._packed
+    return patched()
+
+
+def _five(fa, q, k, v, do, blk_q, blk_k, window=None, segments=None, scale=None):
+    scale = scale or 1.0 / np.sqrt(q.shape[-1])
+    out, lse = fa._forward(q, k, v, True, scale, blk_q, blk_k, True,
+                           with_lse=True, window=window, segments=segments)
+    return (out, lse) + fa._backward_kernels(
+        q, k, v, out, lse, do, True, scale, blk_q, blk_k, True, window=window,
+        segments=segments)
+
+
+def _same_bits(got, want):
     return {name: a.dtype == b.dtype and bool(jnp.array_equal(a, b))
             for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want)}
+
+
+def _inputs(g, S, hd, dtype, Hkv=2):
+    ks = jax.random.split(jax.random.PRNGKey(hd + g + S), 4)
+    q = jax.random.normal(ks[0], (1, Hkv * g, S, hd), dtype)
+    k = jax.random.normal(ks[1], (1, Hkv, S, hd), dtype)
+    v = jax.random.normal(ks[2], (1, Hkv, S, hd), dtype)
+    return q, k, v, jax.random.normal(ks[3], q.shape, dtype)
+
+
+# blocks a side (of 16 positions), query heads to a key/value head, (blk_q,
+# blk_k), head size, dtype: 4, 8 and 16 blocks a side, and blocks that differ
+_GRID_CASES = [("grid", n, g, blocks, hd, dtype)
+               for n in (4, 8, 16)
+               for g, blocks, hd, dtype in ((1, "16x16", 16, "float32"),
+                                            (3, "16x16", 64, "bfloat16"))
+               ] + [("grid", 8, 2, "32x16", 16, "float32"),
+                    ("grid", 8, 4, "16x32", 64, "bfloat16")]
+
+
+def _five_of_both_grids(_, n, g, blocks, hd, dtype):
+    """{output: the same bits?} of a causal call without a window over its
+    live pairs alone and over the parent's square grid."""
+    fa = _fa()
+    blk_q, blk_k = map(int, blocks.split("x"))
+    S = 16 * n
+    visited, _ = fa.block_counts(S, blk_q, blk_k)
+    assert fa.grid_steps(S, blk_q, blk_k, True) == (visited, visited)
+    args = _inputs(g, S, hd, dtype) + (blk_q, blk_k)
+    got = _five(fa, *args)
+    with _as_the_parent_ran_them(fa, "grid"):
+        assert fa.grid_steps(S, blk_q, blk_k, True) == (
+            (S // blk_q) * (S // blk_k),) * 2
+        want = _five(fa, *args)
+    return _same_bits(got, want)
+
+
+# documents of a row of 256 positions in 8 blocks of 32
+PACKED_ROWS = {
+    "one-document": (256,),
+    "on-the-blocks-edges": (64, 96, 32, 64),
+    "shorter-than-a-block": (100, 10, 146),
+    "sixteen-in-eight-blocks": (10, 22, 16, 16, 5, 27, 20, 12, 16, 16, 30, 2,
+                                16, 16, 8, 24)}
+# the row, query heads to a key/value head, head size, dtype, (blk_q, blk_k),
+# window: grouped heads of 64 as the Granite cell's, plain heads of 16, and a
+# band and blocks that differ over the rows with the most boundaries
+_SKIP_CASES = [("skip", row, g, hd, dtype, "32x32", None)
+               for row in PACKED_ROWS
+               for g, hd, dtype in ((4, 64, "bfloat16"), (1, 16, "float32"))
+               ] + [("skip", "sixteen-in-eight-blocks", 4, 64, "bfloat16", "32x32", 80),
+                    ("skip", "shorter-than-a-block", 2, 16, "float32", "64x32", None),
+                    ("skip", "on-the-blocks-edges", 2, 16, "float32", "32x64", 100)]
+
+
+def _documents_of(row):
+    return np.repeat(np.arange(len(PACKED_ROWS[row])), PACKED_ROWS[row]).astype(np.int32)
+
+
+def _five_with_and_without_the_skip(_, row, g, hd, dtype, blocks, window):
+    """{output: the same bits?} of a packed call whose blocks of other
+    documents run no body, against the parent's kernels on the same inputs:
+    every block under the diagonal computed, a square grid."""
+    fa = _fa()
+    blk_q, blk_k = map(int, blocks.split("x"))
+    documents = _documents_of(row)
+    S = documents.size
+    if len(PACKED_ROWS[row]) > 1 and window is None:  # something is skipped
+        assert fa.block_counts(S, blk_q, blk_k, None, documents)[0] < fa.block_counts(
+            S, blk_q, blk_k)[0]
+    args = _inputs(g, S, hd, dtype) + (blk_q, blk_k, window,
+                                       jnp.asarray(documents)[None], 1.0 / 64)
+    got = _five(fa, *args)
+    with _as_the_parent_ran_them(fa, "grid", "documents"):
+        want = _five(fa, *args)
+    return _same_bits(got, want)
 
 
 @pytest.fixture(scope="module")
@@ -373,23 +486,118 @@ def test_unmasked_interior_blocks_change_no_bit(both_forms_bits, call, hd, dtype
     assert all(same.values()) and len(same) == 5, same
 
 
+@pytest.mark.parametrize("case", _GRID_CASES, ids=["-".join(map(str, c)) for c in _GRID_CASES])
+def test_a_grid_of_the_live_pairs_alone_changes_no_bit(both_forms_bits, case):
+    """A causal sweep without a window runs over its live pairs by a table
+    and not over a square: output, row log-sum-exp, dq, dk and dv are the
+    square grid's to the bit."""
+    same = both_forms_bits["-".join(map(str, case))]
+    assert all(same.values()) and len(same) == 5, same
+
+
+@pytest.mark.parametrize("case", _SKIP_CASES, ids=["-".join(map(str, c)) for c in _SKIP_CASES])
+def test_a_skipped_block_of_other_documents_changes_no_bit(both_forms_bits, case):
+    """A block whose keys are all of earlier documents than its queries added
+    exact zeros under its all-false mask and left the running maximum where
+    it was: run for nothing, all five outputs keep the parent's bits."""
+    same = both_forms_bits["-".join(map(str, case))]
+    assert all(same.values()) and len(same) == 5, same
+
+
+@pytest.mark.parametrize("g", [1, 3])
+@pytest.mark.parametrize("n", [4, 8, 16])
+def test_a_sweep_over_its_live_pairs_matches_dense(n, g):
+    """4, 8 and 16 blocks a side: loss and the three gradients of the causal
+    core whose grids hold the live pairs alone, against the dense masked
+    one."""
+    (loss, grads), (want_loss, want) = _flash_and_dense(16 * n, 16, 16, None, g)
+    assert abs(float(loss) - float(want_loss)) <= 1e-4 * abs(float(want_loss)) + 1e-4
+    for got, ref in zip(grads, want):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("causal,window,g", [
+    (True, 40, 1), (True, 24, 3), (False, None, 1), (False, None, 2)],
+    ids=["window", "window-grouped", "not-causal", "not-causal-grouped"])
+def test_a_band_and_a_core_that_is_not_causal_are_the_programs_they_were(causal, window, g):
+    """Neither the table nor the documents' rule is in their traced program:
+    its text is what it is under the parent's rules, letter for letter (and,
+    read once against the parent commit's own file, the parent's: PERF.md,
+    PR 53); three-dimensional grids, no operand more."""
+    from jaxprs import pallas_operands
+
+    fa = _fa()
+    q, k, v, _ = _grouped_qkv(128, g, hd=16, B=1)
+
+    def text():
+        return jax.make_jaxpr(jax.value_and_grad(lambda *a: jnp.sum(
+            fa.flash_attention(*a, causal, None, 32, 16, True, window)), (0, 1, 2)))(q, k, v)
+
+    now = text()
+    with _as_the_parent_ran_them(fa, "grid", "documents"):
+        assert str(text()) == str(now)
+    assert pallas_operands(now.jaxpr) == {"_kernel": 3, "_dq_kernel": 6, "_dkv_kernel": 6}
+    assert "grid=(" + str(2 * g) + ", 4, " in str(now)  # (heads, q-blocks, steps)
+
+
+@pytest.mark.parametrize("blocks,window", [((32, 32), None), ((64, 32), None),
+                                           ((32, 64), None), ((32, 32), 80)])
+@pytest.mark.parametrize("row", list(PACKED_ROWS))
+def test_block_counts_of_a_packed_row_against_brute_force(row, blocks, window):
+    """With a row's documents `block_counts` leaves out the blocks whose mask
+    is false everywhere (exactly those, without a window) and calls interior
+    the blocks whose mask is true everywhere; `_meet` and `_interior` on the
+    block's `_ends` are the rules the kernels run."""
+    fa = _fa()
+    blk_q, blk_k = blocks
+    documents = _documents_of(row)
+    S = documents.size
+    visited = edge = 0
+    for q_off in range(0, S, blk_q):
+        for k_off in range(0, S, blk_k):
+            docs = (documents[q_off:q_off + blk_q, None], documents[None, k_off:k_off + blk_k])
+            ends = fa._ends(documents, q_off, k_off, blk_q, blk_k)
+            mask = np.asarray(fa._mask(q_off, k_off, blk_q, blk_k, window, docs))
+            by_shape = np.asarray(fa._mask(q_off, k_off, blk_q, blk_k, window)).any()
+            live = by_shape and bool(fa._meet(ends))
+            if window is None:
+                assert live == mask.any(), (q_off, k_off)
+            else:  # a band may hold no pair of a block that the rules keep
+                assert live or not mask.any(), (q_off, k_off)
+            if live:
+                visited += 1
+                interior = bool(fa._interior(q_off, k_off, blk_q, blk_k, window, ends))
+                assert interior == mask.all(), (q_off, k_off)
+                edge += not interior
+    assert fa.block_counts(S, blk_q, blk_k, window, documents) == (visited, edge)
+    if row == "one-document":
+        assert (visited, edge) == fa.block_counts(S, blk_q, blk_k, window)
+
+
 def test_the_block_counters_reach_the_metrics():
     """Tracing a core raises `kungfu_flash_blocks_visited_total` and
     `kungfu_flash_blocks_masked_total` by `block_counts`' numbers a head,
     for each kernel it builds: the forward kernel masks the edge blocks, the
-    two backward kernels every block they visit."""
+    two backward kernels every block they visit. `kungfu_flash_grid_steps_total`
+    rises by `grid_steps`': the visited blocks of a causal core without a
+    window (the square grid had 16 for these 10), the band's width a row
+    under a window, dead steps among them."""
     from kungfu_tpu.telemetry import metrics
 
     fa = _fa()
     visited, edge = fa.block_counts(1024, 256, 256)
     assert (visited, edge) == (10, 4)
+    assert fa.grid_steps(1024, 256, 256, True) == (10, 10)
+    assert fa.grid_steps(1024, 256, 256, True, 256) == (8, 8)
+    assert fa.grid_steps(1024, 256, 256, False) == (16, 16)
+    names = ("kungfu_flash_blocks_visited_total", "kungfu_flash_blocks_masked_total",
+             "kungfu_flash_grid_steps_total")
 
     def read():
         return {(name, kernel): metrics.counter(
             name, labelnames=("kernel",)).labels(kernel).value
-            for name in ("kungfu_flash_blocks_visited_total",
-                         "kungfu_flash_blocks_masked_total")
-            for kernel in ("forward", "dq", "dkv")}
+            for name in names for kernel in ("forward", "dq", "dkv")}
 
     q = jnp.zeros((1, 3, 1024, 64))
     before = read()
@@ -398,17 +606,16 @@ def test_the_block_counters_reach_the_metrics():
     after = read()
     for (name, kernel), was in before.items():
         masked = edge if kernel == "forward" else visited
-        want = 3 * (visited if "visited" in name else masked)
+        want = 3 * (masked if "masked" in name else visited)
         assert after[(name, kernel)] - was == want, (name, kernel)
-    # a band core: every visited block is masked
+    # a band core: every visited block is masked, and a row's first step of
+    # two is dead in the first row
     before = after
     jax.jit(lambda q: fa.flash_attention(q, q, q, True, None, 256, 256, True, 256)
             ).lower(q)
     after = read()
-    assert after[("kungfu_flash_blocks_visited_total", "forward")] - before[
-        ("kungfu_flash_blocks_visited_total", "forward")] == 3 * 7
-    assert after[("kungfu_flash_blocks_masked_total", "forward")] - before[
-        ("kungfu_flash_blocks_masked_total", "forward")] == 3 * 7
+    for name, want in zip(names, (7, 7, 8)):
+        assert after[(name, "forward")] - before[(name, "forward")] == 3 * want, name
 
 
 # the three kinds of call: (query heads to a key/value head, window)
@@ -454,8 +661,11 @@ if __name__ == "__main__":  # one of `both_forms_bits`' own processes
     import json
     import sys
 
-    print(json.dumps({f"{call}-{hd}-{dtype}": _five_of_both_forms(call, hd, dtype)
-                      for call, hd, dtype in _BIT_CASES[int(sys.argv[1])::_BIT_CHILDREN]}))
+    cases = ([(_five_of_both_forms, c) for c in _BIT_CASES]
+             + [(_five_of_both_grids, c) for c in _GRID_CASES]
+             + [(_five_with_and_without_the_skip, c) for c in _SKIP_CASES])
+    print(json.dumps({"-".join(map(str, case)): five(*case)
+                      for five, case in cases[int(sys.argv[1])::_BIT_CHILDREN]}))
 
 
 # -- packed rows (PR 52): `segments` number each position's document ---------
@@ -505,7 +715,9 @@ def test_a_packed_row_is_its_documents_run_one_at_a_time(hd, g, window):
 
 def test_without_segments_the_kernels_are_the_program_they_were():
     """No operand and no equation more without segments, forward and
-    backward; a packed call's three kernels take two operands more, the
+    backward (q, k, v and what the backward kernels read, behind the table
+    of a causal sweep's live pairs, PR 53); a packed call's three kernels
+    take three operands more, the blocks' bounds for scalar memory and the
     documents' numbers for the queries and for the keys; and segments of a
     core that is not causal, or of another shape, are refused."""
     from jaxprs import pallas_operands
@@ -520,9 +732,9 @@ def test_without_segments_the_kernels_are_the_program_they_were():
 
     assert str(both()) == str(both(None))
     assert pallas_operands(both().jaxpr) == {
-        "_kernel": 3, "_dq_kernel": 6, "_dkv_kernel": 6}
+        "_kernel": 4, "_dq_kernel": 7, "_dkv_kernel": 7}
     assert pallas_operands(both(segments).jaxpr) == {
-        "_kernel": 5, "_dq_kernel": 8, "_dkv_kernel": 8}
+        "_kernel": 7, "_dq_kernel": 10, "_dkv_kernel": 10}
     with pytest.raises(ValueError, match="segments"):
         flash_attention(q, k, v, False, None, 32, 32, True, None, segments)
     with pytest.raises(ValueError, match="segments"):

@@ -145,6 +145,27 @@ def test_packing_stats_are_the_benchmarks_own_count():
             family.model_config(CONFIG), end_of_document=None))
 
 
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+def test_the_dead_block_share_is_the_blocks_the_kernels_skip(seed):
+    """The family's count against the kernels' own rule on the numbers the
+    model hands them (`_segments`): of the blocks under the diagonal, those
+    that `block_counts` leaves out for a row's documents (PR 53: they run no
+    body)."""
+    from kungfu_tpu.models.transformer import _segments
+    from kungfu_tpu.ops.flash_attention import block_counts
+
+    batch = family.host_batch(CONFIG, seed, 0, 3)
+    blk_q, blk_k = CONFIG["flash_blocks"]
+    (numbers,) = _segments(jnp.asarray(batch[:, :-1]), family.model_config(CONFIG))
+    numbers = np.asarray(numbers)
+    S = numbers.shape[1]
+    under, _ = block_counts(S, blk_q, blk_k)
+    live = sum(block_counts(S, blk_q, blk_k, None, row)[0] for row in numbers)
+    assert 0 < live < len(numbers) * under
+    assert family.dead_block_share(CONFIG, [batch]) == pytest.approx(
+        1 - live / (len(numbers) * under), abs=1e-12)
+
+
 def test_what_keeps_no_documents_apart_is_refused():
     base = dict(end_of_document=0, attn_core="flash")
     fc.refused("packed documents", **{**base, "attn_core": "dense"})
