@@ -7,8 +7,10 @@ drives the training path once through the entry points a user calls, at
 the full width and depth of `TransformerConfig.bert_base()`, on every chip
 JAX finds: `make_mesh` -> `synchronous_sgd` -> `make_train_step`, the
 tensor-parallel `make_sharded_train_step`, `kfrun` workers joined by
-`initialize_device_plane()`, two worlds bridged by `make_hier_train_step`,
-and the Pallas flash-attention kernels compiled by Mosaic. It exits 0 only
+`initialize_device_plane()`, a job that `kfrun` resizes 4 -> 2 -> 4 in
+reload mode with its state carried by a checkpoint, two worlds bridged by
+`make_hier_train_step`, and the Pallas flash-attention kernels compiled by
+Mosaic. It exits 0 only
 if every phase passed, and then ends its output with two lines: the report
 (`CHIP_SMOKE_REPORT ` and one JSON object: versions, and per phase its wall
 seconds, seconds to the first step and the checks' values) and, last, the
@@ -44,6 +46,10 @@ REPORT_TAG = "CHIP_SMOKE_REPORT "  # the parent's account of every phase
 PER_CHIP_BATCH = 16  # largest power of two that leaves headroom in 16 GB
 TRAIN_STEPS = 8  # after the compiling one
 WORKER_STEPS = 3  # launcher and hierarchical workers
+# the resize phase: rank 0 asks for this many workers before that step,
+# and the job ends after RESIZE_STEPS
+RESIZES = {20: 2, 40: 4}
+RESIZE_STEPS = 60
 LEARNING_RATE = 3e-4
 # train-sharded against train, first three losses. Both compute in bf16
 # (8-bit mantissa, 2^-8 = 3.9e-3 per rounding) and reduce in different
@@ -299,38 +305,42 @@ def kernels_phase(seq: int, head_dims, interpret: bool,
             jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
         )
 
-    with jax.default_matmul_precision("highest"):
-        for hd in head_dims:
-            # drawn on the host: no program besides the two under test
-            rng = np.random.RandomState(hd)
-            q, k, v, w = (
-                jnp.asarray(rng.standard_normal((1, 4, seq, hd)), jnp.bfloat16)
-                for _ in range(4)
-            )
-            scale = 1.0 / hd ** 0.5
+    for hd in head_dims:
+        # drawn on the host: no program besides the two under test
+        rng = np.random.RandomState(hd)
+        q, k, v, w = (
+            jnp.asarray(rng.standard_normal((1, 4, seq, hd)), jnp.bfloat16)
+            for _ in range(4)
+        )
+        scale = 1.0 / hd ** 0.5
 
-            def flash(q, k, v):
-                o = flash_attention(q, k, v, True, None, blk, blk, interpret)
-                return jnp.sum(o.astype(jnp.float32) * w), o
+        def flash(q, k, v):
+            o = flash_attention(q, k, v, True, None, blk, blk, interpret)
+            return jnp.sum(o.astype(jnp.float32) * w), o
 
-            def dense(q, k, v):
-                o = _dense_reference(q, k, v, True, scale)
-                return jnp.sum(o.astype(jnp.float32) * w), o
+        def dense(q, k, v):
+            o = _dense_reference(q, k, v, True, scale)
+            return jnp.sum(o.astype(jnp.float32) * w), o
 
-            (_, o_f), g_f = value_and_grads(flash)(q, k, v)
+        # the kernels as a model traces them, the reference at the highest
+        # precision: under that setting Mosaic refuses the kernels' bf16
+        # products since PR 53 ("Bad lhs type": the smoke failed here at
+        # PR 54's parent)
+        (_, o_f), g_f = value_and_grads(flash)(q, k, v)
+        with jax.default_matmul_precision("highest"):
             (_, o_d), g_d = value_and_grads(dense)(q, k, v)
-            for name, a, b in zip(
-                ("out", "dq", "dk", "dv"), (o_f, *g_f), (o_d, *g_d)
-            ):
-                a = a.astype(jnp.float32)
-                b = b.astype(jnp.float32)
-                _check(bool(jnp.all(jnp.isfinite(a))),
-                       f"hd={hd} {name}: non-finite values")
-                err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
-                out["errors"][f"hd{hd}_{name}"] = round(err, 5)
-                _check(err <= KERNEL_TOL,
-                       f"hd={hd} {name}: error {err:.4f} of the reference's "
-                       f"scale exceeds {KERNEL_TOL}")
+        for name, a, b in zip(
+            ("out", "dq", "dk", "dv"), (o_f, *g_f), (o_d, *g_d)
+        ):
+            a = a.astype(jnp.float32)
+            b = b.astype(jnp.float32)
+            _check(bool(jnp.all(jnp.isfinite(a))),
+                   f"hd={hd} {name}: non-finite values")
+            err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+            out["errors"][f"hd{hd}_{name}"] = round(err, 5)
+            _check(err <= KERNEL_TOL,
+                   f"hd={hd} {name}: error {err:.4f} of the reference's "
+                   f"scale exceeds {KERNEL_TOL}")
     out["check_s"] = round(time.perf_counter() - t_start, 3)
     return out
 
@@ -343,6 +353,24 @@ def _params_digest(params) -> bytes:
     for leaf in jax.tree.leaves(params):
         h.update(np.asarray(leaf).tobytes())
     return h.digest()
+
+
+def _span_timeline(spawn_ts: float, prefixes):
+    """(ms by span name, [[name, seconds since spawn, seconds, args]] in
+    order of start) of this process's ring under `prefixes`: each span
+    where it fell, because a sum hides that a name ran twice, and what
+    waited between two spans."""
+    from kungfu_tpu.telemetry import tracing
+
+    spans_ms, timeline = {}, []
+    to_wall = time.time() - time.perf_counter()  # the ring's clock -> wall
+    for prefix in prefixes:
+        spans_ms.update(tracing.summary_ms(prefix))
+        timeline += [[e.name, round(e.start + to_wall - spawn_ts, 3),
+                      round(e.duration, 3), e.args]
+                     for e in tracing.full_events(prefix)]
+    timeline.sort(key=lambda e: e[1])
+    return spans_ms, timeline
 
 
 def _native_loaded() -> bool:
@@ -412,16 +440,8 @@ def launcher_worker(cfg, steps: int, per_chip_batch: int,
                      jax.local_devices())
     since_spawn["first_step"] = since_spawn["state_placed"] + out["first_step_s"]
     # the launcher and placement phases by span, once a process (PERF.md)
-    spans_ms, timeline = {}, []
-    to_wall = time.time() - time.perf_counter()  # the ring's clock -> wall
-    for prefix in ("worker.", "device_plane.", "broadcast.", "smoke."):
-        spans_ms.update(tracing.summary_ms(prefix))
-        # each span where it fell: a sum hides that a name ran twice, and
-        # what waited between two spans
-        timeline += [[e.name, round(e.start + to_wall - spawn_ts, 3),
-                      round(e.duration, 3), e.args]
-                     for e in tracing.full_events(prefix)]
-    timeline.sort(key=lambda e: e[1])
+    spans_ms, timeline = _span_timeline(
+        spawn_ts, ("worker.", "device_plane.", "broadcast.", "smoke."))
     if rank == 0:
         print("launch and placement spans (ms): " + json.dumps(spans_ms),
               flush=True)
@@ -446,6 +466,147 @@ def launcher_worker(cfg, steps: int, per_chip_batch: int,
         "params_agree": agreed,
         "native_kernels": _native_loaded(),
     }
+
+
+def resize_worker(cfg, per_chip_batch: int, resizes: dict, max_steps: int,
+                  ckpt_dir: str) -> None:
+    """One kfrun worker of one incarnation of the resize phase: join the
+    device world of this membership, restore what the last incarnation
+    saved, train; rank 0 asks for `resizes[step]` workers before that
+    step, and every worker saves when the reload is agreed. Prints its
+    tagged result itself, before the runner hears of the reload: the
+    runner stops a worker as soon as it does."""
+    from kungfu_tpu import api, knobs
+    from kungfu_tpu.parallel import initialize_device_plane
+    from kungfu_tpu.telemetry import device, tracing
+
+    initialize_device_plane()
+    rank, size = api.current_rank(), api.cluster_size()
+
+    import jax
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from kungfu_tpu.elastic.checkpoint import Checkpointer
+    from kungfu_tpu.elastic.state import ElasticState
+    from kungfu_tpu.initializer import broadcast_variables
+    from kungfu_tpu.optimizers import synchronous_sgd
+    from kungfu_tpu.parallel import make_mesh, make_train_step
+    from kungfu_tpu.parallel.dp import replicate
+    from kungfu_tpu.peer import get_default_peer
+
+    n = jax.device_count()
+    _check(jax.process_count() == size,
+           f"jax.process_count() {jax.process_count()} != {size} workers")
+    mesh = make_mesh({"dp": n})
+    opt = synchronous_sgd(optax.adamw(LEARNING_RATE), "dp")
+    with tracing.span("smoke.init_params"):
+        params = jax.block_until_ready(_init_params(cfg))
+    params = broadcast_variables(params, mesh)
+    with tracing.span("smoke.opt_state"):
+        opt_state = jax.block_until_ready(replicate(opt.init(params), mesh))
+
+    es = ElasticState(max_progress=max_steps, reload_mode=True)
+    # one jax world, global arrays: every worker takes part in a save
+    ckpt = Checkpointer(ckpt_dir, save_rank=None)
+    leaves, treedef = jax.tree.flatten((params, opt_state))
+    del params, opt_state
+
+    def as_saved(leaves) -> dict:  # a flat tree orbax takes as it is
+        return {f"{i:03d}": leaf for i, leaf in enumerate(leaves)}
+
+    restored, start = ckpt.restore_or(as_saved(leaves))
+    leaves = [restored[k] for k in sorted(restored)]
+    live = dict(zip(("params", "opt_state"), jax.tree.unflatten(treedef, leaves)))
+    del leaves, restored
+    _check(start == es.progress,
+           f"restored step {start}, the runner carried {es.progress}")
+    handed = {}
+    if start:
+        with tracing.span("smoke.digest"):
+            digest = _params_digest(live["params"])
+        with open(os.path.join(ckpt_dir, f"saved-{start}.json")) as f:
+            handed = json.load(f)  # handed_digest, loss_before
+        handed["restored_digest"] = digest.hex()
+        handed["ranks_agree"] = api.consensus(digest, f"chip-smoke-resize:{start}")
+
+    with tracing.span("smoke.batch"):
+        tokens = _seeded_batch(cfg, per_chip_batch * n)
+        batch = jax.make_array_from_callback(
+            tokens.shape, NamedSharding(mesh, P("dp")), lambda idx: tokens[idx]
+        )
+    step = make_train_step(_loss_fn(cfg), opt, mesh)
+    first_step, losses, leaving = es.progress, [], {}
+
+    def report(reason: str) -> None:
+        by_cache = device.compile_requests()
+        print(RESULT_TAG + json.dumps({
+            "rank": rank,
+            "workers": size,
+            "version": get_default_peer().cluster_version,
+            "device": _device_report(),
+            "local_devices": [d.id for d in jax.local_devices()],
+            "coords": [getattr(d, "coords", None) for d in jax.local_devices()],
+            "slots": list(get_default_peer().config.device_slots),
+            "process_bounds": get_default_peer().config.device_world.get(
+                "TPU_PROCESS_BOUNDS"),
+            "first_step": first_step,
+            "last_step": es.progress,
+            "stop_reason": reason,
+            "resize_phases": api.last_resize_phases() if start else {},
+            **handed,
+            "first_loss": losses[0],
+            "last_loss": losses[-1],
+            "compile_requests": by_cache,
+            # the compiles worth a name: what missed the cache for a second or more
+            "slow_misses": [
+                [e.args.get("fun_name"), round(e.duration, 1)]
+                for e in tracing.full_events("device_plane.compile.backend")
+                if e.args.get("cache") == "miss" and e.duration >= 1.0],
+            "smoke_spans_ms": tracing.summary_ms("smoke."),
+            "checkpoint_spans_ms": tracing.summary_ms("checkpoint."),
+            **leaving,
+        }), flush=True)
+
+    def save(progress: int) -> None:
+        if leaving.get("digest_step") != progress:  # agreed a step late
+            with tracing.span("smoke.digest"):
+                leaving["saved_digest"] = _params_digest(live["params"]).hex()
+        ckpt.save(progress, as_saved(jax.tree.leaves((live["params"], live["opt_state"]))))
+        if rank == 0:
+            with open(os.path.join(ckpt_dir, f"saved-{progress}.json"), "w") as f:
+                json.dump({"handed_digest": leaving["saved_digest"],
+                           "loss_before": losses[-1]}, f)
+        report("reload")
+
+    es.on_reload(save)
+    while not es.stopped():
+        with es.scope():
+            target = resizes.get(es.progress)
+            asked = target is not None and target != size
+            if asked and rank == 0:
+                api.propose_new_size(target)
+            live["params"], live["opt_state"], loss = step(
+                live["params"], live["opt_state"], batch)
+            # the step is awaited before end(): the pause ends there
+            losses.append(float(jax.block_until_ready(loss)))
+            if start and rank == 0 and len(losses) == 2:
+                # a step late, so that the print is outside the pause
+                print("[name, seconds since spawn, seconds, args]: " + json.dumps(
+                    _span_timeline(float(knobs.raw("KF_SPAWN_TS")), (
+                        "worker.", "device_plane.", "broadcast.", "smoke.",
+                        "checkpoint.", "elastic.", "resize."))[1]), flush=True)
+            if asked:
+                # before the pause begins: the smoke's own check is not
+                # the resize's cost
+                leaving["saved_digest"] = _params_digest(live["params"]).hex()
+                leaving["digest_step"] = es.progress + 1
+            es.end(1)
+    _check(all(l == l and abs(l) != float("inf") for l in losses),
+           f"non-finite loss: {losses}")
+    if es.stop_reason == "finished":
+        report("finished")
+        api.run_barrier()
 
 
 def hier_worker(cfg, steps: int, per_chip_batch: int) -> dict:
@@ -515,11 +676,14 @@ def _child(phase: str, arg: str) -> dict:
     from kungfu_tpu.parallel.chip import require_tpu
 
     cfg = TransformerConfig.bert_base()
-    if phase == "launcher-worker":
+    if phase in ("launcher-worker", "resize-worker"):
         from kungfu_tpu.parallel import initialize_device_plane
 
         initialize_device_plane()  # before the backend starts
         require_tpu()
+        if phase == "resize-worker":
+            resize_worker(cfg, PER_CHIP_BATCH, RESIZES, RESIZE_STEPS, arg)
+            sys.exit(0)  # it printed its own result, before the reload
         return launcher_worker(cfg, WORKER_STEPS, PER_CHIP_BATCH)
     require_tpu()
     if phase == "train":
@@ -588,14 +752,84 @@ def _run(name: str, argv, timeout: float):
     return code, results, round(time.monotonic() - t0, 1)
 
 
-def _kfrun(np_: int, host_chips: int, worker_phase: str):
+def _kfrun(np_: int, host_chips: int, worker_phase: str, *worker_args,
+           elastic: bool = False):
     return [
         sys.executable, "-m", "kungfu_tpu.runner.cli",
         "-np", str(np_), "-H", f"127.0.0.1:{np_}",
         "-devices-per-host", str(host_chips),
+        # a job the runner may resize: it watches for Stages, restarts
+        # every worker on one, and serves the membership itself
+        *(("-w", "-elastic-mode", "reload", "-builtin-config-port", "0")
+          if elastic else ()),
         "--", sys.executable, os.path.join(REPO, "chip_smoke.py"),
-        worker_phase,
+        worker_phase, *worker_args,
     ]
+
+
+# a pause's parts, which with `unaccounted_ms` are the pause
+PAUSE_PARTS = ("agree_ms", "kill_ms", "spawn_ms", "import_ms", "startup_ms",
+               "device_plane_ms", "restore_ms", "broadcast_ms", "compile_ms",
+               "first_step_ms")
+
+
+def resize_report(results, sizes) -> dict:
+    """The resize phase's account from its workers' results: one entry an
+    incarnation, rank 0's pause with its parts and the slowest rank's.
+    Raises SmokeFailure where the job did not run as `sizes` says, lost
+    its state on the way, or left a part of a pause unmeasured."""
+    versions = sorted({r["version"] for r in results})
+    _check(len(versions) == len(sizes),
+           f"{len(versions)} incarnations ran, not {len(sizes)}")
+    out = []
+    for version, size in zip(versions, sizes):
+        ranks = sorted((r for r in results if r["version"] == version),
+                       key=lambda r: r["rank"])
+        _check([r["rank"] for r in ranks] == list(range(size))
+               and all(r["workers"] == size for r in ranks),
+               f"incarnation {version}: ranks {[r['rank'] for r in ranks]} of "
+               f"{[r['workers'] for r in ranks]} workers, not {size}")
+        first = ranks[0]
+        entry = {
+            "version": version, "workers": size,
+            "slots": [r["slots"] for r in ranks],
+            "local_devices": [r["local_devices"] for r in ranks],
+            "coords": [r["coords"] for r in ranks],
+            "process_bounds": first["process_bounds"],
+            **{k: first[k] for k in (
+                "first_step", "last_step", "stop_reason", "first_loss",
+                "last_loss", "compile_requests", "smoke_spans_ms",
+                "checkpoint_spans_ms")},
+        }
+        if version != versions[0]:
+            for r in ranks:
+                _check(r["ranks_agree"]
+                       and r["restored_digest"] == r["handed_digest"],
+                       f"incarnation {version}, rank {r['rank']}: restored "
+                       f"{r['restored_digest']}, saved {r['handed_digest']}")
+                parts = r["resize_phases"]
+                missing = [k for k in PAUSE_PARTS if parts.get(k) is None]
+                _check(not missing, f"incarnation {version}, rank "
+                       f"{r['rank']}: no {missing} in {parts}")
+                total = sum(parts[k] for k in PAUSE_PARTS) + parts["unaccounted_ms"]
+                _check(abs(total - parts["pause_ms"]) < 1.0
+                       and parts["unaccounted_ms"] >= 0,
+                       f"incarnation {version}, rank {r['rank']}: parts sum to "
+                       f"{total}, pause {parts['pause_ms']}")
+            entry.update(
+                loss_before=first["loss_before"],
+                digest=first["restored_digest"][:16],
+                pause=first["resize_phases"],
+                # a rank that compiles what the others load shows here, and
+                # in the others' first step, which waits for it
+                parts_by_rank={
+                    k: [r["resize_phases"][k] for r in ranks]
+                    for k in (*PAUSE_PARTS, "unaccounted_ms", "pause_ms",
+                              "compile_hits", "compile_misses")},
+                slow_misses_by_rank=[r["slow_misses"] for r in ranks],
+            )
+        out.append(entry)
+    return {"incarnations": out}
 
 
 def result_line(device: dict) -> str:
@@ -665,6 +899,25 @@ def main() -> int:
         w["local_devices"] for w in workers
     ])
     native = all(w["native_kernels"] for w in workers)
+
+    if n >= 4:
+        import shutil
+        import tempfile
+
+        scratch = tempfile.mkdtemp(prefix="chip_smoke_resize_")
+        try:
+            sizes = [n, *RESIZES.values()]
+            # 175 s with the compile cache warm, 275 s with it cold (PR 54)
+            incarnations = phase(
+                "resize", _kfrun(n, n, "resize-worker", scratch, elastic=True),
+                want=sum(sizes),
+            )
+        finally:
+            shutil.rmtree(scratch, ignore_errors=True)
+        try:
+            phases["resize"].update(resize_report(incarnations, sizes))
+        except SmokeFailure as e:
+            fail("resize", str(e))
 
     if n >= 4:
         worlds = phase("launcher-hier", _kfrun(2, n, "hier-worker"), want=2)

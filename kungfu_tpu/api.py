@@ -341,8 +341,19 @@ def propose_new_size(new_size: int) -> None:
 
 
 def last_resize_phases() -> dict:
-    """Per-phase ms breakdown of the most recent resize seen by this peer
-    (wait_config / consensus / notify / update)."""
+    """Per-phase ms breakdown of the most recent resize seen by this peer.
+
+    A peer that lived through a delta resize: `wait_config_ms`,
+    `consensus_ms`, `notify_ms` (rank 0), `update_ms`, each the duration
+    of its `resize.*` span. A worker that a reload started, once its
+    `ElasticState` has seen the first step end: the whole pause as
+    `elastic.state.pause_parts` makes it from the marks that came with
+    the worker and its own ring (`agree_ms` with the old workers'
+    `wait_config_ms`, `consensus_ms`, `notify_ms` inside it, `kill_ms`,
+    `spawn_ms`, `import_ms`, `startup_ms`, `device_plane_ms`,
+    `restore_ms`, `broadcast_ms`, `compile_ms` with `compile_hits` and
+    `compile_misses`, `first_step_ms`, `pause_ms`, `unaccounted_ms`).
+    {} before any resize: a first incarnation knows of none."""
     return dict(get_default_peer().last_resize_phases)
 
 
@@ -381,8 +392,8 @@ def metrics_text() -> str:
     return metrics.render()
 
 
-def change_cluster(progress: int):
-    return get_default_peer().change_cluster(progress)
+def change_cluster(progress: int, before_notify=None):
+    return get_default_peer().change_cluster(progress, before_notify)
 
 
 def monitored_all_reduce_array(

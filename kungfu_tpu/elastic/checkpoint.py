@@ -31,9 +31,22 @@ step.
 from __future__ import annotations
 
 import os
+import time
 from typing import Any, Optional, Tuple
 
 from kungfu_tpu.runner.monitored import RECOVER_EPOCH_ENV
+from kungfu_tpu.telemetry import tracing as trace
+
+
+def _tree_bytes(state: Any) -> int:
+    """The bytes of a pytree's leaves, a global array's whole."""
+    import jax
+    import numpy as np
+
+    return sum(
+        int(getattr(leaf, "nbytes", None) or np.asarray(leaf).nbytes)
+        for leaf in jax.tree.leaves(state)
+    )
 
 
 class Checkpointer:
@@ -42,7 +55,16 @@ class Checkpointer:
     Saving is rank-0-only by default (synchronous data parallelism keeps
     state replicated); every rank restores from the same directory —
     colocated workers share the local FS, multi-host clusters need a
-    shared path (e.g. GCS, which orbax speaks natively)."""
+    shared path (e.g. GCS, which orbax speaks natively). Workers that
+    are one JAX world (`initialize_device_plane()`) hold global arrays,
+    which orbax saves and restores with every process taking part:
+    `save_rank=None` there.
+
+    Spans: `checkpoint.open` (orbax's import and the manager),
+    `checkpoint.save` (`step`, `bytes`, `rank`, `written`; on every rank,
+    a rank that does not write shows that it did not wait) and
+    `checkpoint.restore` (`step`, `bytes`), each around the orbax calls
+    and awaited inside."""
 
     def __init__(
         self,
@@ -50,15 +72,19 @@ class Checkpointer:
         max_to_keep: int = 3,
         save_rank: Optional[int] = 0,
     ):
-        import orbax.checkpoint as ocp
-
-        self._ocp = ocp
         self.directory = os.path.abspath(directory)
         self.save_rank = save_rank
-        self.mgr = ocp.CheckpointManager(
-            self.directory,
-            options=ocp.CheckpointManagerOptions(max_to_keep=max_to_keep),
-        )
+        with trace.span("checkpoint.open", import_s=0.0) as sp:
+            import orbax.checkpoint as ocp
+
+            # what is left of the span is the manager, which in one jax
+            # world waits for every process to have made its own
+            sp.args["import_s"] = round(time.perf_counter() - sp.t0, 3)
+            self._ocp = ocp
+            self.mgr = ocp.CheckpointManager(
+                self.directory,
+                options=ocp.CheckpointManagerOptions(max_to_keep=max_to_keep),
+            )
 
     def _my_rank(self) -> int:
         try:
@@ -72,11 +98,16 @@ class Checkpointer:
 
     def save(self, step: int, state: Any, force: bool = False) -> bool:
         """Save `state` at `step`; returns True if written (rank-gated)."""
-        if self.save_rank is not None and self._my_rank() != self.save_rank:
-            return False
-        self.mgr.save(step, args=self._ocp.args.StandardSave(state), force=force)
-        self.mgr.wait_until_finished()
-        return True
+        rank = self._my_rank()
+        written = self.save_rank is None or rank == self.save_rank
+        with trace.span("checkpoint.save", step=int(step),
+                        bytes=_tree_bytes(state), rank=rank, written=written):
+            if written:
+                self.mgr.save(
+                    step, args=self._ocp.args.StandardSave(state), force=force
+                )
+                self.mgr.wait_until_finished()
+        return written
 
     def latest_step(self) -> Optional[int]:
         """Newest step not beyond the cluster-wide safe resume epoch
@@ -94,9 +125,14 @@ class Checkpointer:
             step = self.latest_step()
         if step is None:
             raise FileNotFoundError(f"no checkpoint in {self.directory}")
-        return self.mgr.restore(
-            step, args=self._ocp.args.StandardRestore(abstract_state)
-        )
+        import jax
+
+        with trace.span("checkpoint.restore", step=int(step)) as sp:
+            state = jax.block_until_ready(self.mgr.restore(
+                step, args=self._ocp.args.StandardRestore(abstract_state)
+            ))
+            sp.args["bytes"] = _tree_bytes(state)
+        return state
 
     def restore_or(self, default_state: Any) -> Tuple[Any, int]:
         """(state, start_step): the newest safe checkpoint, or the given

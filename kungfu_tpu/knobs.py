@@ -178,6 +178,14 @@ _knob("KF_SPAWN_TS", "", _str,
       "Unix timestamp the runner spawned this worker at; start() reports "
       "spawn→ready latency from it.",
       section=_SEC_CONTRACT, kind="float-ts")
+_knob("KF_RESIZE_MARKS", "", _str,
+      "JSON object of the wall-clock marks (`time.time()`) of the reload "
+      "that started this worker: the proposer's `t_propose` with its "
+      "`phases_ms`, `mode` and `old_size`, the runner's `t_stage` and "
+      "`t_killed`, this worker's `t_spawn`. `ElasticState` makes the "
+      "pause's parts from them (`api.last_resize_phases()`); unset for a "
+      "first incarnation.",
+      section=_SEC_CONTRACT, kind="json")
 _knob("KF_LOG_PREFIX", "", _str,
       "Per-worker log prefix (`rank/np`), set by the runner; falls back "
       "to `KF_SELF_SPEC`.",

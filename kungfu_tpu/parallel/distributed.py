@@ -44,6 +44,34 @@ def _free_port(host: str) -> int:
         s.close()
 
 
+_MAX_CHIP_ID = 64
+
+
+def _chip_coords(sess, slots, version: int) -> dict:
+    """{chip id: [x, y, z]} of every one-chip worker of this world, each
+    telling where in the ICI grid its chip sits, summed over the host
+    plane. Which chip ids are neighbours differs between hosts, and the
+    runner that makes the next world's process grid cannot ask libtpu
+    (runner/env.py). {} where the devices have no coordinates (the CPU)."""
+    import jax
+    import numpy as np
+
+    from kungfu_tpu.base.ops import ReduceOp
+    from kungfu_tpu.base.workspace import Workspace
+
+    mine = np.zeros((_MAX_CHIP_ID, 4), np.int64)
+    coords = getattr(jax.local_devices()[0], "coords", None)
+    if len(slots) == 1 and slots[0] < _MAX_CHIP_ID and coords is not None:
+        mine[slots[0]] = (1, *coords)
+    table = np.zeros_like(mine)
+    sess.all_reduce(Workspace(
+        mine.reshape(-1), table.reshape(-1), ReduceOp.SUM,
+        f"kungfu::chipcoords:v{version}",
+    ))
+    return {str(chip): [int(v) for v in row[1:]]
+            for chip, row in enumerate(table) if row[0] == 1}
+
+
 def device_plane_initialized() -> bool:
     return _state["initialized"]
 
@@ -112,6 +140,10 @@ def initialize_device_plane(platform: Optional[str] = None) -> None:
             # so that its seconds are timed where they are spent
             with tracing.span("device_plane.backend_start"):
                 jax.devices()
+            if peer.config.device_slots:
+                with tracing.span("device_plane.chip_coords"):
+                    peer.chip_coords = _chip_coords(
+                        sess, peer.config.device_slots, peer.cluster_version)
         _state["initialized"] = True
         _state["local_only"] = False
         _state["version"] = peer.cluster_version
@@ -152,9 +184,16 @@ def reinitialize_device_plane(platform: Optional[str] = None) -> None:
     The caller must drop references to arrays/compiled functions from the
     old world first (they hold the old backend alive). Parity: NCCL
     ReInit per new cluster version (nccl/controller.hpp:14-44).
+
+    Under `device_plane.reinitialize` (`from_version`: the world torn
+    down), with `device_plane.shutdown` and the bootstrap's own spans
+    inside it: this has not met the chip, and whoever tries it first
+    reads from the ring, or from the open spans, where it stopped.
     """
-    shutdown_device_plane()
-    initialize_device_plane(platform)
+    with tracing.span("device_plane.reinitialize", from_version=_state["version"]):
+        with tracing.span("device_plane.shutdown"):
+            shutdown_device_plane()
+        initialize_device_plane(platform)
 
 
 def current_device_plane_version() -> int:

@@ -18,7 +18,7 @@ import json
 import threading
 import time
 import urllib.request
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 from kungfu_tpu.base.strategy import Strategy
 from kungfu_tpu.collective.host_session import HostSession
@@ -41,6 +41,11 @@ from kungfu_tpu.utils.stall import stall_detect
 
 _default_peer: Optional["Peer"] = None
 _default_lock = threading.Lock()
+
+
+def _ms(span) -> float:
+    """A closed span's duration as a phase of `last_resize_phases`."""
+    return round(span.duration * 1e3, 1)
 
 
 def get_default_peer() -> "Peer":
@@ -80,9 +85,16 @@ class Peer:
         self.epoch_count = 0
         # per-phase wall-clock (ms) of the most recent resize, as seen by
         # this (surviving) peer: wait_config / consensus / notify / update
-        # (update = reconnect + new-session barrier, i.e. joiner-bounded).
+        # (update = reconnect + new-session barrier, i.e. joiner-bounded),
+        # each the duration of its `resize.*` span. A worker that a reload
+        # started holds the whole pause's parts here once its first step
+        # has ended (elastic/state.pause_parts).
         # Parity: the reference's ResizeProfiler phase breakdown.
         self.last_resize_phases: dict = {}
+        # where the chips of this world's one-chip workers sit in the ICI
+        # grid, by chip id (initialize_device_plane() gathers it); a
+        # reload's Stage hands it to the runners for the next world
+        self.chip_coords: dict = {}
         # KF700: config-poll/reload consensus rounds consumed, PER cluster
         # version — every member of a session epoch runs these consensus
         # rounds in lockstep (an allreduce needs all of them), so the
@@ -329,8 +341,8 @@ class Peer:
         telemetry resize audit record.
         """
         sess = self.current_session()
-        t0 = time.perf_counter()
-        with trace.span("resize.consensus"):
+        attrs = self._resize_attrs("delta", cluster)
+        with trace.span("resize.consensus", **attrs) as sp:
             agreed = sess.bytes_consensus(
                 cluster.to_bytes(), f":propose:v{self.cluster_version}"
             )
@@ -340,29 +352,21 @@ class Peer:
             return True, True  # no change
         old_peers = self._peers
         self.last_resize_phases = dict(pre_phases or {})
-        self.last_resize_phases["consensus_ms"] = round(
-            (time.perf_counter() - t0) * 1e3, 1
-        )
+        self.last_resize_phases["consensus_ms"] = _ms(sp)
         stage = {
             "Version": self.cluster_version + 1,
             "Progress": progress,
             "Cluster": cluster.to_json(),
         }
         if sess.rank == 0 and self.config.runners:
-            t1 = time.perf_counter()
-            with trace.span("resize.notify"):
+            with trace.span("resize.notify", **attrs) as sp:
                 self._notify_runners(stage)
-            self.last_resize_phases["notify_ms"] = round(
-                (time.perf_counter() - t1) * 1e3, 1
-            )
+            self.last_resize_phases["notify_ms"] = _ms(sp)
         # all peers advance the version together (they all ran the consensus)
         self.cluster_version += 1
-        t2 = time.perf_counter()
-        with trace.span("resize.update"):
+        with trace.span("resize.update", **attrs) as sp:
             keep = self._update_to(cluster.workers)
-        self.last_resize_phases["update_ms"] = round(
-            (time.perf_counter() - t2) * 1e3, 1
-        )
+        self.last_resize_phases["update_ms"] = _ms(sp)
         from kungfu_tpu.telemetry import audit as _audit
 
         _audit.record_resize(
@@ -457,10 +461,7 @@ class Peer:
         url = self.config.config_server
         if not url:
             return False, False
-        t0 = time.perf_counter()
-        with trace.span("resize.wait_config"):
-            cluster = self._wait_new_config(url)
-        wait_ms = round((time.perf_counter() - t0) * 1e3, 1)
+        sp, cluster = self._wait_new_config_traced(url, "delta")
         if cluster.workers == self._peers:
             return False, False
         # pre_phases rides into _propose so a REJECTED proposal never
@@ -468,7 +469,7 @@ class Peer:
         accepted, keep = self._propose(
             cluster,
             trigger="config_server",
-            pre_phases={"wait_config_ms": wait_ms},
+            pre_phases={"wait_config_ms": _ms(sp)},
         )
         return accepted, not keep
 
@@ -494,32 +495,91 @@ class Peer:
         with urllib.request.urlopen(req, timeout=5) as resp:
             resp.read()
 
-    def change_cluster(self, progress: int) -> Tuple[bool, bool]:
+    def _resize_attrs(self, mode: str, cluster: Optional[Cluster] = None) -> dict:
+        """What every span of a resize carries: the mode, the cluster
+        version the resize makes, and the sizes on both sides of it."""
+        attrs = {
+            "mode": mode,
+            "version": self.cluster_version + 1,
+            "old_size": len(self._peers),
+        }
+        if cluster is not None:
+            attrs["new_size"] = len(cluster.workers)
+        return attrs
+
+    def _wait_new_config_traced(self, url: str, mode: str):
+        """(`resize.wait_config` span, cluster): the span is the wait's one
+        clock, and learns the new size when the wait ends."""
+        with trace.span("resize.wait_config", **self._resize_attrs(mode)) as sp:
+            cluster = self._wait_new_config(url)
+            sp.args["new_size"] = len(cluster.workers)
+        return sp, cluster
+
+    def change_cluster(
+        self, progress: int, before_notify: Optional[Callable[[], None]] = None
+    ) -> Tuple[bool, bool]:
         """Reload-mode resize: every worker exits and the runners relaunch
         from `progress` (parity: ChangeCluster, peer.go:279-291 +
-        ElasticModeReload). Returns (changed, detached_all)."""
+        ElasticModeReload). Returns (changed, detached_all).
+
+        `before_notify` runs on every worker once the new cluster is
+        agreed and before the runners hear of it: a runner stops its
+        workers as soon as it has the Stage, so whatever must outlive
+        this incarnation (a checkpoint) is written there and nowhere
+        later. A barrier follows it, so rank 0 tells the runners only
+        when every worker's has returned. Its time with the barrier is
+        `on_reload_ms`, under `resize.on_reload`.
+
+        The Stage carries `Marks`: the wall time (`time.time()`) of this
+        call and the phases up to the notify, for the new workers'
+        account of the pause (`elastic/state.pause_parts`). On one host
+        every process of a kfrun tree reads the same clock; across hosts
+        each mark is its own host's clock, and a part between two marks
+        of different hosts holds their offset as well."""
         url = self.config.config_server
         if not url:
             return False, False
-        cluster = self._wait_new_config(url)
+        t_propose = time.time()
+        sp, cluster = self._wait_new_config_traced(url, "reload")
         if cluster.workers == self._peers:
             return False, False
+        phases = {"wait_config_ms": _ms(sp)}
+        attrs = self._resize_attrs("reload", cluster)
         sess = self.current_session()
         # KF700: epoch-sequenced — a reload agreement must not rendezvous
         # with an earlier attempt's lanes (repeat change_cluster calls at
         # one version) nor with another epoch's
-        if not sess.bytes_consensus(
-            cluster.to_bytes(), self._cfg_consensus_name("reload")
-        ):
+        with trace.span("resize.consensus", **attrs) as sp:
+            agreed = sess.bytes_consensus(
+                cluster.to_bytes(), self._cfg_consensus_name("reload")
+            )
+        if not agreed:
             return False, False
+        phases["consensus_ms"] = _ms(sp)
+        if before_notify is not None:
+            with trace.span("resize.on_reload", **attrs) as sp:
+                before_notify()
+                sess.barrier(tag=f":on_reload:v{attrs['version']}")
+            phases["on_reload_ms"] = _ms(sp)
         stage = {
             "Version": self.cluster_version + 1,
             "Progress": progress,
             "Cluster": cluster.to_json(),
             "Reload": True,
+            "Marks": {
+                "t_propose": t_propose,
+                "mode": "reload",
+                "old_size": len(self._peers),
+                "phases_ms": dict(phases),
+            },
         }
+        if self.chip_coords:
+            stage["ChipCoords"] = self.chip_coords
         if sess.rank == 0 and self.config.runners:
-            self._notify_runners(stage)
+            with trace.span("resize.notify", **attrs) as sp:
+                self._notify_runners(stage)
+            phases["notify_ms"] = _ms(sp)
+        self.last_resize_phases = phases
         from kungfu_tpu.telemetry import audit as _audit
 
         _audit.record_resize(
@@ -528,6 +588,7 @@ class Peer:
             trigger="reload",
             old_peers=list(self._peers),
             new_peers=list(cluster.workers),
+            phases_ms=phases,
             progress=progress,
             detached=True,
         )

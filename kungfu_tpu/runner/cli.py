@@ -219,9 +219,11 @@ def main(argv: Optional[List[str]] = None) -> int:
 def make_one_worker_proc(
     args, cmd, cluster: Cluster, worker: PeerID, self_host: str,
     strategy: Strategy, config_server_url: str = "", version: int = 0,
-    progress: int = 0, device_slots=None,
+    progress: int = 0, device_slots=None, resize_marks=None,
+    chip_coords=None,
 ) -> WorkerProc:
     rank = cluster.workers.rank(worker)
+    spawn_ts = time.time()
     env = kfenv.worker_env(
         self_id=worker,
         peers=cluster.workers,
@@ -235,9 +237,13 @@ def make_one_worker_proc(
         device_slots=device_slots,
         host_devices=args.devices_per_host,
         port_range=parse_port_range(args.port_range),
+        # a reload's marks go on with this worker's own: one reading
+        # serves KF_SPAWN_TS and the pause's `t_spawn`
+        resize_marks=dict(resize_marks, t_spawn=spawn_ts) if resize_marks else None,
+        chip_coords=chip_coords,
     )
     env["KF_LOG_PREFIX"] = f"{rank}/{len(cluster.workers)}"
-    env["KF_SPAWN_TS"] = str(time.time())
+    env["KF_SPAWN_TS"] = str(spawn_ts)
     return WorkerProc(
         name=f"{rank}/{len(cluster.workers)}",
         argv=list(cmd),

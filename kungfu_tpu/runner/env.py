@@ -26,6 +26,7 @@ ELASTIC_MODE = "KF_ELASTIC_MODE"
 INIT_PROGRESS = "KF_INIT_PROGRESS"
 DEVICE_SLOTS = "KF_DEVICE_SLOTS"
 DEVICE_WORLD = "KF_DEVICE_WORLD"
+RESIZE_MARKS = "KF_RESIZE_MARKS"
 # tuning (parity: config/config.go:24-67)
 ENABLE_MONITORING = "KF_CONFIG_ENABLE_MONITORING"
 ENABLE_STALL_DETECTION = "KF_CONFIG_ENABLE_STALL_DETECTION"
@@ -34,8 +35,8 @@ LOG_LEVEL = "KF_CONFIG_LOG_LEVEL"
 ALL_ENV_NAMES = [
     SELF_SPEC, INIT_PEERS, INIT_RUNNERS, PARENT_ID, INIT_CLUSTER_VERSION,
     ALLREDUCE_STRATEGY, CONFIG_SERVER, ELASTIC_MODE, INIT_PROGRESS,
-    DEVICE_SLOTS, DEVICE_WORLD, ENABLE_MONITORING, ENABLE_STALL_DETECTION,
-    LOG_LEVEL,
+    DEVICE_SLOTS, DEVICE_WORLD, RESIZE_MARKS, ENABLE_MONITORING,
+    ENABLE_STALL_DETECTION, LOG_LEVEL,
 ]
 
 # libtpu's per-process topology on one host, as the chip accepted it in
@@ -47,9 +48,26 @@ ALL_ENV_NAMES = [
 # each process by where its chips sit, not by its task id, so
 # jax.process_index() is not the rank. Host sizes and layouts not listed
 # here have not met the chip and are refused rather than guessed.
+# Which chip ids are neighbours differs from one host to the next (PR 54:
+# chips 0,1 were a column of the 2x2 on one machine and a row on another,
+# and a world of two one-chip workers under "1,2,1" then fails in libtpu's
+# mesh build, "duplicate coordinate assignment"), so where the workers of
+# an earlier world have said where their chips sit (`chip_coords`), the
+# process grid of one-chip workers is made from that and not from the
+# table (`_grid_bounds`).
 HOST_CHIPS = (1, 4)
 _CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
 _PROCESS_BOUNDS = {(1, 4): "2,2,1", (2, 2): "2,1,1", (1, 2): "1,2,1"}
+
+
+def _grid_bounds(coords) -> Optional[str]:
+    """TPU_PROCESS_BOUNDS of one-chip processes whose chips sit at
+    `coords` (x, y, z): the grid they fill, or None where they fill none
+    (two chips on a diagonal of the 2x2)."""
+    xs, ys = {c[0] for c in coords}, {c[1] for c in coords}
+    if not len(xs) * len(ys) == len({tuple(c) for c in coords}) == len(coords):
+        return None
+    return f"{len(xs)},{len(ys)},1"
 
 
 @dataclasses.dataclass
@@ -71,6 +89,10 @@ class WorkerConfig:
     # spanning all workers (empty = the runner described none);
     # initialize_device_plane() applies them before the backend starts
     device_world: dict = dataclasses.field(default_factory=dict)
+    # wall-clock marks of the reload that started this worker (proposer's,
+    # runner's, and `t_spawn`; runner/watch.Stage.marks); empty for a
+    # first incarnation and for a worker no reload started
+    resize_marks: dict = dataclasses.field(default_factory=dict)
 
 
 def parse_config_from_env(environ=None) -> WorkerConfig:
@@ -104,6 +126,7 @@ def parse_config_from_env(environ=None) -> WorkerConfig:
         init_progress=int(env.get(INIT_PROGRESS, "0") or 0),
         device_slots=tuple(int(s) for s in slots_raw.split(",") if s.strip()),
         device_world=json.loads(env.get(DEVICE_WORLD) or "{}"),
+        resize_marks=json.loads(env.get(RESIZE_MARKS) or "{}"),
     )
 
 
@@ -113,6 +136,7 @@ def tpu_process_env(
     device_slots: Sequence[int],
     host_devices: int,
     port_range: Optional[Tuple[int, int]] = None,
+    chip_coords: Optional[dict] = None,
 ) -> dict:
     """The libtpu variables for one worker holding `device_slots` of a
     host with `host_devices` chips.
@@ -127,6 +151,10 @@ def tpu_process_env(
     `port_range`) and this worker's position — for
     `initialize_device_plane()` to apply; the choice between the two
     worlds is the worker's, made before its backend starts.
+
+    `chip_coords` ({chip id as str: [x, y, z]}, what the workers of an
+    earlier world of this host reported; Stage.chip_coords) decides the
+    process grid of one-chip workers where it knows all their chips.
     """
     n = len(device_slots)
     if host_devices not in HOST_CHIPS or n not in _CHIP_BOUNDS:
@@ -144,10 +172,14 @@ def tpu_process_env(
     }
     local = [p for p in peers if p.host == self_id.host]
     i = local.index(self_id)
+    bounds = _PROCESS_BOUNDS.get((n, len(local)))
+    held = [str(j) for j in range(len(local))]  # one chip a worker, in rank order
+    if n == 1 and chip_coords and all(j in chip_coords for j in held):
+        bounds = _grid_bounds([chip_coords[j] for j in held])
     joinable = (
         port_range is not None
         and len(local) == len(peers)
-        and (n, len(local)) in _PROCESS_BOUNDS
+        and bounds is not None
         and list(device_slots) == list(range(i * n, (i + 1) * n))
     )
     if joinable:
@@ -161,7 +193,7 @@ def tpu_process_env(
                 f"of {len(local)} workers"
             )
         env[DEVICE_WORLD] = json.dumps({
-            "TPU_PROCESS_BOUNDS": _PROCESS_BOUNDS[n, len(local)],
+            "TPU_PROCESS_BOUNDS": bounds,
             "TPU_PROCESS_ADDRESSES": ",".join(
                 f"{p.host}:{port}" for p, port in zip(local, ports)
             ),
@@ -184,6 +216,8 @@ def worker_env(
     device_slots=None,
     host_devices: int = 0,
     port_range: Optional[Tuple[int, int]] = None,
+    resize_marks: Optional[dict] = None,
+    chip_coords: Optional[dict] = None,
 ) -> dict:
     """Env block a runner sets for a spawned worker (parity: job.go:35-80)."""
     env = {
@@ -199,10 +233,12 @@ def worker_env(
         env[CONFIG_SERVER] = config_server
     if elastic_mode:
         env[ELASTIC_MODE] = elastic_mode
+    if resize_marks:
+        env[RESIZE_MARKS] = json.dumps(resize_marks)
     if device_slots:
         env[DEVICE_SLOTS] = ",".join(str(i) for i in device_slots)
         # the TPU analog of CUDA_VISIBLE_DEVICES (job.go:35-80)
         env.update(tpu_process_env(
-            self_id, peers, device_slots, host_devices, port_range
+            self_id, peers, device_slots, host_devices, port_range, chip_coords
         ))
     return env

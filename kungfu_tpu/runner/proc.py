@@ -162,11 +162,19 @@ class WorkerProc:
 
     def wait(self, timeout: Optional[float] = None) -> int:
         rc = self.proc.wait(timeout)
-        for t in self._threads:
-            t.join(1)
+        self.drain()
         return rc
 
-    def kill(self) -> None:
+    def drain(self) -> None:
+        """Let the pumps echo what an ended process wrote last: its
+        traceback is there, and a runner that exits first loses it."""
+        for t in self._threads:
+            t.join(1)
+
+    def kill(self) -> bool:
+        """Stop the process and reap it; True if its 5 s after `terminate`
+        ran out and it took a `kill` (the runner's `runner.kill` span
+        says so as `escalated`)."""
         if self.proc and self.proc.poll() is None:
             self.proc.terminate()
             try:
@@ -179,6 +187,8 @@ class WorkerProc:
                     self.proc.wait(5)
                 except subprocess.TimeoutExpired:
                     pass
+                return True
+        return False
 
     @property
     def running(self) -> bool:

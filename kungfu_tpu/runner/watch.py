@@ -21,6 +21,7 @@ from typing import Dict, List, Optional
 
 from kungfu_tpu.plan.cluster import Cluster
 from kungfu_tpu.telemetry import audit, log
+from kungfu_tpu.telemetry import tracing as trace
 from kungfu_tpu.plan.peer import PeerID, PeerList
 from kungfu_tpu.runner.proc import WorkerProc
 from kungfu_tpu.transport.message import ConnType, Message
@@ -28,11 +29,24 @@ from kungfu_tpu.transport.server import Server
 
 
 class Stage:
-    def __init__(self, version: int, progress: int, cluster: Cluster, reload: bool = False):
+    def __init__(self, version: int, progress: int, cluster: Cluster,
+                 reload: bool = False, marks: Optional[dict] = None,
+                 chip_coords: Optional[dict] = None):
         self.version = version
         self.progress = progress
         self.cluster = cluster
         self.reload = reload
+        # a reload's wall-clock marks (`time.time()`), as `Progress`
+        # carried from the old workers to the new: the proposer's
+        # (`t_propose`, its phases; peer.change_cluster), then this
+        # runner's `t_stage` (it has the Stage) and `t_killed` (its last
+        # old worker is gone); each worker it starts gets them with its
+        # own `t_spawn` in KF_RESIZE_MARKS. Empty for any other Stage.
+        self.marks = dict(marks or {})
+        # where the old workers' chips sit in the host's ICI grid, by chip
+        # id ({"0": [x, y, z]}; parallel/distributed._chip_coords): the
+        # process grid of the next world is made from it (runner/env.py)
+        self.chip_coords = dict(chip_coords or {})
 
     @classmethod
     def from_json(cls, obj: dict) -> "Stage":
@@ -41,10 +55,35 @@ class Stage:
             progress=int(obj.get("Progress", 0)),
             cluster=Cluster.from_json(obj["Cluster"]),
             reload=bool(obj.get("Reload", False)),
+            marks=obj.get("Marks"),
+            chip_coords=obj.get("ChipCoords"),
         )
 
     def digest(self) -> bytes:
         return self.cluster.digest() + str(self.version).encode()
+
+
+def _with_runner_ring(doc: dict) -> dict:
+    """The merged trace of the workers with this runner's own ring
+    (`runner.stage`, `runner.kill`, `runner.spawn`) as one more process.
+    The merge is on the runner's `perf_counter` timeline already
+    (TelemetryAggregator.cluster_trace), so the runner's events go in as
+    they are."""
+    pid = 1 + max(
+        (ev["pid"] for ev in doc["traceEvents"] if isinstance(ev.get("pid"), int)),
+        default=-1,
+    )
+    own = trace.chrome_trace()["traceEvents"]
+    for ev in own:
+        ev["pid"] = pid
+    doc["traceEvents"] += [
+        {"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
+         "args": {"name": "runner"}},
+        {"name": "process_sort_index", "ph": "M", "pid": pid, "tid": 0,
+         "args": {"sort_index": pid}},
+        *own,
+    ]
+    return doc
 
 
 class DebugServer:
@@ -79,7 +118,8 @@ class DebugServer:
                 agg.cluster_metrics(), "text/plain; version=0.0.4"
             ),
             "/cluster/trace": lambda agg: (
-                json.dumps(agg.cluster_trace()), "application/json"
+                json.dumps(_with_runner_ring(agg.cluster_trace())),
+                "application/json",
             ),
             "/cluster/audit": lambda agg: (
                 json.dumps(agg.cluster_audit()), "application/json"
@@ -183,6 +223,9 @@ class Watcher:
         self.slot_pool = None
         self.chips_per_worker = 0
         self._worker_slots: Dict[PeerID, list] = {}
+        # of the largest world a Stage has told of: a world's coordinates
+        # are its own, so two worlds' tables do not mix
+        self.chip_coords: dict = {}
         n_dev = getattr(args, "devices_per_host", 0)
         if n_dev > 0:
             from kungfu_tpu.runner.slots import SlotPool
@@ -289,6 +332,8 @@ class Watcher:
         if msg.name != "update":
             return
         stage = Stage.from_json(json.loads(msg.data.decode()))
+        if stage.reload:
+            stage.marks["t_stage"] = time.time()
         digest = stage.digest()
         if stage.version in self.seen_versions:
             if self.seen_versions[stage.version] != digest:
@@ -306,6 +351,13 @@ class Watcher:
 
     # -- proc management -----------------------------------------------
     def _spawn(self, w: PeerID, stage: Stage) -> None:
+        with trace.span(
+            "runner.spawn", rank=stage.cluster.workers.rank(w),
+            version=stage.version,
+        ) as sp:
+            self._spawn_traced(w, stage, sp)
+
+    def _spawn_traced(self, w: PeerID, stage: Stage, sp) -> None:
         from kungfu_tpu.runner.cli import make_one_worker_proc
 
         _t_spawn0 = time.monotonic()
@@ -315,10 +367,12 @@ class Watcher:
             # unpinned worker would try to open chips other workers hold
             slots = self.slot_pool.get(self.chips_per_worker)
             self._worker_slots[w] = slots
+        sp.args["slots"] = slots
         p = make_one_worker_proc(
             self.args, self.cmd, stage.cluster, w, self.self_host, self.strategy,
             self.config_server_url, version=stage.version, progress=stage.progress,
-            device_slots=slots,
+            device_slots=slots, resize_marks=stage.marks,
+            chip_coords=self.chip_coords,
         )
         if self.monitor is not None:
             from kungfu_tpu.runner.monitored import MONITOR_ADDR_ENV
@@ -399,14 +453,31 @@ class Watcher:
     def apply_full(self, stage: Stage) -> None:
         """Reload mode: stop everything, restart from stage.progress."""
         self.last_stage = stage
+        if len(stage.chip_coords) >= len(self.chip_coords):
+            self.chip_coords = stage.chip_coords
         self._update_aggregator(stage)
         self._reset_heartbeats(stage)
         with self._state_lock:
             doomed = list(self.current.items())
             self.current.clear()
+        kills = []
         for w, proc in doomed:
-            proc.kill()
+            # one after another, up to 10 s each; a worker that agreed to
+            # the reload is usually gone already and costs nothing here
+            with trace.span(
+                "runner.kill", rank=proc.rank, version=stage.version
+            ) as sp:
+                sp.args["escalated"] = proc.kill()
+                sp.args["returncode"] = proc.proc.returncode if proc.proc else None
+            kills.append(
+                f"rank {proc.rank}: {sp.duration * 1e3:.0f} ms, exit "
+                f"{sp.args['returncode']}" + (", killed" if sp.args["escalated"] else "")
+            )
             self._release_slots(w)
+        stage.marks["t_killed"] = time.time()
+        if kills:
+            log.info("kfrun: reload v%d: old workers stopped (%s)",
+                     stage.version, "; ".join(kills))
         for w in stage.cluster.workers:
             if w.host == self.self_host:
                 self._spawn(w, stage)
@@ -557,6 +628,10 @@ class Watcher:
             progress=progress,
             cluster=cluster,
             reload=True,
+            # no worker proposed this one: the pause starts where this
+            # runner decided on it
+            marks={"t_stage": time.time(), "mode": "reload",
+                   "old_size": len(base.cluster.workers)},
         )
         self.seen_versions[stage.version] = stage.digest()
         self.record_stage(stage)
@@ -691,16 +766,21 @@ class Watcher:
                         self.standby_pool.refill()
                     continue
                 idle_since = None
-                if stage.reload:
-                    self.apply_full(stage)
-                else:
-                    self.apply_delta(stage)
+                with self._state_lock:
+                    old_size = len(self.current)
+                with trace.span(
+                    "runner.stage", version=stage.version, reload=stage.reload,
+                    old_size=old_size, new_size=len(stage.cluster.workers),
+                ):
+                    if stage.reload:
+                        self.apply_full(stage)
+                    else:
+                        self.apply_delta(stage)
             return self.exit_code
         finally:
-            for p in self.current.values():
+            for p in [*self.current.values(), *self._gone]:
                 p.kill()
-            for p in self._gone:
-                p.kill()
+                p.drain()
             if self.standby_pool is not None:
                 self.standby_pool.kill_all()
             if self.monitor is not None:

@@ -152,9 +152,12 @@ class _Span:
     """Class-based context manager (NOT @contextmanager: spans sit on
     every collective/transport call and generator CMs cost ~3x more to
     enter). Records a complete event on exit; nesting depth comes from a
-    per-thread stack."""
+    per-thread stack. After exit `duration` holds the seconds the event
+    was recorded with, so that a caller who wants the number reads the
+    span (`with span(...) as sp: ...; sp.duration`) and keeps no clock of
+    its own beside it."""
 
-    __slots__ = ("name", "args", "t0", "depth", "mirror")
+    __slots__ = ("name", "args", "t0", "depth", "mirror", "duration")
 
     def __init__(self, name: str, args: Optional[dict]):
         self.name = name
@@ -172,7 +175,7 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self.t0
+        dt = self.duration = time.perf_counter() - self.t0
         if self.mirror is not None:
             self.mirror.__exit__(*exc)
         _stack().pop()

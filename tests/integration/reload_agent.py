@@ -7,7 +7,9 @@ the PRIMARY elastic mode on TPU (SURVEY §7: ICI mesh shape is fixed per
 slice, so membership changes get a fresh mesh via process restart).
 """
 
+import json
 import sys
+import time
 
 import jax
 
@@ -51,6 +53,7 @@ def main() -> int:
     print(f"incarnation rank={rank}/{size} start_progress={es.progress}", flush=True)
     device_psum_check()
 
+    first = True
     while not es.stopped():
         with es.scope():
             if rank == 0:
@@ -58,7 +61,14 @@ def main() -> int:
                 if target is not None and target != api.cluster_size():
                     api.propose_new_size(target)
             es.end(1)
+        if first:
+            # what this incarnation knows of the reload that started it,
+            # and the wall time it knew it at ({} in the first incarnation)
+            first = False
+            print(f"resize_phases rank={rank}/{size} wall={time.time():.6f} "
+                  + json.dumps(api.last_resize_phases()), flush=True)
 
+    print(f"stopping rank={rank}/{size} wall={time.time():.6f}", flush=True)
     print(f"stopped reason={es.stop_reason} progress={es.progress}", flush=True)
     return 0
 

@@ -68,6 +68,35 @@ def test_rank_gating(tmp_path, monkeypatch):
     ckpt.close()
 
 
+def test_open_save_and_restore_leave_one_span_each(tmp_path, monkeypatch):
+    """What a checkpoint costs a step or a resize is read from the ring:
+    one span a call, with the step and the bytes of the tree."""
+    from kungfu_tpu.telemetry import tracing
+
+    tree_bytes = 3 * 2 * 4 + 2 * 4 + 2 * 4  # w, b, momentum: float32
+    tracing.clear()
+    ckpt = Checkpointer(str(tmp_path / "ck"), save_rank=0)
+    assert ckpt.restore_or(_state(0))[1] == 0  # nothing to restore
+    assert not tracing.full_events("checkpoint.restore")
+    assert ckpt.save(5, _state(5))
+    monkeypatch.setattr(Checkpointer, "_my_rank", lambda self: 1)
+    assert not ckpt.save(6, _state(6))  # not this rank's to write: no wait either
+    out, start = ckpt.restore_or(_state(0))
+    assert start == 5
+    events = {}
+    for e in tracing.full_events("checkpoint."):
+        events.setdefault(e.name, []).append(e.args)
+    (opened,) = events["checkpoint.open"]
+    assert opened["import_s"] >= 0
+    assert events["checkpoint.save"] == [
+        {"step": 5, "bytes": tree_bytes, "rank": 0, "written": True},
+        {"step": 6, "bytes": tree_bytes, "rank": 1, "written": False},
+    ]
+    assert events["checkpoint.restore"] == [{"step": 5, "bytes": tree_bytes}]
+    ckpt.close()
+    tracing.clear()
+
+
 def test_dump_final_variables_bf16(tmp_path):
     tree = {"w": jnp.arange(6, dtype=jnp.bfloat16) / 3, "s": jnp.float32(2.5)}
     path = str(tmp_path / "variables-final.kf")

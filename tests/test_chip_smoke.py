@@ -129,6 +129,86 @@ def test_result_line_has_the_contract_keys_and_no_others():
     }
 
 
+def _resize_results(sizes=(4, 2, 4)):
+    """What the workers of a 4 -> 2 -> 4 job print, drawn: every rank of
+    every incarnation, the later ones with a pause whose parts add up."""
+    parts = dict.fromkeys(chip_smoke.PAUSE_PARTS, 1000.0)
+    out = []
+    for version, size in enumerate(sizes):
+        for rank in range(size):
+            r = {
+                "rank": rank, "workers": size, "version": version,
+                "slots": [rank], "local_devices": [rank], "coords": [[rank, 0, 0]],
+                "process_bounds": "2,2,1", "first_step": 20 * version,
+                "last_step": 20 * version + 20, "stop_reason": "reload",
+                "first_loss": 9.0, "last_loss": 8.0, "slow_misses": [],
+                "compile_requests": {"hit": 2, "miss": 0, "off": 0},
+                "smoke_spans_ms": {}, "checkpoint_spans_ms": {},
+                "resize_phases": {},
+            }
+            if version:
+                r.update(
+                    handed_digest="ab" * 32, restored_digest="ab" * 32,
+                    ranks_agree=True, loss_before=8.0,
+                    resize_phases={**parts, "unaccounted_ms": 500.0,
+                                   "pause_ms": 10500.0, "compile_hits": 2,
+                                   "compile_misses": 0},
+                )
+            out.append(r)
+    return out
+
+
+def test_resize_report_of_a_job_that_ran_as_asked():
+    report = chip_smoke.resize_report(_resize_results(), [4, 2, 4])
+    first, second, third = report["incarnations"]
+    assert [i["workers"] for i in report["incarnations"]] == [4, 2, 4]
+    assert "pause" not in first
+    assert second["pause"]["pause_ms"] == 10500.0
+    assert second["parts_by_rank"]["compile_ms"] == [1000.0, 1000.0]
+    assert third["parts_by_rank"]["pause_ms"] == [10500.0] * 4
+    assert second["slots"] == [[0], [1]]
+
+
+def _lost_a_rank(rs):
+    rs.pop()
+
+
+def _restored_something_else(rs):
+    rs[-1]["restored_digest"] = "cd" * 32
+
+
+def _ranks_disagree(rs):
+    rs[5]["ranks_agree"] = False
+
+
+def _left_a_part_out(rs):
+    rs[4]["resize_phases"]["kill_ms"] = None
+
+
+def _parts_do_not_add_up(rs):
+    rs[4]["resize_phases"]["pause_ms"] += 50.0
+
+
+def _never_came_back(rs):
+    del rs[6:]
+
+
+@pytest.mark.parametrize("fault,says", [
+    (_lost_a_rank, "incarnation 2: ranks"),
+    (_restored_something_else, "incarnation 2, rank 3: restored"),
+    (_ranks_disagree, "incarnation 1, rank 1: restored"),
+    (_left_a_part_out, "no ['kill_ms']"),
+    (_parts_do_not_add_up, "parts sum to"),
+    (_never_came_back, "2 incarnations ran, not 3"),
+])
+def test_resize_report_refuses(fault, says):
+    results = _resize_results()
+    fault(results)
+    with pytest.raises(chip_smoke.SmokeFailure) as e:
+        chip_smoke.resize_report(results, [4, 2, 4])
+    assert says in str(e.value)
+
+
 @pytest.mark.parametrize("phase", ["train", "kernels", "hier-worker"])
 def test_child_phases_refuse_the_cpu_before_compiling(phase):
     r = subprocess.run(

@@ -156,6 +156,36 @@ def test_partly_used_host_joins_its_first_column():
     assert all(kfenv.DEVICE_WORLD not in env for env in envs(3))
 
 
+@pytest.mark.parametrize("coords,bounds", [
+    # chips 0,1 side by side in x (the machine of PR 54's calls 1 and 3),
+    # one above the other (call 2's), on a diagonal (no grid: no joined world)
+    ({"0": [1, 1, 0], "1": [0, 1, 0], "2": [0, 0, 0], "3": [1, 0, 0]}, "2,1,1"),
+    ({"0": [1, 0, 0], "1": [1, 1, 0], "2": [0, 1, 0], "3": [0, 0, 0]}, "1,2,1"),
+    ({"0": [0, 0, 0], "1": [1, 1, 0]}, None),
+    ({"0": [0, 0, 0]}, "1,2,1"),  # chip 1 unknown: the table's guess stands
+])
+def test_a_world_of_one_chip_workers_takes_its_grid_from_the_chips_coordinates(
+        coords, bounds):
+    """Which chip ids are neighbours differs between hosts; where an
+    earlier world said where its chips sit, the next world's process grid
+    is made from that."""
+    from kungfu_tpu.plan.peer import PeerID, PeerList
+    from kungfu_tpu.runner import env as kfenv
+
+    peers = PeerList([PeerID("127.0.0.1", 38000 + i) for i in range(2)])
+    for i, p in enumerate(peers):
+        env = kfenv.worker_env(
+            self_id=p, peers=peers, runners=PeerList(), parent=None,
+            device_slots=[i], host_devices=4, port_range=(38000, 38999),
+            chip_coords=coords,
+        )
+        if bounds is None:
+            assert kfenv.DEVICE_WORLD not in env
+        else:
+            world = json.loads(env[kfenv.DEVICE_WORLD])
+            assert world["TPU_PROCESS_BOUNDS"] == bounds
+
+
 def test_standby_activation_carries_the_tpu_env(tmp_path):
     """A warm standby imports jax BEFORE it learns its identity; the
     activation spec must deliver the per-process TPU variables into its
@@ -274,6 +304,65 @@ class TestWatcherReallocation:
                 p.kill()
             for p in w._gone:
                 p.kill()
+
+    def test_a_reload_is_spanned_and_its_marks_go_on_to_the_new_workers(self):
+        """apply_full: a `runner.kill` an old worker, a `runner.spawn` a
+        new one, and the new workers' environment carries the proposer's
+        marks with the runner's own and the grid their chips form."""
+        import time
+
+        from kungfu_tpu.runner import env as kfenv
+        from kungfu_tpu.runner.watch import Stage
+        from kungfu_tpu.telemetry import tracing
+
+        w, stage = self._watcher(n_dev=4, cap=4)
+        tracing.clear()
+        try:
+            w.apply_delta(stage(0, 4))
+            assert not any(kfenv.RESIZE_MARKS in p.env for p in w.current.values())
+            assert not tracing.full_events("runner.kill")
+            old = list(w.current.values())
+            t0 = time.time()
+            reload = Stage(
+                version=1, progress=21, cluster=stage(1, 2).cluster, reload=True,
+                marks={"t_propose": t0 - 2.0, "t_stage": t0 - 1.0, "mode": "reload",
+                       "old_size": 4, "phases_ms": {"consensus_ms": 3.0}},
+                # chips 0 and 1 side by side in x on this host
+                chip_coords={"0": [1, 1, 0], "1": [0, 1, 0],
+                             "2": [0, 0, 0], "3": [1, 0, 0]},
+            )
+            w.apply_full(reload)
+            assert all(not p.running for p in old)
+            kills = [e.args for e in tracing.full_events("runner.kill")]
+            assert sorted(k["rank"] for k in kills) == [0, 1, 2, 3]
+            for k in kills:
+                assert k["version"] == 1 and k["escalated"] is False
+                assert k["returncode"] == -15  # a sleeping worker: terminated
+            spawns = [e.args for e in tracing.full_events("runner.spawn")]
+            assert [(s["rank"], s["version"], s["slots"]) for s in spawns] == [
+                (r, 0, [r]) for r in range(4)] + [(0, 1, [0]), (1, 1, [1])]
+            assert len(w.current) == 2
+            for p in w.current.values():
+                marks = json.loads(p.env[kfenv.RESIZE_MARKS])
+                assert marks["phases_ms"] == {"consensus_ms": 3.0}
+                assert (marks["t_propose"] < marks["t_stage"] < marks["t_killed"]
+                        <= marks["t_spawn"] <= time.time())
+                assert float(p.env["KF_SPAWN_TS"]) == marks["t_spawn"]
+                world = json.loads(p.env[kfenv.DEVICE_WORLD])
+                assert world["TPU_PROCESS_BOUNDS"] == "2,1,1"
+                cfg = kfenv.parse_config_from_env(p.env)
+                assert cfg.resize_marks == marks and cfg.init_progress == 21
+            # a world of two tells of two chips: the four's table stays
+            w.apply_full(Stage(version=2, progress=41, cluster=stage(2, 4).cluster,
+                               reload=True, chip_coords={"0": [0, 0, 0], "1": [1, 0, 0]}))
+            assert len(w.chip_coords) == 4
+            for p in w.current.values():
+                world = json.loads(p.env[kfenv.DEVICE_WORLD])
+                assert world["TPU_PROCESS_BOUNDS"] == "2,2,1"
+        finally:
+            for p in w.current.values():
+                p.kill()
+            tracing.clear()
 
     def test_env_of_spawned_workers_is_pinned(self):
         w, stage = self._watcher(n_dev=4, cap=2)

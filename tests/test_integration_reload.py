@@ -6,10 +6,15 @@ Parity: test-elastic-reload.sh + test_elastic_reload.py:17-47; VERDICT r1
 items #1 (device plane survives resize) and #4 (reload e2e).
 """
 
+import json
 import os
 import re
 import subprocess
 import sys
+import threading
+import time
+
+import pytest
 
 from ports import kfrun_ports
 
@@ -17,11 +22,38 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 AGENT = os.path.join(REPO, "tests", "integration", "reload_agent.py")
 
 
+PAUSE_KEYS = (
+    "agree_ms", "wait_config_ms", "consensus_ms", "notify_ms", "kill_ms",
+    "spawn_ms", "import_ms", "startup_ms", "device_plane_ms", "restore_ms",
+    "broadcast_ms", "compile_ms", "compile_hits", "compile_misses",
+    "first_step_ms", "pause_ms", "unaccounted_ms",
+)
+PAUSE_PARTS = ("agree_ms", "kill_ms", "spawn_ms", "import_ms", "startup_ms",
+               "device_plane_ms", "restore_ms", "broadcast_ms", "compile_ms",
+               "first_step_ms")
+
+
+def _run_reading_the_clock(argv, env, timeout):
+    """Run to the end; every line of output with the wall time this test
+    read it at."""
+    proc = subprocess.Popen(
+        argv, env=env, cwd=REPO, text=True, bufsize=1,
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+    )
+    timer = threading.Timer(timeout, proc.kill)
+    timer.start()
+    try:
+        lines = [(time.time(), line) for line in proc.stdout]
+        return proc.wait(), lines
+    finally:
+        timer.cancel()
+
+
 def test_reload_mode_restarts_with_progress_and_fresh_mesh():
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
-    r = subprocess.run(
+    code, lines = _run_reading_the_clock(
         [
             sys.executable, "-m", "kungfu_tpu.runner.cli",
             *kfrun_ports().args,  # this xdist worker's block
@@ -32,12 +64,13 @@ def test_reload_mode_restarts_with_progress_and_fresh_mesh():
             "-builtin-config-port", "0",
             "--", sys.executable, AGENT,
         ],
-        env=env, capture_output=True, text=True, timeout=300, cwd=REPO,
+        env, timeout=300,
     )
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    out = "".join(line for _, line in lines)
+    assert code == 0, out
 
     # three incarnations: start at 0 (np=2), reload ~10 (np=3), reload ~20 (np=2)
-    starts = re.findall(r"incarnation rank=\d+/(\d+) start_progress=(\d+)", r.stdout)
+    starts = re.findall(r"incarnation rank=\d+/(\d+) start_progress=(\d+)", out)
     progresses = sorted({int(p) for _, p in starts})
     assert len(progresses) >= 3, f"expected >=3 incarnations: {starts}"
     assert progresses[0] == 0
@@ -47,5 +80,36 @@ def test_reload_mode_restarts_with_progress_and_fresh_mesh():
     mid = [p for p in progresses if 10 <= p < 20]
     assert mid and sizes_by_progress[mid[0]] == {3}, sizes_by_progress
     # final incarnation finishes with full progress on every worker
-    finished = re.findall(r"stopped reason=finished progress=30", r.stdout)
-    assert len(finished) == 2, r.stdout
+    finished = re.findall(r"stopped reason=finished progress=30", out)
+    assert len(finished) == 2, out
+
+    # each later incarnation knows where the pause that made it went
+    phases = [
+        (size, json.loads(doc)) for size, doc in
+        re.findall(r"resize_phases rank=\d+/(\d+) wall=\S+ (\{.*\})", out)
+    ]
+    assert [p for _, p in phases[:2]] == [{}, {}]  # the first knows of none
+    later = [(int(size), p) for size, p in phases if p]
+    assert [size for size, _ in later] == [3, 3, 3, 2, 2]
+    for size, p in later:
+        assert not [k for k in PAUSE_KEYS if k not in p], p
+        assert not [k for k in PAUSE_PARTS if p[k] is None], p
+        assert p["unaccounted_ms"] >= 0
+        assert sum(p[k] for k in PAUSE_PARTS) + p["unaccounted_ms"] == \
+            pytest.approx(p["pause_ms"], abs=0.01)
+        assert (p["mode"], p["new_size"]) == ("reload", size)
+        # the version rises by one a reload, and the sizes chain
+        assert (p["version"], p["old_size"]) == {3: (1, 2), 2: (2, 3)}[size]
+        assert p["compile_ms"] > 0  # the psum's program, the cache off here
+    # the pause is no shorter than the silence this test read between the
+    # old incarnation's last line and the new one's first
+    said = [(t, line) for t, line in lines
+            if "stopped reason=" in line or "incarnation rank=" in line]
+    silences = [
+        t1 - t0 for (t0, a), (t1, b) in zip(said, said[1:])
+        if "stopped reason=reload" in a and "incarnation rank=" in b
+    ]
+    assert len(silences) == 2, said
+    for silence, version in zip(silences, (1, 2)):
+        pauses = [p["pause_ms"] for _, p in later if p["version"] == version]
+        assert min(pauses) >= silence * 1e3, (silence, pauses)

@@ -423,7 +423,10 @@ class TestClusterEndpoints:
             assert "kungfu_cluster_straggler_score" in body
             with urllib.request.urlopen(base + "/cluster/trace", timeout=5) as r:
                 doc = json.loads(r.read().decode())
-            assert {e["pid"] for e in doc["traceEvents"]} == set(range(4))
+            # the four workers, and the runner's own ring as one more process
+            assert {e["pid"] for e in doc["traceEvents"]} == set(range(5))
+            assert {"name": "process_name", "ph": "M", "pid": 4, "tid": 0,
+                    "args": {"name": "runner"}} in doc["traceEvents"]
             # query strings must not demote a cluster view to the dump
             with urllib.request.urlopen(
                 base + "/cluster/health?t=123", timeout=5

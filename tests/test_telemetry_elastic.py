@@ -10,6 +10,7 @@
   Chrome-trace JSON (ph/ts/dur) on /trace.
 """
 
+import contextlib
 import json
 import threading
 import urllib.request
@@ -95,43 +96,66 @@ def telemetry_on(monkeypatch):
     tconfig.refresh()
 
 
-def test_schedule_driven_resize_emits_one_audit_record(telemetry_on, monkeypatch):
-    """The full elastic path — StepBasedSchedule proposes to the config
-    server, every peer adopts via consensus — leaves exactly one audit
-    record per surviving peer, with the old/new sizes and the
-    config_server trigger."""
-    import kungfu_tpu.elastic.schedule as sched_mod
+@contextlib.contextmanager
+def _resizable(n):
+    """n in-process peers behind a config server that holds their cluster,
+    and a stand-in runner (clusters must carry a runner per worker host to
+    validate, and rank 0 notifies it of the accepted stage): yields
+    (peers, notified messages)."""
     from kungfu_tpu.elastic.configserver import ConfigServer
-    from kungfu_tpu.elastic.schedule import StepBasedSchedule
     from kungfu_tpu.plan.cluster import Cluster
     from kungfu_tpu.transport.message import ConnType
     from kungfu_tpu.transport.server import Server
 
-    # a stand-in runner: clusters must carry a runner per worker host to
-    # validate, and rank 0 notifies it of the accepted stage
     (runner_port,) = _reserve_low_ports(1)
     runner_id = PeerID("127.0.0.1", runner_port)
     runner_srv = Server(runner_id, use_unix=False)
     notified = []
-    runner_srv.register(
-        ConnType.CONTROL, lambda src, msg: notified.append(msg.name)
-    )
+    runner_srv.register(ConnType.CONTROL, lambda src, msg: notified.append(msg))
     runner_srv.start()
     runners = PeerList([runner_id])
 
-    peers = _make_peers(3)
+    peers = _make_peers(n)
     srv = ConfigServer(
         0,
         initial=Cluster(runners=runners, workers=peers[0].config.peers),
         host="127.0.0.1",
     )
     srv.start()
-    url = f"http://127.0.0.1:{srv.port}"
     for p in peers:
-        p.config.config_server = url
+        p.config.config_server = f"http://127.0.0.1:{srv.port}"
         p.config.runners = runners
     audit.clear()
+    tracing.clear()
     try:
+        yield peers, notified
+    finally:
+        srv.stop()
+        runner_srv.stop()
+        for p in peers:
+            p.stop()
+        audit.clear()
+        tracing.clear()
+
+
+def _resize_spans(tid_of, version):
+    """{thread: {span name: event}} of the `resize.*` spans of one resize."""
+    out = {}
+    for e in tracing.full_events("resize."):
+        if (e.args or {}).get("version") == version and e.tid in tid_of:
+            out.setdefault(tid_of[e.tid], {})[e.name] = e
+    return out
+
+
+def test_schedule_driven_resize_emits_one_audit_record(telemetry_on, monkeypatch):
+    """The full elastic path — StepBasedSchedule proposes to the config
+    server, every peer adopts via consensus — leaves exactly one audit
+    record per surviving peer, with the old/new sizes and the
+    config_server trigger; each phase is the duration of its span."""
+    import kungfu_tpu.elastic.schedule as sched_mod
+    from kungfu_tpu.elastic.schedule import StepBasedSchedule
+
+    with _resizable(3) as (peers, notified):
         # drive the schedule from the acting rank 0 (the api module binds
         # to the process singleton, which in-process multi-peer tests
         # don't use — bind its accessors to peer 0 instead)
@@ -143,9 +167,10 @@ def test_schedule_driven_resize_emits_one_audit_record(telemetry_on, monkeypatch
         sched = StepBasedSchedule("2:100")
         assert sched.maybe_propose(0) == 2  # published to the config server
 
-        results = {}
+        results, tid_of = {}, {}
 
         def resize(i, p):
+            tid_of[threading.get_ident()] = i
             results[i] = p.resize_cluster_from_url()
 
         _par([lambda i=i, p=p: resize(i, p) for i, p in enumerate(peers)])
@@ -153,6 +178,7 @@ def test_schedule_driven_resize_emits_one_audit_record(telemetry_on, monkeypatch
         assert results[1] == (True, False)
         assert results[2] == (True, True)  # shrunk out
 
+        spans = _resize_spans(tid_of, version=1)
         for i, p in enumerate(peers):
             recs = audit.records(kind="resize", peer=str(p.self_id))
             assert len(recs) == 1, (i, [r.to_json() for r in recs])
@@ -163,16 +189,78 @@ def test_schedule_driven_resize_emits_one_audit_record(telemetry_on, monkeypatch
             assert rec.detached == (i == 2)
             assert rec.cluster_version == 1
             assert rec.phases_ms and "update_ms" in rec.phases_ms
-        assert "update" in notified  # rank 0 notified the runner
+            # one clock a phase: the span is the measurement
+            want = {"resize.wait_config", "resize.consensus", "resize.update"}
+            assert set(spans[i]) == want | ({"resize.notify"} if i == 0 else set())
+            for name, e in spans[i].items():
+                assert e.args == {"mode": "delta", "version": 1,
+                                  "old_size": 3, "new_size": 2}, (name, e.args)
+            assert p.last_resize_phases == rec.phases_ms == {
+                name.split(".")[1] + "_ms": round(e.duration * 1e3, 1)
+                for name, e in spans[i].items()
+            }
+        assert [m.name for m in notified] == ["update"]  # rank 0 told the runner
         # a second no-change poll must NOT add records
         _par([lambda p=p: p.resize_cluster_from_url() for p in peers[:2]])
         assert len(audit.records(kind="resize")) == 3
-    finally:
-        srv.stop()
-        runner_srv.stop()
-        for p in peers:
-            p.stop()
-        audit.clear()
+
+
+def test_reload_agreement_runs_under_the_delta_paths_span_names(telemetry_on):
+    """`change_cluster`: the wait, the agreement, the hook and the notify
+    each under a span that says which resize it belongs to; the phases are
+    the spans' durations, the audit record has them, and the Stage carries
+    the proposer's marks to the runner."""
+    import time
+
+    with _resizable(3) as (peers, notified):
+        # nothing to change yet: one wait, no agreement, nothing recorded
+        assert peers[0].propose_new_size(3) is None
+        _par([lambda p=p: p.change_cluster(7) for p in peers])
+        assert {e.name for e in tracing.full_events("resize.")} == {
+            "resize.wait_config"}
+        assert not audit.records(kind="resize") and not notified
+        tracing.clear()
+
+        peers[0].propose_new_size(2)
+        results, tid_of, hooked = {}, {}, []
+        t_before = time.time()
+
+        def reload(i, p):
+            tid_of[threading.get_ident()] = i
+            results[i] = p.change_cluster(
+                8, before_notify=lambda: hooked.append((i, len(notified))))
+
+        _par([lambda i=i, p=p: reload(i, p) for i, p in enumerate(peers)])
+        assert results == {i: (True, True) for i in range(3)}
+        # the hook ran on every worker, and before the runner heard
+        assert sorted(hooked) == [(0, 0), (1, 0), (2, 0)]
+
+        spans = _resize_spans(tid_of, version=1)
+        for i, p in enumerate(peers):
+            want = ["resize.wait_config", "resize.consensus", "resize.on_reload"]
+            assert sorted(spans[i]) == sorted(want + ["resize.notify"] * (i == 0))
+            for name, e in spans[i].items():
+                assert e.args == {"mode": "reload", "version": 1,
+                                  "old_size": 3, "new_size": 2}, (name, e.args)
+            phases = {name.split(".")[1] + "_ms": round(e.duration * 1e3, 1)
+                      for name, e in spans[i].items()}
+            assert p.last_resize_phases == phases
+            (rec,) = audit.records(kind="resize", peer=str(p.self_id))
+            assert rec.trigger == "reload" and rec.detached
+            assert rec.phases_ms == phases
+            assert (rec.old_size, rec.new_size, rec.progress) == (3, 2, 8)
+
+        (msg,) = notified
+        stage = json.loads(msg.data.decode())
+        assert stage["Reload"] and stage["Version"] == 1 and stage["Progress"] == 8
+        marks = stage["Marks"]
+        assert t_before <= marks["t_propose"] <= time.time()
+        assert (marks["mode"], marks["old_size"]) == ("reload", 3)
+        # what rank 0 knew when it sent the Stage: all but the notify
+        assert marks["phases_ms"] == {
+            k: v for k, v in peers[0].last_resize_phases.items()
+            if k != "notify_ms"}
+        assert "ChipCoords" not in stage  # no device plane told of any
 
 
 def test_spans_nest_across_collective_step(telemetry_on):
