@@ -1108,21 +1108,6 @@ def _gated_delta_mixer(h, layer, cfg: TransformerConfig):
     return checkpoint_name(out.astype(h.dtype), "gdn_mix")
 
 
-def _grouped_gated_norm(y, z, scale, groups: int, eps):
-    """rms(y * silu(z)) * scale, the gate first and the mean square over each
-    of `groups` equal groups of the last axis, in float32, the result in z's
-    type (Mamba-2's `RMSNormGated` with `norm_before_gate` false). Under its
-    checkpoint (`_grouped_gated_norm_kept`) it keeps y, z and the scale."""
-    f32 = jnp.float32
-    t = y.astype(f32) * jax.nn.silu(z.astype(f32))
-    t = t.reshape(t.shape[:-1] + (groups, -1))
-    t = t * jax.lax.rsqrt(jnp.mean(jnp.square(t), axis=-1, keepdims=True) + eps)
-    return (t.reshape(y.shape) * scale.astype(f32)).astype(z.dtype)
-
-
-_grouped_gated_norm_kept = _recompute(_grouped_gated_norm, static_argnums=(3, 4))
-
-
 def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=()):
     """The Mamba-2 mixer on normed hidden states h (B, S, D): H heads of P
     features, a state of N a feature, G groups of H / G heads that share B
@@ -1132,11 +1117,15 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=()):
     A, A = -exp(A_log), a number a head and position, float32 from a float32
     projection as the router's is; the state-space recurrence (`ops.ssm_scan`)
     with q = C, k = B (a group's, never repeated a head) and v = Delta x;
-    + D x; the gate silu(z) and then an RMSNorm over each group's features;
-    W_out. `segments`, (the documents' numbers (B, S),) of packed rows, go
-    to the convolution and to the scan, and nothing else of the mixer looks
-    beyond its own position. Scopes `ssm_proj`, `ssm_conv`, `ssm_core`,
-    `ssm_norm`."""
+    + D x, the gate silu(z) and then an RMSNorm over each group's features,
+    one kernel each way (`ops.gated_norm`: it reads the scan's output as the
+    scan lays it out, x and z as the first H P columns of the convolution's
+    and the projection's outputs, writes y once, and keeps those inputs
+    alone, so it is the same kept or run again); W_out. `segments`, (the
+    documents' numbers (B, S),) of packed rows, go to the convolution and to
+    the scan, and nothing else of the mixer looks beyond its own position.
+    Scopes `ssm_proj`, `ssm_conv`, `ssm_core`, `ssm_norm`."""
+    from kungfu_tpu.ops import gated_norm
     from kungfu_tpu.ops.gated_delta import causal_conv
     from kungfu_tpu.ops.ssm_scan import CHUNK, ssm_scan
 
@@ -1146,13 +1135,12 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=()):
     B, S, _ = h.shape
     w_in = layer["w_ssm_in"]
     with jax.named_scope("ssm_proj"):
-        zxbc = h @ w_in[:, :2 * inner + 2 * bc].astype(dt)
-        z, xbc = zxbc[..., :inner], zxbc[..., inner:]
+        zxbc = h @ w_in[:, :2 * inner + 2 * bc].astype(dt)  # z its first columns
         step = jnp.dot(h.astype(f32), w_in[:, 2 * inner + 2 * bc:].astype(f32),
                        precision=jax.lax.Precision.HIGHEST)  # (B, S, H)
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(causal_conv(xbc, layer["conv_w"], layer["conv_b"],
-                                      *segments))
+        xbc = jax.nn.silu(causal_conv(zxbc[..., inner:], layer["conv_w"],
+                                      layer["conv_b"], *segments))
         x = xbc[..., :inner].reshape(B, S, H, hp)
         b, c = (xbc[..., at:at + bc].reshape(B, S, G, N).transpose(0, 2, 1, 3)
                 for at in (inner, inner + bc))
@@ -1164,11 +1152,8 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=()):
         # divides a shorter sequence: the result does not depend on it
         o = ssm_scan(c, b, v, g, math.gcd(S, CHUNK), *segments)  # (B, H, S, hp)
     with jax.named_scope("ssm_norm"):
-        y = (o.transpose(0, 2, 1, 3).astype(f32)
-             + layer["D_skip"].astype(f32)[:, None] * x.astype(f32))
-        norm = _grouped_gated_norm if cfg.layer_remat else _grouped_gated_norm_kept
-        y = norm(y.astype(dt).reshape(B, S, inner), z,
-                 layer["ssm_norm_scale"], G, cfg.norm_eps)
+        y = gated_norm.gated_norm(o, xbc, zxbc, layer["D_skip"],
+                                  layer["ssm_norm_scale"], G, cfg.norm_eps)
     with jax.named_scope("ssm_proj"):
         return y @ layer["wo"].astype(dt)
 

@@ -23,7 +23,7 @@ from benchmark.families import (glm4_moe_lite, granite_hybrid, laguna,
                                 nemotron_h, ouro, qwen3_next)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import param_pspecs
-from kungfu_tpu.ops import moe
+from kungfu_tpu.ops import gated_norm, moe
 from kungfu_tpu.telemetry import metrics
 
 
@@ -598,6 +598,33 @@ def bias_in_the_weight(m):
         return logits, biased, top, idx
 
     m.setattr(moe, "route", route)
+
+
+def gate_after_the_norm(m):
+    """The fault of a Mamba-2 mixer whose gated norm takes the mean square
+    before the gate: rms_G(o + d x) * scale * silu(z) in the place of
+    `ops.gated_norm.gated_norm`."""
+    m.setattr(gated_norm, "gated_norm", _norm_then_gate)
+
+
+def _norm_then_gate(o, x, z, d, scale, groups, eps):
+    B, H, S, P = o.shape
+    x, z = (t[..., :H * P].astype(jnp.float32) for t in (x, z))
+    y = (o.transpose(0, 2, 1, 3).astype(jnp.float32)
+         + d[:, None] * x.reshape(B, S, H, P)).reshape(B, S, groups, -1)
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + eps)
+    return y.reshape(z.shape) * scale * jax.nn.silu(z)
+
+
+def norm_over(groups: int):
+    """The fault of a Mamba-2 mixer whose gated norm takes the mean square
+    over `groups` groups of features whatever the configuration says."""
+    def fault(m):
+        norm = gated_norm.gated_norm
+        m.setattr(gated_norm, "gated_norm", lambda o, x, z, d, scale, _, eps:
+                  norm(o, x, z, d, scale, groups, eps))
+
+    return fault
 
 
 def _eight_bit(state):

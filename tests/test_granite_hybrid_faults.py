@@ -6,7 +6,6 @@ program is allowed. A file of its own so that the suite's workers share the
 compiles."""
 
 import jax
-import jax.numpy as jnp
 import pytest
 
 import family_cases as fc
@@ -30,25 +29,6 @@ def _one_document(m, module, name, arguments):
     m.setattr(module, name, lambda *args: op(*args[:arguments]))
 
 
-def _gated_norm(m, norm):
-    m.setattr(transformer, "_grouped_gated_norm", norm)
-    m.setattr(transformer, "_grouped_gated_norm_kept", norm)
-
-
-def _norm_before_the_gate(m):
-    def norm_then_gate(y, z, scale, groups, eps):
-        y = y.astype(jnp.float32).reshape(y.shape[:-1] + (groups, -1))
-        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + eps)
-        return y.reshape(z.shape) * scale * jax.nn.silu(z)
-
-    _gated_norm(m, norm_then_gate)
-
-
-def _norm_over_eight_groups(m):
-    norm = transformer._grouped_gated_norm
-    _gated_norm(m, lambda y, z, scale, groups, eps: norm(y, z, scale, 8, eps))
-
-
 def _an_untied_head(m):
     """The head's matrix a leaf of its own that happens to hold the
     embedding's values: the embedding is given no gradient through it."""
@@ -69,8 +49,8 @@ FAULTS = {
     "residual_multiplier_1": _as(residual_multiplier=1.0),
     "logits_scaling_1": _as(logits_scaling=1.0),
     "an_untied_head": _an_untied_head,
-    "norm_before_the_gate": _norm_before_the_gate,
-    "norm_over_eight_groups": _norm_over_eight_groups,
+    "norm_before_the_gate": fc.gate_after_the_norm,
+    "norm_over_eight_groups": fc.norm_over(8),
     "a_rotary_pass": _as(positions="rope"),
 }
 

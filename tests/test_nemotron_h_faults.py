@@ -16,27 +16,6 @@ from kungfu_tpu.ops import moe, ssm_scan
 _as = lambda **changes: fc.model_changed(fc.NEMOTRON_H.module, **changes)
 
 
-def _gated_norm(m, norm):
-    """`norm(y, z, scale, groups, eps)` in the place of the mixer's gated
-    norm, kept or run again."""
-    m.setattr(transformer, "_grouped_gated_norm", norm)
-    m.setattr(transformer, "_grouped_gated_norm_kept", norm)
-
-
-def _gate_after_the_norm(m):
-    def norm_then_gate(y, z, scale, groups, eps):
-        y = y.astype(jnp.float32).reshape(y.shape[:-1] + (groups, -1))
-        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + eps)
-        return y.reshape(z.shape) * scale * jax.nn.silu(z)
-
-    _gated_norm(m, norm_then_gate)
-
-
-def _norm_over_all_features(m):
-    norm = transformer._grouped_gated_norm
-    _gated_norm(m, lambda y, z, scale, groups, eps: norm(y, z, scale, 1, eps))
-
-
 def _b_and_c_of_the_wrong_group(m):
     scan = ssm_scan.ssm_scan
     m.setattr(ssm_scan, "ssm_scan", lambda q, k, v, g, chunk: scan(
@@ -56,8 +35,8 @@ def _expert_function(m, act):
 
 
 FAULTS = {
-    "gate_after_the_norm": _gate_after_the_norm,
-    "norm_over_all_features_and_not_a_group": _norm_over_all_features,
+    "gate_after_the_norm": fc.gate_after_the_norm,
+    "norm_over_all_features_and_not_a_group": fc.norm_over(1),
     "b_and_c_of_the_wrong_group": _b_and_c_of_the_wrong_group,
     "delta_without_the_softplus": _no_softplus,
     "a_rotary_pass": _as(positions="rope"),
