@@ -88,6 +88,15 @@ TPU-first design notes:
   its own document (`ops.flash_attention`). The loss is over every position.
   `packing_stats` says what a batch is made of. Without the id every program
   is what it was.
+- The gated short convolution is the fifth mixer (PR 57, LFM2's operator):
+  `mixer` "short_conv" is a layer whose mixer is [B | C | x] = h W_in (three
+  equal thirds of 3 D columns), a causal depthwise convolution of `conv_taps`
+  taps over B * x, the gate C on its output and W_out (`_short_conv_mixer`,
+  `ops.short_conv`: the two gates and the taps in one kernel each way): no
+  recurrence, no softmax, no activation, no bias, no norm of its own and no
+  state in training. Its leaves are `conv_in`, `conv_w` and `conv_out`; packed
+  rows' segments go to it as to every mixer. Layers of it stand in one stack
+  of `layer_kinds` beside attention, dense and expert layers (`lfm2_24b_a2b`).
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -164,8 +173,9 @@ class TransformerConfig:
     # depthwise convolution of `conv_taps` taps; "mamba2", the Mamba-2
     # state-space mixer (`ops.ssm_scan`) over `ssm_dims` = (heads, head size,
     # state size, groups of heads that share B and C), its convolution with
-    # a bias; or "none": the layer is its feed-forward alone, behind its one
-    # norm
+    # a bias; "short_conv", LFM2's gated short convolution (`ops.short_conv`)
+    # of `conv_taps` taps between two gates; or "none": the layer is its
+    # feed-forward alone, behind its one norm
     mixer: str = "attention"
     delta_heads: Tuple = ()
     conv_taps: int = 4
@@ -229,7 +239,7 @@ class TransformerConfig:
                 ("attn_core", self.attn_core, ("dense", "flash")),
                 ("gates", self.gates, ("raw", "renorm")),
                 ("mixer", self.mixer, ("attention", "gated_delta", "latent",
-                                       "mamba2", "none")),
+                                       "mamba2", "short_conv", "none")),
                 ("router_scores", self.router_scores, ("softmax", "sigmoid"))):
             if value not in known:
                 raise ValueError(f"{field} {value!r} is not one of {known}")
@@ -275,6 +285,15 @@ class TransformerConfig:
                 raise ValueError(
                     f"the flash core has one head size: q/k heads of {nope} + "
                     f"{rope} and value heads of {value} need the dense core")
+        if self.mixer == "short_conv":
+            if self.conv_taps != 3:
+                raise ValueError("mixer 'short_conv' convolves over LFM2's 3 "
+                                 f"taps, got conv_taps {self.conv_taps}")
+            if self.loop_steps > 1 or self.mtp_depth:
+                raise ValueError("mixer 'short_conv' is built for the plain "
+                                 "stack: not under a loop (loop_steps > 1) nor "
+                                 "in a model with a multi-token-prediction "
+                                 "module, which no test holds it to")
         if self.mtp_depth not in (0, 1):
             raise ValueError(f"mtp_depth {self.mtp_depth}: one multi-token-"
                              "prediction module or none")
@@ -301,7 +320,8 @@ class TransformerConfig:
                 or (self.mixer == "attention" and self.attn_core != "flash")):
             raise ValueError(
                 "packed documents (end_of_document) are kept apart by the "
-                "Mamba-2 mixer and by the flash core of softmax attention: "
+                "Mamba-2 mixer, the short convolution and the flash core of "
+                "softmax attention: "
                 f"not by mixer {self.mixer!r} on the {self.attn_core} core, "
                 "nor by a multi-token-prediction module")
         if self.layer_kinds and len(self.layer_kinds) != self.n_layers:
@@ -371,6 +391,29 @@ class TransformerConfig:
             qk_norm=True, norm_eps=1e-5, ffn="moe", n_experts=64, top_k=8,
             router_aux_coef=0.01, router_z_coef=0.001, tied_head=False,
             attn_core="flash"), **changes)
+
+    @classmethod
+    def lfm2_24b_a2b(cls, n_layers: int = 40, **changes) -> "TransformerConfig":
+        """LiquidAI/LFM2-24B-A2B's config.json (`model_type` lfm2_moe): a
+        gated short convolution of 3 taps as the mixer of three layers in four
+        (`conv, conv, full_attention, conv` ten times), attention of 32 query
+        heads on 8 key/value heads of 64 with a q/k norm a head and rotary
+        positions at 1e6 in the fourth, two leading dense layers of width
+        11,776 and then 64 sigmoid-scored experts of width 1,536, 4 a token
+        under a selection bias, the chosen scores renormalised; a tied head
+        (the family's convention). `n_layers`: the model's first so many."""
+        mixers = [(("mixer", "short_conv"),), (("mixer", "short_conv"),),
+                  (("mixer", "attention"),), (("mixer", "short_conv"),)] * 10
+        kinds = tuple(mixer + ((("ffn", "swiglu"), ("d_ff", 11776)) if l < 2
+                               else (("ffn", "moe"), ("d_ff", 1536)))
+                      for l, mixer in enumerate(mixers[:n_layers]))
+        return dataclasses.replace(cls(
+            vocab_size=65536, d_model=2048, n_heads=32, n_layers=n_layers,
+            d_ff=1536, max_seq=128000, positions="rope", rope_theta=1e6,
+            qk_norm=True, norm_eps=1e-5, ffn="moe", n_experts=64, top_k=4,
+            tied_head=True, attn_core="flash", head_size=64, n_kv_heads=8,
+            conv_taps=3, router_scores="sigmoid", router_bias=True,
+            gates="renorm", routed_scale=1.0, layer_kinds=kinds), **changes)
 
     @classmethod
     def tiny_moe(cls, **changes) -> "TransformerConfig":
@@ -444,6 +487,11 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
                 D_skip=jnp.ones((H,), jnp.float32),
                 ssm_norm_scale=jnp.ones((H * hp,), jnp.float32),
                 wo=dense(lk[1], (H * hp, D)))
+        elif cfg.mixer == "short_conv":
+            ck = jax.random.split(jax.random.fold_in(key, 5), 3)  # a sixth
+            layer.update(conv_in=dense(ck[0], (D, 3 * D)),
+                         conv_w=dense(ck[1], (cfg.conv_taps, D)),
+                         conv_out=dense(ck[2], (D, D)))
         elif cfg.mixer == "gated_delta":
             Hk, Hv, d = cfg.delta_heads
             K = cfg.conv_taps
@@ -568,7 +616,8 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     feed-forward (two matrices each under `expert_act` "relu2"); a layer of
     one branch has that branch's leaves alone; a Mamba-2 mixer's fused
     projection, taps, bias and gated norm are column-parallel, its numbers a
-    head whole; embedding and an untied head sharded over vocab; an
+    head whole; a short-convolution mixer's `conv_in` and taps column-parallel
+    and its `conv_out` row-parallel; embedding and an untied head sharded over vocab; an
     expert stack over `ep_axis` on its expert dimension, the router whole.
     Layer-stacked leaves have a leading layer axis (unsharded); a
     configuration with `layer_kinds` has a tuple of such stacks. The q/k
@@ -585,7 +634,9 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     def stack_specs(cfg):
         layers = {}
         if cfg.mixer != "none":
-            layers.update(ln1_scale=P(None), wo=P(None, t, None))
+            layers.update(ln1_scale=P(None))
+            if cfg.mixer != "short_conv":
+                layers.update(wo=P(None, t, None))
         if cfg.ffn != "none":
             layers.update(ln2_scale=P(None))
         if cfg.post_norms:
@@ -601,6 +652,12 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
                           conv_b=P(None, t), A_log=P(None, None),
                           dt_bias=P(None, None), D_skip=P(None, None),
                           ssm_norm_scale=P(None, t))
+        elif cfg.mixer == "short_conv":
+            # the projection's columns over tp like any column-parallel
+            # matrix's (a shard holds a slice of B, C and x each, the
+            # partitioner's affair), the taps with the channels, W_out's rows
+            layers.update(conv_in=P(None, None, t), conv_w=P(None, None, t),
+                          conv_out=P(None, t, None))
         elif cfg.mixer == "gated_delta":
             # the fused projection's and the convolution's channels over tp
             # like any column-parallel matrix; a number a head and the
@@ -1158,6 +1215,28 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=()):
         return y @ layer["wo"].astype(dt)
 
 
+def _short_conv_mixer(h, layer, cfg: TransformerConfig, segments=()):
+    """LFM2's gated short convolution on normed hidden states h (B, S, D):
+    [B | C | x] = h W_in, three equal thirds in that order; y = (C * conv(B *
+    x)) W_out with conv a causal depthwise convolution of `conv_taps` taps a
+    channel, c_t = sum_i k_i z_{t-(K-1)+i}, zeros before the row's first
+    position. No activation, no bias, no norm and no state. The gates and
+    the taps are one op (`ops.short_conv`) that reads the projection's
+    output once and keeps it, the taps and the segments alone, so the mixer
+    is the same kept or run again. `segments`, (the documents' numbers (B,
+    S),) of packed rows, go to the op: a tap that would reach into another
+    document reads zero. Scopes `sconv_proj`, `sconv_core`."""
+    from kungfu_tpu.ops.short_conv import short_conv
+
+    dt = cfg.dtype
+    with jax.named_scope("sconv_proj"):
+        bcx = h @ layer["conv_in"].astype(dt)
+    with jax.named_scope("sconv_core"):
+        y = short_conv(bcx, layer["conv_w"], *segments)
+    with jax.named_scope("sconv_proj"):
+        return y @ layer["conv_out"].astype(dt)
+
+
 def _scale(w, cfg: TransformerConfig):
     """A norm's scale from its weight: the weight, or 1 + it."""
     return 1.0 + w if cfg.norm_offset else w
@@ -1233,6 +1312,11 @@ def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
             x = _taken(x, _mamba2_mixer(h, layer, cfg, segments), layer,
                        "ln1_post_scale", cfg)
+    elif cfg.mixer == "short_conv":
+        with jax.named_scope("sconv"):
+            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
+            x = _taken(x, _short_conv_mixer(h, layer, cfg, segments), layer,
+                       "ln1_post_scale", cfg)
     elif cfg.mixer == "gated_delta":
         with jax.named_scope("gdn"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
@@ -1287,6 +1371,9 @@ def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
 # feed-forward are run again in the backward pass, the forward kernel and
 # the DeltaNet mixer's head blocks are not (the blocks run their forward
 # once more for their own gradients, `_delta_heads`: twice a step in all).
+# Of a short-convolution mixer nothing is kept: its op's forward kernel is one
+# pass over the projection's output (0.2 ms a layer of 8,192 positions of
+# 2,048 channels) and runs again with the projection that feeds it.
 # Under a loop every application of a layer keeps its own: the Ouro cell's 32
 # applications (8 layers x 4 loop steps) of 16 heads of 128 at 4,096 positions
 # keep 2 x 16.8 MB each and the row sums, 1.08 GB a step beside the 0.82 GB of
@@ -1301,7 +1388,14 @@ _layer_again = jax.checkpoint(
 def _block(x, layer, cfg: TransformerConfig, core=None):
     """One layer's hidden states alone, for the paths that have no place
     for an expert layer's auxiliary losses (pipeline, ring, a plugged
-    core)."""
+    core). A short-convolution mixer is not built there: a sequence shard
+    would want the last taps' rows of the shard before it, and a pipeline
+    stage runs one kind of layer."""
+    if cfg.mixer == "short_conv":
+        raise NotImplementedError(
+            "mixer 'short_conv' runs on the normal path (`transformer_loss`): "
+            "the ring path would have to hand a shard the rows before it, "
+            "and neither it nor the pipeline path is built for the mixer")
     return _layer(x, layer, cfg, core=core)[0]
 
 

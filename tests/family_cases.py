@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec
 
 from benchmark import harness, manifest as mf
 from benchmark.families import (glm4_moe_lite, granite_hybrid, laguna,
-                                nemotron_h, ouro, qwen3_next)
+                                lfm2_moe, nemotron_h, ouro, qwen3_next)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import param_pspecs
 from kungfu_tpu.ops import gated_norm, moe
@@ -359,8 +359,35 @@ GRANITE_HYBRID = Family(
     recomputed=((), (granite_hybrid.MAMBA, granite_hybrid.ATTENTION)))
 GRANITE_CHUNK = 32  # the scan's chunk in the family's tests (`ssm_scan.CHUNK`)
 
+# every kind of the cell's layers once, `c a c` with the first feed-forward
+# dense: 128 channels (a lane tile: the convolution's kernels run,
+# interpreted), 3 taps; 4 query heads on 2 key/value heads of 32 behind a q/k
+# norm a head and a rotary pass; 16 experts of which numbers 4 to 11 are held,
+# 4 a token; 64 positions; the routers trained, so that every leaf but the
+# bias has a gradient to compare
+LFM2_MOE = Family(
+    name="lfm2_moe", cell="lfm2_24b_a2b.ssgd_conv_8k_1chip", module=lfm2_moe,
+    tiny=dict(hidden_size=128, intermediate_size=192, moe_intermediate_size=32,
+              num_hidden_layers=3, num_dense_layers=1,
+              layer_types=["conv", "full_attention", "conv"],
+              num_attention_heads=4, num_key_value_heads=2,
+              num_experts=8, first_expert_held=4, published={"num_experts": 16},
+              vocab_size=320, sequence_length=64, flash_blocks=[32, 32],
+              flash_interpret=True, compute_dtype="float32",
+              routers_trained=True),
+    scales={"conv_in": 5.0, "conv_w": 20.0, "conv_out": 3.0, "wv": 14.0, "wo": 8.0,
+            "router": 20.0, "router_bias": 120.0, "w_gate": 8.0, "w_up": 8.0,
+            "w_down": 8.0},
+    norms=("ln1_scale", "ln2_scale", "q_norm_scale", "k_norm_scale"),
+    expert_layers=(1, 2), held_share=(0.3, 0.7),
+    scopes=("sconv/sconv_proj/", "sconv/sconv_core/", "attn/attn_full/attn_core",
+            "qk_norm/", "rope/", "ffn", "moe/moe_router", "moe/moe_dispatch",
+            "moe_experts/", "moe_combine/", "embed", "head_loss"),
+    constants=("router_bias",),
+    recomputed=((), (lfm2_moe.DENSE, lfm2_moe.SPARSE)))
+
 FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H, OURO,
-            GRANITE_HYBRID)
+            GRANITE_HYBRID, LFM2_MOE)
 
 
 def pytest_generate_tests(metafunc):
