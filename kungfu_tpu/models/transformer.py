@@ -83,7 +83,9 @@ TPU-first design notes:
   position's document (scope `segments`), and the numbers go to every mixer
   of the stack, through the layer scans and `_layer_again` alike: a
   convolution tap does not reach into an earlier document
-  (`ops.gated_delta.causal_conv`), the scan's state is zero before a
+  (`ops.gated_delta.causal_conv`; `ops.ssm_conv`, whose kernels read each
+  position's depth into its document, made beside the numbers once a step,
+  `ops.ssm_conv.document_marks`), the scan's state is zero before a
   document's first position (`ops.ssm_scan`), and a query sees the keys of
   its own document (`ops.flash_attention`). The loss is over every position.
   `packing_stats` says what a batch is made of. Without the id every program
@@ -1165,25 +1167,30 @@ def _gated_delta_mixer(h, layer, cfg: TransformerConfig):
     return checkpoint_name(out.astype(h.dtype), "gdn_mix")
 
 
-def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=()):
+def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=(), marks=()):
     """The Mamba-2 mixer on normed hidden states h (B, S, D): H heads of P
     features, a state of N a feature, G groups of H / G heads that share B
     and C (`ssm_dims`). [z | x B C | dt] = h W_in (H P + (H P + 2 G N) + H
-    columns); [x | B | C] through the causal convolution with its bias and a
-    silu; the step Delta = softplus(dt + dt_bias) and the log decay g = Delta
-    A, A = -exp(A_log), a number a head and position, float32 from a float32
-    projection as the router's is; the state-space recurrence (`ops.ssm_scan`)
-    with q = C, k = B (a group's, never repeated a head) and v = Delta x;
-    + D x, the gate silu(z) and then an RMSNorm over each group's features,
-    one kernel each way (`ops.gated_norm`: it reads the scan's output as the
-    scan lays it out, x and z as the first H P columns of the convolution's
-    and the projection's outputs, writes y once, and keeps those inputs
-    alone, so it is the same kept or run again); W_out. `segments`, (the
-    documents' numbers (B, S),) of packed rows, go to the convolution and to
-    the scan, and nothing else of the mixer looks beyond its own position.
-    Scopes `ssm_proj`, `ssm_conv`, `ssm_core`, `ssm_norm`."""
+    columns); the step Delta = softplus(dt + dt_bias) and the log decay g =
+    Delta A, A = -exp(A_log), a number a head and position, float32 from a
+    float32 projection as the router's is; [x | B | C] through the causal
+    convolution with its bias and a silu, and v = Delta x, one kernel each
+    way (`ops.ssm_conv`: it reads the projection's columns from x on where
+    the matmul left them, writes [x | B | C] once and v once in the layout
+    the scan reads, float32 between, and keeps its inputs alone); the
+    state-space recurrence (`ops.ssm_scan`) with q = C, k = B (a group's,
+    never repeated a head) and that v; + D x, the gate silu(z) and then an
+    RMSNorm over each group's features, one kernel each way
+    (`ops.gated_norm`: it reads the scan's output as the scan lays it out, x
+    and z as the first H P columns of the convolution's and the projection's
+    outputs, writes y once, and keeps those inputs alone); W_out. Both ops
+    are the same kept or run again. `segments`, (the documents' numbers (B,
+    S),) of packed rows, go to the convolution and to the scan, `marks`
+    (their `ssm_conv.document_marks`,) to the convolution's kernels, and
+    nothing else of the mixer looks beyond its own position. Scopes
+    `ssm_proj`, `ssm_conv`, `ssm_core`, `ssm_norm`."""
     from kungfu_tpu.ops import gated_norm
-    from kungfu_tpu.ops.gated_delta import causal_conv
+    from kungfu_tpu.ops.ssm_conv import ssm_conv
     from kungfu_tpu.ops.ssm_scan import CHUNK, ssm_scan
 
     H, hp, N, G = cfg.ssm_dims
@@ -1196,14 +1203,12 @@ def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=()):
         step = jnp.dot(h.astype(f32), w_in[:, 2 * inner + 2 * bc:].astype(f32),
                        precision=jax.lax.Precision.HIGHEST)  # (B, S, H)
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(causal_conv(zxbc[..., inner:], layer["conv_w"],
-                                      layer["conv_b"], *segments))
-        x = xbc[..., :inner].reshape(B, S, H, hp)
-        b, c = (xbc[..., at:at + bc].reshape(B, S, G, N).transpose(0, 2, 1, 3)
-                for at in (inner, inner + bc))
         delta = jax.nn.softplus(step + layer["dt_bias"].astype(f32))
         g = (delta * -jnp.exp(layer["A_log"].astype(f32))).transpose(0, 2, 1)
-        v = (x.astype(f32) * delta[..., None]).astype(dt).transpose(0, 2, 1, 3)
+        xbc, v = ssm_conv(zxbc, layer["conv_w"], layer["conv_b"], delta,
+                          *segments, *marks)
+        b, c = (xbc[..., at:at + bc].reshape(B, S, G, N).transpose(0, 2, 1, 3)
+                for at in (inner, inner + bc))
     with jax.named_scope("ssm_core"):
         # the published chunk, or the largest power of two under it that
         # divides a shorter sequence: the result does not depend on it
@@ -1303,14 +1308,16 @@ def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
     it, and `residual_multiplier` scales what it takes. aux is the expert
     layer's `ops.moe.MoeAux` (router losses and token-choices per expert),
     None of any other. `segments`: (the documents' numbers (B, S),) of
-    packed rows, for the mixer; () where a row is one document."""
+    packed rows, for the mixer, with a Mamba-2 convolution's marks behind
+    them where `_hidden` made them; () where a row is one document."""
     dt, eps = cfg.dtype, cfg.norm_eps
+    segments, marks = segments[:1], segments[1:]
     if cfg.mixer == "none":
         pass
     elif cfg.mixer == "mamba2":
         with jax.named_scope("ssm"):
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = _taken(x, _mamba2_mixer(h, layer, cfg, segments), layer,
+            x = _taken(x, _mamba2_mixer(h, layer, cfg, segments, marks), layer,
                        "ln1_post_scale", cfg)
     elif cfg.mixer == "short_conv":
         with jax.named_scope("sconv"):
@@ -1532,6 +1539,11 @@ def _hidden(params, tokens, cfg: TransformerConfig, each=None):
     x = _embed(params, tokens, cfg)
     # the documents of packed rows: constants of every layer scan
     packed = _segments(tokens, cfg)
+    if packed and any(kind.mixer == "mamba2" for kind, _ in cfg.stacks):
+        from kungfu_tpu.ops.ssm_conv import document_marks
+
+        with jax.named_scope("segments"):  # once a step, for every such layer
+            packed += (document_marks(*packed),)
     packed = (None, packed) if packed else ()  # no core plugged, then they
     stacks = params["layers"] if cfg.layer_kinds else (params["layers"],)
     looped = cfg.loop_steps > 1

@@ -48,9 +48,16 @@ autodiff, which is also what the tests compare the kernels with. Packed rows
 take it because no configuration packs rows for this mixer yet, and kernels
 that were given each position's neighbours of its own document as one whole
 number a position (a (rows, 1) int32 block) ran at twice the unpacked
-kernels' time on the chip, 9 % under `plain` (PERF.md section 6, PR 57). The
-builders are jitted so that a model's stacks of one shape, and a
-layer run again, share one trace and one lowering of each kernel.
+kernels' time on the chip, 9 % under `plain` (PERF.md section 6, PR 57).
+Laid along the lanes they cost nothing the trace shows: `ops.ssm_conv`'s
+kernels, which are built from this file's pieces (`_moved`, `_shifted`,
+`_split`, `_taps_times`, `_block_rows`, `_halo_maps`, for any K up to a
+register tile's rows), read each position's depth into its document as a
+(rows, 128) int32 block and run the Granite 4.0-H cell's packed rows at the
+time of rows of one document (PERF.md section 6, PR 58); a packed cell on
+this mixer brings `ssm_conv.document_marks` and `_seen` here. The builders
+are jitted so that a model's stacks of one shape, and a layer run again,
+share one trace and one lowering of each kernel.
 
 Tensor parallelism: as `ops.gated_norm`'s. The op is handed no mesh; the
 channels over `tp` (`models/transformer.param_pspecs`) are the partitioner's,
@@ -234,24 +241,28 @@ def _backward_kernel(bcx_ref, before_ref, behind_ref, dy_ref, dy_behind_ref,
         lax.fori_loop(0, n, up, future)
 
 
+def _halo_maps(S: int, rows: int):
+    """Where along S, in halos, a grid step s of `rows` rows finds the halo
+    that ends where its block begins and the one that begins where it ends;
+    at the row's two ends any block, read as zeros."""
+    per, halos = rows // HALO, S // HALO
+    return (lambda s: jnp.maximum(s * per - 1, 0),
+            lambda s: jnp.minimum((s + 1) * per, halos - 1))
+
+
 def _specs(bcx, *, passes: int):
     """The grid and the block specs by name. `passes`: how many rows of D
     channels in bcx's type a grid step holds, for the block rule."""
     B, S, wide = bcx.shape
     D = wide // 3
     rows = _block_rows(S, passes * D * bcx.dtype.itemsize)
-    per, halos = rows // HALO, S // HALO
+    before, behind = _halo_maps(S, rows)
     return (B, S // rows), dict(
         wide=pl.BlockSpec((1, rows, wide), lambda b, s: (b, s, 0)),
         rows=pl.BlockSpec((1, rows, D), lambda b, s: (b, s, 0)),
-        # the halo that ends where the block begins, and the one that begins
-        # where it ends; at the row's two ends any block, read as zeros
-        before=pl.BlockSpec((1, HALO, wide), lambda b, s: (
-            b, jnp.maximum(s * per - 1, 0), 0)),
-        behind=pl.BlockSpec((1, HALO, wide), lambda b, s: (
-            b, jnp.minimum((s + 1) * per, halos - 1), 0)),
-        dy_behind=pl.BlockSpec((1, HALO, D), lambda b, s: (
-            b, jnp.minimum((s + 1) * per, halos - 1), 0)))
+        before=pl.BlockSpec((1, HALO, wide), lambda b, s: (b, before(s), 0)),
+        behind=pl.BlockSpec((1, HALO, wide), lambda b, s: (b, behind(s), 0)),
+        dy_behind=pl.BlockSpec((1, HALO, D), lambda b, s: (b, behind(s), 0)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
