@@ -210,16 +210,50 @@ def _document_lengths(cfg: dict, rng, count: int):
 
 
 def host_batch(cfg: dict, seed: int, i: int, n: int):
-    """The i-th host batch of n rows: token ids (n, S + 1), every row packed.
-    Documents are drawn one after another (`_document_lengths`), each one's
-    ids uniform over the rows of the vocabulary held here that are not the
-    end-of-document id and its last id that one; they are laid end to end in
-    the order drawn and the stream is cut into rows of S + 1 ids, so no
-    position is padding, a row begins inside a document (its head is a
-    document of its own to the model: nothing of it came before) and ends
-    inside one. The cut begins between one and two of the longest
-    documents' lengths into the stream, as a row of a long stream's middle
-    does, and not at a document's first id. The loss shifts the ids by one."""
+    """The i-th host batch of n rows: token ids (n, S + 1), every row packed,
+    no position padding, a document's last id the end-of-document id and
+    every other id uniform from the seed over the rows of the vocabulary
+    held here that are not that one. The loss shifts the ids by one.
+
+    Where the configuration's `documents` holds `rows` (PR 60), a batch of
+    one row with i < len(rows), so every batch of the cell's pool, is row i
+    of the file: its pieces' lengths in order, the same under every seed, and
+    the seed draws the ids alone (`_written_row`). The kernels skip by the
+    documents' boundaries, so a step's time follows its row: a pool drawn
+    from the seed made the cell's step times a lottery over seeds.
+
+    Any other batch (`harness.SAMPLE_INDEX`, a batch of several rows, a
+    configuration without `rows`) draws its documents too (`_drawn_rows`)."""
+    rows = cfg["documents"].get("rows", ())
+    if n == 1 and i < len(rows):
+        return _written_row(cfg, seed, i, rows[i])
+    return _drawn_rows(cfg, seed, i, n)
+
+
+def _written_row(cfg: dict, seed: int, i: int, pieces):
+    """One row whose boundaries are `pieces`, the lengths in order of the
+    documents' pieces it holds (its head and its tail are pieces of
+    documents the row cuts, as `row_documents` counts them): the
+    end-of-document id at each piece's last position but the row's last,
+    every other id uniform from `default_rng([seed, i])`."""
+    width = cfg["sequence_length"] + 1
+    if sum(pieces) != width or min(pieces) < 1:
+        raise ValueError(f"documents.rows[{i}] holds {sum(pieces)} ids in "
+                         f"pieces from {min(pieces)}: a row is {width} ids")
+    rng = np.random.default_rng([seed, i])
+    ids = rng.integers(1, cfg["vocab_size"], size=width, dtype=np.int32)
+    ids[np.cumsum(pieces)[:-1] - 1] = end_of_document(cfg)
+    return ids[None]
+
+
+def _drawn_rows(cfg: dict, seed: int, i: int, n: int):
+    """n rows of one stream drawn from the seed. Documents are drawn one
+    after another (`_document_lengths`) and laid end to end in the order
+    drawn, and the stream is cut into rows of S + 1 ids, so a row begins
+    inside a document (its head is a document of its own to the model:
+    nothing of it came before) and ends inside one. The cut begins between
+    one and two of the longest documents' lengths into the stream, as a row
+    of a long stream's middle does, and not at a document's first id."""
     rng = np.random.default_rng([seed, i])
     d = cfg["documents"]
     need = n * (cfg["sequence_length"] + 1)
@@ -229,6 +263,25 @@ def host_batch(cfg: dict, seed: int, i: int, n: int):
     ids = rng.integers(1, cfg["vocab_size"], size=start + need, dtype=np.int32)
     ids[ends[ends < ids.size]] = end_of_document(cfg)
     return ids[start:].reshape(n, cfg["sequence_length"] + 1)
+
+
+def rows_by_rule(cfg: dict) -> list:
+    """The rows `documents.rows` holds, made again by `documents.rows_rule`:
+    the first `seeds` x `batches` rows the free generator draws
+    (`_drawn_rows(cfg, seed, i, 1)`, seed-major), ranked by
+    `within_document_pairs` (ties in the order drawn), and of each of `keep`
+    equal parts of the ranking the middle row, as the lengths of its pieces
+    over all S + 1 ids: eight rows at the octile midpoints of the
+    attention layer's work, the fourth and fifth beside the distribution's
+    median, the last at its 94th percentile."""
+    rule = cfg["documents"]["rows_rule"]
+    drawn = [_drawn_rows(cfg, seed, i, 1)
+             for seed in range(rule["seeds"]) for i in range(rule["batches"])]
+    ranked = np.argsort([within_document_pairs(cfg, b) for b in drawn],
+                        kind="stable")
+    part = len(drawn) // rule["keep"]
+    return [row_documents(cfg, drawn[ranked[k * part + part // 2]][0])
+            for k in range(rule["keep"])]
 
 
 def row_documents(cfg: dict, tokens) -> list:
@@ -412,10 +465,10 @@ def pool(record) -> list:
 
 
 def pool_within_document_pairs(record):
-    """`within_document_pairs` a row, the mean over the run's pool: the
-    step's time is the median over the traced steps, and the kernels' time
-    does not follow the batch (they mask and skip nothing by document). Of
-    a record that does not say which seed its batches came from, the
+    """`within_document_pairs` a row, the mean over the run's pool (since
+    PR 60 the configuration's written rows, one mean under every seed): the
+    traced steps' time is a median over steps that cycle the pool. Of a
+    record that does not say which seed its batches came from, the
     expectation under the configuration's documents."""
     cfg = cell_config(record)
     if "seed" not in record:
@@ -476,8 +529,8 @@ def dead_block_share(cfg: dict, batches) -> float:
     """Of the (query block, key block) pairs the flash kernels visit under
     the causal mask alone, the share that a document boundary leaves dead:
     every key of the block of an earlier document than every query of it.
-    The kernels of PR 52 visit and mask them; skipping them is a `perf_opt`
-    issue's (ROADMAP R3(b))."""
+    The kernels of PR 52 visited and masked them; since PR 53 they run no
+    body and fetch nothing there, so a row's core time follows this share."""
     blk_q, blk_k = cfg["flash_blocks"]
     visited = dead = 0
     for batch in batches:

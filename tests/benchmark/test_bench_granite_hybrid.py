@@ -3,7 +3,9 @@ the whole of `harness.measure` at tiny size on the CPU mesh with packed
 batches, the parameter, operation and byte counts against the initialised tree
 and sums made by hand, the documents the batches are made of and their
 expectation, the readers against a drawn trace, and the configuration file
-against the catalog's numbers.
+against the catalog's numbers. Since PR 60 the cell's pool is the eight rows
+the configuration's file writes, the same under every seed: the rows, the rule
+that chose them, and what still follows the seed.
 
 These tests find the cell and its entries by name, wherever later cells put
 them: no position in the manifest is pinned."""
@@ -72,6 +74,14 @@ def _real():
     return mf.cell(mf.load(), CELL)["config"]
 
 
+def _free():
+    """The cell's configuration without its written rows: every batch drawn
+    from the seed, as all were until PR 60 and as the reference's sample is."""
+    config = copy.deepcopy(_real())
+    del config["documents"]["rows"], config["documents"]["rows_rule"]
+    return config
+
+
 def _tiny_config(**changes):
     config = copy.deepcopy(_real())
     config.update(copy.deepcopy(TINY))
@@ -86,8 +96,11 @@ def test_the_manifest_with_the_cell_is_sound():
     assert cell == {**cell, "config": NAME, "traffic": "ssgd_packed_1chip",
                     "chips": 1}
     for word in ("8,192", "packed", "log-normal", "median 512", "no padding",
-                 "9 of 10"):
+                 "9 of 10", "8 fixed rows", "12/15/19/22/27/33/44/71 %",
+                 "seeds draw ids only"):
         assert word in cell["why"], word
+    assert len(cell["why"]) <= 200
+    assert "/".join(str(int(share + 0.5)) for share in SHARES) + " %" in cell["why"]
     (entry,) = [c for c in manifest["configs"] if c["name"] == NAME]
     assert entry["reduced"] == ["num_hidden_layers", "vocab_size"]
     mine = [m for m in manifest["per_layer"] if m.get("workloads") == [CELL]]
@@ -128,9 +141,12 @@ def test_the_configuration_is_the_catalogs_but_for_its_cut():
                  "dt_bias", "normal(0, 0.02)", "mamba_chunk_size", "float32",
                  "recomputed_layer_types", "rope_theta", "gate first"):
         assert any(word in line for line in config["assumed"]), word
-    assert config["documents"] == {
+    assert _free()["documents"] == {
         "distribution": "lognormal", "median": 512, "sigma": 1.25,
         "shortest": 16, "longest": 8192, "end_of_document_id": 0}
+    assert {k: v for k, v in config["documents"]["rows_rule"].items()
+            if k != "what"} == {"seeds": 250, "batches": 8, "keep": 8}
+    assert len(config["documents"]["rows"]) == 8
     assert config["sequence_length"] == 8192
     assert config["flash_blocks"] == [512, 512]
     assert (config["param_dtype"], config["compute_dtype"], config["head_dtype"]) == (
@@ -258,7 +274,7 @@ def test_the_expected_pairs_are_the_drawn_batches_mean():
     """The renewal argument of `_expected_pairs` against rows drawn by
     `host_batch`, at the tests' size and at the cell's: within three
     standard errors."""
-    for config, rows, seeds in ((_tiny_config(), 64, 6), (_real(), 8, 24)):
+    for config, rows, seeds in ((_tiny_config(), 64, 6), (_free(), 8, 24)):
         drawn = [granite_hybrid.within_document_pairs(
             config, granite_hybrid.host_batch(config, seed, i, rows)[j:j + 1])
             for seed in range(seeds) for i in range(2) for j in range(rows)]
@@ -326,7 +342,7 @@ def test_the_multiplying_parameters_are_the_initialised_trees():
 
 
 def test_host_batches_are_packed_documents_from_the_seed():
-    config = _real()
+    config = _free()
     a = granite_hybrid.host_batch(config, 2**31 + 11, 3, 2)
     b = granite_hybrid.host_batch(config, 2**31 + 11, 3, 2)
     c = granite_hybrid.host_batch(config, 2**31 + 12, 3, 2)
@@ -352,6 +368,142 @@ def test_host_batches_are_packed_documents_from_the_seed():
     stream = granite_hybrid.host_batch(config, 5, 0, 2)
     assert stream.shape == (2, 8193)
     assert not np.array_equal(stream[0], stream[1])
+
+
+# --- the cell's pool: the rows the configuration's file writes (PR 60) -------
+
+SHARES = (11.66, 15.15, 18.61, 22.16, 26.50, 33.03, 44.05, 70.68)  # % of pairs
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 2**31 + 11])
+def test_the_pools_boundaries_are_the_files_under_every_seed(seed):
+    """Batches 0 to 7 of one row, the traffic's pool, as `harness.measure`
+    and `pool(record)` make them: the file's pieces whatever the seed, and
+    ids that follow the seed."""
+    config = _real()
+    rows = config["documents"]["rows"]
+    pool = [granite_hybrid.host_batch(config, seed, i, 1) for i in range(8)]
+    other = [granite_hybrid.host_batch(config, seed + 1, i, 1) for i in range(8)]
+    for i, (batch, again) in enumerate(zip(pool, other)):
+        assert batch.shape == (1, 8193) and batch.dtype == np.int32
+        assert granite_hybrid.row_documents(config, batch[0]) == rows[i]
+        assert ((batch == 0) == (again == 0)).all()  # one set of boundaries
+        assert (batch != again).mean() > 0.99  # and other ids
+        assert (batch == granite_hybrid.host_batch(config, seed, i, 1)).all()
+    # the batches of one seed are not one another's ids under other cuts
+    assert (pool[0] != pool[1]).mean() > 0.99
+    record = {"workload": CELL, "seed": seed, "samples_per_step": 1}
+    assert all((a == b).all() for a, b in zip(granite_hybrid.pool(record), pool))
+
+
+@pytest.mark.parametrize("i", range(8))
+def test_a_written_row_is_whole(i):
+    """Pieces of 1 to 8,192 ids that sum to the 8,193 a row holds; no
+    position is padding: the end-of-document id ends every piece but the
+    last and stands nowhere else, every other id is of rows 1 to 12,543,
+    uniform; the share of the row's causal pairs within a document is the
+    one the rule's note states (and, rounded, the cell's `why`)."""
+    config = _real()
+    pieces = config["documents"]["rows"][i]
+    assert sum(pieces) == 8193 and 1 <= min(pieces) and max(pieces) <= 8192
+    row = granite_hybrid.host_batch(config, 2**31 + 5, i, 1)[0]
+    assert (np.flatnonzero(row == 0) == np.cumsum(pieces)[:-1] - 1).all()
+    assert 1 <= row[row != 0].min() and row.max() < 12544
+    assert np.bincount(row, minlength=12544)[1:].max() < 9
+    share = 100 * granite_hybrid.within_document_pairs(
+        config, row[None]) / granite_hybrid.causal_pairs(config)
+    assert share == pytest.approx(SHARES[i], abs=0.005)
+    assert f"{SHARES[i]:.2f}" in config["documents"]["rows_rule"]["what"]
+
+
+def test_the_rule_run_again_gives_the_files_rows():
+    """2,000 rows of the free generator, ranked by the attention layer's
+    work, the middle of each eighth: `rows_by_rule` reads the rule's numbers
+    from the file and draws as a configuration without rows does."""
+    config = _real()
+    rows = granite_hybrid.rows_by_rule(config)
+    assert rows == config["documents"]["rows"] and len(rows) == 8
+    # by hand: the 0-based ranks 125, 375, ..., 1,875 of the 2,000
+    free = _free()
+    drawn = [granite_hybrid.host_batch(free, seed, i, 1)
+             for seed in range(250) for i in range(8)]
+    pairs = [granite_hybrid.within_document_pairs(free, b) for b in drawn]
+    ranked = sorted(range(2000), key=lambda k: (pairs[k], k))
+    assert [granite_hybrid.row_documents(free, drawn[ranked[r]][0])
+            for r in range(125, 2000, 250)] == rows
+    # the fourth and fifth rows stand on either side of the draws' median,
+    # the last at their 94th percentile
+    assert pairs[ranked[875]] < np.median(pairs) < pairs[ranked[1125]]
+    assert np.mean(np.array(pairs) < pairs[ranked[1875]]) == pytest.approx(0.9375)
+    for word in ("2,000", "within_document_pairs", "125, 375, ..., 1,875", "8,193",
+                 "same under every seed", "rows_by_rule"):
+        assert word in config["documents"]["rows_rule"]["what"], word
+
+
+def test_the_pools_mean_pairs_are_the_expectations():
+    """`flops_per_sample` and `mfu_pct` count the attention layer by the
+    expectation under the documents' distribution; the pool every run now
+    cycles has that mean to 2 % (a seeded pool's lay between 20 and 40 % of
+    the causal pairs, ISSUE 60)."""
+    config = _real()
+    record = {"workload": CELL, "seed": 7, "samples_per_step": 1}
+    mean = granite_hybrid.pool_within_document_pairs(record)
+    expected = granite_hybrid.expected_within_document_pairs(config)
+    assert abs(mean / expected - 1) < 0.03
+    assert 100 * mean / granite_hybrid.causal_pairs(config) == pytest.approx(
+        np.mean(SHARES), abs=0.005) == pytest.approx(30.23, abs=0.005)
+    assert granite_hybrid.pool_within_document_pairs({**record, "seed": 8}) == mean
+
+
+@pytest.mark.parametrize("i,n", [(harness.SAMPLE_INDEX, 1), (8, 1), (0, 2), (3, 2)],
+                         ids=["the_sample", "past_the_rows", "two_rows", "two_rows_later"])
+def test_what_is_not_the_pool_still_follows_the_seed(i, n):
+    """The reference's sample, a batch past the written rows and a batch of
+    several rows draw their documents from the seed, exactly as a
+    configuration without `rows` does: `correct` keeps meeting layouts it
+    has not seen."""
+    config, free = _real(), _free()
+    a = granite_hybrid.host_batch(config, 11, i, n)
+    assert (a == granite_hybrid.host_batch(free, 11, i, n)).all()
+    b = granite_hybrid.host_batch(config, 12, i, n)
+    assert a.shape == b.shape == (n, 8193)
+    assert [granite_hybrid.row_documents(config, r) for r in a] != [
+        granite_hybrid.row_documents(config, r) for r in b]
+    assert granite_hybrid.row_documents(config, a[0]) not in config["documents"]["rows"]
+
+
+def test_a_configuration_without_rows_draws_every_batch():
+    """The tests' small configurations and the cell's before PR 60."""
+    free = _free()
+    for i in range(8):
+        a, b = (granite_hybrid.host_batch(free, seed, i, 1) for seed in (0, 1))
+        assert granite_hybrid.row_documents(free, a[0]) != (
+            granite_hybrid.row_documents(free, b[0]))
+    tiny = _tiny_config()
+    assert "rows" not in tiny["documents"]
+    assert granite_hybrid.host_batch(tiny, 0, 0, 1).shape == (1, 129)
+
+
+@pytest.mark.parametrize("pieces", [[4096, 4096], [8193, 0], [8000, 100, 94]],
+                         ids=["short", "an_empty_piece", "long"])
+def test_a_written_row_that_is_not_a_row_is_refused(pieces):
+    config = _real()
+    config["documents"]["rows"][2] = pieces
+    with pytest.raises(ValueError, match=r"documents.rows\[2\]"):
+        granite_hybrid.host_batch(config, 0, 2, 1)
+    granite_hybrid.host_batch(config, 0, 1, 1)
+
+
+def test_the_words_say_what_the_traffic_now_is():
+    manifest = mf.load()
+    cell = mf.cell(manifest, CELL)
+    for word in ("documents.rows", "same boundaries under every seed",
+                 "the seed draws the ids", "11.7 to 70.7 %"):
+        assert word in cell["traffic"]["what"], word
+    assert any("documents.rows_rule" in line and "same under every seed" in line
+               for line in cell["config"]["assumed"])
+    for word in ("documents` holds `rows`", "SAMPLE_INDEX", "ids alone"):
+        assert word in granite_hybrid.host_batch.__doc__, word
 
 
 # --- the program against the reference --------------------------------------
@@ -564,10 +716,10 @@ def test_drawn_shares_follow_the_runs_own_documents():
         100 * core / 48e-3)
     share = pk_within_doc_pairs_pct.read(record, None)
     assert share == pytest.approx(100 * pairs / (8192 * 8193 / 2))
-    assert 5 < share < 70
-    # another seed is other documents
+    assert share == pytest.approx(30.23, abs=0.005)
+    # another seed is other ids in the same documents (PR 60)
     other = pk_within_doc_pairs_pct.read({**record, "seed": SEED + 1}, None)
-    assert other != share
+    assert other == share
     assert 0 < pk_ssm_core_roofline_pct.read(record, DRAWN) < 100
     assert 0 < pk_attn_core_roofline_pct.read(record, DRAWN) < 100
 
