@@ -99,6 +99,18 @@ TPU-first design notes:
   state in training. Its leaves are `conv_in`, `conv_w` and `conv_out`; packed
   rows' segments go to it as to every mixer. Layers of it stand in one stack
   of `layer_kinds` beside attention, dense and expert layers (`lfm2_24b_a2b`).
+- Learned sparse attention (PR 61, DeepSeek Sparse Attention on grouped heads,
+  `keye_vl_2_0_30b_a3b`): `sparse_index` = (indexer heads, indexer head size,
+  keys a query) gives an attention layer a lightning indexer (leaves
+  `index_wq`, `index_wk`, `index_w`, `index_ln_scale`, `index_ln_bias`) on the
+  layer's normed input with its gradient stopped, the choice of each query's
+  best-scored keys at or before it, the softmax core over the chosen keys
+  alone and the indexer's own loss, the KL divergence of its distribution from
+  the head-mean of the core's probabilities (`_sparse_attention`,
+  `ops.sparse_attention`). The cross-entropy reaches no leaf of the indexer
+  and the indexer's loss no other leaf; the layers' sum of it, times
+  `indexer_loss_weight`, is in `transformer_loss` beside the routers' losses
+  (`LayerAux`). `()` is every earlier configuration's program, text for text.
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -111,7 +123,7 @@ import contextlib
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -232,6 +244,13 @@ class TransformerConfig:
     # one tuple of (field, value) pairs a layer: what replaces the fields
     # above for that layer; (): every layer is the configuration's own
     layer_kinds: Tuple = ()
+    # learned sparse attention (`ops.sparse_attention`): (indexer heads,
+    # indexer head size, keys a query); every attention layer scores its
+    # (query, key) pairs with a lightning indexer, attends over the so many
+    # best-scored keys at or before each query, and adds the indexer's KL
+    # loss times `indexer_loss_weight` to the model's. (): no indexer
+    sparse_index: Tuple = ()
+    indexer_loss_weight: float = 1.0
 
     def __post_init__(self):
         for field, value, known in (
@@ -312,8 +331,11 @@ class TransformerConfig:
             raise ValueError("a loop (loop_steps > 1) has no place for an "
                              "expert layer's losses and counters a loop step, "
                              "nor for a multi-token-prediction module")
+        if self.sparse_index:
+            self._check_sparse_index()
         if (self.window or self.kv_heads != self.n_heads
-                or self.attention_multiplier) and self.attn_core != "flash":
+                or self.attention_multiplier) and self.attn_core != "flash" \
+                and not self.sparse_index:
             raise ValueError("a window, grouped heads and a scale of the "
                              "scores' own are the flash core's (attn_core "
                              "'flash'); the dense core has none of them")
@@ -331,6 +353,40 @@ class TransformerConfig:
                              f"{self.n_layers} layers")
         for kind in self.layer_kinds:
             dataclasses.replace(self, layer_kinds=(), n_layers=1, **dict(kind))
+
+    def _check_sparse_index(self):
+        """What a learned sparse index stands with, each refusal a sentence."""
+        if not (len(self.sparse_index) == 3 and min(self.sparse_index) >= 1
+                and self.sparse_index[1] % 2 == 0):
+            raise ValueError("sparse_index is (indexer heads, an even indexer "
+                             "head size, keys a query), got "
+                             f"{self.sparse_index}")
+        if self.mixer != "attention" or not self.split_qkv or (
+                self.positions != "rope"):
+            raise ValueError("sparse_index chooses the keys of softmax "
+                             "attention with projections of its own (a head "
+                             "size or key/value heads) and rotary positions, "
+                             f"not of mixer {self.mixer!r} with positions "
+                             f"{self.positions!r}")
+        for field, what, unset in (
+                ("window", "a window beside the choice", 0),
+                ("end_of_document", "packed documents under the choice", None),
+                ("mtp_depth", "a multi-token-prediction module", 0),
+                ("layer_kinds", "layers that differ in kind", ()),
+                ("head_gate", "a gate a head", False),
+                ("q_gate", "a gate a feature", False),
+                ("attention_multiplier", "a scale of the scores' own", 0.0),
+                ("yarn", "YaRN's frequencies", ())):
+            if getattr(self, field) != unset:
+                raise ValueError(
+                    f"sparse_index is not built with {what} ({field}): the "
+                    "choice is made under the causal bound alone, in a stack "
+                    "of one kind of layer, and no test holds it to more")
+        if self.loop_steps > 1 or self.rotary_share != 1.0:
+            raise ValueError("sparse_index is not built under a loop "
+                             "(loop_steps > 1), which has no place for the "
+                             "indexer's loss a loop step, nor with a rotary "
+                             "share of the head (rotary_share)")
 
     @property
     def head_dim(self) -> int:
@@ -543,6 +599,14 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
             layer["wo"] = dense(lk[1], (D, D))
         if cfg.head_gate and cfg.mixer == "attention":
             layer["w_head_gate"] = dense(xk[2], (D, cfg.n_heads))
+        if cfg.sparse_index:  # the lightning indexer's five, from a seventh
+            Hi, di, _ = cfg.sparse_index
+            ik = jax.random.split(jax.random.fold_in(key, 6), 3)
+            layer.update(index_wq=dense(ik[0], (D, Hi * di)),
+                         index_wk=dense(ik[1], (D, di)),
+                         index_w=dense(ik[2], (D, Hi)),
+                         index_ln_scale=jnp.ones((di,), jnp.float32),
+                         index_ln_bias=jnp.zeros((di,), jnp.float32))
         gated = cfg.ffn == "swiglu" or cfg.expert_act == "swiglu"
         if cfg.ffn == "gelu":
             layer["w_in"] = dense(lk[2], (D, F))
@@ -629,7 +693,9 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     up-projections are a head at a time and column-parallel, its
     down-projections (the one rotary key's columns among them) and the
     latents' norms whole. A multi-token-prediction module's block is
-    sharded like a layer of its kind, its norms and projection whole.
+    sharded like a layer of its kind, its norms and projection whole. A
+    lightning indexer's five leaves (`sparse_index`) are whole: every shard of
+    the heads attends under the one choice.
     """
     t, e = tp_axis, ep_axis
 
@@ -680,6 +746,10 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
             layers.update(wqkv=P(None, None, t))
         if cfg.head_gate and cfg.mixer == "attention":
             layers.update(w_head_gate=P(None, None, t))
+        if cfg.sparse_index:  # the indexer whole on every chip: one choice
+            layers.update(index_wq=P(None, None, None), index_wk=P(None, None, None),
+                          index_w=P(None, None, None), index_ln_scale=P(None, None),
+                          index_ln_bias=P(None, None))
         if cfg.ffn == "gelu":
             layers.update(w_in=P(None, None, t), w_out=P(None, t, None))
         elif cfg.ffn == "swiglu":
@@ -1002,6 +1072,123 @@ def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
     return ctx @ wo
 
 
+class LayerAux(NamedTuple):
+    """What a layer with a learned sparse index (`sparse_index`) hands the
+    loss beside its hidden states: `moe`, the expert layer's `ops.moe.MoeAux`
+    (None of any other feed-forward), and `index_kl`, the indexer's KL loss
+    of the layer, a float32 scalar. A layer without an indexer hands its
+    `MoeAux` or None as it is."""
+    moe: Any
+    index_kl: Any
+
+
+def _aux_parts(aux):
+    """-> (the expert layers' aux or None, the indexers' KL losses a layer or
+    None) of what a layer scan stacked."""
+    return tuple(aux) if isinstance(aux, LayerAux) else (aux, None)
+
+
+def _layer_norm(x, scale, bias, eps):
+    """LayerNorm over the last axis, float32: (x - mean) / sqrt(var + eps) *
+    scale + bias."""
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return (x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                              + eps) * scale + bias)
+
+
+def _rotate_half(t, cos, sin):
+    """t cos + rotate_half(t) sin over the last axis, cos and sin of its
+    width: the plain form, for the indexer's small float32 arrays."""
+    half = t.shape[-1] // 2
+    return t * cos + jnp.concatenate([-t[..., half:], t[..., :half]], -1) * sin
+
+
+def _sparse_attention(h, layer, cfg: TransformerConfig, qk_scales):
+    """Learned sparse attention on normed hidden states h (B, S, D) -> (the
+    mixer's output (B, S, D), the indexer's KL loss, a scalar). q, k, v as
+    every attention layer's (`_split_heads`: the norm a head, the rotary
+    pass). The lightning indexer reads h with its gradient stopped, in
+    float32 at the highest precision, as a router does (a rounded score moves
+    the last chosen key as a rounded router moves the last chosen expert): qI
+    = h W_qI as (S, Hi, di), kI = LN(h W_kI), both rotated over all di
+    features at `rope_theta`, w = h W_w / sqrt(Hi di), I[t, s] = sum_j w[t, j]
+    relu(qI[t, j] . kI[s]). Each query's `sparse_index[2]` best-scored keys
+    at or before it are its choice (all of them where it has no more), one
+    choice for all heads; the softmax core runs over the chosen keys; the
+    indexer's loss is the KL divergence of softmax over the chosen keys of I
+    from the head-mean of the core's probabilities there, a constant. The
+    cross-entropy's gradient reaches q, k, v through the chosen keys and no
+    leaf of the indexer; the KL's reaches the indexer's five leaves and
+    nothing else. On the flash core's setting (`attn_core` "flash") the four
+    pieces are `ops.sparse_attention`'s kernels at `flash_blocks`, on "dense"
+    its plain forms. Scopes `attn_proj` (the four projections, with `qk_norm`
+    and `rope` inside), `dsa_index`, `dsa_select`, `attn_sparse` > `attn_core`
+    and `dsa_kl`."""
+    from kungfu_tpu.ops import sparse_attention as dsa
+
+    dt = cfg.dtype
+    B, S, _ = h.shape
+    kernels = cfg.attn_core == "flash"
+    how = (*cfg.flash_blocks, cfg.flash_interpret)
+    with jax.named_scope("attn_proj"):
+        q, k, v, _ = _split_heads(
+            h, tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv")), cfg,
+            qk_scales)
+    scores, chosen = _sparse_choice(h, layer, cfg)
+    with jax.named_scope("attn_sparse"), jax.named_scope("attn_core"):
+        ctx, lse = (dsa.sparse_attention(q, k, v, chosen, None, *how) if kernels
+                    else dsa.plain_sparse_attention(q, k, v, chosen))
+    with jax.named_scope("dsa_kl"):
+        p, entropy = (dsa.head_mean_probs(q, k, lse, chosen, None, *how)
+                      if kernels else
+                      dsa.plain_head_mean_probs(q, k, lse, chosen))
+        kl = dsa.indexer_kl(scores, chosen, p, entropy)
+    with jax.named_scope("attn_proj"):
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, -1)
+        return ctx @ layer["wo"].astype(dt), kl
+
+
+def _sparse_choice(h, layer, cfg: TransformerConfig):
+    """The lightning indexer on normed hidden states h (B, S, D) -> (its
+    scores I (B, S, S) float32, defined at s <= t, and the choice (B, S, S)
+    int8): `_sparse_attention`'s first half, scopes `dsa_index` and
+    `dsa_select`."""
+    from kungfu_tpu.ops import sparse_attention as dsa
+
+    f32 = jnp.float32
+    B, S, _ = h.shape
+    Hi, di, keys = cfg.sparse_index
+    kernels = cfg.attn_core == "flash"
+    how = (*cfg.flash_blocks, cfg.flash_interpret)
+    with jax.named_scope("dsa_index"):
+        ub = jax.lax.stop_gradient(h).astype(f32)
+
+        def projected(w):
+            return jnp.dot(ub, layer[w].astype(f32),
+                           precision=jax.lax.Precision.HIGHEST)
+
+        cos, sin = _rotary_tables(S, di, cfg.rope_theta, 1.0, ())
+        qI = _rotate_half(projected("index_wq").reshape(B, S, Hi, di),
+                          cos[:, None], sin[:, None])
+        kI = _rotate_half(_layer_norm(
+            projected("index_wk"), layer["index_ln_scale"],
+            layer["index_ln_bias"], cfg.norm_eps), cos, sin)
+        w = projected("index_w") * (Hi ** -0.5 * di ** -0.5)
+        scores = (dsa.index_scores(qI, kI, w, *how) if kernels
+                  else dsa.plain_index_scores(qI, kI, w))
+    with jax.named_scope("dsa_select"):
+        # Handed on through its bits, a bit a pair under the name
+        # `dsa_chosen` (8.4 MB a layer of 8,192 positions): a layer that is
+        # run again keeps them (`_layer_again`) and makes the scores again,
+        # which the indexer's loss reads, but not the choice, whose 45
+        # passes over the scores then run once a step and not twice
+        # (14.3 ms a layer each time: PERF.md, PR 61).
+        packed = checkpoint_name(
+            jnp.packbits(dsa.select(scores, keys).astype(jnp.uint8), axis=-1),
+            "dsa_chosen")
+        return scores, jnp.unpackbits(packed, axis=-1, count=S).astype(jnp.int8)
+
+
 def _latent_attention(h, layer, cfg: TransformerConfig, core=None):
     """Latent attention (MLA) on normed hidden states h (B, S, D) -> (B, S,
     D). c_q = norm(h W_q_down) and a head's [q_nope | q_rope] = c_q W_q_up;
@@ -1312,6 +1499,7 @@ def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
     them where `_hidden` made them; () where a row is one document."""
     dt, eps = cfg.dtype, cfg.norm_eps
     segments, marks = segments[:1], segments[1:]
+    index_kl = None
     if cfg.mixer == "none":
         pass
     elif cfg.mixer == "mamba2":
@@ -1340,15 +1528,28 @@ def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
                        _scale(layer["k_norm_scale"], cfg))
                       if cfg.qk_norm else None)
             h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            wqkv = (tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv"))
-                    if cfg.split_qkv else layer["wqkv"].astype(dt))
-            x = _taken(
-                x, _attention(h, wqkv, layer["wo"].astype(dt),
-                              cfg, core=core, qk_scales=scales,
-                              w_head_gate=(layer["w_head_gate"].astype(dt)
-                                           if cfg.head_gate else None),
-                              segments=segments),
-                layer, "ln1_post_scale", cfg)
+            if cfg.sparse_index:
+                y, index_kl = _sparse_attention(h, layer, cfg, scales)
+                x = _taken(x, y, layer, "ln1_post_scale", cfg)
+            else:
+                wqkv = (tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv"))
+                        if cfg.split_qkv else layer["wqkv"].astype(dt))
+                x = _taken(
+                    x, _attention(h, wqkv, layer["wo"].astype(dt),
+                                  cfg, core=core, qk_scales=scales,
+                                  w_head_gate=(layer["w_head_gate"].astype(dt)
+                                               if cfg.head_gate else None),
+                                  segments=segments),
+                    layer, "ln1_post_scale", cfg)
+    x, aux = _feed_forward(x, layer, cfg)
+    # an indexer's record beside the expert layer's
+    return x, aux if index_kl is None else LayerAux(aux, index_kl)
+
+
+def _feed_forward(x, layer, cfg: TransformerConfig):
+    """A layer's second branch on the residual stream x -> (x, the expert
+    layer's aux or None)."""
+    dt, eps = cfg.dtype, cfg.norm_eps
     if cfg.ffn == "none":
         return x, None
     if cfg.ffn == "moe":
@@ -1380,7 +1581,11 @@ def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
 # once more for their own gradients, `_delta_heads`: twice a step in all).
 # Of a short-convolution mixer nothing is kept: its op's forward kernel is one
 # pass over the projection's output (0.2 ms a layer of 8,192 positions of
-# 2,048 channels) and runs again with the projection that feeds it.
+# 2,048 channels) and runs again with the projection that feeds it. Of a
+# learned sparse index the choice is kept as a bit a pair (`dsa_chosen`, 8.4 MB
+# a layer of 8,192 positions) beside its core's output and row sums: the
+# indexer's scores are made again (268 MB a layer, read by the indexer's
+# loss), the search for each query's keys is not.
 # Under a loop every application of a layer keeps its own: the Ouro cell's 32
 # applications (8 layers x 4 loop steps) of 16 heads of 128 at 4,096 positions
 # keep 2 x 16.8 MB each and the row sums, 1.08 GB a step beside the 0.82 GB of
@@ -1389,7 +1594,7 @@ def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
 _layer_again = jax.checkpoint(
     _layer, static_argnums=(2,), prevent_cse=False,
     policy=jax.checkpoint_policies.save_only_these_names(
-        "flash_out", "flash_lse", "gdn_mix"))
+        "flash_out", "flash_lse", "gdn_mix", "dsa_chosen"))
 
 
 def _block(x, layer, cfg: TransformerConfig, core=None):
@@ -1403,6 +1608,11 @@ def _block(x, layer, cfg: TransformerConfig, core=None):
             "mixer 'short_conv' runs on the normal path (`transformer_loss`): "
             "the ring path would have to hand a shard the rows before it, "
             "and neither it nor the pipeline path is built for the mixer")
+    if cfg.sparse_index:
+        raise NotImplementedError(
+            "sparse_index runs on the normal path (`transformer_loss`): the "
+            "ring and pipeline paths have no place for the indexer's loss, "
+            "and a sequence shard's choice would range over other shards' keys")
     return _layer(x, layer, cfg, core=core)[0]
 
 
@@ -1744,21 +1954,38 @@ def transformer_loss(params, batch, cfg: TransformerConfig):
     (tokens, targets); with the module, ids (B, S+2)."""
     if cfg.loop_steps > 1:
         return _loop_losses(params, batch, cfg)["loss"]
-    loss, mtp_loss, aux = _losses(params, batch, cfg)
+    return transformer_loss_and_parts(params, batch, cfg)[0]
+
+
+def transformer_loss_and_parts(params, batch, cfg: TransformerConfig):
+    """(`transformer_loss`, `transformer_losses`) from one pass over the
+    batch: what a caller differentiates with the parts as its `has_aux`
+    (a comparison of the indexer's loss beside the gradients, say), so that
+    the parts cost no second forward pass. Not of a loop."""
+    main, mtp_loss, aux = _losses(params, batch, cfg)
+    parts = {"main": main} if mtp_loss is None else {"main": main, "mtp": mtp_loss}
+    loss = main
+    aux, index_kl = _aux_parts(aux)
     if mtp_loss is not None:
         loss = loss + cfg.mtp_weight * mtp_loss
     if aux is not None and (cfg.router_aux_coef or cfg.router_z_coef):
         with jax.named_scope("moe"), jax.named_scope("moe_router"):
             loss = (loss + cfg.router_aux_coef * jnp.mean(aux.load_balance)
                     + cfg.router_z_coef * jnp.mean(aux.z_loss))
-    return loss
+    if index_kl is not None:  # the sum over the layers, not their mean
+        with jax.named_scope("attn"), jax.named_scope("dsa_kl"):
+            parts["indexer_kl"] = jnp.sum(index_kl)
+            loss = loss + cfg.indexer_loss_weight * parts["indexer_kl"]
+    return loss, parts
 
 
 def transformer_losses(params, batch, cfg: TransformerConfig):
     """The parts of `transformer_loss` on one batch: `main`, the next-token
     cross-entropy (of a loop, its last loop step's), and `mtp`, the
     multi-token-prediction module's, where the configuration has one, each a
-    scalar; of a loop also `loop`, every loop step's cross-entropy, and
+    scalar; `indexer_kl`, the lightning indexers' KL loss summed over the
+    layers, where it has those (`sparse_index`); of a loop also `loop`, every
+    loop step's cross-entropy, and
     `exit_share`, the batch's mean exit share of each, (T,) both, and
     `exit_entropy`. Jit this beside the step, as `routing_stats`: the step
     returns their weighted sum and nothing else."""
@@ -1766,13 +1993,13 @@ def transformer_losses(params, batch, cfg: TransformerConfig):
         parts = _loop_losses(params, batch, cfg)
         return {"main": parts["loop"][-1], **{
             k: parts[k] for k in ("loop", "exit_share", "exit_entropy")}}
-    loss, mtp_loss, _ = _losses(params, batch, cfg)
-    return {"main": loss} if mtp_loss is None else {"main": loss, "mtp": mtp_loss}
+    return transformer_loss_and_parts(params, batch, cfg)[1]
 
 
 def record_losses(losses, registry=None) -> None:
     """`transformer_losses`' numbers as gauges of `telemetry.metrics`:
-    `kungfu_lm_loss` and, beside it where there is one, `kungfu_mtp_loss`;
+    `kungfu_lm_loss` and, beside it where there is one, `kungfu_mtp_loss`
+    and `kungfu_indexer_kl`;
     of a loop `kungfu_loop_loss` and `kungfu_exit_share`, a series a loop
     step (`step`, from 1), and `kungfu_exit_entropy`."""
     from kungfu_tpu.telemetry import metrics
@@ -1783,6 +2010,10 @@ def record_losses(losses, registry=None) -> None:
     if "mtp" in losses:
         reg.gauge("kungfu_mtp_loss", "the multi-token-prediction module's "
                   "cross-entropy on the same batch").set(float(losses["mtp"]))
+    if "indexer_kl" in losses:
+        reg.gauge("kungfu_indexer_kl", "the lightning indexers' KL loss on the "
+                  "same batch, summed over the layers").set(
+                      float(losses["indexer_kl"]))
     if "loop" in losses:
         loop = reg.gauge("kungfu_loop_loss", "a loop step's next-token "
                          "cross-entropy on the same batch", ("step",))
@@ -1814,6 +2045,7 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     if cfg.mtp_depth:
         tokens, tokens_next = tokens[:, :-1], tokens[:, 1:]
     x, aux = _hidden(params, tokens, cfg)
+    aux = _aux_parts(aux)[0]
     kinds = [kind for kind, n in cfg.stacks for _ in range(n)]
     if cfg.mtp_depth and cfg.mtp_kind.ffn == "moe":
         own = _mtp_hidden(params, x, tokens_next, cfg)[1]
@@ -1841,6 +2073,24 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     if aux.bias_moved is not None:
         stats["bias_moved"] = aux.bias_moved
     return stats
+
+
+def sparse_choices(params, tokens, cfg: TransformerConfig):
+    """The keys every layer's lightning indexer chooses for tokens (B, S):
+    (layers, B, S, S) int8, 1 where query t attends to key s. Jit this beside
+    the step, as `routing_stats`; the layers one after another and not in a
+    scan, which would stack nothing else this large."""
+    if not cfg.sparse_index:
+        raise ValueError("sparse_choices: the configuration has no "
+                         "sparse_index, so every earlier key is seen")
+    x = _embed(params, tokens, cfg)
+    chosen = []
+    for at in range(cfg.n_layers):
+        layer = jax.tree.map(lambda leaf: leaf[at], params["layers"])
+        h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), cfg.norm_eps)
+        chosen.append(_sparse_choice(h, layer, cfg)[1])
+        x = _layer(x, layer, cfg)[0]
+    return jnp.stack(chosen)
 
 
 def record_routing(stats, registry=None) -> None:

@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import PartitionSpec
 
 from benchmark import harness, manifest as mf
-from benchmark.families import (glm4_moe_lite, granite_hybrid, laguna,
+from benchmark.families import (glm4_moe_lite, granite_hybrid, keye_vl2, laguna,
                                 lfm2_moe, nemotron_h, ouro, qwen3_next)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import param_pspecs
@@ -386,8 +386,43 @@ LFM2_MOE = Family(
     constants=("router_bias",),
     recomputed=((), (lfm2_moe.DENSE, lfm2_moe.SPARSE)))
 
+# two of the cell's layers: 4 query heads on 2 key/value heads of 16 behind a
+# q/k norm a head and a rotary pass, an indexer of 2 heads of 8 that picks 16
+# keys a query of 64 positions (three rows in four choose), the plain forms of
+# `ops.sparse_attention` (`attention_core` dense; the kernels, interpreted, are
+# a case of the family's file); 8 experts of which numbers 2 to 5 are held,
+# 3 a token; the routers trained, so that every leaf has a gradient to compare;
+# the indexer's loss at a weight of 1 and the three groups of the comparison
+# weighed alike at this size; the reference in four blocks of rows
+KEYE_VL2 = Family(
+    name="keye_vl2", cell="keye_vl_2_0_30b_a3b.ssgd_dsa_1chip", module=keye_vl2,
+    tiny=dict(hidden_size=64, moe_intermediate_size=32, num_hidden_layers=2,
+              num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+              sa_config=dict(indexer_head_dim=8, indexer_num_heads=2,
+                             indexer_num_kv_heads=1, kv_chunk_size=32,
+                             q_chunk_size=32, topk=16),
+              num_experts=4, num_local_experts=4, first_expert_held=2,
+              published={"num_experts": 8}, num_experts_per_tok=3,
+              vocab_size=320, sequence_length=64, flash_blocks=[32, 32],
+              flash_interpret=True, attention_core="dense",
+              compute_dtype="float32", routers_trained=True,
+              indexer_loss_weight=1.0,
+              compared_weights=dict(indexer_grads=0.4, indexer_kl=0.4),
+              reference_row_block=16, reference_position_block=16),
+    scales={"wq": 6.0, "wk": 6.0, "wv": 8.0, "wo": 3.0, "index_wq": 8.0,
+            "index_wk": 8.0, "index_w": 30.0, "router": 20.0, "w_gate": 8.0,
+            "w_up": 8.0, "w_down": 8.0},
+    norms=("ln1_scale", "ln2_scale", "q_norm_scale", "k_norm_scale",
+           "index_ln_scale", "index_ln_bias"),
+    expert_layers=(0, 1), held_share=(0.3, 0.7),
+    scopes=("attn/attn_proj/", "qk_norm/", "rope/", "attn/dsa_index/",
+            "attn/dsa_select/", "attn/attn_sparse/attn_core/", "attn/dsa_kl/",
+            "moe/moe_router", "moe/moe_dispatch", "moe_experts/", "moe_combine/",
+            "embed", "head_loss"),
+    recomputed=((),))  # the cell's layers are run again: against none that are
+
 FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H, OURO,
-            GRANITE_HYBRID, LFM2_MOE)
+            GRANITE_HYBRID, LFM2_MOE, KEYE_VL2)
 
 
 def pytest_generate_tests(metafunc):

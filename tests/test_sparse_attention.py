@@ -1,0 +1,194 @@
+"""`ops.sparse_attention` (PR 61): the indexer's scores, the exact choice, the
+core over the chosen keys and the head-mean of its probabilities, the kernels
+interpreted against the plain `jnp` forms and against a dense softmax under
+the mask written here: values and every gradient, float32 and bfloat16, at 256
+positions with 64 keys a query and blocks of 64, so that rows choose, rows do
+not, and blocks are crossed."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from kungfu_tpu.ops import sparse_attention as dsa
+
+B, S, K, BLK = 2, 256, 64, 64
+H, HKV, HD = 4, 2, 32  # the core's query heads on key/value heads
+HI, DI = 3, 16  # the indexer's heads
+
+
+def _normal(seed, *shape, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
+
+
+def _error(got, want):
+    got, want = (np.asarray(x, np.float64) for x in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+def _causal(x):
+    return jnp.where(np.tril(np.ones(x.shape[-2:], bool)), x, 0.0)
+
+
+@pytest.fixture(scope="module")
+def scores():
+    """The indexer's inputs and their scores under the kernel."""
+    qI, kI = _normal(1, B, S, HI, DI), _normal(2, B, S, DI)
+    w = 0.3 * _normal(3, B, S, HI)
+    return qI, kI, w, jax.jit(lambda *a: dsa.index_scores(
+        *a, BLK, BLK, True))(qI, kI, w)
+
+
+@pytest.fixture(scope="module")
+def chosen(scores):
+    return jax.jit(lambda I: dsa.select(I, K))(scores[3])
+
+
+def test_the_scores_are_the_sum_over_the_heads_under_the_diagonal(scores):
+    qI, kI, w, got = scores
+    want = np.einsum("btj,bjts->bts", w, np.maximum(
+        np.einsum("btjd,bsd->bjts", qI, kI), 0.0))
+    assert _error(_causal(got), _causal(want)) <= 1e-6
+    assert _error(_causal(dsa.plain_index_scores(qI, kI, w)), _causal(want)) <= 1e-6
+
+
+def test_the_scores_gradients_are_the_plain_forms(scores):
+    qI, kI, w, _ = scores
+    weight = _causal(_normal(4, B, S, S))
+
+    def through(op):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(
+            _causal(op(*a)) * weight), argnums=(0, 1, 2)))(qI, kI, w)
+
+    got = through(lambda *a: dsa.index_scores(*a, BLK, BLK, True))
+    for g, want in zip(got, through(dsa.plain_index_scores), strict=True):
+        assert g.shape == want.shape and _error(g, want) <= 1e-5
+
+
+def test_the_choice_is_the_sorted_rows_first_keys(scores, chosen):
+    """Against a stable sort of each row's causal part: row t takes its
+    min(t + 1, K) largest, so the first K rows take every earlier key and the
+    later ones choose."""
+    I, got = np.asarray(scores[3]), np.asarray(chosen)
+    assert got.dtype == np.int8 and got.shape == (B, S, S)
+    for b in range(B):
+        for t in range(0, S, 7):
+            order = np.argsort(-I[b, t, :t + 1], kind="stable")
+            want = np.zeros(S, np.int8)
+            want[order[:min(t + 1, K)]] = 1
+            np.testing.assert_array_equal(got[b, t], want, err_msg=f"{b} {t}")
+    assert (got.sum(-1) == np.minimum(np.arange(S) + 1, K)).all()
+    assert not np.triu(got, 1).any()
+
+
+def test_a_tie_goes_to_the_lower_position():
+    """Rows of a few distinct values, so that the K-th largest is shared: of
+    the keys that equal it the first are taken, as `lax.top_k` and a stable
+    sort take them; a zero of either sign is one value."""
+    rng = np.random.default_rng(5)
+    I = rng.integers(-2, 3, (1, S, S)).astype(np.float32)
+    I[0, 200] = 0.0
+    I[0, 200, ::2] = -0.0
+    I[0, 201] = np.where(np.arange(S) % 3 == 0, 1.5, -1.0)
+    got = np.asarray(jax.jit(lambda I: dsa.select(I, K))(jnp.asarray(I)))
+    for t in (63, 64, 100, 200, 201, 255):
+        order = np.argsort(-I[0, t, :t + 1], kind="stable")
+        want = np.zeros(S, np.int8)
+        want[order[:min(t + 1, K)]] = 1
+        np.testing.assert_array_equal(got[0, t], want, err_msg=str(t))
+        _, top = jax.lax.top_k(jnp.where(jnp.arange(S) <= t, I[0, t] + 0.0,
+                                         -jnp.inf), min(t + 1, K))
+        assert sorted(np.flatnonzero(got[0, t])) == sorted(np.asarray(top))
+    assert np.flatnonzero(got[0, 200]).tolist() == list(range(K))
+
+
+def _dense(q, k, v, chosen):
+    """The core as a dense softmax under the mask, float64 numpy."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    k, v = (np.repeat(x, H // HKV, axis=1) for x in (k, v))
+    s = np.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(HD)
+    s = np.where(np.asarray(chosen)[:, None] != 0, s, -np.inf)
+    a = np.exp(s - s.max(-1, keepdims=True))
+    a /= a.sum(-1, keepdims=True)
+    return np.einsum("bhqk,bhkd->bhqd", a, v), a
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_core_and_its_gradients_see_the_chosen_keys_alone(chosen, dtype, tol):
+    q, k, v = (_normal(6 + i, B, h, S, HD, dtype=dtype)
+               for i, h in enumerate((H, HKV, HKV)))
+    weight = _normal(9, B, H, S, HD)
+
+    def through(op):
+        def loss(q, k, v):
+            out, lse = op(q, k, v, chosen)
+            return jnp.sum(out.astype(jnp.float32) * weight), (out, lse)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(
+            q, k, v)
+
+    (_, (out, lse)), grads = through(
+        lambda *a: dsa.sparse_attention(*a, None, BLK, BLK, True))
+    (_, (plain_out, plain_lse)), plain = through(dsa.plain_sparse_attention)
+    want, _ = _dense(q, k, v, chosen)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    assert _error(out, want) <= tol and _error(plain_out, want) <= tol
+    assert _error(lse, plain_lse) <= 1e-5
+    for g, p in zip(grads, plain, strict=True):
+        assert g.dtype == dtype and _error(g, p) <= tol
+    # a key that is not chosen moves nothing: its value may be anything
+    unchosen = np.asarray(chosen)[0].sum(0) == 0
+    assert unchosen[:-1].any()
+    moved = v.at[0, :, np.flatnonzero(unchosen)[0]].set(1e4)
+    again, _ = jax.jit(lambda *a: dsa.sparse_attention(*a, None, BLK, BLK, True))(
+        q, k, moved, chosen)
+    np.testing.assert_array_equal(np.asarray(again[0], np.float32),
+                                  np.asarray(out[0], np.float32))
+
+
+def test_the_head_mean_is_the_mean_of_the_heads_probabilities(chosen):
+    q, k, v = (_normal(6 + i, B, h, S, HD) for i, h in enumerate((H, HKV, HKV)))
+    _, lse = dsa.plain_sparse_attention(q, k, v, chosen)
+    _, a = _dense(q, k, v, chosen)
+    p, entropy = jax.jit(lambda *args: dsa.head_mean_probs(
+        *args, None, BLK, BLK, True))(q, k, lse, chosen)
+    seen = np.asarray(chosen) != 0
+    assert _error(np.where(seen, p, 0.0), a.mean(1)) <= 1e-5
+    np.testing.assert_allclose(np.where(seen, p, 0.0).sum(-1), 1.0, rtol=1e-5)
+    mean = a.mean(1)
+    np.testing.assert_allclose(entropy, np.where(
+        seen, mean * np.log(np.where(seen, mean, 1.0)), 0.0).sum(-1), rtol=1e-4)
+    plain_p, plain_entropy = dsa.plain_head_mean_probs(q, k, lse, chosen)
+    assert _error(plain_p, a.mean(1)) <= 1e-5
+    np.testing.assert_allclose(plain_entropy, entropy, rtol=1e-4)
+    # a constant: nothing is differentiated through the target
+    grads = jax.grad(lambda q, k: jnp.sum(jnp.where(seen, dsa.head_mean_probs(
+        q, k, lse, chosen, None, BLK, BLK, True)[0], 0.0)), argnums=(0, 1))(q, k)
+    assert not any(np.asarray(g).any() for g in grads)
+
+
+def test_the_loss_is_the_kl_divergence_and_its_gradient_softmax_less_p(scores, chosen):
+    I = scores[3]
+    q, k, v = (_normal(6 + i, B, h, S, HD) for i, h in enumerate((H, HKV, HKV)))
+    _, lse = dsa.plain_sparse_attention(q, k, v, chosen)
+    p, entropy = dsa.plain_head_mean_probs(q, k, lse, chosen)
+    seen = np.asarray(chosen) != 0
+    loss, dI = jax.value_and_grad(dsa.indexer_kl)(I, chosen, p, entropy)
+    logits = np.where(seen, np.asarray(I, np.float64), -np.inf)
+    soft = np.exp(logits - logits.max(-1, keepdims=True))
+    soft /= soft.sum(-1, keepdims=True)
+    pd = np.where(seen, np.asarray(p, np.float64), 0.0)
+    kl = np.where(seen, pd * (np.log(np.where(seen, pd, 1.0))
+                              - np.log(np.where(seen, soft, 1.0))), 0.0)
+    assert float(loss) == pytest.approx(kl.sum(-1).mean(), rel=1e-5)
+    assert float(loss) > 0
+    assert _error(dI, (soft - pd) / (B * S)) <= 1e-5
+    assert not np.asarray(dI)[~seen].any()
+    # what lies outside the choice is never read: NaNs there move nothing
+    dirty = jnp.where(seen, I, jnp.nan), jnp.where(seen, p, jnp.nan)
+    again, dI_again = jax.value_and_grad(dsa.indexer_kl)(
+        dirty[0], chosen, dirty[1], entropy)
+    assert float(again) == float(loss)
+    np.testing.assert_array_equal(np.asarray(dI_again), np.asarray(dI))
