@@ -1119,11 +1119,12 @@ def _sparse_attention(h, layer, cfg: TransformerConfig, qk_scales):
     from the head-mean of the core's probabilities there, a constant. The
     cross-entropy's gradient reaches q, k, v through the chosen keys and no
     leaf of the indexer; the KL's reaches the indexer's five leaves and
-    nothing else. On the flash core's setting (`attn_core` "flash") the four
-    pieces are `ops.sparse_attention`'s kernels at `flash_blocks`, on "dense"
-    its plain forms. Scopes `attn_proj` (the four projections, with `qk_norm`
-    and `rope` inside), `dsa_index`, `dsa_select`, `attn_sparse` > `attn_core`
-    and `dsa_kl`."""
+    nothing else. On the flash core's setting (`attn_core` "flash") the five
+    pieces are `ops.sparse_attention`'s kernels (four at `flash_blocks`, the
+    choice at a block of whole rows of its own), on "dense" its plain forms.
+    Scopes `attn_proj` (the four projections, with `qk_norm` and `rope`
+    inside), `dsa_index`, `dsa_select`, `attn_sparse` > `attn_core` and
+    `dsa_kl`."""
     from kungfu_tpu.ops import sparse_attention as dsa
 
     dt = cfg.dtype
@@ -1180,12 +1181,12 @@ def _sparse_choice(h, layer, cfg: TransformerConfig):
         # Handed on through its bits, a bit a pair under the name
         # `dsa_chosen` (8.4 MB a layer of 8,192 positions): a layer that is
         # run again keeps them (`_layer_again`) and makes the scores again,
-        # which the indexer's loss reads, but not the choice, whose 45
-        # passes over the scores then run once a step and not twice
-        # (14.3 ms a layer each time: PERF.md, PR 61).
+        # which the indexer's loss reads, but not the choice, whose
+        # counting passes then run once a step and not twice.
+        chosen = (dsa.select(scores, keys, cfg.flash_interpret) if kernels
+                  else dsa.plain_select(scores, keys))
         packed = checkpoint_name(
-            jnp.packbits(dsa.select(scores, keys).astype(jnp.uint8), axis=-1),
-            "dsa_chosen")
+            jnp.packbits(chosen.astype(jnp.uint8), axis=-1), "dsa_chosen")
         return scores, jnp.unpackbits(packed, axis=-1, count=S).astype(jnp.int8)
 
 

@@ -24,9 +24,24 @@ probabilities** there. Five pieces, each with its `plain` `jax.numpy` form
   int8: 67 MB a layer at 8,192 positions (a bit a pair would be 8.4 MB and an
   unpacking in every kernel; I itself with a threshold a row 268 MB read by
   every head). Exact: the k-th largest of a row is found by a search over the
-  float's bits, 32 counting passes over I, and a tie at that value is cut at a
+  float's bits, 32 counting passes, and a tie at that value is cut at a
   position found by log2(S) more. No sort, no `lax.approx_max_k` (a different
-  choice, not a faster one). Nothing is differentiated through it.
+  choice, not a faster one). Nothing is differentiated through it. One Pallas
+  kernel (PR 62): **a block of whole rows of I comes into VMEM once** and
+  every pass runs there, where `plain_select`'s 45 passes each read I from
+  HBM (15.1 ms a layer at 8,192 positions against the kernel's 1.4: PERF.md,
+  PR 62). The block holds the rows' ordered integer form, the keys after a
+  row's own position at a number no threshold reaches; a pass compares it a
+  128-lane slice at a time with the rows' thresholds, adds the hits tile on
+  tile and sums across the lanes once a row; the passes are loops inside the
+  kernel, their turns `SELECT_SPAN` keys each and no more of them than reach
+  the block's last row's own position (the causal half is half the work);
+  the position passes run only in a block in which some row has more keys at
+  its threshold than it wants. **The block's rows** are the largest multiple
+  of 32 (an int8 tile) that divides S and whose float32 block is within
+  `SELECT_BLOCK_BYTES`, 4 MB: 128 rows at 8,192 positions (32 rows took 2.0
+  ms a layer, 64 1.6, 128 1.4); `plain_select` where no such block is or the
+  lanes do not tile S.
 - `sparse_attention(q, k, v, chosen)`: softmax(q k^T / sqrt(hd)) v over the
   chosen keys of each query, grouped heads as `flash_attention`'s. Forward, dQ
   and dK/dV are that file's kernels with the byte mask in the place of the
@@ -233,14 +248,9 @@ def _ordered(x):
     return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
 
 
-def select(I, k: int):
-    """The choice (B, S, S) int8 of scores I (B, S, S): 1 at the min(t + 1, k)
-    keys s <= t with the largest I[t, s], a tie to the lower position; 0
-    elsewhere, every s > t among them, whatever I holds there. The k-th
-    largest value of a row by bisection over the 32 bits of its ordered form
-    (the largest T with at least that many keys >= T), then, among the keys
-    that equal it, the position up to which they are taken by bisection over
-    the positions: 32 + log2(S) counting passes over I, and no sort."""
+def plain_select(I, k: int):
+    """`select` as 32 + log2(S) passes of XLA's over the whole of I: the
+    tests' oracle, the "dense" path and any shape the kernel does not take."""
     B, S, _ = I.shape
     t = lax.broadcasted_iota(jnp.int32, (1, S, S), 1)
     s = lax.broadcasted_iota(jnp.int32, (1, S, S), 2)
@@ -266,6 +276,141 @@ def select(I, k: int):
 
     P = lax.fori_loop(0, bits, position_bit, jnp.zeros((B, S, 1), jnp.int32))
     return ((above | (level & (s <= P))) & causal).astype(jnp.int8)
+
+
+_LOWEST = -(1 << 31)  # the signed ordered form no threshold of the search reaches
+LANES = 128  # of a register tile: the keys a comparison takes a row
+SELECT_BLOCK_BYTES = 4 << 20  # of a block of whole rows of the scores
+SELECT_SPAN = 1024  # keys a turn of a counting pass's loop
+
+
+def _signed_order(x):
+    """float32 -> int32 whose signed order is the floats' (-0.0 as +0.0):
+    `_ordered`'s map with the top bit turned, for a chip that compares signed
+    numbers."""
+    bits = lax.bitcast_convert_type(jnp.where(x == 0.0, 0.0, x), jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _select_kernel(i_ref, o_ref, keys_scr, *, k: int, span: int):
+    """The choice of a block of whole rows: the scores (1, rows, S) come once,
+    their ordered form stands in `keys_scr` with the keys after a row's own
+    position at the lowest number, and every counting pass of `plain_select`
+    runs over that. What is a number a row is a (rows, LANES) tile with every
+    lane a copy. Keys are taken `span` at a turn of a loop, and the turns end
+    at the span that holds the block's last row's own position: the rest of
+    the block is written as not chosen and never read."""
+    from jax.experimental import pallas as pl
+
+    rows, S = keys_scr.shape
+    row0 = pl.program_id(1) * rows
+    spans = (row0 + rows + span - 1) // span
+    t = row0 + lax.broadcasted_iota(jnp.int32, (rows, LANES), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    want = jnp.minimum(t + 1, k)
+
+    def over(n, a_slice, carry=None):
+        """`a_slice(at, carry)` for every LANES keys of the spans, `at` the
+        first one's position."""
+        def a_span(j, carry):
+            for c in range(0, span, LANES):
+                carry = a_slice(pl.multiple_of(j * span + c, LANES), carry)
+            return carry
+
+        return lax.fori_loop(0, n, a_span, carry)
+
+    def order(at, _):
+        keys_scr[:, pl.ds(at, LANES)] = jnp.where(
+            at + lane <= t, _signed_order(i_ref[0, :, pl.ds(at, LANES)]), _LOWEST)
+
+    over(spans, order)
+
+    def count(hit):
+        """How many keys of each row `hit(its ordered form, its position)`:
+        the slices' hits added tile on tile, one sum across the lanes a row."""
+        acc = over(spans, lambda at, acc: acc + hit(
+            keys_scr[:, pl.ds(at, LANES)], at + lane).astype(jnp.int32),
+            jnp.zeros((rows, LANES), jnp.int32))
+        return jnp.broadcast_to(jnp.sum(acc, axis=-1, keepdims=True), acc.shape)
+
+    def value_bit(i, found):
+        T, n_T = found
+        higher = T ^ lax.shift_right_logical(jnp.int32(_LOWEST), i)
+        n = count(lambda keys, s: keys >= higher)
+        return jnp.where(n >= want, higher, T), jnp.where(n >= want, n, n_T)
+
+    # T, and how many keys stand at or above it: every key a row sees at first
+    T, n_T = lax.fori_loop(0, 32, value_bit,
+                           (jnp.full((rows, LANES), _LOWEST, jnp.int32), t + 1))
+    bits = max(1, int(S - 1).bit_length())
+
+    def cut_the_ties():
+        short = want - count(lambda keys, s: keys > T)
+
+        def position_bit(i, P):
+            further = P | (jnp.int32(1 << (bits - 1)) >> i)
+            n = count(lambda keys, s: (keys == T) & (s < further) & (s <= t))
+            return jnp.where(n < short, further, P)
+
+        return lax.fori_loop(0, bits, position_bit,
+                             jnp.zeros((rows, LANES), jnp.int32))
+
+    # a block in which no row has more keys at its T than it wants takes them
+    # all: the position passes run where a tie is cut
+    P = lax.cond(jnp.max(n_T - want) > 0, cut_the_ties,
+                 lambda: jnp.full((rows, LANES), S, jnp.int32))
+
+    def write(at, _):
+        keys, s = keys_scr[:, pl.ds(at, LANES)], at + lane
+        o_ref[0, :, pl.ds(at, LANES)] = (
+            ((keys > T) | ((keys == T) & (s <= P))) & (s <= t)).astype(jnp.int8)
+
+    over(spans, write)
+
+    def nothing(j, _):
+        o_ref[0, :, pl.ds(pl.multiple_of(j * span, span), span)] = jnp.zeros(
+            (rows, span), jnp.int8)
+
+    lax.fori_loop(spans, S // span, nothing, None)
+
+
+def _select_rows(S: int) -> int:
+    """The rows of the kernel's block at S keys a row: the largest multiple of
+    32 (an int8 tile's rows) that divides S and whose float32 block is within
+    `SELECT_BLOCK_BYTES`; 0 where there is none or the lanes do not tile S."""
+    if S % LANES:
+        return 0
+    fit = [r for r in range(32, S + 1, 32)
+           if S % r == 0 and r * S * 4 <= SELECT_BLOCK_BYTES]
+    return max(fit, default=0)
+
+
+def select(I, k: int, interpret: bool = False):
+    """The choice (B, S, S) int8 of scores I (B, S, S): 1 at the min(t + 1, k)
+    keys s <= t with the largest I[t, s], a tie to the lower position; 0
+    elsewhere, every s > t among them, whatever I holds there. The k-th
+    largest value of a row by bisection over the 32 bits of its ordered form
+    (the largest T with at least that many keys >= T), then, among the keys
+    that equal it, the position up to which they are taken by bisection over
+    the positions: 32 + log2(S) counting passes and no sort, run by one
+    kernel over a block of whole rows that it reads once (`_select_kernel`);
+    `plain_select` where no such block tiles S."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, S, _ = I.shape
+    rows = _select_rows(S)
+    if not rows:
+        return plain_select(I, k)
+    span = SELECT_SPAN if S % SELECT_SPAN == 0 else LANES
+    block = pl.BlockSpec((1, rows, S), lambda b, r: (b, r, 0))
+    return pl.pallas_call(
+        functools.partial(_select_kernel, k=k, span=span),
+        grid=(B, S // rows), out_shape=jax.ShapeDtypeStruct((B, S, S), jnp.int8),
+        in_specs=[block], out_specs=block,
+        scratch_shapes=[pltpu.VMEM((rows, S), jnp.int32)],
+        compiler_params=_params("parallel", "parallel"),
+        interpret=interpret, name="dsa_select")(lax.stop_gradient(I))
 
 
 # -- the core over the chosen keys ------------------------------------------------

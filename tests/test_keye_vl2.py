@@ -100,6 +100,29 @@ def test_the_kernels_interpreted_are_the_plain_forms():
     assert [inside for kernel, inside in again if kernel == "dsa_core_dq"] == [True]
 
 
+def test_the_choice_lowers_as_one_kernel_under_its_scope():
+    """`attention_core` flash at positions that the kernel's blocks tile: the
+    step's program for the chip holds the call `dsa_select` under the scope
+    `dsa_select`, once (a layer run again keeps the bits), and none of the
+    plain form's counting there; on "dense" the plain form's `while`s."""
+    sample = jax.ShapeDtypeStruct((1, 129), jnp.int32)
+
+    def lowered(**changes):
+        config = tiny_config(sequence_length=128, flash_blocks=[128, 128],
+                             recomputed_layer_types=["sparse"], **changes)
+        state = jax.eval_shape(lambda: family.init(config, 0))
+        return jax.jit(jax.grad(family.loss_fn(config))).trace(
+            state, sample).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+    flash = lowered(attention_core="flash", flash_interpret=False)
+    assert flash.count('attn/dsa_select/dsa_select/pallas_call"') == 1
+    assert "dsa_select/reduce_sum" not in flash
+    assert "rematted_computation/attn/dsa_select/jit(unpackbits)" in flash
+    dense = lowered(attention_core="dense")
+    assert "dsa_select/reduce_sum" in dense and "dsa_select/pallas_call" not in dense
+    assert dense.count("stablehlo.while") > flash.count("stablehlo.while")
+
+
 def test_a_full_choice_is_the_model_without_an_index():
     """With as many keys a query as positions every query chooses every
     earlier key: loss and gradients in the main leaves are those of the same

@@ -23,6 +23,17 @@ def _no_relu(scores):
         "btj,btjd,bsd->bts", w, qI, kI, precision=jax.lax.Precision.HIGHEST)
 
 
+def _the_choice(changed):
+    """The fault that puts `changed(the plain choice)` in the place of the
+    choice, of both its forms: whichever the model under test runs."""
+    def fault(m):
+        chosen = changed(dsa.plain_select)
+        m.setattr(dsa, "plain_select", chosen)
+        m.setattr(dsa, "select", lambda scores, k, interpret=False: chosen(scores, k))
+
+    return fault
+
+
 def _chosen_above_too(select):
     """The choice over every key, later ones among them."""
     def chosen(scores, k):
@@ -75,9 +86,9 @@ FAULTS = {
     "the_weights_without_their_scale": _patched(
         "plain_index_scores", lambda scores: lambda qI, kI, w: scores(
             qI, kI, w * (qI.shape[2] * qI.shape[3]) ** 0.5)),
-    "half_as_many_keys_chosen": _patched(
-        "select", lambda select: lambda scores, k: select(scores, k // 2)),
-    "the_choice_sees_later_keys": _patched("select", _chosen_above_too),
+    "half_as_many_keys_chosen": _the_choice(
+        lambda select: lambda scores, k: select(scores, k // 2)),
+    "the_choice_sees_later_keys": _the_choice(_chosen_above_too),
     "one_heads_probabilities_for_the_mean": _patched(
         "plain_head_mean_probs", _the_first_heads_probabilities),
     "a_target_that_is_no_constant": _no_stop_gradients,

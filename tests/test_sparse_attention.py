@@ -3,7 +3,10 @@ core over the chosen keys and the head-mean of its probabilities, the kernels
 interpreted against the plain `jnp` forms and against a dense softmax under
 the mask written here: values and every gradient, float32 and bfloat16, at 256
 positions with 64 keys a query and blocks of 64, so that rows choose, rows do
-not, and blocks are crossed."""
+not, and blocks are crossed. The choice's kernel (PR 62) against its plain
+form bit for bit, at 256 to 1,024 positions."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -40,9 +43,26 @@ def scores():
         *a, BLK, BLK, True))(qI, kI, w)
 
 
+def _choice(form, I, k, rows=None, span=dsa.LANES):
+    """The choice of I by the `plain` form or by the `kernel`, interpreted,
+    its block `rows` rows (the module's own where None) and its loop's turn
+    `span` keys."""
+    if form == "plain":
+        return jax.jit(lambda I: dsa.plain_select(I, k))(I)
+    with pytest.MonkeyPatch.context() as m:
+        if rows:
+            m.setattr(dsa, "SELECT_BLOCK_BYTES", rows * I.shape[-1] * 4)
+        m.setattr(dsa, "SELECT_SPAN", span)
+        assert dsa._select_rows(I.shape[-1]) == (rows or I.shape[-1])
+        return jax.jit(lambda I: dsa.select(I, k, True))(I)
+
+
+FORMS = pytest.mark.parametrize("form", ["plain", "kernel"])
+
+
 @pytest.fixture(scope="module")
 def chosen(scores):
-    return jax.jit(lambda I: dsa.select(I, K))(scores[3])
+    return _choice("plain", scores[3], K)
 
 
 def test_the_scores_are_the_sum_over_the_heads_under_the_diagonal(scores):
@@ -66,11 +86,12 @@ def test_the_scores_gradients_are_the_plain_forms(scores):
         assert g.shape == want.shape and _error(g, want) <= 1e-5
 
 
-def test_the_choice_is_the_sorted_rows_first_keys(scores, chosen):
+@FORMS
+def test_the_choice_is_the_sorted_rows_first_keys(scores, form):
     """Against a stable sort of each row's causal part: row t takes its
     min(t + 1, K) largest, so the first K rows take every earlier key and the
     later ones choose."""
-    I, got = np.asarray(scores[3]), np.asarray(chosen)
+    I, got = np.asarray(scores[3]), np.asarray(_choice(form, scores[3], K, 64))
     assert got.dtype == np.int8 and got.shape == (B, S, S)
     for b in range(B):
         for t in range(0, S, 7):
@@ -82,7 +103,8 @@ def test_the_choice_is_the_sorted_rows_first_keys(scores, chosen):
     assert not np.triu(got, 1).any()
 
 
-def test_a_tie_goes_to_the_lower_position():
+@FORMS
+def test_a_tie_goes_to_the_lower_position(form):
     """Rows of a few distinct values, so that the K-th largest is shared: of
     the keys that equal it the first are taken, as `lax.top_k` and a stable
     sort take them; a zero of either sign is one value."""
@@ -91,7 +113,7 @@ def test_a_tie_goes_to_the_lower_position():
     I[0, 200] = 0.0
     I[0, 200, ::2] = -0.0
     I[0, 201] = np.where(np.arange(S) % 3 == 0, 1.5, -1.0)
-    got = np.asarray(jax.jit(lambda I: dsa.select(I, K))(jnp.asarray(I)))
+    got = np.asarray(_choice(form, jnp.asarray(I), K, 32))
     for t in (63, 64, 100, 200, 201, 255):
         order = np.argsort(-I[0, t, :t + 1], kind="stable")
         want = np.zeros(S, np.int8)
@@ -101,6 +123,81 @@ def test_a_tie_goes_to_the_lower_position():
                                          -jnp.inf), min(t + 1, K))
         assert sorted(np.flatnonzero(got[0, t])) == sorted(np.asarray(top))
     assert np.flatnonzero(got[0, 200]).tolist() == list(range(K))
+
+
+def _scores_of(kind, S, rng):
+    """(S, S) scores of one `kind`, what lies above the diagonal as a block
+    that `index_scores` never wrote may hold it."""
+    if kind == "random":
+        return rng.standard_normal((S, S), np.float32)
+    if kind == "few_values":  # ties at the k-th largest in every row
+        return rng.integers(-2, 3, (S, S)).astype(np.float32)
+    if kind == "zeros_of_both_signs":
+        I = np.where(rng.random((S, S)) < 0.5, 0.0, -0.0).astype(np.float32)
+        return np.where(rng.random((S, S)) < 0.2, rng.standard_normal(
+            (S, S), np.float32), I)
+    assert kind == "nan_and_inf_above"
+    I = rng.standard_normal((S, S), np.float32)
+    above = np.triu(np.ones((S, S), bool), 1)
+    return np.where(above, np.where(rng.random((S, S)) < 0.5, np.nan, np.inf), I)
+
+
+# (S, k, the block's rows, the loop's span) -> the kinds of scores in a batch
+SHAPES = {
+    (512, 100, 64, 256): ("random", "few_values", "zeros_of_both_signs",
+                          "nan_and_inf_above"),
+    (1024, 192, 32, 512): ("random", "few_values"),
+    (256, 300, None, 128): ("random", "few_values"),  # k >= S, one row block
+}
+
+
+@functools.cache
+def _both_forms(shape):
+    """(scores, the kernel's choice, the plain form's) of a shape's batch."""
+    S, k, rows, span = shape
+    rng = np.random.default_rng(S + k)
+    I = jnp.asarray(np.stack([_scores_of(kind, S, rng) for kind in SHAPES[shape]]))
+    return (np.asarray(I), np.asarray(_choice("kernel", I, k, rows, span)),
+            np.asarray(_choice("plain", I, k)))
+
+
+@pytest.mark.parametrize("shape,kind", [
+    pytest.param(shape, kind, id=f"{kind}-{shape[0]}-{shape[1]}")
+    for shape, kinds in SHAPES.items() for kind in kinds])
+def test_the_kernels_choice_is_the_plain_forms_bit_for_bit(shape, kind):
+    """Every byte, whatever the scores: ties at the k-th largest (the
+    position passes run), zeros of both signs, NaN and +inf where nothing
+    was written, k past S (every row takes all it sees), one row block and
+    many, blocks whose counting stops before the row's end."""
+    S, k = shape[:2]
+    I, got, want = (x[SHAPES[shape].index(kind)] for x in _both_forms(shape))
+    assert got.dtype == np.int8 and got.shape == (S, S)
+    np.testing.assert_array_equal(got, want)
+    assert (got.sum(-1) == np.minimum(np.arange(S) + 1, k)).all()
+    assert not np.triu(got, 1).any()
+    if kind == "few_values":
+        ties = [t for t in range(S) if (np.sort(I[t, :t + 1])[::-1][:k][-1]
+                                        == I[t, :t + 1]).sum() > 1]
+        assert len(ties) > S // 2  # the k-th largest is shared: a tie is cut
+
+
+def test_a_row_block_in_which_no_row_chooses_takes_every_earlier_key():
+    """Rows 0 to 63 of 512 at 100 keys a query are one block of the kernel
+    and see no more than 64 keys each: the block is the causal triangle."""
+    got = _both_forms((512, 100, 64, 256))[1]
+    np.testing.assert_array_equal(
+        got[:, :64], np.broadcast_to(np.tril(np.ones((64, 512), np.int8)),
+                                     (got.shape[0], 64, 512)))
+
+
+def test_the_plain_form_where_no_block_of_rows_tiles_the_scores():
+    """96 positions are no whole lane tile, and 36,864 are more than a block
+    of 32 rows may hold: `select` is `plain_select` there."""
+    assert dsa._select_rows(96) == 0 and dsa._select_rows(36864) == 0
+    assert dsa._select_rows(8192) == 128 and dsa._select_rows(384) == 384
+    I = _normal(6, 1, 96, 96)
+    np.testing.assert_array_equal(jax.jit(lambda I: dsa.select(I, 16))(I),
+                                  _choice("plain", I, 16))
 
 
 def _dense(q, k, v, chosen):
