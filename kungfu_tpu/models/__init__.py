@@ -5,7 +5,8 @@ tests/go/fakemodel size lists); here the models double as benchmark
 workloads and as sharding showcases:
 - mlp: MNIST SLP (the reference's minimum end-to-end example)
 - transformer: flagship decoder-only LM with an explicit TP/DP/SP
-  sharding plan (BERT-config capable)
+  sharding plan (BERT-config capable); a layer's token mixer is a record
+  of `mixers/`, and what mixers and layer share is `blocks.py`
 - resnet: ResNet-50 (the headline throughput benchmark)
 - fake: gradient-size lists for communication benchmarks without real math
   (parity: tests/go/fakemodel/fakemodel.go)

@@ -1,0 +1,121 @@
+"""Latent attention as a layer's token mixer (`mixer="latent"`: DeepSeek-V2's
+MLA as GLM-4.7-Flash has it). q through a normed latent, keys and values
+through another, one rotary key shared by every head beside each head's
+unrotated features; `latent_dims` = (q latent rank, key/value latent rank,
+unrotated features a q/k head, rotated features a q/k head, features a value
+head). k and v are laid out a head for the core every other attention layer
+runs. Leaves `w_q_down`, `q_latent_norm`, `w_q_up`, `w_kv_down`,
+`kv_latent_norm`, `w_kv_up`, `wo`. It keeps no packed documents apart.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from kungfu_tpu.models.blocks import (_layer_keys, _mixer_input, _rmsnorm,
+                                      _rope, _scale, attention_core_of)
+
+
+def check(cfg):
+    if not (len(cfg.latent_dims) == 5 and min(cfg.latent_dims) >= 1
+            and cfg.latent_dims[3] % 2 == 0):
+        raise ValueError("mixer 'latent' needs latent_dims = (q rank, "
+                         "key/value rank, unrotated, rotated (even), "
+                         f"value features a head), got {cfg.latent_dims}")
+    if cfg.positions != "rope":
+        raise ValueError("mixer 'latent' turns its rotated features "
+                         "by positions 'rope'")
+    _, _, nope, rope, value = cfg.latent_dims
+    if int((nope + rope) * (rope / (nope + rope))) != rope:
+        raise ValueError(
+            f"{rope} rotated of {nope + rope} features is a share "
+            "that the rotary pass (`_rope`) rounds down")
+    if cfg.attn_core == "flash" and nope + rope != value:
+        raise ValueError(
+            f"the flash core has one head size: q/k heads of {nope} + "
+            f"{rope} and value heads of {value} need the dense core")
+
+
+def init(key, cfg, dense, unit):
+    """The four projections from [0] to [3] of the split of fold 3, which
+    the router's selection bias draws from too, wo from [1] of the layer's
+    first split. The numbers are fixed because the states of the cells are.
+    The published layout: W_q_up's columns a head at a time, its unrotated
+    features and then its rotated; W_kv_down's the latent and then the one
+    rotated key; W_kv_up's a head at a time, its unrotated key features and
+    then its value."""
+    D, H = cfg.d_model, cfg.n_heads
+    rq, rkv, nope, rope, value = cfg.latent_dims
+    mk = jax.random.split(jax.random.fold_in(key, 3), 5)
+    return dict(
+        w_q_down=dense(mk[0], (D, rq)),
+        q_latent_norm=unit(cfg, (rq,)),
+        w_q_up=dense(mk[1], (rq, H * (nope + rope))),
+        w_kv_down=dense(mk[2], (D, rkv + rope)),
+        kv_latent_norm=unit(cfg, (rkv,)),
+        w_kv_up=dense(mk[3], (rkv, H * (nope + value))),
+        wo=dense(_layer_keys(key, cfg)[1], (H * value, D)))
+
+
+def pspecs(cfg, t):
+    """The up-projections are a head at a time and column-parallel, wo
+    row-parallel; the down-projections (the one rotary key's columns among
+    them) and the latents' norms whole."""
+    return dict(w_q_down=P(None, None, None), q_latent_norm=P(None, None),
+                w_q_up=P(None, None, t), w_kv_down=P(None, None, None),
+                kv_latent_norm=P(None, None), w_kv_up=P(None, None, t),
+                wo=P(None, t, None))
+
+
+def apply(x, layer, cfg, core, segments, marks):
+    return _latent_attention(_mixer_input(x, layer, cfg), layer, cfg,
+                             core=core), None
+
+
+def _latent_attention(h, layer, cfg, core=None):
+    """Latent attention (MLA) on normed hidden states h (B, S, D) -> (B, S,
+    D). c_q = norm(h W_q_down) and a head's [q_nope | q_rope] = c_q W_q_up;
+    [c_kv | k_r] = h W_kv_down, c_kv normed, and a head's [k_nope | v] =
+    c_kv W_kv_up; q = [q_nope | rot(q_rope)] and every head's k = [its
+    k_nope | rot(k_r)], the one rotated key of all heads; the causal core
+    the configuration names over heads of nope + rope features, at the scale
+    1 / sqrt(nope + rope); W_o. Training lays k and v out a head, as the
+    published implementations do (absorbing W_kv_up into q is a decode
+    device). Inside, a head's rotated features stand first: the same
+    permutation of q's and k's features, made on W_q_up's columns and where
+    k is put together, leaves every q . k as it is, and puts the rotated
+    features where the one rotary pass that also lays a projection's output
+    out a head expects them (`blocks._turned`). Scopes `mla_down`,
+    `mla_norm`, `mla_up` (the up-projections and what lays k out a head),
+    `rope`, `attn_latent` > `attn_core`."""
+    dt, eps = cfg.dtype, cfg.norm_eps
+    rq, rkv, nope, rope, value = cfg.latent_dims
+    H, hd = cfg.n_heads, nope + rope
+    B, S, _ = h.shape
+    with jax.named_scope("mla_down"):
+        c_q = h @ layer["w_q_down"].astype(dt)
+        c_kv = h @ layer["w_kv_down"].astype(dt)
+        c_kv, k_r = c_kv[..., :rkv], c_kv[..., rkv:]
+    with jax.named_scope("mla_norm"):
+        c_q = _rmsnorm(c_q, _scale(layer["q_latent_norm"], cfg), eps)
+        c_kv = _rmsnorm(c_kv, _scale(layer["kv_latent_norm"], cfg), eps)
+    with jax.named_scope("mla_up"):
+        w_q = layer["w_q_up"].astype(dt).reshape(rq, H, hd)
+        w_q = jnp.concatenate([w_q[..., nope:], w_q[..., :nope]], axis=-1)
+        q = c_q @ w_q.reshape(rq, H * hd)
+        w_kv = layer["w_kv_up"].astype(dt).reshape(rkv, H, nope + value)
+        k_nope = c_kv @ w_kv[..., :nope].reshape(rkv, H * nope)
+        v = c_kv @ w_kv[..., nope:].reshape(rkv, H * value)
+        k = jnp.concatenate(
+            [jnp.broadcast_to(k_r[:, :, None, :], (B, S, H, rope)),
+             k_nope.reshape(B, S, H, nope)], axis=-1)
+        v = v.reshape(B, S, H, value).transpose(0, 2, 1, 3)
+    with jax.named_scope("rope"):
+        q, k = _rope(q.reshape(B, S, H, hd).transpose(0, 2, 1, 3),
+                     k.transpose(0, 2, 1, 3), cfg.rope_theta, rope / hd, ())
+    with jax.named_scope("attn_latent"), jax.named_scope("attn_core"):
+        ctx = (core or attention_core_of(cfg))(q, k, v)
+    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * value)
+    return ctx @ layer["wo"].astype(dt)

@@ -9,108 +9,60 @@ TPU-first design notes:
   these annotations — nothing is hand-scheduled.
 - Compute in bfloat16 (MXU native), params and optimizer state in f32.
 - Static shapes everywhere; layers are stacked and scanned-friendly.
-- The layer is data (ROADMAP D1): `TransformerConfig` says where positions
-  come from (a learned table or rotary), whether q and k are normed, what
-  the feed-forward is (gelu, gated silu, or routed experts through
-  `ops.moe.moe_ffn`), whether the head is tied, and which attention core
-  runs (XLA's dense one or `ops.flash_attention`). The defaults are the
-  block the repo has always had, so `bert_base()` and `tiny()` mean what
-  they meant; `olmoe_1b_7b()` is the first published architecture.
-- Layers may differ in kind (PR 33): `layer_kinds` gives each layer the
-  fields that replace the configuration's own for it (heads, window, rotary
-  rule, feed-forward), successive layers of one kind are one stacked tree
-  and one `lax.scan`, and `params["layers"]` is then the tuple of those
-  stacks in the model's layer order. Grouped heads with a head size of
-  their own, a band mask, a per-head output gate, rotary over part of the
-  head with YaRN's frequencies, renormalised and scaled expert gates, a
-  shared expert, and an expert layer that holds a share of the experts its
-  router sees are each a field.
-- A layer's token mixer is a kind too (PR 36): `mixer` is softmax attention
-  or the gated delta rule (`ops.gated_delta`: a fused q, k, v, z projection,
-  a causal depthwise convolution, a linear recurrence with a matrix state a
-  head, a gated norm), and layers of both mixers stand in one stack of
-  `layer_kinds`. Norms with the scale 1 + w, q/k norms a head, a gate a
-  feature from a q projection of twice the width and a sigmoid gate on the
-  shared expert are each a field.
-- Latent attention is the third mixer (PR 41, DeepSeek-V2's MLA as
-  GLM-4.7-Flash has it): q through a normed latent, keys and values through
-  another, one rotary key shared by every head beside each head's unrotated
-  features (`latent_dims`, `_latent_attention`); k and v are laid out a head
-  for the core every other layer runs. The router's scores are a softmax
-  over the experts or a sigmoid an expert (`router_scores`), and a selection
-  bias an expert may move the choice and never the weight (`router_bias`,
-  `ops.moe.route`). A multi-token-prediction module (`mtp_depth` 1: two
-  norms, a (2D, D) projection, one further block, a final norm of its own)
-  predicts the token after the next on the shared embedding and head, and
-  `transformer_loss` is then main loss + `mtp_weight` x MTP loss from a
-  batch of S + 2 ids.
-- A layer may be one residual branch alone (PR 43, Nemotron-H's): `mixer`
-  "none" is a layer that is a feed-forward behind its one norm, `ffn`
-  "none" a layer that is a mixer behind its one, and such a layer has that
-  branch's norm leaf and no other. The Mamba-2 mixer is the fourth
-  (`mixer="mamba2"`, `ssm_dims`, `_mamba2_mixer`): one fused projection to
-  [z | x B C | dt], a causal depthwise convolution with a bias, a selective
-  step Delta = softplus(dt + dt_bias) and the diagonal state-space
-  recurrence H_t = exp(Delta_t A) H_{t-1} + Delta_t x_t B_t^T, y_t = H_t C_t
-  + D x_t (`ops.ssm_scan`, the chunked scan's second rule), a norm over
-  groups of features behind the gate silu(z), W_out. `positions` "none" adds
-  no position signal anywhere, and `expert_act` "relu2" makes the routed
-  experts and the shared expert two matrices, W_down (relu(W_up x))^2.
-- The stacks may be run more than once a forward pass (PR 48, Ouro's looped
-  model): `loop_steps` T > 1 makes the layer scans the body of an outer scan
-  of T iterations over the one set of weights, the model's final norm at the
-  end of every loop step and the normed state what the next one reads
-  (`_hidden`, scope `loop_norm`); a shared leaf's gradient is the sum over
-  its T uses, so under S-SGD on several chips the stacks are averaged whole
-  and once, after the backward pass, and not a layer an iteration. The loss
-  is then the expected cross-entropy over T head passes on the shared head
-  under an exit distribution from a gate on the normed states
-  (`exit_gate_w`, `exit_gate_b`), less `exit_entropy_coef` times that
-  distribution's entropy (`_loop_losses`, scope `exit_gate`); each head
-  pass is run again in the backward pass (`_loop_step_rows`), and
-  `transformer_apply` gives the last loop step's logits. `post_norms` puts
-  a second RMSNorm behind each branch of a layer, on the branch's output
-  (`ln1_post_scale`, `ln2_post_scale`, scope `post_norm`).
-- Four multipliers (PR 52, Granite 4.0's): the embedding's rows times
+- The layer is data (ROADMAP D1): `TransformerConfig` says what a layer is,
+  and the defaults are the block the repo has always had, so `bert_base()`
+  and `tiny()` mean what they meant. A layer is a token mixer and a
+  feed-forward, each a residual branch behind its own norm, or one of the two
+  alone (`mixer` "none", `ffn` "none": that branch's norm leaf and no other).
+- The token mixer is one record of `models/mixers/` (`mixer_of(cfg)`): its
+  refusals, leaves, shardings and function live in its module there
+  (softmax attention and its learned sparse index, latent attention, the
+  gated delta rule, Mamba-2, the gated short convolution), and this file asks
+  the record wherever the mixer matters: `__post_init__`, `init_transformer`,
+  `param_pspecs`, `_layer`, `_block`, `_hidden`. What the mixers and the
+  layer share (the norm, the rotary pass, the cores, the checkpoint) is
+  `models/blocks.py`, below both.
+- What stays here: the configuration; the state and its shardings; the layer
+  (`_layer`) with its feed-forward (gelu, gated silu, or routed experts
+  through `ops.moe.moe_ffn` over a share of the experts the router sees, with
+  a shared expert, `_expert_layer`), its second norms (`post_norms`) and the
+  residual's multiplier; the stacks (`_hidden`); the heads and losses; the
+  stats beside the step and their recorders; the ring path.
+- Layers may differ in kind: `layer_kinds` gives each layer the fields that
+  replace the configuration's own for it (mixer, heads, window, rotary rule,
+  feed-forward), successive layers of one kind are one stacked tree and one
+  `lax.scan`, and `params["layers"]` is then the tuple of those stacks in
+  the model's layer order.
+- The stacks may be run more than once a forward pass (Ouro's looped model):
+  `loop_steps` T > 1 makes the layer scans the body of an outer scan of T
+  iterations over the one set of weights, the model's final norm at the end
+  of every loop step and the normed state what the next one reads (`_hidden`,
+  scope `loop_norm`); a shared leaf's gradient is the sum over its T uses, so
+  under S-SGD on several chips the stacks are averaged whole and once, after
+  the backward pass, and not a layer an iteration. The loss is then the
+  expected cross-entropy over T head passes on the shared head under an exit
+  distribution from a gate on the normed states (`exit_gate_w`,
+  `exit_gate_b`), less `exit_entropy_coef` times that distribution's entropy
+  (`_loop_losses`, scope `exit_gate`); each head pass is run again in the
+  backward pass (`_loop_step_rows`), and `transformer_apply` gives the last
+  loop step's logits.
+- A multi-token-prediction module (`mtp_depth` 1: two norms, a (2D, D)
+  projection, one further block, a final norm of its own) predicts the token
+  after the next on the shared embedding and head, and `transformer_loss` is
+  then main loss + `mtp_weight` x MTP loss from a batch of S + 2 ids. The
+  routers' losses and a sparse index's (`LayerAux`) stand beside it.
+- Four multipliers (Granite 4.0's): the embedding's rows times
   `embedding_multiplier`, a branch's output times `residual_multiplier`
   where the residual takes it, the attention scores times
   `attention_multiplier` in the place of 1 / sqrt(head size), the logits over
-  `logits_scaling`; each 1 (or unset) leaves the program as it was. A Mamba-2
-  mixer may have a feed-forward behind it in one layer, and a tied head may
-  stand behind a state-space stack.
-- A row may be several documents (PR 52): `end_of_document` names the id that
-  ends one, and the ids are the only carrier. `_segments` numbers each
-  position's document (scope `segments`), and the numbers go to every mixer
-  of the stack, through the layer scans and `_layer_again` alike: a
-  convolution tap does not reach into an earlier document
-  (`ops.gated_delta.causal_conv`; `ops.ssm_conv`, whose kernels read each
-  position's depth into its document, made beside the numbers once a step,
-  `ops.ssm_conv.document_marks`), the scan's state is zero before a
-  document's first position (`ops.ssm_scan`), and a query sees the keys of
-  its own document (`ops.flash_attention`). The loss is over every position.
-  `packing_stats` says what a batch is made of. Without the id every program
-  is what it was.
-- The gated short convolution is the fifth mixer (PR 57, LFM2's operator):
-  `mixer` "short_conv" is a layer whose mixer is [B | C | x] = h W_in (three
-  equal thirds of 3 D columns), a causal depthwise convolution of `conv_taps`
-  taps over B * x, the gate C on its output and W_out (`_short_conv_mixer`,
-  `ops.short_conv`: the two gates and the taps in one kernel each way): no
-  recurrence, no softmax, no activation, no bias, no norm of its own and no
-  state in training. Its leaves are `conv_in`, `conv_w` and `conv_out`; packed
-  rows' segments go to it as to every mixer. Layers of it stand in one stack
-  of `layer_kinds` beside attention, dense and expert layers (`lfm2_24b_a2b`).
-- Learned sparse attention (PR 61, DeepSeek Sparse Attention on grouped heads,
-  `keye_vl_2_0_30b_a3b`): `sparse_index` = (indexer heads, indexer head size,
-  keys a query) gives an attention layer a lightning indexer (leaves
-  `index_wq`, `index_wk`, `index_w`, `index_ln_scale`, `index_ln_bias`) on the
-  layer's normed input with its gradient stopped, the choice of each query's
-  best-scored keys at or before it, the softmax core over the chosen keys
-  alone and the indexer's own loss, the KL divergence of its distribution from
-  the head-mean of the core's probabilities (`_sparse_attention`,
-  `ops.sparse_attention`). The cross-entropy reaches no leaf of the indexer
-  and the indexer's loss no other leaf; the layers' sum of it, times
-  `indexer_loss_weight`, is in `transformer_loss` beside the routers' losses
-  (`LayerAux`). `()` is every earlier configuration's program, text for text.
+  `logits_scaling`; each 1 (or unset) leaves the program as it was.
+- A row may be several documents: `end_of_document` names the id that ends
+  one, and the ids are the only carrier. `_segments` numbers each position's
+  document (scope `segments`), and the numbers go to every mixer of the
+  stack, through the layer scans and `_layer_again` alike, with what a
+  mixer's record wants made of them once a step (`document_marks`). The loss
+  is over every position; `packing_stats` says what a batch is made of.
+  Without the id every program is what it was.
 
 The reference has no model code (KungFu is model-agnostic); this model is
 the framework's flagship workload for the BERT-config benchmark
@@ -119,18 +71,19 @@ the framework's flagship workload for the BERT-config benchmark
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
-import math
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from kungfu_tpu.models.blocks import (_layer_keys, _mixer_input, _recompute,
+                                      _rmsnorm, _scale)
+from kungfu_tpu.models.mixers import MIXERS, mixer_of
+from kungfu_tpu.models.mixers.attention import _sparse_choice
 from kungfu_tpu.ops import collective
 
 
@@ -259,8 +212,7 @@ class TransformerConfig:
                 ("expert_act", self.expert_act, ("swiglu", "relu2")),
                 ("attn_core", self.attn_core, ("dense", "flash")),
                 ("gates", self.gates, ("raw", "renorm")),
-                ("mixer", self.mixer, ("attention", "gated_delta", "latent",
-                                       "mamba2", "short_conv", "none")),
+                ("mixer", self.mixer, (*MIXERS, "none")),
                 ("router_scores", self.router_scores, ("softmax", "sigmoid"))):
             if value not in known:
                 raise ValueError(f"{field} {value!r} is not one of {known}")
@@ -273,48 +225,12 @@ class TransformerConfig:
         if self.q_gate and not self.split_qkv:
             raise ValueError("q_gate doubles wq, which a layer has with a head "
                              "size or key/value heads of its own (`split_qkv`)")
-        if self.mixer == "gated_delta" and not (
-                len(self.delta_heads) == 3 and self.delta_heads[0] >= 1
-                and self.delta_heads[1] % self.delta_heads[0] == 0):
-            raise ValueError("mixer 'gated_delta' needs delta_heads = (key "
-                             "heads, value heads a multiple of them, head "
-                             f"size), got {self.delta_heads}")
         if self.mixer == "none" and self.ffn == "none":
             raise ValueError("a layer is a mixer, a feed-forward or both: "
                              "mixer 'none' with ffn 'none' is no layer")
-        if self.mixer == "mamba2" and not (
-                len(self.ssm_dims) == 4 and min(self.ssm_dims) >= 1
-                and self.ssm_dims[0] % self.ssm_dims[3] == 0):
-            raise ValueError("mixer 'mamba2' needs ssm_dims = (heads, head "
-                             "size, state size, groups that divide the "
-                             f"heads), got {self.ssm_dims}")
-        if self.mixer == "latent":
-            if not (len(self.latent_dims) == 5 and min(self.latent_dims) >= 1
-                    and self.latent_dims[3] % 2 == 0):
-                raise ValueError("mixer 'latent' needs latent_dims = (q rank, "
-                                 "key/value rank, unrotated, rotated (even), "
-                                 f"value features a head), got {self.latent_dims}")
-            if self.positions != "rope":
-                raise ValueError("mixer 'latent' turns its rotated features "
-                                 "by positions 'rope'")
-            _, _, nope, rope, value = self.latent_dims
-            if int((nope + rope) * (rope / (nope + rope))) != rope:
-                raise ValueError(
-                    f"{rope} rotated of {nope + rope} features is a share "
-                    "that the rotary pass (`_rope`) rounds down")
-            if self.attn_core == "flash" and nope + rope != value:
-                raise ValueError(
-                    f"the flash core has one head size: q/k heads of {nope} + "
-                    f"{rope} and value heads of {value} need the dense core")
-        if self.mixer == "short_conv":
-            if self.conv_taps != 3:
-                raise ValueError("mixer 'short_conv' convolves over LFM2's 3 "
-                                 f"taps, got conv_taps {self.conv_taps}")
-            if self.loop_steps > 1 or self.mtp_depth:
-                raise ValueError("mixer 'short_conv' is built for the plain "
-                                 "stack: not under a loop (loop_steps > 1) nor "
-                                 "in a model with a multi-token-prediction "
-                                 "module, which no test holds it to")
+        mixer = mixer_of(self)
+        if mixer is not None:  # the mixer's own refusals
+            mixer.check(self)
         if self.mtp_depth not in (0, 1):
             raise ValueError(f"mtp_depth {self.mtp_depth}: one multi-token-"
                              "prediction module or none")
@@ -331,17 +247,14 @@ class TransformerConfig:
             raise ValueError("a loop (loop_steps > 1) has no place for an "
                              "expert layer's losses and counters a loop step, "
                              "nor for a multi-token-prediction module")
-        if self.sparse_index:
-            self._check_sparse_index()
         if (self.window or self.kv_heads != self.n_heads
                 or self.attention_multiplier) and self.attn_core != "flash" \
                 and not self.sparse_index:
             raise ValueError("a window, grouped heads and a scale of the "
                              "scores' own are the flash core's (attn_core "
                              "'flash'); the dense core has none of them")
-        if self.end_of_document is not None and (
-                self.mtp_depth or self.mixer in ("gated_delta", "latent")
-                or (self.mixer == "attention" and self.attn_core != "flash")):
+        if self.end_of_document is not None and (self.mtp_depth or not (
+                mixer is None or mixer.keeps_documents_apart(self))):
             raise ValueError(
                 "packed documents (end_of_document) are kept apart by the "
                 "Mamba-2 mixer, the short convolution and the flash core of "
@@ -353,41 +266,6 @@ class TransformerConfig:
                              f"{self.n_layers} layers")
         for kind in self.layer_kinds:
             dataclasses.replace(self, layer_kinds=(), n_layers=1, **dict(kind))
-
-    def _check_sparse_index(self):
-        """What a learned sparse index stands with, each refusal a sentence."""
-        if not (len(self.sparse_index) == 3 and min(self.sparse_index) >= 1
-                and self.sparse_index[1] % 2 == 0):
-            raise ValueError("sparse_index is (indexer heads, an even indexer "
-                             "head size, keys a query), got "
-                             f"{self.sparse_index}")
-        if self.mixer != "attention" or not self.split_qkv or (
-                self.positions != "rope"):
-            raise ValueError("sparse_index chooses the keys of softmax "
-                             "attention with projections of its own (a head "
-                             "size or key/value heads) and rotary positions, "
-                             f"not of mixer {self.mixer!r} with positions "
-                             f"{self.positions!r}")
-        for field, what, unset in (
-                ("window", "a window beside the choice", 0),
-                ("end_of_document", "packed documents under the choice", None),
-                ("mtp_depth", "a multi-token-prediction module", 0),
-                ("layer_kinds", "layers that differ in kind", ()),
-                ("head_gate", "a gate a head", False),
-                ("q_gate", "a gate a feature", False),
-                ("attention_multiplier", "a scale of the scores' own", 0.0),
-                ("yarn", "YaRN's frequencies", ())):
-            if getattr(self, field) != unset:
-                raise ValueError(
-                    f"sparse_index is not built with {what} ({field}): the "
-                    "choice is made under the causal bound alone, in a stack "
-                    "of one kind of layer, and no test holds it to more")
-        if self.loop_steps > 1 or self.rotary_share != 1.0:
-            raise ValueError("sparse_index is not built under a loop "
-                             "(loop_steps > 1), which has no place for the "
-                             "indexer's loss a loop step, nor with a rotary "
-                             "share of the head (rotary_share)")
-
     @property
     def head_dim(self) -> int:
         if self.head_size:
@@ -499,114 +377,26 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
         return (jnp.zeros if cfg.norm_offset else jnp.ones)(shape, jnp.float32)
 
     def init_layer(key, cfg):
-        # the gelu block draws what it always drew from four keys; the
-        # other feed-forwards take further keys of a split of their own,
-        # what PR 33 brought those of a second split, and the gated delta
-        # mixer and the shared expert's gate (PR 36) those of a third
+        # The mixer's leaves are its record's. The feed-forward's are drawn
+        # from the layer's first split ([2] on), the shared expert's from [3]
+        # to [5] of the split of fold 1, its gate from [5] of fold 2's and the
+        # router's bias from [4] of fold 3's: the first keys of each of those
+        # splits are a mixer's, and the numbers are fixed because the states
+        # of the cells are.
         F, E = cfg.d_ff, cfg.n_experts
-        lk = jax.random.split(key, 4 if cfg.ffn == "gelu" else 6)
-        if cfg.split_qkv or cfg.head_gate or cfg.shared_ff:
-            xk = jax.random.split(jax.random.fold_in(key, 1), 6)
-        if cfg.mixer == "gated_delta" or cfg.shared_gate:
-            gk = jax.random.split(jax.random.fold_in(key, 2), 6)
-        if cfg.mixer == "latent" or cfg.router_bias:  # PR 41's, a fourth
-            mk = jax.random.split(jax.random.fold_in(key, 3), 5)
-        # a layer of one branch has that branch's norm alone (PR 43)
+        lk = _layer_keys(key, cfg)
+        mixer = mixer_of(cfg)
+        # a layer of one branch has that branch's norm alone
         layer = {}
-        if cfg.mixer != "none":
+        if mixer is not None:
             layer["ln1_scale"] = unit(cfg, (D,))
         if cfg.ffn != "none":
             layer["ln2_scale"] = unit(cfg, (D,))
         if cfg.post_norms:  # one behind each branch the layer has
             for pre in list(layer):
                 layer[pre.replace("_scale", "_post_scale")] = unit(cfg, (D,))
-        if cfg.mixer == "none":
-            pass
-        elif cfg.mixer == "mamba2":
-            H, hp, N, G = cfg.ssm_dims
-            K, conv = cfg.conv_taps, H * hp + 2 * G * N
-            sk = jax.random.split(jax.random.fold_in(key, 4), 5)  # a fifth
-            # Mamba-2's own start (its `time_step_min`, `_max`, `_floor` and
-            # `A_init_range`): A uniform on [1, 16], the step log-uniform on
-            # [0.001, 0.1] and at least 1e-4, dt_bias its inverse softplus, D
-            # 1; taps and bias as a depthwise Conv1d's default, uniform within
-            # 1 / sqrt(K)
-            dt = jnp.maximum(jnp.exp(jax.random.uniform(
-                sk[3], (H,), jnp.float32, math.log(0.001), math.log(0.1))), 1e-4)
-            layer.update(
-                w_ssm_in=dense(sk[0], (D, H * hp + conv + H)),
-                conv_w=jax.random.uniform(sk[1], (K, conv), jnp.float32,
-                                          -K ** -0.5, K ** -0.5),
-                conv_b=jax.random.uniform(sk[2], (conv,), jnp.float32,
-                                          -K ** -0.5, K ** -0.5),
-                A_log=jnp.log(jax.random.uniform(sk[4], (H,), jnp.float32,
-                                                 1.0, 16.0)),
-                dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
-                D_skip=jnp.ones((H,), jnp.float32),
-                ssm_norm_scale=jnp.ones((H * hp,), jnp.float32),
-                wo=dense(lk[1], (H * hp, D)))
-        elif cfg.mixer == "short_conv":
-            ck = jax.random.split(jax.random.fold_in(key, 5), 3)  # a sixth
-            layer.update(conv_in=dense(ck[0], (D, 3 * D)),
-                         conv_w=dense(ck[1], (cfg.conv_taps, D)),
-                         conv_out=dense(ck[2], (D, D)))
-        elif cfg.mixer == "gated_delta":
-            Hk, Hv, d = cfg.delta_heads
-            K = cfg.conv_taps
-            # the decay's parameters as the Gated DeltaNet reference
-            # implementation draws them (Mamba2's): A uniform in (0, 16),
-            # dt log-uniform in (0.001, 0.1) and dt_bias its inverse
-            # softplus, so g = -A softplus(a + dt_bias) is about -A dt at
-            # the start, from a memory of a thousand positions to one of
-            # less than one, head by head; the taps as a depthwise Conv1d's
-            # default, uniform within 1 / sqrt(K)
-            dt = jnp.exp(jax.random.uniform(gk[4], (Hv,), jnp.float32,
-                                            math.log(0.001), math.log(0.1)))
-            layer.update(
-                w_qkvz=dense(gk[0], (D, 2 * (Hk + Hv) * d)),
-                w_ba=dense(gk[1], (D, 2 * Hv)),
-                conv_w=jax.random.uniform(gk[2], (K, (2 * Hk + Hv) * d),
-                                          jnp.float32, -K ** -0.5, K ** -0.5),
-                A_log=jnp.log(jax.random.uniform(gk[3], (Hv,), jnp.float32,
-                                                 1e-3, 16.0)),
-                dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
-                gdn_norm_scale=jnp.ones((d,), jnp.float32),
-                wo=dense(lk[1], (Hv * d, D)))
-        elif cfg.mixer == "latent":
-            # the published layout: W_q_up's columns a head at a time, its
-            # unrotated features and then its rotated; W_kv_down's the
-            # latent and then the one rotated key; W_kv_up's a head at a
-            # time, its unrotated key features and then its value
-            rq, rkv, nope, rope, value = cfg.latent_dims
-            H = cfg.n_heads
-            layer.update(
-                w_q_down=dense(mk[0], (D, rq)),
-                q_latent_norm=unit(cfg, (rq,)),
-                w_q_up=dense(mk[1], (rq, H * (nope + rope))),
-                w_kv_down=dense(mk[2], (D, rkv + rope)),
-                kv_latent_norm=unit(cfg, (rkv,)),
-                w_kv_up=dense(mk[3], (rkv, H * (nope + value))),
-                wo=dense(lk[1], (H * value, D)))
-        elif cfg.split_qkv:
-            q_width, kv_width = (h * cfg.head_dim
-                                 for h in (cfg.n_heads, cfg.kv_heads))
-            layer["wq"] = dense(lk[0], (D, q_width * (2 if cfg.q_gate else 1)))
-            layer["wk"] = dense(xk[0], (D, kv_width))
-            layer["wv"] = dense(xk[1], (D, kv_width))
-            layer["wo"] = dense(lk[1], (q_width, D))
-        else:
-            layer["wqkv"] = dense(lk[0], (D, 3 * D))
-            layer["wo"] = dense(lk[1], (D, D))
-        if cfg.head_gate and cfg.mixer == "attention":
-            layer["w_head_gate"] = dense(xk[2], (D, cfg.n_heads))
-        if cfg.sparse_index:  # the lightning indexer's five, from a seventh
-            Hi, di, _ = cfg.sparse_index
-            ik = jax.random.split(jax.random.fold_in(key, 6), 3)
-            layer.update(index_wq=dense(ik[0], (D, Hi * di)),
-                         index_wk=dense(ik[1], (D, di)),
-                         index_w=dense(ik[2], (D, Hi)),
-                         index_ln_scale=jnp.ones((di,), jnp.float32),
-                         index_ln_bias=jnp.zeros((di,), jnp.float32))
+        if mixer is not None:
+            layer.update(mixer.init(key, cfg, dense, unit))
         gated = cfg.ffn == "swiglu" or cfg.expert_act == "swiglu"
         if cfg.ffn == "gelu":
             layer["w_in"] = dense(lk[2], (D, F))
@@ -625,18 +415,17 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
                 # what gradients update): drawn small against the spread of
                 # the scores, so that choice and weight differ
                 layer["router_bias"] = 0.01 * jax.random.normal(
-                    mk[4], (E,), jnp.float32)
+                    jax.random.split(jax.random.fold_in(key, 3), 5)[4],
+                    (E,), jnp.float32)
             if cfg.shared_ff:
+                xk = jax.random.split(jax.random.fold_in(key, 1), 6)
                 if gated:
                     layer["shared_gate"] = dense(xk[3], (D, cfg.shared_ff))
                 layer["shared_up"] = dense(xk[4], (D, cfg.shared_ff))
                 layer["shared_down"] = dense(xk[5], (cfg.shared_ff, D))
             if cfg.shared_gate:
-                layer["w_shared_gate"] = dense(gk[5], (D, 1))
-        if cfg.qk_norm and cfg.mixer == "attention":
-            width = cfg.head_dim if cfg.split_qkv else D
-            layer["q_norm_scale"] = unit(cfg, (width,))
-            layer["k_norm_scale"] = unit(cfg, (width,))
+                layer["w_shared_gate"] = dense(
+                    jax.random.split(jax.random.fold_in(key, 2), 6)[5], (D, 1))
         return layer
 
     # stack layers: leading axis = layer, enables lax.scan over layers; a
@@ -676,80 +465,32 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     """PartitionSpec tree matching init_transformer's param tree, whatever
     the layer is.
 
-    Column-parallel wqkv (or wq, wk, wv and the head gate)/w_in/w_gate/w_up
-    (shard output features over tp), row-parallel wo/w_out/w_down (shard
-    input features over tp), a shared expert like a gated-silu
-    feed-forward (two matrices each under `expert_act` "relu2"); a layer of
-    one branch has that branch's leaves alone; a Mamba-2 mixer's fused
-    projection, taps, bias and gated norm are column-parallel, its numbers a
-    head whole; a short-convolution mixer's `conv_in` and taps column-parallel
-    and its `conv_out` row-parallel; embedding and an untied head sharded over vocab; an
-    expert stack over `ep_axis` on its expert dimension, the router whole.
-    Layer-stacked leaves have a leading layer axis (unsharded); a
-    configuration with `layer_kinds` has a tuple of such stacks. The q/k
-    norms' scales span all of q's features, which tp splits: sharded like
-    them; a head's own (split projections) are whole. A gated delta mixer's
-    fused projection and taps are column-parallel. A latent mixer's
-    up-projections are a head at a time and column-parallel, its
-    down-projections (the one rotary key's columns among them) and the
-    latents' norms whole. A multi-token-prediction module's block is
-    sharded like a layer of its kind, its norms and projection whole. A
-    lightning indexer's five leaves (`sparse_index`) are whole: every shard of
-    the heads attends under the one choice.
+    A mixer's leaves as its record says (`mixers.mixer_of(cfg).pspecs`:
+    column-parallel projections, a row-parallel wo). Column-parallel
+    w_in/w_gate/w_up (shard output features over tp), row-parallel
+    w_out/w_down (shard input features over tp), a shared expert like a
+    gated-silu feed-forward (two matrices each under `expert_act` "relu2");
+    a layer of one branch has that branch's leaves alone; embedding and an
+    untied head sharded over vocab; an expert stack over `ep_axis` on its
+    expert dimension, the router whole. Layer-stacked leaves have a leading
+    layer axis (unsharded); a configuration with `layer_kinds` has a tuple of
+    such stacks. A multi-token-prediction module's block is sharded like a
+    layer of its kind, its norms and projection whole.
     """
     t, e = tp_axis, ep_axis
 
     def stack_specs(cfg):
+        mixer = mixer_of(cfg)
         layers = {}
-        if cfg.mixer != "none":
+        if mixer is not None:
             layers.update(ln1_scale=P(None))
-            if cfg.mixer != "short_conv":
-                layers.update(wo=P(None, t, None))
         if cfg.ffn != "none":
             layers.update(ln2_scale=P(None))
         if cfg.post_norms:
             layers.update({name.replace("_scale", "_post_scale"): P(None)
-                           for name in list(layers) if name.startswith("ln")})
-        if cfg.mixer == "none":
-            pass
-        elif cfg.mixer == "mamba2":
-            # the fused projection's, the convolution's and the gated norm's
-            # channels over tp like any column-parallel matrix's; a number a
-            # head whole
-            layers.update(w_ssm_in=P(None, None, t), conv_w=P(None, None, t),
-                          conv_b=P(None, t), A_log=P(None, None),
-                          dt_bias=P(None, None), D_skip=P(None, None),
-                          ssm_norm_scale=P(None, t))
-        elif cfg.mixer == "short_conv":
-            # the projection's columns over tp like any column-parallel
-            # matrix's (a shard holds a slice of B, C and x each, the
-            # partitioner's affair), the taps with the channels, W_out's rows
-            layers.update(conv_in=P(None, None, t), conv_w=P(None, None, t),
-                          conv_out=P(None, t, None))
-        elif cfg.mixer == "gated_delta":
-            # the fused projection's and the convolution's channels over tp
-            # like any column-parallel matrix; a number a head and the
-            # norm's scale whole
-            layers.update(w_qkvz=P(None, None, t), w_ba=P(None, None, None),
-                          conv_w=P(None, None, t), A_log=P(None, None),
-                          dt_bias=P(None, None), gdn_norm_scale=P(None, None))
-        elif cfg.mixer == "latent":
-            layers.update(w_q_down=P(None, None, None), q_latent_norm=P(None, None),
-                          w_q_up=P(None, None, t),
-                          w_kv_down=P(None, None, None),
-                          kv_latent_norm=P(None, None),
-                          w_kv_up=P(None, None, t))
-        elif cfg.split_qkv:  # heads over tp, as wqkv's columns are
-            layers.update(wq=P(None, None, t), wk=P(None, None, t),
-                          wv=P(None, None, t))
-        else:
-            layers.update(wqkv=P(None, None, t))
-        if cfg.head_gate and cfg.mixer == "attention":
-            layers.update(w_head_gate=P(None, None, t))
-        if cfg.sparse_index:  # the indexer whole on every chip: one choice
-            layers.update(index_wq=P(None, None, None), index_wk=P(None, None, None),
-                          index_w=P(None, None, None), index_ln_scale=P(None, None),
-                          index_ln_bias=P(None, None))
+                           for name in list(layers)})
+        if mixer is not None:
+            layers.update(mixer.pspecs(cfg, t))
         if cfg.ffn == "gelu":
             layers.update(w_in=P(None, None, t), w_out=P(None, t, None))
         elif cfg.ffn == "swiglu":
@@ -770,10 +511,6 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
                     layers.update(shared_gate=P(None, None, t))
             if cfg.shared_gate:
                 layers.update(w_shared_gate=P(None, None, None))
-        if cfg.qk_norm and cfg.mixer == "attention":
-            # over all of q's features, which tp splits, or over one head's
-            spec = P(None, None) if cfg.split_qkv else P(None, t)
-            layers.update(q_norm_scale=spec, k_norm_scale=spec)
         return layers
 
     stacks = tuple(stack_specs(kind) for kind, _ in cfg.stacks)
@@ -795,48 +532,6 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     return specs
 
 
-# What the backward pass keeps (PERF.md, PR 25). The layer scan stacks every
-# residual of its body once a layer, so each piece below whose residuals are
-# cheap functions of something smaller that is saved anyway says so itself
-# with `jax.checkpoint`: it keeps its inputs and recomputes the rest where
-# the backward pass wants it. One HBM byte costs the v5e 240 operations, so
-# an S x S probability array (12 bytes an element, written and read) is
-# worth 2,900 operations against the 128 of a second QK^T, at every length.
-# `prevent_cse=False`: inside a scan body the barrier is unnecessary and
-# costs fusions.
-_recompute = functools.partial(jax.checkpoint, prevent_cse=False)
-
-
-def _rmsnorm(x, scale, eps=1e-6):
-    """Keeps x and scale; the f32 upcast, the variance and the normalised
-    output are recomputed. `eps` is data of the configuration, not of the
-    program: a Python number."""
-    return _rmsnorm_at(x, scale, eps)
-
-
-@functools.partial(_recompute, static_argnums=(2,))
-def _rmsnorm_at(x, scale, eps):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
-
-
-@_recompute
-def _full_attention_core(q, k, v):
-    """(B, H, S, hd) q/k/v -> causal attention context, same shape.
-
-    Keeps q, k, v; scores, mask, the f32 softmax and its cast are
-    recomputed. The checkpoint is this core's own, not `_attention`'s or
-    `_block`'s: a core plugged from outside (the ring, flash attention's
-    `custom_vjp`) keeps its own residuals and is never run twice."""
-    hd = q.shape[-1]
-    S = q.shape[2]
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(hd).astype(q.dtype)
-    mask = jnp.tril(jnp.ones((S, S), bool))
-    scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-
-
 @_recompute
 def _gelu_out(pre, w_out):
     """gelu(pre) @ w_out. Keeps the pre-activation and w_out; the
@@ -844,98 +539,6 @@ def _gelu_out(pre, w_out):
     recomputed. Saving the gelu's output for that matmul instead was 0.15
     ms a step slower at bert_base's size and 0.6 GB larger (PERF.md, PR 25)."""
     return jax.nn.gelu(pre) @ w_out
-
-
-def _yarn_ramp(rd: int, theta: float, yarn: Tuple):
-    """YaRN's blend (arXiv:2309.00071, as the transformers library's
-    `_compute_yarn_parameters` computes it): 0 for the rd // 2 frequencies
-    that turn more than beta_fast times over the original positions and
-    keep their own frequency, 1 for those that turn fewer than beta_slow
-    times and take theirs over `factor`, linear between."""
-    _, original, beta_fast, beta_slow, _ = yarn
-
-    def dim_of(turns):
-        return (rd * math.log(original / (turns * 2 * math.pi))
-                / (2 * math.log(theta)))
-
-    low = max(math.floor(dim_of(beta_fast)), 0)
-    high = min(math.ceil(dim_of(beta_slow)), rd - 1)
-    span = (high - low) or 0.001
-    return jnp.clip((jnp.arange(rd // 2, dtype=jnp.float32) - low) / span, 0, 1)
-
-
-def _rotary_tables(S: int, hd: int, theta: float, share: float, yarn: Tuple):
-    """(S, hd) float32 cos and sin of positions 0..S-1 for the rotate-half
-    form over the leading `share` of the head: the rd // 2 frequencies on
-    both halves of the rotated features, under `yarn` its blended
-    frequencies and its attention factor on both tables, and cos 1, sin 0
-    on the features that pass through. Traced `jnp` of static shapes: made
-    again inside the program wherever a pass wants them."""
-    rd = int(hd * share)  # the rotated features
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
-    if yarn:
-        ramp = _yarn_ramp(rd, theta, yarn)
-        inv_freq = inv_freq / yarn[0] * ramp + inv_freq * (1 - ramp)
-    angles = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(angles), jnp.sin(angles)  # (S, rd // 2)
-    if yarn:
-        cos, sin = cos * yarn[4], sin * yarn[4]
-    through = jnp.ones((S, hd - rd), jnp.float32)
-    return (jnp.concatenate([cos, cos, through], axis=-1),
-            jnp.concatenate([sin, sin, jnp.zeros_like(through)], axis=-1))
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _turned(t, rule: Tuple, back: bool):
-    """One pass over (B, H, S, hd) t (`ops.rotary.rotate`): cos * t + sin *
-    P t, P the signed swap of the two halves of the rotated features, or,
-    `back`, its transpose cos * t - sin * P t on a cotangent; the sign of P
-    rides in the sine table. The kernel reads t as (B, S, H * hd) and writes
-    (B, H, S, hd), and the other way about on the way back: the transposition
-    below undoes the caller's own, so a projection's output goes to the
-    attention core through this one pass. Mosaic where the program is
-    lowered for the TPU, the same kernel interpreted anywhere else. Under a
-    `jax.jit` of its own, so that a step's calls of one shape trace and
-    lower one body: the Laguna cell's first step is 2 s shorter warm and 9 s
-    cold for it on the chip's host (PERF.md, PR 35)."""
-    from kungfu_tpu.ops.rotary import rotate
-
-    theta, share, yarn = rule
-    B, H, S, hd = t.shape
-    half = int(hd * share) // 2
-    cos, sin = _rotary_tables(S, hd, theta, share, yarn)
-    sin = jnp.where((jnp.arange(hd) < half) != back, -sin, sin)
-    if not back:
-        t = t.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
-    kernel = functools.partial(rotate, half=half, into_heads=not back)
-    out = jax.lax.platform_dependent(
-        t, cos, sin, tpu=kernel,
-        default=functools.partial(kernel, interpret=True))
-    return out.reshape(B, S, H, hd).transpose(0, 2, 1, 3) if back else out
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _rotated(t, rule: Tuple):
-    """The rotation is linear in t and its transpose is the same pass with
-    the sine's sign turned, so the backward pass needs nothing of t: no
-    residual, and no transposed slices (pads) and concatenations (slices
-    and adds) of q's size in float32, which is what autodiff writes for the
-    rotate-half form (33.7 ms of the Laguna cell's step, PERF.md, PR 35)."""
-    return _turned(t, rule, False)
-
-
-_rotated.defvjp(lambda t, rule: (_turned(t, rule, False), None),
-                lambda rule, _, dy: (_turned(dy, rule, True),))
-
-
-def _rope(q, k, theta: float, share: float, yarn: Tuple):
-    """Rotary positions on (B, H, S, hd) q and k (each its own H),
-    positions 0..S-1: the rotate-half form over the leading `share` of the
-    head dimension (the rest passes through), angles and the rotation in
-    float32, under `yarn` its frequencies and attention factor. Keeps
-    nothing for the backward pass."""
-    rule = (theta, share, yarn)
-    return _rotated(q, rule), _rotated(k, rule)
 
 
 @_recompute
@@ -952,126 +555,6 @@ def _relu2_out(up, w_down):
     return jnp.square(jax.nn.relu(up)) @ w_down
 
 
-def attention_core_of(cfg: TransformerConfig):
-    """The (q, k, v) -> ctx core the configuration names."""
-    if cfg.attn_core == "dense":
-        return _full_attention_core
-    from kungfu_tpu.ops.flash_attention import flash_attention
-
-    blk_q, blk_k = cfg.flash_blocks
-    return lambda q, k, v, *segments: flash_attention(
-        q, k, v, True, cfg.attention_multiplier or None, blk_q, blk_k,
-        cfg.flash_interpret, cfg.window or None, *segments)
-
-
-def _gated_out(ctx, pre, wo):
-    """(ctx (B, H, S, hd) times sigmoid(pre (B, S, H)), a scalar a head and
-    position, the sigmoid in float32) as (B, S, H * hd) @ wo. Under its
-    checkpoint (`_gated_out_kept`) it keeps ctx, which the core keeps
-    anyway, pre and wo; the gated copy of ctx, the matmul's operand, is made
-    again, as `_gelu_out` makes its gelu again."""
-    B, H, S, hd = ctx.shape
-    gate = jax.nn.sigmoid(pre.astype(jnp.float32)).astype(ctx.dtype)
-    ctx = ctx * gate.transpose(0, 2, 1)[..., None]
-    return ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd) @ wo
-
-
-def _split_heads(x, wqkv, cfg, qk_scales=None):
-    """x @ (wq, wk, wv) as (B, heads, S, hd) q, k, v with rotary positions,
-    and the (B, S, H, hd) gate that a doubled wq carries behind each head's
-    q (`q_gate`; None without). q and k are normed a head where the
-    configuration says so (`qk_scales`). Without a norm the backward pass
-    wants x and the matrices and nothing else: the rotation keeps nothing,
-    so there is no checkpoint to say so."""
-    B, S, _ = x.shape
-    hd = cfg.head_dim
-
-    def heads(t):
-        return t.transpose(0, 2, 1, 3)
-
-    gate = None
-    if cfg.q_gate:
-        q = (x @ wqkv[0]).reshape(B, S, -1, 2 * hd)
-        q, gate = q[..., :hd], q[..., hd:]
-    else:
-        q = (x @ wqkv[0]).reshape(B, S, -1, hd)
-    if not cfg.qk_norm:
-        q = heads(q)
-    k = (x @ wqkv[1]).reshape(B, S, -1, hd)
-    if cfg.qk_norm:
-        with jax.named_scope("qk_norm"):
-            q = heads(_rmsnorm(q, qk_scales[0], cfg.norm_eps))
-            k = _rmsnorm(k, qk_scales[1], cfg.norm_eps)
-    k = heads(k)
-    v = heads((x @ wqkv[2]).reshape(B, S, -1, hd))
-    if cfg.positions == "rope":
-        with jax.named_scope("rope"):
-            q, k = _rope(q, k, cfg.rope_theta, cfg.rotary_share, cfg.yarn)
-    return q, k, v, gate
-
-
-def _feature_gated_out(ctx, gate, wo):
-    """(ctx (B, H, S, hd) times sigmoid(gate (B, S, H, hd)), one a feature,
-    the sigmoid in float32) as (B, S, H * hd) @ wo. Under its checkpoint
-    (`_feature_gated_out_kept`) it keeps ctx, gate and wo and makes the
-    gated copy again, as `_gated_out` does."""
-    B, H, S, hd = ctx.shape
-    ctx = ctx.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
-        gate.astype(jnp.float32)).astype(ctx.dtype)
-    return ctx.reshape(B, S, H * hd) @ wo
-
-
-# in a layer that is run again whole (`layer_remat`) the piece as it is: a
-# checkpoint inside would run it a third time
-_gated_out_kept = _recompute(_gated_out)
-_feature_gated_out_kept = _recompute(_feature_gated_out)
-
-
-def _attention(x, wqkv, wo, cfg: TransformerConfig, core=None, qk_scales=None,
-               w_head_gate=None, segments=()):
-    """QKV projection + head reshape around a pluggable (q,k,v)->ctx core
-    (the configuration's by default, the ring core for sequence parallelism
-    — ONE copy of the projection plumbing for every path). `wqkv` is the
-    fused (D, 3D) matrix, or (wq, wk, wv) where q's width and k's, v's are
-    the configuration's own (`split_qkv`), wq twice as wide where it carries
-    a gate a feature (`q_gate`). `qk_scales` = (q_norm_scale, k_norm_scale)
-    where the configuration norms q and k, over all of their features or,
-    with split projections, a head; `w_head_gate` (D, H) where it gates each
-    head's output; `segments`, (the documents' numbers,) of packed rows, go
-    to the core."""
-    B, S, _ = x.shape
-    H, hd = cfg.n_heads, cfg.head_dim
-    gate = None
-    if cfg.split_qkv:
-        q, k, v, gate = _split_heads(x, wqkv, cfg, qk_scales)
-    else:
-        qkv = x @ wqkv  # (B, S, 3D)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        if cfg.qk_norm:
-            with jax.named_scope("qk_norm"):
-                q = _rmsnorm(q, qk_scales[0], cfg.norm_eps)
-                k = _rmsnorm(k, qk_scales[1], cfg.norm_eps)
-        q = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
-        if cfg.positions == "rope":
-            with jax.named_scope("rope"):
-                q, k = _rope(q, k, cfg.rope_theta, cfg.rotary_share, cfg.yarn)
-    with _core_kind_scope(cfg), jax.named_scope("attn_core"):
-        ctx = (core or attention_core_of(cfg))(q, k, v, *segments)
-    if cfg.head_gate:
-        with jax.named_scope("attn_gate"):
-            gated_out = _gated_out if cfg.layer_remat else _gated_out_kept
-            return gated_out(ctx, x @ w_head_gate, wo)
-    if gate is not None:
-        with jax.named_scope("attn_gate"):
-            gated_out = (_feature_gated_out if cfg.layer_remat
-                         else _feature_gated_out_kept)
-            return gated_out(ctx, gate, wo)
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
-    return ctx @ wo
-
-
 class LayerAux(NamedTuple):
     """What a layer with a learned sparse index (`sparse_index`) hands the
     loss beside its hidden states: `moe`, the expert layer's `ops.moe.MoeAux`
@@ -1086,353 +569,6 @@ def _aux_parts(aux):
     """-> (the expert layers' aux or None, the indexers' KL losses a layer or
     None) of what a layer scan stacked."""
     return tuple(aux) if isinstance(aux, LayerAux) else (aux, None)
-
-
-def _layer_norm(x, scale, bias, eps):
-    """LayerNorm over the last axis, float32: (x - mean) / sqrt(var + eps) *
-    scale + bias."""
-    x = x - jnp.mean(x, axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-                              + eps) * scale + bias)
-
-
-def _rotate_half(t, cos, sin):
-    """t cos + rotate_half(t) sin over the last axis, cos and sin of its
-    width: the plain form, for the indexer's small float32 arrays."""
-    half = t.shape[-1] // 2
-    return t * cos + jnp.concatenate([-t[..., half:], t[..., :half]], -1) * sin
-
-
-def _sparse_attention(h, layer, cfg: TransformerConfig, qk_scales):
-    """Learned sparse attention on normed hidden states h (B, S, D) -> (the
-    mixer's output (B, S, D), the indexer's KL loss, a scalar). q, k, v as
-    every attention layer's (`_split_heads`: the norm a head, the rotary
-    pass). The lightning indexer reads h with its gradient stopped, in
-    float32 at the highest precision, as a router does (a rounded score moves
-    the last chosen key as a rounded router moves the last chosen expert): qI
-    = h W_qI as (S, Hi, di), kI = LN(h W_kI), both rotated over all di
-    features at `rope_theta`, w = h W_w / sqrt(Hi di), I[t, s] = sum_j w[t, j]
-    relu(qI[t, j] . kI[s]). Each query's `sparse_index[2]` best-scored keys
-    at or before it are its choice (all of them where it has no more), one
-    choice for all heads; the softmax core runs over the chosen keys; the
-    indexer's loss is the KL divergence of softmax over the chosen keys of I
-    from the head-mean of the core's probabilities there, a constant. The
-    cross-entropy's gradient reaches q, k, v through the chosen keys and no
-    leaf of the indexer; the KL's reaches the indexer's five leaves and
-    nothing else. On the flash core's setting (`attn_core` "flash") the five
-    pieces are `ops.sparse_attention`'s kernels (four at `flash_blocks`, the
-    choice at a block of whole rows of its own), on "dense" its plain forms.
-    Scopes `attn_proj` (the four projections, with `qk_norm` and `rope`
-    inside), `dsa_index`, `dsa_select`, `attn_sparse` > `attn_core` and
-    `dsa_kl`."""
-    from kungfu_tpu.ops import sparse_attention as dsa
-
-    dt = cfg.dtype
-    B, S, _ = h.shape
-    kernels = cfg.attn_core == "flash"
-    how = (*cfg.flash_blocks, cfg.flash_interpret)
-    with jax.named_scope("attn_proj"):
-        q, k, v, _ = _split_heads(
-            h, tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv")), cfg,
-            qk_scales)
-    scores, chosen = _sparse_choice(h, layer, cfg)
-    with jax.named_scope("attn_sparse"), jax.named_scope("attn_core"):
-        ctx, lse = (dsa.sparse_attention(q, k, v, chosen, None, *how) if kernels
-                    else dsa.plain_sparse_attention(q, k, v, chosen))
-    with jax.named_scope("dsa_kl"):
-        p, entropy = (dsa.head_mean_probs(q, k, lse, chosen, None, *how)
-                      if kernels else
-                      dsa.plain_head_mean_probs(q, k, lse, chosen))
-        kl = dsa.indexer_kl(scores, chosen, p, entropy)
-    with jax.named_scope("attn_proj"):
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, -1)
-        return ctx @ layer["wo"].astype(dt), kl
-
-
-def _sparse_choice(h, layer, cfg: TransformerConfig):
-    """The lightning indexer on normed hidden states h (B, S, D) -> (its
-    scores I (B, S, S) float32, defined at s <= t, and the choice (B, S, S)
-    int8): `_sparse_attention`'s first half, scopes `dsa_index` and
-    `dsa_select`."""
-    from kungfu_tpu.ops import sparse_attention as dsa
-
-    f32 = jnp.float32
-    B, S, _ = h.shape
-    Hi, di, keys = cfg.sparse_index
-    kernels = cfg.attn_core == "flash"
-    how = (*cfg.flash_blocks, cfg.flash_interpret)
-    with jax.named_scope("dsa_index"):
-        ub = jax.lax.stop_gradient(h).astype(f32)
-
-        def projected(w):
-            return jnp.dot(ub, layer[w].astype(f32),
-                           precision=jax.lax.Precision.HIGHEST)
-
-        cos, sin = _rotary_tables(S, di, cfg.rope_theta, 1.0, ())
-        qI = _rotate_half(projected("index_wq").reshape(B, S, Hi, di),
-                          cos[:, None], sin[:, None])
-        kI = _rotate_half(_layer_norm(
-            projected("index_wk"), layer["index_ln_scale"],
-            layer["index_ln_bias"], cfg.norm_eps), cos, sin)
-        w = projected("index_w") * (Hi ** -0.5 * di ** -0.5)
-        scores = (dsa.index_scores(qI, kI, w, *how) if kernels
-                  else dsa.plain_index_scores(qI, kI, w))
-    with jax.named_scope("dsa_select"):
-        # Handed on through its bits, a bit a pair under the name
-        # `dsa_chosen` (8.4 MB a layer of 8,192 positions): a layer that is
-        # run again keeps them (`_layer_again`) and makes the scores again,
-        # which the indexer's loss reads, but not the choice, whose
-        # counting passes then run once a step and not twice.
-        chosen = (dsa.select(scores, keys, cfg.flash_interpret) if kernels
-                  else dsa.plain_select(scores, keys))
-        packed = checkpoint_name(
-            jnp.packbits(chosen.astype(jnp.uint8), axis=-1), "dsa_chosen")
-        return scores, jnp.unpackbits(packed, axis=-1, count=S).astype(jnp.int8)
-
-
-def _latent_attention(h, layer, cfg: TransformerConfig, core=None):
-    """Latent attention (MLA) on normed hidden states h (B, S, D) -> (B, S,
-    D). c_q = norm(h W_q_down) and a head's [q_nope | q_rope] = c_q W_q_up;
-    [c_kv | k_r] = h W_kv_down, c_kv normed, and a head's [k_nope | v] =
-    c_kv W_kv_up; q = [q_nope | rot(q_rope)] and every head's k = [its
-    k_nope | rot(k_r)], the one rotated key of all heads; the causal core
-    the configuration names over heads of nope + rope features, at the scale
-    1 / sqrt(nope + rope); W_o. Training lays k and v out a head, as the
-    published implementations do (absorbing W_kv_up into q is a decode
-    device). Inside, a head's rotated features stand first: the same
-    permutation of q's and k's features, made on W_q_up's columns and where
-    k is put together, leaves every q . k as it is, and puts the rotated
-    features where the one rotary pass that also lays a projection's output
-    out a head expects them (`_turned`). Scopes `mla_down`, `mla_norm`,
-    `mla_up` (the up-projections and what lays k out a head), `rope`,
-    `attn_latent` > `attn_core`."""
-    dt, eps = cfg.dtype, cfg.norm_eps
-    rq, rkv, nope, rope, value = cfg.latent_dims
-    H, hd = cfg.n_heads, nope + rope
-    B, S, _ = h.shape
-    with jax.named_scope("mla_down"):
-        c_q = h @ layer["w_q_down"].astype(dt)
-        c_kv = h @ layer["w_kv_down"].astype(dt)
-        c_kv, k_r = c_kv[..., :rkv], c_kv[..., rkv:]
-    with jax.named_scope("mla_norm"):
-        c_q = _rmsnorm(c_q, _scale(layer["q_latent_norm"], cfg), eps)
-        c_kv = _rmsnorm(c_kv, _scale(layer["kv_latent_norm"], cfg), eps)
-    with jax.named_scope("mla_up"):
-        w_q = layer["w_q_up"].astype(dt).reshape(rq, H, hd)
-        w_q = jnp.concatenate([w_q[..., nope:], w_q[..., :nope]], axis=-1)
-        q = c_q @ w_q.reshape(rq, H * hd)
-        w_kv = layer["w_kv_up"].astype(dt).reshape(rkv, H, nope + value)
-        k_nope = c_kv @ w_kv[..., :nope].reshape(rkv, H * nope)
-        v = c_kv @ w_kv[..., nope:].reshape(rkv, H * value)
-        k = jnp.concatenate(
-            [jnp.broadcast_to(k_r[:, :, None, :], (B, S, H, rope)),
-             k_nope.reshape(B, S, H, nope)], axis=-1)
-        v = v.reshape(B, S, H, value).transpose(0, 2, 1, 3)
-    with jax.named_scope("rope"):
-        q, k = _rope(q.reshape(B, S, H, hd).transpose(0, 2, 1, 3),
-                     k.transpose(0, 2, 1, 3), cfg.rope_theta, rope / hd, ())
-    with jax.named_scope("attn_latent"), jax.named_scope("attn_core"):
-        ctx = (core or attention_core_of(cfg))(q, k, v)
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * value)
-    return ctx @ layer["wo"].astype(dt)
-
-
-def _core_kind_scope(cfg: TransformerConfig):
-    """`attn_window` or `attn_full` around the core where a model has both
-    kinds of layer to tell apart (a window anywhere in it, or split
-    projections); nothing more around the one core every other
-    configuration runs."""
-    if cfg.window:
-        return jax.named_scope("attn_window")
-    if cfg.split_qkv:
-        return jax.named_scope("attn_full")
-    return contextlib.nullcontext()
-
-
-def _l2_normed(t, scale: float, dtype):
-    """t / sqrt(|t|^2 + 1e-6) * scale over the last axis, in float32."""
-    t = t.astype(jnp.float32)
-    norm = jax.lax.rsqrt(jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-    return (t * (norm * scale)).astype(dtype)
-
-
-def _gated_norm(o, scale, z, eps):
-    """rms(o) * scale * silu(z) over the last axis (a head), in float32, the
-    result in o's type. No checkpoint of its own, nor `_l2_normed`: the
-    block of heads they stand in is run again whole (`_delta_heads`)."""
-    o32 = o.astype(jnp.float32)
-    var = jnp.mean(jnp.square(o32), axis=-1, keepdims=True)
-    y = o32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
-    return (y * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype)
-
-
-# value heads a block of the gated delta mixer (`_gated_delta_mixer`)
-DELTA_HEAD_BLOCK = 8
-
-
-@functools.partial(_recompute, static_argnums=(3,))
-def _delta_heads(h, part, norm_scale, cfg: TransformerConfig):
-    """A block of the Gated DeltaNet mixer's key heads with their value
-    heads, from normed hidden states h (B, S, D) to the block's part of the
-    mixer's output (B, S, D); `part` = the block's columns of W_qkvz, W_ba
-    and the taps, its A_log and dt_bias, its rows of W_o. Keeps its
-    arguments and runs again in the backward pass."""
-    from kungfu_tpu.ops.gated_delta import causal_conv, gated_delta_rule
-
-    w_qkvz, w_ba, conv_w, A_log, dt_bias, wo = part
-    dt, f32 = cfg.dtype, jnp.float32
-    B, S, _ = h.shape
-    d = cfg.delta_heads[2]
-    r = cfg.delta_heads[1] // cfg.delta_heads[0]
-    kb = A_log.shape[0] // r  # key heads in this block
-    with jax.named_scope("gdn_proj"):
-        qkvz = (h @ w_qkvz.astype(dt)).reshape(B, S, kb, (2 + 2 * r) * d)
-        ba = jnp.dot(h.astype(f32), w_ba.astype(f32),
-                     precision=jax.lax.Precision.HIGHEST).reshape(B, S, kb, 2 * r)
-        b, a = (t.reshape(B, S, kb * r).transpose(0, 2, 1)
-                for t in (ba[..., :r], ba[..., r:]))
-        beta = jax.nn.sigmoid(b)
-        g = -jnp.exp(A_log.astype(f32))[:, None] * jax.nn.softplus(
-            a + dt_bias.astype(f32)[:, None])
-    with jax.named_scope("gdn_conv"):
-        qkv = qkvz[..., :(2 + r) * d].reshape(B, S, kb * (2 + r) * d)
-        qkv = jax.nn.silu(causal_conv(qkv, conv_w)).reshape(B, S, kb, (2 + r) * d)
-        q = _l2_normed(qkv[..., :d], d ** -0.5, dt).transpose(0, 2, 1, 3)
-        k = _l2_normed(qkv[..., d:2 * d], 1.0, dt).transpose(0, 2, 1, 3)
-        v = qkv[..., 2 * d:].reshape(B, S, kb * r, d).transpose(0, 2, 1, 3)
-        if r > 1:  # value head j reads key head j // r
-            q, k = jnp.repeat(q, r, axis=1), jnp.repeat(k, r, axis=1)
-    with jax.named_scope("gdn_core"):
-        o = gated_delta_rule(q, k, v, g, beta)
-    with jax.named_scope("gdn_norm"):
-        z = qkvz[..., (2 + r) * d:].reshape(B, S, kb * r, d)
-        y = _gated_norm(o.transpose(0, 2, 1, 3), norm_scale, z, cfg.norm_eps)
-    with jax.named_scope("gdn_proj"):
-        return y.reshape(B, S, kb * r * d) @ wo.astype(dt)
-
-
-def _gated_delta_mixer(h, layer, cfg: TransformerConfig):
-    """The Gated DeltaNet mixer on normed hidden states h (B, S, D), Hk key
-    heads and Hv = r Hk value heads of one size d. W_qkvz's columns lie a
-    key head at a time, as the published layout has them: its q, its k, its
-    r value heads' v and their z; W_ba's likewise, its r b and r a; the
-    taps' its q, k and v channels. [q | k | v] go through the causal
-    convolution and a silu; q and k are normalised a head (q over sqrt(d)
-    besides); beta = sigmoid(b) and the log decay g = -exp(A_log)
-    softplus(a + dt_bias), a number a value head and position, are float32
-    from a float32 projection as the router's is; the gated delta rule
-    (`ops.gated_delta`); an RMSNorm a head times silu(z); W_o. The heads are
-    taken a block of `DELTA_HEAD_BLOCK` value heads at a time, one after
-    another, each block run again in the backward pass (`_delta_heads`):
-    the rule's kernels hold a chunk in VMEM (PR 37; XLA's temporaries were
-    0.15 GB a head), but a block still keeps q, k, v, z and the chunks'
-    states, and without the blocks' checkpoint the step does not fit the
-    chip (ROADMAP S17). The result carries the name `gdn_mix`, which a layer
-    that is run again keeps (`_layer_again`): the second run of such a layer
-    has no reader for the blocks, so the mixer's forward runs twice a step,
-    in the forward pass and once for each block's gradients, not three
-    times; the identity anywhere else. Scopes `gdn_proj`, `gdn_conv`,
-    `gdn_core`, `gdn_norm`."""
-    Hk, Hv, _ = cfg.delta_heads
-    r = Hv // Hk
-    kb = max(b for b in range(1, Hk + 1)
-             if Hk % b == 0 and b * r <= max(DELTA_HEAD_BLOCK, r))
-
-    def blocks(w, axis):
-        """`axis`, a key head at a time, as (blocks, ..., a block's, ...)."""
-        shape = w.shape[:axis] + (Hk // kb, -1) + w.shape[axis + 1:]
-        return jnp.moveaxis(w.reshape(shape), axis, 0)
-
-    parts = (blocks(layer["w_qkvz"], 1), blocks(layer["w_ba"], 1),
-             blocks(layer["conv_w"], 1), blocks(layer["A_log"], 0),
-             blocks(layer["dt_bias"], 0), blocks(layer["wo"], 0))
-
-    def one(out, part):
-        return out + _delta_heads(h, part, layer["gdn_norm_scale"], cfg
-                                  ).astype(jnp.float32), None
-
-    out, _ = jax.lax.scan(one, jnp.zeros(h.shape, jnp.float32), parts)
-    return checkpoint_name(out.astype(h.dtype), "gdn_mix")
-
-
-def _mamba2_mixer(h, layer, cfg: TransformerConfig, segments=(), marks=()):
-    """The Mamba-2 mixer on normed hidden states h (B, S, D): H heads of P
-    features, a state of N a feature, G groups of H / G heads that share B
-    and C (`ssm_dims`). [z | x B C | dt] = h W_in (H P + (H P + 2 G N) + H
-    columns); the step Delta = softplus(dt + dt_bias) and the log decay g =
-    Delta A, A = -exp(A_log), a number a head and position, float32 from a
-    float32 projection as the router's is; [x | B | C] through the causal
-    convolution with its bias and a silu, and v = Delta x, one kernel each
-    way (`ops.ssm_conv`: it reads the projection's columns from x on where
-    the matmul left them, writes [x | B | C] once and v once in the layout
-    the scan reads, float32 between, and keeps its inputs alone); the
-    state-space recurrence (`ops.ssm_scan`) with q = C, k = B (a group's,
-    never repeated a head) and that v; + D x, the gate silu(z) and then an
-    RMSNorm over each group's features, one kernel each way
-    (`ops.gated_norm`: it reads the scan's output as the scan lays it out, x
-    and z as the first H P columns of the convolution's and the projection's
-    outputs, writes y once, and keeps those inputs alone); W_out. Both ops
-    are the same kept or run again. `segments`, (the documents' numbers (B,
-    S),) of packed rows, go to the convolution and to the scan, `marks`
-    (their `ssm_conv.document_marks`,) to the convolution's kernels, and
-    nothing else of the mixer looks beyond its own position. Scopes
-    `ssm_proj`, `ssm_conv`, `ssm_core`, `ssm_norm`."""
-    from kungfu_tpu.ops import gated_norm
-    from kungfu_tpu.ops.ssm_conv import ssm_conv
-    from kungfu_tpu.ops.ssm_scan import CHUNK, ssm_scan
-
-    H, hp, N, G = cfg.ssm_dims
-    inner, bc = H * hp, G * N
-    dt, f32 = cfg.dtype, jnp.float32
-    B, S, _ = h.shape
-    w_in = layer["w_ssm_in"]
-    with jax.named_scope("ssm_proj"):
-        zxbc = h @ w_in[:, :2 * inner + 2 * bc].astype(dt)  # z its first columns
-        step = jnp.dot(h.astype(f32), w_in[:, 2 * inner + 2 * bc:].astype(f32),
-                       precision=jax.lax.Precision.HIGHEST)  # (B, S, H)
-    with jax.named_scope("ssm_conv"):
-        delta = jax.nn.softplus(step + layer["dt_bias"].astype(f32))
-        g = (delta * -jnp.exp(layer["A_log"].astype(f32))).transpose(0, 2, 1)
-        xbc, v = ssm_conv(zxbc, layer["conv_w"], layer["conv_b"], delta,
-                          *segments, *marks)
-        b, c = (xbc[..., at:at + bc].reshape(B, S, G, N).transpose(0, 2, 1, 3)
-                for at in (inner, inner + bc))
-    with jax.named_scope("ssm_core"):
-        # the published chunk, or the largest power of two under it that
-        # divides a shorter sequence: the result does not depend on it
-        o = ssm_scan(c, b, v, g, math.gcd(S, CHUNK), *segments)  # (B, H, S, hp)
-    with jax.named_scope("ssm_norm"):
-        y = gated_norm.gated_norm(o, xbc, zxbc, layer["D_skip"],
-                                  layer["ssm_norm_scale"], G, cfg.norm_eps)
-    with jax.named_scope("ssm_proj"):
-        return y @ layer["wo"].astype(dt)
-
-
-def _short_conv_mixer(h, layer, cfg: TransformerConfig, segments=()):
-    """LFM2's gated short convolution on normed hidden states h (B, S, D):
-    [B | C | x] = h W_in, three equal thirds in that order; y = (C * conv(B *
-    x)) W_out with conv a causal depthwise convolution of `conv_taps` taps a
-    channel, c_t = sum_i k_i z_{t-(K-1)+i}, zeros before the row's first
-    position. No activation, no bias, no norm and no state. The gates and
-    the taps are one op (`ops.short_conv`) that reads the projection's
-    output once and keeps it, the taps and the segments alone, so the mixer
-    is the same kept or run again. `segments`, (the documents' numbers (B,
-    S),) of packed rows, go to the op: a tap that would reach into another
-    document reads zero. Scopes `sconv_proj`, `sconv_core`."""
-    from kungfu_tpu.ops.short_conv import short_conv
-
-    dt = cfg.dtype
-    with jax.named_scope("sconv_proj"):
-        bcx = h @ layer["conv_in"].astype(dt)
-    with jax.named_scope("sconv_core"):
-        y = short_conv(bcx, layer["conv_w"], *segments)
-    with jax.named_scope("sconv_proj"):
-        return y @ layer["conv_out"].astype(dt)
-
-
-def _scale(w, cfg: TransformerConfig):
-    """A norm's scale from its weight: the weight, or 1 + it."""
-    return 1.0 + w if cfg.norm_offset else w
 
 
 def _expert_layer(h, layer, cfg: TransformerConfig):
@@ -1493,55 +629,19 @@ def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
     """One layer -> (x, aux): a mixer and a feed-forward, each a residual
     branch behind its own norm, or one of the two alone; with `post_norms`
     the branch's output goes through a second norm before the residual takes
-    it, and `residual_multiplier` scales what it takes. aux is the expert
+    it, and `residual_multiplier` scales what it takes. The mixer is its
+    record's (`mixers.mixer_of`), under the record's scope. aux is the expert
     layer's `ops.moe.MoeAux` (router losses and token-choices per expert),
-    None of any other. `segments`: (the documents' numbers (B, S),) of
-    packed rows, for the mixer, with a Mamba-2 convolution's marks behind
-    them where `_hidden` made them; () where a row is one document."""
-    dt, eps = cfg.dtype, cfg.norm_eps
+    None of any other. `core`: an attention core plugged from outside.
+    `segments`: (the documents' numbers (B, S),) of packed rows, for the
+    mixer, with what the records wanted made of them behind them
+    (`_hidden`); () where a row is one document."""
     segments, marks = segments[:1], segments[1:]
-    index_kl = None
-    if cfg.mixer == "none":
-        pass
-    elif cfg.mixer == "mamba2":
-        with jax.named_scope("ssm"):
-            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = _taken(x, _mamba2_mixer(h, layer, cfg, segments, marks), layer,
-                       "ln1_post_scale", cfg)
-    elif cfg.mixer == "short_conv":
-        with jax.named_scope("sconv"):
-            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = _taken(x, _short_conv_mixer(h, layer, cfg, segments), layer,
-                       "ln1_post_scale", cfg)
-    elif cfg.mixer == "gated_delta":
-        with jax.named_scope("gdn"):
-            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = _taken(x, _gated_delta_mixer(h, layer, cfg), layer,
-                       "ln1_post_scale", cfg)
-    elif cfg.mixer == "latent":
-        with jax.named_scope("attn"):
-            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            x = _taken(x, _latent_attention(h, layer, cfg, core=core), layer,
-                       "ln1_post_scale", cfg)
-    else:
-        with jax.named_scope("attn"):
-            scales = ((_scale(layer["q_norm_scale"], cfg),
-                       _scale(layer["k_norm_scale"], cfg))
-                      if cfg.qk_norm else None)
-            h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), eps)
-            if cfg.sparse_index:
-                y, index_kl = _sparse_attention(h, layer, cfg, scales)
-                x = _taken(x, y, layer, "ln1_post_scale", cfg)
-            else:
-                wqkv = (tuple(layer[w].astype(dt) for w in ("wq", "wk", "wv"))
-                        if cfg.split_qkv else layer["wqkv"].astype(dt))
-                x = _taken(
-                    x, _attention(h, wqkv, layer["wo"].astype(dt),
-                                  cfg, core=core, qk_scales=scales,
-                                  w_head_gate=(layer["w_head_gate"].astype(dt)
-                                               if cfg.head_gate else None),
-                                  segments=segments),
-                    layer, "ln1_post_scale", cfg)
+    mixer, index_kl = mixer_of(cfg), None
+    if mixer is not None:
+        with jax.named_scope(mixer.scope):
+            y, index_kl = mixer.apply(x, layer, cfg, core, segments, marks)
+            x = _taken(x, y, layer, "ln1_post_scale", cfg)
     x, aux = _feed_forward(x, layer, cfg)
     # an indexer's record beside the expert layer's
     return x, aux if index_kl is None else LayerAux(aux, index_kl)
@@ -1601,19 +701,11 @@ _layer_again = jax.checkpoint(
 def _block(x, layer, cfg: TransformerConfig, core=None):
     """One layer's hidden states alone, for the paths that have no place
     for an expert layer's auxiliary losses (pipeline, ring, a plugged
-    core). A short-convolution mixer is not built there: a sequence shard
-    would want the last taps' rows of the shard before it, and a pipeline
-    stage runs one kind of layer."""
-    if cfg.mixer == "short_conv":
-        raise NotImplementedError(
-            "mixer 'short_conv' runs on the normal path (`transformer_loss`): "
-            "the ring path would have to hand a shard the rows before it, "
-            "and neither it nor the pipeline path is built for the mixer")
-    if cfg.sparse_index:
-        raise NotImplementedError(
-            "sparse_index runs on the normal path (`transformer_loss`): the "
-            "ring and pipeline paths have no place for the indexer's loss, "
-            "and a sequence shard's choice would range over other shards' keys")
+    core). A mixer that is built for the normal path alone says so in its
+    record, with the reason."""
+    mixer = mixer_of(cfg)
+    if mixer is not None and mixer.off_the_normal_path:
+        raise NotImplementedError(mixer.off_the_normal_path)
     return _layer(x, layer, cfg, core=core)[0]
 
 
@@ -1750,11 +842,12 @@ def _hidden(params, tokens, cfg: TransformerConfig, each=None):
     x = _embed(params, tokens, cfg)
     # the documents of packed rows: constants of every layer scan
     packed = _segments(tokens, cfg)
-    if packed and any(kind.mixer == "mamba2" for kind, _ in cfg.stacks):
-        from kungfu_tpu.ops.ssm_conv import document_marks
-
-        with jax.named_scope("segments"):  # once a step, for every such layer
-            packed += (document_marks(*packed),)
+    if packed:
+        records = (mixer_of(kind) for kind, _ in cfg.stacks)
+        for make in dict.fromkeys(m.document_marks for m in records
+                                  if m is not None and m.document_marks):
+            with jax.named_scope("segments"):  # once a step, for every such layer
+                packed += (make(*packed[:1]),)
     packed = (None, packed) if packed else ()  # no core plugged, then they
     stacks = params["layers"] if cfg.layer_kinds else (params["layers"],)
     looped = cfg.loop_steps > 1
@@ -2088,8 +1181,7 @@ def sparse_choices(params, tokens, cfg: TransformerConfig):
     chosen = []
     for at in range(cfg.n_layers):
         layer = jax.tree.map(lambda leaf: leaf[at], params["layers"])
-        h = _rmsnorm(x, _scale(layer["ln1_scale"], cfg), cfg.norm_eps)
-        chosen.append(_sparse_choice(h, layer, cfg)[1])
+        chosen.append(_sparse_choice(_mixer_input(x, layer, cfg), layer, cfg)[1])
         x = _layer(x, layer, cfg)[0]
     return jnp.stack(chosen)
 

@@ -73,7 +73,7 @@ only where a shard holds whole groups (tp | G); no caller does that and no
 test shows it. Interpreted (the CPU tests' tp mesh of two) it is plain
 operations and partitions like them.
 
-`models/transformer._mamba2_mixer` is the caller, under the scope `ssm_norm`.
+`models/mixers/mamba2._mamba2_mixer` is the caller, under the scope `ssm_norm`.
 """
 
 from __future__ import annotations

@@ -22,7 +22,7 @@ take; the transpose pass reads (B, H, S, hd) and writes (B, S, H * hd). XLA
 writes those transpositions as passes of their own beside a custom call
 (10.7 ms of the Laguna cell's `window_core_ms`, PERF.md, PR 33).
 
-Mosaic unless `interpret=True`. `models/transformer._rope` is the caller.
+Mosaic unless `interpret=True`. `models/blocks._rope` is the caller.
 """
 
 from __future__ import annotations

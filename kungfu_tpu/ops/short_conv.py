@@ -66,7 +66,7 @@ mixer runs in a program of one device until its caller stands under a
 `shard_map`; interpreted (the CPU tests' tp mesh of two) it is plain
 operations and partitions like them.
 
-`models/transformer._short_conv_mixer` is the caller, under the scope
+`models/mixers/short_conv._short_conv_mixer` is the caller, under the scope
 `sconv_core`. On the chip: PERF.md section 6, PR 57.
 """
 

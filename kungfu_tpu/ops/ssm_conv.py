@@ -99,7 +99,7 @@ mixer runs in a program of one device until its caller stands under a
 `shard_map`; interpreted (the CPU tests' tp mesh of two) it is plain
 operations and partitions like them.
 
-`models/transformer._mamba2_mixer` is the caller, under the scope `ssm_conv`.
+`models/mixers/mamba2._mamba2_mixer` is the caller, under the scope `ssm_conv`.
 On the chip: PERF.md section 6, PR 58.
 """
 

@@ -21,6 +21,7 @@ from benchmark.reference import glm_4_7_flash as ref
 from family_cases import *  # noqa: F401,F403  the shared cases
 from jaxprs import pallas_calls
 from kungfu_tpu.models import transformer
+from kungfu_tpu.models.mixers import latent
 from kungfu_tpu.models.transformer import TransformerConfig, init_transformer
 from kungfu_tpu.ops import moe
 from kungfu_tpu.telemetry import metrics
@@ -143,7 +144,7 @@ def test_latent_attention_alone_against_a_plain_softmax_over_materialised_heads(
     mc = family.model_config(config).stacks[1][0]
     layer = jax.tree.map(lambda a: a[0], FAMILY.state()["layers"][1])
     h = jax.random.normal(jax.random.PRNGKey(2), (2, 64, 64))
-    got = jax.jit(lambda h, w: transformer._latent_attention(h, w, mc))(h, layer)
+    got = jax.jit(lambda h, w: latent._latent_attention(h, w, mc))(h, layer)
     with jax.default_matmul_precision("highest"):
         want = ref.latent_attention(h, layer, family._hyper(config))
     assert got.shape == want.shape == (2, 64, 64)

@@ -10,18 +10,19 @@ import family_cases as fc
 from family_cases import (  # noqa: F401  the shared case
     pytest_generate_tests, test_a_fault_fails_the_familys_tolerance)
 from kungfu_tpu.models import transformer
+from kungfu_tpu.models.mixers import latent
 
 _as = lambda **changes: fc.model_changed(fc.GLM_4_7_FLASH.module, **changes)
 
 
 def _no_latent_norm(m):
-    norm = transformer._rmsnorm
+    norm = latent._rmsnorm
 
     def skipping(x, scale, eps=1e-6):
         # the latents are the only normed arrays of these widths
         return x if x.shape[-1] in (24, 16) else norm(x, scale, eps)
 
-    m.setattr(transformer, "_rmsnorm", skipping)
+    m.setattr(latent, "_rmsnorm", skipping)
 
 
 def _rotary_key_not_shared(m):
@@ -36,7 +37,7 @@ def _rotary_key_not_shared(m):
                              for h in range(shape[2])], axis=2)
         return out
 
-    m.setattr(transformer.jnp, "broadcast_to", apart)
+    m.setattr(latent.jnp, "broadcast_to", apart)
 
 
 def _mtp_fed_the_same_token(m):

@@ -19,6 +19,7 @@ from benchmark import harness
 from family_cases import *  # noqa: F401,F403  the shared cases
 from family_cases import test_a_fault_fails_the_familys_tolerance  # noqa: F401
 from kungfu_tpu.models import transformer
+from kungfu_tpu.models.mixers import attention
 
 family = fc.LAGUNA.module
 _as = lambda **changes: fc.model_changed(family, **changes)
@@ -32,13 +33,13 @@ def _no_yarn_factor(cfg, model_config=family.model_config):
 
 
 def _wrong_group(m):
-    core_of = transformer.attention_core_of
+    core_of = attention.attention_core_of
 
     def reversed_groups(cfg):
         core = core_of(cfg)
         return lambda q, k, v: core(q, k[:, ::-1], v[:, ::-1])
 
-    m.setattr(transformer, "attention_core_of", reversed_groups)
+    m.setattr(attention, "attention_core_of", reversed_groups)
 
 
 FAULTS = {

@@ -21,6 +21,7 @@ from benchmark.reference import lfm2_moe as ref
 from family_cases import *  # noqa: F401,F403  the shared cases
 from jaxprs import pallas_calls
 from kungfu_tpu.models import transformer
+from kungfu_tpu.models.mixers import short_conv as short_conv_mixer
 from kungfu_tpu.models.transformer import TransformerConfig
 from kungfu_tpu.ops import short_conv
 from kungfu_tpu.telemetry import metrics
@@ -120,7 +121,7 @@ def test_the_mixer_alone_is_the_references_and_runs_the_kernels():
     mc = family.model_config(CONFIG).stacks[0][0]
     layer = jax.tree.map(lambda a: a[0], FAMILY.state()["layers"][0])
     h = jax.random.normal(jax.random.PRNGKey(2), (2, 64, 128))
-    mixer = lambda h, w: transformer._short_conv_mixer(h, w, mc)
+    mixer = lambda h, w: short_conv_mixer._short_conv_mixer(h, w, mc)
     got = jax.jit(mixer)(h, layer)
     with jax.default_matmul_precision("highest"):
         want = ref.short_conv(h, layer)
@@ -191,7 +192,7 @@ def test_the_loss_reaches_its_gauge_and_no_modules():
 
 def test_what_is_not_built_is_refused_with_a_sentence():
     conv = dict(mixer="short_conv", ffn="swiglu", conv_taps=3)
-    fc.refused("LFM2's 3 taps", **{**conv, "conv_taps": 4})
+    fc.refused("3 taps, what `ops/short_conv.py`'s kernels", **{**conv, "conv_taps": 4})
     fc.refused("under a loop", **conv, loop_steps=2)
     fc.refused("multi-token-prediction", **conv, mtp_depth=1, mtp_weight=0.3)
     fc.refused("under a loop", loop_steps=2, n_layers=2, conv_taps=3, layer_kinds=(

@@ -21,6 +21,7 @@ from benchmark import harness
 from benchmark.reference import nemotron_h as ref
 from family_cases import *  # noqa: F401,F403  the shared cases
 from kungfu_tpu.models import transformer
+from kungfu_tpu.models.mixers import mamba2
 from kungfu_tpu.models.transformer import TransformerConfig, init_transformer
 from kungfu_tpu.telemetry import metrics
 
@@ -113,12 +114,12 @@ def test_the_mamba2_mixer_alone_against_the_recurrence_a_position_at_a_time():
     hyper = family._hyper(CONFIG)
 
     def mine(h, w):
-        return jnp.sum(transformer._mamba2_mixer(h, w, mc) * weight)
+        return jnp.sum(mamba2._mamba2_mixer(h, w, mc) * weight)
 
     def theirs(h, w):
         return jnp.sum(ref.mamba_mixer(h, w, hyper) * weight)
 
-    got = jax.jit(lambda h, w: transformer._mamba2_mixer(h, w, mc))(h, layer)
+    got = jax.jit(lambda h, w: mamba2._mamba2_mixer(h, w, mc))(h, layer)
     with jax.default_matmul_precision("highest"):
         want = ref.mamba_mixer(h, layer, hyper)
         want_grads = jax.grad(theirs, (0, 1))(h, layer)
@@ -134,7 +135,7 @@ def test_the_mamba2_mixer_alone_against_the_recurrence_a_position_at_a_time():
                                       want_grads[1][name]) <= 1e-3, name
     # the memory is longer than a chunk of the tests' scan would need: a
     # change at position 3 still moves position 40
-    moved = jax.jit(lambda h, w: transformer._mamba2_mixer(h, w, mc))(
+    moved = jax.jit(lambda h, w: mamba2._mamba2_mixer(h, w, mc))(
         h.at[:, 3].add(1.0), layer)
     assert float(jnp.abs(moved[:, 40] - got[:, 40]).max()) > 1e-6
     assert np.array_equal(np.asarray(moved[:, :3]), np.asarray(got[:, :3]))

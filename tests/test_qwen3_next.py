@@ -20,6 +20,7 @@ from benchmark import harness
 from benchmark.reference import qwen3_next as ref
 from family_cases import *  # noqa: F401,F403  the shared cases
 from kungfu_tpu.models import transformer
+from kungfu_tpu.models.mixers import gated_delta as gated_delta_mixer
 from kungfu_tpu.models.transformer import TransformerConfig, init_transformer
 
 
@@ -90,7 +91,7 @@ def test_the_mixers_head_blocks_change_no_number(block, monkeypatch, fresh_trace
     two blocks of two value heads, or one of all four."""
     state, sample = FAMILY.state(), FAMILY.sample()
     loss, grads = FAMILY.baseline()
-    monkeypatch.setattr(transformer, "DELTA_HEAD_BLOCK", block)
+    monkeypatch.setattr(gated_delta_mixer, "DELTA_HEAD_BLOCK", block)
     jax.clear_caches()
     text = str(jax.make_jaxpr(family.program_loss_and_grads(CONFIG))(state, sample))
     assert ("f32[2,64,96]" in text) == (block == 2)  # two of the four key heads
@@ -116,7 +117,7 @@ def test_a_layer_run_again_runs_its_mixers_forward_twice_a_step(monkeypatch,
     third. Two blocks of eight value heads, as the cell's four: a scan of
     one block XLA unrolls, and merges the two later runs by itself."""
     config = tiny_config(linear_num_key_heads=8, linear_num_value_heads=16)
-    assert transformer.DELTA_HEAD_BLOCK == 8
+    assert gated_delta_mixer.DELTA_HEAD_BLOCK == 8
     assert [kind.layer_remat for kind, _ in family.model_config(config).stacks
             ] == [True, False]
     state = jax.eval_shape(lambda: family.init(config, 0))

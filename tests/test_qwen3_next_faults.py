@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import family_cases as fc
 from family_cases import (  # noqa: F401  the shared case
     pytest_generate_tests, test_a_fault_fails_the_familys_tolerance)
-from kungfu_tpu.models import transformer
+from kungfu_tpu.models.mixers import attention, gated_delta as gated_delta_mixer
 from kungfu_tpu.ops import gated_delta
 
 _as = lambda **changes: fc.model_changed(fc.QWEN3_NEXT.module, **changes)
@@ -20,8 +20,8 @@ def _no_feature_gate(m):
         B, H, S, hd = ctx.shape
         return ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd) @ wo
 
-    m.setattr(transformer, "_feature_gated_out", ungated)
-    m.setattr(transformer, "_feature_gated_out_kept", ungated)
+    m.setattr(attention, "_feature_gated_out", ungated)
+    m.setattr(attention, "_feature_gated_out_kept", ungated)
 
 
 def _no_convolution(m):
@@ -40,7 +40,7 @@ def _keys_of_the_wrong_head(m):
     def reversed_heads(x, r, axis):
         return repeat(x, r, axis=axis)[:, ::-1]
 
-    m.setattr(transformer.jnp, "repeat", reversed_heads)
+    m.setattr(gated_delta_mixer.jnp, "repeat", reversed_heads)
 
 
 FAULTS = {

@@ -1,4 +1,4 @@
-"""Rotary positions in one pass each way (`models/transformer._rope`, the
+"""Rotary positions in one pass each way (`models/blocks._rope`, the
 kernel of `ops/rotary.py`; PERF.md, PR 35): the values and gradients are
 those of the body it replaced, written out here as plain `jax.numpy` and
 differentiated by autodiff; the backward pass keeps nothing of q's or k's
@@ -20,8 +20,9 @@ import numpy as np
 import pytest
 
 from benchmark import harness
-from kungfu_tpu.models import transformer
-from kungfu_tpu.models.transformer import TransformerConfig, _rope
+from kungfu_tpu.models import blocks, transformer
+from kungfu_tpu.models.blocks import _rope
+from kungfu_tpu.models.transformer import TransformerConfig
 
 LAGUNA_YARN = (128, 8192, 32, 1, 1.4852030263919618)
 
@@ -32,7 +33,7 @@ def _oracle(q, k, theta, share, yarn):
     rd = int(hd * share)
     inv_freq = 1.0 / (theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
     if yarn:
-        ramp = transformer._yarn_ramp(rd, theta, yarn)
+        ramp = blocks._yarn_ramp(rd, theta, yarn)
         inv_freq = inv_freq / yarn[0] * ramp + inv_freq * (1 - ramp)
     angles = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     angles = jnp.concatenate([angles, angles], axis=-1)

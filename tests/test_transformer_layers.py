@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec
 from benchmark import harness
 from benchmark.families import olmoe as family
 from kungfu_tpu.models import transformer
+from kungfu_tpu.models.mixers import attention
 from kungfu_tpu.models.transformer import (TransformerConfig, init_transformer,
                                            param_pspecs, transformer_loss)
 from kungfu_tpu.ops import moe
@@ -122,7 +123,7 @@ FAULTS = {
         family, "model_config", functools.partial(_changed, qk_norm=False)),
     "dropped_token_choices": lambda m: m.setattr(
         moe, "swiglu_experts", _capacity(1.25)),
-    "missing_rope": lambda m: m.setattr(transformer, "_rope", _no_rope),
+    "missing_rope": lambda m: m.setattr(attention, "_rope", _no_rope),
 }
 _model_config = family.model_config
 

@@ -1,13 +1,13 @@
 """What the layer scan of `models/transformer.py` saves for the backward
-pass (PERF.md, PR 25): the dense attention core, RMSNorm and the gelu keep
-their inputs and recompute the rest. The values are those of the same model
+pass (PERF.md, PR 25): the dense attention core, RMSNorm (`models/blocks.py`)
+and the gelu keep their inputs and recompute the rest. The values are those of the same model
 without `jax.checkpoint`, on the dense, the pipeline and the ring path; a
 core plugged from outside is never run twice; the declared precision holds;
 and the residuals of `bert_base`'s step stay inside a budget that is read
 off the traced program, on the CPU, before it costs a chip run."""
 
 import functools
-import importlib.util
+import importlib
 import sys
 
 import jax
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kungfu_tpu.models import transformer
+from kungfu_tpu.models import blocks, transformer
 from kungfu_tpu.models.transformer import (TransformerConfig, init_transformer,
                                            make_ring_transformer_loss,
                                            transformer_loss)
@@ -57,19 +57,32 @@ PATHS = {"dense": _dense, "pipeline": _pipeline, "ring": _ring}
 
 @pytest.fixture(scope="module")
 def plain():
-    """A second copy of `models/transformer.py`, executed with
-    `jax.checkpoint` patched to the identity: the model as it was."""
-    spec = importlib.util.spec_from_file_location("_transformer_unchecked",
-                                                  transformer.__file__)
-    module = importlib.util.module_from_spec(spec)
+    """A second copy of `models/transformer.py` and of what it is built
+    from (`models/blocks.py`, `models/mixers/`), imported anew with
+    `jax.checkpoint` patched to the identity: the model as it was. The first
+    copies are put back where they were, in `sys.modules` and on their
+    packages."""
+    import kungfu_tpu.models as package
+
+    prefixes = tuple(package.__name__ + name
+                     for name in (".blocks", ".mixers", ".transformer"))
+    first = {name: module for name, module in sys.modules.items()
+             if name.startswith(prefixes)}
     real = jax.checkpoint
     jax.checkpoint = lambda fn, **kwargs: fn
-    sys.modules[spec.name] = module  # dataclasses looks the module up
+    for name in first:
+        del sys.modules[name]
     try:
-        spec.loader.exec_module(module)
+        module = importlib.import_module(transformer.__name__)
     finally:
         jax.checkpoint = real
-        del sys.modules[spec.name]
+        for name in [n for n in sys.modules if n.startswith(prefixes)]:
+            del sys.modules[name]
+        sys.modules.update(first)
+        for name, module_ in first.items():
+            parent, _, leaf = name.rpartition(".")
+            setattr(sys.modules[parent], leaf, module_)
+    assert module is not transformer and module._rmsnorm is not blocks._rmsnorm
     return module
 
 
@@ -159,7 +172,7 @@ def _scan_loss(core):
 
 
 def _plain_core(q, k, v):
-    return transformer._full_attention_core.__wrapped__(q, k, v)
+    return blocks._full_attention_core.__wrapped__(q, k, v)
 
 
 def _custom_vjp_core(calls):
