@@ -18,7 +18,29 @@ probabilities** there. Five pieces, each with its `plain` `jax.numpy` form
   exists; one backward, which makes the heads' scores again and writes dqI, dw
   and, a q-block at a time, the parts of dkI. **Only I[t, s <= t] is
   defined**: a block above the diagonal is never written, and every reader
-  masks before it reads.
+  masks before it reads. **The float32 products are written out as their six
+  bfloat16 terms** (PR 64): x = hi + mid + lo in bfloat16s (`_pieces`), and a
+  product at the highest precision is hi.hi + mid.hi + hi.mid + hi.lo +
+  mid.mid + lo.hi, each one pass of the MXU summed in float32; no term is
+  left out (a bfloat16x3 product keeps the first three and is a lower
+  precision than the indexer states). Left to the compiler each term is a
+  pass of its own, and at the indexer's head of 64 each uses half of the
+  128 x 128 array: q . k^T contracts over d (half its depth), ds . k and
+  ds^T . q write d columns (half its width). Here the terms share passes.
+  *The scores*: the pieces stand side by side along the contraction,
+  [q_hi | q_mid | q_hi | q_hi | q_mid | q_lo] . [k_hi | k_hi | k_mid | k_lo |
+  k_mid | k_hi]^T, one product 6 d deep (`_deep`), of which the array takes
+  128 a pass: 128 // d terms share one, **three passes for six at d = 64**,
+  one at the tests' d = 16, the compiler's own six at d = 128. *The two
+  gradients* are made transposed, dq^T = k^T ds^T and dk^T = q^T ds: `ds`
+  (a 512 x 512 tile, split once) stands in the array, by its own pieces, and
+  the pieces of k^T or q^T, hi over mid over lo (`_stacked`), stream past it
+  as rows, which the array pads to nothing: ds_hi meets all 3 d rows, ds_mid
+  the first 2 d, ds_lo the first d, six terms for 6 d rows a tile, **three
+  passes' worth for six at d = 64** where side by side along the d columns
+  they would take four, and no `ds.T` is made. Nine half-array passes a head
+  and block for eighteen, three forward for six (PERF.md, PR 64: 2.4 ms a
+  layer forward for 4.6, 8.9 backward for 15.2).
 - `select(I, k)`: for each query t the min(t + 1, k) keys s <= t with the
   largest I[t, s], a tie to the lower position, as one byte a pair (B, S, S)
   int8: 67 MB a layer at 8,192 positions (a bit a pair would be 8.4 MB and an
@@ -106,58 +128,117 @@ def plain_index_scores(qI, kI, w):
     return jnp.einsum("btj,bjts->bts", w, jax.nn.relu(s), precision=_HIGHEST)
 
 
-def _dot32(a, b, dims):
-    return lax.dot_general(a, b, (dims, ((), ())), precision=_HIGHEST,
-                           preferred_element_type=jnp.float32)
+# A float32 product at the highest precision is six bfloat16 products of the
+# operands' pieces (x = hi + mid + lo, each a bfloat16): of the nine, those
+# whose order is 2^-16 of the product's or above. (left piece, right piece),
+# 0 hi, 1 mid, 2 lo, in the order they stand side by side along a contraction:
+_SIX_TERMS = ((0, 0), (1, 0), (0, 1), (0, 2), (1, 1), (2, 0))
+
+
+def _pieces(x):
+    """float32 x as three bfloat16s, hi + mid + lo = x to float32's last bit:
+    each the nearest bfloat16 of what the ones before it left."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _deep(x, side: int):
+    """x (rows, d) float32 as (rows, 6 d) bfloat16: the pieces that side
+    (0 left, 1 right) of the six terms takes, side by side along the
+    contraction, so that left . right^T over 6 d is the float32 product over
+    d: the array is 128 deep, so 128 // d terms share a pass of it."""
+    pieces = _pieces(x)
+    return jnp.concatenate([pieces[term[side]] for term in _SIX_TERMS], axis=-1)
+
+
+def _stacked(x):
+    """x (rows, n) float32 as (3 rows, n) bfloat16: hi over mid over lo."""
+    return jnp.concatenate(_pieces(x), axis=0)
+
+
+def _head_scores(q, k_deep):
+    """q k^T of float32 q (blk_q, d) and a block of keys as `_deep(k, 1)`:
+    the six terms in one contraction, 6 d deep."""
+    return _nt(_deep(q, 0), k_deep)
 
 
 def _index_kernel(table, q_ref, k_ref, w_ref, o_ref, *, heads: int):
     """A block of I: the heads' scores one after another, relu, times the
     head's weight a query, summed. q (1, heads, blk_q, d), k (1, blk_k, d),
-    w (1, blk_q, heads)."""
-    k, w = k_ref[0], w_ref[0]
+    w (1, blk_q, heads). A head's scores are one bfloat16 product 6 d deep
+    (`_deep`): at d = 64 three passes of the 128-deep array for the six
+    64-deep ones of a float32 product, with no term left out."""
+    k_deep, w = _deep(k_ref[0], 1), w_ref[0]
     acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
     for j in range(heads):
-        s = _dot32(q_ref[0, j], k, ((1,), (1,)))
+        s = _head_scores(q_ref[0, j], k_deep)
         acc = acc + w[:, j:j + 1] * jnp.maximum(s, 0.0)
     o_ref[0] = acc
 
 
 def _index_bwd_kernel(table, q_ref, k_ref, w_ref, di_ref, dq_ref, dw_ref,
-                      dkp_ref, dq_scr, dw_scr, *, heads: int, blk_q: int,
-                      blk_k: int):
+                      dkp_ref, dq_scr, dw_scr, dk_scr, *, heads: int,
+                      blk_q: int, blk_k: int):
     """The scores' gradients from dI, a q-block's sweep over its k-blocks:
     ds_j = dI w_j [s_j > 0]; dq_j += ds_j k and dw_j += sum_s dI relu(s_j)
     in scratch, written at the sweep's end; dk's part of this q-block, sum_j
-    ds_j^T q_j, written a block pair (the caller adds the q-blocks up)."""
+    ds_j^T q_j, written a block pair (the caller adds the q-blocks up).
+
+    Three float32 products a head and block, each as its six bfloat16 terms:
+    s_j again as the forward kernel makes it (three 128-deep passes at d =
+    64), and the two gradients **transposed**, dq_j^T = k^T ds_j^T and dk^T =
+    sum_j q_j^T ds_j, so that `ds` (blk_q, blk_k), split into its pieces
+    once, stands in the array as it is or transposed by the product itself
+    (no `ds.T`), and what streams past it is the rows of k^T's or q_j^T's
+    pieces, hi over mid over lo (`_stacked`): ds's hi meets all 3 d rows, its
+    mid the first 2 d, its lo the first d. Rows are not padded to the
+    array's 128, so the six terms cost 6 d rows a tile of `ds` where a
+    product with d columns out costs 128 a term: at d = 64 nine half-array
+    passes a head and block in all for the parent's eighteen. The three row
+    groups of a transposed gradient are added up, and the sum transposed
+    back, once a grid step (dk) or once a sweep (dq)."""
     from jax.experimental import pallas as pl
 
     qi, kb = _pair(table, pl.program_id(1))
+    d = k_ref.shape[-1]
 
     @pl.when(kb == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
         dw_scr[...] = jnp.zeros_like(dw_scr[...])
 
+    def folded(x):
+        """(3 d, n) as the sum of its three row groups, transposed."""
+        return (x[:d] + x[d:2 * d] + x[2 * d:]).T
+
     k, w, di = k_ref[0], w_ref[0], di_ref[0]
+    k_deep, k_rows = _deep(k, 1), _stacked(k.T)
     lane = lax.broadcasted_iota(jnp.int32, dw_scr.shape, 1)
-    dk = jnp.zeros(k.shape, jnp.float32)
+    dk_scr[...] = jnp.zeros_like(dk_scr[...])
     dw = jnp.zeros(dw_scr.shape, jnp.float32)
     for j in range(heads):
         q = q_ref[0, j]
-        s = _dot32(q, k, ((1,), (1,)))
+        q_rows = _stacked(q.T)
+        s = _head_scores(q, k_deep)
         live = s > 0.0
         dw = dw + jnp.where(lane == j, jnp.sum(
             jnp.where(live, di * s, 0.0), axis=-1, keepdims=True), 0.0)
         ds = jnp.where(live, di * w[:, j:j + 1], 0.0)
-        dq_scr[j] += _dot32(ds, k, ((1,), (0,)))
-        dk = dk + _dot32(ds.T, q, ((1,), (0,)))
+        # ds's piece i with the first 3 - i pieces of the other operand
+        for i, piece in enumerate(_pieces(ds)):
+            rows = (3 - i) * d
+            dq_scr[j, :rows] += _nt(k_rows[:rows], piece)
+            dk_scr[:rows] += jnp.dot(q_rows[:rows], piece,
+                                     preferred_element_type=jnp.float32)
     dw_scr[...] += dw
-    dkp_ref[0, 0] = dk
+    dkp_ref[0, 0] = folded(dk_scr[...])
 
     @pl.when(kb == _last_kv_step(qi, blk_q, blk_k))
     def _finalize():
-        dq_ref[0] = dq_scr[...]
+        for j in range(heads):
+            dq_ref[0, j] = folded(dq_scr[j])
         dw_ref[0] = dw_scr[...]
 
 
@@ -202,8 +283,9 @@ def _index_backward(qh, kI, w, dI, blk_q, blk_k, interpret):
         out_specs=[spec["q"], spec["w"],
                    pl.BlockSpec((1, 1, blk_k, d),
                                 lambda b, at, t: (b, *_pair(t, at), 0))],
-        scratch_shapes=[pltpu.VMEM((Hi, blk_q, d), jnp.float32),
-                        pltpu.VMEM((blk_q, Hi), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((Hi, 3 * d, blk_q), jnp.float32),
+                        pltpu.VMEM((blk_q, Hi), jnp.float32),
+                        pltpu.VMEM((3 * d, blk_k), jnp.float32)],
         compiler_params=_params("parallel", "arbitrary"),
         interpret=interpret, name="dsa_index_scores_bwd")(qh, kI, w, dI)
     # a q-block wrote its parts of dk for the k-blocks up to its diagonal

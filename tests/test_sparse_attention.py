@@ -4,7 +4,10 @@ interpreted against the plain `jnp` forms and against a dense softmax under
 the mask written here: values and every gradient, float32 and bfloat16, at 256
 positions with 64 keys a query and blocks of 64, so that rows choose, rows do
 not, and blocks are crossed. The choice's kernel (PR 62) against its plain
-form bit for bit, at 256 to 1,024 positions."""
+form bit for bit, at 256 to 1,024 positions. The scores at the test's head of
+16 and the cell's of 64 (PR 64: their float32 products are six bfloat16 terms
+side by side, all six in a pass at 16, two a pass at 64), and on inputs on
+which a product that drops a term is wrong in the first digit."""
 
 import functools
 
@@ -34,13 +37,25 @@ def _causal(x):
     return jnp.where(np.tril(np.ones(x.shape[-2:], bool)), x, 0.0)
 
 
+def _scored(qI, kI, w):
+    return qI, kI, w, jax.jit(lambda *a: dsa.index_scores(
+        *a, BLK, BLK, True))(qI, kI, w)
+
+
 @pytest.fixture(scope="module")
 def scores():
     """The indexer's inputs and their scores under the kernel."""
-    qI, kI = _normal(1, B, S, HI, DI), _normal(2, B, S, DI)
-    w = 0.3 * _normal(3, B, S, HI)
-    return qI, kI, w, jax.jit(lambda *a: dsa.index_scores(
-        *a, BLK, BLK, True))(qI, kI, w)
+    return _scored(_normal(1, B, S, HI, DI), _normal(2, B, S, DI),
+                   0.3 * _normal(3, B, S, HI))
+
+
+@pytest.fixture(scope="module", params=[DI, 64], ids="head{}".format)
+def scores_at(request, scores):
+    """`scores` at the tests' head and at the Keye cell's, two heads."""
+    if request.param == DI:
+        return scores
+    return _scored(_normal(1, B, S, 2, request.param),
+                   _normal(2, B, S, request.param), 0.3 * _normal(3, B, S, 2))
 
 
 def _choice(form, I, k, rows=None, span=dsa.LANES):
@@ -65,16 +80,15 @@ def chosen(scores):
     return _choice("plain", scores[3], K)
 
 
-def test_the_scores_are_the_sum_over_the_heads_under_the_diagonal(scores):
-    qI, kI, w, got = scores
-    want = np.einsum("btj,bjts->bts", w, np.maximum(
-        np.einsum("btjd,bsd->bjts", qI, kI), 0.0))
+def test_the_scores_are_the_sum_over_the_heads_under_the_diagonal(scores_at):
+    qI, kI, w, got = scores_at
+    want = _scores64(qI, kI, w)
     assert _error(_causal(got), _causal(want)) <= 1e-6
     assert _error(_causal(dsa.plain_index_scores(qI, kI, w)), _causal(want)) <= 1e-6
 
 
-def test_the_scores_gradients_are_the_plain_forms(scores):
-    qI, kI, w, _ = scores
+def test_the_scores_gradients_are_the_plain_forms(scores_at):
+    qI, kI, w, _ = scores_at
     weight = _causal(_normal(4, B, S, S))
 
     def through(op):
@@ -84,6 +98,100 @@ def test_the_scores_gradients_are_the_plain_forms(scores):
     got = through(lambda *a: dsa.index_scores(*a, BLK, BLK, True))
     for g, want in zip(got, through(dsa.plain_index_scores), strict=True):
         assert g.shape == want.shape and _error(g, want) <= 1e-5
+
+
+def _scores64(qI, kI, w, products=None):
+    """The scores in float64, from the heads' products (B, Hi, S, S) where
+    given."""
+    qI, kI, w = (np.asarray(x, np.float64) for x in (qI, kI, w))
+    if products is None:
+        products = np.einsum("btjd,bsd->bjts", qI, kI)
+    return np.einsum("btj,bjts->bts", w, np.maximum(products, 0.0))
+
+
+def _pieces(x):
+    """float32 x as the kernels' three bfloat16 pieces, in float64."""
+    return [np.asarray(p, np.float64)
+            for p in dsa._pieces(jnp.asarray(x, jnp.float32))]
+
+
+def _three_scales(seed, rows, directions):
+    """(x, its three pieces) with x[..., :] = a h0 + b 2^-10 h1 + c 2^-20 h2,
+    a, b, c integers a row (3, 5, 6 or 7, either sign: no power of two, so
+    that no sum leaves its binade) and h0, h1, h2 three orthogonal rows of
+    +-1: every element's bfloat16 pieces are +-a, +-b 2^-10 and +-c 2^-20 to
+    the bit, and float32 holds their sum to the bit."""
+    rng = np.random.default_rng(seed)
+    pieces = [rng.choice([3.0, 5.0, 6.0, 7.0], rows + (1,))
+              * rng.choice([-1.0, 1.0], rows + (1,)) * 2.0 ** (-10 * i) * h
+              for i, h in enumerate(directions)]
+    x = jnp.asarray(sum(pieces), jnp.float32)
+    assert np.array_equal(np.asarray(x, np.float64), sum(pieces))
+    for got, want in zip(_pieces(x), pieces, strict=True):
+        assert np.array_equal(got, want)
+    return x, pieces
+
+
+# a float32 product at the highest precision keeps these six products of the
+# operands' pieces (0 hi, 1 mid, 2 lo), a bfloat16x3 product the first three
+KEPT_BY_THREE = ((0, 0), (0, 1), (1, 0))
+KEPT_BY_SIX = KEPT_BY_THREE + ((1, 1), (0, 2), (2, 0))
+
+
+@pytest.mark.parametrize("d", [DI, 64], ids="head{}".format)
+def test_no_term_of_the_float32_products_is_left_out(d):
+    """Queries along h1 + e h2 + e^2 h3 and keys along h3 + e h2 + e^2 h1 (e =
+    2^-10, h three orthogonal rows of +-1): over a head's d features hi.hi,
+    hi.mid and mid.hi sum to nothing, mid.mid, hi.lo and lo.hi to d e^2 times
+    integers, the three terms below them to nothing. The kernel's scores are
+    the float64 product's; a product of the three leading terms, written out
+    here from the same pieces, is zero everywhere. The gradients' products
+    run over keys and queries, where nothing cancels, so each is read along
+    the direction that holds one scale of the right operand: along the e^2
+    direction hi.lo is all there is, along the e direction mid.mid is the
+    second term, along the whole direction lo.hi the third."""
+    heads = 2
+    h = [np.array([(-1.0) ** bin(r & c).count("1") for c in range(d)])
+         for r in (1, 2, 3)]
+    qI, q3 = _three_scales(5, (B, S, heads), h)
+    kI, k3 = _three_scales(6, (B, S), h[::-1])
+    w = jnp.asarray(np.random.default_rng(7).choice([0.25, 0.5, 1.0], (B, S, heads)),
+                    jnp.float32)
+
+    def products(terms):
+        return sum(np.einsum("btjd,bsd->bjts", q3[a], k3[b]) for a, b in terms)
+
+    want = _scores64(qI, kI, w)
+    assert np.array_equal(_scores64(qI, kI, w, products(KEPT_BY_SIX)), want)
+    assert _error(_causal(_scores64(qI, kI, w, products(KEPT_BY_THREE))),
+                  _causal(want)) >= 1e-4
+    got = jax.jit(lambda *a: dsa.index_scores(*a, BLK, BLK, True))(qI, kI, w)
+    assert _error(_causal(got), _causal(want)) <= 1e-6
+
+    # the gradients under a cotangent dI: ds = dI w [s > 0] a head, float32
+    dI = _causal(_normal(8, B, S, S))
+    dq, dk, dw = jax.jit(jax.grad(lambda *a: jnp.sum(
+        dsa.index_scores(*a, BLK, BLK, True) * dI), argnums=(0, 1, 2)))(qI, kI, w)
+    s = np.einsum("btjd,bsd->bjts", *(np.asarray(x, np.float64) for x in (qI, kI)))
+    ds = jnp.where(s > 0, dI[:, None] * w.transpose(0, 2, 1)[..., None], 0.0)
+    ds3 = _pieces(ds)
+    assert _error(dw, np.einsum("bts,bjts->btj", dI, np.maximum(s, 0.0))) <= 1e-6
+    # along the direction of (the right operand's hi, mid, lo): the error of
+    # all six terms at most, of the leading three at least. The kernel reads
+    # 6e-8, 3e-5 and 3e-2 (its float32 result holds the smaller scales to
+    # that), the three terms 2.3e-6 (no lo.hi), 1.6e-3 (no mid.mid) and 1
+    limits = ((5e-7, 1.5e-6), (2e-4, 8e-4), (0.2, 0.5))
+    for got, form, right, along in ((dq, "bjts,bsd->btjd", k3, h[::-1]),
+                                    (dk, "bjts,btjd->bsd", q3, h)):
+        def product(terms):
+            return sum(np.einsum(form, ds3[a], right[b]) for a, b in terms)
+
+        want = np.einsum(form, np.asarray(ds, np.float64), sum(right))
+        assert _error(got, want) <= 1e-6
+        for direction, (six, three) in zip(along, limits, strict=True):
+            assert _error(np.asarray(got) @ direction, want @ direction) <= six
+            assert _error(product(KEPT_BY_SIX) @ direction, want @ direction) <= six
+            assert _error(product(KEPT_BY_THREE) @ direction, want @ direction) >= three
 
 
 @FORMS
