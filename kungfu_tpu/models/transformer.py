@@ -25,12 +25,14 @@ TPU-first design notes:
 - What stays here: the configuration; the state and its shardings; the layer
   (`_layer`) with its feed-forward (gelu, gated silu, or routed experts
   through `ops.moe.moe_ffn` over a share of the experts the router sees, with
-  a shared expert, `_expert_layer`), its second norms (`post_norms`) and the
+  a shared expert, `_expert_layer`; the router on the feed-forward's normed
+  input or, `router_input` "layer", on the layer's own input ahead of the
+  mixer, `_early_routing`), its second norms (`post_norms`) and the
   residual's multiplier; the stacks (`_hidden`); the heads and losses; the
   stats beside the step and their recorders; the ring path.
 - Layers may differ in kind: `layer_kinds` gives each layer the fields that
   replace the configuration's own for it (mixer, heads, window, rotary rule,
-  feed-forward), successive layers of one kind are one stacked tree and one
+  positions, feed-forward), successive layers of one kind are one stacked tree and one
   `lax.scan`, and `params["layers"]` is then the tuple of those stacks in
   the model's layer order.
 - The stacks may be run more than once a forward pass (Ouro's looped model):
@@ -98,7 +100,8 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     # what the layer is; every default is the repo's own block
     # "learned", "rope" (rotate-half over the whole head) or "none": no
-    # position signal of any kind
+    # position signal of any kind. A layer kind may say "rope" or "none" for
+    # its own layers (`layer_kinds`): "learned" is the embedding's, once
     positions: str = "learned"
     rope_theta: float = 10000.0
     qk_norm: bool = False  # RMSNorm over all of q and of k, before the heads split
@@ -163,9 +166,16 @@ class TransformerConfig:
     # and not for the weight; the loss is constant in it
     router_bias: bool = False
     # the routed experts' function, and the shared expert's: "swiglu", three
-    # matrices, w_down (silu(w_gate x) * w_up x), or "relu2", two, w_down
-    # (relu(w_up x))^2
+    # matrices, w_down (silu(w_gate x) * w_up x), "relu2", two, w_down
+    # (relu(w_up x))^2, or "reglu", three, w_down (relu(w_gate x) * w_up x)
     expert_act: str = "swiglu"
+    # what an expert layer's router reads: "ffn", the feed-forward's normed
+    # input, the rows the experts transform; or "layer", the layer's own
+    # input as it is, before the first norm and the mixer (SmallThinker's):
+    # choice, gates and the order of the token-choices are then made ahead
+    # of the mixer (`_early_routing`) and the experts read the normed state
+    # behind it
+    router_input: str = "ffn"
     # multi-token prediction (DeepSeek-V3's, section 2.2): modules after the
     # stack (0 or 1), each one further block of the last layer's kind, and
     # the weight of their loss beside the main one
@@ -209,7 +219,8 @@ class TransformerConfig:
         for field, value, known in (
                 ("positions", self.positions, ("learned", "rope", "none")),
                 ("ffn", self.ffn, ("gelu", "swiglu", "moe", "none")),
-                ("expert_act", self.expert_act, ("swiglu", "relu2")),
+                ("expert_act", self.expert_act, ("swiglu", "relu2", "reglu")),
+                ("router_input", self.router_input, ("ffn", "layer")),
                 ("attn_core", self.attn_core, ("dense", "flash")),
                 ("gates", self.gates, ("raw", "renorm")),
                 ("mixer", self.mixer, (*MIXERS, "none")),
@@ -236,6 +247,10 @@ class TransformerConfig:
                              "prediction module or none")
         if self.shared_gate and not self.shared_ff:
             raise ValueError("shared_gate gates the shared expert (shared_ff)")
+        if self.expert_act == "reglu" and self.shared_ff:
+            raise ValueError("expert_act 'reglu' is built for routed experts "
+                             "alone: no model the repo runs has a relu-gated "
+                             "shared expert (shared_ff)")
         if self.loop_steps < 1:
             raise ValueError(f"loop_steps {self.loop_steps}: the stacks are "
                              "run once or more")
@@ -265,7 +280,16 @@ class TransformerConfig:
             raise ValueError(f"{len(self.layer_kinds)} layer kinds for "
                              f"{self.n_layers} layers")
         for kind in self.layer_kinds:
-            dataclasses.replace(self, layer_kinds=(), n_layers=1, **dict(kind))
+            one = dataclasses.replace(self, layer_kinds=(), n_layers=1,
+                                      **dict(kind))
+            if "learned" in {self.positions, one.positions} and (
+                    one.positions != self.positions):
+                raise ValueError(
+                    "learned positions are added to the embedding once, for "
+                    "every layer: a layer kind's own `positions` is 'rope' or "
+                    "'none' in a model whose own is one of the two, not "
+                    f"{one.positions!r} under {self.positions!r}")
+
     @property
     def head_dim(self) -> int:
         if self.head_size:
@@ -298,6 +322,17 @@ class TransformerConfig:
         return tuple((dataclasses.replace(self, layer_kinds=(), n_layers=n,
                                           **dict(kind)), n)
                      for kind, n in runs)
+
+    @property
+    def rotary(self) -> bool:
+        """Whether any layer turns q and k by their positions, the model's
+        own rule or a layer kind's."""
+        return any(kind.positions == "rope" for kind, _ in self.stacks)
+
+    @property
+    def gated_experts(self) -> bool:
+        """Whether an expert is three matrices, a gate beside up and down."""
+        return self.expert_act in ("swiglu", "reglu")
 
     @property
     def mtp_kind(self) -> "TransformerConfig":
@@ -352,6 +387,29 @@ class TransformerConfig:
             gates="renorm", routed_scale=1.0, layer_kinds=kinds), **changes)
 
     @classmethod
+    def smallthinker_21b_a3b(cls, n_layers: int = 52,
+                             **changes) -> "TransformerConfig":
+        """PowerInfer/SmallThinker-21BA3B-Instruct's config.json: 28 query
+        heads on 4 key/value heads of 128, no q/k norm; layers 0, 4, 8, ...
+        full attention with no position signal, the other three of four a
+        window of 4,096 with rotary positions at 1.5e6 (`rope_layout` and
+        `sliding_window_layout`, which agree); every layer 64 relu-gated
+        experts of width 768, 6 a token, softmax scores renormalised over
+        the chosen, routed from the layer's own input ahead of the mixer
+        (the published implementation's early router); an untied head.
+        `n_layers`: the model's first so many."""
+        full = (("positions", "none"), ("window", 0))
+        band = (("positions", "rope"), ("window", 4096))
+        kinds = tuple(band if l % 4 else full for l in range(n_layers))
+        return dataclasses.replace(cls(
+            vocab_size=151936, d_model=2560, n_heads=28, n_layers=n_layers,
+            d_ff=768, max_seq=16384, positions="none", rope_theta=1.5e6,
+            norm_eps=1e-6, ffn="moe", n_experts=64, top_k=6, gates="renorm",
+            expert_act="reglu", router_input="layer", tied_head=False,
+            attn_core="flash", head_size=128, n_kv_heads=4,
+            layer_kinds=kinds), **changes)
+
+    @classmethod
     def tiny_moe(cls, **changes) -> "TransformerConfig":
         """Every mechanism of `olmoe_1b_7b` on, at the tests' size; the
         flash core in interpret mode."""
@@ -397,7 +455,7 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
                 layer[pre.replace("_scale", "_post_scale")] = unit(cfg, (D,))
         if mixer is not None:
             layer.update(mixer.init(key, cfg, dense, unit))
-        gated = cfg.ffn == "swiglu" or cfg.expert_act == "swiglu"
+        gated = cfg.ffn == "swiglu" or cfg.gated_experts
         if cfg.ffn == "gelu":
             layer["w_in"] = dense(lk[2], (D, F))
             layer["w_out"] = dense(lk[3], (F, D))
@@ -469,7 +527,8 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     column-parallel projections, a row-parallel wo). Column-parallel
     w_in/w_gate/w_up (shard output features over tp), row-parallel
     w_out/w_down (shard input features over tp), a shared expert like a
-    gated-silu feed-forward (two matrices each under `expert_act` "relu2");
+    gated-silu feed-forward (two matrices each under `expert_act` "relu2",
+    three under "reglu" as under "swiglu");
     a layer of one branch has that branch's leaves alone; embedding and an
     untied head sharded over vocab; an expert stack over `ep_axis` on its
     expert dimension, the router whole. Layer-stacked leaves have a leading
@@ -497,7 +556,7 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
             layers.update(w_gate=P(None, None, t), w_up=P(None, None, t),
                           w_down=P(None, t, None))
         elif cfg.ffn == "moe":
-            gated = cfg.expert_act == "swiglu"
+            gated = cfg.gated_experts
             layers.update(w_up=P(None, e, None, t), w_down=P(None, e, t, None),
                           router=P(None, None, None))
             if gated:
@@ -571,25 +630,29 @@ def _aux_parts(aux):
     return tuple(aux) if isinstance(aux, LayerAux) else (aux, None)
 
 
-def _expert_layer(h, layer, cfg: TransformerConfig):
+def _expert_layer(h, layer, cfg: TransformerConfig, routing=None):
     """The expert layer on normed tokens h (T, D) -> (y (T, D), aux): the
     routed experts held here through `ops.moe.moe_ffn`, and the shared
     expert where the configuration has one, behind its sigmoid gate where it
-    has that; both gated silu or two-matrix relu^2 (`expert_act`)."""
-    from kungfu_tpu.ops.moe import (moe_ffn, raw_gates, relu2_experts,
-                                    renormalised_gates, scaled, swiglu_experts)
+    has that; both gated silu or two-matrix relu^2, the routed ones relu-gated
+    too (`expert_act`). `routing`: the layer's `_early_routing`, made of its
+    input ahead of the mixer, or None: the router reads h."""
+    from kungfu_tpu.ops import moe
+    from kungfu_tpu.ops.moe import moe_ffn, raw_gates, renormalised_gates, scaled
 
     dt = cfg.dtype
     gates = raw_gates if cfg.gates == "raw" else renormalised_gates
-    gated = cfg.expert_act == "swiglu"
+    gated = cfg.gated_experts
     y, aux = moe_ffn(
         h, layer["router"],
         tuple(layer[w] for w in (("w_gate", "w_up", "w_down") if gated
                                  else ("w_up", "w_down"))),
         top_k=cfg.top_k, gates=scaled(gates, cfg.routed_scale),
-        expert_fn=swiglu_experts if gated else relu2_experts,
+        expert_fn={"swiglu": moe.swiglu_experts, "relu2": moe.relu2_experts,
+                   "reglu": moe.reglu_experts}[cfg.expert_act],
         held=cfg.experts_held or None, scores=cfg.router_scores,
-        bias=layer["router_bias"] if cfg.router_bias else None)
+        bias=layer["router_bias"] if cfg.router_bias else None,
+        routing=routing)
     if cfg.shared_ff:
         with jax.named_scope("moe_shared"):
             if gated:
@@ -625,31 +688,74 @@ def _taken(x, y, layer, norm: str, cfg: TransformerConfig):
                 else y * cfg.residual_multiplier)
 
 
-def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
-    """One layer -> (x, aux): a mixer and a feed-forward, each a residual
-    branch behind its own norm, or one of the two alone; with `post_norms`
-    the branch's output goes through a second norm before the residual takes
-    it, and `residual_multiplier` scales what it takes. The mixer is its
-    record's (`mixers.mixer_of`), under the record's scope. aux is the expert
-    layer's `ops.moe.MoeAux` (router losses and token-choices per expert),
-    None of any other. `core`: an attention core plugged from outside.
-    `segments`: (the documents' numbers (B, S),) of packed rows, for the
-    mixer, with what the records wanted made of them behind them
-    (`_hidden`); () where a row is one document."""
+def _early_routing(x, layer, cfg: TransformerConfig):
+    """The expert layer's routing from the layer's own input x (B, S, D), as
+    it is, ahead of the first norm and the mixer (`router_input` "layer") ->
+    `ops.moe.Routing`: the router's product in float32, its scores and the
+    top_k under `moe_early_router`, and on one shard the order of the
+    token-choices under `moe_plan`, both inside `moe`. Nothing here reads what
+    the mixer computes, so the sort can run beside it. The order goes on under
+    the name `moe_plan` (T x top_k int32: 0.4 MB a layer of 16,384 tokens
+    and 6 a token): a layer that is run again keeps it (`_layer_again`) and
+    makes the router's scores again, which the gates' derivative reads, but
+    not the sort."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from kungfu_tpu.ops.moe import Routing, dispatch_plan, route
+
+    B, S, D = x.shape
+    with jax.named_scope("moe"):
+        with jax.named_scope("moe_early_router"):
+            made = route(x.reshape(B * S, D), layer["router"], cfg.top_k,
+                         cfg.router_scores,
+                         layer["router_bias"] if cfg.router_bias else None)
+        with jax.named_scope("moe_plan"):
+            plan = dispatch_plan(made[3], cfg.n_experts,
+                                 cfg.experts_held or None)
+            return Routing(made, plan._replace(
+                order=checkpoint_name(plan.order, "moe_plan")))
+
+
+def _mixed(x, layer, cfg: TransformerConfig, core=None, segments=()):
+    """The residual stream x with the layer's first branch in it -> (x, the
+    layer's indexer loss or None): the mixer, its record's
+    (`mixers.mixer_of`), under the record's scope; x as it is of a layer
+    that is its feed-forward alone."""
     segments, marks = segments[:1], segments[1:]
     mixer, index_kl = mixer_of(cfg), None
     if mixer is not None:
         with jax.named_scope(mixer.scope):
             y, index_kl = mixer.apply(x, layer, cfg, core, segments, marks)
             x = _taken(x, y, layer, "ln1_post_scale", cfg)
-    x, aux = _feed_forward(x, layer, cfg)
+    return x, index_kl
+
+
+def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
+    """One layer -> (x, aux): a mixer and a feed-forward, each a residual
+    branch behind its own norm, or one of the two alone; with `post_norms`
+    the branch's output goes through a second norm before the residual takes
+    it, and `residual_multiplier` scales what it takes. The mixer is its
+    record's (`_mixed`). An expert layer whose router reads the layer's input
+    (`router_input` "layer") is routed first, ahead of the mixer
+    (`_early_routing`). aux is the expert
+    layer's `ops.moe.MoeAux` (router losses and token-choices per expert),
+    None of any other. `core`: an attention core plugged from outside.
+    `segments`: (the documents' numbers (B, S),) of packed rows, for the
+    mixer, with what the records wanted made of them behind them
+    (`_hidden`); () where a row is one document."""
+    routing = None
+    if cfg.ffn == "moe" and cfg.router_input == "layer":
+        routing = _early_routing(x, layer, cfg)
+    x, index_kl = _mixed(x, layer, cfg, core, segments)
+    x, aux = _feed_forward(x, layer, cfg, routing)
     # an indexer's record beside the expert layer's
     return x, aux if index_kl is None else LayerAux(aux, index_kl)
 
 
-def _feed_forward(x, layer, cfg: TransformerConfig):
+def _feed_forward(x, layer, cfg: TransformerConfig, routing=None):
     """A layer's second branch on the residual stream x -> (x, the expert
-    layer's aux or None)."""
+    layer's aux or None); `routing`, an expert layer's made ahead of the
+    mixer (`_early_routing`), or None."""
     dt, eps = cfg.dtype, cfg.norm_eps
     if cfg.ffn == "none":
         return x, None
@@ -657,7 +763,7 @@ def _feed_forward(x, layer, cfg: TransformerConfig):
         with jax.named_scope("moe"):
             B, S, D = x.shape
             h = _rmsnorm(x, _scale(layer["ln2_scale"], cfg), eps).reshape(B * S, D)
-            y, aux = _expert_layer(h, layer, cfg)
+            y, aux = _expert_layer(h, layer, cfg, routing)
             return _taken(x, y.reshape(B, S, D), layer, "ln2_post_scale",
                           cfg), aux
     with jax.named_scope("ffn"):
@@ -686,7 +792,11 @@ def _feed_forward(x, layer, cfg: TransformerConfig):
 # learned sparse index the choice is kept as a bit a pair (`dsa_chosen`, 8.4 MB
 # a layer of 8,192 positions) beside its core's output and row sums: the
 # indexer's scores are made again (268 MB a layer, read by the indexer's
-# loss), the search for each query's keys is not.
+# loss), the search for each query's keys is not. Of an expert layer routed
+# ahead of its mixer (`router_input` "layer") the order of the token-choices
+# is kept (`moe_plan`, 0.4 MB a layer of 98,304 choices): the router's
+# product, scores and top-k are made again, for the gates' derivative, the
+# stable sort is not.
 # Under a loop every application of a layer keeps its own: the Ouro cell's 32
 # applications (8 layers x 4 loop steps) of 16 heads of 128 at 4,096 positions
 # keep 2 x 16.8 MB each and the row sums, 1.08 GB a step beside the 0.82 GB of
@@ -695,7 +805,7 @@ def _feed_forward(x, layer, cfg: TransformerConfig):
 _layer_again = jax.checkpoint(
     _layer, static_argnums=(2,), prevent_cse=False,
     policy=jax.checkpoint_policies.save_only_these_names(
-        "flash_out", "flash_lse", "gdn_mix", "dsa_chosen"))
+        "flash_out", "flash_lse", "gdn_mix", "dsa_chosen", "moe_plan"))
 
 
 def _block(x, layer, cfg: TransformerConfig, core=None):
@@ -781,7 +891,7 @@ _xent.defvjp(_xent_fwd, _xent_bwd)
 
 def _embed(params, tokens, cfg: TransformerConfig):
     S = tokens.shape[1]
-    if S > cfg.max_seq and cfg.positions == "rope":
+    if S > cfg.max_seq and cfg.rotary:
         raise ValueError(f"sequence {S} exceeds max_seq {cfg.max_seq}")
     dt = cfg.dtype
     with jax.named_scope("embed"):
@@ -1169,6 +1279,58 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     return stats
 
 
+def _each_layer(params, cfg: TransformerConfig):
+    """(the layer's kind, its leaves with no layer axis) of every layer, in
+    the model's order: for what goes through the layers one after another
+    beside the step, and not in a scan."""
+    stacks = params["layers"] if cfg.layer_kinds else (params["layers"],)
+    for (kind, n), stacked in zip(cfg.stacks, stacks, strict=True):
+        for at in range(n):
+            yield kind, jax.tree.map(lambda leaf: leaf[at], stacked)
+
+
+def gate_zero_shares(params, tokens, cfg: TransformerConfig):
+    """What relu-gated experts (`expert_act` "reglu") leave for a kernel to
+    skip, expert layer by expert layer, for tokens (B, S): (L,) float32, the
+    share of the elements of relu(W_gate,e m) that are exactly 0 over the
+    rows computed here, token-choice (t, e) with e held, m the normed state
+    the experts read. Each such element multiplies a row of W_down,e that
+    need not be read. Jit this beside the step, as `routing_stats`; the
+    layers one after another, a held expert at a time over all the tokens in
+    the compute type, as the step makes the product."""
+    from kungfu_tpu.ops.moe import route
+
+    if not any(kind.ffn == "moe" and kind.expert_act == "reglu"
+               for kind, _ in cfg.stacks):
+        raise ValueError("gate_zero_shares: the configuration has no expert "
+                         "layer of relu-gated experts (expert_act 'reglu')")
+    shares = []
+    x = _embed(params, tokens, cfg)
+    for kind, layer in _each_layer(params, cfg):
+        routed = kind.ffn == "moe"
+        routing = (_early_routing(x, layer, kind)
+                   if routed and kind.router_input == "layer" else None)
+        mid = _mixed(x, layer, kind)[0]
+        if routed and kind.expert_act == "reglu":
+            m = _rmsnorm(mid, _scale(layer["ln2_scale"], kind),
+                         kind.norm_eps).reshape(-1, kind.d_model)
+            chosen = (routing.route if routing else route(
+                m, layer["router"], kind.top_k, kind.router_scores,
+                layer["router_bias"] if kind.router_bias else None))[3]
+            first, held = kind.experts_held or (0, kind.n_experts)
+
+            def zeros_of(e):  # called at once, in this turn of the loop
+                mine = jnp.any(chosen == first + e, axis=-1)  # (T,)
+                pre = m @ layer["w_gate"][e].astype(kind.dtype)
+                return (jnp.sum((pre <= 0) & mine[:, None], dtype=jnp.float32),
+                        jnp.sum(mine, dtype=jnp.float32) * pre.shape[-1])
+
+            zero, of = jax.lax.map(zeros_of, jnp.arange(held))
+            shares.append(jnp.sum(zero) / jnp.maximum(jnp.sum(of), 1.0))
+        x = _feed_forward(mid, layer, kind, routing)[0]
+    return jnp.stack(shares)
+
+
 def sparse_choices(params, tokens, cfg: TransformerConfig):
     """The keys every layer's lightning indexer chooses for tokens (B, S):
     (layers, B, S, S) int8, 1 where query t attends to key s. Jit this beside
@@ -1191,8 +1353,10 @@ def record_routing(stats, registry=None) -> None:
     a layer: `kungfu_moe_dropped_token_choices`, `kungfu_moe_max_over_mean_load`,
     `kungfu_moe_held_rows` and `kungfu_moe_held_share` (the token-choices
     computed here, and their share of all the layer's), per expert held
-    `kungfu_moe_expert_token_choices`, and under a selection bias
-    `kungfu_moe_bias_moved_token_choices`."""
+    `kungfu_moe_expert_token_choices`, under a selection bias
+    `kungfu_moe_bias_moved_token_choices`, and where `stats` holds
+    `gate_zero_share` (`gate_zero_shares`' row, put there by the caller)
+    `kungfu_moe_gate_zero_share`."""
     from kungfu_tpu.telemetry import metrics
 
     reg = registry or metrics.REGISTRY
@@ -1214,6 +1378,9 @@ def record_routing(stats, registry=None) -> None:
     moved = reg.gauge("kungfu_moe_bias_moved_token_choices",
                       "token-choices the router's selection bias changed",
                       ("layer",)) if "bias_moved" in stats else None
+    zero = reg.gauge("kungfu_moe_gate_zero_share",
+                     "the share of the held rows' relu(w_gate x) that is "
+                     "exactly 0", ("layer",)) if "gate_zero_share" in stats else None
     choices = stats["chosen"][0].size
     for i, row in enumerate(np.asarray(stats["counts"])):
         layer = int(stats["layer"][i])
@@ -1223,6 +1390,8 @@ def record_routing(stats, registry=None) -> None:
         share.labels(layer).set(float(stats["held_rows"][i]) / choices)
         if moved is not None:
             moved.labels(layer).set(float(stats["bias_moved"][i]))
+        if zero is not None:
+            zero.labels(layer).set(float(stats["gate_zero_share"][i]))
         for expert, n in enumerate(row):
             load.labels(layer, expert).set(float(n))
 

@@ -28,11 +28,16 @@ The gate rule and the expert function are the caller's: `switch_gates`
 (top-1 raw, top-k renormalised) with `gelu_experts` by default, `raw_gates`
 with `swiglu_experts` for OLMoE, renormalised and scaled gates with
 `relu2_experts` (two matrices, w_down (relu(w_up x))^2, their widths filled
-with zeros to what the grouped matmul runs well at) for Nemotron-H. So is
+with zeros to what the grouped matmul runs well at) for Nemotron-H,
+`reglu_experts` (w_down (relu(w_gate x) * w_up x)) for SmallThinker. So is
 the router's rule (`route`, PR 41):
 the scores are a softmax over the experts or a sigmoid an expert, and a
 selection bias an expert may be added for the choice and not for the weight
-(GLM-4.7-Flash, after DeepSeek-V3). `switch_moe` (top-1, one expert per
+(GLM-4.7-Flash, after DeepSeek-V3). And so may the routing itself be (PR 65):
+`moe_ffn(routing=...)` takes what `route` made of other rows than those the
+experts transform (SmallThinker routes from the layer's input, ahead of the
+mixer), with the order of the token-choices (`dispatch_plan`) where the caller
+made that too, in all three layouts. `switch_moe` (top-1, one expert per
 device) is the round-4 surface, a thin special case.
 """
 
@@ -153,6 +158,26 @@ def relu2_experts(rows, experts, group_sizes):
     return _relu2_down(up, w_down, group_sizes)[:, :D]
 
 
+@functools.partial(jax.checkpoint, prevent_cse=False)
+def _relu_gate_down(gate, up, w_down, group_sizes):
+    """(relu(gate) * up) @ w_down over the groups. Keeps gate, up and w_down;
+    the relu, the product and with them the matmul's operand are recomputed,
+    as `_silu_gate_down`'s are."""
+    return lax.ragged_dot(jax.nn.relu(gate) * up, w_down, group_sizes)
+
+
+def reglu_experts(rows, experts, group_sizes):
+    """Three-matrix relu-gated experts (SmallThinker's sparse ReGLU): experts
+    = (w_gate (e, D, F), w_up (e, D, F), w_down (e, F, D)); y = w_down
+    (relu(w_gate x) * w_up x). `swiglu_experts` with the relu in the silu's
+    place: where the gate's product is not positive the row of w_down is
+    multiplied by an exact zero (`transformer.gate_zero_shares` counts them)."""
+    w_gate, w_up, w_down = (w.astype(rows.dtype) for w in experts)
+    gate = lax.ragged_dot(rows, w_gate, group_sizes)
+    up = lax.ragged_dot(rows, w_up, group_sizes)
+    return _relu_gate_down(gate, up, w_down, group_sizes)
+
+
 @jax.custom_vjp
 def _permute_rows(x, perm, inverse):
     """x[perm] for a permutation `perm` of x's rows with `inverse` its
@@ -239,6 +264,53 @@ def bias_moved(probs, top_idx):
     _, plain = lax.top_k(probs, top_idx.shape[-1])
     same = (top_idx[:, :, None] == plain[:, None, :]).any(-1)
     return jnp.sum(~same).astype(jnp.int32)
+
+
+class Plan(NamedTuple):
+    """The order of one shard's token-choices (`dispatch_plan`): `order`
+    (T * top_k,) int32 lists the token-choices c = t * top_k + j by expert,
+    under `held` the held experts' groups first and then every choice that
+    fell elsewhere; `back` its inverse (None under `held`, whose combine
+    scatters by token); `counts` (E,) int32 the choices an expert."""
+    order: jax.Array
+    back: jax.Array
+    counts: jax.Array
+
+
+class Routing(NamedTuple):
+    """A routing made outside `moe_ffn`, of whatever rows the caller routes
+    from: `route`, the four that `route` gives (logits, scores, the chosen
+    experts' scores, the chosen experts), and `plan`, `dispatch_plan` of the
+    choice where the caller made that too (one shard and a share; across an
+    expert axis the buckets are the plan, and `moe_ffn` makes them)."""
+    route: tuple
+    plan: Plan = None
+
+
+def dispatch_plan(top_idx, n_experts: int, held=None) -> Plan:
+    """The plan of the choice `top_idx` (T, top_k) over `n_experts` experts
+    on one shard, of which `held` = (first, count) are here (None: all): a
+    stable sort of the T * top_k token-choices by expert and the count an
+    expert. It depends on the choice alone, so whoever has the choice can
+    make it, beside whatever the layer computes before its experts. A share
+    of every expert is no share, as in `moe_ffn`."""
+    T, top_k = top_idx.shape
+    if held is not None and held[1] == n_experts:
+        held = None
+    # token-choice c = t * top_k + j; `order` lists them by expert
+    flat = top_idx.reshape(T * top_k)
+    group, back = flat, None
+    if held is not None:
+        # the held experts' groups first, in their order, then
+        # every token-choice that fell elsewhere
+        first, count = held
+        group = jnp.where((flat >= first) & (flat < first + count),
+                          flat - first, count)
+    order = jnp.argsort(group, stable=True)
+    if held is None:
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * top_k, dtype=order.dtype))  # order's inverse
+    return Plan(order, back, _count_choices(flat, n_experts))
 
 
 def _aux(logits, probs, counts, chosen, biased: bool = False) -> MoeAux:
@@ -378,7 +450,7 @@ def _share_chunk(T: int, top_k: int, held: int, n_experts: int) -> int:
 def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
             top_k: int = 1, capacity_factor: float = 1.25,
             gates=switch_gates, expert_fn=gelu_experts, held=None,
-            scores: str = "softmax", bias=None):
+            scores: str = "softmax", bias=None, routing: Routing = None):
     """x (T, D) tokens on this shard; router_w (D, E); `experts` a tuple of
     THIS device's expert weight stacks (leading dim = experts per device,
     epd; E = axis_size * epd), handed to `expert_fn(rows, experts,
@@ -401,7 +473,14 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
     scores, and a selection bias (E,) that moves the choice alone; `gates`
     sees the chosen experts' scores. A share (`held`) under a bias computes
     every chunk its rows reach whole, the rows of no group as zeros in the
-    last group: its cost is its chunks', not its rows' (PERF.md, PR 43)."""
+    last group: its cost is its chunks', not its rows' (PERF.md, PR 43).
+
+    `routing`: a `Routing` made elsewhere, in any of the three layouts: the
+    experts then transform x under a choice and gates that `route` made of
+    other rows (a router that reads the layer's input, ahead of the mixer),
+    `router_w` says the router's width alone, and the gates' derivative goes
+    where the routing came from. Its `plan`, where it has one, is the order
+    this function would have sorted out of the same choice."""
     if top_k < 1:
         raise ValueError(f"top_k must be at least 1, got {top_k}")
     T, D = x.shape
@@ -422,27 +501,23 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
         )
     if top_k > E:
         raise ValueError(f"top_k {top_k} exceeds the {E} experts")
+    if routing is not None and routing.route[3].shape != (T, top_k):
+        raise ValueError(
+            f"a routing of {routing.route[3].shape} choices for {T} rows "
+            f"and {top_k} experts a row")
 
     with jax.named_scope("moe_router"):
-        logits, probs, top_probs, top_idx = route(x, router_w, top_k, scores,
-                                                  bias)
+        logits, probs, top_probs, top_idx = (
+            route(x, router_w, top_k, scores, bias) if routing is None
+            else routing.route)
         gate = gates(top_probs)  # (T, top_k), float32
 
     if axis_size == 1:
-        with jax.named_scope("moe_dispatch"):
-            # token-choice c = t * top_k + j; `order` lists them by expert
-            flat = top_idx.reshape(T * top_k)
-            group = flat
-            if held is not None:
-                # the held experts' groups first, in their order, then
-                # every token-choice that fell elsewhere
-                group = jnp.where((flat >= first) & (flat < first + count),
-                                  flat - first, count)
-            order = jnp.argsort(group, stable=True)
-            if held is None:
-                back = jnp.zeros_like(order).at[order].set(
-                    jnp.arange(T * top_k, dtype=order.dtype))  # order's inverse
-            counts = _count_choices(flat, E)
+        if routing is None or routing.plan is None:
+            with jax.named_scope("moe_dispatch"):
+                order, back, counts = dispatch_plan(top_idx, E, held)
+        else:
+            order, back, counts = routing.plan
         if held is not None:
             sizes = counts[first:first + count]
             chunk = _share_chunk(T, top_k, count, E)

@@ -20,7 +20,8 @@ from jax.sharding import PartitionSpec
 
 from benchmark import harness, manifest as mf
 from benchmark.families import (glm4_moe_lite, granite_hybrid, keye_vl2, laguna,
-                                lfm2_moe, nemotron_h, ouro, qwen3_next)
+                                lfm2_moe, nemotron_h, ouro, qwen3_next,
+                                smallthinker)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import param_pspecs
 from kungfu_tpu.ops import gated_norm, moe
@@ -421,8 +422,44 @@ KEYE_VL2 = Family(
             "embed", "head_loss"),
     recomputed=((),))  # the cell's layers are run again: against none that are
 
+# two periods of the cell's pattern, so that a full layer follows window
+# layers: a full-attention layer without positions and three window-16 layers
+# with rotary positions, twice (four stacks); 4 query heads on 2 key/value heads
+# of 16; 8 relu-gated experts of which numbers 2 to 5 are held, 3 a token,
+# routed from the layer's input; 64 positions in blocks of 16, so that a band
+# is two blocks wide; the routers trained, so that every leaf has a gradient
+# to compare; the reference in four blocks of rows
+SMALLTHINKER = Family(
+    name="smallthinker", cell="smallthinker_21b_a3b.ssgd_swa_nope_1chip",
+    module=smallthinker,
+    tiny=dict(hidden_size=64, moe_ffn_hidden_size=32, num_hidden_layers=8,
+              num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+              sliding_window_size=16, rope_layout=[0, 1, 1, 1] * 2,
+              sliding_window_layout=[0, 1, 1, 1] * 2,
+              moe_num_primary_experts=4, first_expert_held=2,
+              published={"moe_num_primary_experts": 8},
+              moe_num_active_primary_experts=3, vocab_size=320,
+              sequence_length=64, flash_blocks=[16, 16], flash_interpret=True,
+              compute_dtype="float32", routers_trained=True,
+              reference_query_block=16, reference_position_block=16),
+    scales={"wq": 6.0, "wk": 6.0, "wv": 8.0, "wo": 3.0, "router": 20.0,
+            "w_gate": 8.0, "w_up": 8.0, "w_down": 8.0},
+    norms=("ln1_scale", "ln2_scale"),
+    expert_layers=tuple(range(8)), held_share=(0.3, 0.7),
+    scopes=("moe/moe_early_router/", "moe/moe_plan/", "attn/attn_window/attn_core",
+            "attn/attn_full/attn_core", "rope/", "moe/moe_router",
+            "moe_dispatch/", "moe_experts/", "moe_combine/", "embed",
+            "head_loss"),
+    recomputed=((),))  # the cell's layers are run again: against none that are
+
+# one period of it, for the faults' file and the family's own cases: every
+# fault is a program of its own, and four layers are two stacks for four
+SMALLTHINKER_ONE_PERIOD = dataclasses.replace(SMALLTHINKER, tiny={
+    **SMALLTHINKER.tiny, "num_hidden_layers": 4, "rope_layout": [0, 1, 1, 1],
+    "sliding_window_layout": [0, 1, 1, 1]}, expert_layers=tuple(range(4)))
+
 FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H, OURO,
-            GRANITE_HYBRID, LFM2_MOE, KEYE_VL2)
+            GRANITE_HYBRID, LFM2_MOE, KEYE_VL2, SMALLTHINKER)
 
 
 def pytest_generate_tests(metafunc):
@@ -598,20 +635,24 @@ def test_routers_that_are_not_trained_get_no_gradient_and_change_no_other(family
                 np.testing.assert_array_equal(reference[name], full[name])
 
 
-def shares_add_up(n, w, cfg, held, want, chosen, shared):
+def shares_add_up(n, w, cfg, held, want, chosen, shared, routed_from=None):
     """Model-configs guide, section 4: one expert layer of `cfg` on rows n
     (T, D) with weights w, cut into shares of `held` experts. Each share
     routes over all the experts and computes its own experts' part and the
     shared expert, which every chip computes alike; the parts of all, the
     shared expert's `shared` counted once, are the uncut reference's `want`
-    for the whole layer, and their counts those of its choices `chosen`."""
+    for the whole layer, and their counts those of its choices `chosen`.
+    `routed_from`: the rows (T, D) a layer that routes ahead of its mixer
+    reads in the place of n (`router_input` "layer")."""
     choices = cfg.top_k * n.shape[0]
 
     def share(first):
         mine = {name: leaf[first:first + held] if leaf.ndim == 3 else leaf
                 for name, leaf in w.items()}
-        return transformer._expert_layer(
-            n, mine, dataclasses.replace(cfg, experts_held=(first, held)))
+        own = dataclasses.replace(cfg, experts_held=(first, held))
+        routing = None if routed_from is None else transformer._early_routing(
+            routed_from[None], mine, own)
+        return transformer._expert_layer(n, mine, own, routing)
 
     parts = [share(first) for first in range(0, cfg.n_experts, held)]
     total = sum(y for y, _ in parts) - (len(parts) - 1) * shared

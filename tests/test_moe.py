@@ -822,3 +822,147 @@ def test_the_forward_pass_counts_without_a_scatter(held):
     else:  # the walk reaches the share's loop
         assert [operand for operand, _, _ in scalars] == [(count,)]
         assert [operand for operand, _, _ in adds if len(operand) == 2] == [x.shape]
+
+
+def test_reglu_experts_are_three_matrices_over_the_groups():
+    """`reglu_experts`: rows in groups, one group an expert, y = w_down
+    (relu(w_gate x) * w_up x), values and every gradient against a loop over
+    the experts; rows past the groups' sum give nothing; the gated product is
+    made again in the backward pass and not kept."""
+    from kungfu_tpu.ops import moe
+
+    E, D, F = 4, 16, 8
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    sizes = jnp.asarray([5, 0, 7, 3], jnp.int32)
+    rows = jax.random.normal(ks[0], (20, D))  # 15 in groups, 5 in none
+    w_gate, w_up = (0.5 * jax.random.normal(k, (E, D, F)) for k in ks[1:3])
+    w_down = 0.5 * jax.random.normal(ks[3], (E, F, D))
+
+    def by_hand(rows, w_gate, w_up, w_down):
+        out, at = [], 0
+        for e, n in enumerate(sizes.tolist()):
+            x = rows[at:at + n]
+            out.append((jnp.maximum(x @ w_gate[e], 0.0) * (x @ w_up[e])) @ w_down[e])
+            at += n
+        return jnp.concatenate(out + [jnp.zeros((20 - at, D))])
+
+    weights = (w_gate, w_up, w_down)
+    weight = jax.random.normal(jax.random.PRNGKey(1), (20, D))
+
+    def both(f):
+        """f's values and, of its sum weighed by `weight`, every gradient."""
+        return jax.jit(lambda *a: (f(*a), jax.grad(
+            lambda *a: jnp.sum(f(*a) * weight), (0, 1, 2, 3))(*a)))(rows, *weights)
+
+    got, grads = both(lambda *a: moe.reglu_experts(a[0], a[1:], sizes))
+    want, wants = both(by_hand)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    assert not np.asarray(got[15:]).any()
+    for g, w in zip(grads, wants):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-5)
+    assert not np.asarray(grads[1][1]).any()  # the expert with no row
+    # silu in the relu's place is another function
+    assert not np.allclose(np.asarray(moe.swiglu_experts(rows, weights, sizes)),
+                           np.asarray(got), atol=1e-2)
+    kept = jax.make_jaxpr(jax.grad(lambda r: jnp.sum(moe.reglu_experts(
+        r, weights, sizes))))(rows)
+    assert "checkpoint" in str(kept) or "remat" in str(kept)
+
+
+def _routed_elsewhere(layout, top_k=2):
+    """(with a routing handed in, making the same one itself) of `moe_ffn` in
+    one of its three layouts, on the same rows: outputs, counts, chosen."""
+    from kungfu_tpu.ops.moe import (Routing, dispatch_plan, moe_ffn,
+                                    reglu_experts, renormalised_gates, route)
+
+    T, D, F, E = 32, 8, 16, 8
+    x = jax.random.normal(jax.random.PRNGKey(0), (T, D), jnp.float32)
+    router_w = jax.random.normal(jax.random.PRNGKey(1), (D, E), jnp.float32)
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    w_gate, w_up = (0.3 * jax.random.normal(k, (E, D, F)) for k in ks[:2])
+    w_down = 0.3 * jax.random.normal(ks[2], (E, F, D))
+    how = dict(top_k=top_k, gates=renormalised_gates, expert_fn=reglu_experts)
+
+    def layer(x, router_w, experts, given, planned=True, **where):
+        routing = None
+        if given:
+            made = route(x, router_w, top_k)
+            plan = (dispatch_plan(made[3], E, where.get("held"))
+                    if planned and "axis_name" not in where else None)
+            routing = Routing(made, plan)
+        out, aux = moe_ffn(x, router_w, experts, **how, **where, routing=routing)
+        return out, aux.counts, aux.chosen
+
+    if layout == "axis":
+        ep = 4
+        mesh = _ep_mesh(ep)
+
+        def over_the_axis(given):
+            fn = shard_map(
+                lambda x, r, *w: layer(x, r, tuple(a[0] for a in w), given,
+                                       axis_name="ep", axis_size=ep,
+                                       capacity_factor=float(E)),
+                mesh=mesh, in_specs=(P("ep"), P(), P("ep"), P("ep"), P("ep")),
+                out_specs=(P("ep"), P("ep"), P("ep")), check_vma=False)
+            return jax.jit(fn)(x, router_w, *(w.reshape(ep, E // ep, *w.shape[1:])
+                                              for w in (w_gate, w_up, w_down)))
+
+        return over_the_axis(True), over_the_axis(False)
+    held = (2, 4) if layout == "share" else None
+    experts = tuple(w[2:6] if held else w for w in (w_gate, w_up, w_down))
+    where = {"held": held} if held else {}
+    runs = [jax.jit(lambda x, r, given=given, planned=planned: layer(
+        x, r, experts, given, planned, **where))(x, router_w)
+        for given, planned in ((True, True), (True, False), (False, True))]
+    for a, b in zip(runs[0], runs[1]):  # with its plan, and the plan left to moe_ffn
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    return runs[0], runs[2]
+
+
+@pytest.mark.parametrize("layout", ["one_shard", "share", "axis"])
+def test_moe_ffn_given_a_routing_is_moe_ffn_making_the_same_one(layout):
+    """One signature in the three layouts: a `Routing` that `route` made of
+    the same rows, with `dispatch_plan`'s order or without it, gives the
+    output, counts and choices that `moe_ffn` gives making its own."""
+    given, made = _routed_elsewhere(layout)
+    for a, b in zip(given, made):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_routing_from_other_rows_moves_the_gates_derivative_there():
+    """Routed from rows h that are not the rows x the experts transform: the
+    gates' derivative reaches h, and x's is what it is under a routing that
+    is a constant; a routing of another shape is refused."""
+    from kungfu_tpu.ops.moe import (Routing, dispatch_plan, moe_ffn,
+                                    reglu_experts, renormalised_gates, route)
+
+    T, D, F, E, top_k = 24, 8, 16, 8, 3
+    ks = jax.random.split(jax.random.PRNGKey(4), 6)
+    x, h = (jax.random.normal(k, (T, D)) for k in ks[:2])
+    router_w = jax.random.normal(ks[2], (D, E))
+    experts = tuple(0.3 * jax.random.normal(k, shape) for k, shape in zip(
+        ks[3:], ((E, D, F), (E, D, F), (E, F, D))))
+    held = (2, 4)
+    mine = tuple(w[2:6] for w in experts)
+
+    def out(x, h, constant=False):
+        made = route(h, router_w, top_k)
+        routing = Routing(made, dispatch_plan(made[3], E, held))
+        if constant:
+            routing = jax.lax.stop_gradient(routing)
+        y, aux = moe_ffn(x, router_w, mine, top_k=top_k, gates=renormalised_gates,
+                         expert_fn=reglu_experts, held=held, routing=routing)
+        return jnp.sum(jnp.square(y)), aux.chosen
+
+    (dx, dh), chosen = jax.jit(jax.grad(out, (0, 1), has_aux=True))(x, h)
+    (dx_fixed, dh_fixed), _ = jax.jit(jax.grad(
+        lambda x, h: out(x, h, True), (0, 1), has_aux=True))(x, h)
+    assert np.asarray(dh).any() and not np.asarray(dh_fixed).any()
+    np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_fixed), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(chosen),
+                                  np.asarray(route(h, router_w, top_k)[3]))
+    assert (np.asarray(chosen) != np.asarray(route(x, router_w, top_k)[3])).any()
+    with pytest.raises(ValueError, match="a routing of"):
+        moe_ffn(x[:8], router_w, mine, top_k=top_k, held=held,
+                routing=Routing(route(h, router_w, top_k)))
