@@ -1242,7 +1242,10 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     `chosen` (L, B * S, top_k) the experts each token took; `layer` (L,)
     which of the model's layers each row is; under a selection bias
     (`router_bias`) `bias_moved` (L,), the token-choices that the bias
-    changed against a choice on the scores alone. With a
+    changed against a choice on the scores alone; of a share of the experts
+    (`experts_held`) `chunk_rows` (L,), the rows of the chunks that the
+    share's loop ran (`ops.moe._live_chunks` times `_share_chunk`, the two
+    that the layer itself asks), of which `held_rows` lie in a group. With a
     multi-token-prediction module, tokens (B, S + 1): the module reads the
     ids one further on, and where its block has an expert layer that is the
     last row, `layer` = n_layers."""
@@ -1276,6 +1279,14 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     }
     if aux.bias_moved is not None:
         stats["bias_moved"] = aux.bias_moved
+    if held < moe.n_experts:
+        from kungfu_tpu.ops.moe import _live_chunks, _share_chunk
+
+        chunk = _share_chunk(tokens.size, moe.top_k, held, moe.n_experts,
+                             moe.router_bias)
+        stats["chunk_rows"] = chunk * jnp.asarray(
+            [_live_chunks(moe.top_k, chunk, tokens.size, sizes)
+             for sizes in counts], jnp.int32)
     return stats
 
 
@@ -1352,7 +1363,10 @@ def record_routing(stats, registry=None) -> None:
     """`routing_stats`' numbers as gauges of `telemetry.metrics`, a series
     a layer: `kungfu_moe_dropped_token_choices`, `kungfu_moe_max_over_mean_load`,
     `kungfu_moe_held_rows` and `kungfu_moe_held_share` (the token-choices
-    computed here, and their share of all the layer's), per expert held
+    computed here, and their share of all the layer's), of a share of the
+    experts `kungfu_moe_chunk_fill_share` (the held rows over the rows of
+    the chunks that ran, 1 where none did: the rest are rows of no group
+    that were gathered, weighed and scattered all the same), per expert held
     `kungfu_moe_expert_token_choices`, under a selection bias
     `kungfu_moe_bias_moved_token_choices`, and where `stats` holds
     `gate_zero_share` (`gate_zero_shares`' row, put there by the caller)
@@ -1375,6 +1389,9 @@ def record_routing(stats, registry=None) -> None:
     share = reg.gauge("kungfu_moe_held_share",
                       "held rows over all the layer's token-choices",
                       ("layer",))
+    fill = reg.gauge("kungfu_moe_chunk_fill_share",
+                     "held rows over the rows of the share's chunks that ran",
+                     ("layer",)) if "chunk_rows" in stats else None
     moved = reg.gauge("kungfu_moe_bias_moved_token_choices",
                       "token-choices the router's selection bias changed",
                       ("layer",)) if "bias_moved" in stats else None
@@ -1388,6 +1405,10 @@ def record_routing(stats, registry=None) -> None:
         skew.labels(layer).set(float(stats["max_over_mean"][i]))
         rows.labels(layer).set(float(stats["held_rows"][i]))
         share.labels(layer).set(float(stats["held_rows"][i]) / choices)
+        if fill is not None:
+            ran = float(stats["chunk_rows"][i])
+            fill.labels(layer).set(float(stats["held_rows"][i]) / ran
+                                   if ran else 1.0)
         if moved is not None:
             moved.labels(layer).set(float(stats["bias_moved"][i]))
         if zero is not None:

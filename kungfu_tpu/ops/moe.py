@@ -325,24 +325,18 @@ def _aux(logits, probs, counts, chosen, biased: bool = False) -> MoeAux:
                   bias_moved(probs, chosen) if biased else None)
 
 
-def _all_in_one(top_k: int, chunk: int, T: int, sizes) -> bool:
-    """Whether one chunk holds every row that can fall on the held experts
-    (`_share_chunk` makes it so for a share of an eighth or more)."""
-    return chunk >= T * min(top_k, sizes.shape[0])
-
-
 def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
                 sizes, i, whole: bool = False):
     """What rows i * chunk to (i + 1) * chunk of a share's row order add to
     the layer: (T, D) float32. Rows past the last group are token-choices
     that fell elsewhere: they go in as zeros and weigh nothing, in both
     passes (the grouped matmul leaves whatever it finds in a row of no
-    group). A row is weighed by its gate where it lies. In a chunk of all
-    that can fall here (`_all_in_one`) those rows are the last group's: the
-    grouped matmul costs what its groups hold (5.8 to 16.6 ms a step with
-    the rows that came: PERF.md, PR 41), and there the cost is to be the
-    buffer's whatever came. So it is in every chunk that runs `whole`
-    (`moe_ffn`: a share under a selection bias)."""
+    group). A row is weighed by its gate where it lies. The grouped matmul
+    costs what its groups hold (5.8 to 16.6 ms a step with the rows that
+    came: PERF.md, PR 41), so the groups are the rows that came and the
+    chunk costs them; but in a chunk that runs `whole` (`moe_ffn`: a share
+    under a selection bias) those rows are the last group's, up to the
+    chunk's end, and the chunk costs its buffer whatever came."""
     T, D = x.shape
     ends = jnp.cumsum(sizes)
     lo = i * chunk
@@ -352,7 +346,9 @@ def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
     # the part of each expert's group that lies in this chunk
     here = (jnp.clip(ends, lo, lo + chunk)
             - jnp.clip(ends - sizes, lo, lo + chunk))
-    if _all_in_one(top_k, chunk, T, sizes):
+    if whole and chunk >= T * min(top_k, sizes.shape[0]):
+        # one chunk of all that can fall here starts at row 0 and ends past
+        # every group: the same number with nothing to clip
         here = here.at[-1].add(chunk - ends[-1])
     elif whole:
         here = here.at[-1].add(lo + chunk - jnp.clip(ends[-1], lo, lo + chunk))
@@ -367,12 +363,16 @@ def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
 
 
 def _live_chunks(top_k: int, chunk: int, T: int, sizes):
-    """Chunks of `chunk` rows that the held groups `sizes` fill. A chunk of
-    all that can fall here runs whatever came, none included: a count that
-    is no data, so the step's work does not move with the routing at all."""
-    if _all_in_one(top_k, chunk, T, sizes):
+    """Chunks of `chunk` rows that the held groups `sizes` fill: none where
+    nothing came. But one chunk of all T * min(top_k, held) rows that can
+    fall here is run once whatever came, none included: a loop of no or one
+    turn is no loop (as a `while` the SmallThinker cell's read 15 ms a step
+    and 0.78 GB more than unrolled: PERF.md, PR 66), and under a selection
+    bias, where `_share_chunk` makes that one chunk of two, the count is to
+    be no data. `sizes` may be numpy's."""
+    if chunk >= T * min(top_k, sizes.shape[0]):
         return 1
-    return (jnp.sum(sizes) + chunk - 1) // chunk
+    return (sizes.sum() + chunk - 1) // chunk
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
@@ -383,10 +383,10 @@ def _held_part(expert_fn, top_k: int, chunk: int, whole: bool, x, gate,
     (`sizes` (held,) of them an expert). Dropless whatever the load: the
     rows are taken `chunk` at a time, as many chunks as the groups fill
     (`_live_chunks`), up to all T * min(top_k, held) rows that can fall
-    here. So the work is
-    that of the rows that came (of the chunks they fill, where a chunk runs
-    `whole`: `_chunk_part`), and the memory that of one chunk (PERF.md, PR
-    33).
+    here. So the work is that of the rows that came, and the memory that of
+    one chunk (PERF.md, PR 33); `whole`, what `moe_ffn` says of a share
+    under a selection bias, makes the work that of the chunks the rows
+    fill (`_chunk_part`).
     The loop's length is data, so the backward pass is written out: the
     same loop, each chunk run again and transposed (nothing is kept but the
     arguments), its cotangents added up in float32."""
@@ -429,22 +429,30 @@ def _held_part_bwd(expert_fn, top_k, chunk, whole, res, g):
 _held_part.defvjp(_held_part_fwd, _held_part_bwd)
 
 
-def _share_chunk(T: int, top_k: int, held: int, n_experts: int) -> int:
+def _share_chunk(T: int, top_k: int, held: int, n_experts: int,
+                 biased: bool = False) -> int:
     """Rows to a chunk of a share's row order: four times what a balanced
     router sends to `held` of `n_experts` experts, so that one chunk is
     the usual case and a step's work does not move with the routing (a
     router with no balancing loss, trained 80 steps on the Laguna cell's
     pool, sends its held experts up to 2.6 times the balanced load, from
-    1.0 at the start: PERF.md, PR 33); never more than can fall here. And
-    where two such chunks hold all that can fall here (a share of an eighth
-    of the experts or more), one chunk of all of it: routers that follow a
-    vector the positions share send a share none of a batch's rows or most
-    of them, and a loop of no, one or two chunks is a step of three
-    lengths (8 of 64 held behind full attention: PERF.md, PR 41)."""
+    1.0 at the start: PERF.md, PR 33); never more than can fall here. But
+    under a selection bias (`biased`), where two such chunks hold all that
+    can fall here (a share of an eighth of the experts or more), one chunk
+    of all of it: such routers, whose balance is a step's that this program
+    does not run, follow a vector the positions share and send a share none
+    of a batch's rows or most of them, and a loop of no, one or two chunks
+    is a step of three lengths (8 of 64 held behind full attention:
+    PERF.md, PR 41). One chunk runs whatever came (`_live_chunks`). Without
+    a bias a share's work is that of the rows that came and its step follows
+    them: softmax routers with none swing as far under the cells' traffic
+    (a layer's held rows between nothing and four balanced loads from one
+    step to the next: PERF.md, PR 66), and what steadies such a cell is its
+    traffic, not a third chunk rule."""
     most = T * min(top_k, held)
     usual = -(-4 * T * top_k * held // n_experts)
     usual = -(-usual // 8) * 8
-    return most if most <= 2 * usual else usual
+    return most if biased and most <= 2 * usual else min(most, usual)
 
 
 def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
@@ -471,9 +479,13 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
 
     `scores` and `bias` are the router's rule (`route`): softmax or sigmoid
     scores, and a selection bias (E,) that moves the choice alone; `gates`
-    sees the chosen experts' scores. A share (`held`) under a bias computes
-    every chunk its rows reach whole, the rows of no group as zeros in the
-    last group: its cost is its chunks', not its rows' (PERF.md, PR 43).
+    sees the chosen experts' scores. A share's (`held`) work is its live
+    rows' unless a bias says otherwise: without one the grouped matmuls
+    get the groups that came, in chunks of four balanced loads, as many as
+    the rows fill. Under a bias every chunk the rows reach is computed
+    whole, the rows of no group as zeros in the last group, and a share of
+    an eighth or more is one chunk of all that can fall here, run whatever
+    came: its cost is its chunks', not its rows' (PERF.md, PR 43, PR 66).
 
     `routing`: a `Routing` made elsewhere, in any of the three layouts: the
     experts then transform x under a choice and gates that `route` made of
@@ -520,18 +532,21 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
             order, back, counts = routing.plan
         if held is not None:
             sizes = counts[first:first + count]
-            chunk = _share_chunk(T, top_k, count, E)
-            # every chunk of the rows that can fall here lies inside `order`
-            n = -(-T * min(top_k, count) // chunk) * chunk
-            order = jnp.pad(order, (0, max(0, n - T * top_k)))
             # Under a selection bias the layer's balance is a step's that
             # no gradient makes and this program does not run (ROADMAP
             # S19): from the initial parameters such routers send a share
             # a third of the balanced load or three times it within a few
             # steps, and the grouped matmul costs the rows its groups hold.
-            # There a live chunk costs its buffer whatever came.
-            out = _held_part(expert_fn, top_k, chunk, bias is not None, x,
-                             gate, experts, order, sizes)
+            # There a live chunk costs its buffer whatever came, and a
+            # share of an eighth or more is one chunk; anywhere else the
+            # share's work is that of the rows that came.
+            whole = bias is not None
+            chunk = _share_chunk(T, top_k, count, E, whole)
+            # every chunk of the rows that can fall here lies inside `order`
+            n = -(-T * min(top_k, count) // chunk) * chunk
+            order = jnp.pad(order, (0, max(0, n - T * top_k)))
+            out = _held_part(expert_fn, top_k, chunk, whole, x, gate,
+                             experts, order, sizes)
             with jax.named_scope("moe_router"):
                 aux = _aux(logits, probs, counts, top_idx,
                            bias is not None)._replace(counts=sizes)

@@ -125,6 +125,12 @@ class Family:
             state, self.sample()).as_text(debug_info=True)
 
     @functools.cache
+    def program(self):
+        """The jitted program at `config`, for a case that runs it on
+        several states. Never called under a patch."""
+        return self.module.program_loss_and_grads(self.config)
+
+    @functools.cache
     def baseline(self, recomputed=None):
         """The program's loss and gradients on `state()` and `sample()`, at
         `config` or with these layer kinds run again in its place: arrays,
@@ -460,6 +466,58 @@ SMALLTHINKER_ONE_PERIOD = dataclasses.replace(SMALLTHINKER, tiny={
 
 FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H, OURO,
             GRANITE_HYBRID, LFM2_MOE, KEYE_VL2, SMALLTHINKER)
+
+
+# what the held experts get of a family's choices, as the number its routers'
+# weights of the steering feature are moved by (`steered`)
+HELD_LOADS = {"balanced": 0.0, "nothing": -100.0, "every_choice": 100.0}
+
+
+def steered(family, toward: float):
+    """`family.state()` with routers that send the held experts every choice
+    (`toward` > 0) or none (< 0) whatever the token: every embedding row's
+    first feature is 4, a number the residual stream keeps positive through
+    the layers, its norm's scale 1, and the held experts' router weights of
+    that feature are moved by `toward`. The state itself at 0."""
+    state = family.state()
+    if not toward:
+        return state
+    first, held = family.module.model_config(family.config).experts_held
+
+    def steer(stack):
+        return {**stack, "ln2_scale": stack["ln2_scale"].at[:, 0].set(1.0),
+                "router": stack["router"].at[:, 0, first:first + held].add(toward)}
+
+    layers = state["layers"]
+    return {**state, "embed": state["embed"].at[:, 0].set(4.0),
+            "layers": (steer(layers) if isinstance(layers, dict)
+                       else tuple(steer(stack) for stack in layers))}
+
+
+def held_load_is_the_references(family, load: str):
+    """The float32 program against the reference, loss and gradients (the
+    logits through the loss) in the tolerance of
+    `test_float32_program_equals_the_reference`, with the held experts
+    getting `load` of `HELD_LOADS` in every expert layer; and the share's
+    chunk: one of all that can fall here at these sizes, run once whatever
+    came, with the groups that came (a share's work is its live rows', PR
+    66)."""
+    module, config = family.module, family.config
+    state, sample = steered(family, HELD_LOADS[load]), family.sample()
+    loss, grads = family.program()(state, sample)
+    want_loss, want = module.reference_loss_and_grads(config, state, sample)
+    assert off(loss, want_loss) <= 1e-5
+    assert harness.relative_error(grads, want) <= family.float32_grad_rtol
+    mc = module.model_config(config)
+    tokens = sample[:, :-1].size
+    most = tokens * min(mc.top_k, mc.experts_held[1])
+    assert moe._share_chunk(tokens, mc.top_k, mc.experts_held[1], mc.n_experts,
+                            mc.router_bias) == most
+    stats = module.routing_stats(config, state, sample)
+    rows = {"nothing": [0], "every_choice": [most]}.get(load) or range(1, most)
+    assert all(n in rows for n in stats["held_rows"]), stats["held_rows"]
+    assert stats["chunk_rows"] == [most] * len(family.expert_layers)
+    assert stats["dropped"] == [0] * len(family.expert_layers)
 
 
 def pytest_generate_tests(metafunc):

@@ -178,6 +178,11 @@ def test_the_two_losses_are_the_references_and_share_no_leaf():
                                   weight * kl["layers"]["index_wq"]) <= 1e-5
 
 
+@pytest.mark.parametrize("load", fc.HELD_LOADS)
+def test_the_model_is_the_reference_whatever_the_held_experts_get(load):
+    fc.held_load_is_the_references(FAMILY, load)
+
+
 def test_the_losses_reach_their_gauges():
     registry = metrics.Registry()
     transformer.record_losses({"main": 5.5, "indexer_kl": 0.25}, registry)

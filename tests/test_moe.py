@@ -319,61 +319,79 @@ def test_the_shares_parts_add_up_to_the_whole_layer(top_k, count, E):
                                    rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("E,chunks", [(16, 1), (32, 1), (64, 4)])
-def test_a_share_is_dropless_under_the_worst_load(E, chunks):
-    """Every token's three choices on the four experts held: all T x
-    min(top_k, held) rows that can fall here are taken, in as many chunks
-    as they fill (of 64 experts a chunk is a quarter of them; of 32, where
-    two usual chunks would hold them all, one chunk is all of them), none is
-    dropped, values and gradients are the whole layer's; with the choices
-    all elsewhere the part is zero."""
+@pytest.mark.parametrize("E,first,chunks", [(16, 4, 1), (32, 4, 2), (64, 4, 4),
+                                            (32, 8, 0), (16, 8, 1)])
+def test_a_share_is_dropless_under_the_worst_load(E, first, chunks):
+    """Every token's three choices on experts 4, 5 and 6. The four experts
+    held from 4 on get all T x min(top_k, held) rows that can fall here, in
+    as many chunks of four balanced loads as they fill (of 16 experts one
+    chunk is all of them, of 32 two, of 64 four), none is dropped, values
+    and gradients are the whole layer's. The four held from 8 on get every
+    choice elsewhere: of 32 experts no chunk runs, of 16 the one chunk of
+    all that can fall here runs over nothing, and the part and its
+    gradients are exact zeros either way."""
     moe, x, router, experts, gates = _share_setup(E=E)
-    assert -(-48 * 3 // moe._share_chunk(48, 3, 4, E)) == chunks
+    chunk = moe._share_chunk(48, 3, 4, E)
     # logits that put experts 4, 5, 6 first for every token, whatever x
     router = jnp.zeros_like(router).at[0, 4:7].set(jnp.array([3.0, 2.0, 1.0]))
     x = x.at[:, 0].set(jnp.abs(x[:, 0]) + 1.0)
-    out, aux = _share(moe, x, router, experts, gates, 3, 4, 4)
-    assert aux.counts.tolist() == [48, 48, 48, 0]
-    assert int(aux.counts.sum()) == 48 * 3  # every held choice computed
+    out, aux = _share(moe, x, router, experts, gates, 3, first, 4)
+    assert int(moe._live_chunks(3, chunk, 48, aux.counts)) == chunks
+
     def whole(x, router, experts):
         return moe.moe_ffn(x, router, experts, top_k=3, gates=gates,
                            expert_fn=moe.swiglu_experts)[0]
 
     def part(x, router, experts):
-        return _share(moe, x, router, experts, gates, 3, 4, 4)[0]
+        return _share(moe, x, router, experts, gates, 3, first, 4)[0]
 
+    grads = jax.tree.leaves(jax.grad(lambda *a: jnp.sum(part(*a) ** 2), (0, 1, 2))(
+        x, router, experts))
+    if first == 8:
+        assert aux.counts.tolist() == [0, 0, 0, 0]
+        assert float(jnp.abs(out).max()) == 0.0
+        assert all(float(jnp.abs(g).max()) == 0.0 for g in grads)
+        return
+    assert aux.counts.tolist() == [48, 48, 48, 0]
+    assert int(aux.counts.sum()) == 48 * 3  # every held choice computed
     np.testing.assert_allclose(np.asarray(out), np.asarray(whole(x, router, experts)),
                                rtol=1e-5, atol=1e-5)
     for g, w in zip(
-            jax.tree.leaves(jax.grad(lambda *a: jnp.sum(part(*a) ** 2), (0, 1, 2))(
-                x, router, experts)),
+            grads,
             jax.tree.leaves(jax.grad(lambda *a: jnp.sum(whole(*a) ** 2), (0, 1, 2))(
                 x, router, experts))):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-5)
-    nothing, aux = _share(moe, x, router, experts, gates, 3, 8, 4)
-    assert aux.counts.tolist() == [0, 0, 0, 0]
-    assert float(jnp.abs(nothing).max()) == 0.0
 
 
+@pytest.mark.parametrize("biased", [False, True], ids=["no_bias", "bias"])
 @pytest.mark.parametrize("E,one", [(16, True), (32, True), (64, False)])
-def test_a_chunk_of_all_that_can_fall_here_does_not_follow_the_routing(E, one):
-    """Where one chunk holds all that can fall on the held experts (a share
-    of an eighth of them or more) the step's work is no data: the program
-    has no loop whose count the routing gives, and the groups handed to the
-    experts fill the chunk whatever came, the rows of no group in the last
-    one, where they are zeros and weigh nothing (the values and gradients
-    of the tests above). A smaller share keeps its loop and its groups."""
+def test_a_chunk_of_all_that_can_fall_here_does_not_follow_the_routing(E, one,
+                                                                       biased):
+    """A share's work is its live rows' unless a selection bias says
+    otherwise. Under a bias, where two usual chunks hold all that can fall
+    on the held experts (a share of an eighth of them or more), one chunk
+    holds it, and the groups handed to the experts fill every chunk
+    whatever came, the rows of no group in the last one, where they are
+    zeros and weigh nothing (the values and gradients of the tests above).
+    Without a bias the chunk is four balanced loads and no more than can
+    fall here, and the groups are the rows that came, zeros past them.
+    Either way one chunk of all that can fall here is run once, whatever
+    came: the program has no loop whose count the routing gives; a smaller
+    chunk keeps its loop."""
     moe, x, router, experts, gates = _share_setup(E=E)
-    chunk = moe._share_chunk(48, 3, 4, E)
-    assert (chunk == 48 * 3) == one
+    chunk = moe._share_chunk(48, 3, 4, E, biased)
+    assert chunk == (48 * 3 if one and biased else
+                     {16: 144, 32: 72, 64: 40}[E])
     mine = tuple(w[4:8] for w in experts)
+    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(9), (E,)) if biased else None
 
     def part(x, router):
         return moe.moe_ffn(x, router, mine, top_k=3, gates=gates,
-                           expert_fn=moe.swiglu_experts, held=(4, 4))[0]
+                           expert_fn=moe.swiglu_experts, held=(4, 4),
+                           bias=bias)[0]
 
-    assert ("while" in str(jax.make_jaxpr(part)(x, router))) == (not one)
+    assert ("while" in str(jax.make_jaxpr(part)(x, router))) == (chunk < 48 * 3)
     seen = []
 
     def spy(rows, experts, sizes):
@@ -383,15 +401,91 @@ def test_a_chunk_of_all_that_can_fall_here_does_not_follow_the_routing(E, one):
     order = jnp.arange(48 * 3, dtype=jnp.int32)
     for sizes in ([5, 0, 7, 2], [0, 0, 0, 0]):  # some rows; all elsewhere
         out = moe._chunk_part(spy, 3, chunk, x, jnp.ones((48, 3)), mine, order,
-                              jnp.array(sizes, jnp.int32), 0)
+                              jnp.array(sizes, jnp.int32), 0, biased)
         rows, groups = seen.pop()
         live = sum(sizes)
         assert float(jnp.abs(rows[live:]).max()) == 0.0
-        if one:  # the rows of no group are the last group's
+        if biased:  # the rows of no group are the last group's
             assert groups.tolist() == sizes[:3] + [sizes[3] + chunk - live]
         else:
             assert groups.tolist() == sizes
         assert (live == 0) == (float(jnp.abs(out).max()) == 0.0)
+        assert int(moe._live_chunks(3, chunk, 48, np.array(sizes))) == (
+            1 if chunk == 48 * 3 else -(-live // chunk))
+
+
+# what `_share_chunk` gives at the cells' shapes: T, top_k, held, experts, bias
+CELL_CHUNKS = {
+    "glm_4_7_flash": ((8192, 4, 8, 64, True), 32768),
+    "lfm2_24b_a2b": ((8192, 4, 8, 64, True), 32768),
+    "nemotron_3_nano_30b_a3b": ((8192, 6, 8, 128, True), 12288),
+    "laguna_s_2_1": ((8192, 10, 8, 256, False), 10240),
+    "qwen3_next_80b_a3b": ((16384, 10, 32, 512, False), 40960),
+    "keye_vl_2_0_30b_a3b": ((8192, 8, 16, 128, False), 32768),
+    "smallthinker_21b_a3b": ((16384, 6, 16, 64, False), 98304),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_CHUNKS)
+def test_the_one_chunk_rule_is_the_selection_biass(cell):
+    """Under a bias a share's chunk is what it was at PR 65, one chunk of all
+    that can fall here for a share of an eighth or more (GLM-4.7-Flash,
+    LFM2) and the usual chunk below that (Nemotron-3-Nano); without one it
+    is four balanced loads and never more than can fall here: half of the
+    65,536 rows in the Keye cell, all 98,304 in the SmallThinker cell, and
+    what it was for the smaller shares (Laguna, Qwen3-Next)."""
+    from kungfu_tpu.ops import moe
+
+    (T, top_k, held, E, biased), rows = CELL_CHUNKS[cell]
+    assert moe._share_chunk(T, top_k, held, E, biased) == rows
+    most, balanced = T * min(top_k, held), T * top_k * held // E
+    assert rows == (most if biased and most <= 8 * balanced
+                    else min(most, 4 * balanced))
+
+
+def test_the_chunk_fill_share_is_the_held_rows_over_the_chunks_that_ran():
+    """`kungfu_moe_chunk_fill_share` against a count by hand: a model of two
+    expert layers that holds experts 4 to 7 of 64 (chunks of 48 rows for 64
+    tokens of 3 choices), the rows of its chunks that ran from its own
+    choices; then the gauge of stats written by hand, a layer of two chunks,
+    one of none and one under a bias's one chunk."""
+    from kungfu_tpu.models import transformer
+    from kungfu_tpu.models.transformer import TransformerConfig
+    from kungfu_tpu.telemetry import metrics
+
+    cfg = TransformerConfig.tiny_moe(n_experts=64, experts_held=(4, 4))
+    params = transformer.init_transformer(jax.random.PRNGKey(2), cfg)
+    params["layers"]["router"] = params["layers"]["router"].at[1, :, 4:8].multiply(50.0)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 32), 0, cfg.vocab_size)
+    stats = jax.jit(lambda p, t: transformer.routing_stats(p, t, cfg))(params, tokens)
+    chosen = np.asarray(stats["chosen"])
+    rows = ((chosen >= 4) & (chosen < 8)).sum(axis=(1, 2))
+    assert rows.tolist() == stats["held_rows"].tolist() and rows[1] > 48 > rows[0] > 0
+    assert stats["chunk_rows"].tolist() == (48 * -(-rows // 48)).tolist()
+    registry = metrics.Registry()
+    transformer.record_routing(stats, registry)
+    text = registry.render()
+    for layer in range(2):
+        share = rows[layer] / (48 * -(-rows[layer] // 48))
+        line = [l for l in text.splitlines() if l.startswith(
+            f'kungfu_moe_chunk_fill_share{{layer="{layer}"}}')]
+        assert float(line[0].split()[-1]) == pytest.approx(share, rel=1e-6)
+    by_hand = {"counts": np.array([[30, 20, 10, 10], [0, 0, 0, 0], [5, 0, 7, 2]]),
+               "held_rows": np.array([70, 0, 14]), "dropped": np.zeros(3),
+               "max_over_mean": np.ones(3), "chosen": np.zeros((3, 64, 3), np.int32),
+               "layer": np.array([0, 2, 5]), "chunk_rows": np.array([96, 0, 192])}
+    registry = metrics.Registry()
+    transformer.record_routing(by_hand, registry)
+    text = registry.render()
+    assert f'kungfu_moe_chunk_fill_share{{layer="0"}} {70 / 96}' in text
+    assert 'kungfu_moe_chunk_fill_share{layer="2"} 1' in text  # no chunk ran
+    assert f'kungfu_moe_chunk_fill_share{{layer="5"}} {14 / 192}' in text
+    whole = TransformerConfig.tiny_moe()  # every expert held: no chunks, no gauge
+    stats = jax.jit(lambda p, t: transformer.routing_stats(p, t, whole))(
+        transformer.init_transformer(jax.random.PRNGKey(2), whole), tokens)
+    registry = metrics.Registry()
+    transformer.record_routing(stats, registry)
+    assert "chunk_rows" not in stats and "chunk_fill_share" not in registry.render()
 
 
 def test_a_share_of_every_expert_is_the_layer_itself():
@@ -616,8 +710,8 @@ def test_under_a_selection_bias_a_live_chunk_costs_its_buffer(biased):
     without one the groups are the rows that came. Values and gradients are
     the same either way."""
     moe, x, router, experts, gates = _share_setup(E=64)
-    chunk = moe._share_chunk(48, 3, 4, 64)
-    assert chunk < 48 * 3 and not moe._all_in_one(3, chunk, 48, jnp.zeros(4))
+    chunk = moe._share_chunk(48, 3, 4, 64, biased)
+    assert chunk == 40  # four balanced loads, whatever the bias
     mine = tuple(w[4:8] for w in experts)
     seen = []
 
@@ -800,19 +894,23 @@ def test_count_choices_is_numpys_bincount(chosen, E):
     assert got.tolist() == np.bincount(inside, minlength=E).tolist()
 
 
-@pytest.mark.parametrize("held", [None, (4, 4)], ids=["whole", "held"])
-def test_the_forward_pass_counts_without_a_scatter(held):
+@pytest.mark.parametrize("held,biased", [(None, False), ((4, 4), False),
+                                         ((4, 4), True)],
+                         ids=["whole", "held", "held_under_a_bias"])
+def test_the_forward_pass_counts_without_a_scatter(held, biased):
     """No `scatter-add` of one scalar a token-choice is left in the
     layer's forward pass (the v5e applies such updates one after another:
     `ops/moe._count_choices`). What stays: the whole layer's inverse order
-    (a `scatter`, no sum), and in a share `_chunk_part`'s one-element
-    `here.at[-1].add` and its rows' way back, (chunk, D) into (T, D)."""
+    (a `scatter`, no sum), in a share its rows' way back, (chunk, D) into
+    (T, D), and under a selection bias `_chunk_part`'s one-element
+    `here.at[-1].add`, the last group's filling."""
     moe, x, router, experts, gates = _share_setup(E=16)
     first, count = held or (0, 16)
     mine = tuple(w[first:first + count] for w in experts)
+    bias = jnp.linspace(-0.1, 0.1, 16) if biased else None
     jaxpr = jax.make_jaxpr(lambda x, r, w: moe.moe_ffn(
         x, r, w, top_k=3, gates=gates, expert_fn=moe.swiglu_experts,
-        held=held))(x, router, mine)
+        held=held, bias=bias))(x, router, mine)
     adds = [[v.aval.shape for v in eqn.invars] for eqn in _all_eqns(jaxpr.jaxpr)
             if eqn.primitive.name == "scatter-add"]
     scalars = [shapes for shapes in adds if len(shapes[2]) < 2]
@@ -820,7 +918,7 @@ def test_the_forward_pass_counts_without_a_scatter(held):
     if held is None:
         assert adds == []
     else:  # the walk reaches the share's loop
-        assert [operand for operand, _, _ in scalars] == [(count,)]
+        assert [operand for operand, _, _ in scalars] == [(count,)] * biased
         assert [operand for operand, _, _ in adds if len(operand) == 2] == [x.shape]
 
 

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from family_cases import SMALLTHINKER_ONE_PERIOD, refused, shares_add_up
+from family_cases import (HELD_LOADS, SMALLTHINKER_ONE_PERIOD,
+                          held_load_is_the_references, refused, shares_add_up)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import TransformerConfig
 from kungfu_tpu.telemetry import metrics
@@ -55,6 +56,11 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference():
     # routed from the rows it transforms it is another layer
     late, _ = reference.routing(m, w["router"], 6)
     assert (np.asarray(late) != np.asarray(chosen)).any()
+
+
+@pytest.mark.parametrize("load", HELD_LOADS)
+def test_the_model_is_the_reference_whatever_the_held_experts_get(load):
+    held_load_is_the_references(FAMILY, load)
 
 
 def _sorts(text: str) -> int:
