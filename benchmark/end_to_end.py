@@ -130,6 +130,26 @@ def merge_ranks(record: dict, out: str) -> dict:
     return {**record, "ranks": sorted(ranks, key=lambda r: r["rank"])}
 
 
+def compared(record: dict) -> dict:
+    """What `correct` was decided on, {name: [number, limit]}: the
+    reference's two errors under the family's tolerances, the last pass
+    over the pool over the first (`loss_fell`: under 1), the counts that
+    must be 0, and then every check of the record as 1 or 0 of 1."""
+    reference = record.get("reference") or {}
+    out = {f"{name}_error": [reference[f"{name}_error"], reference[f"{name}_rtol"]]
+           for name in ("loss", "grad") if f"{name}_error" in reference}
+    if "loss_passes" in record:
+        first, last = record["loss_passes"]
+        out["last_pass_over_first"] = [last / first, 1.0]
+    out["compiles_in_window"] = [record["window"]["compiles"], 0]
+    out["steps_failed"] = [record["failed"], 0]
+    if "precision_faults" in reference:
+        out["precision_faults"] = [len(reference["precision_faults"]), 0]
+    for name, ok in record.get("checks", {}).items():
+        out[name] = [int(bool(ok)), 1]
+    return out
+
+
 def result_line(record: dict, trace, manifest: dict) -> dict:
     """The run's last line. Raises for a record that is not from a TPU: no
     CPU number is written under a device metric's name."""
@@ -163,6 +183,7 @@ def result_line(record: dict, trace, manifest: dict) -> dict:
         busy_s, window_s = trace_reduce.device_busy_and_window_s(trace)
         device["busy_s"], device["window_s"] = busy_s, window_s
         line["breakdown"] = trace_reduce.breakdown(trace)
+    line["compared"] = compared(record)
     faults = mf.check_result_line(line, manifest, workload, traced)
     if faults:
         raise RuntimeError("; ".join(faults))

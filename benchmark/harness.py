@@ -269,9 +269,11 @@ def precision_faults(config: dict, head_width: int, jaxpr, state,
 def reference_check(family, config: dict, seed: int, final_state,
                     opt_state) -> dict:
     """The program's loss and gradients against the plain float32
-    reference, on a seeded sample and the run's initial parameters (made
-    again from the seed: the run's own were donated to its first step),
-    to the family's tolerances; and the program's declared precision,
+    reference, on a seeded sample and the seed's initial parameters (made
+    here: the run's own were donated to its first step, and where the
+    configuration fixes the timed state they were another seed's, so the
+    comparison meets weights and ids no run has met), to the family's
+    tolerances; and the program's declared precision,
     read from the same traced program and the run's final state, of which
     shapes and types are enough (`jax.ShapeDtypeStruct` trees). Three
     copies of the parameters are alive at the most here: the initial
@@ -297,6 +299,24 @@ def reference_check(family, config: dict, seed: int, final_state,
     }
 
 
+def timed_state(config: dict, traffic: dict, seed: int) -> tuple:
+    """What the timed steps train from and on: (the seed of their state,
+    [(seed, index) of each host batch of their pool, in the order it is
+    cycled]). The run's seed and its batches 0 to `pool` - 1; but where the
+    configuration's file writes `timed_state`, that key's `seed` and the
+    batches `pool` of that seed's stream, as many as the traffic's `pool`:
+    a cell whose step's work follows its weights and ids, as a share's
+    grouped matmuls follow its routers' loads, fixes them (PERF.md, PR 67).
+    The reference check stays the run's seed's either way."""
+    timed = config.get("timed_state")
+    if timed is None:
+        return seed, [(seed, i) for i in range(traffic["pool"])]
+    if len(timed["pool"]) != traffic["pool"]:
+        raise ValueError(f"timed_state.pool names {len(timed['pool'])} batches, "
+                         f"the traffic's pool is {traffic['pool']}")
+    return timed["seed"], [(timed["seed"], i) for i in timed["pool"]]
+
+
 def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
             trace_dir, events: EventCounter, t_command: float,
             marks: dict | None = None) -> dict:
@@ -319,14 +339,15 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
     samples_per_step = traffic["per_chip_batch"] * chips
     step_fn, init_opt_state = factory.build(family, config, traffic, mesh)
 
-    state = jax.block_until_ready(family.init(config, seed))
+    state_seed, batches = timed_state(config, traffic, seed)
+    state = jax.block_until_ready(family.init(config, state_seed))
     marks["t_init"] = time.time()
     state = world.place_state(state, mesh)
     state, opt_state = jax.block_until_ready(
         factory.place(state, init_opt_state(state), mesh))
     marks["t_placed"] = time.time()
-    pool = [family.host_batch(config, seed, i, samples_per_step)
-            for i in range(traffic["pool"])]
+    pool = [family.host_batch(config, of, i, samples_per_step)
+            for of, i in batches]
     place = manifest.plugin("placements", traffic["placement"]).make(
         mesh, factory.BATCH_AXIS)
     marks["t_pool"] = time.time()
@@ -397,11 +418,12 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
     k = min(len(pool), len(before), n)
     losses = window["losses"] + (traced_window["losses"] if traced_window else [])
     failed = sum(1 for l in losses if not math.isfinite(l))
+    # one pass over the pool at the start against one at the end
+    loss_passes = [float(np.mean(before[:k])), float(np.mean(losses[-k:]))]
     checks = {
         "no_compile_in_window": window["compiles"] == 0,
         "no_step_failed": failed == 0,
-        # one pass over the pool at the start against one at the end
-        "loss_fell": float(np.mean(losses[-k:])) < float(np.mean(before[:k])),
+        "loss_fell": loss_passes[1] < loss_passes[0],
         "state_spans_mesh": all(len(l.sharding.device_set) == chips
                                 for l in jax.tree.leaves(state)),
         "one_process_a_worker": jax.process_count() == world.size,
@@ -428,6 +450,7 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
     return {
         "workload": cell["name"],
         "seed": seed,
+        "timed_state_seed": state_seed,
         "traced": bool(trace_dir),
         "rank": world.rank,
         "device": {"platform": device.platform, "kind": device.device_kind,
@@ -449,6 +472,7 @@ def measure(cell: dict, mesh, world, peaks: dict, seed: int, seconds: float,
         "window": window,
         "traced_window": traced_window,
         "losses_before": before,
+        "loss_passes": loss_passes,
         "probe_intervals_s": intervals(probe),
         "attempted": n + n_traced,
         "failed": failed,

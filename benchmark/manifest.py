@@ -32,8 +32,9 @@ MANIFEST = os.path.join(REPO, "BENCHMARK.json")
 # the contract's limits (builder's instructions, PR 23)
 MANIFEST_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
                  "end_to_end", "per_layer"}
-RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device")
-TRACED_RESULT_KEYS = RESULT_KEYS + ("breakdown",)
+# `compared`, each number `correct` was decided on beside its limit, comes last
+RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device", "compared")
+TRACED_RESULT_KEYS = RESULT_KEYS[:-1] + ("breakdown", "compared")
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}\Z")
 UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}\Z")
 PATH = re.compile(r"[A-Za-z0-9_.\-/]{1,200}\Z")
@@ -296,7 +297,8 @@ def check(manifest: dict, bench_dir: str = BENCH_DIR, repo: str = REPO) -> list:
 def check_result_line(line: dict, manifest: dict, workload: str,
                       traced: bool) -> list:
     """Faults in a run's last line: its keys are exactly the contract's,
-    and its metrics are those the cell reports in this kind of run."""
+    its metrics are those the cell reports in this kind of run, and what
+    was compared comes last, each number beside its limit."""
     p = []
     allowed = TRACED_RESULT_KEYS if traced else RESULT_KEYS
     if not set(RESULT_KEYS) <= set(line) <= set(allowed):
@@ -318,4 +320,10 @@ def check_result_line(line: dict, manifest: dict, workload: str,
         want |= {"busy_s", "window_s"}
     if set(line["device"]) != want:
         p.append(f"device keys {sorted(line['device'])} are not {sorted(want)}")
+    if list(line)[-1] != "compared":
+        p.append("compared is not the last key")
+    for name, pair in line["compared"].items():
+        if not (isinstance(pair, list) and len(pair) == 2 and all(
+                isinstance(x, (int, float)) for x in pair)):
+            p.append(f"compared {name}: {pair} is not [number, limit]")
     return p

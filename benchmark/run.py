@@ -9,10 +9,11 @@ starts the cell's child (`child.py`), or a `kfrun` tree of them, in a session
 of its own with a time limit, echoes what they print, reads the record the
 reporting rank wrote under `benchmark/out/`, kills whatever is left of the
 session, and prints the result as the last line: one JSON object with
-`correct`, `attempted`, `failed`, `metrics`, `device` and, traced,
-`breakdown`. Without a TPU the child fails, and so does this, with no
-result line. `--check` checks BENCHMARK.json and the files it names, with no
-chip and no jax.
+`correct`, `attempted`, `failed`, `metrics`, `device`, traced `breakdown`,
+and last `compared`, each number `correct` was decided on beside its limit
+(also the last lines on standard error). Without a TPU the child fails,
+and so does this, with no result line. `--check` checks BENCHMARK.json and
+the files it names, with no chip and no jax.
 """
 
 from __future__ import annotations
@@ -125,6 +126,8 @@ def main() -> int:
         {**end_to_end.values(record),
          "stall_share_pct": 100 * end_to_end.stall_share(record)}))
     print(json.dumps(line), flush=True)
+    for name, (value, limit) in line["compared"].items():
+        print(f"benchmark: compared {name} {value} limit {limit}", file=sys.stderr)
     return 0
 
 

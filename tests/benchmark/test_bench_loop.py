@@ -240,7 +240,25 @@ def test_result_line_of_a_chip_record():
     assert line["device"]["backend_start_s"] == pytest.approx(8.0)
     assert line["device"]["command_to_window_s"] == pytest.approx(17.5)
     assert line["metrics"]["setup_s"]["value"] == pytest.approx(9.5)
+    # what `correct` was decided on, last: a drawn record has the counts alone
+    assert line["compared"] == {"compiles_in_window": [0, 0], "steps_failed": [0, 0]}
     json.dumps(line)
+
+
+def test_the_line_ends_with_each_number_compared_beside_its_limit():
+    record = {**_record(), "failed": 1, "loss_passes": [10.0, 9.5],
+              "reference": {"loss_error": 1e-5, "loss_rtol": 2e-4,
+                            "grad_error": 0.05, "grad_rtol": 0.04,
+                            "precision_faults": ["the loss is bfloat16"]},
+              "checks": {"reference_grads": False, "loss_fell": True,
+                         "state_spans_mesh": True, "workers_agree_on_state": False}}
+    line = end_to_end.result_line(record, None, mf.load())
+    assert tuple(line)[-1] == "compared" and line["compared"] == {
+        "loss_error": [1e-5, 2e-4], "grad_error": [0.05, 0.04],
+        "last_pass_over_first": [0.95, 1.0], "compiles_in_window": [0, 0],
+        "steps_failed": [1, 0], "precision_faults": [1, 0],
+        "reference_grads": [0, 1], "loss_fell": [1, 1],
+        "state_spans_mesh": [1, 1], "workers_agree_on_state": [0, 1]}
 
 
 @pytest.mark.parametrize("platform", ["cpu", "gpu", "CPU"])

@@ -229,6 +229,7 @@ def _line(traced):
             "mfu_pct": {"value": 35.0, "unit": "%"},
             "setup_s": {"value": 16.0, "unit": "s"},
         }
+    line["compared"] = {"grad_error": [0.0125, 0.04], "steps_failed": [0, 0]}
     return line
 
 
@@ -251,6 +252,9 @@ LINE_FAULTS = {
     "device_without_backend_start": lambda l: l["device"].pop("backend_start_s"),
     "device_without_the_whole_setup": lambda l: l["device"].pop("command_to_window_s"),
     "extra_key_in_metric": lambda l: l["metrics"]["mfu_pct"].update(p95=1),
+    "nothing_compared": lambda l: l.pop("compared"),
+    "compared_not_last": lambda l: l.update(device=l.pop("device")),
+    "compared_without_its_limit": lambda l: l["compared"].update(loss_error=1e-5),
 }
 
 

@@ -67,8 +67,12 @@ def test_the_manifest_with_the_sixth_cell_is_sound():
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert cell == {**cell, "config": "qwen3_next_80b_a3b",
                     "traffic": "ssgd_longseq_1chip", "chips": 1}
-    assert manifest["workloads"][-1] == cell and manifest["configs"][-1][
-        "name"] == "qwen3_next_80b_a3b"
+    # an addition at the ends when it came (PR 36): right behind the fifth
+    # cell and its configuration, wherever the ends are now
+    cells = [w["name"] for w in manifest["workloads"]]
+    configs = [c["name"] for c in manifest["configs"]]
+    assert cells[cells.index(CELL) - 1] == "laguna_s_2_1.ssgd_1seq_1chip"
+    assert configs[configs.index("qwen3_next_80b_a3b") - 1] == "laguna_s_2_1"
     mine = [m for m in manifest["per_layer"] if m.get("workloads") == [CELL]]
     assert [m["name"] for m in mine] == [
         "gdn_core_ms", "gdn_core_roofline_pct", "gdn_mix_ms", "gattn_core_ms",

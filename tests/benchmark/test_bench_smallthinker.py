@@ -145,7 +145,8 @@ def test_the_configuration_is_the_catalogs_but_for_its_cut():
         1, 8, {"dp": 1})
     assert (traffic["launcher"], traffic["step"], traffic["placement"]) == (
         "none", "ssgd", "shard_batch")
-    assert traffic["optimizer"] == {"name": "adamw", "learning_rate": 0.0003}
+    assert traffic["optimizer"] == {"name": "adamw_warmup", "learning_rate": 0.0003,
+                                    "warmup_steps": 2000}  # since PR 67
 
 
 def test_the_cut_holds_the_parameters_its_file_says():
