@@ -366,10 +366,13 @@ def _live_chunks(top_k: int, chunk: int, T: int, sizes):
     """Chunks of `chunk` rows that the held groups `sizes` fill: none where
     nothing came. But one chunk of all T * min(top_k, held) rows that can
     fall here is run once whatever came, none included: a loop of no or one
-    turn is no loop (as a `while` the SmallThinker cell's read 15 ms a step
-    and 0.78 GB more than unrolled: PERF.md, PR 66), and under a selection
-    bias, where `_share_chunk` makes that one chunk of two, the count is to
-    be no data. `sizes` may be numpy's."""
+    turn is no loop (as a `while` the SmallThinker cell's chunk of all
+    98,304 rows read 15 ms a step and 0.78 GB more than unrolled: PERF.md,
+    PR 66), and under a selection bias, where `_share_chunk` makes that one
+    chunk of two, the count is to be no data. Without a bias `_share_chunk`
+    gives half of those rows at the most (PR 68), so but for a share of a
+    handful of rows that chunk is the selection bias's. `sizes` may be
+    numpy's."""
     if chunk >= T * min(top_k, sizes.shape[0]):
         return 1
     return (sizes.sum() + chunk - 1) // chunk
@@ -448,11 +451,19 @@ def _share_chunk(T: int, top_k: int, held: int, n_experts: int,
     them: softmax routers with none swing as far under the cells' traffic
     (a layer's held rows between nothing and four balanced loads from one
     step to the next: PERF.md, PR 66), and what steadies such a cell is its
-    traffic, not a third chunk rule."""
+    traffic, not a third chunk rule. So without a bias the chunk is never
+    more than half of what can fall here: where four balanced loads are all
+    of it (a share of a quarter of the experts or more) the chunk is no
+    chunk, it runs whole whatever came, and the dispatch and combine around
+    the grouped matmuls, which cost the chunk's rows and not the groups',
+    follow nothing (16 of 64 held: 98,304 rows moved a layer where 24,576
+    came, PERF.md, PR 68)."""
     most = T * min(top_k, held)
     usual = -(-4 * T * top_k * held // n_experts)
     usual = -(-usual // 8) * 8
-    return most if biased and most <= 2 * usual else min(most, usual)
+    if biased:
+        return most if most <= 2 * usual else min(most, usual)
+    return min(most, usual, -(-most // 16) * 8)
 
 
 def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
@@ -481,11 +492,12 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
     scores, and a selection bias (E,) that moves the choice alone; `gates`
     sees the chosen experts' scores. A share's (`held`) work is its live
     rows' unless a bias says otherwise: without one the grouped matmuls
-    get the groups that came, in chunks of four balanced loads, as many as
-    the rows fill. Under a bias every chunk the rows reach is computed
-    whole, the rows of no group as zeros in the last group, and a share of
-    an eighth or more is one chunk of all that can fall here, run whatever
-    came: its cost is its chunks', not its rows' (PERF.md, PR 43, PR 66).
+    get the groups that came, in chunks of four balanced loads and of half
+    of what can fall here at the most, as many as the rows fill. Under a
+    bias every chunk the rows reach is computed whole, the rows of no group
+    as zeros in the last group, and a share of an eighth or more is one
+    chunk of all that can fall here, run whatever came: its cost is its
+    chunks', not its rows' (PERF.md, PR 43, PR 66).
 
     `routing`: a `Routing` made elsewhere, in any of the three layouts: the
     experts then transform x under a choice and gates that `route` made of

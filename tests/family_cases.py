@@ -499,9 +499,12 @@ def held_load_is_the_references(family, load: str):
     logits through the loss) in the tolerance of
     `test_float32_program_equals_the_reference`, with the held experts
     getting `load` of `HELD_LOADS` in every expert layer; and the share's
-    chunk: one of all that can fall here at these sizes, run once whatever
-    came, with the groups that came (a share's work is its live rows', PR
-    66)."""
+    chunks: without a selection bias half of all that can fall here at these
+    sizes (PR 68: never more, so that the rows moved follow the rows that
+    came), as many as the held rows fill, with the groups that came (a
+    share's work is its live rows', PR 66). So a layer that holds more than
+    half of what can fall here, every choice among them, runs two chunks
+    against the reference, and one that holds nothing none."""
     module, config = family.module, family.config
     state, sample = steered(family, HELD_LOADS[load]), family.sample()
     loss, grads = family.program()(state, sample)
@@ -509,14 +512,19 @@ def held_load_is_the_references(family, load: str):
     assert off(loss, want_loss) <= 1e-5
     assert harness.relative_error(grads, want) <= family.float32_grad_rtol
     mc = module.model_config(config)
+    assert not mc.router_bias
     tokens = sample[:, :-1].size
     most = tokens * min(mc.top_k, mc.experts_held[1])
+    half = -(-most // 16) * 8
     assert moe._share_chunk(tokens, mc.top_k, mc.experts_held[1], mc.n_experts,
-                            mc.router_bias) == most
+                            mc.router_bias) == half < most
     stats = module.routing_stats(config, state, sample)
     rows = {"nothing": [0], "every_choice": [most]}.get(load) or range(1, most)
     assert all(n in rows for n in stats["held_rows"]), stats["held_rows"]
-    assert stats["chunk_rows"] == [most] * len(family.expert_layers)
+    assert stats["chunk_rows"] == [-(-n // half) * half
+                                   for n in stats["held_rows"]]
+    if load == "every_choice":
+        assert stats["chunk_rows"] == [2 * half] * len(family.expert_layers)
     assert stats["dropped"] == [0] * len(family.expert_layers)
 
 

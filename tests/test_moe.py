@@ -319,22 +319,34 @@ def test_the_shares_parts_add_up_to_the_whole_layer(top_k, count, E):
                                    rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("E,first,chunks", [(16, 4, 1), (32, 4, 2), (64, 4, 4),
-                                            (32, 8, 0), (16, 8, 1)])
-def test_a_share_is_dropless_under_the_worst_load(E, first, chunks):
-    """Every token's three choices on experts 4, 5 and 6. The four experts
-    held from 4 on get all T x min(top_k, held) rows that can fall here, in
-    as many chunks of four balanced loads as they fill (of 16 experts one
-    chunk is all of them, of 32 two, of 64 four), none is dropped, values
-    and gradients are the whole layer's. The four held from 8 on get every
-    choice elsewhere: of 32 experts no chunk runs, of 16 the one chunk of
-    all that can fall here runs over nothing, and the part and its
-    gradients are exact zeros either way."""
+@pytest.mark.parametrize("E,first,aimed,chunks", [
+    (16, 4, 144, 2), (32, 4, 144, 2), (64, 4, 144, 4), (32, 8, 144, 0),
+    (16, 8, 144, 0), (16, 4, 0, 0), (16, 4, 72, 1), (16, 4, 73, 2)])
+def test_a_share_is_dropless_under_the_worst_load(E, first, aimed, chunks):
+    """`aimed` of the 144 token-choices on experts 4, 5 and 6: the first
+    tokens send all three there, one more token its first where `aimed` is
+    no multiple of three, and the rest go to experts 0, 1 and 2. The four
+    experts held from 4 on get them in as many chunks as they fill, chunks
+    of four balanced loads and never more than half of all T x min(top_k,
+    held) rows that can fall here (of 16 and of 32 experts 72 rows, of 64
+    40): none where nothing came, one for exactly a chunk's rows, two for
+    one row more and for every row that can fall here (of 64 experts four),
+    and none is dropped. Values and gradients are those of one chunk of all
+    the rows and, where every choice came, the whole layer's. The four held
+    from 8 on get every choice elsewhere: no chunk runs, and the part and
+    its gradients are exact zeros, as where nothing was aimed."""
     moe, x, router, experts, gates = _share_setup(E=E)
     chunk = moe._share_chunk(48, 3, 4, E)
-    # logits that put experts 4, 5, 6 first for every token, whatever x
-    router = jnp.zeros_like(router).at[0, 4:7].set(jnp.array([3.0, 2.0, 1.0]))
-    x = x.at[:, 0].set(jnp.abs(x[:, 0]) + 1.0)
+    # logits that put experts 4, 5, 6 first for a token of kind 0, expert 4
+    # and then 0 and 1 for one of kind 1 and 0, 1, 2 for one of kind 2,
+    # whatever the rest of x
+    kind = np.full(48, 2)
+    kind[:aimed // 3] = 0
+    kind[aimed // 3:aimed // 3 + aimed % 3] = 1
+    router = (jnp.zeros_like(router).at[0, 4:7].set(jnp.array([3.0, 2.0, 1.0]))
+              .at[1, jnp.array([4, 0, 1])].set(jnp.array([3.0, 2.0, 1.0]))
+              .at[2, 0:3].set(jnp.array([3.0, 2.0, 1.0])))
+    x = x.at[:, :3].set(jax.nn.one_hot(kind, 3) * (jnp.abs(x[:, :1]) + 1.0))
     out, aux = _share(moe, x, router, experts, gates, 3, first, 4)
     assert int(moe._live_chunks(3, chunk, 48, aux.counts)) == chunks
 
@@ -345,23 +357,32 @@ def test_a_share_is_dropless_under_the_worst_load(E, first, chunks):
     def part(x, router, experts):
         return _share(moe, x, router, experts, gates, 3, first, 4)[0]
 
-    grads = jax.tree.leaves(jax.grad(lambda *a: jnp.sum(part(*a) ** 2), (0, 1, 2))(
-        x, router, experts))
-    if first == 8:
+    def one_chunk(x, router, experts):
+        """The same share as one chunk of all 144 rows that can fall here."""
+        top_scores, chosen = moe.route(x, router, 3)[2:]
+        order, _, counts = moe.dispatch_plan(chosen, E, (first, 4))
+        return moe._held_part(
+            moe.swiglu_experts, 3, 144, False, x, gates(top_scores),
+            tuple(w[first:first + 4] for w in experts), order,
+            counts[first:first + 4])
+
+    grads = lambda fn: jax.tree.leaves(jax.grad(
+        lambda *a: jnp.sum(fn(*a) ** 2), (0, 1, 2))(x, router, experts))
+    if first == 8 or not aimed:
         assert aux.counts.tolist() == [0, 0, 0, 0]
         assert float(jnp.abs(out).max()) == 0.0
-        assert all(float(jnp.abs(g).max()) == 0.0 for g in grads)
+        assert all(float(jnp.abs(g).max()) == 0.0 for g in grads(part))
         return
-    assert aux.counts.tolist() == [48, 48, 48, 0]
-    assert int(aux.counts.sum()) == 48 * 3  # every held choice computed
-    np.testing.assert_allclose(np.asarray(out), np.asarray(whole(x, router, experts)),
-                               rtol=1e-5, atol=1e-5)
-    for g, w in zip(
-            grads,
-            jax.tree.leaves(jax.grad(lambda *a: jnp.sum(whole(*a) ** 2), (0, 1, 2))(
-                x, router, experts))):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=1e-4, atol=1e-5)
+    assert aux.counts.tolist() == [-(-aimed // 3), aimed // 3, aimed // 3, 0]
+    assert int(aux.counts.sum()) == aimed  # every held choice computed
+    got = grads(part)
+    for want in (one_chunk, whole) if aimed == 144 else (one_chunk,):
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(want(x, router, experts)),
+                                   rtol=1e-5, atol=1e-5)
+        for g, w in zip(got, grads(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("biased", [False, True], ids=["no_bias", "bias"])
@@ -374,15 +395,15 @@ def test_a_chunk_of_all_that_can_fall_here_does_not_follow_the_routing(E, one,
     holds it, and the groups handed to the experts fill every chunk
     whatever came, the rows of no group in the last one, where they are
     zeros and weigh nothing (the values and gradients of the tests above).
-    Without a bias the chunk is four balanced loads and no more than can
-    fall here, and the groups are the rows that came, zeros past them.
-    Either way one chunk of all that can fall here is run once, whatever
-    came: the program has no loop whose count the routing gives; a smaller
-    chunk keeps its loop."""
+    Without a bias the chunk is four balanced loads and no more than half
+    of what can fall here, and the groups are the rows that came, zeros
+    past them. One chunk of all that can fall here, which is then the
+    bias's alone, is run once, whatever came: the program has no loop whose
+    count the routing gives; a smaller chunk keeps its loop."""
     moe, x, router, experts, gates = _share_setup(E=E)
     chunk = moe._share_chunk(48, 3, 4, E, biased)
     assert chunk == (48 * 3 if one and biased else
-                     {16: 144, 32: 72, 64: 40}[E])
+                     {16: 72, 32: 72, 64: 40}[E])
     mine = tuple(w[4:8] for w in experts)
     bias = 0.3 * jax.random.normal(jax.random.PRNGKey(9), (E,)) if biased else None
 
@@ -422,7 +443,7 @@ CELL_CHUNKS = {
     "laguna_s_2_1": ((8192, 10, 8, 256, False), 10240),
     "qwen3_next_80b_a3b": ((16384, 10, 32, 512, False), 40960),
     "keye_vl_2_0_30b_a3b": ((8192, 8, 16, 128, False), 32768),
-    "smallthinker_21b_a3b": ((16384, 6, 16, 64, False), 98304),
+    "smallthinker_21b_a3b": ((16384, 6, 16, 64, False), 49152),
 }
 
 
@@ -431,16 +452,18 @@ def test_the_one_chunk_rule_is_the_selection_biass(cell):
     """Under a bias a share's chunk is what it was at PR 65, one chunk of all
     that can fall here for a share of an eighth or more (GLM-4.7-Flash,
     LFM2) and the usual chunk below that (Nemotron-3-Nano); without one it
-    is four balanced loads and never more than can fall here: half of the
-    65,536 rows in the Keye cell, all 98,304 in the SmallThinker cell, and
-    what it was for the smaller shares (Laguna, Qwen3-Next)."""
+    is four balanced loads and never more than half of what can fall here:
+    half of the 65,536 rows in the Keye cell either way, half of the 98,304
+    in the SmallThinker cell, whose four balanced loads are all of them
+    (PR 68), and four balanced loads for the smaller shares (Laguna,
+    Qwen3-Next)."""
     from kungfu_tpu.ops import moe
 
     (T, top_k, held, E, biased), rows = CELL_CHUNKS[cell]
     assert moe._share_chunk(T, top_k, held, E, biased) == rows
     most, balanced = T * min(top_k, held), T * top_k * held // E
-    assert rows == (most if biased and most <= 8 * balanced
-                    else min(most, 4 * balanced))
+    assert rows == ((most if most <= 8 * balanced else 4 * balanced) if biased
+                    else min(most // 2, 4 * balanced))
 
 
 def test_the_chunk_fill_share_is_the_held_rows_over_the_chunks_that_ran():
