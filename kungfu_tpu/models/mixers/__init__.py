@@ -5,7 +5,11 @@ from the layer's input to the branch's output. `models/transformer.py` asks
 the record (`mixer_of`) wherever it used to know the mixers by name: the
 configuration's `__post_init__`, `init_transformer`, `param_pspecs`, `_layer`,
 `_block` and `_hidden`. The next mixer is a module here, an entry in `MIXERS`
-and its fields in `TransformerConfig`.
+and its fields in `TransformerConfig`. Six mixers stand in the table:
+`attention`, `gated_delta`, `kda` (PR 69), `latent`, `mamba2`, `short_conv`.
+`latent` takes `latent_dims[0]` 0 for no q latent, `positions` "none" for
+nothing turned, and value heads whose size `hd_v` differs from the q/k heads'
+on the flash core as on the dense one.
 
 The arrows: `ops/` <- `models/blocks.py` <- this package <-
 `models/transformer.py`. Nothing here imports `models/transformer.py` (the
@@ -19,8 +23,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Optional
 
-from kungfu_tpu.models.mixers import (attention, gated_delta, latent, mamba2,
-                                      short_conv)
+from kungfu_tpu.models.mixers import (attention, gated_delta, kda, latent,
+                                      mamba2, short_conv)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +67,9 @@ MIXERS = {
     "gated_delta": Mixer(
         "gdn", gated_delta.init, gated_delta.pspecs, gated_delta.apply,
         check=gated_delta.check),
+    "kda": Mixer(
+        "kda", kda.init, kda.pspecs, kda.apply, check=kda.check,
+        off_the_normal_path=kda.OFF_THE_NORMAL_PATH),
     "latent": Mixer(
         "attn", latent.init, latent.pspecs, latent.apply, check=latent.check),
     "mamba2": Mixer(

@@ -77,14 +77,15 @@ def _l2_normed(t, scale: float, dtype):
     return (t * (norm * scale)).astype(dtype)
 
 
-def _gated_norm(o, scale, z, eps):
-    """rms(o) * scale * silu(z) over the last axis (a head), in float32, the
-    result in o's type. No checkpoint of its own, nor `_l2_normed`: the
-    block of heads they stand in is run again whole (`_delta_heads`)."""
+def _gated_norm(o, scale, z, eps, gate=jax.nn.silu):
+    """rms(o) * scale * gate(z) over the last axis (a head), the gate silu
+    (sigmoid for `mixers.kda`), in float32, the result in o's type. No
+    checkpoint of its own, nor `_l2_normed`: the block of heads they stand
+    in is run again whole (`_delta_heads`)."""
     o32 = o.astype(jnp.float32)
     var = jnp.mean(jnp.square(o32), axis=-1, keepdims=True)
     y = o32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
-    return (y * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype)
+    return (y * gate(z.astype(jnp.float32))).astype(o.dtype)
 
 
 # value heads a block of the gated delta mixer (`_gated_delta_mixer`)

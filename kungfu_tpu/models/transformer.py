@@ -16,8 +16,9 @@ TPU-first design notes:
   alone (`mixer` "none", `ffn` "none": that branch's norm leaf and no other).
 - The token mixer is one record of `models/mixers/` (`mixer_of(cfg)`): its
   refusals, leaves, shardings and function live in its module there
-  (softmax attention and its learned sparse index, latent attention, the
-  gated delta rule, Mamba-2, the gated short convolution), and this file asks
+  (softmax attention and its learned sparse index, latent attention with or
+  without a q latent and positions, the gated delta rule, Kimi Delta
+  Attention, Mamba-2, the gated short convolution: six mixers), and this file asks
   the record wherever the mixer matters: `__post_init__`, `init_transformer`,
   `param_pspecs`, `_layer`, `_block`, `_hidden`. What the mixers and the
   layer share (the norm, the rotary pass, the cores, the checkpoint) is
@@ -148,6 +149,11 @@ class TransformerConfig:
     # feed-forward alone, behind its one norm
     mixer: str = "attention"
     delta_heads: Tuple = ()
+    # mixer "kda" (Kimi Delta Attention, `ops.kda`): (heads, head size) for
+    # q, k and v alike behind convolutions of `conv_taps` taps; the delta
+    # rule's decay is a number a key feature, from a projection through a
+    # rank of the head size, and a second such projection gates the norm
+    kda_heads: Tuple = ()
     conv_taps: int = 4
     ssm_dims: Tuple = ()
     norm_offset: bool = False  # every RMSNorm's scale is 1 + w, w from 0
@@ -157,9 +163,12 @@ class TransformerConfig:
     # sigmoid(gate), one a feature, is on the core's output
     q_gate: bool = False
     shared_gate: bool = False  # sigmoid(h @ w_shared_gate (D, 1)) on the shared expert
-    # mixer "latent": (q latent rank, key/value latent rank, unrotated
-    # features a q/k head, rotated features a q/k head, features a value
-    # head); the rotated key is one for all heads, rotate-half at rope_theta
+    # mixer "latent": (q latent rank, or 0: q = h W_q with no latent;
+    # key/value latent rank; unrotated features a q/k head; rotated features
+    # a q/k head; features a value head, `hd_v`, the q/k heads' size or its
+    # own on either core); the rotated key is one for all heads, rotate-half
+    # at rope_theta under `positions` "rope", and under "none" nothing is
+    # turned: the one shared key stands beside each head's own as it is
     latent_dims: Tuple = ()
     router_scores: str = "softmax"  # or "sigmoid": each expert's own score
     # a leaf `router_bias` (n_experts,) added to the scores for the choice
@@ -781,8 +790,8 @@ def _feed_forward(x, layer, cfg: TransformerConfig, routing=None):
 # `layer_remat`: the scan keeps the layer's input and, of what the layer
 # computes, the flash core's output and row sums, whatever the call (0.15 GB
 # a layer of 72 heads of 128 at 8,192 positions, 0.085 GB a layer of 20
-# heads of 256), and a Gated DeltaNet mixer's output (`gdn_mix`: 0.067 GB a
-# layer of 16,384 positions of 2,048): the projections, the rotation and the
+# heads of 256), and a Gated DeltaNet or KDA mixer's output (`gdn_mix`: 0.067
+# GB a layer of 16,384 positions of 2,048; `kda_mix`): the projections, the rotation and the
 # feed-forward are run again in the backward pass, the forward kernel and
 # the DeltaNet mixer's head blocks are not (the blocks run their forward
 # once more for their own gradients, `_delta_heads`: twice a step in all).
@@ -805,7 +814,8 @@ def _feed_forward(x, layer, cfg: TransformerConfig, routing=None):
 _layer_again = jax.checkpoint(
     _layer, static_argnums=(2,), prevent_cse=False,
     policy=jax.checkpoint_policies.save_only_these_names(
-        "flash_out", "flash_lse", "gdn_mix", "dsa_chosen", "moe_plan"))
+        "flash_out", "flash_lse", "gdn_mix", "kda_mix", "dsa_chosen",
+        "moe_plan"))
 
 
 def _block(x, layer, cfg: TransformerConfig, core=None):

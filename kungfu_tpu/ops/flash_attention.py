@@ -462,11 +462,12 @@ def _group(q, k, v, causal: bool, window, segments=None) -> int:
             f"flash_attention: segments {segments.shape} number the documents "
             f"of a causal core's positions, (B, S) = {q.shape[:1] + q.shape[2:3]}")
     H, Hkv = q.shape[1], k.shape[1]
-    if k.shape != v.shape or H % Hkv or (
+    if k.shape[:-1] != v.shape[:-1] or H % Hkv or (
             k.shape[:1] + k.shape[2:] != q.shape[:1] + q.shape[2:]):
         raise ValueError(
             f"flash_attention: q {q.shape} against k {k.shape}, v {v.shape}: "
-            "k and v share a shape, and q's heads are a multiple of theirs")
+            "k and v share their heads and positions, k's features are q's, "
+            "and q's heads are a multiple of theirs")
     if window is not None and not (causal and window >= 1):
         raise ValueError("flash_attention: a window is causal (0 <= i - j < "
                          f"window) and at least 1, got {window}")
@@ -603,11 +604,12 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, hd = q.shape
+    hd_v = v.shape[-1]  # the values' head size, and o's: q's and k's or its own
     g = _group(q, k, v, causal, window, segments)
     blk_q, blk_k = _blocks(S, blk_q, blk_k)
     qf = q.reshape(B * H, S, hd)
     kf = k.reshape(B * H // g, S, hd)
-    vf = v.reshape(B * H // g, S, hd)
+    vf = v.reshape(B * H // g, S, hd_v)
     packed = None if segments is None else (H, S)
     bounds, numbers = _packed(segments, blk_q, blk_k)
     q_index = functools.partial(_swept_for_index, 2, causal, window)
@@ -619,28 +621,28 @@ def _forward(q, k, v, causal: bool, sm_scale: float, blk_q: int,
                           sm_scale=sm_scale, window=window, packed=packed),
         grid, table + bounds,
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
+            jax.ShapeDtypeStruct((B * H, S, hd_v), q.dtype),
             jax.ShapeDtypeStruct((B * H, S, 8), jnp.float32),
         ],
         in_specs=[
             pl.BlockSpec((1, blk_q, hd), q_index),
             pl.BlockSpec((1, blk_k, hd), kv_index),
-            pl.BlockSpec((1, blk_k, hd), kv_index),
+            pl.BlockSpec((1, blk_k, hd_v), kv_index),
         ] + _packed_specs(segments, blk_q, blk_k,
                           lambda b, *step: (b // H, q_index(b, *step)[1]),
                           lambda b, *step: (b // H, kv_index(b, *step)[1])),
         out_specs=[
-            pl.BlockSpec((1, blk_q, hd), q_index),
+            pl.BlockSpec((1, blk_q, hd_v), q_index),
             pl.BlockSpec((1, blk_q, 8), q_index),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, 128), jnp.float32),  # m, every lane a copy
             pltpu.VMEM((blk_q, 128), jnp.float32),  # l
-            pltpu.VMEM((blk_q, hd), jnp.float32),  # acc
+            pltpu.VMEM((blk_q, hd_v), jnp.float32),  # acc
         ],
         interpret=interpret,
     )(qf, kf, vf, *numbers)
-    out = out.reshape(B, H, S, hd)
+    out = out.reshape(B, H, S, hd_v)
     if with_lse:
         return out, lse  # (B*H, S, 8), lane-replicated
     return out
@@ -823,6 +825,7 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, hd = q.shape
+    hd_v = v.shape[-1]  # of v, o, dO and dv
     group = _group(q, k, v, causal, window, segments)
     Hkv = H // group
     # delta = rowsum(dO * O): one fused elementwise+reduce pass, XLA's
@@ -832,8 +835,8 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
     )  # (B, H, S)
     qf = q.reshape(B * H, S, hd)
     kf = k.reshape(B * Hkv, S, hd)
-    vf = v.reshape(B * Hkv, S, hd)
-    gf = g.reshape(B * H, S, hd)
+    vf = v.reshape(B * Hkv, S, hd_v)
+    gf = g.reshape(B * H, S, hd_v)
     lsef = lse  # (B*H, S, 8), as the forward kernel writes it
     deltaf = jnp.broadcast_to(
         delta.reshape(B * H, S)[:, :, None], (B * H, S, 8)
@@ -846,6 +849,8 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
     kv_index = functools.partial(_kv_index, blk_q, blk_k, causal, window, group,
                                  packed)
     kv_spec = pl.BlockSpec((1, blk_k, hd), kv_index)
+    v_spec = pl.BlockSpec((1, blk_k, hd_v), kv_index)
+    do_spec = pl.BlockSpec((1, blk_q, hd_v), q_index)
     row_spec = pl.BlockSpec((1, blk_q, 8), q_index)
     _count_blocks(("dq", "dkv"), B * H, S, blk_q, blk_k, causal, window, False)
 
@@ -855,7 +860,7 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
                           sm_scale=sm_scale, window=window, packed=packed),
         grid, table + bounds,
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+        in_specs=[q_spec, kv_spec, v_spec, do_spec, row_spec, row_spec]
         + _packed_specs(segments, blk_q, blk_k,
                         lambda b, *step: (b // H, q_index(b, *step)[1]),
                         lambda b, *step: (b // H, kv_index(b, *step)[1])),
@@ -878,6 +883,8 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
     qi_spec = pl.BlockSpec((1, blk_q, hd), q_index)
     row_i_spec = pl.BlockSpec((1, blk_q, 8), q_index)
     kj_spec = pl.BlockSpec((1, blk_k, hd), k_index)
+    vj_spec = pl.BlockSpec((1, blk_k, hd_v), k_index)
+    doi_spec = pl.BlockSpec((1, blk_q, hd_v), q_index)
     dk, dv = _sweep_call(
         functools.partial(_dkv_kernel, blk_q=blk_q, blk_k=blk_k,
                           causal=causal, sm_scale=sm_scale, window=window,
@@ -885,17 +892,17 @@ def _backward_kernels(q, k, v, o, lse, g, causal, sm_scale, blk_q, blk_k,
         grid, table + bounds,
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, S, hd), k.dtype),
-            jax.ShapeDtypeStruct((B * Hkv, S, hd), v.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, S, hd_v), v.dtype),
         ],
-        in_specs=[qi_spec, kj_spec, kj_spec, qi_spec, row_i_spec, row_i_spec]
+        in_specs=[qi_spec, kj_spec, vj_spec, doi_spec, row_i_spec, row_i_spec]
         + _packed_specs(segments, blk_q, blk_k,
                         lambda b, *step: (q_index(b, *step)[0] // H,
                                           q_index(b, *step)[1]),
                         lambda b, *step: (b // Hkv, k_index(b, *step)[1])),
-        out_specs=[kj_spec, kj_spec],
+        out_specs=[kj_spec, vj_spec],
         scratch_shapes=[
             pltpu.VMEM((blk_k, hd), jnp.float32),
-            pltpu.VMEM((blk_k, hd), jnp.float32),
+            pltpu.VMEM((blk_k, hd_v), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf, gf, lsef, deltaf, *numbers)
@@ -908,9 +915,12 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float = None,
                     blk_q: int = 512, blk_k: int = 512,
                     interpret: bool = False, window: int = None,
                     segments=None):
-    """Fused causal attention for (B, H, S, hd) q and (B, Hkv, S, hd) k, v,
-    H a multiple of Hkv (equal: plain multi-head); drop-in for the
-    transformer's pluggable attention core:
+    """Fused causal attention for (B, H, S, hd) q, (B, Hkv, S, hd) k and (B,
+    Hkv, S, hd_v) v, H a multiple of Hkv (equal: plain multi-head), -> (B, H,
+    S, hd_v); the values' head size is q's and k's or its own (latent
+    attention's 192 on 128: `hd` of q, k, dq, dk, `hd_v` of v, o, dO, dv and
+    the forward kernel's accumulator; with the two equal every program is
+    what it was); drop-in for the transformer's pluggable attention core:
 
         _block(x, layer, cfg, core=lambda q, k, v: flash_attention(q, k, v))
 

@@ -403,13 +403,14 @@ def _block_chunks(N: int) -> int:
     return N
 
 
-def _specs(B, H, S, dk, dv, chunk, back: bool):
+def _specs(B, H, S, dk, dv, chunk, back: bool, heads_most: int = BLOCK_HEADS):
     """Block specs by name for a grid (B, H, blocks of chunks), the blocks
-    in reverse for the backward pass."""
+    in reverse for the backward pass; `heads_most` heads a grid step
+    (`ops.kda` takes the same grid with fewer)."""
     N = S // chunk
     n = _block_chunks(N)
     last = N // n - 1
-    heads = max(h for h in range(1, BLOCK_HEADS + 1) if H % h == 0)
+    heads = max(h for h in range(1, heads_most + 1) if H % h == 0)
 
     def at(*tail):
         if back:

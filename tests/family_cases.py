@@ -19,9 +19,9 @@ import pytest
 from jax.sharding import PartitionSpec
 
 from benchmark import harness, manifest as mf
-from benchmark.families import (glm4_moe_lite, granite_hybrid, keye_vl2, laguna,
-                                lfm2_moe, nemotron_h, ouro, qwen3_next,
-                                smallthinker)
+from benchmark.families import (glm4_moe_lite, granite_hybrid, keye_vl2,
+                                kimi_linear, laguna, lfm2_moe, nemotron_h, ouro,
+                                qwen3_next, smallthinker)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import param_pspecs
 from kungfu_tpu.ops import gated_norm, moe
@@ -464,8 +464,66 @@ SMALLTHINKER_ONE_PERIOD = dataclasses.replace(SMALLTHINKER, tiny={
     **SMALLTHINKER.tiny, "num_hidden_layers": 4, "rope_layout": [0, 1, 1, 1],
     "sliding_window_layout": [0, 1, 1, 1]}, expert_layers=tuple(range(4)))
 
+
+
+def _kimi_memory(family, state, key):
+    """In a KDA layer decays that weigh and differ feature by feature: A in
+    [0.1, 0.6] a head under steps softplus(f + dt_bias) with dt_bias in
+    [-2, 2] a feature, a log decay of -0.01 to -1.3 a position, in the place
+    of the start's few thousandths."""
+    def remembering(stack, key):
+        if "A_log" not in stack:
+            return stack
+        return {**stack,
+                "A_log": jnp.log(jax.random.uniform(
+                    jax.random.fold_in(key, 7), stack["A_log"].shape,
+                    minval=0.1, maxval=0.6)),
+                "dt_bias": jax.random.uniform(
+                    jax.random.fold_in(key, 8), stack["dt_bias"].shape,
+                    minval=-2.0, maxval=2.0)}
+
+    return {**state, "layers": tuple(
+        remembering(stack, jax.random.fold_in(key, 10 + s))
+        for s, stack in enumerate(state["layers"]))}
+
+
+# the cell's first four layers in small, so that the one period's latent layer
+# is there: KDA with the dense feed-forward, two KDA expert layers and the
+# latent expert layer (three stacks); 4 heads of 16 for KDA's q, k and v; 4
+# latent heads of 16 + 8 q/k features on 8 value features, a latent of 16,
+# no q latent, nothing turned, a sharp softmax so that its scale weighs; 16 experts of which numbers 4 to 11 are held,
+# 4 a token; 128 positions, two chunks of the rule's 64; the routers trained,
+# so that every leaf but the bias has a gradient to compare
+KIMI_LINEAR = Family(
+    name="kimi_linear", cell="kimi_linear_48b_a3b.ssgd_kda_1chip",
+    module=kimi_linear,
+    tiny=dict(hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+              num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
+              kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+              v_head_dim=8, num_experts=8, first_expert_held=4,
+              num_experts_per_token=4, published={"num_experts": 16},
+              vocab_size=256, sequence_length=128, flash_blocks=[32, 32],
+              flash_interpret=True, compute_dtype="float32",
+              routers_trained=True, reference_query_block=32,
+              reference_position_block=32),
+    configured=lambda config: config["linear_attn_config"].update(
+        num_heads=4, head_dim=16),
+    scales={"w_q": 4.0, "w_k": 4.0, "w_v": 4.0, "w_f_a": 6.0, "w_f_b": 6.0,
+            "w_beta": 20.0, "w_g_a": 6.0, "w_g_b": 6.0, "w_q_up": 20.0,
+            "w_kv_down": 6.0, "w_kv_up": 20.0, "wo": 3.0, "router": 20.0,
+            "router_bias": 40.0, "w_gate": 8.0, "w_up": 8.0, "w_down": 8.0,
+            "shared_gate": 3.0, "shared_up": 3.0, "shared_down": 3.0},
+    norms=("ln1_scale", "ln2_scale", "kv_latent_norm", "kda_norm_scale"),
+    trained_more=_kimi_memory, expert_layers=(1, 2, 3), held_share=(0.3, 0.7),
+    scopes=("kda/", "kda_proj/", "kda_conv/", "kda_core/", "kda_norm/",
+            "attn/mla_down", "attn/mla_norm", "attn/mla_up",
+            "attn/attn_latent/attn_core", "moe/moe_router", "moe/moe_shared",
+            "moe/moe_dispatch", "moe_experts/", "moe_combine/", "head_loss"),
+    constants=("router_bias",),
+    recomputed=((),))  # the cell's layers are run again: against none that are
+
 FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H, OURO,
-            GRANITE_HYBRID, LFM2_MOE, KEYE_VL2, SMALLTHINKER)
+            GRANITE_HYBRID, LFM2_MOE, KEYE_VL2, SMALLTHINKER, KIMI_LINEAR)
 
 
 # what the held experts get of a family's choices, as the number its routers'

@@ -739,3 +739,43 @@ def test_without_segments_the_kernels_are_the_program_they_were():
         flash_attention(q, k, v, False, None, 32, 32, True, None, segments)
     with pytest.raises(ValueError, match="segments"):
         flash_attention(q, k, v, True, None, 32, 32, True, None, segments[:, :32])
+
+
+# --- value heads of their own size (PR 69) -----------------------------------
+
+# latent attention without positions: q/k heads of 128 + 64 features on value
+# heads of 128. One case a kernel: the forward kernel by the output, dQ by q's
+# gradient, dK/dV by k's and v's; 6 query heads on 6 and on 2 key/value heads.
+@pytest.mark.parametrize("g", [1, 3])
+@pytest.mark.parametrize("kernel", ["forward", "dq", "dkv"])
+def test_value_heads_of_128_under_qk_heads_of_192(kernel, g):
+    H, Hkv, S, hd, hd_v = 6, 6 // g, 256, 192, 128
+    ks = jax.random.split(jax.random.PRNGKey(11 + g), 4)
+    q = jax.random.normal(ks[0], (1, H, S, hd))
+    k = jax.random.normal(ks[1], (1, Hkv, S, hd))
+    v = jax.random.normal(ks[2], (1, Hkv, S, hd_v))
+    weight = jax.random.normal(ks[3], (1, H, S, hd_v))
+    scale = 1.0 / np.sqrt(hd)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, None, 128, 128, True)
+
+    def dense(q, k, v):
+        return _dense_reference(q, k, v, True, scale)
+
+    if kernel == "forward":
+        out = flash(q, k, v)
+        assert out.shape == (1, H, S, hd_v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(dense(q, k, v)),
+                                   rtol=1e-5, atol=1e-5)
+        # a scale of 1 / sqrt(128), the value heads' size, is another core
+        wrong = _dense_reference(q, k, v, True, 1.0 / np.sqrt(hd_v))
+        assert float(jnp.max(jnp.abs(out - wrong))) > 0.05
+        return
+    args = {"dq": (0,), "dkv": (1, 2)}[kernel]
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * weight), args)(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * weight), args)(q, k, v)
+    for mine, ref, of in zip(got, want, args):
+        assert mine.shape == ref.shape == (q, k, v)[of].shape
+        np.testing.assert_allclose(np.asarray(mine), np.asarray(ref),
+                                   rtol=1e-4, atol=2e-5)
