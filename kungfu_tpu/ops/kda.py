@@ -33,11 +33,15 @@ one underflows to 0 and nothing overflows.
 
 Four kernels on `ops.gated_delta`'s grid (batch, blocks of heads, blocks of
 chunks), Mosaic where the program is lowered for the TPU and the same kernels
-interpreted anywhere else:
+interpreted anywhere else. The running sums live in them: a chunk's (C, dk)
+tile added to itself log2 C times, turned down its rows (`_sums`), float32.
 
-1. `_pairs_kernel`, every chunk on its own: A without beta, float32, and P
-   in q's type. XLA multiplies beta in and `gated_delta._unit_lower_inverse`
-   (the delta rule's own substitution kernel) makes T = (I + A)^-1.
+1. `_pairs_kernel`, every chunk on its own, takes g: it makes G, the
+   running sum of g inside the chunk, A without beta in float32 and P in
+   q's type from it, and writes G out for the three kernels below, which
+   read it as it is. XLA multiplies beta in and
+   `gated_delta._unit_lower_inverse` (the delta rule's own substitution
+   kernel) makes T = (I + A)^-1.
 2. `_forward_kernel`, the chunks in sequence with each head's float32 state
    in VMEM scratch, as the gated delta rule's: W = T (beta exp(G) K), U = T
    (beta V) - W S, O and the next state, the state's rows decayed by
@@ -46,14 +50,17 @@ interpreted anywhere else:
    dG, dbeta of what the chunk's products read, and the cotangents of T and
    P in float32.
 4. `_pairs_back_kernel`: what q, k and G receive through A and P, the same
-   strips and distances transposed.
+   strips and distances transposed. It takes the third kernel's dG, adds
+   its own and sums the two from each position to its chunk's end (G's
+   sum transposed): what it writes is dg, which the op returns as it is.
 
 q, k, v come in the model's type (bfloat16: the matrix products take them so
 and accumulate in float32; float32 inputs give a float32 computation); g and
 beta are float32, every decay is an exponential of a difference of running
 sums taken in float32, and the state is float32. Between the passes the op
 keeps its five inputs and the states at the chunks' starts (B H S/C dk dv
-float32).
+float32): G is not kept, the backward pass's pairs are made again and G
+with them.
 
 `models/mixers/kda.py` runs it as the core of a layer whose `mixer` is
 `"kda"`, under the scope `kda_core`.
@@ -125,6 +132,21 @@ def _lanes(t):
     return jnp.sum(t, axis=1, keepdims=True)
 
 
+def _sums(x, to_end: bool = False):
+    """The running sum down the rows of one chunk's (C, dk) float32 tile,
+    each row with the rows above it (`to_end`: with the rows below it, the
+    transpose), C a power of two: log2 C turns of the tile added to itself,
+    float32 as `jnp.cumsum`'s."""
+    C = x.shape[0]
+    at = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    step = 1
+    while step < C:  # a row takes the sum `step` rows up (down) where one is
+        has, turn = (at < C - step, C - step) if to_end else (at >= step, step)
+        x = x + jnp.where(has, pltpu.roll(x, turn, 0), 0.0)
+        step *= 2
+    return x
+
+
 def _pairs(q, k, G):
     """One chunk: q, k (C, dk) in their type, G (C, dk) float32 the running
     sum of the log decays -> (A without beta, strictly below the diagonal;
@@ -193,24 +215,30 @@ def _pairs_back(q, k, G, dA, dP):
     return dq, dk, dG
 
 
-def _pairs_kernel(q_ref, k_ref, G_ref, A_ref, P_ref, *, chunk: int):
-    """A block of chunks of some heads, every chunk on its own."""
+def _pairs_kernel(q_ref, k_ref, g_ref, A_ref, P_ref, G_ref, *, chunk: int):
+    """A block of chunks of some heads, every chunk on its own: G of the
+    chunk's log decays first, for its pairs and for the kernels after it."""
     heads, blocks = A_ref.shape[1], A_ref.shape[2]
 
     def one(i, carry):
         h, c = i // blocks, i % blocks
         rows = _rows(c, chunk)
-        A, P = _pairs(q_ref[0, h, rows, :], k_ref[0, h, rows, :],
-                      G_ref[0, h, rows, :])
+        G = _sums(g_ref[0, h, rows, :].astype(jnp.float32))
+        A, P = _pairs(q_ref[0, h, rows, :], k_ref[0, h, rows, :], G)
         A_ref[0, h, c] = A
         P_ref[0, h, c] = P.astype(P_ref.dtype)
+        G_ref[0, h, rows, :] = G
         return carry
 
     lax.fori_loop(0, heads * blocks, one, None)
 
 
-def _pairs_back_kernel(q_ref, k_ref, G_ref, dA_ref, dP_ref, dq_ref, dk_ref,
-                       dG_ref, *, chunk: int):
+def _pairs_back_kernel(q_ref, k_ref, G_ref, dA_ref, dP_ref, dG_ref, dq_ref,
+                       dk_ref, dg_ref, *, chunk: int):
+    """`dG_ref` is what G receives from the chunk's other products
+    (`_backward_kernel`'s); with the pairs' own it is summed from each
+    position to its chunk's end, G being the running sum of g: g's whole
+    cotangent."""
     heads, blocks = dA_ref.shape[1], dA_ref.shape[2]
 
     def one(i, carry):
@@ -221,7 +249,7 @@ def _pairs_back_kernel(q_ref, k_ref, G_ref, dA_ref, dP_ref, dq_ref, dk_ref,
                                  dP_ref[0, h, c])
         dq_ref[0, h, rows, :] = dq.astype(dq_ref.dtype)
         dk_ref[0, h, rows, :] = dk.astype(dk_ref.dtype)
-        dG_ref[0, h, rows, :] = dG
+        dg_ref[0, h, rows, :] = _sums(dG_ref[0, h, rows, :] + dG, to_end=True)
         return carry
 
     lax.fori_loop(0, heads * blocks, one, None)
@@ -337,33 +365,38 @@ _EVERY_CHUNK_ALONE = dict(compiler_params=pltpu.CompilerParams(
     vmem_limit_bytes=VMEM_LIMIT))
 
 
-def _pairs_call(q, k, G, *, chunk: int, interpret: bool):
-    """-> (A without beta (B, H, S / chunk, chunk, chunk) float32, P
-    likewise in q's type)."""
+def _pairs_call(q, k, g, *, chunk: int, interpret: bool):
+    """g the log decays -> (A without beta (B, H, S / chunk, chunk, chunk)
+    float32, P likewise in q's type, G the running sum of g inside each
+    chunk, g's shape in float32)."""
     B, H, S, dk = q.shape
     grid, spec = _specs(B, H, S, dk, dk, chunk, back=False)
     shape = (B, H, S // chunk, chunk, chunk)
     return pl.pallas_call(
         functools.partial(_pairs_kernel, chunk=chunk), grid=grid,
-        in_specs=[spec["qk"]] * 3, out_specs=[spec["T"]] * 2,
+        in_specs=[spec["qk"]] * 3, out_specs=[spec["T"]] * 2 + [spec["qk"]],
         out_shape=[jax.ShapeDtypeStruct(shape, jnp.float32),
-                   jax.ShapeDtypeStruct(shape, q.dtype)],
-        interpret=interpret, name="kda_pairs", **_EVERY_CHUNK_ALONE)(q, k, G)
+                   jax.ShapeDtypeStruct(shape, q.dtype),
+                   jax.ShapeDtypeStruct(g.shape, jnp.float32)],
+        interpret=interpret, name="kda_pairs", **_EVERY_CHUNK_ALONE)(q, k, g)
 
 
-def _pairs_back_call(q, k, G, dA, dP, *, chunk: int, interpret: bool):
-    """-> (dq, dk in q's type, dG float32): what they receive through A and P."""
+def _pairs_back_call(q, k, G, dA, dP, dG, *, chunk: int, interpret: bool):
+    """dG float32 what G receives beside A and P -> (dq, dk in q's type:
+    what they receive through A and P; dg float32, in dG's buffer: all that
+    the log decays receive)."""
     B, H, S, dk = q.shape
     grid, spec = _specs(B, H, S, dk, dk, chunk, back=False)
     return pl.pallas_call(
         functools.partial(_pairs_back_kernel, chunk=chunk), grid=grid,
-        in_specs=[spec["qk"]] * 3 + [spec["T"]] * 2,
+        in_specs=[spec["qk"]] * 3 + [spec["T"]] * 2 + [spec["qk"]],
         out_specs=[spec["qk"]] * 3,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(G.shape, jnp.float32)],
+        input_output_aliases={5: 2},
         interpret=interpret, name="kda_pairs_backward", **_EVERY_CHUNK_ALONE,
-    )(q, k, G, dA, dP)
+    )(q, k, G, dA, dP, dG)
 
 
 def _forward(q, k, v, G, beta, T, P, *, chunk: int, interpret: bool):
@@ -409,14 +442,6 @@ def _backward(q, k, v, G, beta, T, P, states, do, *, chunk: int,
     return (*d, dbeta.reshape(beta.shape), dT, dP)
 
 
-def _running(g, chunk: int):
-    """The running sum of the log decays inside each chunk, (B, H, S, dk)
-    float32."""
-    B, H, S, dk = g.shape
-    return jnp.cumsum(g.astype(jnp.float32).reshape(B, H, S // chunk, chunk, dk),
-                      axis=3).reshape(B, H, S, dk)
-
-
 def _solve(A, beta, dtype):
     """T = (I + beta A)^-1 a chunk, the inverse in float32 (`gated_delta`'s
     substitution kernel), rounded after it."""
@@ -439,8 +464,7 @@ def _fwd(q, k, v, g, beta, chunk):
     if chunk & (chunk - 1) or S % chunk:
         raise ValueError(f"kda_rule: the sequence length {S} is no multiple "
                          f"of the chunk {chunk}, a power of two")
-    G = _running(g, chunk)
-    A, P = _on_platform(_pairs_call, q, k, G, chunk=chunk)
+    A, P, G = _on_platform(_pairs_call, q, k, g, chunk=chunk)
     T = _solve(A, beta, q.dtype)
     o, states = _on_platform(_forward, q, k, v, G, beta, T, P, chunk=chunk)
     return o, (q, k, v, g, beta, states)
@@ -448,21 +472,14 @@ def _fwd(q, k, v, g, beta, chunk):
 
 def _bwd(chunk, res, do):
     q, k, v, g, beta, states = res
-    B, H, S, dk = g.shape
-    G = _running(g, chunk)
-    A, P = _on_platform(_pairs_call, q, k, G, chunk=chunk)
+    A, P, G = _on_platform(_pairs_call, q, k, g, chunk=chunk)
     T, back_solve = jax.vjp(lambda A, beta: _solve(A, beta, q.dtype), A, beta)
     dq, dk_, dv, dG, dbeta, dT, dP = _on_platform(
         _backward, q, k, v, G, beta, T, P, states, do, chunk=chunk)
     dA, dbeta_T = back_solve(dT.astype(T.dtype))
-    dq_P, dk_P, dG_P = _on_platform(_pairs_back_call, q, k, G, dA, dP,
-                                    chunk=chunk)
-    # G is the running sum of g inside a chunk: g's cotangent is dG's from
-    # each position to its chunk's end
-    dG = (dG + dG_P).reshape(B, H, S // chunk, chunk, dk)
-    dg = jnp.flip(jnp.cumsum(jnp.flip(dG, axis=3), axis=3), axis=3)
-    return (dq + dq_P, dk_ + dk_P, dv, dg.reshape(g.shape).astype(g.dtype),
-            dbeta + dbeta_T)
+    dq_P, dk_P, dg = _on_platform(_pairs_back_call, q, k, G, dA, dP, dG,
+                                  chunk=chunk)
+    return dq + dq_P, dk_ + dk_P, dv, dg.astype(g.dtype), dbeta + dbeta_T
 
 
 kda_rule.defvjp(_fwd, _bwd)

@@ -2,8 +2,11 @@
 against the recurrence a position at a time (the definition,
 `benchmark/reference/kimi_linear.delta_rule`), outputs and the gradients of
 all five inputs, under weak, strong and mixed decays; the pairs' kernel
-against the pairs written out; what a decay a head, a state in bfloat16 and
-an exp(G) beside an exp(-G) would cost."""
+against the pairs written out, and the running sums it and its transpose
+make against `jnp.cumsum`; what a decay a head, a state in bfloat16 and an
+exp(G) beside an exp(-G) would cost."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +48,14 @@ def _weighted(fn, weight):
     return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weight)
 
 
+def _running(g, chunk):
+    """The running sum of g inside each chunk, XLA's: the reference of the
+    kernels' own."""
+    B, H, S, dk = g.shape
+    return jnp.cumsum(g.reshape(B, H, S // chunk, chunk, dk),
+                      axis=3).reshape(g.shape)
+
+
 CASES = [
     # S, chunk, B, H, decay a position and feature
     (32, 8, 1, 2, 0.05),      # four chunks of one sub-block
@@ -55,21 +66,29 @@ CASES = [
 IDS = ["-".join(map(str, case)) for case in CASES]
 
 
+@functools.cache
+def _both_ways(S, chunk, B, H, decay):
+    """A case's inputs, then the recurrence's and the kernels' output and
+    five gradients under one weight: the tests of a case share them."""
+    args = _inputs(S + chunk, B, H, S, 16, 24, decay)
+    weight = jax.random.normal(jax.random.PRNGKey(7), (B, H, S, 24))
+
+    def both(fn):
+        return fn(*args), jax.grad(_weighted(fn, weight),
+                                   argnums=(0, 1, 2, 3, 4))(*args)
+
+    return (args, both(lambda *a: delta_rule(*a, block=16)),
+            both(lambda *a: kda_rule(*a, chunk)))
+
+
 @pytest.mark.parametrize("S,chunk,B,H,decay", CASES, ids=IDS)
 def test_outputs_and_all_five_gradients_agree_with_the_recurrence(
         S, chunk, B, H, decay):
-    args = _inputs(S + chunk, B, H, S, 16, 24, decay)
-    want = delta_rule(*args, block=16)
-    got = kda_rule(*args, chunk)
+    _, (want, want_grads), (got, grads) = _both_ways(S, chunk, B, H, decay)
     assert got.shape == (B, H, S, 24) and got.dtype == jnp.float32
     assert bool(jnp.isfinite(got).all())
     assert _rel(got, want) < 2e-5
-    weight = jax.random.normal(jax.random.PRNGKey(7), (B, H, S, 24))
-    want = jax.grad(_weighted(lambda *a: delta_rule(*a, block=16), weight),
-                    argnums=(0, 1, 2, 3, 4))(*args)
-    got = jax.grad(_weighted(lambda *a: kda_rule(*a, chunk), weight),
-                   argnums=(0, 1, 2, 3, 4))(*args)
-    for name, g, w in zip(("q", "k", "v", "g", "beta"), got, want):
+    for name, g, w in zip(("q", "k", "v", "g", "beta"), grads, want_grads):
         assert g.shape == w.shape, name
         assert bool(jnp.isfinite(g).all()), name
         assert _rel(g, w) < 5e-5, name
@@ -83,20 +102,74 @@ def _pairs_written_out(q, k, G):
     return jnp.tril(A, -1), jnp.tril(P)
 
 
-@pytest.mark.parametrize("decay", [0.05, 3.0, "mixed"])
-def test_the_pairs_kernel_against_the_pairs_written_out(decay):
+@pytest.mark.parametrize("decay,atol", [(0.05, 1e-7), (3.0, 2e-6),
+                                        ("mixed", 1e-7)])
+def test_the_pairs_kernel_against_the_pairs_written_out(decay, atol):
     """One chunk of 64 in sub-blocks of 16: three strips through a
-    reference row and sixteen distances inside a sub-block."""
+    reference row and sixteen distances inside a sub-block. The reference's
+    G is XLA's sum and the kernel's is its own: under e^-3 a position G
+    reaches -190, where float32 sums taken in two orders stand 2e-5 apart,
+    and the pairs made from them 1e-6 (each 7e-7 from the pairs of a
+    float64 G)."""
     q, k, _, g, _ = _inputs(4, 1, 1, 64, 16, 16, decay)
-    G = kda._running(g, 64)
-    A, P = kda._pairs_call(q, k, G, chunk=64, interpret=True)
+    G = _running(g, 64)
+    A, P, _ = kda._pairs_call(q, k, g, chunk=64, interpret=True)
     want_A, want_P = _pairs_written_out(q[0, 0], k[0, 0], G[0, 0])
     assert A.shape == P.shape == (1, 1, 1, 64, 64)
-    np.testing.assert_allclose(A[0, 0, 0], want_A, rtol=1e-5, atol=1e-7)
-    np.testing.assert_allclose(P[0, 0, 0], want_P, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(A[0, 0, 0], want_A, rtol=1e-5, atol=atol)
+    np.testing.assert_allclose(P[0, 0, 0], want_P, rtol=1e-5, atol=atol)
     # nothing above the diagonal, and A's diagonal is empty
     assert not np.triu(np.asarray(A[0, 0, 0])).any()
     assert not np.triu(np.asarray(P[0, 0, 0]), 1).any()
+
+
+@pytest.mark.parametrize("decay", [0.05, 3.0, "mixed"])
+def test_the_pairs_kernel_makes_the_running_sum_of_each_chunk(decay):
+    """G, the kernel's third output, against `jnp.cumsum` a chunk in
+    float32: two heads of three chunks, so that no sum crosses a chunk's or
+    a head's edge."""
+    q, k, _, g, _ = _inputs(3, 1, 2, 192, 16, 16, decay)
+    _, _, G = kda._pairs_call(q, k, g, chunk=64, interpret=True)
+    assert G.shape == g.shape and G.dtype == jnp.float32
+    np.testing.assert_allclose(G, _running(g, 64), rtol=1e-6, atol=0)
+    np.testing.assert_array_equal(G[:, :, ::64], g[:, :, ::64])
+
+
+def test_the_pairs_backward_kernel_sums_both_halves_of_dG_to_the_chunks_end():
+    """dg against XLA's reverse sum a chunk: the half the kernel is handed
+    beside the half it makes itself (which a dG of zeros leaves alone)."""
+    q, k, _, g, _ = _inputs(12, 1, 2, 128, 16, 16, 0.3)
+    ks = jax.random.split(jax.random.PRNGKey(13), 3)
+    dA = jnp.tril(jax.random.normal(ks[0], (1, 2, 2, 64, 64)), -1)
+    dP = jnp.tril(jax.random.normal(ks[1], (1, 2, 2, 64, 64)))
+    dG = jax.random.normal(ks[2], g.shape)
+
+    def dg(dG):
+        return kda._pairs_back_call(q, k, _running(g, 64), dA, dP, dG,
+                                    chunk=64, interpret=True)[2]
+
+    handed = _running(dG[:, :, ::-1], 64)[:, :, ::-1]
+    got = dg(dG) - dg(jnp.zeros_like(dG))
+    np.testing.assert_allclose(got, handed, rtol=1e-5, atol=1e-5)
+    # a chunk's last position keeps its own and nothing of the next chunk's
+    np.testing.assert_allclose(got[:, :, 63::64], dG[:, :, 63::64],
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("S,chunk,B,H,decay", CASES, ids=IDS)
+def test_dg_is_dG_summed_from_each_position_to_its_chunks_end(
+        S, chunk, B, H, decay):
+    """The reverse sum: the log decays' gradient against the recurrence's
+    where what G receives differs from row to row of a chunk (the
+    recurrence's dg less the next position's is that row's dG), so a sum
+    that ran the wrong way, or over a chunk's edge, would show."""
+    _, (_, want_grads), (_, grads) = _both_ways(S, chunk, B, H, decay)
+    want, got = want_grads[3], grads[3]
+    rows = (want - jnp.roll(want, -1, axis=2)).reshape(B, H, S // chunk,
+                                                       chunk, 16)[..., :-1, :]
+    assert float(jnp.std(rows, axis=3).min()) > 0
+    assert _rel(got, want) < 5e-5
+    assert _rel(got[:, :, ::chunk], want[:, :, ::chunk]) < 5e-5  # a chunk's sum
 
 
 def test_exp_g_and_exp_minus_g_formed_apart_do_not_survive_a_strong_decay():
@@ -104,7 +177,7 @@ def test_exp_g_and_exp_minus_g_formed_apart_do_not_survive_a_strong_decay():
     infinite within five positions and the product a NaN, where the kernel's
     pairs are finite and the recurrence's."""
     q, k, v, g, beta = _inputs(6, 1, 1, 64, 16, 16, "mixed")
-    G = kda._running(g, 64)[0, 0]
+    G = _running(g, 64)[0, 0]
     apart = (k[0, 0] * jnp.exp(G)) @ (k[0, 0] * jnp.exp(-G)).T
     assert not bool(jnp.isfinite(apart).all())
     got = kda_rule(q, k, v, g, beta)
@@ -195,6 +268,40 @@ def test_the_rule_is_kernels_and_no_loop():
                    "kda_pairs_backward", "gated_delta_solve"):
         assert kernel in text, kernel
     assert " scan[" not in text.split("pallas_call")[0]
+
+
+def _equations(jaxpr):
+    """Every equation outside a `pallas_call`, the sub-jaxprs' with it
+    (`platform_dependent`'s branches, `custom_vjp`'s calls)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_the_running_sums_are_the_kernels_own():
+    """Beside the test above: the jaxpr of the rule's gradient holds no
+    `cumsum`, `reduce_window` or `rev` outside a `pallas_call` and no
+    float32 add of g's shape (q and k are bfloat16 here, so theirs do not
+    count), and still names the four kernels and the inverse's."""
+    args = _inputs(2, 1, 2, 256, 16, 24, 0.1, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kda_rule(*a).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+    eqns = list(_equations(jaxpr.jaxpr))
+    names = {eqn.primitive.name for eqn in eqns}
+    assert not {n for n in names if n.startswith(("cumsum", "reduce_window"))
+                or n == "rev"}, names
+    g = args[3]
+    assert not [e for e in eqns if e.primitive.name == "add"
+                and e.outvars[0].aval.shape == g.shape
+                and e.outvars[0].aval.dtype == g.dtype]
+    kernels = {e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"}
+    assert kernels == {"kda_pairs", "kda_forward", "kda_backward",
+                       "kda_pairs_backward", "gated_delta_solve"}
 
 
 @pytest.mark.parametrize("S,chunk", [(100, 64), (64, 48), (32, 64)])
