@@ -9,7 +9,12 @@ and its fields in `TransformerConfig`. Six mixers stand in the table:
 `attention`, `gated_delta`, `kda` (PR 69), `latent`, `mamba2`, `short_conv`.
 `latent` takes `latent_dims[0]` 0 for no q latent, `positions` "none" for
 nothing turned, and value heads whose size `hd_v` differs from the q/k heads'
-on the flash core as on the dense one.
+on the flash core as on the dense one; under `yarn` its rotated features
+turn at YaRN's frequencies (PR 71), and a scale of the scores' own goes
+through `attention_multiplier` to the flash core. A mixer reads (B, S, D)
+whatever the residual path is: of several streams (`streams` > 1) the layer
+hands it the branch's input read out of them (`transformer._read`) and takes
+its output back into them.
 
 The arrows: `ops/` <- `models/blocks.py` <- this package <-
 `models/transformer.py`. Nothing here imports `models/transformer.py` (the

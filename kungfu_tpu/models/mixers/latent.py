@@ -4,9 +4,14 @@ MLA as GLM-4.7-Flash and Kimi Linear have it). q through a normed latent, or,
 `w_q_down`, no `q_latent_norm`, and `w_q_up` is (D, heads x features));
 keys and values through another latent, one key shared by every head beside
 each head's own features, turned by its position with q's like features
-(`positions` "rope") or, `positions` "none", as the projection gives it:
-nothing is turned, and the one shared key still stands beside each head's
-own features. `latent_dims` = (q latent rank or 0, key/value latent rank,
+(`positions` "rope", under `yarn` at YaRN's blended frequencies over the
+rotated features, cos and sin times its attention factor, as
+`mixers/attention.py` has them) or, `positions` "none", as the projection
+gives it: nothing is turned, and the one shared key still stands beside each
+head's own features. The softmax's scale is 1 / sqrt(q/k head size) or
+`attention_multiplier` in its place (DeepSeek-V2's mscale^2 / sqrt(head size)
+under YaRN goes there), which `blocks.attention_core_of` hands the flash core;
+the dense core has no scale of its own and the configuration refuses it. `latent_dims` = (q latent rank or 0, key/value latent rank,
 features a q/k head of its own, features of the shared key, features a value
 head). k and v are laid out a head for the core every other attention layer
 runs; the value heads' size `hd_v` may differ from the q/k heads' on either
@@ -87,9 +92,10 @@ def _latent_attention(h, layer, cfg, core=None):
     [q_nope | q_rope] = c_q W_q_up;
     [c_kv | k_r] = h W_kv_down, c_kv normed, and a head's [k_nope | v] =
     c_kv W_kv_up; q = [q_nope | rot(q_rope)] and every head's k = [its
-    k_nope | rot(k_r)], the one rotated key of all heads; the causal core
+    k_nope | rot(k_r)], the one rotated key of all heads (rot at `yarn`'s
+    frequencies where the configuration has it); the causal core
     the configuration names over heads of nope + rope features, at the scale
-    1 / sqrt(nope + rope); W_o. Training lays k and v out a head, as the
+    1 / sqrt(nope + rope) or `attention_multiplier`; W_o. Training lays k and v out a head, as the
     published implementations do (absorbing W_kv_up into q is a decode
     device). Inside, a head's rotated features stand first: the same
     permutation of q's and k's features, made on W_q_up's columns and where
@@ -128,7 +134,7 @@ def _latent_attention(h, layer, cfg, core=None):
     with jax.named_scope("rope" if turned else "mla_up"):
         q, k = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
         if turned:
-            q, k = _rope(q, k, cfg.rope_theta, rope / hd, ())
+            q, k = _rope(q, k, cfg.rope_theta, rope / hd, cfg.yarn)
     with jax.named_scope("attn_latent"), jax.named_scope("attn_core"):
         ctx = (core or attention_core_of(cfg))(q, k, v)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * value)

@@ -59,6 +59,19 @@ TPU-first design notes:
   where the residual takes it, the attention scores times
   `attention_multiplier` in the place of 1 / sqrt(head size), the logits over
   `logits_scaling`; each 1 (or unset) leaves the program as it was.
+- The residual path is one stream a position, or several (`streams` n > 1:
+  manifold-constrained hyper-connections, `ops/hyper_connections.py`). With
+  n streams the layer scan carries (B, S, n * D), the embedding's output
+  enters as n copies (`_enter`, scope `hc_in`) and the stacks' output leaves
+  as their sum (`_leave`, `hc_out`), so the heads, the losses and a
+  multi-token-prediction module read (B, S, D) as ever; around each branch
+  the layer makes three maps from the streams and the branch's own leaves
+  (`hc1_*` the mixer's, `hc2_*` the feed-forward's), reads the branch's input
+  out of the streams (`_read`: scope `hc` > `hc_maps`, `hc_read`) and writes
+  the streams back with the branch's output mixed in (`_taken`: `hc_write`).
+  A loop, a router that reads the layer's input, a sparse index and the ring
+  and pipeline paths carry one stream and refuse several with a sentence.
+  With one stream no map leaf is built and every program is what it was.
 - A row may be several documents: `end_of_document` names the id that ends
   one, and the ids are the only carrier. `_segments` numbers each position's
   document (scope `segments`), and the numbers go to every mixer of the
@@ -223,6 +236,17 @@ class TransformerConfig:
     # loss times `indexer_loss_weight` to the model's. (): no indexer
     sparse_index: Tuple = ()
     indexer_loss_weight: float = 1.0
+    # manifold-constrained hyper-connections (`ops.hyper_connections`,
+    # arXiv:2512.24880): so many residual streams a position, mixed around
+    # every branch by maps the layer computes from them (leaves `hc1_phi`,
+    # `hc1_a`, `hc1_b` of the mixer's branch, `hc2_*` of the feed-forward's):
+    # the Sinkhorn-Knopp passes of the streams' own map, the eps beside its
+    # sums and the clamp on its logits. 1: the one residual stream, no map
+    # leaf and the program it always was
+    streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple = (-30.0, 30.0)
 
     def __post_init__(self):
         for field, value, known in (
@@ -285,6 +309,17 @@ class TransformerConfig:
                 "softmax attention: "
                 f"not by mixer {self.mixer!r} on the {self.attn_core} core, "
                 "nor by a multi-token-prediction module")
+        if self.streams < 1:
+            raise ValueError(f"streams {self.streams}: one residual stream or "
+                             "more")
+        if self.streams > 1 and (self.loop_steps > 1 or self.sparse_index or (
+                self.router_input == "layer" and self.ffn == "moe")):
+            raise ValueError(
+                "residual streams (streams > 1) are built for the layer scan "
+                "of the normal path: a loop's final norm a loop step, a "
+                "router that reads the layer's own input ahead of the mixer "
+                "(router_input 'layer') and a sparse index beside the step "
+                "read one stream, and none of them carries several")
         if self.layer_kinds and len(self.layer_kinds) != self.n_layers:
             raise ValueError(f"{len(self.layer_kinds)} layer kinds for "
                              f"{self.n_layers} layers")
@@ -443,6 +478,21 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
         1 + the weight, from 0."""
         return (jnp.zeros if cfg.norm_offset else jnp.ones)(shape, jnp.float32)
 
+    def init_maps(k_phi, k_b, branch, n):
+        """One branch's maps at the start: Phi as every matrix; the three
+        gains 0.25 (the mHC paper starts them at 0.01: there the static part
+        is all there is for the first steps, and a timed state in which the
+        dynamic part is rounding could not tell a program that drops it);
+        the biases normal(0, 1) with 2 more on the diagonal of the streams'
+        own map, so that H_pre and H_post differ a stream and H_res is
+        neither uniform nor the identity (no balancing step moves a bias
+        here either: drawn, as `router_bias` is)."""
+        b = jax.random.normal(k_b, (2 * n + n * n,), jnp.float32)
+        b = b.at[2 * n:].add(2.0 * jnp.eye(n, dtype=jnp.float32).ravel())
+        return {f"{branch}_phi": dense(k_phi, (n * D, 2 * n + n * n)),
+                f"{branch}_a": jnp.full((3,), 0.25, jnp.float32),
+                f"{branch}_b": b}
+
     def init_layer(key, cfg):
         # The mixer's leaves are its record's. The feed-forward's are drawn
         # from the layer's first split ([2] on), the shared expert's from [3]
@@ -493,6 +543,13 @@ def init_transformer(key, cfg: TransformerConfig) -> Dict:
             if cfg.shared_gate:
                 layer["w_shared_gate"] = dense(
                     jax.random.split(jax.random.fold_in(key, 2), 6)[5], (D, 1))
+        if cfg.streams > 1:  # a branch's maps, from fold 7: no other's keys
+            hk = jax.random.split(jax.random.fold_in(key, 7), 4)
+            for at, (branch, there) in enumerate((("hc1", mixer is not None),
+                                                  ("hc2", cfg.ffn != "none"))):
+                if there:
+                    layer.update(init_maps(hk[2 * at], hk[2 * at + 1], branch,
+                                           cfg.streams))
         return layer
 
     # stack layers: leading axis = layer, enables lax.scan over layers; a
@@ -540,7 +597,8 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
     three under "reglu" as under "swiglu");
     a layer of one branch has that branch's leaves alone; embedding and an
     untied head sharded over vocab; an expert stack over `ep_axis` on its
-    expert dimension, the router whole. Layer-stacked leaves have a leading
+    expert dimension, the router whole; the maps of several residual streams
+    (`hc1_*`, `hc2_*`) whole on every chip. Layer-stacked leaves have a leading
     layer axis (unsharded); a configuration with `layer_kinds` has a tuple of
     such stacks. A multi-token-prediction module's block is sharded like a
     layer of its kind, its norms and projection whole.
@@ -579,6 +637,13 @@ def param_pspecs(cfg: TransformerConfig, tp_axis: str = "tp",
                     layers.update(shared_gate=P(None, None, t))
             if cfg.shared_gate:
                 layers.update(w_shared_gate=P(None, None, None))
+        if cfg.streams > 1:  # a branch's maps whole on every chip
+            for branch, there in (("hc1", mixer is not None),
+                                  ("hc2", cfg.ffn != "none")):
+                if there:
+                    layers.update({f"{branch}_phi": P(None, None, None),
+                                   f"{branch}_a": P(None, None),
+                                   f"{branch}_b": P(None, None)})
         return layers
 
     stacks = tuple(stack_specs(kind) for kind, _ in cfg.stacks)
@@ -688,13 +753,51 @@ def _behind(y, layer, norm: str, cfg: TransformerConfig):
         return _rmsnorm(y, _scale(layer[norm], cfg), cfg.norm_eps)
 
 
-def _taken(x, y, layer, norm: str, cfg: TransformerConfig):
+def _taken(x, y, layer, norm: str, cfg: TransformerConfig, maps=None):
     """The residual stream x with a branch's output y in it: y behind its
     second norm where the configuration has one (`_behind`), times
-    `residual_multiplier` where that is not 1."""
+    `residual_multiplier` where that is not 1. Of several streams (`maps`,
+    the branch's own from `_read`), x is all of them and each takes its
+    share of the others and of y: X'[i] = sum_j H_res[i, j] X[j] + H_post[i]
+    y (scope `hc` > `hc_write`)."""
     y = _behind(y, layer, norm, cfg)
-    return x + (y if cfg.residual_multiplier == 1.0
-                else y * cfg.residual_multiplier)
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    if maps is None:
+        return x + y
+    from kungfu_tpu.ops import hyper_connections
+
+    # Between the barriers the mixing is ops of its own: without them XLA
+    # makes the write the epilogue of the branch's last product and the next
+    # maps' mean square a second result of it, under those scopes' names, and
+    # no reader can say what the residual path costs (PERF.md, PR 71)
+    x, y = jax.lax.optimization_barrier((x, y))
+    with jax.named_scope("hc"), jax.named_scope("hc_write"):
+        return jax.lax.optimization_barrier(
+            hyper_connections.write(x, y, maps.res, maps.post))
+
+
+def _read(x, layer, branch: str, cfg: TransformerConfig):
+    """What a branch reads of the residual path, ahead of its norm -> (its
+    input (B, S, D), the branch's maps or None). The one stream as it is. Of
+    several (`streams` n > 1, x (B, S, n * D), `ops.hyper_connections`) the
+    branch's maps from the streams and its leaves `<branch>_phi`, `_a`, `_b`
+    (scope `hc` > `hc_maps`: the root mean square over all n D features, the
+    product with Phi, the sigmoids and the Sinkhorn passes, float32) and u =
+    sum_j H_pre[j] X[j] (`hc_read`); `_taken` writes with the same maps."""
+    if cfg.streams == 1:
+        return x, None
+    from kungfu_tpu.ops import hyper_connections
+
+    with jax.named_scope("hc"):
+        with jax.named_scope("hc_maps"):
+            maps = hyper_connections.maps(
+                x, layer[f"{branch}_phi"], layer[f"{branch}_a"],
+                layer[f"{branch}_b"], cfg.streams, cfg.hc_sinkhorn_iters,
+                cfg.hc_eps, cfg.hc_clamp)
+        with jax.named_scope("hc_read"):  # an op of its own: `_taken`'s note
+            return jax.lax.optimization_barrier(
+                hyper_connections.read(x, maps.pre)), maps
 
 
 def _early_routing(x, layer, cfg: TransformerConfig):
@@ -726,24 +829,32 @@ def _early_routing(x, layer, cfg: TransformerConfig):
 
 
 def _mixed(x, layer, cfg: TransformerConfig, core=None, segments=()):
-    """The residual stream x with the layer's first branch in it -> (x, the
-    layer's indexer loss or None): the mixer, its record's
-    (`mixers.mixer_of`), under the record's scope; x as it is of a layer
-    that is its feed-forward alone."""
+    """The residual path x (one stream, or all of several) with the layer's
+    first branch in it -> (x, the layer's indexer loss or None): the mixer,
+    its record's (`mixers.mixer_of`), under the record's scope, on what
+    `_read` hands it; x as it is of a layer that is its feed-forward alone."""
     segments, marks = segments[:1], segments[1:]
-    mixer, index_kl = mixer_of(cfg), None
-    if mixer is not None:
-        with jax.named_scope(mixer.scope):
-            y, index_kl = mixer.apply(x, layer, cfg, core, segments, marks)
-            x = _taken(x, y, layer, "ln1_post_scale", cfg)
-    return x, index_kl
+    mixer = mixer_of(cfg)
+    if mixer is None:
+        return x, None
+    u, maps = _read(x, layer, "hc1", cfg)
+    with jax.named_scope(mixer.scope):
+        y, index_kl = mixer.apply(u, layer, cfg, core, segments, marks)
+        if maps is None:
+            return _taken(x, y, layer, "ln1_post_scale", cfg), index_kl
+    # the streams' own mixing outside the branch's scope: `attn` stays the mixer's
+    return _taken(x, y, layer, "ln1_post_scale", cfg, maps), index_kl
 
 
 def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
     """One layer -> (x, aux): a mixer and a feed-forward, each a residual
     branch behind its own norm, or one of the two alone; with `post_norms`
     the branch's output goes through a second norm before the residual takes
-    it, and `residual_multiplier` scales what it takes. The mixer is its
+    it, and `residual_multiplier` scales what it takes. x is the one
+    residual stream (B, S, D), or with `streams` n > 1 all n side by side
+    (B, S, n * D): each branch then reads u = sum_j H_pre[j] X[j] and the
+    streams take X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y, the three maps
+    the branch's own (`_read`, `_taken`). The mixer is its
     record's (`_mixed`). An expert layer whose router reads the layer's input
     (`router_input` "layer") is routed first, ahead of the mixer
     (`_early_routing`). aux is the expert
@@ -762,29 +873,30 @@ def _layer(x, layer, cfg: TransformerConfig, core=None, segments=()):
 
 
 def _feed_forward(x, layer, cfg: TransformerConfig, routing=None):
-    """A layer's second branch on the residual stream x -> (x, the expert
-    layer's aux or None); `routing`, an expert layer's made ahead of the
-    mixer (`_early_routing`), or None."""
+    """A layer's second branch on the residual path x (one stream, or all of
+    several: `_read`) -> (x, the expert layer's aux or None); `routing`, an
+    expert layer's made ahead of the mixer (`_early_routing`), or None."""
     dt, eps = cfg.dtype, cfg.norm_eps
     if cfg.ffn == "none":
         return x, None
-    if cfg.ffn == "moe":
-        with jax.named_scope("moe"):
-            B, S, D = x.shape
-            h = _rmsnorm(x, _scale(layer["ln2_scale"], cfg), eps).reshape(B * S, D)
-            y, aux = _expert_layer(h, layer, cfg, routing)
-            return _taken(x, y.reshape(B, S, D), layer, "ln2_post_scale",
-                          cfg), aux
-    with jax.named_scope("ffn"):
-        h = _rmsnorm(x, _scale(layer["ln2_scale"], cfg), eps)
-        if cfg.ffn == "swiglu":
+    u, maps = _read(x, layer, "hc2", cfg)
+    aux = None
+    with jax.named_scope("moe" if cfg.ffn == "moe" else "ffn"):
+        h = _rmsnorm(u, _scale(layer["ln2_scale"], cfg), eps)
+        if cfg.ffn == "moe":
+            B, S, D = u.shape
+            y, aux = _expert_layer(h.reshape(B * S, D), layer, cfg, routing)
+            y = y.reshape(B, S, D)
+        elif cfg.ffn == "swiglu":
             y = _silu_gate_out(h @ layer["w_gate"].astype(dt),
                                h @ layer["w_up"].astype(dt),
                                layer["w_down"].astype(dt))
         else:
             y = _gelu_out(h @ layer["w_in"].astype(dt),
                           layer["w_out"].astype(dt))
-        return _taken(x, y, layer, "ln2_post_scale", cfg), None
+        if maps is None:
+            return _taken(x, y, layer, "ln2_post_scale", cfg), aux
+    return _taken(x, y, layer, "ln2_post_scale", cfg, maps), aux
 
 
 # `layer_remat`: the scan keeps the layer's input and, of what the layer
@@ -826,6 +938,12 @@ def _block(x, layer, cfg: TransformerConfig, core=None):
     mixer = mixer_of(cfg)
     if mixer is not None and mixer.off_the_normal_path:
         raise NotImplementedError(mixer.off_the_normal_path)
+    if cfg.streams > 1:
+        raise NotImplementedError(
+            "residual streams (streams > 1) are built for the normal path "
+            "alone: the ring and pipeline paths hand a layer one stream "
+            "(B, S, D) a shard or a stage, and nothing there enters or "
+            "leaves the streams")
     return _layer(x, layer, cfg, core=core)[0]
 
 
@@ -927,6 +1045,29 @@ def _segments(tokens, cfg: TransformerConfig):
         return (jnp.cumsum(behind_an_end.astype(jnp.int32), axis=1),)
 
 
+def _enter(x, cfg: TransformerConfig):
+    """The residual path's start from the embedding's (or a module's
+    projection's) output x (B, S, D): x itself, or of `streams` n > 1 the n
+    streams (B, S, n * D), each a copy of it (scope `hc_in`)."""
+    if cfg.streams == 1:
+        return x
+    from kungfu_tpu.ops import hyper_connections
+
+    with jax.named_scope("hc_in"):
+        return hyper_connections.enter(x, cfg.streams)
+
+
+def _leave(x, cfg: TransformerConfig):
+    """The residual path's end, ahead of a final norm: x itself, or the sum
+    of the streams (B, S, n * D) -> (B, S, D) (scope `hc_out`)."""
+    if cfg.streams == 1:
+        return x
+    from kungfu_tpu.ops import hyper_connections
+
+    with jax.named_scope("hc_out"):
+        return hyper_connections.leave(x, cfg.streams)
+
+
 def _loop_step_end(u, params, cfg: TransformerConfig, each):
     """The end of a loop step on the stacks' output u: the model's final
     norm (scope `loop_norm`) -> (what the next loop step reads, what the
@@ -937,8 +1078,10 @@ def _loop_step_end(u, params, cfg: TransformerConfig, each):
 
 
 def _hidden(params, tokens, cfg: TransformerConfig, each=None):
-    """-> (final hidden states, the expert layers' stacked aux or None),
-    one scan for each stack of layers of one kind. Under plain S-SGD on
+    """-> (final hidden states (B, S, D), the expert layers' stacked aux or
+    None), one scan for each stack of layers of one kind. With `streams` n >
+    1 the embedding's output enters n streams, the scans carry (B, S, n * D)
+    and what is handed back is the streams' sum (`_enter`, `_leave`). Under plain S-SGD on
     several chips a layer's gradients are averaged in the iteration of the
     backward scan that produces them (`ops.collective.reduce_in_backward`,
     the identity otherwise).
@@ -959,7 +1102,7 @@ def _hidden(params, tokens, cfg: TransformerConfig, each=None):
     order of the T backward scans and their head passes to XLA: the Ouro
     cell's step then wants 19.3 GB of the chip's 16.9 and the outer scan
     15.2, `benchmark/aot_check.py`, PR 48.)"""
-    x = _embed(params, tokens, cfg)
+    x = _enter(_embed(params, tokens, cfg), cfg)
     # the documents of packed rows: constants of every layer scan
     packed = _segments(tokens, cfg)
     if packed:
@@ -996,6 +1139,7 @@ def _hidden(params, tokens, cfg: TransformerConfig, each=None):
 
         return jax.lax.scan(loop_step, x, None, length=cfg.loop_steps)[1], None
     x, auxes = run_stacks(x)
+    x = _leave(x, cfg)
     if len(auxes) > 1:  # the expert layers' aux, stack after stack
         return x, jax.tree.map(lambda *a: jnp.concatenate(a), *auxes)
     return x, auxes[0] if auxes else None
@@ -1022,14 +1166,24 @@ def _mtp_hidden(params, x, tokens_next, cfg: TransformerConfig):
     [norm_e(E(t_{i+1})) | norm_h(x_i)] W_eh, E the model's own embedding,
     then one block of the last layer's kind -> (its output, before the
     module's final norm; the block's aux). Scope `mtp_proj`, then the
-    block's own."""
+    block's own. Under several residual streams x is the stack's collapsed
+    output, h' enters streams of the module's own as the embedding does, the
+    block has maps of its own and its output leaves by the sum."""
     mtp, kind = params["mtp"], cfg.mtp_kind
+    z, aux = (_layer_again if kind.layer_remat else _layer)(
+        _enter(_mtp_input(params, x, tokens_next, cfg), kind), mtp["layer"], kind)
+    return _leave(z, kind), aux
+
+
+def _mtp_input(params, x, tokens_next, cfg: TransformerConfig):
+    """What the module's block reads, h' of `_mtp_hidden` (B, S, D); scope
+    `mtp_proj`."""
+    mtp = params["mtp"]
     with jax.named_scope("mtp_proj"):
         e = _rmsnorm(_embed(params, tokens_next, cfg),
                      _scale(mtp["enorm_scale"], cfg), cfg.norm_eps)
         h = _rmsnorm(x, _scale(mtp["hnorm_scale"], cfg), cfg.norm_eps)
-        h = jnp.concatenate([e, h], axis=-1) @ mtp["eh_proj"].astype(cfg.dtype)
-    return (_layer_again if kind.layer_remat else _layer)(h, mtp["layer"], kind)
+        return jnp.concatenate([e, h], axis=-1) @ mtp["eh_proj"].astype(cfg.dtype)
 
 
 def _split_batch(batch, cfg: TransformerConfig):
@@ -1325,6 +1479,10 @@ def gate_zero_shares(params, tokens, cfg: TransformerConfig):
                for kind, _ in cfg.stacks):
         raise ValueError("gate_zero_shares: the configuration has no expert "
                          "layer of relu-gated experts (expert_act 'reglu')")
+    if cfg.streams > 1:
+        raise ValueError("gate_zero_shares walks one residual stream through "
+                         "the layers: no model the repo runs has relu-gated "
+                         "experts under several (streams > 1)")
     shares = []
     x = _embed(params, tokens, cfg)
     for kind, layer in _each_layer(params, cfg):
@@ -1425,6 +1583,77 @@ def record_routing(stats, registry=None) -> None:
             zero.labels(layer).set(float(stats["gate_zero_share"][i]))
         for expert, n in enumerate(row):
             load.labels(layer, expert).set(float(n))
+
+
+def residual_stats(params, tokens, cfg: TransformerConfig):
+    """What the maps of a model of several residual streams (`streams` n >
+    1) do with tokens (B, S), a row a layer and branch in the model's order:
+    jit this beside the step, as `routing_stats`. `layer` and `branch` (1
+    the mixer's, 2 the feed-forward's) say which; `res_diagonal`, the mean
+    over positions and streams of H_res[i, i], how much a stream keeps of
+    itself; `res_sum_error`, the largest |row or column sum - 1| of any
+    position's H_res, what the Sinkhorn passes leave; `pre_mean` and
+    `post_mean`, the mean H_pre and H_post. The layers one after another
+    and not in a scan. With a multi-token-prediction module, tokens (B, S +
+    1), and the module's block is the last rows, `layer` = n_layers."""
+    if cfg.streams == 1:
+        raise ValueError("residual_stats: the configuration has one residual "
+                         "stream (streams 1) and no map to read")
+    if cfg.mtp_depth:
+        tokens, tokens_next = tokens[:, :-1], tokens[:, 1:]
+    rows = []
+
+    def through(x, layer, kind, at):
+        for branch, run in ((1, _mixed), (2, _feed_forward)):
+            if f"hc{branch}_phi" in layer:
+                maps = _read(x, layer, f"hc{branch}", kind)[1]
+                n = kind.streams
+                sums = jnp.stack([jnp.sum(maps.res, axis=0),
+                                  jnp.sum(maps.res, axis=1)])
+                rows.append((at, branch,
+                             jnp.mean(jnp.stack([maps.res[i, i] for i in range(n)])),
+                             jnp.max(jnp.abs(sums - 1.0)),
+                             jnp.mean(maps.pre), jnp.mean(maps.post)))
+            x = run(x, layer, kind)[0]
+        return x
+
+    x = _enter(_embed(params, tokens, cfg), cfg)
+    for at, (kind, layer) in enumerate(_each_layer(params, cfg)):
+        x = through(x, layer, kind, at)
+    if cfg.mtp_depth:
+        kind = cfg.mtp_kind
+        through(_enter(_mtp_input(params, _leave(x, cfg), tokens_next, cfg), kind),
+                params["mtp"]["layer"], kind, cfg.n_layers)
+    names = ("layer", "branch", "res_diagonal", "res_sum_error", "pre_mean",
+             "post_mean")
+    return {name: jnp.stack([jnp.asarray(row[i]) for row in rows])
+            for i, name in enumerate(names)}
+
+
+def record_residual(stats, registry=None) -> None:
+    """`residual_stats`' numbers as gauges of `telemetry.metrics`, a series
+    a layer and branch (`branch` "mixer" or "ffn"):
+    `kungfu_hc_res_diagonal`, `kungfu_hc_res_sum_error`,
+    `kungfu_hc_pre_mean` and `kungfu_hc_post_mean`."""
+    from kungfu_tpu.telemetry import metrics
+
+    reg = registry or metrics.REGISTRY
+    gauges = {
+        name: reg.gauge(f"kungfu_hc_{name}", text + ", of the batch read last",
+                        ("layer", "branch"))
+        for name, text in (
+            ("res_diagonal", "the mean of H_res's diagonal: what a residual "
+             "stream keeps of itself around the branch"),
+            ("res_sum_error", "the largest |row or column sum - 1| of any "
+             "position's H_res: what the Sinkhorn passes leave"),
+            ("pre_mean", "the mean of H_pre, a stream's weight in the "
+             "branch's input"),
+            ("post_mean", "the mean of H_post, the weight of the branch's "
+             "output in a stream"))}
+    for i, layer in enumerate(np.asarray(stats["layer"])):
+        branch = "mixer" if int(stats["branch"][i]) == 1 else "ffn"
+        for name, gauge in gauges.items():
+            gauge.labels(int(layer), branch).set(float(stats[name][i]))
 
 
 def packing_stats(tokens, cfg: TransformerConfig):

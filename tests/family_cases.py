@@ -21,7 +21,7 @@ from jax.sharding import PartitionSpec
 from benchmark import harness, manifest as mf
 from benchmark.families import (glm4_moe_lite, granite_hybrid, keye_vl2,
                                 kimi_linear, laguna, lfm2_moe, nemotron_h, ouro,
-                                qwen3_next, smallthinker)
+                                qwen3_next, smallthinker, xing4_0)
 from kungfu_tpu.models import transformer
 from kungfu_tpu.models.transformer import param_pspecs
 from kungfu_tpu.ops import gated_norm, moe
@@ -522,8 +522,76 @@ KIMI_LINEAR = Family(
     constants=("router_bias",),
     recomputed=((),))  # the cell's layers are run again: against none that are
 
+
+
+def _xing_maps(family, state, key):
+    """Maps in which the static and the dynamic part both weigh: gains that
+    differ a map (the start's are one number), and logits of the streams'
+    own map far apart and on no diagonal (7 x normal in the place of the
+    start's 2 I + normal), so that one Sinkhorn pass, or a softmax over the
+    rows, is far from twenty passes; the module as `_glm_module` leaves it,
+    its block's maps as a layer's."""
+    def mapped(layer, at):
+        layer = {name: leaf * jnp.asarray([3.0, 2.0, 6.0]) if name.endswith("_a")
+                 else leaf for name, leaf in layer.items()}
+        for i, name in enumerate(("hc1_b", "hc2_b")):
+            own = 7.0 * jax.random.normal(
+                jax.random.fold_in(key, 40 + 2 * at + i), layer[name][..., 8:].shape)
+            layer[name] = layer[name].at[..., 8:].set(own)
+        return layer
+
+    state = {**state, "layers": tuple(mapped(stack, s)
+                                      for s, stack in enumerate(state["layers"]))}
+    if "mtp" not in state:
+        return state
+    state = _glm_module(family, state, key)
+    return {**state, "mtp": {**state["mtp"],
+                             "layer": mapped(state["mtp"]["layer"], 10)}}
+
+
+# the cell's stack in small with the module ON (the cell leaves it with a
+# later stage): a dense layer and an expert layer, then the module's expert
+# block, each branch under maps of its own over 4 streams of 64 features, 20
+# Sinkhorn passes; 4 heads of 24 unrotated + 8 rotated q/k features on 16
+# value features, latents of 24 and 16, YaRN by 8 over 64 original positions,
+# so that of the 4 pairs one keeps its frequency, one blends and two take
+# theirs over 8, and the softmax's scale is 1.46 of its own;
+# 16 experts of which numbers 4 to 11 are held, 4 a token; the routers
+# trained, so that every leaf but the bias has a gradient to compare
+XING4_0 = Family(
+    name="xing4_0", cell="xing4_0_29b_a4b.ssgd_mhc_4k_1chip", module=xing4_0,
+    tiny=dict(hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+              num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+              q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=24,
+              qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=8,
+              first_expert_held=4, published={"n_routed_experts": 16},
+              num_nextn_predict_layers=1, vocab_size=256, sequence_length=64,
+              flash_blocks=[32, 32], flash_interpret=True,
+              compute_dtype="float32", routers_trained=True),
+    configured=lambda config: config["rope_scaling"].update(
+        original_max_position_embeddings=64, factor=8),
+    scales={"w_q_down": 6.0, "w_q_up": 6.0, "w_kv_down": 6.0, "w_kv_up": 6.0,
+            "router": 20.0, "router_bias": 40.0, "w_gate": 8.0, "w_up": 8.0,
+            "w_down": 8.0, "shared_gate": 3.0, "shared_up": 3.0,
+            "shared_down": 3.0, "hc1_phi": 8.0, "hc2_phi": 8.0},
+    norms=("ln1_scale", "ln2_scale", "q_latent_norm", "kv_latent_norm"),
+    trained_more=_xing_maps, expert_layers=(1, 2), held_share=(0.3, 0.7),
+    scopes=("hc/hc_maps", "hc/hc_read", "hc/hc_write", "hc_in", "hc_out",
+            "attn/mla_down", "attn/mla_norm", "attn/mla_up", "attn/rope",
+            "attn/attn_latent/attn_core", "moe/moe_router", "moe/moe_shared",
+            "moe/moe_dispatch", "moe_experts/", "moe_combine/", "ffn",
+            "head_loss", "mtp_proj/"),
+    constants=("router_bias",),
+    recomputed=((),))  # the cell's layers are run again: against none that are
+
+# the two layers without the module, for the faults' file: every fault is a
+# program of its own, and the module's block is a third of one
+XING4_0_STACK = dataclasses.replace(XING4_0, tiny={
+    **XING4_0.tiny, "num_nextn_predict_layers": 0}, expert_layers=(1,))
+
 FAMILIES = (LAGUNA, QWEN3_NEXT, GLM_4_7_FLASH, NEMOTRON_H, OURO,
-            GRANITE_HYBRID, LFM2_MOE, KEYE_VL2, SMALLTHINKER, KIMI_LINEAR)
+            GRANITE_HYBRID, LFM2_MOE, KEYE_VL2, SMALLTHINKER, KIMI_LINEAR,
+            XING4_0)
 
 
 # what the held experts get of a family's choices, as the number its routers'
