@@ -1409,7 +1409,12 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     changed against a choice on the scores alone; of a share of the experts
     (`experts_held`) `chunk_rows` (L,), the rows of the chunks that the
     share's loop ran (`ops.moe._live_chunks` times `_share_chunk`, the two
-    that the layer itself asks), of which `held_rows` lie in a group. With a
+    that the layer itself asks), of which `held_rows` lie in a group; where
+    the experts' rows take the grouped matmul's kernels
+    (`ops.grouped_matmul.tiling`, asked as the layer asks) `tile_visits`
+    (L,), the visits the kernels make of row tiles for the groups that came
+    (`ops.grouped_matmul.tile_visits`, a share's chunk by chunk), and `tiles`
+    (L,), the row tiles of the buffers they were handed. With a
     multi-token-prediction module, tokens (B, S + 1): the module reads the
     ids one further on, and where its block has an expert layer that is the
     last row, `layer` = n_layers."""
@@ -1443,14 +1448,34 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     }
     if aux.bias_moved is not None:
         stats["bias_moved"] = aux.bias_moved
-    if held < moe.n_experts:
-        from kungfu_tpu.ops.moe import _live_chunks, _share_chunk
+    from kungfu_tpu.ops import moe as moe_ops
+    from kungfu_tpu.ops.grouped_matmul import tile_visits, tiling
 
-        chunk = _share_chunk(tokens.size, moe.top_k, held, moe.n_experts,
-                             moe.router_bias)
-        stats["chunk_rows"] = chunk * jnp.asarray(
-            [_live_chunks(moe.top_k, chunk, tokens.size, sizes)
+    chunk = choices  # rows a call of the grouped matmul: all, or a share's chunk
+    if held < moe.n_experts:
+        chunk = moe_ops._share_chunk(tokens.size, moe.top_k, held,
+                                     moe.n_experts, moe.router_bias)
+        chunks = jnp.asarray(
+            [moe_ops._live_chunks(moe.top_k, chunk, tokens.size, sizes)
              for sizes in counts], jnp.int32)
+        stats["chunk_rows"] = chunk * chunks
+    fill = moe_ops.GROUPED_WIDTH if moe.expert_act == "relu2" else 1
+    tiles = tiling(chunk, -(-moe.d_model // fill) * fill,
+                   -(-moe.d_ff // fill) * fill, held)
+    if tiles is not None and held == moe.n_experts:
+        stats["tile_visits"] = jnp.sum(tile_visits(counts, tiles.tm), axis=-1)
+        stats["tiles"] = jnp.full(counts.shape[:1], choices // tiles.tm)
+    elif tiles is not None:
+        def visits(sizes, i):  # of chunk i, the groups `_chunk_part` hands on
+            return jnp.sum(tile_visits(moe_ops._chunk_groups(
+                tokens.size, moe.top_k, chunk, sizes, i, moe.router_bias),
+                tiles.tm))
+
+        most = tokens.size * min(moe.top_k, held)
+        stats["tile_visits"] = sum(
+            jnp.where(i < chunks, jnp.stack([visits(sizes, i) for sizes in counts]), 0)
+            for i in range(-(-most // chunk)))
+        stats["tiles"] = chunks * (chunk // tiles.tm)
     return stats
 
 
@@ -1534,7 +1559,11 @@ def record_routing(stats, registry=None) -> None:
     computed here, and their share of all the layer's), of a share of the
     experts `kungfu_moe_chunk_fill_share` (the held rows over the rows of
     the chunks that ran, 1 where none did: the rest are rows of no group
-    that were gathered, weighed and scattered all the same), per expert held
+    that were gathered, weighed and scattered all the same), where the
+    grouped matmul's kernels run `kungfu_moe_tile_visit_share` (the row tiles
+    they visit over the tiles of the buffers they get: 1 where every tile is
+    one group's, more by what the groups' edges cost, a share's fill where
+    its chunk is part full, 0 where no chunk ran), per expert held
     `kungfu_moe_expert_token_choices`, under a selection bias
     `kungfu_moe_bias_moved_token_choices`, and where `stats` holds
     `gate_zero_share` (`gate_zero_shares`' row, put there by the caller)
@@ -1560,6 +1589,10 @@ def record_routing(stats, registry=None) -> None:
     fill = reg.gauge("kungfu_moe_chunk_fill_share",
                      "held rows over the rows of the share's chunks that ran",
                      ("layer",)) if "chunk_rows" in stats else None
+    visit = reg.gauge("kungfu_moe_tile_visit_share",
+                      "row tiles the grouped matmul's kernels visit over the "
+                      "tiles of their buffers",
+                      ("layer",)) if "tile_visits" in stats else None
     moved = reg.gauge("kungfu_moe_bias_moved_token_choices",
                       "token-choices the router's selection bias changed",
                       ("layer",)) if "bias_moved" in stats else None
@@ -1577,6 +1610,10 @@ def record_routing(stats, registry=None) -> None:
             ran = float(stats["chunk_rows"][i])
             fill.labels(layer).set(float(stats["held_rows"][i]) / ran
                                    if ran else 1.0)
+        if visit is not None:
+            tiles = float(stats["tiles"][i])
+            visit.labels(layer).set(float(stats["tile_visits"][i]) / tiles
+                                    if tiles else 0.0)
         if moved is not None:
             moved.labels(layer).set(float(stats["bias_moved"][i]))
         if zero is not None:

@@ -8,10 +8,11 @@ SURVEY §2.4). `moe_ffn` is one function for both layouts:
   `models/transformer.py`): nothing crosses a wire, so nothing needs a
   capacity. The T x top_k token-choices are ordered by expert, the group
   sizes counted, the experts run over groups of the sizes that came
-  (`jax.lax.ragged_dot`, which the TPU compiler lowers to a grouped-matmul
-  kernel), and the results go back by the inverse order, weighted by their
-  gates. Every token-choice is computed whatever the load: no drops, no
-  dense pass over all experts. Told which experts it holds (`held`, one
+  (`ops/grouped_matmul.py`: the repo's Pallas kernels where the shape tiles
+  and the program is the TPU's, `jax.lax.ragged_dot` anywhere else), and the
+  results go back by the inverse order, weighted by their gates. Every
+  token-choice is computed whatever the load: no drops, no dense pass over
+  all experts. Told which experts it holds (`held`, one
   chip's share of a layer whose experts lie on several chips), the shard
   routes over all of the router's experts and computes the part of the
   result that its own give, as dropless; it exchanges nothing.
@@ -49,6 +50,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from kungfu_tpu.ops.grouped_matmul import grouped_matmul
 
 
 class MoeAux(NamedTuple):
@@ -101,8 +104,8 @@ def gelu_experts(rows, experts, group_sizes):
     """Two-matrix gelu experts: experts = (w_in (e, D, F), w_out (e, F, D));
     rows (N, D) ordered by expert in groups of `group_sizes` (e,)."""
     w_in, w_out = experts
-    h = jax.nn.gelu(lax.ragged_dot(rows, w_in.astype(rows.dtype), group_sizes))
-    return lax.ragged_dot(h, w_out.astype(rows.dtype), group_sizes)
+    h = jax.nn.gelu(grouped_matmul(rows, w_in.astype(rows.dtype), group_sizes))
+    return grouped_matmul(h, w_out.astype(rows.dtype), group_sizes)
 
 
 @functools.partial(jax.checkpoint, prevent_cse=False)
@@ -110,15 +113,15 @@ def _silu_gate_down(gate, up, w_down, group_sizes):
     """(silu(gate) * up) @ w_down over the groups. Keeps gate, up and
     w_down; the silu, the product and with them the matmul's operand are
     recomputed (PERF.md, PR 25's rule)."""
-    return lax.ragged_dot(jax.nn.silu(gate) * up, w_down, group_sizes)
+    return grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
 
 
 def swiglu_experts(rows, experts, group_sizes):
     """Three-matrix gated-silu experts: experts = (w_gate (e, D, F), w_up
     (e, D, F), w_down (e, F, D)); y = w_down (silu(w_gate x) * w_up x)."""
     w_gate, w_up, w_down = (w.astype(rows.dtype) for w in experts)
-    gate = lax.ragged_dot(rows, w_gate, group_sizes)
-    up = lax.ragged_dot(rows, w_up, group_sizes)
+    gate = grouped_matmul(rows, w_gate, group_sizes)
+    up = grouped_matmul(rows, w_up, group_sizes)
     return _silu_gate_down(gate, up, w_down, group_sizes)
 
 
@@ -126,7 +129,7 @@ def swiglu_experts(rows, experts, group_sizes):
 def _relu2_down(up, w_down, group_sizes):
     """relu(up)^2 @ w_down over the groups. Keeps up and w_down; the square,
     the matmul's operand, is recomputed, as `_silu_gate_down`'s is."""
-    return lax.ragged_dot(jnp.square(jax.nn.relu(up)), w_down, group_sizes)
+    return grouped_matmul(jnp.square(jax.nn.relu(up)), w_down, group_sizes)
 
 
 GROUPED_WIDTH = 512  # `relu2_experts` feeds the grouped matmul multiples of it
@@ -154,7 +157,7 @@ def relu2_experts(rows, experts, group_sizes):
     D = rows.shape[-1]
     w_up, w_down = (_to_width(_to_width(w.astype(rows.dtype), 1), 2)
                     for w in experts)
-    up = lax.ragged_dot(_to_width(rows, 1), w_up, group_sizes)
+    up = grouped_matmul(_to_width(rows, 1), w_up, group_sizes)
     return _relu2_down(up, w_down, group_sizes)[:, :D]
 
 
@@ -163,7 +166,7 @@ def _relu_gate_down(gate, up, w_down, group_sizes):
     """(relu(gate) * up) @ w_down over the groups. Keeps gate, up and w_down;
     the relu, the product and with them the matmul's operand are recomputed,
     as `_silu_gate_down`'s are."""
-    return lax.ragged_dot(jax.nn.relu(gate) * up, w_down, group_sizes)
+    return grouped_matmul(jax.nn.relu(gate) * up, w_down, group_sizes)
 
 
 def reglu_experts(rows, experts, group_sizes):
@@ -173,8 +176,8 @@ def reglu_experts(rows, experts, group_sizes):
     place: where the gate's product is not positive the row of w_down is
     multiplied by an exact zero (`transformer.gate_zero_shares` counts them)."""
     w_gate, w_up, w_down = (w.astype(rows.dtype) for w in experts)
-    gate = lax.ragged_dot(rows, w_gate, group_sizes)
-    up = lax.ragged_dot(rows, w_up, group_sizes)
+    gate = grouped_matmul(rows, w_gate, group_sizes)
+    up = grouped_matmul(rows, w_up, group_sizes)
     return _relu_gate_down(gate, up, w_down, group_sizes)
 
 
@@ -325,6 +328,24 @@ def _aux(logits, probs, counts, chosen, biased: bool = False) -> MoeAux:
                   bias_moved(probs, chosen) if biased else None)
 
 
+def _chunk_groups(T: int, top_k: int, chunk: int, sizes, i, whole: bool):
+    """The groups of rows i * chunk to (i + 1) * chunk of a share's row
+    order: the part of each held expert's group (`sizes` of them) that lies
+    in the chunk, and in a chunk that runs `whole` the rows of no group as
+    the last group's, up to the chunk's end."""
+    ends = jnp.cumsum(sizes)
+    lo = i * chunk
+    here = (jnp.clip(ends, lo, lo + chunk)
+            - jnp.clip(ends - sizes, lo, lo + chunk))
+    if whole and chunk >= T * min(top_k, sizes.shape[0]):
+        # one chunk of all that can fall here starts at row 0 and ends past
+        # every group: the same number with nothing to clip
+        here = here.at[-1].add(chunk - ends[-1])
+    elif whole:
+        here = here.at[-1].add(lo + chunk - jnp.clip(ends[-1], lo, lo + chunk))
+    return here
+
+
 def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
                 sizes, i, whole: bool = False):
     """What rows i * chunk to (i + 1) * chunk of a share's row order add to
@@ -338,20 +359,11 @@ def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
     under a selection bias) those rows are the last group's, up to the
     chunk's end, and the chunk costs its buffer whatever came."""
     T, D = x.shape
-    ends = jnp.cumsum(sizes)
     lo = i * chunk
     mine = lax.dynamic_slice(order, (lo,), (chunk,))
     token = mine // top_k  # token-choice c = t * top_k + j reads token t
-    live = (lo + jnp.arange(chunk) < ends[-1])[:, None]
-    # the part of each expert's group that lies in this chunk
-    here = (jnp.clip(ends, lo, lo + chunk)
-            - jnp.clip(ends - sizes, lo, lo + chunk))
-    if whole and chunk >= T * min(top_k, sizes.shape[0]):
-        # one chunk of all that can fall here starts at row 0 and ends past
-        # every group: the same number with nothing to clip
-        here = here.at[-1].add(chunk - ends[-1])
-    elif whole:
-        here = here.at[-1].add(lo + chunk - jnp.clip(ends[-1], lo, lo + chunk))
+    live = (lo + jnp.arange(chunk) < jnp.sum(sizes))[:, None]
+    here = _chunk_groups(T, top_k, chunk, sizes, i, whole)
     with jax.named_scope("moe_dispatch"):
         rows = jnp.where(live, x[token], 0)
     with jax.named_scope("moe_experts"):

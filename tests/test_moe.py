@@ -785,7 +785,8 @@ def test_under_a_selection_bias_a_live_chunk_costs_its_buffer(biased):
 
 
 def test_relu2_experts_feed_the_grouped_matmul_whole_widths(monkeypatch):
-    """D and F go into `lax.ragged_dot` filled with zeros to multiples of
+    """D and F go into the grouped matmul (`lax.ragged_dot` at these rows,
+    `ops/grouped_matmul.py`'s rule) filled with zeros to multiples of
     `GROUPED_WIDTH` (3,072 and 2,048 for Nemotron-3-Nano's 2,688 and 1,856),
     and the result is the unfilled one's to the last bit."""
     from kungfu_tpu.ops import moe

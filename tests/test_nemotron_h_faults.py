@@ -29,7 +29,7 @@ def _no_softplus(m):
 def _expert_function(m, act):
     """`act(up)` in the place of relu(up)^2, in the routed experts and the
     shared expert alike."""
-    m.setattr(moe, "_relu2_down", lambda up, w_down, sizes: jax.lax.ragged_dot(
+    m.setattr(moe, "_relu2_down", lambda up, w_down, sizes: moe.grouped_matmul(
         act(up), w_down, sizes))
     m.setattr(transformer, "_relu2_out", lambda up, w_down: act(up) @ w_down)
 
