@@ -938,6 +938,8 @@ _SPAN_INDIRECT = frozenset({
     # telemetry.device's compile watch names a stage's span by the stage
     "device_plane.compile.trace",
     "device_plane.compile.lower",
+    # ops.kernel_call names its span once, for its readers too (`SPAN`)
+    "device_plane.compile.kernel",
 })
 
 _SPAN_TABLE_HEADING = "## Span table"

@@ -74,6 +74,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from kungfu_tpu.ops.kernel_call import kernel_call
+
 NEG_INF = -1e30
 
 
@@ -580,18 +582,17 @@ def _kv_grid(rows: int, S, blk_q, blk_k, causal, window):
 
 
 def _sweep_call(kernel, grid, prefetched, **call):
-    """`pl.pallas_call(kernel, grid=grid, **call)`; where anything is
+    """`kernel_call(kernel, grid=grid, **call)`; where anything is
     `prefetched` (the table of live pairs, a packed sequence's bounds), the
     call with those as its first operands, in scalar memory before the grid
     runs: the kernel's first refs and every index map's last arguments."""
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if not prefetched:
-        return pl.pallas_call(kernel, grid=grid, **call)
+        return kernel_call(kernel, grid=grid, **call)
     specs = {name: call.pop(name)
              for name in ("in_specs", "out_specs", "scratch_shapes")}
-    return functools.partial(pl.pallas_call(
+    return functools.partial(kernel_call(
         kernel, grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetched), grid=grid, **specs), **call),
         *prefetched)

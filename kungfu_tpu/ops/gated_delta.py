@@ -78,6 +78,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kungfu_tpu.ops.kernel_call import kernel_call
+
 CHUNK = 64
 BLOCK_CHUNKS = 8  # chunks a grid step of the two passes' kernels: 512 positions
 BLOCK_HEADS = 4  # heads a grid step: their chains are independent
@@ -206,7 +208,7 @@ def _substitution(a, *, interpret: bool):
     C, _, n = a.shape
     lanes = SOLVE_LANES if n % SOLVE_LANES == 0 else n
     block = pl.BlockSpec((C, C, lanes), lambda s: (0, 0, s))
-    return pl.pallas_call(
+    return kernel_call(
         _substitution_kernel, grid=(n // lanes,), in_specs=[block],
         out_specs=block, out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -438,7 +440,7 @@ def _forward(q, k, v, g, beta, T, *, chunk: int, interpret: bool):
     dv, N = v.shape[-1], S // chunk
     grid, spec = _specs(B, H, S, dk, dv, chunk, back=False)
     rows = (B, H, N, chunk)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_forward_kernel, chunk=chunk),
         grid=grid,
         in_specs=[spec["qk"], spec["qk"], spec["v"], spec["row"], spec["row"],
@@ -461,7 +463,7 @@ def _backward(q, k, v, g, beta, T, states, do, *, chunk: int, interpret: bool):
     shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)]
     shapes += [jax.ShapeDtypeStruct(rows, jnp.float32)] * 2
     shapes += [jax.ShapeDtypeStruct(T.shape, jnp.float32)]
-    *d, dg, dbeta, dT = pl.pallas_call(
+    *d, dg, dbeta, dT = kernel_call(
         functools.partial(_backward_kernel, chunk=chunk),
         grid=grid,
         in_specs=[spec["qk"], spec["qk"], spec["v"], spec["row"], spec["row"],

@@ -87,6 +87,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kungfu_tpu.ops.gated_delta import VMEM_LIMIT, _on_platform
+from kungfu_tpu.ops.kernel_call import kernel_call
 
 BLOCK_BYTES = 24 << 20  # of VMEM for a grid step's blocks, double-buffered
 CHUNK_ROWS = 16  # of a block at a time in the kernels' loops: a bfloat16 tile's
@@ -220,7 +221,7 @@ def _a_feature(d, scale, P: int):
 def _forward(o, x, z, d, scale, *, groups: int, eps: float, interpret: bool):
     B, H, S, P = o.shape
     grid, spec = _specs(o, x, passes=(1, 3))
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_forward_kernel, groups=groups, eps=eps),
         grid=grid,
         in_specs=[spec["heads"], spec["rows"], spec["rows"], spec["feature"],
@@ -245,7 +246,7 @@ def _backward(o, x, z, d, scale, dy, *, groups: int, eps: float,
     rows = [jax.ShapeDtypeStruct((B, S, inner), t.dtype) for t in (x, z)]
     sums = jax.ShapeDtypeStruct((B, 8, inner), jnp.float32)
     d_feature, scale_feature = _a_feature(d, scale, P)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_backward_kernel, groups=groups, eps=eps),
         grid=grid,
         in_specs=[spec["heads"], spec["rows"], spec["rows"], spec["feature"],

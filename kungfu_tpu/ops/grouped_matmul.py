@@ -62,6 +62,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kungfu_tpu.ops.kernel_call import kernel_call
+
 SUB = 128  # rows of a straddling tile's sub-block
 VMEM_LIMIT = 100 << 20  # of the chip's 128 MiB; the default scope is 16
 # what a grid step's blocks may take of it by `tiling`'s account. Measured
@@ -292,7 +294,7 @@ def _gmm(rows, weights, group_sizes, *, tm: int, tn: int,
         w_spec = pl.BlockSpec((None, tn, K), lambda n, at, g, t, *_: (g[at], n, 0))
     else:
         w_spec = pl.BlockSpec((None, K, tn), lambda n, at, g, t, *_: (g[at], 0, n))
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_gmm_kernel, tm=tm, transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(M // tn, count),
@@ -321,7 +323,7 @@ def _tgmm(rows, dy, group_sizes, *, groups: int, tm: int, tk: int, tn: int,
     N, K = rows.shape
     M = dy.shape[1]
     scalars, count = _visits(group_sizes, tm=tm, n_tiles=N // tm, empty=True)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_tgmm_kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(K // tk, M // tn, count),

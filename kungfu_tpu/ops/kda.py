@@ -80,6 +80,7 @@ from kungfu_tpu.ops.gated_delta import (_NT, _PARAMS, _TN, VMEM_LIMIT, _dot,
                                         _on_platform, _rows, _to_column,
                                         _to_row, _unit_lower_inverse)
 from kungfu_tpu.ops.gated_delta import _specs as _gd_specs
+from kungfu_tpu.ops.kernel_call import kernel_call
 
 CHUNK = 64
 SUB = 16  # positions a sub-block of a chunk's pairs
@@ -372,7 +373,7 @@ def _pairs_call(q, k, g, *, chunk: int, interpret: bool):
     B, H, S, dk = q.shape
     grid, spec = _specs(B, H, S, dk, dk, chunk, back=False)
     shape = (B, H, S // chunk, chunk, chunk)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_pairs_kernel, chunk=chunk), grid=grid,
         in_specs=[spec["qk"]] * 3, out_specs=[spec["T"]] * 2 + [spec["qk"]],
         out_shape=[jax.ShapeDtypeStruct(shape, jnp.float32),
@@ -387,7 +388,7 @@ def _pairs_back_call(q, k, G, dA, dP, dG, *, chunk: int, interpret: bool):
     the log decays receive)."""
     B, H, S, dk = q.shape
     grid, spec = _specs(B, H, S, dk, dk, chunk, back=False)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_pairs_back_kernel, chunk=chunk), grid=grid,
         in_specs=[spec["qk"]] * 3 + [spec["T"]] * 2 + [spec["qk"]],
         out_specs=[spec["qk"]] * 3,
@@ -405,7 +406,7 @@ def _forward(q, k, v, G, beta, T, P, *, chunk: int, interpret: bool):
     B, H, S, dk = q.shape
     dv, N = v.shape[-1], S // chunk
     grid, spec = _specs(B, H, S, dk, dv, chunk, back=False)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_forward_kernel, chunk=chunk), grid=grid,
         in_specs=[spec["qk"], spec["qk"], spec["v"], spec["qk"], spec["row"],
                   spec["T"], spec["T"]],
@@ -429,7 +430,7 @@ def _backward(q, k, v, G, beta, T, P, states, do, *, chunk: int,
     shapes += [jax.ShapeDtypeStruct(G.shape, jnp.float32),
                jax.ShapeDtypeStruct(rows, jnp.float32)]
     shapes += [jax.ShapeDtypeStruct(T.shape, jnp.float32)] * 2
-    *d, dbeta, dT, dP = pl.pallas_call(
+    *d, dbeta, dT, dP = kernel_call(
         functools.partial(_backward_kernel, chunk=chunk), grid=grid,
         in_specs=[spec["qk"], spec["qk"], spec["v"], spec["qk"], spec["row"],
                   spec["T"], spec["T"], spec["state"], spec["v"]],

@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kungfu_tpu.ops.kernel_call import kernel_call
+
 BLOCK_ROWS = 512
 BLOCK_BYTES = 1 << 20  # of t a block: in and out, double-buffered, 4 MB of VMEM
 CHUNK_ROWS = 64  # of a block at a time in the kernel's loop
@@ -95,7 +97,7 @@ def rotate(t, cos, sin, *, half: int, into_heads: bool, interpret: bool = False)
     across = pl.BlockSpec((1, rows, heads * hd), lambda b, s, h: (b, s, h))
     apart = pl.BlockSpec((1, heads, rows, hd), lambda b, s, h: (b, h, s, 0))
     table = pl.BlockSpec((rows, hd), lambda b, s, h: (s, 0))
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_kernel, heads=heads, hd=hd, half=half,
                           into_heads=into_heads),
         grid=(B, S // rows, H // heads),  # heads last: the tables stay

@@ -82,6 +82,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from kungfu_tpu.ops.gated_delta import (VMEM_LIMIT, _on_platform,
                                         _same_document, _taps_over)
+from kungfu_tpu.ops.kernel_call import kernel_call
 
 BLOCK_BYTES = 24 << 20  # of VMEM for a grid step's blocks, double-buffered
 HALO = 16  # rows of a neighbouring block a grid step reads: a bfloat16 tile's
@@ -270,7 +271,7 @@ def _forward(bcx, taps, *, interpret: bool):
     B, S, wide = bcx.shape
     K, D = taps.shape
     grid, spec = _specs(bcx, passes=4)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_forward_kernel, K=K),
         grid=grid,
         in_specs=[spec["wide"], spec["before"],
@@ -291,7 +292,7 @@ def _backward(bcx, taps, dy, *, interpret: bool):
     B, S, wide = bcx.shape
     K, D = taps.shape
     grid, spec = _specs(bcx, passes=7)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_backward_kernel, K=K),
         grid=grid,
         in_specs=[spec["wide"], spec["before"], spec["behind"], spec["rows"],

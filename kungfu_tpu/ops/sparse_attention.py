@@ -97,6 +97,7 @@ from jax.ad_checkpoint import checkpoint_name
 from kungfu_tpu.ops.flash_attention import (NEG_INF, _across, _blocks,
                                             _live_pairs, _nt, _row, _sweep_call)
 from kungfu_tpu.ops.gated_delta import VMEM_LIMIT
+from kungfu_tpu.ops.kernel_call import kernel_call
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -486,7 +487,7 @@ def select(I, k: int, interpret: bool = False):
         return plain_select(I, k)
     span = SELECT_SPAN if S % SELECT_SPAN == 0 else LANES
     block = pl.BlockSpec((1, rows, S), lambda b, r: (b, r, 0))
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_select_kernel, k=k, span=span),
         grid=(B, S // rows), out_shape=jax.ShapeDtypeStruct((B, S, S), jnp.int8),
         in_specs=[block], out_specs=block,

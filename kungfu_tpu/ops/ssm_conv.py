@@ -115,6 +115,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from kungfu_tpu.ops import gated_delta
 from kungfu_tpu.ops.gated_delta import VMEM_LIMIT, _on_platform
+from kungfu_tpu.ops.kernel_call import kernel_call
 from kungfu_tpu.ops.short_conv import (HALO, ROWS, TILE, _block_rows,
                                        _halo_maps, _moved, _shifted, _split,
                                        _taps_times)
@@ -432,7 +433,7 @@ def _forward(zxbc, taps, bias, delta, marks, *, interpret: bool):
     f32 = jnp.float32
     grid, spec = _specs(zxbc, taps, delta, passes=(2, 1))
     packed = () if marks is None else (marks,)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_forward_kernel, K=K),
         grid=grid,
         in_specs=[spec["x"], spec["bc"], spec["x_before"], spec["bc_before"],
@@ -461,7 +462,7 @@ def _backward(zxbc, taps, bias, delta, marks, dxbc, dv, *, interpret: bool):
     f32 = jnp.float32
     grid, spec = _specs(zxbc, taps, delta, passes=(3, 1), up=True)
     packed = () if marks is None else (marks,)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_backward_kernel, K=K),
         grid=grid,
         in_specs=[spec["x"], spec["bc"], spec["x_before"], spec["bc_before"],

@@ -61,6 +61,7 @@ from jax.experimental.pallas import tpu as pltpu
 from kungfu_tpu.ops.gated_delta import (_NT, _PARAMS, _TN, BLOCK_HEADS,
                                         _block_chunks, _dot, _on_platform,
                                         _rows, _to_column, _to_row)
+from kungfu_tpu.ops.kernel_call import kernel_call
 
 CHUNK = 128  # Mamba-2's published chunk_size; the result does not depend on it
 
@@ -273,7 +274,7 @@ def _forward(q, k, v, g, *marks, chunk: int, interpret: bool):
     B, groups, S, N = q.shape
     H, P = v.shape[1], v.shape[-1]
     grid, spec = _specs(B, H, groups, S, N, P, chunk, back=False)
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_forward_kernel, chunk=chunk),
         grid=grid,
         in_specs=[spec["group"], spec["group"], spec["v"], spec["row"]]
@@ -294,7 +295,7 @@ def _backward(q, k, v, g, states, do, *marks, chunk: int, interpret: bool):
     grid, spec = _specs(B, H, groups, S, N, P, chunk, back=True)
     rows = (B, H, S // chunk, chunk)
     sums = jax.ShapeDtypeStruct((B, grid[1], S, N), jnp.float32)
-    dq, dk, dv, dg = pl.pallas_call(
+    dq, dk, dv, dg = kernel_call(
         functools.partial(_backward_kernel, chunk=chunk),
         grid=grid,
         in_specs=[spec["group"], spec["group"], spec["v"], spec["row"],

@@ -85,6 +85,7 @@ _STAGES = {
 _CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 CACHE_SAID = ("hit", "miss", "off")  # the `cache` of a compile request
+OWN_ROWS = 8  # rows of `own` on an outermost trace or lowering's span
 
 
 class _CompileWatch(threading.local):
@@ -100,14 +101,23 @@ class _CompileWatch(threading.local):
     `wrote_as_peer()`: `written: "peer"` on the request's span and
     `kungfu_compile_cache_peer_writes_total`). Tracing and lowering nest,
     in themselves and in each other (2,471 trace events in ResNet's first
-    step, PERF.md; a lowering rule may trace), so only the outermost of a
-    thread is a span, with the count of those folded into it, or one model
-    floods the ring. The counters take every event, as JAX sums them. The
-    listeners run inside JAX's compile path: they raise nothing."""
+    step, 14,697 in the Kimi-Linear cell's, PERF.md; a lowering rule may
+    trace), so only the outermost of a thread is a span, or one model floods
+    the ring. What ended inside it is folded into it by name: every event's
+    **own** seconds (its duration less that of the events and compile
+    requests inside it), summed by (`fun_name`, stage), and the span leaves
+    with `events`, how many there were with itself, and `own`, the
+    `OWN_ROWS` largest of those sums. `events` is the program's alone, the
+    same on any host; the seconds are the host's. The counters take own
+    seconds too, so the stages sum to the wall. The listeners run inside
+    JAX's compile path: they raise nothing."""
 
     def __init__(self):
-        self.depth = 0  # trace and lower events this thread is inside
-        self.nested = 0  # those that ended inside the outermost one
+        # the seconds of what ended inside each trace and lower event this
+        # thread is inside, the outermost first
+        self.inside: List[float] = []
+        # {(fun_name, stage): [own seconds, events]} since the outermost began
+        self.own: Dict[Tuple[str, str], list] = {}
         self.cache = "off"
         self.written = ""  # "peer" where this process wrote the entry as one
         requests = metrics.counter(
@@ -115,7 +125,8 @@ class _CompileWatch(threading.local):
             "Compile requests by the persistent cache's answer", ("cache",))
         seconds = metrics.counter(
             "kungfu_compile_seconds_total",
-            "Seconds in JAX's compile stages, nested events too", ("stage",))
+            "Seconds in JAX's compile stages, each event's own: the stages "
+            "sum to the wall", ("stage",))
         # every series from the start: a request count of 0 is information
         self.requests = {c: requests.labels(c) for c in CACHE_SAID}
         self.seconds = {s: seconds.labels(s) for s in _STAGES.values()}
@@ -129,7 +140,7 @@ class _CompileWatch(threading.local):
         if stage == "backend":
             self.cache, self.written = "off", ""
         elif stage is not None:
-            self.depth += 1
+            self.inside.append(0.0)
 
     def cache_said(self, event: str, **kw) -> None:
         if event == _CACHE_ASKED:
@@ -143,21 +154,32 @@ class _CompileWatch(threading.local):
         if stage is None:
             return
         took = max(0.0, end - start)  # the wall clock may step
-        self.seconds[stage].inc(took)
         if stage == "backend":
+            self.seconds[stage].inc(took)
             self.requests[self.cache].inc()
+            if self.inside:  # an eager op while tracing: not the trace's own
+                self.inside[-1] += took
             written = {"written": self.written} if self.written else {}
             tracing.record("device_plane.compile.backend", took,
                            fun_name=fun_name, cache=self.cache, **written)
             return
         # an exit with no entry: the watch began inside it
-        self.depth = max(0, self.depth - 1)
-        if self.depth:
-            self.nested += 1
+        own = max(0.0, took - (self.inside.pop() if self.inside else 0.0))
+        self.seconds[stage].inc(own)
+        row = self.own.setdefault((fun_name, stage), [0.0, 0])
+        row[0] += own
+        row[1] += 1
+        if self.inside:
+            self.inside[-1] += took
             return
-        tracing.record("device_plane.compile." + stage, took,
-                       fun_name=fun_name, nested=self.nested)
-        self.nested = 0
+        rows, self.own = self.own, {}
+        events = sum(count for _, count in rows.values())
+        largest = sorted(rows.items(), key=lambda row: -row[1][0])[:OWN_ROWS]
+        tracing.record(
+            "device_plane.compile." + stage, took, fun_name=fun_name,
+            nested=events - 1, events=events,
+            own=[[name, of, round(seconds, 6), count]
+                 for (name, of), (seconds, count) in largest])
 
 
 _compile_watch = None
