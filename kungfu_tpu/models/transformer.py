@@ -1409,7 +1409,11 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     changed against a choice on the scores alone; of a share of the experts
     (`experts_held`) `chunk_rows` (L,), the rows of the chunks that the
     share's loop ran (`ops.moe._live_chunks` times `_share_chunk`, the two
-    that the layer itself asks), of which `held_rows` lie in a group; where
+    that the layer itself asks), of which `held_rows` lie in a group, and
+    `rows_moved` (L,), those of them that the dispatch and the combine move
+    (`ops.row_moves.rows_visited` a chunk where the rows' kernels run,
+    `tiling` asked as the layer asks: the live rows rounded up to a row
+    tile; every row under a selection bias and where XLA's forms stand); where
     the experts' rows take the grouped matmul's kernels
     (`ops.grouped_matmul.tiling`, asked as the layer asks) `tile_visits`
     (L,), the visits the kernels make of row tiles for the groups that came
@@ -1448,7 +1452,7 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
     }
     if aux.bias_moved is not None:
         stats["bias_moved"] = aux.bias_moved
-    from kungfu_tpu.ops import moe as moe_ops
+    from kungfu_tpu.ops import moe as moe_ops, row_moves
     from kungfu_tpu.ops.grouped_matmul import tile_visits, tiling
 
     chunk = choices  # rows a call of the grouped matmul: all, or a share's chunk
@@ -1459,6 +1463,14 @@ def routing_stats(params, tokens, cfg: TransformerConfig):
             [moe_ops._live_chunks(moe.top_k, chunk, tokens.size, sizes)
              for sizes in counts], jnp.int32)
         stats["chunk_rows"] = chunk * chunks
+        # of those rows the ones the dispatch and the combine move: the rows
+        # of the tiles their kernels visit, every row where XLA's forms stand
+        moves = None if moe.router_bias else row_moves.tiling(
+            chunk, moe.d_model, tokens.size)
+        stats["rows_moved"] = stats["chunk_rows"] if moves is None else sum(
+            jnp.where(i < chunks, row_moves.rows_visited(jnp.clip(
+                stats["held_rows"] - i * chunk, 0, chunk), moves.tm), 0)
+            for i in range(-(-tokens.size * min(moe.top_k, held) // chunk)))
     fill = moe_ops.GROUPED_WIDTH if moe.expert_act == "relu2" else 1
     tiles = tiling(chunk, -(-moe.d_model // fill) * fill,
                    -(-moe.d_ff // fill) * fill, held)
@@ -1559,7 +1571,11 @@ def record_routing(stats, registry=None) -> None:
     computed here, and their share of all the layer's), of a share of the
     experts `kungfu_moe_chunk_fill_share` (the held rows over the rows of
     the chunks that ran, 1 where none did: the rest are rows of no group
-    that were gathered, weighed and scattered all the same), where the
+    that were gathered, weighed and scattered all the same) and
+    `kungfu_moe_rows_moved_share` (the rows that the dispatch and the combine
+    move over the same rows: the fill rounded up to a row tile where
+    `ops/row_moves.py`'s kernels run, 1 under a selection bias, wherever XLA's
+    gather and scatter-add stand and where no chunk ran), where the
     grouped matmul's kernels run `kungfu_moe_tile_visit_share` (the row tiles
     they visit over the tiles of the buffers they get: 1 where every tile is
     one group's, more by what the groups' edges cost, a share's fill where
@@ -1589,6 +1605,10 @@ def record_routing(stats, registry=None) -> None:
     fill = reg.gauge("kungfu_moe_chunk_fill_share",
                      "held rows over the rows of the share's chunks that ran",
                      ("layer",)) if "chunk_rows" in stats else None
+    moved_rows = reg.gauge("kungfu_moe_rows_moved_share",
+                           "rows the share's dispatch and combine move over "
+                           "the rows of its chunks that ran",
+                           ("layer",)) if "rows_moved" in stats else None
     visit = reg.gauge("kungfu_moe_tile_visit_share",
                       "row tiles the grouped matmul's kernels visit over the "
                       "tiles of their buffers",
@@ -1610,6 +1630,10 @@ def record_routing(stats, registry=None) -> None:
             ran = float(stats["chunk_rows"][i])
             fill.labels(layer).set(float(stats["held_rows"][i]) / ran
                                    if ran else 1.0)
+        if moved_rows is not None:
+            ran = float(stats["chunk_rows"][i])
+            moved_rows.labels(layer).set(float(stats["rows_moved"][i]) / ran
+                                         if ran else 1.0)
         if visit is not None:
             tiles = float(stats["tiles"][i])
             visit.labels(layer).set(float(stats["tile_visits"][i]) / tiles

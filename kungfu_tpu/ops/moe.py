@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from kungfu_tpu.ops.grouped_matmul import grouped_matmul
+from kungfu_tpu.ops.row_moves import add_rows, take_rows
 
 
 class MoeAux(NamedTuple):
@@ -350,18 +351,30 @@ def _chunk_part(expert_fn, top_k: int, chunk: int, x, gate, experts, order,
                 sizes, i, whole: bool = False):
     """What rows i * chunk to (i + 1) * chunk of a share's row order add to
     the layer: (T, D) float32. Rows past the last group are token-choices
-    that fell elsewhere: they go in as zeros and weigh nothing, in both
-    passes (the grouped matmul leaves whatever it finds in a row of no
-    group). A row is weighed by its gate where it lies. The grouped matmul
-    costs what its groups hold (5.8 to 16.6 ms a step with the rows that
-    came: PERF.md, PR 41), so the groups are the rows that came and the
-    chunk costs them; but in a chunk that runs `whole` (`moe_ffn`: a share
-    under a selection bias) those rows are the last group's, up to the
-    chunk's end, and the chunk costs its buffer whatever came."""
+    that fell elsewhere: they weigh nothing, in both passes (the grouped
+    matmul leaves whatever it finds in a row of no group). A row is weighed
+    by its gate where it lies. The grouped matmul costs what its groups hold
+    (5.8 to 16.6 ms a step with the rows that came: PERF.md, PR 41), so the
+    groups are the rows that came, and the rows are taken and added back as
+    far as the last that came (`ops/row_moves.py`: the movement ends at its
+    last live row tile, as the products between do) and the chunk costs
+    them; but in a chunk that runs `whole` (`moe_ffn`: a share under a
+    selection bias) the rows of no group go in as zeros and are the last
+    group's, up to the chunk's end, XLA's gather and scatter-add move every
+    row, and the chunk costs its buffer whatever came."""
     T, D = x.shape
     lo = i * chunk
     mine = lax.dynamic_slice(order, (lo,), (chunk,))
     token = mine // top_k  # token-choice c = t * top_k + j reads token t
+    if not whole:
+        live = jnp.clip(jnp.sum(sizes) - lo, 0, chunk)
+        here = _chunk_groups(T, top_k, chunk, sizes, i, whole)
+        with jax.named_scope("moe_dispatch"):
+            rows = take_rows(x, token, live)
+        with jax.named_scope("moe_experts"):
+            y = expert_fn(rows, experts, here)
+        with jax.named_scope("moe_combine"):
+            return add_rows(y, token, live, T, gate.reshape(T * top_k)[mine])
     live = (lo + jnp.arange(chunk) < jnp.sum(sizes))[:, None]
     here = _chunk_groups(T, top_k, chunk, sizes, i, whole)
     with jax.named_scope("moe_dispatch"):
@@ -505,7 +518,9 @@ def moe_ffn(x, router_w, experts, axis_name: str = None, axis_size: int = 1,
     sees the chosen experts' scores. A share's (`held`) work is its live
     rows' unless a bias says otherwise: without one the grouped matmuls
     get the groups that came, in chunks of four balanced loads and of half
-    of what can fall here at the most, as many as the rows fill. Under a
+    of what can fall here at the most, as many as the rows fill, and the
+    rows are taken and added back as far as the last that came
+    (`ops/row_moves.py`). Under a
     bias every chunk the rows reach is computed whole, the rows of no group
     as zeros in the last group, and a share of an eighth or more is one
     chunk of all that can fall here, run whatever came: its cost is its

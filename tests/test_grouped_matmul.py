@@ -3,7 +3,6 @@ and its transposes, the rule that chooses the path from the shape, and the
 count of tile visits against counts made by hand."""
 
 import dataclasses
-import functools
 import re
 
 import jax
@@ -12,8 +11,10 @@ import numpy as np
 import pytest
 from jax import lax
 
+from jaxprs import interpret_kernels, primitives
 from kungfu_tpu.ops import grouped_matmul as gm
 from kungfu_tpu.ops import moe
+from kungfu_tpu.ops import row_moves
 
 
 ROW_TILE = 128
@@ -27,10 +28,10 @@ def interpreted():
     no trace made under the patches outlives it."""
     jax.clear_caches()
     with pytest.MonkeyPatch.context() as m:
-        for name in ("_gmm", "_tgmm"):
-            m.setattr(gm, name, functools.partial(getattr(gm, name), interpret=True))
-        m.setattr(gm.lax, "platform_dependent",
-                  lambda *args, tpu, default: tpu(*args))
+        # `lax.platform_dependent` is one function for every module: the
+        # rows' kernels beside these are interpreted too
+        interpret_kernels(m, gm, ("_gmm", "_tgmm"))
+        interpret_kernels(m, row_moves, ("_take", "_add"))
         rule = gm.tiling
         m.setattr(gm, "tiling", lambda *shape: rule(*shape)._replace(tm=ROW_TILE))
         yield
@@ -99,10 +100,11 @@ class TestTheInterpretedKernels:
 
     def test_leave_a_chunk_a_third_full_finite_and_xlas_in_both_passes(self):
         """`_chunk_part` under `jax.vjp` with the interpreted kernels in
-        `lax.ragged_dot`'s place: they leave NaN in the rows of no group, and
-        the callers' `jnp.where(live, ...)` select and never multiply, so
-        output and all three cotangents are finite and XLA's. (The class's
-        last case: it clears the traces the others share.)"""
+        `lax.ragged_dot`'s place and `ops/row_moves.py`'s, interpreted, in
+        the place of XLA's gather and scatter-add: what lies in the rows of
+        no group is whatever was found there and none of the kernels reads
+        it, so output and all three cotangents are finite and XLA's. (The
+        class's last case: it clears the traces the others share.)"""
         T, D, F, top_k, held, chunk = 64, 128, 128, 4, 4, 256
         keys = jax.random.split(jax.random.PRNGKey(5), 6)
         x = jax.random.normal(keys[0], (T, D), jnp.bfloat16)
@@ -113,27 +115,22 @@ class TestTheInterpretedKernels:
         order = jax.random.permutation(keys[5], T * top_k)
         dout = jnp.ones((T, D), jnp.float32)
 
-        def run(product):
+        def run(product, take=None, add=None):
             with pytest.MonkeyPatch.context() as m:
                 m.setattr(moe, "grouped_matmul", product)
+                m.setattr(moe, "take_rows", take or moe.take_rows)
+                m.setattr(moe, "add_rows", add or moe.add_rows)
                 jax.clear_caches()  # `_silu_gate_down` is a checkpoint: its trace is kept
                 out, transposes = jax.vjp(
                     lambda *a: moe._chunk_part(moe.swiglu_experts, top_k, chunk,
                                                *a, order, sizes, 0), x, gate, experts)
                 return out, transposes(dout)
 
-        want = run(lax.ragged_dot)
+        want = run(lax.ragged_dot, row_moves.plain_take_rows,
+                   row_moves.plain_add_rows)
         got = run(gm.grouped_matmul)
         for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
             _close(g, w, rel=2.0 ** -6)
-
-
-def _primitives(jaxpr):
-    """The names of the primitives a jaxpr stages, its inner ones' too."""
-    from kungfu_tpu.telemetry.device import _sub_jaxprs
-
-    return {eqn.primitive.name for eqn in jaxpr.eqns}.union(
-        *(_primitives(sub) for eqn in jaxpr.eqns for sub in _sub_jaxprs(eqn)))
 
 
 RULE = {
@@ -160,7 +157,7 @@ def test_the_path_is_chosen_from_the_shape(case):
     shapes = (jax.ShapeDtypeStruct((N, K), jnp.bfloat16),
               jax.ShapeDtypeStruct((e, K, M), jnp.bfloat16),
               jax.ShapeDtypeStruct((e,), jnp.int32))
-    staged = _primitives(jax.make_jaxpr(lambda r, w, s: jax.vjp(
+    staged = primitives(jax.make_jaxpr(lambda r, w, s: jax.vjp(
         lambda r, w: gm.grouped_matmul(r, w, s), r, w)[1](
             jnp.ones((N, M), jnp.bfloat16)))(*shapes).jaxpr)
     assert "ragged_dot_general" in staged

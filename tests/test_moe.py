@@ -283,9 +283,32 @@ def _share(moe, x, router, experts, gates, top_k, first, count):
                        expert_fn=moe.swiglu_experts, held=(first, count))
 
 
-@pytest.mark.parametrize("top_k,count,E", [(3, 4, 16), (5, 2, 16), (1, 8, 16),
-                                           (3, 16, 16), (3, 4, 64)])
-def test_the_shares_parts_add_up_to_the_whole_layer(top_k, count, E):
+@pytest.fixture
+def row_kernels(request):
+    """Whether an unbiased share's rows are moved by `ops/row_moves.py`'s
+    kernels, interpreted, on row tiles of 8 and one strip (the tests' 72, 40
+    and 144 rows of 16 columns tile nowhere by the rule and take XLA's gather
+    and scatter-add): the same values and gradients either way, a case's last
+    parameter (`indirect`; an interpreted program is 2 s a share, so some
+    cases and not all). No trace made under the patches outlives the test."""
+    if not request.param:
+        yield False
+        return
+    from jaxprs import interpret_kernels
+    from kungfu_tpu.ops import row_moves as rm
+
+    jax.clear_caches()
+    with pytest.MonkeyPatch.context() as m:
+        interpret_kernels(m, rm, ("_take", "_add"))
+        m.setattr(rm, "tiling", lambda N, D, T: rm.Tiles(8, D))
+        yield True
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("top_k,count,E,row_kernels", [
+    (3, 4, 16, False), (5, 2, 16, False), (1, 8, 16, False), (3, 16, 16, False),
+    (3, 4, 64, False), (3, 4, 16, True)], indirect=["row_kernels"])
+def test_the_shares_parts_add_up_to_the_whole_layer(top_k, count, E, row_kernels):
     """Every share routes over all E experts and computes its own experts'
     part: the parts of all the shares sum to the layer with every expert
     held, values and gradients, and the shares' counts are the whole
@@ -319,10 +342,15 @@ def test_the_shares_parts_add_up_to_the_whole_layer(top_k, count, E):
                                    rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("E,first,aimed,chunks", [
-    (16, 4, 144, 2), (32, 4, 144, 2), (64, 4, 144, 4), (32, 8, 144, 0),
-    (16, 8, 144, 0), (16, 4, 0, 0), (16, 4, 72, 1), (16, 4, 73, 2)])
-def test_a_share_is_dropless_under_the_worst_load(E, first, aimed, chunks):
+@pytest.mark.parametrize("E,first,aimed,chunks,row_kernels", [
+    (16, 4, 144, 2, False), (32, 4, 144, 2, False), (64, 4, 144, 4, False),
+    (32, 8, 144, 0, False), (16, 8, 144, 0, False), (16, 4, 0, 0, False),
+    (16, 4, 72, 1, False), (16, 4, 73, 2, False),
+    # the kernels: four chunks to the last row, one row in a second chunk, none
+    (64, 4, 144, 4, True), (16, 4, 73, 2, True), (16, 4, 0, 0, True)],
+    indirect=["row_kernels"])
+def test_a_share_is_dropless_under_the_worst_load(E, first, aimed, chunks,
+                                                  row_kernels):
     """`aimed` of the 144 token-choices on experts 4, 5 and 6: the first
     tokens send all three there, one more token its first where `aimed` is
     no multiple of three, and the rest go to experts 0, 1 and 2. The four
@@ -435,6 +463,47 @@ def test_a_chunk_of_all_that_can_fall_here_does_not_follow_the_routing(E, one,
             1 if chunk == 48 * 3 else -(-live // chunk))
 
 
+@pytest.mark.parametrize("biased", [False, True], ids=["no_bias", "bias"])
+def test_a_whole_chunk_holds_no_kernel_of_the_rows_movement(biased):
+    """At a shape `ops/row_moves.tiling` takes (chunks of 128 and 256 rows
+    of 128 columns for 64 tokens) a share without a bias stages the rows'
+    kernels for the TPU in both passes, `take_rows` twice (the dispatch, and
+    again in the backward pass's loop), `add_rows` three times (the combine,
+    again in that loop, where nothing reads it and XLA drops it, and the
+    dispatch's transpose) and `take_rows_weighted` (the combine's
+    transpose); under a selection bias the chunk runs `whole`, on the path
+    it had: XLA's gather and scatter-add, and no kernel of that module."""
+    from kungfu_tpu.ops import row_moves
+
+    moe, x, router, experts, gates = _share_setup(T=64, D=128, F=128)
+    chunk = moe._share_chunk(64, 4, 4, 16, biased)
+    assert chunk == (256 if biased else 128)
+    assert row_moves.tiling(chunk, 128, 64) is not None
+    mine = tuple(w[4:8] for w in experts)
+    bias = jnp.linspace(-0.1, 0.1, 16) if biased else None
+
+    def loss(x, router, mine):
+        return jnp.sum(moe.moe_ffn(x, router, mine, top_k=4, gates=gates,
+                                   expert_fn=moe.swiglu_experts, held=(4, 4),
+                                   bias=bias)[0] ** 2)
+
+    eqns = list(_all_eqns(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+        x, router, mine).jaxpr))
+    kernels = sorted(eqn.params["name"] for eqn in eqns
+                     if eqn.primitive.name == "pallas_call")
+    if biased:
+        assert kernels == []
+    else:
+        assert kernels == ["add_rows"] * 3 + ["take_rows"] * 2 + [
+            "take_rows_weighted"]
+    # XLA's forms: the path itself under a bias, the other platforms' branch
+    # beside the kernels
+    moved = [eqn.primitive.name for eqn in eqns
+             if eqn.primitive.name in ("gather", "scatter-add")
+             and 128 in eqn.outvars[0].aval.shape]
+    assert {"gather", "scatter-add"} <= set(moved)
+
+
 # what `_share_chunk` gives at the cells' shapes: T, top_k, held, experts, bias
 CELL_CHUNKS = {
     "glm_4_7_flash": ((8192, 4, 8, 64, True), 32768),
@@ -485,6 +554,8 @@ def test_the_chunk_fill_share_is_the_held_rows_over_the_chunks_that_ran():
     rows = ((chosen >= 4) & (chosen < 8)).sum(axis=(1, 2))
     assert rows.tolist() == stats["held_rows"].tolist() and rows[1] > 48 > rows[0] > 0
     assert stats["chunk_rows"].tolist() == (48 * -(-rows // 48)).tolist()
+    # chunks of 48 rows of 32 columns tile nowhere: XLA's forms move them all
+    assert stats["rows_moved"].tolist() == stats["chunk_rows"].tolist()
     registry = metrics.Registry()
     transformer.record_routing(stats, registry)
     text = registry.render()
@@ -493,22 +564,30 @@ def test_the_chunk_fill_share_is_the_held_rows_over_the_chunks_that_ran():
         line = [l for l in text.splitlines() if l.startswith(
             f'kungfu_moe_chunk_fill_share{{layer="{layer}"}}')]
         assert float(line[0].split()[-1]) == pytest.approx(share, rel=1e-6)
+        assert f'kungfu_moe_rows_moved_share{{layer="{layer}"}} 1' in text
     by_hand = {"counts": np.array([[30, 20, 10, 10], [0, 0, 0, 0], [5, 0, 7, 2]]),
                "held_rows": np.array([70, 0, 14]), "dropped": np.zeros(3),
                "max_over_mean": np.ones(3), "chosen": np.zeros((3, 64, 3), np.int32),
-               "layer": np.array([0, 2, 5]), "chunk_rows": np.array([96, 0, 192])}
+               "layer": np.array([0, 2, 5]), "chunk_rows": np.array([96, 0, 192]),
+               "rows_moved": np.array([80, 0, 192])}
     registry = metrics.Registry()
     transformer.record_routing(by_hand, registry)
     text = registry.render()
     assert f'kungfu_moe_chunk_fill_share{{layer="0"}} {70 / 96}' in text
     assert 'kungfu_moe_chunk_fill_share{layer="2"} 1' in text  # no chunk ran
     assert f'kungfu_moe_chunk_fill_share{{layer="5"}} {14 / 192}' in text
+    # the rows of the tiles the movement visits over the same chunks: the
+    # fill rounded up to a row tile, 1 where no chunk ran and under a bias
+    assert f'kungfu_moe_rows_moved_share{{layer="0"}} {80 / 96}' in text
+    assert 'kungfu_moe_rows_moved_share{layer="2"} 1' in text
+    assert 'kungfu_moe_rows_moved_share{layer="5"} 1' in text
     whole = TransformerConfig.tiny_moe()  # every expert held: no chunks, no gauge
     stats = jax.jit(lambda p, t: transformer.routing_stats(p, t, whole))(
         transformer.init_transformer(jax.random.PRNGKey(2), whole), tokens)
     registry = metrics.Registry()
     transformer.record_routing(stats, registry)
     assert "chunk_rows" not in stats and "chunk_fill_share" not in registry.render()
+    assert "rows_moved" not in stats and "rows_moved_share" not in registry.render()
 
 
 def test_a_share_of_every_expert_is_the_layer_itself():
